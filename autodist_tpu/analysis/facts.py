@@ -74,15 +74,21 @@ _BARRIER_RE = re.compile(r"\b(?:opt-barrier|optimization-barrier)(?:\.\d+)?\(")
 # the kernel's ops exist in the optimized program (the ADT120 rule).
 _KERNEL_MARKER_RE = re.compile(r"adtk_([a-z0-9_]+)")
 
-# Plain `gather` ops with their first-operand shape (the paged-KV
-# block-table rule scans for gathers whose OPERAND carries the block
-# pool's distinctive extent — the structural evidence the decode reads
-# K/V through the table).  The negative lookbehind keeps `all-gather(`
-# (a collective, counted above) out.
+# Plain `gather` ops and their first operand (the paged-KV block-table
+# rule scans for gathers whose OPERAND carries the block pool's
+# distinctive extent — the structural evidence the decode reads K/V
+# through the table).  HLO text names the operand; whether it also
+# prints the operand's shape inline (`gather(f32[13,2]{1,0} %pool, ...)`)
+# or only the name (`gather(%pool, ...)`, jax 0.9.0) is the printer's
+# choice, so the shape is resolved through the operand's defining
+# instruction when it is not inline.  The negative lookbehind keeps
+# `all-gather(` (a collective, counted above) out.
+_ARRAY_TYPE = (r"(?:pred|s4|u4|s8|u8|s16|u16|s32|u32|s64|u64|"
+               r"f8\w*|bf16|f16|f32|f64|c64|c128)\[([0-9,]*)\]")
 _GATHER_RE = re.compile(
-    r"(?<![\w-])gather\(\s*"
-    r"(?:pred|s4|u4|s8|u8|s16|u16|s32|u32|s64|u64|"
-    r"f8\w*|bf16|f16|f32|f64|c64|c128)\[([0-9,]*)\]")
+    r"(?<![\w-])gather\(\s*(?:" + _ARRAY_TYPE + r"\S*\s+)?%?([\w.\-]+)")
+_ARRAY_DEF_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s*" + _ARRAY_TYPE, re.M)
 
 
 def collective_counts(hlo_text: str) -> dict[str, int]:
@@ -200,10 +206,15 @@ def gathers_with_operand_dim(hlo_text: str, dim: int) -> int:
     ``num_blocks`` extent), a hit IS a block-table gather over the KV
     pool, and zero hits proves the program never reads the cache
     through the table."""
+    shape_of = None
     hits = 0
     for m in _GATHER_RE.finditer(hlo_text):
-        dims = [int(d) for d in m.group(1).split(",") if d]
-        if dim in dims:
+        dims = m.group(1)
+        if dims is None:                  # operand printed by name only
+            if shape_of is None:
+                shape_of = dict(_ARRAY_DEF_RE.findall(hlo_text))
+            dims = shape_of.get(m.group(2), "")
+        if dim in [int(d) for d in dims.split(",") if d]:
             hits += 1
     return hits
 

@@ -60,9 +60,13 @@ U_AXIS = "axis"               # 1/N chunk along a tensor axis
 # restructures the *program* so overlap is possible; these flags let the
 # *compiler* exploit it — and they also overlap collectives this build
 # doesn't decompose (grad allreduces behind backprop).  Gated behind
-# AUTODIST_TPU_ASYNC_COLLECTIVES=1 because they are TPU-backend
-# scheduling flags: harmless but useless on CPU, and on a shared XLA_FLAGS
-# environment silently appending them would surprise whoever set it.
+# AUTODIST_TPU_ASYNC_COLLECTIVES=1 because they are TPU-compiler
+# scheduling flags, and silently appending to a shared flag environment
+# would surprise whoever set it.  They are libtpu's flags, so they ride
+# libtpu's own channel, LIBTPU_INIT_ARGS: under jax 0.9.0 / libtpu 0.0.34
+# jaxlib's XLA_FLAGS parser aborts on every one of them ("Unknown flags
+# in XLA_FLAGS", chip run, PR 21) while LIBTPU_INIT_ARGS takes them.
+LATENCY_HIDING_FLAGS_ENV = "LIBTPU_INIT_ARGS"
 LATENCY_HIDING_XLA_FLAGS = (
     "--xla_tpu_enable_async_collective_fusion=true",
     "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
@@ -89,16 +93,16 @@ def _targets_tpu(platform, env) -> bool:
 
 
 def apply_latency_hiding_flags(env=None, platform=None) -> bool:
-    """Append :data:`LATENCY_HIDING_XLA_FLAGS` to ``XLA_FLAGS`` when the
-    ``AUTODIST_TPU_ASYNC_COLLECTIVES`` knob is set (value ``1``/``True``
-    = the default list; a value starting with ``--`` replaces the list
-    verbatim — flag names drift across jaxlib versions).
+    """Append :data:`LATENCY_HIDING_XLA_FLAGS` to ``LIBTPU_INIT_ARGS``
+    when the ``AUTODIST_TPU_ASYNC_COLLECTIVES`` knob is set (value
+    ``1``/``True`` = the default list; a value starting with ``--``
+    replaces the list verbatim — flag names drift across libtpu
+    versions).
 
     Returns whether the flags are (now) present.  Applied only when the
-    process targets a TPU backend: XLA *aborts* on flags its build
-    doesn't define, so appending TPU scheduling flags under a CPU/GPU
-    client would kill the process at init.  XLA reads the env var once
-    at backend-client init, so this must run before the first device
+    process targets a TPU backend (nothing else reads the variable).
+    libtpu reads it once at backend-client init, so this must run
+    before the first device
     touch — ``ResourceSpec.bootstrap()`` calls it at the right moment
     for ``AutoDist``-built runners (passing the spec's platform);
     scripts managing their own backend call it first thing.  If the
@@ -118,13 +122,13 @@ def apply_latency_hiding_flags(env=None, platform=None) -> bool:
         logging.warning(
             "AUTODIST_TPU_ASYNC_COLLECTIVES is set but this process does "
             "not target a TPU backend; skipping the latency-hiding "
-            "XLA flags (XLA aborts on flags its build doesn't define)")
+            "flags")
         return False
-    current = env.get("XLA_FLAGS", "")
+    current = env.get(LATENCY_HIDING_FLAGS_ENV, "")
     missing = [f for f in flags if f not in current]
     if not missing:
         return True
-    env["XLA_FLAGS"] = " ".join([current] + missing).strip()
+    env[LATENCY_HIDING_FLAGS_ENV] = " ".join([current] + missing).strip()
     already_up = False
     try:  # backend registry probe; private, so failure = assume not up
         from jax._src import xla_bridge

@@ -47,42 +47,52 @@ from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.kernel import quantize as qz
 from autodist_tpu.kernel.pallas import default_interpret, kernel_marker
+from autodist_tpu.kernel.pallas.quant_ring import (LANES, hop_grid,
+                                                   reduce_abs_max, to_tiles)
 
 
 def _dq_and_q_kernel(scale_in_ref, q_in_ref, next_ref, out_ref,
-                     q_out_ref, scale_out_ref):
+                     q_out_ref, scale_out_ref, amax_ref):
     """One fused hop pass: dequantize the arrived chunk
     (``out = q_in * scale_in``) and quantize the next outgoing chunk
     against its own abs-max scale — the work a composed lowering would
-    spread over HBM-shaped converts, in one VMEM pass.  ``scale_in ==
-    0`` (the warm-up, nothing arrived yet) makes the dequantized block
-    vanish to exact zeros; an all-zero ``next`` quantizes to exact
-    zeros through the scale floor."""
-    out_ref[...] = q_in_ref[...].astype(jnp.float32) * scale_in_ref[0, 0]
+    spread over HBM-shaped converts, block by block in VMEM (the
+    two-phase walk of :func:`~autodist_tpu.kernel.pallas.quant_ring
+    .hop_grid`).  ``scale_in == 0`` (the warm-up, nothing arrived yet)
+    makes the dequantized block vanish to exact zeros; an all-zero
+    ``next`` quantizes to exact zeros through the scale floor."""
     nxt = next_ref[...].astype(jnp.float32)
-    scale = qz.abs_max_scale(nxt)
-    q_out_ref[...] = qz.quantize_levels(nxt, scale).astype(jnp.int8)
-    scale_out_ref[0, 0] = scale
+    reduce_abs_max(amax_ref, nxt)
+
+    @pl.when(pl.program_id(0) == 1)
+    def _write():
+        out_ref[...] = q_in_ref[...].astype(jnp.float32) \
+            * scale_in_ref[0, 0]
+        scale = qz.scale_of_abs_max(amax_ref[0])
+        q_out_ref[...] = qz.quantize_levels(nxt, scale).astype(jnp.int8)
+        scale_out_ref[0, 0] = scale
 
 
 def _fused_hop(q_in, scale_in, nxt, *, interpret: bool):
-    """Run the fused pass; ``q_in`` s8 ``[1, L]``, ``scale_in`` f32
-    scalar, ``nxt`` f32 ``[1, L]`` -> ``(arrived f32 [1, L], q_out s8
-    [1, L], scale_out f32 scalar)``."""
-    L = nxt.shape[-1]
+    """Run the fused pass; ``q_in`` s8 ``[rows, LANES]``, ``scale_in``
+    f32 scalar, ``nxt`` f32 ``[rows, LANES]`` -> ``(arrived f32 [rows,
+    LANES], q_out s8 [rows, LANES], scale_out f32 scalar)``."""
+    rows = nxt.shape[0]
+    grid, br, in_map, out_map = hop_grid(rows)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     out, q_out, scale_out = pl.pallas_call(
         _dq_and_q_kernel,
-        in_specs=[
-            pl.BlockSpec((1, 1), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec((1, 1), memory_space=pltpu.SMEM)),
-        out_shape=(jax.ShapeDtypeStruct((1, L), jnp.float32),
-                   jax.ShapeDtypeStruct((1, L), jnp.int8),
+        grid=grid,
+        in_specs=[smem,
+                  # only phase 1 reads the arrived chunk
+                  pl.BlockSpec((br, LANES), out_map),
+                  pl.BlockSpec((br, LANES), in_map)],
+        out_specs=(pl.BlockSpec((br, LANES), out_map),
+                   pl.BlockSpec((br, LANES), out_map), smem),
+        out_shape=(jax.ShapeDtypeStruct((rows, LANES), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)),
+        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
     )(scale_in.reshape(1, 1), q_in, nxt)
     return out, q_out, scale_out[0, 0]
@@ -110,27 +120,27 @@ def quantized_ring_all_to_all(x, axis_name, *, split_axis: int,
     me = lax.axis_index(axis_name)
 
     # Canonicalize: parts[j] = the chunk destined for device j, each
-    # flattened to [1, L] for the kernel passes.
+    # flattened and zero-padded to whole VMEM tiles for the kernel
+    # passes (the form it keeps on the wire).
     moved = jnp.moveaxis(x, split_axis, 0).astype(jnp.float32)
     part_shape = (moved.shape[0] // n,) + moved.shape[1:]
-    parts = moved.reshape((n,) + part_shape)
     L = int(np.prod(part_shape)) if part_shape else 1
-    flat = parts.reshape(n, 1, L)
+    flat = to_tiles(moved.reshape(n, L))             # [n, rows, LANES]
+    tile = flat.shape[1:]
 
     def part(shift):
         # The chunk destined for device (me + shift) % n.
-        return lax.dynamic_slice_in_dim(
-            flat, (me + shift) % n, 1, axis=0).reshape(1, L)
+        return lax.dynamic_index_in_dim(flat, (me + shift) % n, axis=0,
+                                        keepdims=False)
 
-    out = jnp.zeros((n, 1, L), jnp.float32)
+    out = jnp.zeros((n,) + tile, jnp.float32)
     with jax.named_scope(kernel_marker("a2a_ring")):
         # Warm-up: quantize hop 1's outgoing chunk (nothing arrived).
-        _, q, s = _fused_hop(jnp.zeros((1, L), jnp.int8),
+        _, q, s = _fused_hop(jnp.zeros(tile, jnp.int8),
                              jnp.float32(0.0), part(1),
                              interpret=interp)
         # Own chunk stays local and exact (it never rides the wire).
-        out = lax.dynamic_update_slice(
-            out, part(0).reshape(1, 1, L), (me, 0, 0))
+        out = lax.dynamic_update_index_in_dim(out, part(0), me, axis=0)
         # Hops unrolled (n is static and small): every hop's s8
         # ppermute is its own HLO op — the n-1 narrowed transfers per
         # all-to-all (2(n-1) per dispatch+combine pair) ADT120 counts
@@ -139,14 +149,15 @@ def quantized_ring_all_to_all(x, axis_name, *, split_axis: int,
             perm = [(i, (i + h) % n) for i in range(n)]
             q = lax.ppermute(q, axis_name, perm)
             s = lax.ppermute(s, axis_name, perm)
-            nxt = part(h + 1) if h + 1 < n else jnp.zeros((1, L),
+            nxt = part(h + 1) if h + 1 < n else jnp.zeros(tile,
                                                           jnp.float32)
             arrived, q, s = _fused_hop(q, s, nxt, interpret=interp)
             # Hop h delivered device (me - h)'s chunk for me -> slot
             # (me - h) % n (output parts are source-ordered).
-            out = lax.dynamic_update_slice(
-                out, arrived.reshape(1, 1, L), ((me - h) % n, 0, 0))
+            out = lax.dynamic_update_index_in_dim(
+                out, arrived, (me - h) % n, axis=0)
 
+    out = out.reshape(n, -1)[:, :L]
     gathered = out.reshape((n,) + part_shape)        # source-major
     # Reassemble tiled-concat semantics: received parts concatenate
     # along concat_axis in source order.

@@ -32,13 +32,37 @@ from jax.experimental import pallas as pl
 from autodist_tpu.kernel.pallas import default_interpret, kernel_marker
 
 
-def _matmul_acc_kernel(carry_ref, x_ref, k_ref, o_ref, *, out_dtype):
-    """``o = carry + x @ k`` in one pass (fp32 accumulation)."""
-    acc = jax.lax.dot_general(
+# Tile sizes of the fused step's (M, C, K) grid.  A dimension no larger
+# than its tile is one full-extent block; a larger one is walked in
+# tiles (zero-padded to a whole number of them), so VMEM use is bounded
+# by the tiles — about 1.5 MiB double-buffered in bf16 — whatever the
+# activation's row count or the contraction width.
+TILE_M, TILE_C, TILE_K = 256, 256, 512
+
+
+def _matmul_acc_kernel(carry_ref, x_ref, k_ref, o_ref, acc_ref):
+    """``o = carry + x @ k``, accumulated in fp32 over the K grid axis:
+    the accumulator opens on the carry tile and closes into the output
+    tile at the last K step."""
+    kk = pl.program_id(2)
+
+    @pl.when(kk == 0)
+    def _open():
+        acc_ref[...] = carry_ref[...].astype(jnp.float32)
+
+    acc_ref[...] += jax.lax.dot_general(
         x_ref[...], k_ref[...], (((1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32)
-    o_ref[...] = (carry_ref[...].astype(jnp.float32)
-                  + acc).astype(out_dtype)
+
+    @pl.when(kk == pl.num_programs(2) - 1)
+    def _close():
+        o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _padded(a, tiles):
+    """Zero-pad each dim of ``a`` past its tile up to whole tiles."""
+    pads = [(0, (-d) % t if d > t else 0) for d, t in zip(a.shape, tiles)]
+    return jnp.pad(a, pads) if any(p for _, p in pads) else a
 
 
 def _fused_matmul_add(carry, x2d, kc2d, *, interpret: bool):
@@ -47,13 +71,24 @@ def _fused_matmul_add(carry, x2d, kc2d, *, interpret: bool):
     from jax.experimental.pallas import tpu as pltpu
 
     M, C = carry.shape
-    return pl.pallas_call(
-        functools.partial(_matmul_acc_kernel, out_dtype=carry.dtype),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM)] * 3,
-        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((M, C), carry.dtype),
+    carry_p = _padded(carry, (TILE_M, TILE_C))
+    x_p = _padded(x2d, (TILE_M, TILE_K))
+    k_p = _padded(kc2d, (TILE_K, TILE_C))
+    Mp, Cp = carry_p.shape
+    Kp = x_p.shape[1]
+    tm, tc, tk = min(Mp, TILE_M), min(Cp, TILE_C), min(Kp, TILE_K)
+    out = pl.pallas_call(
+        _matmul_acc_kernel,
+        grid=(Mp // tm, Cp // tc, Kp // tk),
+        in_specs=[pl.BlockSpec((tm, tc), lambda i, j, k: (i, j)),
+                  pl.BlockSpec((tm, tk), lambda i, j, k: (i, k)),
+                  pl.BlockSpec((tk, tc), lambda i, j, k: (k, j))],
+        out_specs=pl.BlockSpec((tm, tc), lambda i, j, k: (i, j)),
+        out_shape=jax.ShapeDtypeStruct((Mp, Cp), carry.dtype),
+        scratch_shapes=[pltpu.VMEM((tm, tc), jnp.float32)],
         interpret=interpret,
-    )(carry, x2d, kc2d)
+    )(carry_p, x_p, k_p)
+    return out[:M, :C] if (Mp, Cp) != (M, C) else out
 
 
 def _fused_ring_fwd(x, kernel, model_axis, axes: int,

@@ -24,6 +24,7 @@ agreement with the full-recompute ``sequential_logits`` reference.
 """
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -42,44 +43,79 @@ NEG_INF = float(np.finfo(np.float32).min)
 DEFAULT_BLOCK_K = 128
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, *, block_k: int,
-                   num_blocks: int, scale: float, out_dtype):
-    """One (slot, head) program: online-softmax over T in ``block_k``
-    tiles.  ``len_ref``: (1, 1) int32 in SMEM — the slot's occupancy;
-    visible keys are positions ``<= length``."""
-    length = len_ref[0, 0]
-    d = q_ref.shape[-1]
-    q = q_ref[...].reshape(1, d).astype(jnp.float32)
+def online_softmax_step(first_pos, j, q_ref, k_ref, v_ref, o_ref, m_ref,
+                        s_ref, acc_ref, *, block_len: int, scale: float,
+                        out_dtype):
+    """One (slot, head, kv-block) grid step of cached attention for a
+    window of ``C`` query rows — shared by the dense decode, the paged
+    decode (``C == 1``) and the paged prefill kernel (``C`` = the
+    chunk); they differ only in how the BlockSpec index maps pick block
+    ``j``'s ``[block_len, d]`` tile.
 
-    def body(i, carry):
-        m, s, acc = carry
-        kblk = k_ref[0, 0, pl.ds(i * block_k, block_k), :] \
-            .astype(jnp.float32)                          # [bk, d]
-        scores = jax.lax.dot_general(
-            q, kblk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale    # [1, bk]
-        idx = i * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1)
-        scores = jnp.where(idx <= length, scores, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)
-        p = jnp.exp(scores - m_new)                        # [1, bk]
-        vblk = v_ref[0, 0, pl.ds(i * block_k, block_k), :] \
-            .astype(jnp.float32)                          # [bk, d]
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)            # [1, d]
-        return m_new, s_new_of(s, alpha, p), acc_new
+    Window row ``r`` sits at absolute position ``first_pos + r`` and
+    sees keys at positions ``<= first_pos + r`` (a decode step's one
+    query is the token just written at ``first_pos == length``).
+    Everything later — the zero tail, a previous occupant's stale rows,
+    an unassigned table entry's aliased block — is hidden by that mask.
+    Position 0 is visible to every row, so the running max is finite
+    from block 0 on and fully-masked later blocks contribute
+    ``exp(NEG_INF - finite) == 0``.
 
-    def s_new_of(s, alpha, p):
-        return s * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    The kv-block walk lives in the GRID's innermost dimension, so
+    Pallas's own pipeline double-buffers the per-block DMA and the VMEM
+    working set is one block per operand — independent of the cache
+    length or pool size.  The online-softmax carry (running max / sum /
+    accumulator, one row per query) persists across the ``j`` steps in
+    VMEM scratch: initialized at ``j == 0``, emitted at the last
+    block."""
+    rows, d = q_ref.shape[-2:]
 
-    m0 = jnp.full((1, 1), NEG_INF, jnp.float32)
-    s0 = jnp.zeros((1, 1), jnp.float32)
-    acc0 = jnp.zeros((1, d), jnp.float32)
-    m, s, acc = jax.lax.fori_loop(0, num_blocks, body, (m0, s0, acc0))
-    # Position 0 is always visible (length >= 0), so s > 0.
-    o_ref[...] = (acc / s).reshape(o_ref.shape).astype(out_dtype)
+    @pl.when(j == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        s_ref[...] = jnp.zeros_like(s_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    q = q_ref[...].reshape(rows, d).astype(jnp.float32)
+    kblk = k_ref[...].reshape(block_len, d).astype(jnp.float32)
+    scores = jax.lax.dot_general(
+        q, kblk, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) * scale        # [C, bl]
+    idx = j * block_len + jax.lax.broadcasted_iota(
+        jnp.int32, (rows, block_len), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, block_len), 0)
+    scores = jnp.where(idx <= first_pos + row, scores, NEG_INF)
+    m, s, acc = m_ref[...], s_ref[...], acc_ref[...]
+    m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+    alpha = jnp.exp(m - m_new)                             # [C, 1]
+    p = jnp.exp(scores - m_new)                            # [C, bl]
+    vblk = v_ref[...].reshape(block_len, d).astype(jnp.float32)
+    m_ref[...] = m_new
+    s_ref[...] = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
+    acc_ref[...] = acc * alpha + jax.lax.dot_general(
+        p, vblk, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                # [C, d]
+
+    @pl.when(j == pl.num_programs(2) - 1)
+    def _emit():
+        # Position 0 is visible to every row, so s > 0 rowwise.
+        o_ref[...] = (acc_ref[...] / s_ref[...]) \
+            .reshape(o_ref.shape).astype(out_dtype)
+
+
+def carry_scratch(rows: int, d: int):
+    return [pltpu.VMEM((rows, 1), jnp.float32),   # running max per row
+            pltpu.VMEM((rows, 1), jnp.float32),   # running sum per row
+            pltpu.VMEM((rows, d), jnp.float32)]   # accumulator per row
+
+
+def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, s_ref,
+                   acc_ref, **kw):
+    """Dense cache: block ``j`` is rows ``[j*bk, (j+1)*bk)`` of the
+    slot's lane.  ``len_ref``: scalar-prefetched ``[B]`` int32."""
+    online_softmax_step(len_ref[pl.program_id(0)], pl.program_id(2),
+                        q_ref, k_ref, v_ref, o_ref, m_ref, s_ref,
+                        acc_ref, **kw)
 
 
 def flash_decode_attention(q, k_layer, v_layer, lengths, *,
@@ -94,9 +130,11 @@ def flash_decode_attention(q, k_layer, v_layer, lengths, *,
     cache slice in its native layout); ``lengths``: ``[B]`` int32.
     Returns ``[B, 1, heads, head_dim]`` in ``dtype``.
 
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU (the
-    CPU-golden contract); ``block_k`` defaults to
-    :data:`DEFAULT_BLOCK_K` capped at the padded cache length.
+    ``interpret=None`` follows :func:`default_interpret`; ``block_k``
+    defaults to :data:`DEFAULT_BLOCK_K` capped at the cache length.  A
+    cache length that ``block_k`` does not divide is zero-padded per
+    call (a copy of the layer's cache) — size ``max_len`` to a block
+    multiple where that matters.
     """
     B, _, H, d = q.shape
     T = k_layer.shape[2]
@@ -105,40 +143,34 @@ def flash_decode_attention(q, k_layer, v_layer, lengths, *,
     pad = (-T) % bk
     if pad:
         # Padded positions sit at idx >= T > any legal length, so the
-        # in-kernel mask never reads them as real keys — no clamped
-        # dynamic-slice aliasing of earlier rows.
+        # in-kernel mask never reads them as real keys.
         cfg = [(0, 0), (0, 0), (0, pad), (0, 0)]
         k_layer = jnp.pad(k_layer, cfg)
         v_layer = jnp.pad(v_layer, cfg)
-    num_blocks = (T + pad) // bk
     scale = 1.0 / float(np.sqrt(d))
 
     q2 = jnp.swapaxes(q, 1, 2)                 # [B, H, 1, d]
-    len2d = lengths.astype(jnp.int32).reshape(B, 1)
-
-    import functools
-
-    kern = functools.partial(_decode_kernel, block_k=bk,
-                             num_blocks=num_blocks, scale=scale,
+    kern = functools.partial(_decode_kernel, block_len=bk, scale=scale,
                              out_dtype=dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,                 # lengths (SMEM)
+        grid=(B, H, (T + pad) // bk),
+        in_specs=[
+            pl.BlockSpec((1, 1, 1, d), lambda b, h, j, lens: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, lens: (b, h, j, 0)),
+            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, lens: (b, h, j, 0)),
+        ],
+        out_specs=pl.BlockSpec((1, 1, 1, d),
+                               lambda b, h, j, lens: (b, h, 0, 0)),
+        scratch_shapes=carry_scratch(1, d),
+    )
     with jax.named_scope(kernel_marker("flash_decode")):
         out = pl.pallas_call(
             kern,
-            grid=(B, H),
-            in_specs=[
-                pl.BlockSpec((1, 1), lambda b, h: (b, 0),
-                             memory_space=pltpu.SMEM),
-                pl.BlockSpec((1, 1, 1, d), lambda b, h: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, T + pad, d),
-                             lambda b, h: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, T + pad, d),
-                             lambda b, h: (b, h, 0, 0)),
-            ],
-            out_specs=pl.BlockSpec((1, 1, 1, d),
-                                   lambda b, h: (b, h, 0, 0)),
+            grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, 1, d), dtype),
             interpret=interp,
-        )(len2d, q2, k_layer, v_layer)
+        )(lengths.astype(jnp.int32), q2, k_layer, v_layer)
     return jnp.swapaxes(out, 1, 2)             # [B, 1, H, d]
 
 
@@ -146,58 +178,14 @@ def flash_decode_attention(q, k_layer, v_layer, lengths, *,
 # Paged variant: the block loop IS the page loop
 # --------------------------------------------------------------------------- #
 def _paged_decode_kernel(len_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, s_ref, acc_ref, *, block_len: int,
-                         scale: float, out_dtype):
-    """One (slot, head, logical-block) program over a *paged* cache.
-
-    The page walk lives in the GRID, not in the kernel body: the grid's
-    innermost dimension is the slot's logical block index ``j``, and
-    the k/v BlockSpecs' index maps read the scalar-prefetched block
-    table (``tab_ref[b, j]``) to pick WHICH pool block this step's
-    ``[block_len, d]`` VMEM tile stages — so Pallas's own pipeline
-    double-buffers the per-block DMA and the VMEM working set is one
-    block per operand, independent of pool size.  The online-softmax
-    carry (running max / sum / accumulator) persists across the ``j``
-    steps in VMEM scratch: initialized at ``j == 0``, emitted at the
-    last block — the dense kernel's fori_loop recurrence, unrolled
-    into the grid.  The tail block (and any unassigned table entry,
-    which holds 0 and may alias another slot's block) is hidden by the
-    ``idx <= length`` mask exactly like the dense kernel's zero-pad."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    d = q_ref.shape[-1]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        s_ref[...] = jnp.zeros_like(s_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    length = len_ref[b, 0]
-    q = q_ref[...].reshape(1, d).astype(jnp.float32)
-    kblk = k_ref[...].reshape(block_len, d).astype(jnp.float32)
-    scores = jax.lax.dot_general(
-        q, kblk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale        # [1, bl]
-    idx = j * block_len + jax.lax.broadcasted_iota(
-        jnp.int32, (1, block_len), 1)
-    scores = jnp.where(idx <= length, scores, NEG_INF)
-    m, s, acc = m_ref[...], s_ref[...], acc_ref[...]
-    m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m - m_new)
-    p = jnp.exp(scores - m_new)                            # [1, bl]
-    vblk = v_ref[...].reshape(block_len, d).astype(jnp.float32)
-    m_ref[...] = m_new
-    s_ref[...] = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc * alpha + jax.lax.dot_general(
-        p, vblk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                # [1, d]
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        # Position 0 is always visible (length >= 0), so s > 0.
-        o_ref[...] = (acc_ref[...] / s_ref[...]) \
-            .reshape(o_ref.shape).astype(out_dtype)
+                         m_ref, s_ref, acc_ref, **kw):
+    """Paged cache: block ``j`` is pool block ``tab[b, j]`` — the k/v
+    BlockSpecs' index maps read the scalar-prefetched block table, so
+    the kernel body never sees the table (``tab_ref`` is unused here)."""
+    del tab_ref
+    online_softmax_step(len_ref[pl.program_id(0)], pl.program_id(2),
+                        q_ref, k_ref, v_ref, o_ref, m_ref, s_ref,
+                        acc_ref, **kw)
 
 
 def flash_decode_attention_paged(q, k_pool, v_pool, lengths, block_table,
@@ -227,15 +215,12 @@ def flash_decode_attention_paged(q, k_pool, v_pool, lengths, block_table,
     scale = 1.0 / float(np.sqrt(d))
 
     q2 = jnp.swapaxes(q, 1, 2)                 # [B, H, 1, d]
-    len2d = lengths.astype(jnp.int32).reshape(B, 1)
     tab = block_table.astype(jnp.int32)
-
-    import functools
 
     kern = functools.partial(_paged_decode_kernel, block_len=block_len,
                              scale=scale, out_dtype=dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # len2d, tab (SMEM)
+        num_scalar_prefetch=2,                 # lengths, tab (SMEM)
         grid=(B, H, mb),
         in_specs=[
             pl.BlockSpec((1, 1, 1, d),
@@ -247,11 +232,7 @@ def flash_decode_attention_paged(q, k_pool, v_pool, lengths, block_table,
         ],
         out_specs=pl.BlockSpec((1, 1, 1, d),
                                lambda b, h, j, lens, t: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running sum
-            pltpu.VMEM((1, d), jnp.float32),   # accumulator
-        ],
+        scratch_shapes=carry_scratch(1, d),
     )
     with jax.named_scope(kernel_marker("flash_decode")):
         out = pl.pallas_call(
@@ -259,5 +240,5 @@ def flash_decode_attention_paged(q, k_pool, v_pool, lengths, block_table,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, 1, d), dtype),
             interpret=interp,
-        )(len2d, tab, q2, k_pool, v_pool)
+        )(lengths.astype(jnp.int32), tab, q2, k_pool, v_pool)
     return jnp.swapaxes(out, 1, 2)             # [B, 1, H, d]

@@ -41,52 +41,22 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.kernel.pallas import default_interpret, kernel_marker
-
-NEG_INF = float(np.finfo(np.float32).min)
+from autodist_tpu.kernel.pallas.flash_decode import (carry_scratch,
+                                                     online_softmax_step)
 
 
 def _paged_prefill_kernel(start_ref, tab_ref, q_ref, k_ref, v_ref,
-                          o_ref, m_ref, s_ref, acc_ref, *,
-                          block_len: int, chunk: int, scale: float,
-                          out_dtype):
+                          o_ref, m_ref, s_ref, acc_ref, **kw):
     """One (slot, head, logical-block) program: ``C`` chunk queries
-    against one pool block, online-softmax carries keyed per row."""
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-    d = q_ref.shape[-1]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        s_ref[...] = jnp.zeros_like(s_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    start = start_ref[b, 0]
-    q = q_ref[...].reshape(chunk, d).astype(jnp.float32)
-    kblk = k_ref[...].reshape(block_len, d).astype(jnp.float32)
-    scores = jax.lax.dot_general(
-        q, kblk, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) * scale    # [C, bl]
-    idx = j * block_len + jax.lax.broadcasted_iota(
-        jnp.int32, (chunk, block_len), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (chunk, block_len), 0)
-    scores = jnp.where(idx <= start + row, scores, NEG_INF)
-    m, s, acc = m_ref[...], s_ref[...], acc_ref[...]
-    m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-    alpha = jnp.exp(m - m_new)                         # [C, 1]
-    p = jnp.exp(scores - m_new)                        # [C, bl]
-    vblk = v_ref[...].reshape(block_len, d).astype(jnp.float32)
-    m_ref[...] = m_new
-    s_ref[...] = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
-    acc_ref[...] = acc * alpha + jax.lax.dot_general(
-        p, vblk, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)            # [C, d]
-
-    @pl.when(j == pl.num_programs(2) - 1)
-    def _emit():
-        # Position 0 is visible to every chunk row, so s > 0 rowwise.
-        o_ref[...] = (acc_ref[...] / s_ref[...]) \
-            .reshape(o_ref.shape).astype(out_dtype)
+    against one pool block — the decode kernels' grid step
+    (:func:`~autodist_tpu.kernel.pallas.flash_decode
+    .online_softmax_step`) with a ``C``-row window starting at the
+    slot's ``starts`` entry.  The block table is read by the k/v index
+    maps, not here."""
+    del tab_ref
+    online_softmax_step(start_ref[pl.program_id(0)], pl.program_id(2),
+                        q_ref, k_ref, v_ref, o_ref, m_ref, s_ref, acc_ref,
+                        **kw)
 
 
 def flash_prefill_attention_paged(q, k_pool, v_pool, starts, block_table,
@@ -111,13 +81,12 @@ def flash_prefill_attention_paged(q, k_pool, v_pool, starts, block_table,
     scale = 1.0 / float(np.sqrt(d))
 
     q2 = jnp.swapaxes(q, 1, 2)                 # [B, H, C, d]
-    start2d = starts.astype(jnp.int32).reshape(B, 1)
     tab = block_table.astype(jnp.int32)
 
     kern = functools.partial(_paged_prefill_kernel, block_len=block_len,
-                             chunk=C, scale=scale, out_dtype=dtype)
+                             scale=scale, out_dtype=dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,                 # start2d, tab (SMEM)
+        num_scalar_prefetch=2,                 # starts, tab (SMEM)
         grid=(B, H, mb),
         in_specs=[
             pl.BlockSpec((1, 1, C, d),
@@ -129,11 +98,7 @@ def flash_prefill_attention_paged(q, k_pool, v_pool, starts, block_table,
         ],
         out_specs=pl.BlockSpec((1, 1, C, d),
                                lambda b, h, j, st, t: (b, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((C, 1), jnp.float32),   # running max per row
-            pltpu.VMEM((C, 1), jnp.float32),   # running sum per row
-            pltpu.VMEM((C, d), jnp.float32),   # accumulator per row
-        ],
+        scratch_shapes=carry_scratch(C, d),
     )
     with jax.named_scope(kernel_marker("flash_prefill")):
         out = pl.pallas_call(
@@ -141,5 +106,5 @@ def flash_prefill_attention_paged(q, k_pool, v_pool, starts, block_table,
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((B, H, C, d), dtype),
             interpret=interp,
-        )(start2d, tab, q2, k_pool, v_pool)
+        )(starts.astype(jnp.int32), tab, q2, k_pool, v_pool)
     return jnp.swapaxes(out, 1, 2)             # [B, C, H, d]

@@ -45,37 +45,93 @@ from autodist_tpu.kernel import quantize as qz
 from autodist_tpu.kernel.pallas import default_interpret, kernel_marker
 
 
+# VMEM tile geometry of the hop kernels.  A hop's payload is viewed as
+# ``[rows, LANES]`` with ``rows`` a multiple of the int8 sublane tile,
+# and the kernel walks it ``BLOCK_ROWS`` rows per grid step (an fp32
+# block of 512 KiB), so VMEM use does not grow with the payload.
+LANES = 128
+BLOCK_ROWS = 1024
+_INT8_SUBLANES = 32
+
+
+def tile_rows(length: int) -> int:
+    """Rows of the ``[rows, LANES]`` view that holds ``length``
+    elements: whole int8 tiles, and whole blocks once past one block."""
+    rows = -(-length // LANES)
+    unit = _INT8_SUBLANES if rows <= BLOCK_ROWS else BLOCK_ROWS
+    return -(-rows // unit) * unit
+
+
+def to_tiles(mat):
+    """``[n, C]`` -> ``[n, rows, LANES]``, each row of ``mat`` zero-
+    padded on its own (zeros change neither an abs-max nor a level)."""
+    n, length = mat.shape
+    rows = tile_rows(length)
+    mat = jnp.pad(mat, ((0, 0), (0, rows * LANES - length)))
+    return mat.reshape(n, rows, LANES)
+
+
+def hop_grid(rows: int):
+    """``(grid, block_rows, in_map, out_map)`` of a two-phase hop call.
+    Phase 0 walks the payload to reduce its abs-max into SMEM scratch;
+    phase 1 walks it again and writes.  The output maps pin block 0
+    through phase 0 (``p * i``), so no unwritten block is ever flushed:
+    block 0's buffer is first written back after phase 1 filled it."""
+    br = min(rows, BLOCK_ROWS)
+    return ((2, rows // br), br,
+            lambda p, i: (i, 0), lambda p, i: (p * i, 0))
+
+
+def reduce_abs_max(amax_ref, x):
+    """Phase 0 of a hop kernel: fold this block's ``max|x|`` into the
+    running max in SMEM scratch (reset at the walk's first block)."""
+    phase, i = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((phase == 0) & (i == 0))
+    def _reset():
+        amax_ref[0] = jnp.float32(0.0)
+
+    @pl.when(phase == 0)
+    def _reduce():
+        amax_ref[0] = jnp.maximum(amax_ref[0], jnp.max(jnp.abs(x)))
+
+
 def _dq_add_q_kernel(scale_in_ref, q_in_ref, local_ref, q_out_ref,
-                     scale_out_ref):
+                     scale_out_ref, amax_ref):
     """One fused ring-step pass: ``acc = dq(incoming) + local`` then
     requantize ``acc`` against its own abs-max scale — the arithmetic a
     composed lowering would spread over four HBM-shaped ops (convert,
-    add, reduce, convert), in one VMEM pass.  ``scale_in == 0`` (the
-    ring's first send) makes the incoming term vanish, so the same
+    add, reduce, convert), block by block in VMEM.  ``scale_in == 0``
+    (the ring's first send) makes the incoming term vanish, so the same
     kernel is the plain quantizer too."""
     acc = q_in_ref[...].astype(jnp.float32) * scale_in_ref[0, 0] \
         + local_ref[...].astype(jnp.float32)
-    scale = qz.abs_max_scale(acc)
-    q_out_ref[...] = qz.quantize_levels(acc, scale).astype(jnp.int8)
-    scale_out_ref[0, 0] = scale
+    reduce_abs_max(amax_ref, acc)
+
+    @pl.when(pl.program_id(0) == 1)
+    def _write():
+        scale = qz.scale_of_abs_max(amax_ref[0])
+        q_out_ref[...] = qz.quantize_levels(acc, scale).astype(jnp.int8)
+        scale_out_ref[0, 0] = scale
 
 
 def _fused_hop(q_in, scale_in, local, *, interpret: bool):
-    """Run the fused pass; ``q_in`` s8 ``[1, C]``, ``scale_in`` f32
-    scalar, ``local`` f32 ``[1, C]`` -> ``(q_out s8 [1, C], scale_out
-    f32 scalar)``."""
-    C = local.shape[-1]
+    """Run the fused pass; ``q_in`` s8 ``[rows, LANES]``, ``scale_in``
+    f32 scalar, ``local`` f32 ``[rows, LANES]`` -> ``(q_out s8 [rows,
+    LANES], scale_out f32 scalar)``."""
+    rows = local.shape[0]
+    grid, br, in_map, out_map = hop_grid(rows)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     q_out, scale_out = pl.pallas_call(
         _dq_add_q_kernel,
-        in_specs=[
-            pl.BlockSpec((1, 1), memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-            pl.BlockSpec(memory_space=pltpu.ANY),
-        ],
-        out_specs=(pl.BlockSpec(memory_space=pltpu.ANY),
-                   pl.BlockSpec((1, 1), memory_space=pltpu.SMEM)),
-        out_shape=(jax.ShapeDtypeStruct((1, C), jnp.int8),
+        grid=grid,
+        in_specs=[smem,
+                  pl.BlockSpec((br, LANES), in_map),
+                  pl.BlockSpec((br, LANES), in_map)],
+        out_specs=(pl.BlockSpec((br, LANES), out_map), smem),
+        out_shape=(jax.ShapeDtypeStruct((rows, LANES), jnp.int8),
                    jax.ShapeDtypeStruct((1, 1), jnp.float32)),
+        scratch_shapes=[pltpu.SMEM((1,), jnp.float32)],
         interpret=interpret,
     )(scale_in.reshape(1, 1), q_in, local)
     return q_out, scale_out[0, 0]
@@ -89,7 +145,9 @@ def quantized_ring_all_reduce(x, axis_name, *,
     ``precision="int8"`` — same contract, TRUE ``s8`` wire.
 
     Any payload shape is legal: the flattened payload zero-pads to
-    ``n`` equal chunks (zero columns quantize to exact zeros)."""
+    ``n`` equal chunks (zero columns quantize to exact zeros), and each
+    chunk zero-pads to whole VMEM tiles (:func:`to_tiles`) — the form
+    it keeps on the wire."""
     n = lax.axis_size(axis_name)
     if n == 1:
         return x
@@ -101,12 +159,12 @@ def quantized_ring_all_reduce(x, axis_name, *,
     if pad:
         flat = jnp.pad(flat, (0, pad))
     chunk = (size + pad) // n
-    chunks = flat.reshape(n, chunk)
+    chunks = to_tiles(flat.reshape(n, chunk))       # [n, rows, LANES]
+    tile = chunks.shape[1:]
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def local(c):
-        return lax.dynamic_slice_in_dim(chunks, c, 1, axis=0) \
-            .reshape(1, chunk)
+        return lax.dynamic_index_in_dim(chunks, c, axis=0, keepdims=False)
 
     with jax.named_scope(kernel_marker("quant_ring")):
         # --- reduce-scatter phase: n-1 hops of re-quantized partials --- #
@@ -114,7 +172,7 @@ def quantized_ring_all_reduce(x, axis_name, *,
         # ring); after hop h it holds the partial sum of chunk
         # (me - h) % n; after n-1 hops it owns the full sum of chunk
         # (me - (n-1)) % n == (me + 1) % n.
-        q, s = _fused_hop(jnp.zeros((1, chunk), jnp.int8),
+        q, s = _fused_hop(jnp.zeros(tile, jnp.int8),
                           jnp.float32(0.0), local(me % n),
                           interpret=interp)
         # Hops unrolled (n is static and small): every hop's s8
@@ -129,18 +187,18 @@ def quantized_ring_all_reduce(x, axis_name, *,
         own_idx = (me + 1) % n
 
         # --- all-gather phase: n-1 hops of the final owned chunks ------ #
-        out = jnp.zeros((n, chunk), jnp.float32)
-        out = lax.dynamic_update_slice(
-            out, (q_own.astype(jnp.float32) * s_own), (own_idx, 0))
+        out = jnp.zeros((n,) + tile, jnp.float32)
+        out = lax.dynamic_update_index_in_dim(
+            out, q_own.astype(jnp.float32) * s_own, own_idx, axis=0)
         for j in range(n - 1):
             q = lax.ppermute(q, axis_name, perm)
             s = lax.ppermute(s, axis_name, perm)
             # After j+1 hops the arriving chunk was owned by device
             # me - (j+1), i.e. chunk index (me - j) % n.
-            out = lax.dynamic_update_slice(
-                out, q.astype(jnp.float32) * s, ((me - j) % n, 0))
+            out = lax.dynamic_update_index_in_dim(
+                out, q.astype(jnp.float32) * s, (me - j) % n, axis=0)
 
-    full = out.reshape(-1)
+    full = out.reshape(n, -1)[:, :chunk].reshape(-1)
     if pad:
         full = lax.slice_in_dim(full, 0, size)
     return full.reshape(x.shape).astype(x.dtype)

@@ -69,10 +69,16 @@ def check_precision(value, *, where: str = "precision") -> str:
 _SCALE_FLOOR = 1e-20
 
 
+def scale_of_abs_max(amax):
+    """The int8 scale of a payload whose ``max|x|`` is ``amax`` (a
+    kernel that walks a payload in tiles reduces the max itself)."""
+    return jnp.maximum(amax / 127.0, _SCALE_FLOOR)
+
+
 def abs_max_scale(x):
     """Symmetric per-tensor int8 scale: ``max|x| / 127``, floored so an
     all-zero (or single-element zero) block quantizes to exact zeros."""
-    return jnp.maximum(jnp.max(jnp.abs(x)) / 127.0, _SCALE_FLOOR)
+    return scale_of_abs_max(jnp.max(jnp.abs(x)))
 
 
 def quantize_levels(x, scale):

@@ -28,13 +28,26 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from autodist_tpu.kernel.pallas import default_interpret
+
 NEG_INF = float(np.finfo(np.float32).min)
+
+# The kernels stage K and V (and, in the dK/dV kernel, Q, g, lse and
+# delta) as whole-sequence VMEM blocks, so the sequence length is bounded
+# by the 16 MiB scoped-VMEM default.  Measured on a v5e (jax 0.9.0,
+# libtpu 0.0.34, head_dim 64): forward and backward compile at 16384 rows
+# of bf16; at 32768 the backward is refused (48 MB scoped allocation).
+# A row costs in proportion to its bytes, so the bound is on rows x
+# itemsize x 128-lane groups of head_dim.
+MAX_SEQ_BYTES = 16384 * 2
 
 # --------------------------------------------------------------------------- #
 # Measured tuning table (written by tools/flash_crossover.py --write):
 # per-(causal, seq-length) best block sizes and the einsum-vs-flash
 # crossover, so on-silicon measurements are adopted by every caller that
-# leaves block sizes unset — instead of living only in BASELINE.md prose.
+# leaves block sizes unset — instead of living only in prose.  (No such
+# table has been written yet: the file is absent until the kernel is
+# measured on the chip.)
 # --------------------------------------------------------------------------- #
 DEFAULT_BLOCK = 128
 _TUNING_ENV = "AUTODIST_TPU_FLASH_TUNING"
@@ -469,7 +482,7 @@ def _layout_bhld(q, k, v, scale, block_q, block_k, interpret):
     materializing [L, L] scores.  Returns the kernel inputs plus the
     facts needed to undo the layout."""
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
     b, l, h, d = q.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
@@ -477,6 +490,14 @@ def _layout_bhld(q, k, v, scale, block_q, block_k, interpret):
     bk = _aligned_block(l, block_k)
     lcm = bq * bk // math.gcd(bq, bk)
     l_pad = ((l + lcm - 1) // lcm) * lcm
+    row_bytes = jnp.dtype(q.dtype).itemsize * -(-d // 128)
+    if l_pad * row_bytes > MAX_SEQ_BYTES:
+        raise ValueError(
+            f"flash attention holds whole-sequence K/V blocks in VMEM: "
+            f"seq_len {l} ({jnp.dtype(q.dtype).name}, head_dim {d}) is "
+            f"past the longest that compiles, "
+            f"{MAX_SEQ_BYTES // row_bytes}; shard the sequence "
+            "(parallel/ring_attention.py) or use the einsum attention")
 
     def to_bhld(x):
         x = jnp.moveaxis(x, 2, 1).reshape(b * h, x.shape[1], d)
@@ -497,8 +518,9 @@ def flash_attention(q, k, v, *, causal: bool = False,
 
     ``block_q``/``block_k`` default to the measured tuning table
     (:func:`tuned_blocks`; :data:`DEFAULT_BLOCK` when none committed).
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU (the
-    simulated CPU mesh used by the test harness).
+    ``interpret=None`` follows :func:`~autodist_tpu.kernel.pallas
+    .default_interpret` (the Pallas interpreter off-TPU).  Sequences
+    past :data:`MAX_SEQ_BYTES` raise ``ValueError``.
     """
     block_q, block_k = _resolve_blocks(int(q.shape[1]), bool(causal),
                                        block_q, block_k)

@@ -11,8 +11,11 @@ construction with a deterministic device order.
 Spec format (dict or YAML file)::
 
     topology:
-      platform: tpu          # tpu | cpu (simulated mesh for tests)
-      generation: v5e        # informational; selects hardware constants
+      platform: tpu          # auto (jax's default backend) | tpu | cpu
+                             # (simulated mesh for tests); a named
+                             # platform that is absent is an error
+      generation: v5e        # selects hardware constants; default: from
+                             # the device_kind jax reports
       num_devices: 8         # optional; default = all visible devices
     mesh:                    # optional; default {'data': num_devices}
       data: 4
@@ -91,6 +94,8 @@ class ChipSpec:
 
 
 # Public figures; used only for relative cost decisions and MFU math.
+# The "cpu" entry prices the simulated mesh the cost-model tests run on;
+# it describes no real device.
 CHIP_SPECS = {
     "v4": ChipSpec("v4", peak_bf16_tflops=275.0, hbm_gb=32, hbm_gbps=1228, ici_gbps=50, dcn_gbps=6.25),
     "v5e": ChipSpec("v5e", peak_bf16_tflops=197.0, hbm_gb=16, hbm_gbps=819, ici_gbps=50, dcn_gbps=6.25),
@@ -98,6 +103,38 @@ CHIP_SPECS = {
     "v6e": ChipSpec("v6e", peak_bf16_tflops=918.0, hbm_gb=32, hbm_gbps=1640, ici_gbps=100, dcn_gbps=12.5),
     "cpu": ChipSpec("cpu", peak_bf16_tflops=1.0, hbm_gb=8, hbm_gbps=50, ici_gbps=10, dcn_gbps=1.0),
 }
+
+
+# ``device_kind`` as jax reports it -> generation.  "TPU v5 lite" is what
+# a v5e reports (chip run, PR 21: jax 0.9.0 / libtpu 0.0.34); the other
+# spellings are the ones libtpu is documented to use and have not been
+# seen on hardware here.  A kind that is not listed is an error, not a
+# default: pricing an unknown chip as a v5e hides the device.
+DEVICE_KIND_GENERATION = {
+    "tpu v4": "v4",
+    "tpu v5 lite": "v5e", "tpu v5e": "v5e",
+    "tpu v5": "v5p", "tpu v5p": "v5p",
+    "tpu v6 lite": "v6e", "tpu v6e": "v6e",
+}
+
+
+def on_accelerator() -> bool:
+    """Whether jax's default backend is an accelerator — the one rule
+    the benchmark entry points size themselves by.  A CPU backend is
+    acceptable only when the caller pinned it (``JAX_PLATFORMS=cpu``:
+    the toy-size dry run); reached any other way it means the chip did
+    not come up, and that is an error rather than a smaller model."""
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "cpu":
+        return True
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return False
+    raise RuntimeError(
+        "no accelerator: jax.default_backend() is 'cpu' and JAX_PLATFORMS "
+        "does not ask for it; set JAX_PLATFORMS=cpu for the toy-size dry "
+        "run")
 
 
 def factor_3d(num_devices: int, *, pipe: int = 1, model: int = 1,
@@ -198,8 +235,28 @@ class ResourceSpec:
     def chip(self) -> ChipSpec:
         gen = self.generation
         if gen == "auto":
-            gen = _detect_generation()
-        return CHIP_SPECS.get(gen, CHIP_SPECS["cpu"])
+            gen = "cpu" if self.platform == "cpu" else _detect_generation(
+                self._platform_devices()[0])
+        if gen not in CHIP_SPECS:
+            raise ValueError(
+                f"unknown chip generation {gen!r}; known: "
+                f"{sorted(CHIP_SPECS)}")
+        return CHIP_SPECS[gen]
+
+    def _platform_devices(self) -> list:
+        """The devices of ``topology.platform``: jax's default backend
+        for ``auto``, otherwise that platform's or an error — a spec
+        that names a platform never runs on another."""
+        import jax
+        if self.platform == "auto":
+            return list(jax.devices())
+        try:
+            return list(jax.devices(self.platform))
+        except RuntimeError as e:
+            raise RuntimeError(
+                f"topology.platform is {self.platform!r} but jax has no "
+                f"such backend here (default backend: "
+                f"{jax.default_backend()!r}): {e}") from e
 
     def devices(self) -> Sequence[Any]:
         """Deterministically ordered global device list (counterpart of the
@@ -207,9 +264,8 @@ class ResourceSpec:
         ``cluster.py:78-81``).  Touching the live device list in a
         multihost job requires the distributed backend, so this
         bootstraps first (idempotent)."""
-        import jax
         self.bootstrap()
-        devs = list(jax.devices())
+        devs = self._platform_devices()
         devs.sort(key=lambda d: d.id)
         if self._requested_devices is not None:
             if self._requested_devices > len(devs):
@@ -319,14 +375,17 @@ class ResourceSpec:
         With a ``dcn`` axis on real multi-slice hardware the mesh comes
         from ``mesh_utils.create_hybrid_device_mesh`` so the dcn axis
         provably falls on slice boundaries (a naive reshape could put the
-        high-volume data-axis collectives on the slow DCN links);
-        simulated/CPU devices carry no slice topology and keep the
-        deterministic reshape."""
+        high-volume data-axis collectives on the slow DCN links).
+        Devices that are one slice — simulated/CPU devices, which carry
+        no slice topology, and a single host's chips, which all report
+        the same ``slice_index`` — keep the deterministic reshape: the
+        ``dcn`` axis is then a logical split whose collectives ride ICI
+        (the hybrid builder refuses a slice count the hardware lacks)."""
         import jax
         shape = self.resolved_mesh_shape()
         devs = self.devices()
-        if const.DCN_AXIS in shape and getattr(
-                devs[0], "slice_index", None) is not None:
+        slices = {getattr(d, "slice_index", None) for d in devs}
+        if const.DCN_AXIS in shape and len(slices) > 1:
             from jax.experimental import mesh_utils
             axes = list(shape.keys())
             per_slice = [1 if a == const.DCN_AXIS else shape[a]
@@ -382,23 +441,26 @@ class ResourceSpec:
         }
 
 
-def _detect_generation() -> str:
-    import jax
+def _detect_generation(device) -> str:
+    """Generation of ``device``: the ``AUTODIST_TPU_GENERATION``
+    override, else the simulated-mesh ``"cpu"`` entry for a CPU device,
+    else :data:`DEVICE_KIND_GENERATION` — raising on a ``device_kind``
+    it does not list."""
     env_gen = const.ENV.AUTODIST_TPU_GENERATION.val
-    if env_gen in CHIP_SPECS:
-        return env_gen
     if env_gen:
-        logging.warning(
-            "unrecognized chip generation override %r (valid: %s); "
-            "falling back to device_kind detection",
-            env_gen, sorted(CHIP_SPECS))
-    try:
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # pragma: no cover
+        if env_gen not in CHIP_SPECS:
+            raise ValueError(
+                f"AUTODIST_TPU_GENERATION={env_gen!r} is not a known chip "
+                f"generation; known: {sorted(CHIP_SPECS)}")
+        return env_gen
+    if device.platform == "cpu":
         return "cpu"
-    for gen in ("v6e", "v5p", "v5e", "v4"):
-        if gen in kind or gen.replace("e", " lite") in kind:
-            return gen
-    if "v5 lite" in kind or "v5lite" in kind:
-        return "v5e"
-    return "cpu" if "cpu" in kind else "v5e"
+    gen = DEVICE_KIND_GENERATION.get(device.device_kind.lower())
+    if gen is None:
+        raise ValueError(
+            f"unrecognised device_kind {device.device_kind!r} (platform "
+            f"{device.platform!r}); known kinds: "
+            f"{sorted(DEVICE_KIND_GENERATION)} — set topology.generation "
+            "or AUTODIST_TPU_GENERATION to one of "
+            f"{sorted(CHIP_SPECS)}")
+    return gen

@@ -26,7 +26,8 @@ reference's runtime — and is built here — is:
 
 Remote transport is plain ``ssh`` subprocesses (paramiko is not in this
 image); ``LocalCluster`` spawns workers on localhost for testing the
-process plane without hardware.
+process plane without hardware (its workers are CPU-pinned: on a chip
+host one process drives all chips).
 """
 from __future__ import annotations
 
@@ -747,7 +748,12 @@ class LocalCluster(Cluster):
     hardware: same launcher, env handoff, coordination service,
     watchers, and (opt-in) supervision as a real fleet, every process
     on this machine.  The chaos harness (``tools/chaos_run.py``) runs
-    its fault matrix against one of these."""
+    its fault matrix against one of these.
+
+    A CPU harness by construction: a chip belongs to one process at a
+    time, so N+1 processes on one machine cannot share it — the workers
+    are pinned to the CPU backend here, whatever the chief runs on.  On
+    a chip host, one process drives all of the host's chips."""
 
     def __init__(self, num_workers: int, resource_spec=None, **kwargs):
         if num_workers < 1:
@@ -757,6 +763,11 @@ class LocalCluster(Cluster):
             resource_spec = ResourceSpec({})
         super().__init__(resource_spec,
                          hosts=["localhost"] * num_workers, **kwargs)
+
+    def launch_clients(self, strategy, argv=None, extra_env=None):
+        return super().launch_clients(
+            strategy, argv=argv,
+            extra_env={**(extra_env or {}), "JAX_PLATFORMS": "cpu"})
 
 
 def make_global_batch(batch, mesh, spec=None):

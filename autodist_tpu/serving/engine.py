@@ -304,6 +304,13 @@ class ServingEngine:
             raise ValueError("top_k must be >= 0")
         self._axis = const.MODEL_AXIS if tp > 1 else None
 
+        # ``devices=`` places the engine: a tp>1 engine's mesh is its
+        # first tp entries; a tp=1 engine commits its params and cache
+        # to devices[0], and the compiled programs follow their
+        # operands there — so N one-chip engines in one process can sit
+        # on N chips.  With no ``devices`` a tp=1 engine stays
+        # uncommitted on jax's default device.
+        self._device = devices[0] if devices and tp == 1 else None
         if devices is None:
             devices = jax.devices()
         if tp > len(devices):
@@ -330,10 +337,22 @@ class ServingEngine:
                 lambda s: NamedSharding(self.mesh, s), self._param_specs,
                 is_leaf=lambda x: isinstance(x, P))
             params = jax.tree.map(jax.device_put, params, shardings)
+        elif self._device is not None:
+            params = jax.device_put(params, self._device)
         self.params = params
 
         # ---- cache + per-slot decode state -----------------------------
+        # The current-token vector is placed where the programs will
+        # return it: a placed engine whose first prefill saw an
+        # uncommitted ``_tok`` and whose second saw the program's own
+        # committed output compiled its prefill twice (seen on the chip:
+        # ~6 s of the second batch, PR 21).
         self._tok = jnp.zeros((self.num_slots,), jnp.int32)
+        if self.mesh is not None:
+            self._tok = jax.device_put(self._tok,
+                                       NamedSharding(self.mesh, P()))
+        elif self._device is not None:
+            self._tok = jax.device_put(self._tok, self._device)
         self._sample_seeds = np.zeros((self.num_slots,), np.int32)
         if self.kv_layout == "paged":
             cache = kv_cache.init_paged_cache(
@@ -385,6 +404,8 @@ class ServingEngine:
                     v=jax.device_put(cache.v, csh),
                     lengths=jax.device_put(
                         cache.lengths, NamedSharding(self.mesh, P())))
+        if self._device is not None:
+            cache = jax.device_put(cache, self._device)
         self.cache = cache
 
         self._prefill_jit = (self._build_chunk_prefill()
@@ -413,7 +434,8 @@ class ServingEngine:
                 decode_steps=self.speculative, kv_layout=self.kv_layout,
                 kv_block_len=self.kv_block_len,
                 temperature=self.temperature, top_k=self.top_k,
-                prefill_chunk=self.prefill_chunk)
+                prefill_chunk=self.prefill_chunk,
+                devices=self._device and [self._device])
             self._spec_verify_jit = self._build_spec_verify()
             self._spec_catch = np.zeros((self.num_slots,), bool)
             self._spec_catch_tok = np.zeros((self.num_slots,), np.int32)
@@ -1329,12 +1351,20 @@ class ServingEngine:
             active).compile().as_text()
 
     def compiled_prefill_text(self) -> str:
-        """Optimized HLO of the prefill program."""
+        """Optimized HLO of the prefill program (the ``[B, C]`` window
+        program on a chunked-prefill engine)."""
         c = self.cache
-        prompts = jnp.zeros((self.num_slots, self.prefill_len), jnp.int32)
+        if self.prefill_chunk is None:
+            window = (jnp.zeros((self.num_slots, self.prefill_len),
+                                jnp.int32),)
+        else:
+            window = (jnp.zeros((self.num_slots, self.prefill_chunk),
+                                jnp.int32), jnp.int32(0))
         p_lens = jnp.ones((self.num_slots,), jnp.int32)
         admit = jnp.ones((self.num_slots,), bool)
+        rest = ((jnp.asarray(self._write_from),)
+                if self.prefix_caching else ())
         return self._prefill_jit.lower(
             self.params, c.k, c.v, c.lengths, self._tok,
-            self._table_arg(), jnp.asarray(self._sample_seeds), prompts,
-            p_lens, admit).compile().as_text()
+            self._table_arg(), jnp.asarray(self._sample_seeds), *window,
+            p_lens, admit, *rest).compile().as_text()

@@ -173,10 +173,17 @@ class _SelfFaultPlane:
 def _engine_meta(engine, max_queue: Optional[int]) -> dict:
     """The scalar engine facts the chief-side proxy needs (published
     once at startup — doubling as the replica-ready handshake)."""
+    import jax
+
     blocks = list(engine.block_accounting()) \
         if hasattr(engine, "block_accounting") else [0, 0, 0]
+    device = jax.devices()[0]
     return {
         "pid": os.getpid(),
+        # what this worker's jax actually runs on — the chief may hold a
+        # chip while its replica processes serve from CPUs
+        "platform": device.platform,
+        "device_kind": device.device_kind,
         "num_slots": int(engine.num_slots),
         "prefill_len": int(engine.prefill_len),
         "max_len": int(engine.max_len),
@@ -249,7 +256,6 @@ def run_replica_worker() -> int:
     engine from the shipped spec, heartbeat, consume ops, publish state
     snapshots — until a ``stop`` op, an orphaning (the chief died), or
     a self-injected fault ends it."""
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     from autodist_tpu.runtime import cluster, coordination, faults
     from autodist_tpu.serving.batcher import ContinuousBatcher
 
@@ -346,6 +352,8 @@ class _RemoteEngineProxy:
     blocks through its evictions."""
 
     def __init__(self, meta: dict):
+        self.platform = meta.get("platform")
+        self.device_kind = meta.get("device_kind")
         self.num_slots = meta["num_slots"]
         self.prefill_len = meta["prefill_len"]
         self.max_len = meta["max_len"]
@@ -628,7 +636,10 @@ class ProcessFleet(ServingFleet):
             "AUTODIST_TPU_COORD_SERVICE": self._addr,
             "PYTHONPATH": (f"{pkg_root}:{py_path}" if py_path
                            else pkg_root),
-            "JAX_PLATFORMS": os.environ.get("JAX_PLATFORMS", "cpu"),
+            # A chip belongs to one process and the chief may hold it:
+            # workers run on the CPU unless the engine spec's "env"
+            # hands one of them a platform (see platforms()).
+            "JAX_PLATFORMS": "cpu",
             "XLA_FLAGS": "",   # replicas never inherit a simulated mesh
             _HB_ENV: str(min(self.config.heartbeat_interval_s, 0.2)),
         }
@@ -730,6 +741,13 @@ class ProcessFleet(ServingFleet):
             time.sleep(0.1)
 
     # ------------------------------------------------------------------ #
+    def platforms(self) -> dict:
+        """``{replica name: (platform, device_kind)}`` as each worker's
+        own jax reported it at start-up — what the fleet really serves
+        from, whatever backend the chief process holds."""
+        return {r.name: (r.engine.platform, r.engine.device_kind)
+                for r in self.replicas}
+
     def close(self):
         """Tear the fleet down: stop ops to live workers, SIGKILL the
         rest, coordination server down, env restored."""

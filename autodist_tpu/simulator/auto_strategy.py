@@ -781,8 +781,13 @@ class AutoStrategy(StrategyBuilder):
                 if best is None or dt < best[0]:
                     best, runner = (dt, name, strategy, runner), best and best[3]
             except Exception as e:  # a candidate that cannot run loses
-                logging.warning("auto-strategy measure %s failed: %s",
-                                name, e)
+                # ... and loses visibly: on a chip a lowering the
+                # compiler refuses would otherwise drop out of the
+                # election without a trace of why.
+                logging.warning(
+                    "auto-strategy measure %s failed and is dropped from "
+                    "the election: %s: %s", name, type(e).__name__,
+                    (str(e).splitlines() or [""])[0])
             finally:
                 # Free the loser before the next compile; close() tears
                 # down any host-side machinery (async-PS thread, in-

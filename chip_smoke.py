@@ -1,0 +1,679 @@
+"""The quickest proof that the system still starts on the chip.
+
+    python chip_smoke.py
+
+drives the main path once, through the entry points a user would call,
+in ONE process (a chip belongs to one process at a time), at the full
+width of the models the benchmark modes use — depth as published,
+weights random from a seed:
+
+1. **training** — ``bert.bert_base`` (12 layers, hidden 768, vocab
+   30522), seq 512, 16 examples per chip, 76 masked positions, adamw
+   with a bf16 first moment: ``make_mlm_trainable`` →
+   ``AutoDist(ResourceSpec({}), AllReduce(chunk_size=256)).build`` →
+   three ``runner.step`` calls and a k=4 ``runner.run_steps`` window,
+   over every visible chip (``ResourceSpec({})`` resolves to
+   ``{"data": n}``);
+2. **serving** — 8 layers, hidden 1024, 16 heads, vocab 32768, bf16:
+   ``ServingEngine`` → ``ContinuousBatcher`` → 8 requests of mixed
+   prompt length, dense and paged KV, composed attention;
+3. **parity** — the step-0 training loss and the first decoded token's
+   logits against a float32 evaluation of the same functions on the
+   host CPU;
+4. **kernels** — every Pallas kernel compiled through Mosaic
+   (``interpret=False``) against the composed path it replaces, both on
+   the chip, then once through the engine;
+5. **multi-chip** (when ``jax.device_count() > 1``) — the six lowering
+   programs of ``__graft_entry__``, the ring kernels over real ICI,
+   tensor-parallel serving, and two one-chip engines on two chips.
+
+It exits non-zero when jax's backend is not a TPU, when any phase
+raises, or when any check fails; nothing below catches a phase's
+failure.  On success the last line of stdout is one JSON object,
+``{"ok": true, "device": {"platform", "kind", "count"}}``.  Compile
+seconds (the first call) and run seconds (later calls) are printed
+apart, for the record only — they are not metrics.
+
+The phases are functions of their sizes so that tier-1
+(``tests/unit/test_chip_smoke.py``) drives the same code at toy width on
+the simulated CPU mesh; only ``__main__`` needs the chip.
+
+Tolerances (bf16 on the MXU against float32 on the host; chosen for the
+TPU's default matmul precision, not copied from the CPU goldens, which
+run at ``highest``):
+
+* step-0 loss: ``5e-3`` absolute on a loss of ~10.8 (2^-11 relative).
+  The logits are bf16 products with float32 accumulation and the loss
+  is a float32 mean over 16 x 76 log-softmax terms, so bf16's 2^-8 unit
+  roundoff averages down; a path that rounds the loss or the softmax
+  itself to bf16 lands at 2^-8 x 10.8 = 4e-2 and fails.
+* first-token logits: ``2^-5`` of the largest host logit, absolute —
+  one bf16 roundoff (2^-8) per layer of the 8-layer stack; the token
+  the engine emitted must be within twice that of the host's best.
+* attention kernels against the composed bf16 path: ``2^-5`` of the
+  reference's largest magnitude forward, ``2^-4`` backward (both sides
+  round to bf16 between their matmuls, in different places).
+* ring hop kernels: the scale bit-equal, levels within one (a value on
+  a rounding boundary may land on either side); ring collectives
+  against the exact float32 collective: ``2^-5`` of its largest
+  magnitude (at most four int8 roundings of 1/254 each).
+"""
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import sys
+import time
+
+LOSS_TOL = 5e-3
+LOGIT_RTOL = 2.0 ** -5
+ATTN_FWD_RTOL = 2.0 ** -5
+ATTN_BWD_RTOL = 2.0 ** -4
+RING_RTOL = 2.0 ** -5
+
+
+class SmokeFailure(RuntimeError):
+    """A check's observed value is outside what the smoke accepts."""
+
+
+def say(phase: str, msg: str) -> None:
+    print(f"[{phase}] {msg}", flush=True)
+
+
+def require(cond, phase: str, what: str, observed) -> None:
+    """Print one check with its observed value; raise if it failed."""
+    say(phase, f"check {what}: {observed} -> {'ok' if cond else 'FAILED'}")
+    if not cond:
+        raise SmokeFailure(f"{phase}: {what}: {observed}")
+
+
+def timed(fn):
+    """``(result, seconds)`` of ``fn()``; the caller's ``fn`` ends in a
+    host fetch, so the seconds include the device's work."""
+    t0 = time.perf_counter()
+    out = fn()
+    return out, time.perf_counter() - t0
+
+
+def devices_of(tree) -> set:
+    import jax
+
+    return {s.device for leaf in jax.tree.leaves(tree)
+            for s in leaf.addressable_shards}
+
+
+def max_err(got, ref):
+    """``(max |got - ref|, max |ref|)`` in float32 on the host."""
+    import numpy as np
+
+    got, ref = np.asarray(got, np.float32), np.asarray(ref, np.float32)
+    return float(np.max(np.abs(got - ref))), float(np.max(np.abs(ref)))
+
+
+def require_close(phase, what, got, ref, rtol) -> None:
+    err, scale = max_err(got, ref)
+    require(err <= rtol * scale, phase, what,
+            f"max|err|={err:.3g} vs tol={rtol * scale:.3g} "
+            f"(max|ref|={scale:.3g})")
+
+
+# --------------------------------------------------------------------------- #
+# 1. training
+# --------------------------------------------------------------------------- #
+def training_phase(cfg, *, resource_spec, batch_per_device: int,
+                   seq_len: int, num_masked: int, window: int = 4,
+                   platform: str = "tpu", seed: int = 0) -> dict:
+    """BERT MLM through ``AutoDist(...).build`` → ``step`` x3 →
+    ``run_steps`` (k=``window``) on a repeated batch, then the step-0
+    loss against float32 on the host."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    import optax
+
+    from autodist_tpu import AllReduce, AutoDist, stack_steps
+    from autodist_tpu.models import bert
+    from autodist_tpu.resource import ResourceSpec
+
+    ph = "train"
+    rs = ResourceSpec(resource_spec)
+    n = rs.num_devices()
+    say(ph, f"layers={cfg.num_layers} hidden={cfg.hidden_size} "
+            f"heads={cfg.num_heads} vocab={cfg.vocab_size} seq={seq_len} "
+            f"batch={batch_per_device}x{n} masked={num_masked} "
+            f"AllReduce(chunk_size=256) mesh={rs.resolved_mesh_shape()}")
+    trainable, init_s = timed(lambda: bert.make_mlm_trainable(
+        cfg, optax.adamw(1e-4, weight_decay=0.01, mu_dtype=jnp.bfloat16),
+        jax.random.PRNGKey(seed), batch_size=2, seq_len=seq_len,
+        num_masked=num_masked, with_input_mask=False))
+    params0 = jax.tree.map(np.asarray, trainable.params)   # host copy
+    runner, build_s = timed(
+        lambda: AutoDist(rs, AllReduce(chunk_size=256)).build(trainable))
+    say(ph, f"init_s={init_s:.1f} build_s={build_s:.1f}")
+    batch = bert.synthetic_mlm_batch(seed, batch_per_device * n, seq_len,
+                                     num_masked, cfg.vocab_size)
+    batch.pop("input_mask", None)      # unpadded: no mask pass on scores
+
+    def loss_of(metrics):
+        return np.asarray(metrics["loss"], np.float32)
+
+    step_losses, step_s = [], []
+    for _ in range(3):
+        loss, dt = timed(lambda: float(loss_of(runner.step(batch))))
+        step_losses.append(loss)
+        step_s.append(dt)
+    say(ph, f"step: compile_s={step_s[0]:.2f} (first call) "
+            f"run_s={[round(t, 3) for t in step_s[1:]]}")
+    stacked = runner.place_steps(stack_steps([batch] * window))
+    w1, w1_s = timed(lambda: loss_of(runner.run_steps(stacked)))
+    w2, w2_s = timed(lambda: loss_of(runner.run_steps(stacked)))
+    say(ph, f"run_steps(k={window}): compile_s={w1_s:.2f} (first call) "
+            f"run_s={w2_s:.3f}")
+    losses = np.concatenate([step_losses, w1, w2])
+    require(bool(np.isfinite(losses).all()), ph, "losses finite",
+            [round(float(x), 4) for x in losses])
+    require(w1.shape == (window,), ph, f"run_steps returns k={window} losses",
+            w1.shape)
+    require(float(w1[-1]) < step_losses[0], ph,
+            "loss after the window below step 0 (repeated batch)",
+            f"{float(w1[-1]):.4f} < {step_losses[0]:.4f}")
+    pdevs = devices_of(runner.state["params"])
+    require({d.platform for d in pdevs} == {platform}, ph,
+            f"params live on {platform} devices", sorted(map(str, pdevs)))
+    if n > 1:
+        require(len(devices_of(stacked)) == n, ph,
+                f"batch spread over {n} distinct devices",
+                len(devices_of(stacked)))
+        odevs = devices_of(runner.state["opt_state"])
+        require(len(odevs) == n and len(pdevs) == n, ph,
+                f"optimizer state and params on {n} distinct devices",
+                (len(odevs), len(pdevs)))
+    runner.close()
+
+    # ---- not silently wrong: the same loss in float32 on the host ------
+    cpu = jax.devices("cpu")[0]
+    model32 = bert.BertModel(dataclasses.replace(cfg, dtype=jnp.float32))
+
+    def host_loss():
+        with jax.default_device(cpu):
+            f = jax.jit(lambda p, b: bert.mlm_loss_head(
+                model32.apply({"params": p}, b, deterministic=True), b)[0])
+            return float(f(jax.device_put(params0, cpu),
+                           jax.device_put(batch, cpu)))
+
+    ref, host_s = timed(host_loss)
+    require(abs(step_losses[0] - ref) <= LOSS_TOL, ph,
+            "step-0 loss vs host float32",
+            f"|{step_losses[0]:.5f} - {ref:.5f}| = "
+            f"{abs(step_losses[0] - ref):.2g} <= {LOSS_TOL} "
+            f"(host_s={host_s:.1f})")
+    return {"devices": n, "losses": [float(x) for x in losses]}
+
+
+# --------------------------------------------------------------------------- #
+# 2. serving
+# --------------------------------------------------------------------------- #
+def make_prompts(vocab_size: int, prefill_len: int, count: int = 8,
+                 seed: int = 1) -> list:
+    """``count`` prompts of mixed length in ``[1, prefill_len]``; the
+    first is the full bucket (the parity check reads it)."""
+    import numpy as np
+
+    r = np.random.RandomState(seed)
+    lens = [prefill_len] + [int(r.randint(1, prefill_len + 1))
+                            for _ in range(count - 1)]
+    return [r.randint(0, vocab_size, (n,)).tolist() for n in lens]
+
+
+def serving_phase(cfg, params, prompts, *, label: str, num_slots: int,
+                  prefill_len: int, decode_steps: int, max_new_tokens: int,
+                  marker_of=None, **engine_kwargs) -> dict:
+    """``ServingEngine`` → ``ContinuousBatcher`` → submit every prompt →
+    ``run()``, twice (the first call compiles).  Every request must
+    complete with ``max_new_tokens`` tokens, and the second run must
+    repeat the first token for token.  ``marker_of`` names a kernel
+    whose marker must be in the compiled decode or prefill program."""
+    from autodist_tpu import serving
+
+    ph = f"serve:{label}"
+    engine, build_s = timed(lambda: serving.ServingEngine(
+        cfg, params, num_slots=num_slots, max_len=cfg.max_len,
+        prefill_len=prefill_len, decode_steps=decode_steps,
+        **engine_kwargs))
+    batcher = serving.ContinuousBatcher(engine)
+
+    def serve():
+        rids = [batcher.submit(p, max_new_tokens=max_new_tokens)
+                for p in prompts]
+        done = batcher.run()
+        return [done[r] for r in rids]
+
+    first, first_s = timed(serve)
+    again, again_s = timed(serve)
+    say(ph, f"layers={cfg.num_layers} hidden={cfg.hidden_size} "
+            f"vocab={cfg.vocab_size} max_len={cfg.max_len} "
+            f"slots={num_slots} K={decode_steps} requests={len(prompts)} "
+            f"prompt_lens={[len(p) for p in prompts]} {engine_kwargs}")
+    say(ph, f"build_s={build_s:.2f} compile+run_s={first_s:.2f} "
+            f"(first call) run_s={again_s:.3f}")
+    require(all(len(c.tokens) == max_new_tokens
+                and c.finish_reason == "max_tokens" for c in first), ph,
+            f"all {len(first)} requests complete with {max_new_tokens} "
+            "tokens",
+            sorted({(len(c.tokens), c.finish_reason) for c in first}))
+    tokens = [c.tokens for c in first]
+    require([c.tokens for c in again] == tokens, ph,
+            "a second run repeats the first token for token",
+            f"{sum(a.tokens == t for a, t in zip(again, tokens))}"
+            f"/{len(tokens)} identical")
+    if marker_of is not None:
+        from autodist_tpu.kernel.pallas import kernel_marker
+
+        text = (engine.compiled_prefill_text()
+                if marker_of == "flash_prefill"
+                else engine.compiled_decode_text())
+        require(kernel_marker(marker_of) in text, ph,
+                f"{marker_of} is in the compiled program",
+                f"marker {kernel_marker(marker_of)!r} found")
+    return {"engine": engine, "tokens": tokens}
+
+
+def require_mostly_identical(phase, tokens, ref_tokens) -> None:
+    """A kernel-elected engine against the composed engine: greedy
+    decode in bf16 may part ways at a near-tie, after which a stream
+    differs to its end — but a kernel wired to the wrong cache rows
+    agrees on none.  At least half the requests must match in full."""
+    same = sum(a == b for a, b in zip(tokens, ref_tokens))
+    require(2 * same >= len(tokens), phase,
+            "token streams identical to the composed engine's",
+            f"{same}/{len(tokens)}")
+
+
+def serving_parity(cfg, params, prompt, first_token: int) -> None:
+    """The first decoded token's logits: ``sequential_logits`` (the
+    layer function the engine's prefill runs) on the default backend at
+    ``cfg.dtype`` against float32 on the host CPU."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.models.pipeline_lm import sequential_logits
+
+    ph = "serve:parity"
+    tokens = np.asarray([prompt], np.int32)
+    got = np.asarray(jax.jit(
+        lambda p, t: sequential_logits(cfg, p, t))(params, tokens))[0, -1]
+    cpu = jax.devices("cpu")[0]
+    cfg32 = dataclasses.replace(cfg, dtype=jnp.float32)
+    with jax.default_device(cpu):
+        host = jax.device_put(jax.tree.map(np.asarray, params), cpu)
+        ref = np.asarray(jax.jit(
+            lambda p, t: sequential_logits(cfg32, p, t))(
+                host, jax.device_put(tokens, cpu)))[0, -1]
+    require_close(ph, f"first-token logits ({jnp.dtype(cfg.dtype).name} "
+                      "vs host float32)", got, ref, LOGIT_RTOL)
+    tol = LOGIT_RTOL * float(np.max(np.abs(ref)))
+    gap = float(ref.max() - ref[first_token])
+    require(gap <= 2 * tol, ph,
+            "the engine's first token is the host's best within tolerance",
+            f"token {first_token} (host argmax {int(ref.argmax())}), "
+            f"host logit gap {gap:.3g} <= {2 * tol:.3g}")
+
+
+# --------------------------------------------------------------------------- #
+# 4. kernels
+# --------------------------------------------------------------------------- #
+def kernels_phase(*, interpret: bool, seq_len: int = 512, heads: int = 12,
+                  head_dim: int = 64, cache_len: int = 1024,
+                  block_len: int = 16, chunk: int = 64, slots: int = 8,
+                  hop_elems: int = 1 << 20,
+                  matmul_shape=(8192, 2048, 512),
+                  flash_max_len: int = 16384, seed: int = 0) -> list:
+    """Each Pallas kernel against the composed path it replaces, both
+    on the default backend.  The chip passes ``interpret=False``: a
+    kernel can never pass here by being interpreted."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.kernel import quantize as qz
+    from autodist_tpu.kernel.pallas import (a2a_ring, collective_matmul,
+                                            flash_decode, flash_prefill,
+                                            quant_ring)
+    from autodist_tpu.models.transformer import dot_product_attention
+    from autodist_tpu.ops.flash_attention import flash_attention
+    from autodist_tpu.serving import kv_cache
+
+    ph = "kernel"
+    r = np.random.RandomState(seed)
+    bf16 = jnp.bfloat16
+    done = []
+
+    def rand(*shape, scale=1.0, dtype=bf16):
+        return jnp.asarray(r.randn(*shape) * scale, dtype)
+
+    # ---- ops/flash_attention.py: forward and backward (custom VJP) -----
+    q, k, v = (rand(4, seq_len, heads, head_dim, scale=0.5)
+               for _ in range(3))
+    for causal in (False, True):
+        mask = (jnp.tril(jnp.ones((seq_len, seq_len), bool))[None, None]
+                if causal else None)
+
+        def composed(q, k, v, mask=mask):
+            return dot_product_attention(q, k, v, mask, dtype=bf16)
+
+        def fused(q, k, v, causal=causal):
+            return flash_attention(q, k, v, causal=causal,
+                                   interpret=interpret)
+
+        def grads(f):
+            return jax.jit(jax.grad(
+                lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) ** 2),
+                argnums=(0, 1, 2)))
+
+        name = f"flash_attention(causal={causal}, seq={seq_len})"
+        (got, ref), fwd_s = timed(lambda: jax.block_until_ready(
+            (jax.jit(fused)(q, k, v), jax.jit(composed)(q, k, v))))
+        require_close(ph, f"{name} forward", got, ref, ATTN_FWD_RTOL)
+        (gg, gr), bwd_s = timed(lambda: jax.block_until_ready(
+            (grads(fused)(q, k, v), grads(composed)(q, k, v))))
+        for what, a, b in zip(("dq", "dk", "dv"), gg, gr):
+            require_close(ph, f"{name} backward {what}", a, b,
+                          ATTN_BWD_RTOL)
+        say(ph, f"{name}: fwd compile+run_s={fwd_s:.2f} "
+                f"bwd compile+run_s={bwd_s:.2f}")
+        done.append(name)
+    if not interpret:
+        # The longest sequence the kernels hold in VMEM (see
+        # ops/flash_attention.py MAX_SEQ_BYTES): it must still compile.
+        shape = jax.ShapeDtypeStruct((1, flash_max_len, 2, head_dim), bf16)
+        _, s = timed(lambda: jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(
+                q, k, v, causal=True, interpret=False).astype(jnp.float32)),
+            argnums=(0, 1, 2))).lower(shape, shape, shape).compile())
+        say(ph, f"flash_attention fwd+bwd compiles at the documented "
+                f"longest seq_len {flash_max_len}: compile_s={s:.2f}")
+
+    # ---- decode / prefill attention over the KV cache -------------------
+    H, d, T, bl = heads, head_dim, cache_len, block_len
+    lens = jnp.asarray(r.randint(0, T - 1, (slots,)), jnp.int32) \
+        .at[0].set(0).at[1].set(T - 1)
+    q1 = rand(slots, 1, H, d)
+    kd, vd = rand(slots, H, T, d), rand(slots, H, T, d)
+    got, ref = jax.block_until_ready((
+        jax.jit(lambda *a: flash_decode.flash_decode_attention(
+            *a, dtype=bf16, interpret=interpret))(q1, kd, vd, lens),
+        jax.jit(lambda *a: kv_cache.cached_attention(
+            *a, dtype=bf16))(q1, kd, vd, lens)))
+    require_close(ph, f"flash_decode_attention(T={T})", got, ref,
+                  ATTN_FWD_RTOL)
+    done.append("flash_decode_attention")
+
+    mb = T // bl
+    pool_k, pool_v = (rand(slots * mb, H, bl, d) for _ in range(2))
+    table = jnp.asarray(r.permutation(slots * mb).reshape(slots, mb),
+                        jnp.int32)
+    got, ref = jax.block_until_ready((
+        jax.jit(lambda *a: flash_decode.flash_decode_attention_paged(
+            *a, block_len=bl, dtype=bf16, interpret=interpret))(
+                q1, pool_k, pool_v, lens, table),
+        jax.jit(lambda *a: kv_cache.paged_cached_attention(
+            *a, block_len=bl, dtype=bf16))(q1, pool_k, pool_v, lens, table)))
+    require_close(ph, f"flash_decode_attention_paged(block_len={bl})",
+                  got, ref, ATTN_FWD_RTOL)
+    done.append("flash_decode_attention_paged")
+
+    qc = rand(slots, chunk, H, d)
+    starts = jnp.asarray(r.randint(0, (T - chunk) // bl + 1, (slots,)) * bl,
+                         jnp.int32)
+    got, ref = jax.block_until_ready((
+        jax.jit(lambda *a: flash_prefill.flash_prefill_attention_paged(
+            *a, block_len=bl, dtype=bf16, interpret=interpret))(
+                qc, pool_k, pool_v, starts, table),
+        jax.jit(lambda *a: kv_cache.paged_chunk_attention(
+            *a, block_len=bl, dtype=bf16))(
+                qc, pool_k, pool_v, starts, table)))
+    require_close(ph, f"flash_prefill_attention_paged(chunk={chunk})",
+                  got, ref, ATTN_FWD_RTOL)
+    done.append("flash_prefill_attention_paged")
+
+    # ---- the ring kernels' per-hop bodies (one chip suffices) -----------
+    local = quant_ring.to_tiles(rand(1, hop_elems, dtype=jnp.float32))[0]
+    q_in = quant_ring.to_tiles(jnp.asarray(
+        r.randint(-127, 128, (1, hop_elems)), jnp.int8))[0]
+    s_in = jnp.float32(0.01)
+
+    def require_levels(name, q_got, s_got, acc):
+        scale = qz.abs_max_scale(acc)
+        want = qz.quantize_levels(acc, scale).astype(jnp.int32)
+        off = jnp.abs(q_got.astype(jnp.int32) - want)
+        require(float(s_got) == float(scale) and int(off.max()) <= 1, ph,
+                f"{name} scale bit-equal, levels within one",
+                f"scale {float(s_got):.6g} vs {float(scale):.6g}, "
+                f"max level diff {int(off.max())}, "
+                f"{int((off > 0).sum())}/{off.size} differ")
+
+    q_got, s_got = jax.jit(lambda a, b, c: quant_ring._fused_hop(
+        a, b, c, interpret=interpret))(q_in, s_in, local)
+    require_levels(f"quant_ring._fused_hop({hop_elems} elements)", q_got,
+                   s_got, q_in.astype(jnp.float32) * s_in + local)
+    done.append("quant_ring._fused_hop")
+
+    arrived, q_got, s_got = jax.jit(lambda a, b, c: a2a_ring._fused_hop(
+        a, b, c, interpret=interpret))(q_in, s_in, local)
+    require_levels(f"a2a_ring._fused_hop({hop_elems} elements)", q_got,
+                   s_got, local)
+    require_close(ph, "a2a_ring._fused_hop dequantized arrival", arrived,
+                  q_in.astype(jnp.float32) * s_in, 0.0)
+    done.append("a2a_ring._fused_hop")
+
+    M, K, C = matmul_shape
+    carry, x2d, kc = rand(M, C), rand(M, K, scale=0.1), rand(K, C, scale=0.1)
+    got = jax.jit(lambda a, b, c: collective_matmul._fused_matmul_add(
+        a, b, c, interpret=interpret))(carry, x2d, kc)
+    ref = jax.jit(lambda a, b, c: (a.astype(jnp.float32) + jnp.dot(
+        b, c, preferred_element_type=jnp.float32)).astype(bf16))(
+            carry, x2d, kc)
+    require_close(ph, f"collective_matmul._fused_matmul_add{matmul_shape}",
+                  got, ref, ATTN_FWD_RTOL)
+    done.append("collective_matmul._fused_matmul_add")
+    return done
+
+
+def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,
+                       matmul_shape=(1024, 1024, 1024), seed: int = 0) -> list:
+    """The three ring kernels whole, inside ``shard_map`` over
+    ``devices`` — their ``ppermute`` hops cross real links — against the
+    exact float32 collective each replaces."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax import lax
+    from jax.sharding import Mesh, PartitionSpec as P
+
+    from autodist_tpu.kernel.pallas.a2a_ring import quantized_ring_all_to_all
+    from autodist_tpu.kernel.pallas.collective_matmul import \
+        collective_matmul_row_fused
+    from autodist_tpu.kernel.pallas.quant_ring import \
+        quantized_ring_all_reduce
+
+    ph = "kernel:ring"
+    n = len(devices)
+    mesh = Mesh(np.array(devices), ("model",))
+    r = np.random.RandomState(seed)
+
+    def on_mesh(fn, *specs):
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=specs,
+                                     out_specs=P("model"), check_vma=False))
+
+    x = jnp.asarray(r.randn(n, elems), jnp.float32)
+    got = on_mesh(lambda a: quantized_ring_all_reduce(
+        a, "model", interpret=interpret), P("model"))(x)
+    ref = np.broadcast_to(np.asarray(x).sum(0), (n, elems))
+    require_close(ph, f"quantized_ring_all_reduce over {n} devices", got,
+                  ref, RING_RTOL)
+
+    M, K, C = matmul_shape
+    xs = jnp.asarray(r.randn(M, K) * 0.1, jnp.float32)
+    ks = jnp.asarray(r.randn(K, C) * 0.1, jnp.float32)
+    got = on_mesh(lambda a, b: collective_matmul_row_fused(
+        a, b, "model", 1, interpret), P(None, "model"), P("model"))(xs, ks)
+    ref = np.tile(np.asarray(xs) @ np.asarray(ks), (n, 1))
+    require_close(ph, f"collective_matmul_row_fused over {n} devices", got,
+                  ref, ATTN_FWD_RTOL)
+
+    rows = 8 * n
+    y = jnp.asarray(r.randn(n * rows, elems // rows), jnp.float32)
+    got = on_mesh(lambda a: quantized_ring_all_to_all(
+        a, "model", split_axis=0, concat_axis=0, interpret=interpret),
+        P("model"))(y)
+    ref = on_mesh(lambda a: lax.all_to_all(
+        a, "model", split_axis=0, concat_axis=0, tiled=True),
+        P("model"))(y)
+    require_close(ph, f"quantized_ring_all_to_all over {n} devices", got,
+                  ref, RING_RTOL)
+    return ["quantized_ring_all_reduce", "collective_matmul_row_fused",
+            "quantized_ring_all_to_all"]
+
+
+# --------------------------------------------------------------------------- #
+# 5. multi-chip
+# --------------------------------------------------------------------------- #
+def multichip_phase(cfg, params, prompts, ref_tokens, *, serve_sizes: dict,
+                    interpret: bool) -> None:
+    """What needs more than one device: the six lowering programs, the
+    ring kernels over real links, tp=2 serving with the cache on two
+    devices, and two tp=1 engines on two different devices.  (The
+    data-parallel BERT run is :func:`training_phase` itself —
+    ``ResourceSpec({})`` takes every visible device.)"""
+    import jax
+
+    import __graft_entry__
+
+    ph = "multichip"
+    devices = sorted(jax.devices(), key=lambda d: d.id)
+    n = len(devices)
+    say(ph, f"using {n} devices: {[str(d) for d in devices]}")
+    ran, s = timed(lambda: __graft_entry__.run_lowering_programs(n))
+    want = 6 if n % 4 == 0 else 4 if n % 2 == 0 else 2
+    require(len(ran) == want, ph,
+            f"the {want} lowering programs a {n}-device host admits "
+            "completed", f"{ran} in {s:.1f}s")
+
+    ring_kernels_phase(devices, interpret=interpret)
+
+    tp2 = serving_phase(cfg, params, prompts, label="tp2+vocab_parallel",
+                        tensor_parallel=2, vocab_parallel=True,
+                        devices=devices[:2], **serve_sizes)
+    cache = tp2["engine"].cache.k
+    require(devices_of(cache) == set(devices[:2])
+            and cache.sharding.spec[2] == "model", ph,
+            "tp=2 KV cache's model axis is on two devices",
+            f"{sorted(map(str, devices_of(cache)))} spec="
+            f"{cache.sharding.spec}")
+    require_mostly_identical(ph + ":tp2", tp2["tokens"], ref_tokens)
+
+    pair = []
+    for dev in devices[:2]:
+        out = serving_phase(cfg, params, prompts, label=f"tp1@{dev}",
+                            devices=[dev], **serve_sizes)
+        eng = out["engine"]
+        where = devices_of((eng.params, eng.cache.k, eng.cache.v, eng._tok))
+        require(where == {dev}, ph,
+                f"a tp=1 engine given devices=[{dev}] lives there",
+                sorted(map(str, where)))
+        pair.append(out["tokens"])
+    require(pair[0] == pair[1], ph,
+            "two engines on two devices decode the same tokens",
+            f"{sum(a == b for a, b in zip(*pair))}/{len(pair[0])} identical")
+
+
+# --------------------------------------------------------------------------- #
+def main() -> int:
+    # The strategy dump at INFO is ~200 lines for BERT-base.
+    os.environ.setdefault("AUTODIST_TPU_MIN_LOG_LEVEL", "WARNING")
+    import jax
+
+    backend = jax.default_backend()
+    if backend != "tpu":
+        print(f"chip_smoke.py needs a TPU: jax.default_backend() is "
+              f"{backend!r} (devices: {jax.devices()}); nothing was run",
+              file=sys.stderr)
+        return 1
+
+    import jax.numpy as jnp
+    import jaxlib
+    import optax
+
+    from autodist_tpu.models import bert
+    from autodist_tpu.models.pipeline_lm import make_pipeline_lm_trainable
+    from autodist_tpu.models.transformer import TransformerConfig
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    t_start = time.perf_counter()
+    cache_dir = enable_compile_cache()
+    try:
+        import libtpu
+        libtpu_version = getattr(libtpu, "__version__", "unknown")
+    except ImportError:
+        libtpu_version = "not importable"
+    dev0 = jax.devices()[0]
+    n = jax.device_count()
+    say("chip_smoke", f"platform={dev0.platform} device_kind="
+                      f"{dev0.device_kind!r} devices={n} jax={jax.__version__} "
+                      f"jaxlib={jaxlib.__version__} libtpu={libtpu_version}")
+    def cache_entries():
+        return len(os.listdir(cache_dir)) if os.path.isdir(cache_dir) else 0
+
+    say("chip_smoke", f"compile cache at {cache_dir}: {cache_entries()} "
+                      "entries at start (a warm cache shows as collapsed "
+                      "compile_s below)")
+
+    training_phase(
+        bert.bert_base(dropout_rate=0.0, attention_dropout_rate=0.0),
+        resource_spec={}, batch_per_device=16, seq_len=512, num_masked=76)
+
+    cfg = TransformerConfig(vocab_size=32768, hidden_size=1024, num_layers=8,
+                            num_heads=16, mlp_dim=4096, max_len=1024,
+                            dtype=jnp.bfloat16, dropout_rate=0.0,
+                            attention_dropout_rate=0.0)
+    sizes = dict(num_slots=8, prefill_len=64, decode_steps=16,
+                 max_new_tokens=32)
+    params, s = timed(lambda: make_pipeline_lm_trainable(
+        cfg, optax.adam(1e-3), jax.random.PRNGKey(0)).params)
+    say("serve", f"params init_s={s:.1f}")
+    prompts = make_prompts(cfg.vocab_size, sizes["prefill_len"])
+    paged = dict(kv_layout="paged", kv_block_len=16)
+    dense = serving_phase(cfg, params, prompts, label="dense", **sizes)
+    serving_phase(cfg, params, prompts, label="paged", **paged, **sizes)
+    serving_parity(cfg, params, prompts[0], dense["tokens"][0][0])
+
+    kernels = kernels_phase(interpret=False)
+    for label, marker, kw in (
+            ("dense+flash_decode", "flash_decode", {}),
+            ("paged+flash_decode", "flash_decode", paged),
+            ("paged+chunked+flash_prefill", "flash_prefill",
+             dict(paged, prefill_chunk=32))):
+        out = serving_phase(cfg, params, prompts, label=label,
+                            kernel={marker: True}, marker_of=marker,
+                            **kw, **sizes)
+        require_mostly_identical(f"serve:{label}", out["tokens"],
+                                 dense["tokens"])
+    say("kernel", f"compiled (interpret=False) and agreed: {kernels}")
+
+    if n > 1:
+        multichip_phase(cfg, params, prompts, dense["tokens"],
+                        serve_sizes=sizes, interpret=False)
+
+    say("chip_smoke", f"all phases passed in "
+                      f"{time.perf_counter() - t_start:.0f}s; compile cache "
+                      f"now holds {cache_entries()} entries")
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev0.platform, "kind": dev0.device_kind, "count": n}}),
+        flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -9,6 +9,9 @@ from common import BenchmarkLogger, base_parser, run_benchmark
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = base_parser("BERT MLM pretraining benchmark")
     ap.add_argument("--bert-config", default="base",
                     choices=["tiny", "base", "large"])

@@ -33,6 +33,9 @@ def build_model(name: str):
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = base_parser("ImageNet CNN benchmark")
     ap.add_argument("--model", default="resnet50",
                     choices=["resnet18", "resnet50", "resnet101", "vgg16",
@@ -46,12 +49,12 @@ def main():
 
     from autodist_tpu import AutoDist
     from autodist_tpu.models.resnet import make_image_trainable
-    from autodist_tpu.resource import ResourceSpec
+    from autodist_tpu.resource import ResourceSpec, on_accelerator
     from autodist_tpu.strategy import builders
 
     rs = ResourceSpec({})
     n = rs.num_devices()
-    on_accel = jax.default_backend() != "cpu"
+    on_accel = on_accelerator()
     if args.preset == "tiny":
         image_size, candidates = 32, [8 * n]
     else:

@@ -12,6 +12,9 @@ from common import BenchmarkLogger, base_parser, run_benchmark
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = base_parser("NCF recommendation benchmark")
     ap.add_argument("--num-users", type=int, default=None)
     ap.add_argument("--num-items", type=int, default=None)

@@ -21,6 +21,9 @@ from autodist_tpu.models.cnn import make_cnn_trainable
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch-size", type=int, default=64)

@@ -22,6 +22,9 @@ from autodist_tpu.models.lm1b import make_lm1b_trainable
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--batch-size", type=int, default=32)

@@ -20,6 +20,9 @@ import numpy as np
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--batch-size", type=int, default=8)

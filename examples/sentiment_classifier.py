@@ -53,6 +53,9 @@ def make_trainable(vocab_size=20_000, embed_dim=64, hidden=64, seq_len=64):
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=30)
     ap.add_argument("--batch-size", type=int, default=32)

@@ -57,6 +57,7 @@ def _serve_fleet(args, cfg, trainable):
                         "tiny_engine_factory"},
             config=serving.FleetConfig(replicas=args.replicas),
             telemetry_dir=args.telemetry_dir)
+        print(f"replica worker platforms: {fleet.platforms()}")
     else:
         prompt_cap = max(args.prefill_len - args.max_new, 1)
 
@@ -138,6 +139,9 @@ def _serve_fleet(args, cfg, trainable):
 
 
 def main():
+    from autodist_tpu.utils.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=16)

@@ -96,6 +96,25 @@ def test_program_facts_from_synthetic_hlo():
     assert f.gathers_larger_than(4) == 1
 
 
+def test_block_table_gather_found_in_either_operand_spelling():
+    """ADT115's evidence is structural: the gather's first operand
+    carries the pool extent, whether the HLO printer spells the
+    operand's shape inline or (jax 0.9.0) by name only."""
+    from autodist_tpu.analysis.facts import gathers_with_operand_dim
+
+    inline = ("  %g = f32[3,4]{1,0} gather(f32[13,2,16,8]{3,2,1,0} %pool, "
+              "s32[3,4]{1,0} %tab), offset_dims={1}\n"
+              "  %ag = f32[26]{0} all-gather(f32[13]{0} %p)\n")
+    by_name = ("  %pool = f32[13,2,16,8]{3,2,1,0} parameter(0)\n"
+               "  ROOT %g = f32[3,4]{1,0} gather(%pool, %tab), "
+               "offset_dims={1}\n"
+               "  %g2 = f32[3]{0} gather(%undefined, %tab)\n")
+    assert gathers_with_operand_dim(inline, 13) == 1
+    assert gathers_with_operand_dim(by_name, 13) == 1
+    assert gathers_with_operand_dim(by_name, 16) == 1
+    assert gathers_with_operand_dim(by_name, 99) == 0
+
+
 def test_host_transfer_variants_detected():
     from autodist_tpu.analysis.facts import host_transfers
     assert host_transfers("  %r = (f32[2]) recv(token[] %t)") == 1

@@ -1,0 +1,91 @@
+"""``chip_smoke.py``'s phases at toy width on the simulated CPU mesh —
+the same functions the chip runs at full width — and its refusal to run
+without a TPU.  The kernel and multi-device phases interpret Pallas
+kernels, so they are ``slow``."""
+import json
+import os
+import subprocess
+import sys
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+
+import chip_smoke
+from autodist_tpu.models.pipeline_lm import make_pipeline_lm_trainable
+from autodist_tpu.models.transformer import TransformerConfig
+
+REPO = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+SIZES = dict(num_slots=2, prefill_len=16, decode_steps=4, max_new_tokens=8)
+PAGED = dict(kv_layout="paged", kv_block_len=8)
+
+
+@pytest.fixture(scope="module")
+def lm():
+    cfg = TransformerConfig(vocab_size=128, hidden_size=32, num_layers=2,
+                            num_heads=2, mlp_dim=64, max_len=64,
+                            dtype=jnp.float32, dropout_rate=0.0,
+                            attention_dropout_rate=0.0)
+    params = make_pipeline_lm_trainable(
+        cfg, optax.adam(1e-3), jax.random.PRNGKey(0)).params
+    return cfg, params, chip_smoke.make_prompts(cfg.vocab_size, 16)
+
+
+def test_training_and_serving_phases_at_toy_width(lm):
+    bert = TransformerConfig(vocab_size=512, hidden_size=32, num_layers=2,
+                             num_heads=2, mlp_dim=64, max_len=32,
+                             dropout_rate=0.0, attention_dropout_rate=0.0)
+    out = chip_smoke.training_phase(
+        bert, resource_spec={"topology": {"platform": "cpu",
+                                          "num_devices": 4}},
+        batch_per_device=2, seq_len=32, num_masked=4, platform="cpu")
+    assert out["devices"] == 4 and len(out["losses"]) == 3 + 4 + 4
+
+    cfg, params, prompts = lm
+    dense = chip_smoke.serving_phase(cfg, params, prompts, label="dense",
+                                     **SIZES)
+    paged = chip_smoke.serving_phase(cfg, params, prompts, label="paged",
+                                     **PAGED, **SIZES)
+    assert dense["tokens"] == paged["tokens"]      # float32: exact
+    chip_smoke.serving_parity(cfg, params, prompts[0],
+                              dense["tokens"][0][0])
+    # a check that fails raises — nothing lets a phase fail quietly
+    with pytest.raises(chip_smoke.SmokeFailure, match="first token"):
+        wrong = (dense["tokens"][0][0] + 1) % cfg.vocab_size
+        chip_smoke.serving_parity(cfg, params, prompts[0], wrong)
+
+
+def test_chip_smoke_refuses_to_run_without_a_tpu():
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        env=dict(os.environ, JAX_PLATFORMS="cpu"), cwd=REPO,
+        capture_output=True, text=True, timeout=120)
+    assert proc.returncode != 0
+    assert "needs a TPU" in proc.stderr and "'cpu'" in proc.stderr
+    assert not any(line.startswith("{") for line in proc.stdout.splitlines())
+
+
+@pytest.mark.slow
+def test_kernel_phase_at_toy_width_under_the_interpreter(lm):
+    done = chip_smoke.kernels_phase(
+        interpret=True, seq_len=64, heads=2, head_dim=16, cache_len=64,
+        block_len=8, chunk=16, slots=3, hop_elems=5000,
+        matmul_shape=(300, 600, 130))
+    assert len(done) == 8
+    cfg, params, prompts = lm
+    for marker, kw in (("flash_decode", {}), ("flash_decode", PAGED),
+                       ("flash_prefill", dict(PAGED, prefill_chunk=8))):
+        chip_smoke.serving_phase(cfg, params, prompts, label=marker,
+                                 kernel={marker: True}, marker_of=marker,
+                                 **kw, **SIZES)
+
+
+@pytest.mark.slow
+def test_multichip_phase_on_the_cpu_mesh(lm):
+    cfg, params, prompts = lm
+    dense = chip_smoke.serving_phase(cfg, params, prompts, label="dense",
+                                     **SIZES)
+    chip_smoke.multichip_phase(cfg, params, prompts, dense["tokens"],
+                               serve_sizes=SIZES, interpret=True)

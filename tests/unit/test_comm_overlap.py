@@ -418,10 +418,11 @@ def test_calibration_link_section_reaches_cost_model(tmp_path):
 
 
 def test_latency_hiding_flags_knob(monkeypatch):
-    """The runner knob: off by default; refused on non-TPU targets (XLA
-    aborts on flags its build doesn't define); applied into XLA_FLAGS
-    for TPU targets; a '--'-prefixed value replaces the default list
-    (the escape hatch for jaxlib flag drift)."""
+    """The runner knob: off by default; skipped on non-TPU targets;
+    applied into LIBTPU_INIT_ARGS (libtpu's flag channel — jaxlib's
+    XLA_FLAGS parser aborts on TPU flags) for TPU targets; a
+    '--'-prefixed value replaces the default list (the escape hatch for
+    libtpu flag drift)."""
     from autodist_tpu.kernel import lowering as kl
 
     env = {}
@@ -430,21 +431,21 @@ def test_latency_hiding_flags_knob(monkeypatch):
 
     monkeypatch.setenv("AUTODIST_TPU_ASYNC_COLLECTIVES", "1")
     assert kl.apply_latency_hiding_flags(env, platform="cpu") is False
-    assert "XLA_FLAGS" not in env
+    assert "LIBTPU_INIT_ARGS" not in env
 
     assert kl.apply_latency_hiding_flags(env, platform="tpu") is True
     for flag in kl.LATENCY_HIDING_XLA_FLAGS:
-        assert flag in env["XLA_FLAGS"]
+        assert flag in env["LIBTPU_INIT_ARGS"]
     # idempotent
-    before = env["XLA_FLAGS"]
+    before = env["LIBTPU_INIT_ARGS"]
     assert kl.apply_latency_hiding_flags(env, platform="tpu") is True
-    assert env["XLA_FLAGS"] == before
+    assert env["LIBTPU_INIT_ARGS"] == before
 
     monkeypatch.setenv("AUTODIST_TPU_ASYNC_COLLECTIVES",
                        "--xla_custom_flag=true")
     custom = {}
     assert kl.apply_latency_hiding_flags(custom, platform="tpu") is True
-    assert custom["XLA_FLAGS"] == "--xla_custom_flag=true"
+    assert custom["LIBTPU_INIT_ARGS"] == "--xla_custom_flag=true"
 
     monkeypatch.setenv("AUTODIST_TPU_ASYNC_COLLECTIVES", "0")
     assert kl.apply_latency_hiding_flags({}, platform="tpu") is False

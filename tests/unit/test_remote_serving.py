@@ -66,6 +66,10 @@ def test_routed_across_worker_processes_matches_run_alone(clean_env,
         assert len(fleet.live) == 2
         assert all(r.handle.proc.pid != os.getpid()
                    for r in fleet.live)
+        # each worker says what its own jax runs on: CPU-pinned, since
+        # a chip belongs to one process and the chief may hold it
+        assert fleet.platforms() == {"replica-0": ("cpu", "cpu"),
+                                     "replica-1": ("cpu", "cpu")}
         router = Router(fleet)
         rids = [router.submit(p, max_new_tokens=MAX_NEW)
                 for p in PROMPTS]

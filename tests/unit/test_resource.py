@@ -69,6 +69,43 @@ def test_chip_spec_lookup():
     assert rs.chip.peak_bf16_tflops > 0
 
 
+def test_named_platform_that_is_absent_raises():
+    """A spec that says ``platform: tpu`` gets TPU devices or an error
+    naming both what was asked and what jax has — never the CPUs."""
+    import pytest
+
+    rs = ResourceSpec({"topology": {"platform": "tpu"}})
+    with pytest.raises(RuntimeError, match="'tpu'.*'cpu'"):
+        rs.devices()
+    with pytest.raises(RuntimeError, match="'tpu'"):
+        rs.chip
+    # auto keeps jax's default backend; cpu keeps the simulated mesh
+    assert len(ResourceSpec({}).devices()) == 8
+    assert ResourceSpec({"topology": {"platform": "cpu"}}).chip.name == "cpu"
+
+
+def test_unknown_device_kind_or_generation_raises(monkeypatch):
+    """An unrecognised TPU is an error naming the string seen — not a
+    v5e, and not the simulated mesh's made-up ``cpu`` entry."""
+    import pytest
+
+    from autodist_tpu import resource
+
+    class Device:
+        platform = "tpu"
+        device_kind = "TPU v9 mega"
+
+    with pytest.raises(ValueError, match="TPU v9 mega"):
+        resource._detect_generation(Device())
+    Device.device_kind = "TPU v5 lite"       # what the v5e reports
+    assert resource._detect_generation(Device()) == "v5e"
+    with pytest.raises(ValueError, match="v9"):
+        ResourceSpec({"topology": {"generation": "v9"}}).chip
+    monkeypatch.setenv("AUTODIST_TPU_GENERATION", "v9")
+    with pytest.raises(ValueError, match="AUTODIST_TPU_GENERATION"):
+        ResourceSpec({}).chip
+
+
 def test_reference_style_nodes_spec_rejected():
     """Deliberate exclusion (docs/usage/migration.md): reference SSH GPU
     inventories are not a TPU topology; heterogeneous ones name the

@@ -1,6 +1,5 @@
 """RetryPolicy tier-1 pins: deterministic under a fixed seed, gives up
-at the deadline, never fires on success — and bench.py's UNAVAILABLE
-backoff is the same one implementation."""
+at the deadline, never fires on success."""
 import pytest
 
 from autodist_tpu.runtime.retry import (RetryError, RetryPolicy,
@@ -10,13 +9,6 @@ from autodist_tpu.runtime.retry import (RetryError, RetryPolicy,
 def test_backoff_delay_capped_exponential():
     assert [backoff_delay(a, 5.0, 60.0) for a in range(1, 6)] == \
         [5.0, 10.0, 20.0, 40.0, 60.0]
-
-
-def test_bench_backoff_is_the_shared_implementation():
-    import bench
-
-    assert [bench._backoff_delay(a) for a in range(1, 6)] == \
-        [backoff_delay(a, 5.0, 60.0) for a in range(1, 6)]
 
 
 def test_delays_deterministic_under_fixed_seed():

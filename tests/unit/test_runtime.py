@@ -369,12 +369,16 @@ def test_local_cluster_launches_n_workers(tmp_path):
         "import os, sys\n"
         "pid = os.environ['AUTODIST_TPU_PROCESS_ID']\n"
         f"open(os.path.join({str(tmp_path)!r}, 'w%s.txt' % pid), "
-        "'w').write(os.environ.get('AUTODIST_TPU_STRATEGY_ID', ''))\n")
+        "'w').write(os.environ.get('AUTODIST_TPU_STRATEGY_ID', '') + ' ' "
+        "+ os.environ.get('JAX_PLATFORMS', ''))\n")
     cluster = LocalCluster(2)
     try:
         cluster.launch_clients("strat-7",
-                               argv=[sys.executable, str(script)])
+                               argv=[sys.executable, str(script)],
+                               extra_env={"JAX_PLATFORMS": "tpu"})
         cluster.join(timeout=60)
     finally:
         cluster.terminate()
-    assert [o.read_text() for o in outs] == ["strat-7", "strat-7"]
+    # N+1 processes on one machine cannot share a chip: the workers are
+    # CPU-pinned whatever the caller's environment says
+    assert [o.read_text() for o in outs] == ["strat-7 cpu", "strat-7 cpu"]

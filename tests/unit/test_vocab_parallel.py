@@ -21,7 +21,6 @@ import optax
 import pytest
 from jax.sharding import PartitionSpec as P
 
-import autodist_tpu._jax_compat  # noqa: F401  (jax.shard_map on 0.4.x)
 from autodist_tpu import AutoDist
 from autodist_tpu.models.pipeline_lm import make_pipeline_lm_trainable
 from autodist_tpu.models.transformer import TransformerConfig

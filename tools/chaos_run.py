@@ -227,7 +227,8 @@ def run_scenario(kind: str, steps: int, tel_dir: str,
         "PYTHONPATH": os.path.dirname(os.path.dirname(
             os.path.abspath(__file__))),
         # workers need no simulated mesh and must not inherit ours
-        "XLA_FLAGS": "", "JAX_PLATFORMS": "cpu",
+        # (LocalCluster pins their platform to the CPU itself)
+        "XLA_FLAGS": "",
     })
     problems: list[str] = []
     try:

@@ -11,7 +11,6 @@ the sanctioned modules:
 
 * ``autodist_tpu/parallel/tensor.py`` — the precision primitives
 * ``autodist_tpu/kernel/`` — the quantize/compressor/gather layer
-* ``autodist_tpu/_jax_compat.py`` — the version shim
 
 A deliberate exception (a collective that is *not* a policied data
 boundary — e.g. the pipeline's pipe-axis role reductions) carries an
@@ -44,8 +43,7 @@ FORBIDDEN = ("psum", "all_gather", "psum_scatter")
 # Modules allowed to touch lax collectives directly (repo-relative,
 # forward slashes; directories end with "/").
 ALLOWED = ("autodist_tpu/parallel/tensor.py",
-           "autodist_tpu/kernel/",
-           "autodist_tpu/_jax_compat.py")
+           "autodist_tpu/kernel/")
 
 PRAGMA = "lint: allow-raw-collective"
 
