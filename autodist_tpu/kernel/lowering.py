@@ -46,6 +46,7 @@ from autodist_tpu.kernel import common
 from autodist_tpu.kernel.compressor import Compressor
 from autodist_tpu.strategy.ir import (AllReduceSynchronizer, PSSynchronizer,
                                       Strategy)
+from autodist_tpu.telemetry import scope
 from autodist_tpu.utils import logging
 
 # Update-space kinds: where the optimizer update for a variable runs.
@@ -724,32 +725,33 @@ def lower(trainable: Trainable, strategy: Strategy, mesh) -> Lowered:
         # ScopedAllocator merging) ---------------------------------------- #
         synced: dict[str, Any] = {}
         new_sync_state: dict[str, Any] = {}
-        for key, names in plan.buckets.items():
-            comp_name = plan.bucket_compressor.get(key, "none")
-            if n == 1 and comp_name in ("", "none", None):  # ≙ Compressor.create's no-op aliases
-                # Single replica: the allreduce is an identity and
-                # bucketing exists only to amortize collectives — skip
-                # the flatten/concat/slice round trip (a full extra
-                # pass over every gradient through HBM per step).
+        with scope("grad_sync"):
+            for key, names in plan.buckets.items():
+                comp_name = plan.bucket_compressor.get(key, "none")
+                if n == 1 and comp_name in ("", "none", None):  # ≙ Compressor.create's no-op aliases
+                    # Single replica: the allreduce is an identity and
+                    # bucketing exists only to amortize collectives — skip
+                    # the flatten/concat/slice round trip (a full extra
+                    # pass over every gradient through HBM per step).
+                    for nm in names:
+                        synced[nm] = g_by_name[nm]
+                    continue
+                comp = Compressor.create(comp_name)
+                flats = [g_by_name[nm].reshape(-1).astype(jnp.float32)
+                         for nm in names]
+                concat = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
+                comp_state = (state["sync_state"][key][0]
+                              if comp.stateful else None)
+                reduced, comp_state = comp.allreduce(concat, comp_state, data_axis)
+                if comp.stateful:
+                    new_sync_state[key] = comp_state[None]
+                offset = 0
                 for nm in names:
-                    synced[nm] = g_by_name[nm]
-                continue
-            comp = Compressor.create(comp_name)
-            flats = [g_by_name[nm].reshape(-1).astype(jnp.float32)
-                     for nm in names]
-            concat = jnp.concatenate(flats) if len(flats) > 1 else flats[0]
-            comp_state = (state["sync_state"][key][0]
-                          if comp.stateful else None)
-            reduced, comp_state = comp.allreduce(concat, comp_state, data_axis)
-            if comp.stateful:
-                new_sync_state[key] = comp_state[None]
-            offset = 0
-            for nm in names:
-                vp = plan.var_plans[nm]
-                sz = math.prod(vp.shape) or 1
-                synced[nm] = lax.slice_in_dim(reduced, offset, offset + sz)\
-                    .reshape(vp.shape).astype(g_by_name[nm].dtype)
-                offset += sz
+                    vp = plan.var_plans[nm]
+                    sz = math.prod(vp.shape) or 1
+                    synced[nm] = lax.slice_in_dim(reduced, offset, offset + sz)\
+                        .reshape(vp.shape).astype(g_by_name[nm].dtype)
+                    offset += sz
 
         # --- update-space grads and param views --------------------------- #
         def u_grad(name, _p):
@@ -774,11 +776,14 @@ def lower(trainable: Trainable, strategy: Strategy, mesh) -> Lowered:
                 return common.local_flat_shard(p, data_axis, n)
             return common.local_axis_shard(p, data_axis, n, vp.split_axis)
 
-        u_grads = common.tree_from_names(params_store, lambda nm, p: u_grad(nm, p))
+        with scope("grad_sync"):
+            u_grads = common.tree_from_names(params_store, u_grad)
         u_params = common.tree_from_names(params_store, u_param)
 
-        updates, new_opt_state = opt.update(u_grads, state["opt_state"], u_params)
-        u_new = optax.apply_updates(u_params, updates)
+        with scope("optimizer"):
+            updates, new_opt_state = opt.update(
+                u_grads, state["opt_state"], u_params)
+            u_new = optax.apply_updates(u_params, updates)
 
         # --- back to storage space ---------------------------------------- #
         def to_store(name, un):
@@ -790,7 +795,8 @@ def lower(trainable: Trainable, strategy: Strategy, mesh) -> Lowered:
             return common.all_gather_axis(
                 un, data_axis, vp.split_axis, vp.shape[vp.split_axis])
 
-        new_params = common.tree_from_names(u_new, to_store)
+        with scope("optimizer"):
+            new_params = common.tree_from_names(u_new, to_store)
 
         metrics = _reduce_metrics(dict(metrics), data_axis)
         # extra state (e.g. batch stats) must be SPMD-invariant: average

@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from autodist_tpu.models.transformer import Encoder, TransformerConfig
+from autodist_tpu.telemetry import scope
 
 
 def bert_base(**kw) -> TransformerConfig:
@@ -46,13 +47,15 @@ class BertModel(nn.Module):
         B, L = tokens.shape
         embed = nn.Embed(cfg.vocab_size, cfg.hidden_size, dtype=cfg.dtype,
                          name="token_embed")
-        x = embed(tokens)
-        pos = self.param("pos_embed", nn.initializers.normal(0.02),
-                         (cfg.max_len, cfg.hidden_size), jnp.float32)
-        x = x + pos[None, :L].astype(cfg.dtype)
-        if segments is not None:
-            x = x + nn.Embed(cfg.type_vocab_size, cfg.hidden_size,
-                             dtype=cfg.dtype, name="segment_embed")(segments)
+        with scope("embed"):
+            x = embed(tokens)
+            pos = self.param("pos_embed", nn.initializers.normal(0.02),
+                             (cfg.max_len, cfg.hidden_size), jnp.float32)
+            x = x + pos[None, :L].astype(cfg.dtype)
+            if segments is not None:
+                x = x + nn.Embed(cfg.type_vocab_size, cfg.hidden_size,
+                                 dtype=cfg.dtype,
+                                 name="segment_embed")(segments)
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_embed")(x)
         x = nn.Dropout(cfg.dropout_rate)(x, deterministic=deterministic)
 
@@ -63,20 +66,24 @@ class BertModel(nn.Module):
 
         # MLM head: gather masked positions (static count), transform,
         # decode against the tied embedding table.
-        gathered = jnp.take_along_axis(
-            x, masked_pos[..., None], axis=1)         # [B, P, H]
-        h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype, name="mlm_dense")(gathered)
-        h = nn.gelu(h)
-        h = nn.LayerNorm(dtype=cfg.dtype, name="mlm_ln")(h)
-        # Tied-embedding decode on the MXU in model dtype (the [H, V]
-        # matmul is the head's FLOP bulk); logits promoted to fp32 for
-        # the softmax by the loss head.
-        logits = embed.attend(h).astype(jnp.float32)  # [B, P, V]
-        logits = logits + self.param(
-            "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,), jnp.float32)
+        with scope("lm_head"):
+            gathered = jnp.take_along_axis(
+                x, masked_pos[..., None], axis=1)     # [B, P, H]
+            h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
+                         name="mlm_dense")(gathered)
+            h = nn.gelu(h)
+            h = nn.LayerNorm(dtype=cfg.dtype, name="mlm_ln")(h)
+            # Tied-embedding decode on the MXU in model dtype (the
+            # [H, V] matmul is the head's FLOP bulk); logits promoted to
+            # fp32 for the softmax by the loss head.
+            logits = embed.attend(h).astype(jnp.float32)  # [B, P, V]
+            logits = logits + self.param(
+                "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,),
+                jnp.float32)
         return logits
 
 
+@scope("lm_head")
 def mlm_loss_head(logits, batch):
     """Masked-LM cross entropy over the static masked positions.
 

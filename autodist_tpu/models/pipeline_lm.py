@@ -17,6 +17,7 @@ import jax.numpy as jnp
 from autodist_tpu.models.transformer import (EncoderLayer,
                                              TransformerConfig,
                                              dot_product_attention)
+from autodist_tpu.telemetry import scope
 
 
 def _layer_norm(x, scale, bias):
@@ -68,27 +69,31 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
     dtype = cfg.dtype
     att = chunk["attention"]
     x = x.astype(dtype)
-    qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
-                          att["qkv"]["bias"].astype(dtype),
-                          model_axis=model_axis, comm_overlap=comm_overlap)
-    q, k, v = jnp.moveaxis(qkv, -3, 0)
-    if cfg.attention_fn is not None:
-        out = cfg.attention_fn(q, k, v, mask, None)
-    else:
-        out = dot_product_attention(q, k, v, mask, dropout_rate=0.0,
-                                    dtype=dtype)
-    a = row_parallel(out, att["out"]["kernel"].astype(dtype),
-                     att["out"]["bias"].astype(dtype),
-                     model_axis=model_axis, axes=2,
-                     comm_overlap=comm_overlap)
+    with scope("attention"):
+        qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
+                              att["qkv"]["bias"].astype(dtype),
+                              model_axis=model_axis,
+                              comm_overlap=comm_overlap)
+        q, k, v = jnp.moveaxis(qkv, -3, 0)
+        if cfg.attention_fn is not None:
+            out = cfg.attention_fn(q, k, v, mask, None)
+        else:
+            out = dot_product_attention(q, k, v, mask, dropout_rate=0.0,
+                                        dtype=dtype)
+        a = row_parallel(out, att["out"]["kernel"].astype(dtype),
+                         att["out"]["bias"].astype(dtype),
+                         model_axis=model_axis, axes=2,
+                         comm_overlap=comm_overlap)
     x = _flax_layer_norm(x + a, chunk["ln_attention"], dtype)
-    h = column_parallel(x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
-                        chunk["mlp"]["wi"]["bias"].astype(dtype),
-                        model_axis=model_axis, comm_overlap=comm_overlap)
-    h = jax.nn.gelu(h)
-    m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
-                     chunk["mlp"]["wo"]["bias"].astype(dtype),
-                     model_axis=model_axis, comm_overlap=comm_overlap)
+    with scope("mlp"):
+        h = column_parallel(x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
+                            chunk["mlp"]["wi"]["bias"].astype(dtype),
+                            model_axis=model_axis,
+                            comm_overlap=comm_overlap)
+        h = jax.nn.gelu(h)
+        m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
+                         chunk["mlp"]["wo"]["bias"].astype(dtype),
+                         model_axis=model_axis, comm_overlap=comm_overlap)
     y = _flax_layer_norm(x + m, chunk["ln_mlp"], dtype)
     return (y, k, v) if return_kv else y
 
@@ -145,6 +150,7 @@ def make_pipeline_lm_trainable(cfg: TransformerConfig, optimizer, rng, *,
         "ln_final_bias": jnp.zeros((H,), jnp.float32),
     }
 
+    @scope("embed")
     def prologue(shared, batch, model_axis=None, comm_overlap=None):
         """Token + position embedding.  Under ``Pipeline(vocab_parallel=
         True)`` the lowering passes ``model_axis`` and ``shared
@@ -198,6 +204,7 @@ def make_pipeline_lm_trainable(cfg: TransformerConfig, optimizer, rng, *,
 
         return jax.vmap(one_row)(x, keys)
 
+    @scope("lm_head")
     def loss_head(outputs, batch, shared, model_axis=None,
                   comm_overlap=None):
         """Tied-unembedding softmax cross-entropy.  Replicated path: the

@@ -186,24 +186,28 @@ class DistributedRunner:
                   for i in range(k)]
             return jax.tree.map(lambda *xs: jnp.stack(xs), *ms)
 
-        batches = self.place_steps(batches)
-        if rngs is None:
-            self.rng, sub = jax.random.split(self.rng)
-            rngs = jax.random.split(sub, k)
-        if self._scanned_fn is None:
-            step_fn = self.lowered.step_fn
-
-            def scanned(state, batches, rngs):
-                def body(s, xs):
-                    b, r = xs
-                    return step_fn(s, b, r)
-                return lax.scan(body, state, (batches, rngs))
-
-            # Shape-generic: jit specializes per (k, batch shapes); state
-            # donation keeps params/opt buffers in place across the call.
-            self._scanned_fn = jax.jit(scanned, donate_argnums=(0,))
         with telemetry.span("runner/run_steps", k=k):
-            self.state, metrics = self._scanned_fn(self.state, batches, rngs)
+            with telemetry.span("runner/place"):
+                batches = self.place_steps(batches)
+            if rngs is None:
+                self.rng, sub = jax.random.split(self.rng)
+                rngs = jax.random.split(sub, k)
+            if self._scanned_fn is None:
+                step_fn = self.lowered.step_fn
+
+                def scanned(state, batches, rngs):
+                    def body(s, xs):
+                        b, r = xs
+                        return step_fn(s, b, r)
+                    return lax.scan(body, state, (batches, rngs))
+
+                # Shape-generic: jit specializes per (k, batch shapes);
+                # state donation keeps params/opt buffers in place
+                # across the call.
+                self._scanned_fn = jax.jit(scanned, donate_argnums=(0,))
+            with telemetry.span("runner/dispatch"):
+                self.state, metrics = self._scanned_fn(self.state, batches,
+                                                       rngs)
         self._host_step += k
         telemetry.counter("runner/steps").inc(k)
         return metrics

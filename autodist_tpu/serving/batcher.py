@@ -341,17 +341,18 @@ class ContinuousBatcher:
             raise
         t_first = time.perf_counter()
         chunk = getattr(self.engine, "prefill_chunk", None)
-        for i, req, hits in taken:
-            slot = _Slot(req=req, tokens=[int(toks[i])], admitted_s=now,
-                         first_tok_s=t_first, inter_token_ms=[],
-                         prefix_hit_blocks=hits,
-                         prefill_chunks=(-(-len(req.prompt) // chunk)
-                                         if chunk else 1))
-            ttft = t_first - req.submit_s
-            telemetry.histogram("serve/ttft_ms").observe(ttft * 1e3)
-            telemetry.counter("serve/tokens").inc()
-            self._slots[i] = slot
-            self._check_terminal(i)
+        with telemetry.span("serve/distribute"):
+            for i, req, hits in taken:
+                slot = _Slot(req=req, tokens=[int(toks[i])], admitted_s=now,
+                             first_tok_s=t_first, inter_token_ms=[],
+                             prefix_hit_blocks=hits,
+                             prefill_chunks=(-(-len(req.prompt) // chunk)
+                                             if chunk else 1))
+                ttft = t_first - req.submit_s
+                telemetry.histogram("serve/ttft_ms").observe(ttft * 1e3)
+                telemetry.counter("serve/tokens").inc()
+                self._slots[i] = slot
+                self._check_terminal(i)
 
     def _check_terminal(self, i: int):
         """Mark slot ``i`` done on EOS / token budget / cache capacity
@@ -460,42 +461,46 @@ class ContinuousBatcher:
                 proposed = accepted = np.zeros_like(counts)
         dt = time.perf_counter() - t0
         per_tok_ms = dt / max(int(np.max(counts)), 1) * 1e3
-        for i, slot in enumerate(self._slots):
-            if slot is None or not active[i]:
-                continue
-            before = len(slot.tokens)
-            slot.tokens.extend(int(toks[k, i])
-                               for k in range(int(counts[i])))
-            slot.spec_proposed += int(proposed[i])
-            slot.spec_accepted += int(accepted[i])
-            if proposed[i]:
-                telemetry.counter("serve/spec_proposed").inc(
-                    int(proposed[i]))
-                telemetry.counter("serve/spec_accepted").inc(
-                    int(accepted[i]))
-            self._check_terminal(i)
-            # Only tokens the request actually keeps count: a window's
-            # over-decode past EOS/budget is discarded above, and the
-            # counters/histograms must agree with the per-request
-            # serve records the report aggregates.
-            kept = max(0, len(slot.tokens) - before)
-            slot.inter_token_ms.extend([per_tok_ms] * kept)
-            for _ in range(kept):
-                telemetry.histogram("serve/inter_token_ms").observe(
-                    per_tok_ms)
-            telemetry.counter("serve/tokens").inc(kept)
+        with telemetry.span("serve/distribute"):
+            for i, slot in enumerate(self._slots):
+                if slot is None or not active[i]:
+                    continue
+                before = len(slot.tokens)
+                slot.tokens.extend(int(toks[k, i])
+                                   for k in range(int(counts[i])))
+                slot.spec_proposed += int(proposed[i])
+                slot.spec_accepted += int(accepted[i])
+                if proposed[i]:
+                    telemetry.counter("serve/spec_proposed").inc(
+                        int(proposed[i]))
+                    telemetry.counter("serve/spec_accepted").inc(
+                        int(accepted[i]))
+                self._check_terminal(i)
+                # Only tokens the request actually keeps count: a window's
+                # over-decode past EOS/budget is discarded above, and the
+                # counters/histograms must agree with the per-request
+                # serve records the report aggregates.
+                kept = max(0, len(slot.tokens) - before)
+                slot.inter_token_ms.extend([per_tok_ms] * kept)
+                for _ in range(kept):
+                    telemetry.histogram("serve/inter_token_ms").observe(
+                        per_tok_ms)
+                telemetry.counter("serve/tokens").inc(kept)
 
     # ------------------------------------------------------------------ #
     def step(self):
         """One scheduler round: expire deadlines, evict finished,
         admit, decode."""
-        self._expire_slots()
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot.done is not None:
-                self._evict(i)
-        if not self._draining:
-            self._admit()
-        self._decode_window()
+        with telemetry.span("serve/step"):
+            with telemetry.span("serve/evict"):
+                self._expire_slots()
+                for i, slot in enumerate(self._slots):
+                    if slot is not None and slot.done is not None:
+                        self._evict(i)
+            if not self._draining:
+                with telemetry.span("serve/admit"):
+                    self._admit()
+            self._decode_window()
 
     def run(self) -> dict[str, Completion]:
         """Drain the queue and every in-flight request; returns

@@ -38,8 +38,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from autodist_tpu import const
+from autodist_tpu import const, telemetry
 from autodist_tpu.serving import kv_cache
+from autodist_tpu.utils.stack_room import FirstCallWithRoom
 from autodist_tpu.parallel.tensor import (column_parallel,
                                           normalize_comm_overlap,
                                           row_parallel, vocab_pad,
@@ -473,6 +474,7 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     # the model math (one definition serves tp=1 and the shard_map path)
     # ------------------------------------------------------------------ #
+    @telemetry.scope("embed")
     def _embed(self, shared, tokens, positions):
         """Token + position embedding for ``[B, S]`` token ids at
         per-token ``positions`` (``[B, S]`` or a static ``[S]``)."""
@@ -509,30 +511,35 @@ class ServingEngine:
         dtype = cfg.dtype
         att = chunk["attention"]
         x = x.astype(dtype)
-        qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
-                              att["qkv"]["bias"].astype(dtype),
-                              model_axis=axis, comm_overlap=overlap)
-        q, k, v = jnp.moveaxis(qkv, -3, 0)          # [B, 1, heads, dh]
+        with telemetry.scope("attention"):
+            qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
+                                  att["qkv"]["bias"].astype(dtype),
+                                  model_axis=axis, comm_overlap=overlap)
+            q, k, v = jnp.moveaxis(qkv, -3, 0)      # [B, 1, heads, dh]
+        # the cache writes wear their own scope (kv_write), so the
+        # attention scope is left for them and entered again
         if table is not None:
             bl = self.kv_block_len
             kc = kv_cache.paged_write_token(kc, layer, k, lengths,
                                             table, bl, write_mask=active)
             vc = kv_cache.paged_write_token(vc, layer, v, lengths,
                                             table, bl, write_mask=active)
-            if self.kernel.get("flash_decode"):
-                from autodist_tpu.kernel.pallas.flash_decode import \
-                    flash_decode_attention_paged
-                out = flash_decode_attention_paged(
-                    q, kc[layer], vc[layer], lengths, table,
-                    block_len=bl, dtype=dtype)
-            else:
-                out = kv_cache.paged_cached_attention(
-                    q, kc[layer], vc[layer], lengths, table,
-                    block_len=bl, dtype=dtype)
         else:
             kc = kv_cache.write_token(kc, layer, k, lengths)
             vc = kv_cache.write_token(vc, layer, v, lengths)
-            if self.kernel.get("flash_decode"):
+        with telemetry.scope("attention"):
+            if table is not None:
+                if self.kernel.get("flash_decode"):
+                    from autodist_tpu.kernel.pallas.flash_decode import \
+                        flash_decode_attention_paged
+                    out = flash_decode_attention_paged(
+                        q, kc[layer], vc[layer], lengths, table,
+                        block_len=bl, dtype=dtype)
+                else:
+                    out = kv_cache.paged_cached_attention(
+                        q, kc[layer], vc[layer], lengths, table,
+                        block_len=bl, dtype=dtype)
+            elif self.kernel.get("flash_decode"):
                 from autodist_tpu.kernel.pallas.flash_decode import \
                     flash_decode_attention
                 out = flash_decode_attention(q, kc[layer], vc[layer],
@@ -540,17 +547,19 @@ class ServingEngine:
             else:
                 out = kv_cache.cached_attention(q, kc[layer], vc[layer],
                                                 lengths, dtype=dtype)
-        a = row_parallel(out, att["out"]["kernel"].astype(dtype),
-                         att["out"]["bias"].astype(dtype),
-                         model_axis=axis, axes=2, comm_overlap=overlap)
+            a = row_parallel(out, att["out"]["kernel"].astype(dtype),
+                             att["out"]["bias"].astype(dtype),
+                             model_axis=axis, axes=2, comm_overlap=overlap)
         x = _flax_layer_norm(x + a, chunk["ln_attention"], dtype)
-        h = column_parallel(x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
-                            chunk["mlp"]["wi"]["bias"].astype(dtype),
-                            model_axis=axis, comm_overlap=overlap)
-        h = jax.nn.gelu(h)
-        m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
-                         chunk["mlp"]["wo"]["bias"].astype(dtype),
-                         model_axis=axis, comm_overlap=overlap)
+        with telemetry.scope("mlp"):
+            h = column_parallel(
+                x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
+                chunk["mlp"]["wi"]["bias"].astype(dtype),
+                model_axis=axis, comm_overlap=overlap)
+            h = jax.nn.gelu(h)
+            m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
+                             chunk["mlp"]["wo"]["bias"].astype(dtype),
+                             model_axis=axis, comm_overlap=overlap)
         return _flax_layer_norm(x + m, chunk["ln_mlp"], dtype), kc, vc
 
     def _layer_chunk(self, chunk, x, kc, vc, layer, starts, table, write):
@@ -568,37 +577,41 @@ class ServingEngine:
         dtype = cfg.dtype
         att = chunk["attention"]
         x = x.astype(dtype)
-        qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
-                              att["qkv"]["bias"].astype(dtype),
-                              model_axis=axis, comm_overlap=overlap)
-        q, k, v = jnp.moveaxis(qkv, -3, 0)          # [B, C, heads, dh]
-        kc, vc = write(kc, vc, k, v)
-        if table is not None:
-            bl = self.kv_block_len
-            if self.kernel.get("flash_prefill"):
-                from autodist_tpu.kernel.pallas.flash_prefill import \
-                    flash_prefill_attention_paged
-                out = flash_prefill_attention_paged(
-                    q, kc[layer], vc[layer], starts, table,
-                    block_len=bl, dtype=dtype)
+        with telemetry.scope("attention"):
+            qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
+                                  att["qkv"]["bias"].astype(dtype),
+                                  model_axis=axis, comm_overlap=overlap)
+            q, k, v = jnp.moveaxis(qkv, -3, 0)      # [B, C, heads, dh]
+        kc, vc = write(kc, vc, k, v)                # scope: kv_write
+        with telemetry.scope("attention"):
+            if table is not None:
+                bl = self.kv_block_len
+                if self.kernel.get("flash_prefill"):
+                    from autodist_tpu.kernel.pallas.flash_prefill import \
+                        flash_prefill_attention_paged
+                    out = flash_prefill_attention_paged(
+                        q, kc[layer], vc[layer], starts, table,
+                        block_len=bl, dtype=dtype)
+                else:
+                    out = kv_cache.paged_chunk_attention(
+                        q, kc[layer], vc[layer], starts, table,
+                        block_len=bl, dtype=dtype)
             else:
-                out = kv_cache.paged_chunk_attention(
-                    q, kc[layer], vc[layer], starts, table,
-                    block_len=bl, dtype=dtype)
-        else:
-            out = kv_cache.chunk_attention(q, kc[layer], vc[layer],
-                                           starts, dtype=dtype)
-        a = row_parallel(out, att["out"]["kernel"].astype(dtype),
-                         att["out"]["bias"].astype(dtype),
-                         model_axis=axis, axes=2, comm_overlap=overlap)
+                out = kv_cache.chunk_attention(q, kc[layer], vc[layer],
+                                               starts, dtype=dtype)
+            a = row_parallel(out, att["out"]["kernel"].astype(dtype),
+                             att["out"]["bias"].astype(dtype),
+                             model_axis=axis, axes=2, comm_overlap=overlap)
         x = _flax_layer_norm(x + a, chunk["ln_attention"], dtype)
-        h = column_parallel(x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
-                            chunk["mlp"]["wi"]["bias"].astype(dtype),
-                            model_axis=axis, comm_overlap=overlap)
-        h = jax.nn.gelu(h)
-        m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
-                         chunk["mlp"]["wo"]["bias"].astype(dtype),
-                         model_axis=axis, comm_overlap=overlap)
+        with telemetry.scope("mlp"):
+            h = column_parallel(
+                x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
+                chunk["mlp"]["wi"]["bias"].astype(dtype),
+                model_axis=axis, comm_overlap=overlap)
+            h = jax.nn.gelu(h)
+            m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
+                             chunk["mlp"]["wo"]["bias"].astype(dtype),
+                             model_axis=axis, comm_overlap=overlap)
         return _flax_layer_norm(x + m, chunk["ln_mlp"], dtype), kc, vc
 
     def _greedy(self, shared, h):
@@ -612,6 +625,7 @@ class ServingEngine:
             x, shared["embedding"], vocab_size=self.cfg.vocab_size,
             model_axis=self._axis if self.vocab_parallel else None)
 
+    @telemetry.scope("lm_head")
     def _next_token(self, shared, h, seeds, positions):
         """The decode epilogue: greedy at ``temperature == 0`` (the
         exact pre-sampling program — the sampler is never traced), else
@@ -640,9 +654,12 @@ class ServingEngine:
         mesh at tp>1, with the cache arrays donated so updates alias in
         place.  ``n_in_rest``/``n_out_rest`` count the replicated
         non-cache operands/results after ``(params, k, v)`` /
-        ``(k, v)``."""
+        ``(k, v)``.  The first call, which traces and lowers these
+        unrolled programs, gets stack room of its own: where it stands
+        on the interpreter's frame stack otherwise decides whether
+        lowering takes half a second or twenty (``utils/stack_room``)."""
         if self.mesh is None:
-            return jax.jit(fn, donate_argnums=(1, 2))
+            return FirstCallWithRoom(jax.jit(fn, donate_argnums=(1, 2)))
         cspec = kv_cache.cache_spec()
         sm = jax.shard_map(
             fn, mesh=self.mesh,
@@ -650,7 +667,7 @@ class ServingEngine:
             + (P(),) * n_in_rest,
             out_specs=(cspec, cspec) + (P(),) * n_out_rest,
             check_vma=False)
-        return jax.jit(sm, donate_argnums=(1, 2))
+        return FirstCallWithRoom(jax.jit(sm, donate_argnums=(1, 2)))
 
     def _build_prefill(self):
         L, S = self.cfg.num_layers, self.prefill_len
@@ -1003,8 +1020,6 @@ class ServingEngine:
             self.release_slot(slot)
 
     def _emit_block_gauges(self):
-        from autodist_tpu import telemetry
-
         telemetry.gauge("serve/kv_blocks_free").set(
             self._allocator.free_blocks)
         telemetry.gauge("serve/kv_blocks_used").set(
@@ -1133,38 +1148,43 @@ class ServingEngine:
         (``chunk_start`` is traced), skipping leading chunks every
         admitted slot already has cached via prefix hits.  Returns the
         per-slot current token ``[B]`` (numpy)."""
-        prompts_np = np.asarray(prompts)
-        p_lens_np = np.asarray(p_lens)
-        admit_np = np.asarray(admit, bool)
-        if seeds is not None:
-            self._sample_seeds = np.where(
-                admit_np, np.asarray(seeds, np.int32),
-                self._sample_seeds).astype(np.int32)
-        p_lens_j = jnp.asarray(p_lens_np, jnp.int32)
-        admit_j = jnp.asarray(admit_np)
-        rest = ((jnp.asarray(self._write_from),)
-                if self.prefix_caching else ())
+        with telemetry.span("engine/prefill/stage"):
+            prompts_np = np.asarray(prompts)
+            p_lens_np = np.asarray(p_lens)
+            admit_np = np.asarray(admit, bool)
+            if seeds is not None:
+                self._sample_seeds = np.where(
+                    admit_np, np.asarray(seeds, np.int32),
+                    self._sample_seeds).astype(np.int32)
+            p_lens_j = jnp.asarray(p_lens_np, jnp.int32)
+            admit_j = jnp.asarray(admit_np)
+            rest = ((jnp.asarray(self._write_from),)
+                    if self.prefix_caching else ())
+            if self.prefill_chunk is None:
+                c = self.cache
+                args = (self.params, c.k, c.v, c.lengths, self._tok,
+                        self._table_arg(), jnp.asarray(self._sample_seeds),
+                        jnp.asarray(prompts_np, jnp.int32), p_lens_j,
+                        admit_j, *rest)
         if self.prefill_chunk is None:
-            c = self.cache
-            k, v, lengths, tok = self._prefill_jit(
-                self.params, c.k, c.v, c.lengths, self._tok,
-                self._table_arg(), jnp.asarray(self._sample_seeds),
-                jnp.asarray(prompts_np, jnp.int32), p_lens_j, admit_j,
-                *rest)
-            self.cache = self._rebuild_cache(k, v, lengths)
-            self._tok = tok
+            with telemetry.span("engine/prefill/dispatch"):
+                k, v, lengths, tok = self._prefill_jit(*args)
+                self.cache = self._rebuild_cache(k, v, lengths)
+                self._tok = tok
             self.last_prefill_chunks = 1
         else:
             self._chunked_prefill(prompts_np, p_lens_np, admit_np,
                                   p_lens_j, admit_j, rest)
-        self._flush_registration(admit_np)
-        if self.draft is not None:
-            # The draft mirrors the target's resident prompts so its
-            # proposals condition on the same context; its first-token
-            # emission is discarded (decode_window aligns _tok to the
-            # target's before every proposal run).
-            self.draft.prefill(prompts_np, p_lens_np, admit_np, seeds)
-        return np.asarray(jax.device_get(self._tok))
+        with telemetry.span("engine/prefill/register"):
+            self._flush_registration(admit_np)
+            if self.draft is not None:
+                # The draft mirrors the target's resident prompts so its
+                # proposals condition on the same context; its
+                # first-token emission is discarded (decode_window aligns
+                # _tok to the target's before every proposal run).
+                self.draft.prefill(prompts_np, p_lens_np, admit_np, seeds)
+        with telemetry.span("engine/prefill/fetch"):
+            return np.asarray(jax.device_get(self._tok))
 
     def _chunked_prefill(self, prompts_np, p_lens_np, admit_np,
                          p_lens_j, admit_j, rest):
@@ -1193,13 +1213,15 @@ class ServingEngine:
         for ci in range(first, n_chunks):
             cs = ci * C
             c = self.cache
-            k, v, lengths, tok = self._prefill_jit(
-                self.params, c.k, c.v, c.lengths, self._tok,
-                self._table_arg(), jnp.asarray(self._sample_seeds),
-                jnp.asarray(padded[:, cs:cs + C], jnp.int32),
-                jnp.int32(cs), p_lens_j, admit_j, *rest)
-            self.cache = self._rebuild_cache(k, v, lengths)
-            self._tok = tok
+            with telemetry.span("engine/prefill/stage"):
+                args = (self.params, c.k, c.v, c.lengths, self._tok,
+                        self._table_arg(), jnp.asarray(self._sample_seeds),
+                        jnp.asarray(padded[:, cs:cs + C], jnp.int32),
+                        jnp.int32(cs), p_lens_j, admit_j, *rest)
+            with telemetry.span("engine/prefill/dispatch"):
+                k, v, lengths, tok = self._prefill_jit(*args)
+                self.cache = self._rebuild_cache(k, v, lengths)
+                self._tok = tok
             dispatched += 1
         self.last_prefill_chunks = dispatched
 
@@ -1207,17 +1229,21 @@ class ServingEngine:
         """One fused ``decode_steps``-token dispatch; inactive slots
         hold their state.  Returns the emitted tokens ``[K, B]``
         (numpy; inactive columns repeat the held token)."""
-        active_np = np.asarray(active, bool)
-        if self.kv_layout == "paged":
-            self._cow_protect(active_np, self.lengths, self.decode_steps)
-        c = self.cache
-        k, v, lengths, tok, toks = self._decode_jit(
-            self.params, c.k, c.v, c.lengths, self._tok,
-            self._table_arg(), jnp.asarray(self._sample_seeds),
-            jnp.asarray(active_np))
-        self.cache = self._rebuild_cache(k, v, lengths)
-        self._tok = tok
-        return np.asarray(jax.device_get(toks))
+        with telemetry.span("engine/decode/stage"):
+            active_np = np.asarray(active, bool)
+            if self.kv_layout == "paged":
+                self._cow_protect(active_np, self.lengths,
+                                  self.decode_steps)
+            c = self.cache
+            args = (self.params, c.k, c.v, c.lengths, self._tok,
+                    self._table_arg(), jnp.asarray(self._sample_seeds),
+                    jnp.asarray(active_np))
+        with telemetry.span("engine/decode/dispatch"):
+            k, v, lengths, tok, toks = self._decode_jit(*args)
+            self.cache = self._rebuild_cache(k, v, lengths)
+            self._tok = tok
+        with telemetry.span("engine/decode/fetch"):
+            return np.asarray(jax.device_get(toks))
 
     def decode_one(self, active):
         """A single-token dispatch through a lazily-built K=1 program —

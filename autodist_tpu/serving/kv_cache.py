@@ -56,6 +56,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from autodist_tpu import const
+from autodist_tpu.telemetry import scope
 
 
 def cache_spec() -> P:
@@ -98,6 +99,7 @@ def init_cache(num_layers: int, num_slots: int, num_heads: int,
                    lengths=jnp.zeros((num_slots,), jnp.int32))
 
 
+@scope("kv_write")
 def write_token(cache_arr, layer: int, kv, positions):
     """Write one decode step's projections into ``cache_arr`` in place.
 
@@ -116,6 +118,7 @@ def write_token(cache_arr, layer: int, kv, positions):
     return cache_arr
 
 
+@scope("kv_write")
 def write_prompt(cache_arr, layer: int, kv, admit):
     """Write a prefill's whole-prompt projections for admitted slots.
 
@@ -368,6 +371,7 @@ def init_paged_cache(num_layers: int, num_slots: int, num_heads: int,
         block_table=jnp.zeros((num_slots, max_blocks), jnp.int32))
 
 
+@scope("kv_write")
 def paged_write_token(cache_arr, layer: int, kv, positions, block_table,
                       block_len: int, write_mask=None):
     """The paged :func:`write_token`: slot ``i``'s row lands in pool
@@ -401,6 +405,7 @@ def paged_write_token(cache_arr, layer: int, kv, positions, block_table,
     return cache_arr
 
 
+@scope("kv_write")
 def paged_write_prompt(cache_arr, layer: int, kv, admit, block_table,
                        block_len: int, p_lens, write_from=None):
     """The paged :func:`write_prompt`: slot ``i``'s prompt rows land
@@ -444,6 +449,7 @@ def paged_write_prompt(cache_arr, layer: int, kv, admit, block_table,
     return cache_arr
 
 
+@scope("kv_write")
 def paged_write_chunk(cache_arr, layer: int, kv, admit, block_table,
                       block_len: int, chunk_start, p_lens,
                       write_from=None):

@@ -27,6 +27,7 @@ from autodist_tpu.telemetry.drift import DriftMonitor, drift_report
 from autodist_tpu.telemetry.metrics import (NULL_INSTRUMENT, Counter, Gauge,
                                             Histogram, MetricsRegistry)
 from autodist_tpu.telemetry.records import build_manifest, provenance
+from autodist_tpu.telemetry.scopes import SCOPES, scope
 from autodist_tpu.telemetry.tracing import (current_trace_id, mint_trace_id,
                                             request_timeline, stitch_trace,
                                             trace_context)
@@ -34,7 +35,7 @@ from autodist_tpu.telemetry.tracing import (current_trace_id, mint_trace_id,
 __all__ = [
     "Telemetry", "get", "configure", "reset", "enabled", "span", "counter",
     "gauge", "histogram", "record_step", "record_event", "annotate",
-    "flush", "manifest",
+    "flush", "manifest", "SCOPES", "scope",
     "summary", "drift_report", "provenance", "build_manifest",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_SPAN", "NULL_INSTRUMENT",

@@ -6,7 +6,10 @@ The reference AutoDist's observability was chrome-trace timelines per
 
 * :meth:`Telemetry.span` — nested timing spans (``with
   telemetry.span("compile"):``) exported as chrome-trace JSON
-  (``chrome://tracing`` / Perfetto load it directly).
+  (``chrome://tracing`` / Perfetto load it directly).  Every span is
+  also a ``jax.profiler.TraceAnnotation`` of the same name, so while a
+  profiler session runs it lands in the trace's host plane, on the
+  clock of the device ops — the one way the program writes host spans.
 * counters / gauges / histograms (:mod:`autodist_tpu.telemetry.metrics`)
   flushed to a JSONL sink plus a human-readable summary.
 * per-step records (step latency, examples, metrics) with a sampling
@@ -60,11 +63,27 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+_TraceAnnotation = None
+
+
+def _trace_annotation(name: str, args: dict):
+    """The span as a ``jax.profiler.TraceAnnotation`` carrying its
+    scalar args (one TraceMe level check when no profiler session
+    runs).  jax is imported on the first span, not with this module:
+    the tools that read telemetry files never enter one."""
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
+        from jax.profiler import TraceAnnotation as _TraceAnnotation
+    return _TraceAnnotation(name, **{
+        k: v for k, v in args.items()
+        if isinstance(v, (bool, int, float, str))})
+
+
 class Span:
     """One timed region; nesting is tracked per thread so the chrome
     trace shows parent/child stacks."""
 
-    __slots__ = ("name", "args", "_tel", "_t0", "_tid")
+    __slots__ = ("name", "args", "_tel", "_t0", "_tid", "_annotation")
 
     def __init__(self, tel: "Telemetry", name: str, args: dict):
         self._tel = tel
@@ -80,16 +99,20 @@ class Span:
     def __enter__(self):
         self._tid = threading.get_ident()
         self._tel._span_stack().append(self.name)
+        self._annotation = _trace_annotation(self.name, self.args)
+        self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
+        self._annotation.__exit__(*exc)
         stack = self._tel._span_stack()
         if stack and stack[-1] == self.name:
             stack.pop()
         self._tel._record_span(self, self._t0, t1, self._tid,
-                               depth=len(stack))
+                               depth=len(stack),
+                               parent=stack[-1] if stack else None)
         return False
 
 
@@ -137,7 +160,7 @@ class Telemetry:
         return Span(self, name, args)
 
     def _record_span(self, span: Span, t0: float, t1: float, tid: int,
-                     depth: int):
+                     depth: int, parent: Optional[str] = None):
         event = {"name": span.name, "ph": "X", "pid": os.getpid(),
                  "tid": tid,
                  "ts": self._epoch_wall_us + (t0 - self._epoch_perf) * 1e6,
@@ -145,7 +168,7 @@ class Telemetry:
         if span.args:
             event["args"] = {k: _jsonable(v) for k, v in span.args.items()}
         if depth:
-            event.setdefault("args", {})["depth"] = depth
+            event.setdefault("args", {}).update(depth=depth, parent=parent)
         with self._lock:
             if len(self._spans) < MAX_SPANS:
                 self._spans.append(event)

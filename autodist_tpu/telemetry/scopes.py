@@ -1,0 +1,34 @@
+"""The scopes the compiled programs wear on their device ops.
+
+One small vocabulary, applied with :func:`scope` at the one place each
+thing is computed.  A scope is a ``jax.named_scope``: it names the ops
+traced under it (``jit(decode)/while/body/attention/dot_general``) and
+changes nothing the compiler schedules, so a profiler trace can say
+which part of this system a device op belongs to, and keep saying it
+after a recompile renames every fusion.  The flax models name their
+modules the same way (``SelfAttention(name="attention")``,
+``MlpBlock(name="mlp")``), so the training forward wears ``attention``
+and ``mlp`` without a call here; a backward op carries
+``transpose(jvp(...))`` ahead of the same scope.
+"""
+from __future__ import annotations
+
+SCOPES = (
+    "embed",       # token + position (+ segment) embedding lookup
+    "attention",   # qkv projection .. output projection, no cache write
+    "mlp",         # wi, GELU, wo
+    "kv_write",    # keys / values written into the serving cache
+    "lm_head",     # final norm, logits, argmax / sampling; MLM head + loss
+    "grad_sync",   # gradient buckets' all-reduce, the reduce-scatters
+    "optimizer",   # the update, its application, the gather to storage
+)
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of :data:`SCOPES`."""
+    if name not in SCOPES:
+        raise ValueError(f"{name!r} is not a scope of the vocabulary "
+                         f"{SCOPES}")
+    import jax
+
+    return jax.named_scope(name)
