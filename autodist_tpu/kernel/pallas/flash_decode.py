@@ -2,21 +2,30 @@
 
 The decode analog of ``ops/flash_attention.py``: a single-token step's
 attention over a layer's cache slice (``serving/kv_cache.py
-cached_attention``) computes a ``[B, heads, 1, T]`` score row, a full-T
-softmax, and a second full-T contraction — three HBM-shaped passes over
-the cache per layer per token.  This kernel streams the cache in
-``block_k``-sized tiles with the online-softmax recurrence (running
-max / sum / accumulator in VMEM), so the cache is read once and the
-scores never exist outside a ``[1, block_k]`` tile.
+cached_attention``) computes a ``[B, heads, 1, T]`` score row over all
+``T`` positions of every slot, a full-T softmax, and a second full-T
+contraction.  The kernels here stream the cache in blocks with the
+online-softmax recurrence (running max / sum / accumulator in VMEM), so
+the cache is read once and the scores never exist outside one block.
+
+The **dense** kernel (:func:`flash_decode_attention_dense`) is what
+``ServingEngine`` runs by default on a TPU.  It takes the whole
+``[L, B, H, T, d]`` cache and a layer index (no slice, so no copy),
+reads only each slot's live blocks (a block above ``lengths[slot]`` is
+neither fetched nor computed), handles all the heads of a slot that
+fit VMEM in one grid step, and multiplies the cache's own bf16 tiles.
+It reads the lanes transposed, ``[.., d, T]``: that is how the TPU
+lays out a cache of heads narrower than its 128 lanes, so the kernel's
+view of the array is the array (:func:`fused_decode_block`).  The
+**paged** kernel walks a slot's block table one ``[block_len, d]`` pool
+block per grid step (:func:`online_softmax_step`, shared with the paged
+prefill kernel).
 
 Masking matches ``cached_attention`` exactly: key positions ``<=
 lengths[slot]`` are visible (the just-written token attends to itself
 and everything before it), everything past a slot's occupancy —
 including the zero tail and any previous occupant's stale rows — is
-unreachable.  Slot lengths shorter than one block and cache lengths
-that don't divide ``block_k`` are handled by the same mask (the wrapper
-zero-pads T up to a block multiple; padded positions sit above every
-legal length).
+unreachable.
 
 Softmax statistics in fp32 regardless of cache dtype, the trained
 model's scaling — the greedy-parity goldens pin token-for-token
@@ -37,10 +46,15 @@ from autodist_tpu.kernel.pallas import default_interpret, kernel_marker
 
 NEG_INF = float(np.finfo(np.float32).min)
 
-# Default cache-tile length.  Small caches stream in one tile; the
-# tuning table measured by ``tools/flash_crossover.py --decode`` can
-# override per call.
+# The dense kernel's cache-block length, the shortest lane that
+# ``ServingEngine``'s default election gives it (from there on it won at
+# every fill), and its VMEM sizing: read on a v5e with
+# ``tools/flash_crossover.py --decode`` at 8 slots x 20 heads x 64, bf16
+# (PERF.md section 6, PR 25).
 DEFAULT_BLOCK_K = 128
+MIN_FUSED_DECODE_LEN = 256
+KV_BLOCK_BYTES = 2 << 20       # one K (or V) block, lane-padded
+VMEM_LIMIT_BYTES = 32 << 20    # K and V blocks double-buffered + carry
 
 
 def online_softmax_step(first_pos, j, q_ref, k_ref, v_ref, o_ref, m_ref,
@@ -109,69 +123,329 @@ def carry_scratch(rows: int, d: int):
             pltpu.VMEM((rows, d), jnp.float32)]   # accumulator per row
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, s_ref,
-                   acc_ref, **kw):
-    """Dense cache: block ``j`` is rows ``[j*bk, (j+1)*bk)`` of the
-    slot's lane.  ``len_ref``: scalar-prefetched ``[B]`` int32."""
-    online_softmax_step(len_ref[pl.program_id(0)], pl.program_id(2),
-                        q_ref, k_ref, v_ref, o_ref, m_ref, s_ref,
-                        acc_ref, **kw)
+# --------------------------------------------------------------------------- #
+# Dense cache: the whole ``[L, B, H, T, d]`` array, live blocks only
+# --------------------------------------------------------------------------- #
+def decode_block_len(max_len: int, block_k: Optional[int] = None):
+    """The block length the dense kernel walks a ``max_len`` lane in,
+    or ``None`` when no block does without a padded copy of the lane:
+    ``block_k`` (default :data:`DEFAULT_BLOCK_K`) where it divides
+    ``max_len``, the whole lane where the lane is shorter than one
+    block."""
+    bk = int(block_k or DEFAULT_BLOCK_K)
+    if max_len <= bk:
+        return int(max_len)
+    return bk if max_len % bk == 0 else None
+
+
+def fused_decode_block(max_len: int, head_dim: int):
+    """The block length with which the dense kernel reads a
+    ``[L, B, H, max_len, head_dim]`` cache in place, or ``None`` where
+    it cannot: the lane has to divide into blocks, and the TPU has to
+    keep the array with the positions minor-most, as it
+    does when ``head_dim`` is under its 128 lanes and ``max_len`` a
+    multiple of them; the kernel's transposed view is then the array
+    itself and otherwise a copy of all of it."""
+    bk = decode_block_len(max_len)
+    if bk is None or head_dim >= 128 or max_len % 128:
+        return None
+    return bk
+
+
+def _heads_per_step(heads: int, block_len: int, d: int, itemsize: int):
+    """Most heads of a slot (a divisor of ``heads``) whose K block fits
+    :data:`KV_BLOCK_BYTES` of VMEM (the minor dimension pads to the 128
+    lanes there)."""
+    per_head = d * max(block_len, 128) * itemsize
+    cap = max(1, KV_BLOCK_BYTES // per_head)
+    return max(h for h in range(1, heads + 1)
+               if heads % h == 0 and h <= cap)
+
+
+def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
+                         block_len: int, num_blocks: int, scale: float,
+                         write: bool):
+    """One (slot, head group) grid step: walk the slot's live blocks of
+    the TRANSPOSED lane, ``[hb, d, bk]`` tiles with the positions on the
+    lanes (the layout a TPU keeps a cache of narrow heads in), with the
+    kernel's own double-buffered DMA.  Block ``j`` holds positions
+    ``[j*bk, (j+1)*bk)``; the last live block is ``lengths[slot] // bk``
+    (position ``lengths`` is this step's token), so the trip count is
+    the slot's own and a dead block costs nothing.  The block after the
+    last is the next grid step's first: it is on its way while this
+    step's last is computed, and ``par_ref`` tells the next step which
+    buffer it went to.
+
+    With ``write`` the step's new key and value rows are put into the
+    block that holds position ``wpos[slot]`` while it is in VMEM, before
+    the products, and the 128 positions around it go back to the cache
+    (the aliased outputs): the cache write of the step, without a pass
+    of its own.  ``wpos < 0`` writes nothing.
+
+    Products take the cache's own tiles (bf16 stays bf16) with float32
+    results; the running max, sum and accumulator are float32."""
+    if write:
+        (new_ref, kt_hbm, vt_hbm, o_ref, kt_out, vt_out,
+         kbuf, vbuf, sem, wbuf, wsem, par_ref, m_ref, s_ref, acc_ref) = refs
+    else:
+        (kt_hbm, vt_hbm, o_ref,
+         kbuf, vbuf, sem, par_ref, m_ref, s_ref, acc_ref) = refs
+    bk = block_len
+    hb, d = kbuf.shape[1], kbuf.shape[2]
+    b, h, nh = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
+    g = b * nh + h
+    layer = layer_ref[0]
+    length = len_ref[b]
+    n = jnp.minimum(length // bk, num_blocks - 1) + 1      # live blocks
+
+    def fetch(slot, group, j, buf):
+        at = (layer, slot, pl.ds(group * hb, hb), slice(None),
+              pl.ds(pl.multiple_of(j * bk, bk), bk))
+        return (pltpu.make_async_copy(kt_hbm.at[at], kbuf.at[buf],
+                                      sem.at[0, buf]),
+                pltpu.make_async_copy(vt_hbm.at[at], vbuf.at[buf],
+                                      sem.at[1, buf]))
+
+    @pl.when(g == 0)
+    def _prime():
+        par_ref[0] = 0
+        if write:
+            par_ref[1] = 0              # no write-back in flight
+        for dma in fetch(0, 0, 0, 0):
+            dma.start()
+
+    par = par_ref[0]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    s_ref[...] = jnp.zeros_like(s_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    q = q_ref[...]                                         # [hb, 1, d]
+    if write:
+        # the new rows as columns, [hb, d, 2] (key, value)
+        cols = jnp.swapaxes(new_ref[...], 1, 2).astype(kbuf.dtype)
+        wpos = wpos_ref[b]
+        w = wbuf.shape[-1]              # positions written back as one
+
+        def put_back(at):
+            return (pltpu.make_async_copy(wbuf.at[0], kt_out.at[at],
+                                          wsem.at[0]),
+                    pltpu.make_async_copy(wbuf.at[1], vt_out.at[at],
+                                          wsem.at[1]))
+
+        def settle():                   # the write-back in flight, if any
+            @pl.when(par_ref[1] == 1)
+            def _():
+                for dma in put_back((layer, b, pl.ds(h * hb, hb),
+                                     slice(None), pl.ds(0, w))):
+                    dma.wait()
+                par_ref[1] = 0
+
+    def block(j, carry):
+        cur = (par + j) % 2
+
+        @pl.when(j + 1 < n)
+        def _next_block():
+            for dma in fetch(b, h, j + 1, 1 - cur):
+                dma.start()
+
+        @pl.when((j + 1 == n) & (g + 1 < pl.num_programs(0) * nh))
+        def _next_step():
+            for dma in fetch((g + 1) // nh, (g + 1) % nh, 0, 1 - cur):
+                dma.start()
+
+        for dma in fetch(b, h, j, cur):
+            dma.wait()
+
+        if write:
+            here = (wpos >= 0) & (wpos // bk == j)
+            for c in range(bk // w):
+                @pl.when(here & ((wpos - j * bk) // w == c))
+                def _insert(c=c):
+                    settle()
+                    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, w), 2)
+                    hit = lane == wpos - j * bk - c * w
+                    sl = (cur, slice(None), slice(None),
+                          slice(c * w, (c + 1) * w))
+                    for i, buf in enumerate((kbuf, vbuf)):
+                        tile = jnp.where(hit, cols[:, :, i:i + 1], buf[sl])
+                        buf[sl] = tile
+                        wbuf[i] = tile
+                    for dma in put_back((
+                            layer, b, pl.ds(h * hb, hb), slice(None),
+                            pl.ds(pl.multiple_of(j * bk + c * w, w), w))):
+                        dma.start()
+                    par_ref[1] = 1
+
+        k, v = kbuf[cur], vbuf[cur]                        # [hb, d, bk]
+        scores = jax.lax.dot_general(
+            q.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale    # [hb, 1, bk]
+        idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
+        scores = jnp.where(idx <= length, scores, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)                         # [hb, 1, 1]
+        p = jnp.exp(scores - m_new)                        # [hb, 1, bk]
+        m_ref[...] = m_new
+        s_ref[...] = s_ref[...] * alpha + jnp.sum(p, axis=-1,
+                                                   keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)            # [hb, 1, d]
+
+        return carry
+
+    jax.lax.fori_loop(0, n, block, 0)
+    par_ref[0] = (par + n) % 2
+    if write:
+        @pl.when(g + 1 == pl.num_programs(0) * nh)
+        def _last():
+            settle()
+    # Position 0 is visible to every slot, so s > 0.
+    o_ref[...] = (acc_ref[...] / s_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_len", "heads_per_step", "dtype", "interpret"))
+def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
+                       *, block_len: int, heads_per_step: int, dtype,
+                       interpret: bool):
+    """The one inner function every layer's call goes through: ``layer``
+    is an operand, so a decode body of any depth lowers this kernel
+    once.  ``q2``: ``[B, H, 1, d]``; ``new_kv``: ``[B, H, 2, d]`` (the
+    step's key and value rows) or ``None``; the caches whole, as
+    ``[L, B, H, d, T]``.  Returns the attention
+    output, and the two caches after it when ``new_kv`` was written."""
+    _, B, H, d, T = kt_cache.shape
+    bk, hb = block_len, heads_per_step
+    write = new_kv is not None
+
+    def row_map(b, h, *_):
+        return b, h, 0, 0
+
+    row = pl.BlockSpec((None, hb, 1, d), row_map)
+    rows = pl.BlockSpec((None, hb, 2, d), row_map)     # new key, value
+    w = min(bk, 128)
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    out = jax.ShapeDtypeStruct((B, H, 1, d), dtype)
+    cache = jax.ShapeDtypeStruct(kt_cache.shape, kt_cache.dtype)
+    buf = pltpu.VMEM((2, hb, d, bk), kt_cache.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,          # lengths, wpos, layer (SMEM)
+        grid=(B, H // hb),
+        in_specs=[row] + [rows] * write + [whole, whole],
+        out_specs=[row] + [whole, whole] * write,
+        scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]
+        + [pltpu.VMEM((2, hb, d, w), kt_cache.dtype),      # write-back
+           pltpu.SemaphoreType.DMA((2,))] * write
+        + [pltpu.SMEM((2,), jnp.int32),     # parity, write-back pending
+           pltpu.VMEM((hb, 1, 1), jnp.float32),            # max
+           pltpu.VMEM((hb, 1, 1), jnp.float32),            # sum
+           pltpu.VMEM((hb, 1, d), jnp.float32)],           # acc
+    )
+    kern = functools.partial(
+        _dense_decode_kernel, block_len=bk, num_blocks=T // bk,
+        scale=1.0 / float(np.sqrt(d)), write=write)
+    with jax.named_scope(kernel_marker("flash_decode")):
+        res = pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=[out] + [cache, cache] * write,
+            # operands: 3 scalars, q, (the new rows), the two caches
+            input_output_aliases={5: 1, 6: 2} if write else {},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary"),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=interpret,
+        )(lengths, wpos, layer, q2, *([new_kv] * write), kt_cache, vt_cache)
+    return tuple(res) if write else res[0]
+
+
+def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
+                                 new_kv=None, active=None,
+                                 dtype=jnp.float32,
+                                 block_k: Optional[int] = None,
+                                 heads_per_step: Optional[int] = None,
+                                 interpret: Optional[bool] = None):
+    """Fused :func:`autodist_tpu.serving.kv_cache.cached_attention` over
+    layer ``layer`` of the whole dense cache, reading only each slot's
+    live blocks — and, given ``new_kv``, the step's
+    :func:`~autodist_tpu.serving.kv_cache.write_token` in the same pass.
+
+    ``q``: ``[B, 1, heads, head_dim]``; ``k_cache``/``v_cache``:
+    ``[L, B, heads, T, head_dim]`` (the cache arrays themselves: no
+    slice is taken, the kernel picks the layer); ``layer``: int or
+    int32 scalar; ``lengths``: ``[B]`` int32.  Returns
+    ``[B, 1, heads, head_dim]`` in ``dtype``.
+
+    ``new_kv=(k, v)``, each ``[B, 1, heads, head_dim]``: slot ``i``'s
+    rows are written at position ``lengths[i]`` before the slot attends,
+    and ``(out, k_cache, v_cache)`` comes back, the caches updated in
+    place under ``jit`` with donation.  ``active`` (``[B]`` bool, with or
+    without ``new_kv``): a slot that is not active writes nothing and
+    reads one block; its output means nothing.
+
+    The kernel reads the lanes transposed, ``[.., head_dim, T]``.  That
+    is how a TPU lays out an array whose minor dimension is under 128
+    and whose next is a multiple of 128, so there the transposition is
+    a relabelling; on any other shape it is a copy of what it is given
+    (:func:`fused_decode_block` says which).  ``T`` must divide into
+    blocks (:func:`decode_block_len`); ``heads_per_step`` defaults to as
+    many heads of a slot as fit :data:`KV_BLOCK_BYTES`.
+    """
+    _, _, H, T, d = k_cache.shape
+    bk = decode_block_len(T, block_k)
+    if bk is None:
+        raise ValueError(
+            f"a cache lane of {T} positions does not divide into blocks "
+            f"of {int(block_k or DEFAULT_BLOCK_K)}; use "
+            "flash_decode_attention on the layer's slice (it pads a copy)")
+    hb = int(heads_per_step or _heads_per_step(
+        H, bk, d, jnp.dtype(k_cache.dtype).itemsize))
+    if H % hb:
+        raise ValueError(f"heads_per_step={hb} must divide heads={H}")
+    interp = default_interpret() if interpret is None else bool(interpret)
+    lengths = lengths.astype(jnp.int32)
+    live = lengths if active is None else jnp.where(active, lengths, 0)
+    wpos = jnp.full_like(lengths, -1) if new_kv is None else (
+        lengths if active is None else jnp.where(active, lengths, -1))
+    if new_kv is not None:
+        new_kv = jnp.concatenate(new_kv, axis=1).swapaxes(1, 2) \
+            .astype(k_cache.dtype)                 # [B, H, 2, d]
+    res = flash_decode_layer(
+        live, wpos, jnp.asarray(layer, jnp.int32).reshape(1),
+        jnp.swapaxes(q, 1, 2), new_kv, jnp.swapaxes(k_cache, 3, 4),
+        jnp.swapaxes(v_cache, 3, 4), block_len=bk, heads_per_step=hb,
+        dtype=jnp.dtype(dtype), interpret=interp)
+    if new_kv is None:
+        return jnp.swapaxes(res, 1, 2)             # [B, 1, H, d]
+    out, kt, vt = res
+    return (jnp.swapaxes(out, 1, 2), jnp.swapaxes(kt, 3, 4),
+            jnp.swapaxes(vt, 3, 4))
 
 
 def flash_decode_attention(q, k_layer, v_layer, lengths, *,
                            dtype=jnp.float32,
                            block_k: Optional[int] = None,
                            interpret: Optional[bool] = None):
-    """Drop-in fused replacement for :func:`autodist_tpu.serving.
-    kv_cache.cached_attention`.
+    """:func:`flash_decode_attention_dense` on one layer's slice.
 
-    ``q``: ``[B, 1, heads, head_dim]`` (the step's query);
-    ``k_layer``/``v_layer``: ``[B, heads, T, head_dim]`` (one layer's
-    cache slice in its native layout); ``lengths``: ``[B]`` int32.
-    Returns ``[B, 1, heads, head_dim]`` in ``dtype``.
-
-    ``interpret=None`` follows :func:`default_interpret`; ``block_k``
+    ``k_layer``/``v_layer``: ``[B, heads, T, head_dim]``.  ``block_k``
     defaults to :data:`DEFAULT_BLOCK_K` capped at the cache length.  A
     cache length that ``block_k`` does not divide is zero-padded per
-    call (a copy of the layer's cache) — size ``max_len`` to a block
-    multiple where that matters.
+    call (a copy of the layer's cache; padded positions sit above every
+    legal length, so the mask never reads them as keys) — size
+    ``max_len`` to a block multiple where that matters.
     """
-    B, _, H, d = q.shape
     T = k_layer.shape[2]
-    interp = default_interpret() if interpret is None else bool(interpret)
     bk = min(int(block_k or DEFAULT_BLOCK_K), T)
     pad = (-T) % bk
     if pad:
-        # Padded positions sit at idx >= T > any legal length, so the
-        # in-kernel mask never reads them as real keys.
         cfg = [(0, 0), (0, 0), (0, pad), (0, 0)]
         k_layer = jnp.pad(k_layer, cfg)
         v_layer = jnp.pad(v_layer, cfg)
-    scale = 1.0 / float(np.sqrt(d))
-
-    q2 = jnp.swapaxes(q, 1, 2)                 # [B, H, 1, d]
-    kern = functools.partial(_decode_kernel, block_len=bk, scale=scale,
-                             out_dtype=dtype)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,                 # lengths (SMEM)
-        grid=(B, H, (T + pad) // bk),
-        in_specs=[
-            pl.BlockSpec((1, 1, 1, d), lambda b, h, j, lens: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, lens: (b, h, j, 0)),
-            pl.BlockSpec((1, 1, bk, d), lambda b, h, j, lens: (b, h, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, 1, d),
-                               lambda b, h, j, lens: (b, h, 0, 0)),
-        scratch_shapes=carry_scratch(1, d),
-    )
-    with jax.named_scope(kernel_marker("flash_decode")):
-        out = pl.pallas_call(
-            kern,
-            grid_spec=grid_spec,
-            out_shape=jax.ShapeDtypeStruct((B, H, 1, d), dtype),
-            interpret=interp,
-        )(lengths.astype(jnp.int32), q2, k_layer, v_layer)
-    return jnp.swapaxes(out, 1, 2)             # [B, 1, H, d]
+    return flash_decode_attention_dense(
+        q, k_layer[None], v_layer[None], 0, lengths, dtype=dtype,
+        block_k=bk, interpret=interpret)
 
 
 # --------------------------------------------------------------------------- #

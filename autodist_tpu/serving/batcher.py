@@ -437,6 +437,26 @@ class ContinuousBatcher:
                      spec_accepted=slot.spec_accepted,
                      prefill_chunks=slot.prefill_chunks)
 
+    def _count_kv_blocks(self, active, steps: int):
+        """``serve/kv_blocks_attended`` over ``serve/kv_blocks_resident``
+        is the share of a dense cache that the window's decode attention
+        reads, per layer and head: a step reads a decoding slot's blocks
+        up to the one its new token lands in and one block of any other
+        slot, of ``max_len / block`` resident.  The block is the fused
+        kernel's, or the whole lane under ``cached_attention`` (share
+        1).  From the lengths this side holds; nothing is fetched."""
+        block = getattr(self.engine, "decode_block_len", None)
+        if not block or getattr(self.engine, "speculative", None):
+            return
+        lane = self.engine.max_len // block
+        first = np.array([len(s.req.prompt) + len(s.tokens) - 1 if a else 0
+                          for s, a in zip(self._slots, active)])
+        at = first + np.arange(steps)[:, None] * active    # [steps, B]
+        telemetry.counter("serve/kv_blocks_attended").inc(
+            int(np.minimum(at // block + 1, lane).sum()))
+        telemetry.counter("serve/kv_blocks_resident").inc(
+            steps * len(active) * lane)
+
     def _decode_window(self):
         """One fused decode dispatch; distribute tokens, evict terminal
         slots."""
@@ -445,6 +465,7 @@ class ContinuousBatcher:
         if not active.any():
             return
         K = self.engine.decode_steps
+        self._count_kv_blocks(active, K)
         t0 = time.perf_counter()
         tids = [s.req.trace_id for s, a in zip(self._slots, active)
                 if a and s is not None and s.req.trace_id]

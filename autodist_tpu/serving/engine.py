@@ -203,6 +203,10 @@ class ServingEngine:
         # have no grad sync or matmul-overlap ring for the training
         # kernels to replace.
         self.kernel = normalize_kernel(kernel)
+        # {"flash_decode": False} forbids the kernel; with no word on it
+        # the dense decode branch elects (below, once max_len is known).
+        decode_left_open = not (isinstance(kernel, dict)
+                                and "flash_decode" in kernel)
         attn_fn = getattr(cfg, "attention_fn", None)
         if attn_fn is not None:
             from autodist_tpu.ops.flash_attention import \
@@ -258,6 +262,25 @@ class ServingEngine:
             raise ValueError("kv_block_len must be >= 1")
         self.max_blocks = kv_cache.blocks_for(self.max_len,
                                               self.kv_block_len)
+        # ---- the dense decode attention: the fused kernel where it
+        # wins, from what can be observed here — a TPU under the
+        # programs, a lane the kernel's blocks divide, heads narrow
+        # enough for the chip to keep the positions minor-most (the
+        # kernel's view of the cache is then the array itself), a lane
+        # long enough by the chip's own readings.  Elsewhere, and on the
+        # CPU always, cached_attention.
+        self._fused_block = None    # the kernel's block, read in place
+        forced = bool(self.kernel.get("flash_decode"))
+        if self.kv_layout == "dense" and (forced or (
+                decode_left_open and jax.default_backend() == "tpu")):
+            from autodist_tpu.kernel.pallas.flash_decode import (
+                MIN_FUSED_DECODE_LEN, fused_decode_block)
+            block = fused_decode_block(self.max_len, cfg.head_dim)
+            if forced:
+                self._fused_block = block
+            elif block and self.max_len >= MIN_FUSED_DECODE_LEN:
+                self._fused_block = block
+                self.kernel = dict(self.kernel, flash_decode=True)
         # Default pool: byte parity with the dense cache (num_slots full
         # lanes) — the capacity win comes from admitting MORE slots than
         # the pool could hold at max_len, gated on free blocks.
@@ -504,7 +527,8 @@ class ServingEngine:
         this layer's k/v into the cache in place (through the block
         table under the paged layout, suppressed for inactive slots
         whose table rows hold no reservation), attend over the cache
-        slice."""
+        slice — or both at once in the fused dense kernel, where it is
+        elected."""
         from autodist_tpu.models.pipeline_lm import _flax_layer_norm
 
         cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
@@ -517,14 +541,16 @@ class ServingEngine:
                                   model_axis=axis, comm_overlap=overlap)
             q, k, v = jnp.moveaxis(qkv, -3, 0)      # [B, 1, heads, dh]
         # the cache writes wear their own scope (kv_write), so the
-        # attention scope is left for them and entered again
+        # attention scope is left for them and entered again; the fused
+        # dense kernel writes the step's rows itself, as it reads
+        fused = None if table is not None else self._fused_block
         if table is not None:
             bl = self.kv_block_len
             kc = kv_cache.paged_write_token(kc, layer, k, lengths,
                                             table, bl, write_mask=active)
             vc = kv_cache.paged_write_token(vc, layer, v, lengths,
                                             table, bl, write_mask=active)
-        else:
+        elif not fused:
             kc = kv_cache.write_token(kc, layer, k, lengths)
             vc = kv_cache.write_token(vc, layer, v, lengths)
         with telemetry.scope("attention"):
@@ -539,7 +565,17 @@ class ServingEngine:
                     out = kv_cache.paged_cached_attention(
                         q, kc[layer], vc[layer], lengths, table,
                         block_len=bl, dtype=dtype)
+            elif fused:
+                # The caches themselves, the layer an operand; a slot
+                # that is not decoding writes nothing and reads one block.
+                from autodist_tpu.kernel.pallas.flash_decode import \
+                    flash_decode_attention_dense
+                out, kc, vc = flash_decode_attention_dense(
+                    q, kc, vc, layer, lengths, new_kv=(k, v),
+                    active=active, dtype=dtype, block_k=fused)
             elif self.kernel.get("flash_decode"):
+                # forced on a shape the kernel's view of the cache would
+                # copy whole: a copy of this layer's lanes instead
                 from autodist_tpu.kernel.pallas.flash_decode import \
                     flash_decode_attention
                 out = flash_decode_attention(q, kc[layer], vc[layer],
@@ -1363,6 +1399,15 @@ class ServingEngine:
     @property
     def lengths(self):
         return np.asarray(jax.device_get(self.cache.lengths))
+
+    @property
+    def decode_block_len(self) -> Optional[int]:
+        """Positions of a dense lane that the decode attention reads or
+        skips as one: the fused kernel's block, the whole lane under
+        ``cached_attention``; ``None`` for a paged cache."""
+        if self.kv_layout != "dense":
+            return None
+        return self._fused_block or self.max_len
 
     # ------------------------------------------------------------------ #
     # HLO probe hooks (tools/hlo_probe.py --probe decode)

@@ -16,7 +16,8 @@ weights random from a seed:
    ``{"data": n}``);
 2. **serving** — 8 layers, hidden 1024, 16 heads, vocab 32768, bf16:
    ``ServingEngine`` → ``ContinuousBatcher`` → 8 requests of mixed
-   prompt length, dense and paged KV, composed attention;
+   prompt length, dense and paged KV, composed attention (the dense
+   engine's default election, the fused decode kernel, runs in 4);
 3. **parity** — the step-0 training loss and the first decoded token's
    logits against a float32 evaluation of the same functions on the
    host CPU;
@@ -408,6 +409,29 @@ def kernels_phase(*, interpret: bool, seq_len: int = 512, heads: int = 12,
             *a, dtype=bf16))(q1, kd, vd, lens)))
     require_close(ph, f"flash_decode_attention(T={T})", got, ref,
                   ATTN_FWD_RTOL)
+    # the same kernel as the engine calls it: the whole cache, a layer
+    # index, only the live blocks read (the other layer holds 1e4), the
+    # step's rows written on the way (slot 2 is not decoding)
+    k5, v5 = (jnp.stack([jnp.full_like(a, 1e4), a]) for a in (kd, vd))
+    kn, vn = rand(slots, 1, H, d), rand(slots, 1, H, d)
+    act = jnp.ones((slots,), bool).at[2].set(False)
+    put = jnp.where(act, lens, T - 1)      # slot 2's row: put back below
+    kw, vw = (kv_cache.write_token(c, 1, n, put).at[1, 2].set(c[1, 2])
+              for c, n in ((k5, kn), (v5, vn)))
+    ref = jax.jit(lambda *a: kv_cache.cached_attention(*a, dtype=bf16))(
+        q1, kw[1], vw[1], lens)
+    got, kg, vg = jax.block_until_ready(jax.jit(
+        lambda q, k, v, l, kn, vn, a:
+        flash_decode.flash_decode_attention_dense(
+            q, k, v, 1, l, new_kv=(kn, vn), active=a, dtype=bf16,
+            interpret=interpret))(q1, k5, v5, lens, kn, vn, act))
+    keep = np.asarray(act)
+    require_close(ph, f"flash_decode_attention_dense(T={T}, layer 1 of 2, "
+                      "writing)", np.asarray(got, np.float32)[keep],
+                  np.asarray(ref, np.float32)[keep], ATTN_FWD_RTOL)
+    require(bool(jnp.array_equal(kg, kw)) and bool(jnp.array_equal(vg, vw)),
+            ph, "the kernel leaves the caches as write_token does",
+            "bit-identical")
     done.append("flash_decode_attention")
 
     mb = T // bl
@@ -562,10 +586,24 @@ def multichip_phase(cfg, params, prompts, ref_tokens, *, serve_sizes: dict,
             "completed", f"{ran} in {s:.1f}s")
 
     ring_kernels_phase(devices, interpret=interpret)
+    multichip_serving(cfg, params, prompts, ref_tokens, devices,
+                      serve_sizes=serve_sizes)
 
+
+def multichip_serving(cfg, params, prompts, ref_tokens, devices, *,
+                      serve_sizes: dict) -> None:
+    """tp=2 serving with the cache on two devices and two tp=1 engines
+    on two different devices, each with the engine's own decode election
+    (on the chip: the fused kernel, on ``heads / tp`` heads inside
+    ``shard_map``), against the composed one-device engine's tokens."""
+    import jax
+
+    ph = "multichip"
+    elected = "flash_decode" if jax.default_backend() == "tpu" else None
     tp2 = serving_phase(cfg, params, prompts, label="tp2+vocab_parallel",
                         tensor_parallel=2, vocab_parallel=True,
-                        devices=devices[:2], **serve_sizes)
+                        devices=devices[:2], marker_of=elected,
+                        **serve_sizes)
     cache = tp2["engine"].cache.k
     require(devices_of(cache) == set(devices[:2])
             and cache.sharding.spec[2] == "model", ph,
@@ -577,7 +615,8 @@ def multichip_phase(cfg, params, prompts, ref_tokens, *, serve_sizes: dict,
     pair = []
     for dev in devices[:2]:
         out = serving_phase(cfg, params, prompts, label=f"tp1@{dev}",
-                            devices=[dev], **serve_sizes)
+                            devices=[dev], marker_of=elected,
+                            **serve_sizes)
         eng = out["engine"]
         where = devices_of((eng.params, eng.cache.k, eng.cache.v, eng._tok))
         require(where == {dev}, ph,
@@ -645,19 +684,21 @@ def main() -> int:
     say("serve", f"params init_s={s:.1f}")
     prompts = make_prompts(cfg.vocab_size, sizes["prefill_len"])
     paged = dict(kv_layout="paged", kv_block_len=16)
-    dense = serving_phase(cfg, params, prompts, label="dense", **sizes)
+    dense = serving_phase(cfg, params, prompts, label="dense",
+                          kernel={"flash_decode": False}, **sizes)
     serving_phase(cfg, params, prompts, label="paged", **paged, **sizes)
     serving_parity(cfg, params, prompts[0], dense["tokens"][0][0])
 
     kernels = kernels_phase(interpret=False)
     for label, marker, kw in (
-            ("dense+flash_decode", "flash_decode", {}),
+            # no kernel= at all: on the chip the dense decode elects
+            ("dense, the default election", "flash_decode", None),
             ("paged+flash_decode", "flash_decode", paged),
             ("paged+chunked+flash_prefill", "flash_prefill",
              dict(paged, prefill_chunk=32))):
+        elect = {} if kw is None else dict(kw, kernel={marker: True})
         out = serving_phase(cfg, params, prompts, label=label,
-                            kernel={marker: True}, marker_of=marker,
-                            **kw, **sizes)
+                            marker_of=marker, **elect, **sizes)
         require_mostly_identical(f"serve:{label}", out["tokens"],
                                  dense["tokens"])
     say("kernel", f"compiled (interpret=False) and agreed: {kernels}")

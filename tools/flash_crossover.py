@@ -10,14 +10,15 @@ bench self-tuner should pick the kernel.
 Usage: ``python tools/flash_crossover.py [--seqs 512,1024,2048,4096]``
 
 ``--decode`` switches to the serving-side crossover: single-query-per-
-slot shapes (one token attending over a KV cache of each ``--seqs``
-length) at ``--fill`` slot-length fractions, comparing the composed
-einsum cache attention (``serving/kv_cache.cached_attention``) against
-the Pallas flash-decode kernel.  Each point prints one provenance-
-stamped record in the bench schema, and ``--write-calibration`` merges
-the measured crossover into calibration.json's ``"kernel"`` section
-(``flash_decode_crossover_len`` / ``flash_decode_speedup``) — the
-constants ``CostModel.decode_cost`` elects the kernel by.
+slot shapes (one token attending over a dense KV cache of each
+``--seqs`` length, ``--slots`` x ``--heads`` x ``--head-dim`` in
+``--cache-dtype``) at ``--fill`` slot-length fractions and ``--blocks``
+block lengths, comparing the composed cache write and attention
+(``serving/kv_cache`` ``write_token`` + ``cached_attention``) against
+the Pallas dense flash-decode kernel on the whole cache, as the engine
+calls it.  Each
+point prints one provenance-stamped record in the bench schema; nothing
+is written (the engine's election threshold is set from such a run).
 """
 import argparse
 import json
@@ -76,7 +77,8 @@ def main():
                     help="per-step token budget: batch = tokens // seq")
     ap.add_argument("--causal", action="store_true")
     ap.add_argument("--blocks", default="128,256,512",
-                    help="flash block sizes to try (best reported)")
+                    help="flash block sizes to try (best reported; "
+                         "--decode: cache-block lengths, each reported)")
     ap.add_argument("--steps", type=int, default=10)
     ap.add_argument("--write", default="",
                     help="merge results into this flash_tuning.json "
@@ -104,13 +106,28 @@ def main():
     ap.add_argument("--fill", default="1.0,0.5",
                     help="--decode: slot-length fractions of the cache "
                          "length (the occupancy distribution decode "
-                         "actually sees)")
+                         "actually sees); 'mixed' spreads the slots "
+                         "evenly from 1/16 to 0.8 of it")
+    ap.add_argument("--layers", type=int, default=4,
+                    help="--decode: layers of the [layers, slots, heads, "
+                         "T, head_dim] cache the kernel walks")
+    ap.add_argument("--cache-dtype", default="bfloat16",
+                    help="--decode: the cache's (and the query's) type")
+    ap.add_argument("--reps", type=int, default=16,
+                    help="--decode: passes over the layers inside one "
+                         "timed program (a decode window's steps)")
+    ap.add_argument("--heads-per-step", type=int, default=0,
+                    help="--decode: heads of a slot per grid step "
+                         "(0: as many as fit the kernel's VMEM budget)")
+    ap.add_argument("--read-only", action="store_true",
+                    help="--decode: leave the step's cache write out on "
+                         "both sides (the attention alone)")
     ap.add_argument("--write-calibration", default="",
                     metavar="PATH",
-                    help="--decode: merge the measured crossover into "
+                    help="--prefill: merge the measured crossover into "
                          "this calibration.json's 'kernel' section "
-                         "(flash_decode_crossover_len / "
-                         "flash_decode_speedup)")
+                         "(flash_prefill_crossover_chunk / "
+                         "flash_prefill_speedup)")
     args = ap.parse_args()
     if args.decode:
         return _main_decode(args)
@@ -182,100 +199,116 @@ def main():
 
 
 def _main_decode(args):
-    """The ``--decode`` mode: one record per (cache length, fill)
-    point, bench-schema-shaped and provenance-stamped; the summary line
-    derives the crossover, and ``--write-calibration`` commits it."""
-    from autodist_tpu.serving.kv_cache import cached_attention
-    from autodist_tpu.kernel.pallas.flash_decode import \
-        flash_decode_attention
+    """The ``--decode`` mode: the dense decode kernel on the whole
+    ``[layers, slots, heads, T, head_dim]`` cache, as ``ServingEngine``
+    calls it (every layer of the cache in turn through the one inner
+    function, the step's rows written by the kernel, ``--reps`` passes
+    inside one program so that a dispatch is not what is timed), against
+    ``write_token`` and ``cached_attention`` on each layer's slice.  One
+    record per (cache length, fill, block length); the summary names the
+    shortest lane from which the kernel wins at every fill.  It prints and writes nothing: the election's threshold
+    (``flash_decode.MIN_FUSED_DECODE_LEN``) is set by hand from a run
+    of this on the chip."""
+    from jax import lax
+
+    from autodist_tpu.kernel.pallas import flash_decode as fd
+    from autodist_tpu.serving.kv_cache import cached_attention, write_token
     from autodist_tpu.telemetry.records import provenance
 
-    H, D, B = args.heads, args.head_dim, args.slots
-    fills = [float(f) for f in args.fill.split(",")]
+    H, D, B, L = args.heads, args.head_dim, args.slots, args.layers
+    dtype = jnp.dtype(args.cache_dtype)
+    fills = [f if f == "mixed" else float(f) for f in args.fill.split(",")]
+    blocks = [int(b) for b in args.blocks.split(",")]
+
+    def chained(attend):
+        """``--reps`` passes over every layer as a decode window makes
+        them: each layer writes a row into the (donated, carried) caches
+        and attends, its query made from the last layer's output so that
+        nothing runs side by side."""
+        def run(q, k, v, lens):
+            def one_pass(carry, _):
+                q, k, v = carry
+                for layer in range(L):
+                    out, k, v = attend(q, k, v, layer, lens)
+                    q = q + out * 1e-3
+                return (q, k, v), None
+            return lax.scan(one_pass, (q, k, v), None, length=args.reps)[0]
+        return jax.jit(run, donate_argnums=(1, 2))
+
+    def window_s(fn, q, k, v, lens):
+        """Seconds per call of ``fn`` after one to compile, and the
+        caches it hands on."""
+        out, k, v = fn(q, k, v, lens)
+        fence(out)
+        t0 = time.perf_counter()
+        for _ in range(args.steps):
+            out, k, v = fn(q, k, v, lens)
+        fence(out)
+        return (time.perf_counter() - t0) / args.steps, k, v
+
+    def composed(q, k, v, layer, lens):
+        if not args.read_only:
+            k = write_token(k, layer, q, lens)
+            v = write_token(v, layer, q, lens)
+        return cached_attention(q, k[layer], v[layer], lens,
+                                dtype=dtype), k, v
+
     records = []
     for T in [int(s) for s in args.seqs.split(",")]:
         r = np.random.RandomState(0)
-        q = jnp.asarray(r.randn(B, 1, H, D), jnp.bfloat16)
-        k = jnp.asarray(r.randn(B, H, T, D), jnp.bfloat16)
-        v = jnp.asarray(r.randn(B, H, T, D), jnp.bfloat16)
+        q = jnp.asarray(r.randn(B, 1, H, D), dtype)
+        k = jnp.asarray(r.randn(L, B, H, T, D), dtype)
+        v = jnp.asarray(r.randn(L, B, H, T, D), dtype)
         for fill in fills:
-            lengths = jnp.full((B,), max(int(T * fill) - 1, 0),
-                               jnp.int32)
-            t_einsum = timed(jax.jit(
-                lambda q, k, v, l: cached_attention(
-                    q, k, v, l, dtype=jnp.bfloat16)),
-                (q, k, v, lengths), args.steps)
-            try:
-                t_flash = timed(jax.jit(
-                    lambda q, k, v, l: flash_decode_attention(
-                        q, k, v, l, dtype=jnp.bfloat16)),
-                    (q, k, v, lengths), args.steps)
-            except Exception as e:
-                print(f"# flash decode T={T} fill={fill} failed: {e}",
-                      file=sys.stderr)
-                continue
-            rec = {
-                "metric": "flash_decode_crossover",
-                "kv_len": T, "fill": fill, "slots": B, "heads": H,
-                "head_dim": D,
-                "einsum_ms": round(t_einsum * 1e3, 4),
-                "flash_ms": round(t_flash * 1e3, 4),
-                "value": round(t_einsum / t_flash, 4),
-                "unit": "ratio", "scored": True,
-                "provenance": provenance(),
-            }
-            records.append(rec)
-            print(json.dumps(rec), flush=True)
-    wins = sorted({r["kv_len"] for r in records if r["value"] > 1.0})
-    crossover = wins[0] if wins else None
-    speedups = [r["value"] for r in records
-                if crossover is not None and r["kv_len"] >= crossover]
-    summary = {
-        "summary": (f"flash decode wins from kv_len {crossover}"
-                    if crossover is not None
-                    else "einsum wins at every measured cache length"),
+            if fill == "mixed":     # T/16 ... 0.8 T, evenly over the slots
+                lens = jnp.asarray(np.linspace(T / 16, 0.8 * T, B), jnp.int32)
+            else:
+                lens = jnp.full((B,), max(int(T * fill) - 1, 0), jnp.int32)
+            calls = args.reps * L
+            t_ref, k, v = window_s(chained(composed), q, k, v, lens)
+            live = 2 * H * int(jnp.sum(lens + 1)) * D * dtype.itemsize
+            for bk in blocks:
+                if fd.decode_block_len(T, bk) != bk:
+                    continue
+
+                def fused(q, k, v, layer, lens, bk=bk):
+                    kw = dict(dtype=dtype, block_k=bk,
+                              heads_per_step=args.heads_per_step or None)
+                    if args.read_only:
+                        return fd.flash_decode_attention_dense(
+                            q, k, v, layer, lens, **kw), k, v
+                    return fd.flash_decode_attention_dense(
+                        q, k, v, layer, lens, new_kv=(q, q), **kw)
+                t_k, k, v = window_s(chained(fused), q, k, v, lens)
+                rec = {
+                    "metric": "flash_decode_crossover",
+                    "kv_len": T, "fill": fill, "block": bk,
+                    "slots": B, "heads": H, "head_dim": D, "layers": L,
+                    "cache_dtype": dtype.name,
+                    "composed_us": round(t_ref / calls * 1e6, 2),
+                    "kernel_us": round(t_k / calls * 1e6, 2),
+                    "kernel_live_gb_per_s": round(
+                        live * calls / t_k / 1e9, 1),
+                    "value": round(t_ref / t_k, 4),
+                    "unit": "ratio", "scored": True,
+                    "provenance": provenance(),
+                }
+                records.append(rec)
+                print(json.dumps(rec), flush=True)
+    best = {}
+    for rec in records:
+        key = (rec["kv_len"], rec["fill"])
+        best[key] = max(best.get(key, 0.0), rec["value"])
+    wins = sorted(T for T in {k[0] for k in best}
+                  if all(v > 1.0 for (t, _), v in best.items() if t == T))
+    print(json.dumps({
+        "summary": (f"the dense decode kernel wins at every fill from "
+                    f"kv_len {wins[0]}" if wins
+                    else "cached_attention wins somewhere at every "
+                         "measured cache length"),
         "backend": jax.default_backend(),
         "device_kind": jax.devices()[0].device_kind,
-    }
-    print(json.dumps(summary))
-    if args.write_calibration and records:
-        if jax.default_backend() == "cpu":
-            # Interpreter timings say nothing about the TPU kernel and
-            # would mislead every chip's planning (load_calibration has
-            # no per-section provenance to filter them back out).
-            print("# refusing to write CPU-measured kernel constants "
-                  f"into {args.write_calibration}", file=sys.stderr)
-            return
-        table = {}
-        if os.path.exists(args.write_calibration):
-            try:
-                with open(args.write_calibration) as f:
-                    table = json.load(f)
-            except (OSError, ValueError):
-                table = {}
-        kern = dict(table.get("kernel", {}))
-        if crossover is not None:
-            kern["flash_decode_crossover_len"] = crossover
-            kern["flash_decode_speedup"] = round(
-                sum(speedups) / len(speedups), 3)
-        else:
-            # Flash never won: push the crossover past every measured
-            # length so the cost model stops electing it in this range.
-            kern["flash_decode_crossover_len"] = 2 * max(
-                r["kv_len"] for r in records)
-        table["kernel"] = kern
-        meta = dict(table.get("meta", {}))
-        meta["kernel_source"] = (
-            f"tools/flash_crossover.py --decode on "
-            f"{jax.devices()[0].device_kind} "
-            f"({provenance().get('git_sha', '')[:12]})")
-        table["meta"] = meta
-        tmp = args.write_calibration + ".tmp"
-        with open(tmp, "w") as f:
-            json.dump(table, f, indent=1)
-        os.replace(tmp, args.write_calibration)
-        print(f"# wrote kernel section to {args.write_calibration}",
-              file=sys.stderr)
+    }))
 
 
 def _main_prefill(args):
