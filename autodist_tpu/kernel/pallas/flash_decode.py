@@ -14,9 +14,10 @@ The **dense** kernel (:func:`flash_decode_attention_dense`) is what
 reads only each slot's live blocks (a block above ``lengths[slot]`` is
 neither fetched nor computed), handles all the heads of a slot that
 fit VMEM in one grid step, and multiplies the cache's own bf16 tiles.
-It reads the lanes transposed, ``[.., d, T]``: that is how the TPU
-lays out a cache of heads narrower than its 128 lanes, so the kernel's
-view of the array is the array (:func:`fused_decode_block`).  The
+It reads the cache as the TPU lays it out, so the kernel's view of the
+array is the array (:func:`fused_decode_block`): the lanes transposed,
+``[.., d, T]`` tiles, for heads narrower than the chip's 128 lanes, and
+row-major ``[.., T, d]`` tiles for heads of 128 and wider.  The
 **paged** kernel walks a slot's block table one ``[block_len, d]`` pool
 block per grid step (:func:`online_softmax_step`, shared with the paged
 prefill kernel).
@@ -141,15 +142,26 @@ def decode_block_len(max_len: int, block_k: Optional[int] = None):
 def fused_decode_block(max_len: int, head_dim: int):
     """The block length with which the dense kernel reads a
     ``[L, B, H, max_len, head_dim]`` cache in place, or ``None`` where
-    it cannot: the lane has to divide into blocks, and the TPU has to
-    keep the array with the positions minor-most, as it
-    does when ``head_dim`` is under its 128 lanes and ``max_len`` a
-    multiple of them; the kernel's transposed view is then the array
-    itself and otherwise a copy of all of it."""
+    it cannot: the lane has to divide into blocks, and the kernel's view
+    has to be the array as the TPU keeps it.  Heads under the chip's 128
+    lanes it keeps with the positions minor-most when ``max_len`` is a
+    multiple of 128, and the kernel reads ``[d, block]`` tiles; heads of
+    a multiple of 128 it keeps row-major, and the kernel reads
+    ``[block, d]`` tiles (:func:`rows_layout`).  Any other shape would
+    be a copy of all of it."""
     bk = decode_block_len(max_len)
-    if bk is None or head_dim >= 128 or max_len % 128:
+    if bk is None:
         return None
-    return bk
+    if rows_layout(head_dim):
+        return bk if head_dim % 128 == 0 else None
+    return bk if max_len % 128 == 0 else None
+
+
+def rows_layout(head_dim: int) -> bool:
+    """Whether the dense kernel walks ``[block, d]`` tiles of the cache
+    as stored (heads of 128 and wider) or ``[d, block]`` tiles of its
+    transposed view (narrower heads)."""
+    return head_dim >= 128
 
 
 def _heads_per_step(heads: int, block_len: int, d: int, itemsize: int):
@@ -164,11 +176,13 @@ def _heads_per_step(heads: int, block_len: int, d: int, itemsize: int):
 
 def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
                          block_len: int, num_blocks: int, scale: float,
-                         write: bool):
+                         write: bool, rows: bool):
     """One (slot, head group) grid step: walk the slot's live blocks of
-    the TRANSPOSED lane, ``[hb, d, bk]`` tiles with the positions on the
-    lanes (the layout a TPU keeps a cache of narrow heads in), with the
-    kernel's own double-buffered DMA.  Block ``j`` holds positions
+    the lane with the kernel's own double-buffered DMA — of the
+    TRANSPOSED lane, ``[hb, d, bk]`` tiles with the positions on the
+    lanes (the layout a TPU keeps a cache of narrow heads in), or with
+    ``rows`` of the lane as stored, ``[hb, bk, d]`` tiles (heads of 128
+    and wider).  Block ``j`` holds positions
     ``[j*bk, (j+1)*bk)``; the last live block is ``lengths[slot] // bk``
     (position ``lengths`` is this step's token), so the trip count is
     the slot's own and a dead block costs nothing.  The block after the
@@ -178,9 +192,10 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
 
     With ``write`` the step's new key and value rows are put into the
     block that holds position ``wpos[slot]`` while it is in VMEM, before
-    the products, and the 128 positions around it go back to the cache
-    (the aliased outputs): the cache write of the step, without a pass
-    of its own.  ``wpos < 0`` writes nothing.
+    the products, and the positions around it (128 columns; with
+    ``rows`` one sublane tile of rows) go back to the cache (the aliased
+    outputs): the cache write of the step, without a pass of its own.
+    ``wpos < 0`` writes nothing.
 
     Products take the cache's own tiles (bf16 stays bf16) with float32
     results; the running max, sum and accumulator are float32."""
@@ -191,16 +206,22 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
         (kt_hbm, vt_hbm, o_ref,
          kbuf, vbuf, sem, par_ref, m_ref, s_ref, acc_ref) = refs
     bk = block_len
-    hb, d = kbuf.shape[1], kbuf.shape[2]
+    hb, d = kbuf.shape[1], kbuf.shape[3 if rows else 2]
     b, h, nh = pl.program_id(0), pl.program_id(1), pl.num_programs(1)
     g = b * nh + h
     layer = layer_ref[0]
     length = len_ref[b]
     n = jnp.minimum(length // bk, num_blocks - 1) + 1      # live blocks
 
+    def span(start, size):
+        """``size`` positions from ``start``: the last two indices of a
+        cache or a buffer, after its heads."""
+        pos = pl.ds(start, size)
+        return (pos, slice(None)) if rows else (slice(None), pos)
+
     def fetch(slot, group, j, buf):
-        at = (layer, slot, pl.ds(group * hb, hb), slice(None),
-              pl.ds(pl.multiple_of(j * bk, bk), bk))
+        at = (layer, slot, pl.ds(group * hb, hb),
+              *span(pl.multiple_of(j * bk, bk), bk))
         return (pltpu.make_async_copy(kt_hbm.at[at], kbuf.at[buf],
                                       sem.at[0, buf]),
                 pltpu.make_async_copy(vt_hbm.at[at], vbuf.at[buf],
@@ -220,10 +241,14 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
     acc_ref[...] = jnp.zeros_like(acc_ref)
     q = q_ref[...]                                         # [hb, 1, d]
     if write:
-        # the new rows as columns, [hb, d, 2] (key, value)
-        cols = jnp.swapaxes(new_ref[...], 1, 2).astype(kbuf.dtype)
+        # the new key and value rows [hb, 2, d]; as columns, [hb, d, 2],
+        # for the transposed tiles
+        new = new_ref[...].astype(kbuf.dtype)
+        if not rows:
+            new = jnp.swapaxes(new, 1, 2)
         wpos = wpos_ref[b]
-        w = wbuf.shape[-1]              # positions written back as one
+        # positions written back as one
+        w = wbuf.shape[2 if rows else 3]
 
         def put_back(at):
             return (pltpu.make_async_copy(wbuf.at[0], kt_out.at[at],
@@ -235,7 +260,7 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
             @pl.when(par_ref[1] == 1)
             def _():
                 for dma in put_back((layer, b, pl.ds(h * hb, hb),
-                                     slice(None), pl.ds(0, w))):
+                                     *span(0, w))):
                     dma.wait()
                 par_ref[1] = 0
 
@@ -257,27 +282,44 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
 
         if write:
             here = (wpos >= 0) & (wpos // bk == j)
-            for c in range(bk // w):
-                @pl.when(here & ((wpos - j * bk) // w == c))
-                def _insert(c=c):
-                    settle()
-                    lane = jax.lax.broadcasted_iota(jnp.int32, (1, 1, w), 2)
-                    hit = lane == wpos - j * bk - c * w
-                    sl = (cur, slice(None), slice(None),
-                          slice(c * w, (c + 1) * w))
-                    for i, buf in enumerate((kbuf, vbuf)):
-                        tile = jnp.where(hit, cols[:, :, i:i + 1], buf[sl])
-                        buf[sl] = tile
-                        wbuf[i] = tile
-                    for dma in put_back((
-                            layer, b, pl.ds(h * hb, hb), slice(None),
-                            pl.ds(pl.multiple_of(j * bk + c * w, w), w))):
-                        dma.start()
-                    par_ref[1] = 1
 
-        k, v = kbuf[cur], vbuf[cur]                        # [hb, d, bk]
+            def insert(off, sl):
+                """Put the new rows at ``wpos`` into the ``w`` positions
+                from ``off`` of this block (``sl``: their index in a
+                buffer), and send those back to the cache."""
+                settle()
+                pos = jax.lax.broadcasted_iota(
+                    jnp.int32, (1, w, 1) if rows else (1, 1, w),
+                    1 if rows else 2)
+                hit = pos == wpos - j * bk - off
+                for i, buf in enumerate((kbuf, vbuf)):
+                    row = new[:, i:i + 1, :] if rows else new[:, :, i:i + 1]
+                    tile = jnp.where(hit, row, buf[sl])
+                    buf[sl] = tile
+                    wbuf[i] = tile
+                for dma in put_back((
+                        layer, b, pl.ds(h * hb, hb),
+                        *span(pl.multiple_of(j * bk + off, w), w))):
+                    dma.start()
+                par_ref[1] = 1
+
+            if rows:
+                # a sublane offset may be dynamic: one insertion
+                @pl.when(here)
+                def _insert():
+                    off = pl.multiple_of((wpos - j * bk) // w * w, w)
+                    insert(off, (cur, slice(None), *span(off, w)))
+            else:
+                # a lane offset may not: one branch per 128 columns
+                for c in range(bk // w):
+                    @pl.when(here & ((wpos - j * bk) // w == c))
+                    def _insert(c=c):
+                        insert(c * w, (cur, slice(None), *span(c * w, w)))
+
+        k, v = kbuf[cur], vbuf[cur]         # [hb, d, bk]; rows: [hb, bk, d]
         scores = jax.lax.dot_general(
-            q.astype(k.dtype), k, (((2,), (1,)), ((0,), (0,))),
+            q.astype(k.dtype), k,
+            (((2,), (2 if rows else 1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale    # [hb, 1, bk]
         idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
         scores = jnp.where(idx <= length, scores, NEG_INF)
@@ -289,7 +331,8 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
         s_ref[...] = s_ref[...] * alpha + jnp.sum(p, axis=-1,
                                                    keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((2,), (2,)), ((0,), (0,))),
+            p.astype(v.dtype), v,
+            (((2,), (1 if rows else 2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)            # [hb, 1, d]
 
         return carry
@@ -305,17 +348,21 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_len", "heads_per_step", "dtype", "interpret"))
+    "block_len", "heads_per_step", "dtype", "interpret", "rows"))
 def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
                        *, block_len: int, heads_per_step: int, dtype,
-                       interpret: bool):
+                       interpret: bool, rows: bool = False):
     """The one inner function every layer's call goes through: ``layer``
     is an operand, so a decode body of any depth lowers this kernel
     once.  ``q2``: ``[B, H, 1, d]``; ``new_kv``: ``[B, H, 2, d]`` (the
     step's key and value rows) or ``None``; the caches whole, as
-    ``[L, B, H, d, T]``.  Returns the attention
-    output, and the two caches after it when ``new_kv`` was written."""
-    _, B, H, d, T = kt_cache.shape
+    ``[L, B, H, d, T]`` (with ``rows`` as ``[L, B, H, T, d]``).  Returns
+    the attention output, and the two caches after it when ``new_kv``
+    was written."""
+    if rows:
+        _, B, H, T, d = kt_cache.shape
+    else:
+        _, B, H, d, T = kt_cache.shape
     bk, hb = block_len, heads_per_step
     write = new_kv is not None
 
@@ -323,19 +370,23 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
         return b, h, 0, 0
 
     row = pl.BlockSpec((None, hb, 1, d), row_map)
-    rows = pl.BlockSpec((None, hb, 2, d), row_map)     # new key, value
-    w = min(bk, 128)
+    pair = pl.BlockSpec((None, hb, 2, d), row_map)     # new key, value
+    # what goes back to the cache around the new position: 128 columns
+    # of the transposed lane; of the lane as stored, one sublane tile of
+    # rows (8 of 32 bits, 16 of 16, 32 of 8)
+    w = min(bk, 32 // kt_cache.dtype.itemsize if rows else 128)
+    tile = (lambda n: (hb, n, d)) if rows else (lambda n: (hb, d, n))
     whole = pl.BlockSpec(memory_space=pl.ANY)
     out = jax.ShapeDtypeStruct((B, H, 1, d), dtype)
     cache = jax.ShapeDtypeStruct(kt_cache.shape, kt_cache.dtype)
-    buf = pltpu.VMEM((2, hb, d, bk), kt_cache.dtype)
+    buf = pltpu.VMEM((2, *tile(bk)), kt_cache.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,          # lengths, wpos, layer (SMEM)
         grid=(B, H // hb),
-        in_specs=[row] + [rows] * write + [whole, whole],
+        in_specs=[row] + [pair] * write + [whole, whole],
         out_specs=[row] + [whole, whole] * write,
         scratch_shapes=[buf, buf, pltpu.SemaphoreType.DMA((2, 2))]
-        + [pltpu.VMEM((2, hb, d, w), kt_cache.dtype),      # write-back
+        + [pltpu.VMEM((2, *tile(w)), kt_cache.dtype),      # write-back
            pltpu.SemaphoreType.DMA((2,))] * write
         + [pltpu.SMEM((2,), jnp.int32),     # parity, write-back pending
            pltpu.VMEM((hb, 1, 1), jnp.float32),            # max
@@ -344,7 +395,7 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
     )
     kern = functools.partial(
         _dense_decode_kernel, block_len=bk, num_blocks=T // bk,
-        scale=1.0 / float(np.sqrt(d)), write=write)
+        scale=1.0 / float(np.sqrt(d)), write=write, rows=rows)
     with jax.named_scope(kernel_marker("flash_decode")):
         res = pl.pallas_call(
             kern,
@@ -384,11 +435,13 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     without ``new_kv``): a slot that is not active writes nothing and
     reads one block; its output means nothing.
 
-    The kernel reads the lanes transposed, ``[.., head_dim, T]``.  That
-    is how a TPU lays out an array whose minor dimension is under 128
-    and whose next is a multiple of 128, so there the transposition is
-    a relabelling; on any other shape it is a copy of what it is given
-    (:func:`fused_decode_block` says which).  ``T`` must divide into
+    For heads under 128 the kernel reads the lanes transposed,
+    ``[.., head_dim, T]``.  That is how a TPU lays out an array whose
+    minor dimension is under 128 and whose next is a multiple of 128, so
+    there the transposition is a relabelling; heads of 128 and wider it
+    reads as stored (:func:`rows_layout`).  On any other shape its view
+    is a copy of what it is given (:func:`fused_decode_block` says
+    which).  ``T`` must divide into
     blocks (:func:`decode_block_len`); ``heads_per_step`` defaults to as
     many heads of a slot as fit :data:`KV_BLOCK_BYTES`.
     """
@@ -411,16 +464,17 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     if new_kv is not None:
         new_kv = jnp.concatenate(new_kv, axis=1).swapaxes(1, 2) \
             .astype(k_cache.dtype)                 # [B, H, 2, d]
+    rows = rows_layout(d)
+    view = (lambda c: c) if rows else (lambda c: jnp.swapaxes(c, 3, 4))
     res = flash_decode_layer(
         live, wpos, jnp.asarray(layer, jnp.int32).reshape(1),
-        jnp.swapaxes(q, 1, 2), new_kv, jnp.swapaxes(k_cache, 3, 4),
-        jnp.swapaxes(v_cache, 3, 4), block_len=bk, heads_per_step=hb,
-        dtype=jnp.dtype(dtype), interpret=interp)
+        jnp.swapaxes(q, 1, 2), new_kv, view(k_cache), view(v_cache),
+        block_len=bk, heads_per_step=hb, dtype=jnp.dtype(dtype),
+        interpret=interp, rows=rows)
     if new_kv is None:
         return jnp.swapaxes(res, 1, 2)             # [B, 1, H, d]
     out, kt, vt = res
-    return (jnp.swapaxes(out, 1, 2), jnp.swapaxes(kt, 3, 4),
-            jnp.swapaxes(vt, 3, 4))
+    return jnp.swapaxes(out, 1, 2), view(kt), view(vt)
 
 
 def flash_decode_attention(q, k_layer, v_layer, lengths, *,

@@ -21,6 +21,63 @@ import jax.numpy as jnp
 import numpy as np
 
 
+@dataclasses.dataclass(frozen=True)
+class BlockSpec:
+    """What one layer of the stack IS, said once: the serving engine's
+    prefill, decode and chunk layers and the pipelined LM's
+    ``_tp_encoder_layer`` all read it (``cfg.block``).  The default is
+    the block the flax :class:`EncoderLayer` defines — LayerNorm after
+    each residual add, learned positions, a two-matrix GELU MLP, biases,
+    the head tied to the embedding, one pass over the layers.
+
+    * ``norm`` ``"layernorm"`` | ``"rmsnorm"`` (scale only), at
+      ``norm_eps``; ``norm_placement`` ``"post"`` (``LN(x + f(x))``) or
+      ``"sandwich"`` (``x + N(f(N(x)))``: a norm before AND after each
+      sub-block, four a layer).
+    * ``positions`` ``"learned"`` (a table added to the embedding) or
+      ``"rope"`` (rotate-half rotary at ``rope_theta`` on q and k inside
+      attention; the key is rotated before it is cached).
+    * ``ffn`` ``"gelu"`` (``wo(gelu(wi x))``) or ``"swiglu"``
+      (``wo(silu(gate x) * (up x))``, gate and up stacked in ``wi``).
+    * ``bias`` — whether the projections carry biases.
+    * ``tied_head`` — logits from the embedding table, or from
+      ``shared["lm_head"]``.
+    * ``loop_steps`` ``T`` — the layers run ``T`` times with the same
+      weights; the final norm closes every pass (it is pass ``u``'s
+      output and pass ``u + 1``'s input), the head reads the last, and
+      the serving cache holds ``T * num_layers`` layers, pass ``u``'s
+      layer ``l`` at ``u * num_layers + l``.  ``exit_threshold`` is the
+      published early-exit knob: at 1.0 every token leaves at pass
+      ``T``, which is all the engine runs.
+    """
+
+    norm: str = "layernorm"
+    norm_placement: str = "post"
+    norm_eps: float = 1e-6
+    positions: str = "learned"
+    rope_theta: float = 10000.0
+    ffn: str = "gelu"
+    bias: bool = True
+    tied_head: bool = True
+    loop_steps: int = 1
+    exit_threshold: float = 1.0
+
+    def __post_init__(self):
+        for name, allowed in (("norm", ("layernorm", "rmsnorm")),
+                              ("norm_placement", ("post", "sandwich")),
+                              ("positions", ("learned", "rope")),
+                              ("ffn", ("gelu", "swiglu"))):
+            if getattr(self, name) not in allowed:
+                raise ValueError(f"BlockSpec.{name}={getattr(self, name)!r}"
+                                 f": one of {allowed}")
+        if self.loop_steps < 1:
+            raise ValueError("BlockSpec.loop_steps must be >= 1")
+
+    @property
+    def is_default(self) -> bool:
+        return self == BlockSpec()
+
+
 @dataclasses.dataclass(unsafe_hash=True)
 class TransformerConfig:
     vocab_size: int = 30522
@@ -44,6 +101,10 @@ class TransformerConfig:
     # additionally rejects the mismatch statically at trace time.
     position_fn: Optional[Callable] = None
     causal: bool = False
+    # What a layer is (norms, positions, FFN, biases, head, loops).  The
+    # flax modules below are the default block only; the pipelined LM's
+    # ``_tp_encoder_layer`` and the serving engine read the spec.
+    block: BlockSpec = BlockSpec()
 
     @property
     def head_dim(self) -> int:

@@ -30,6 +30,7 @@ the entry-point conveniences.
 from __future__ import annotations
 
 import dataclasses
+import weakref
 from typing import Optional
 
 import jax
@@ -41,9 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from autodist_tpu import const, telemetry
 from autodist_tpu.serving import kv_cache
 from autodist_tpu.utils.stack_room import FirstCallWithRoom
-from autodist_tpu.parallel.tensor import (column_parallel,
-                                          normalize_comm_overlap,
-                                          row_parallel, vocab_pad,
+from autodist_tpu.parallel.tensor import (normalize_comm_overlap, vocab_pad,
                                           vocab_parallel_embedding,
                                           vocab_parallel_greedy_token)
 
@@ -234,6 +233,23 @@ class ServingEngine:
             raise ValueError(
                 "serving requires dropout_rate == "
                 "attention_dropout_rate == 0 (inference mode)")
+        # ---- the block (cfg.block): every layer function below reads
+        # it; the default is the post-LN block and its programs --------
+        spec = cfg.block
+        if spec.exit_threshold != 1.0:
+            raise ValueError(
+                f"exit_threshold={spec.exit_threshold}: the engine runs "
+                f"all {spec.loop_steps} passes for every token, which is "
+                "what the threshold 1.0 selects; adaptive exit depth "
+                "(the cache rows of skipped passes) is not served")
+        if not spec.is_default and int(tensor_parallel) > 1:
+            raise ValueError(
+                f"tensor_parallel={tensor_parallel} with a non-default "
+                f"block ({spec}): the Megatron rule tables name the "
+                "default block's leaves only")
+        # the cache holds every pass's keys and values: a layer's input
+        # differs from pass to pass, so its projections do too
+        self.cache_layers = cfg.num_layers * spec.loop_steps
         tp = int(tensor_parallel)
         if tp < 1:
             raise ValueError("tensor_parallel must be >= 1")
@@ -380,7 +396,7 @@ class ServingEngine:
         self._sample_seeds = np.zeros((self.num_slots,), np.int32)
         if self.kv_layout == "paged":
             cache = kv_cache.init_paged_cache(
-                cfg.num_layers, self.num_slots, cfg.num_heads,
+                self.cache_layers, self.num_slots, cfg.num_heads,
                 cfg.head_dim, self.max_len,
                 block_len=self.kv_block_len,
                 num_blocks=self.kv_num_blocks, dtype=cfg.dtype)
@@ -417,7 +433,7 @@ class ServingEngine:
             self._emit_block_gauges()
         else:
             cache = kv_cache.init_cache(
-                cfg.num_layers, self.num_slots, cfg.num_heads,
+                self.cache_layers, self.num_slots, cfg.num_heads,
                 cfg.head_dim, self.max_len,
                 dtype=cfg.dtype)
             self._allocator = None
@@ -431,6 +447,10 @@ class ServingEngine:
         if self._device is not None:
             cache = jax.device_put(cache, self._device)
         self.cache = cache
+        telemetry.gauge("engine/cache_layers").set(self.cache_layers)
+        telemetry.gauge("engine/kv_bytes_per_token").set(
+            2 * self.cache_layers * cfg.num_heads * cfg.head_dim
+            * jnp.dtype(cfg.dtype).itemsize)
 
         self._prefill_jit = (self._build_chunk_prefill()
                              if self.prefill_chunk is not None
@@ -473,6 +493,18 @@ class ServingEngine:
             from autodist_tpu.parallel._spmd import emit_kernel_gauges
             emit_kernel_gauges(gauges)
 
+    def __setattr__(self, name, value):
+        """Handing the engine one of its own methods back (a caller that
+        wrapped ``prefill`` or ``decode_window`` to observe them, and
+        restores them) removes the override: stored, the bound method
+        would tie the engine to itself, and its device memory to the
+        cycle collector (see :meth:`_wrap`)."""
+        if getattr(value, "__self__", None) is self and getattr(
+                value, "__func__", None) is getattr(type(self), name, None):
+            self.__dict__.pop(name, None)
+        else:
+            object.__setattr__(self, name, value)
+
     # ------------------------------------------------------------------ #
     # constructors from the training stack
     # ------------------------------------------------------------------ #
@@ -499,17 +531,20 @@ class ServingEngine:
     # ------------------------------------------------------------------ #
     @telemetry.scope("embed")
     def _embed(self, shared, tokens, positions):
-        """Token + position embedding for ``[B, S]`` token ids at
-        per-token ``positions`` (``[B, S]`` or a static ``[S]``)."""
+        """Token (+ learned position) embedding for ``[B, S]`` token ids
+        at per-token ``positions`` (``[B, S]`` or a static ``[S]``); a
+        rotary block's positions enter inside attention instead."""
         cfg = self.cfg
         x = vocab_parallel_embedding(
             tokens, shared["embedding"], model_axis=self._axis
             if self.vocab_parallel else None,
             comm_overlap=self.comm_overlap).astype(cfg.dtype)
+        if cfg.block.positions != "learned":
+            return x
         pos = jnp.take(shared["pos_embed"], positions, axis=0)
         return x + pos.astype(cfg.dtype)
 
-    def _layer_prefill(self, chunk, x, mask):
+    def _layer_prefill(self, chunk, x, mask, positions):
         """One encoder layer over the whole prompt — the training
         :func:`~autodist_tpu.models.pipeline_lm._tp_encoder_layer`
         itself (``return_kv=True`` hands back the layer's k/v
@@ -519,7 +554,7 @@ class ServingEngine:
 
         return _tp_encoder_layer(self.cfg, chunk, x, mask, self._axis,
                                  comm_overlap=self.comm_overlap,
-                                 return_kv=True)
+                                 return_kv=True, positions=positions)
 
     def _layer_decode(self, chunk, x, kc, vc, layer, lengths, table=None,
                       active=None):
@@ -528,18 +563,15 @@ class ServingEngine:
         table under the paged layout, suppressed for inactive slots
         whose table rows hold no reservation), attend over the cache
         slice — or both at once in the fused dense kernel, where it is
-        elected."""
-        from autodist_tpu.models.pipeline_lm import _flax_layer_norm
+        elected.  ``layer`` is the CACHE layer (an int, or traced under
+        a looped stack); the sub-blocks around the attention are the
+        pipelined LM's own (``attention_inputs`` .. ``ffn_residual``)."""
+        from autodist_tpu.models import pipeline_lm as lm
 
         cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
         dtype = cfg.dtype
-        att = chunk["attention"]
-        x = x.astype(dtype)
-        with telemetry.scope("attention"):
-            qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
-                                  att["qkv"]["bias"].astype(dtype),
-                                  model_axis=axis, comm_overlap=overlap)
-            q, k, v = jnp.moveaxis(qkv, -3, 0)      # [B, 1, heads, dh]
+        x, q, k, v = lm.attention_inputs(cfg, chunk, x, lengths[:, None],
+                                         axis, overlap)  # [B, 1, heads, dh]
         # the cache writes wear their own scope (kv_write), so the
         # attention scope is left for them and entered again; the fused
         # dense kernel writes the step's rows itself, as it reads
@@ -583,20 +615,8 @@ class ServingEngine:
             else:
                 out = kv_cache.cached_attention(q, kc[layer], vc[layer],
                                                 lengths, dtype=dtype)
-            a = row_parallel(out, att["out"]["kernel"].astype(dtype),
-                             att["out"]["bias"].astype(dtype),
-                             model_axis=axis, axes=2, comm_overlap=overlap)
-        x = _flax_layer_norm(x + a, chunk["ln_attention"], dtype)
-        with telemetry.scope("mlp"):
-            h = column_parallel(
-                x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
-                chunk["mlp"]["wi"]["bias"].astype(dtype),
-                model_axis=axis, comm_overlap=overlap)
-            h = jax.nn.gelu(h)
-            m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
-                             chunk["mlp"]["wo"]["bias"].astype(dtype),
-                             model_axis=axis, comm_overlap=overlap)
-        return _flax_layer_norm(x + m, chunk["ln_mlp"], dtype), kc, vc
+        x = lm.attention_residual(cfg, chunk, x, out, axis, overlap)
+        return lm.ffn_residual(cfg, chunk, x, axis, overlap), kc, vc
 
     def _layer_chunk(self, chunk, x, kc, vc, layer, starts, table, write):
         """One encoder layer for a ``[B, C]`` token *window* against the
@@ -607,17 +627,13 @@ class ServingEngine:
         queries over the cache — which now holds every earlier position
         AND this window's own rows (write-then-attend, the decode
         step's ordering), masked causally at ``key <= starts + row``."""
-        from autodist_tpu.models.pipeline_lm import _flax_layer_norm
+        from autodist_tpu.models import pipeline_lm as lm
 
         cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
         dtype = cfg.dtype
-        att = chunk["attention"]
-        x = x.astype(dtype)
-        with telemetry.scope("attention"):
-            qkv = column_parallel(x, att["qkv"]["kernel"].astype(dtype),
-                                  att["qkv"]["bias"].astype(dtype),
-                                  model_axis=axis, comm_overlap=overlap)
-            q, k, v = jnp.moveaxis(qkv, -3, 0)      # [B, C, heads, dh]
+        positions = starts[:, None] + jnp.arange(x.shape[1])[None, :]
+        x, q, k, v = lm.attention_inputs(cfg, chunk, x, positions, axis,
+                                         overlap)        # [B, C, heads, dh]
         kc, vc = write(kc, vc, k, v)                # scope: kv_write
         with telemetry.scope("attention"):
             if table is not None:
@@ -635,30 +651,44 @@ class ServingEngine:
             else:
                 out = kv_cache.chunk_attention(q, kc[layer], vc[layer],
                                                starts, dtype=dtype)
-            a = row_parallel(out, att["out"]["kernel"].astype(dtype),
-                             att["out"]["bias"].astype(dtype),
-                             model_axis=axis, axes=2, comm_overlap=overlap)
-        x = _flax_layer_norm(x + a, chunk["ln_attention"], dtype)
-        with telemetry.scope("mlp"):
-            h = column_parallel(
-                x, chunk["mlp"]["wi"]["kernel"].astype(dtype),
-                chunk["mlp"]["wi"]["bias"].astype(dtype),
-                model_axis=axis, comm_overlap=overlap)
-            h = jax.nn.gelu(h)
-            m = row_parallel(h, chunk["mlp"]["wo"]["kernel"].astype(dtype),
-                             chunk["mlp"]["wo"]["bias"].astype(dtype),
-                             model_axis=axis, comm_overlap=overlap)
-        return _flax_layer_norm(x + m, chunk["ln_mlp"], dtype), kc, vc
+        x = lm.attention_residual(cfg, chunk, x, out, axis, overlap)
+        return lm.ffn_residual(cfg, chunk, x, axis, overlap), kc, vc
+
+    def _run_layers(self, shared, stages, x, kc, vc, layer_fn):
+        """Every layer of the stack over ``(x, kc, vc)``, once or — a
+        looped stack — ``loop_steps`` times
+        (:func:`~autodist_tpu.models.pipeline_lm.run_stack`).
+        ``layer_fn(chunk, x, kc, vc, l, cache_layer)``: ``l`` is the
+        weights' layer (static), and ``cache_layer`` where its keys and
+        values live, ``u * num_layers + l`` (traced for a looped stack,
+        ``l`` for one pass: today's program)."""
+        from autodist_tpu.models.pipeline_lm import run_stack
+
+        L = self.cfg.num_layers
+
+        def layers(u, carry):
+            x, kc, vc = carry
+            for l in range(L):
+                chunk = jax.tree.map(lambda p: p[l], stages)
+                x, kc, vc = layer_fn(chunk, x, kc, vc, l, u * L + l)
+            return x, kc, vc
+
+        return run_stack(self.cfg, shared, (x, kc, vc), layers)
+
+    def _head(self, shared, h):
+        """``(rows, table)`` of the output projection for ``[B, H]``
+        last-position hidden states: the final norm of the rows (the
+        training loss head's; a looped stack's last pass has applied it)
+        and the tied or untied table."""
+        from autodist_tpu.models.pipeline_lm import head_rows, head_table
+
+        return head_rows(self.cfg, shared, h), head_table(self.cfg, shared)
 
     def _greedy(self, shared, h):
-        """Next token from ``[B, H]`` last-position hidden states (the
-        training loss head's ``_layer_norm`` + tied unembedding)."""
-        from autodist_tpu.models.pipeline_lm import _layer_norm
-
-        x = _layer_norm(h, shared["ln_final_scale"],
-                        shared["ln_final_bias"])
+        """Next token from ``[B, H]`` last-position hidden states."""
+        x, table = self._head(shared, h)
         return vocab_parallel_greedy_token(
-            x, shared["embedding"], vocab_size=self.cfg.vocab_size,
+            x, table, vocab_size=self.cfg.vocab_size,
             model_axis=self._axis if self.vocab_parallel else None)
 
     @telemetry.scope("lm_head")
@@ -670,14 +700,12 @@ class ServingEngine:
         run-alone, and against the sequential reference."""
         if self.temperature == 0.0:
             return self._greedy(shared, h)
-        from autodist_tpu.models.pipeline_lm import _layer_norm
         from autodist_tpu.parallel.tensor import \
             vocab_parallel_sample_token
 
-        x = _layer_norm(h, shared["ln_final_scale"],
-                        shared["ln_final_bias"])
+        x, table = self._head(shared, h)
         return vocab_parallel_sample_token(
-            x, shared["embedding"], vocab_size=self.cfg.vocab_size,
+            x, table, vocab_size=self.cfg.vocab_size,
             seeds=seeds, positions=positions,
             temperature=self.temperature, top_k=self.top_k,
             model_axis=self._axis if self.vocab_parallel else None)
@@ -693,7 +721,13 @@ class ServingEngine:
         ``(k, v)``.  The first call, which traces and lowers these
         unrolled programs, gets stack room of its own: where it stands
         on the interpreter's frame stack otherwise decides whether
-        lowering takes half a second or twenty (``utils/stack_room``)."""
+        lowering takes half a second or twenty (``utils/stack_room``).
+
+        The builders hand ``fn`` a weak proxy of the engine: a program
+        that held the engine that holds it would be a cycle, and an
+        engine in a cycle gives its parameters and cache back only when
+        the cycle collector finds it — never, on a heap its owner has
+        frozen (``gc.freeze``), as a benchmark does before it times."""
         if self.mesh is None:
             return FirstCallWithRoom(jax.jit(fn, donate_argnums=(1, 2)))
         cspec = kv_cache.cache_spec()
@@ -706,9 +740,14 @@ class ServingEngine:
         return FirstCallWithRoom(jax.jit(sm, donate_argnums=(1, 2)))
 
     def _build_prefill(self):
-        L, S = self.cfg.num_layers, self.prefill_len
+        self = weakref.proxy(self)      # see _wrap: no cycle through jit
+        S = self.prefill_len
         paged = self.kv_layout == "paged"
         prefix = self.prefix_caching
+        # heads of 128 and wider: the cache stays as the decode kernel
+        # reads it (narrower heads the chip keeps positions minor-most)
+        from autodist_tpu.kernel.pallas.flash_decode import rows_layout
+        row_major = rows_layout(self.cfg.head_dim)
 
         def prefill(params, kc, vc, lengths, tok, table, seeds, prompts,
                     p_lens, admit, *rest):
@@ -717,11 +756,12 @@ class ServingEngine:
             # signature and HLO bit-for-bit.
             wf = rest[0] if prefix else None
             stages, shared = params["stages"], params["shared"]
-            x = self._embed(shared, prompts, jnp.arange(S))
+            positions = jnp.arange(S)
+            x = self._embed(shared, prompts, positions)
             mask = jnp.tril(jnp.ones((S, S), bool))[None, None]
-            for layer in range(L):
-                chunk = jax.tree.map(lambda p: p[layer], stages)
-                x, k, v = self._layer_prefill(chunk, x, mask)
+
+            def layer_fn(chunk, x, kc, vc, _, layer):
+                x, k, v = self._layer_prefill(chunk, x, mask, positions)
                 if paged:
                     kc = kv_cache.paged_write_prompt(
                         kc, layer, k, admit, table, self.kv_block_len,
@@ -732,6 +772,13 @@ class ServingEngine:
                 else:
                     kc = kv_cache.write_prompt(kc, layer, k, admit)
                     vc = kv_cache.write_prompt(vc, layer, v, admit)
+                    if row_major:
+                        kc = kv_cache.keep_row_major(kc)
+                        vc = kv_cache.keep_row_major(vc)
+                return x, kc, vc
+
+            x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
+                                         layer_fn)
             last = jnp.take_along_axis(
                 x, (p_lens - 1)[:, None, None], axis=1)[:, 0]
             # The first emitted token conditions on the p_lens prompt
@@ -754,7 +801,8 @@ class ServingEngine:
         token here — other slots pass through — so the host loop's last
         relevant chunk completes exactly what single-shot prefill does,
         token-for-token (the parity golden)."""
-        L, C = self.cfg.num_layers, self.prefill_chunk
+        self = weakref.proxy(self)
+        C = self.prefill_chunk
         bl = self.kv_block_len
         prefix = self.prefix_caching
 
@@ -765,10 +813,9 @@ class ServingEngine:
             x = self._embed(shared, chunk_toks,
                             chunk_start + jnp.arange(C))
             starts = jnp.zeros_like(p_lens) + chunk_start
-            for layer in range(L):
-                chunk = jax.tree.map(lambda p: p[layer], stages)
 
-                def write(kc, vc, k, v, layer=layer):
+            def layer_fn(chunk, x, kc, vc, _, layer):
+                def write(kc, vc, k, v):
                     kc = kv_cache.paged_write_chunk(
                         kc, layer, k, admit, table, bl, chunk_start,
                         p_lens, write_from=wf)
@@ -777,8 +824,11 @@ class ServingEngine:
                         p_lens, write_from=wf)
                     return kc, vc
 
-                x, kc, vc = self._layer_chunk(chunk, x, kc, vc, layer,
-                                              starts, table, write)
+                return self._layer_chunk(chunk, x, kc, vc, layer, starts,
+                                         table, write)
+
+            x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
+                                         layer_fn)
             emit_here = admit & (p_lens > chunk_start) \
                 & (p_lens <= chunk_start + C)
             last_idx = jnp.clip(p_lens - 1 - chunk_start, 0, C - 1)
@@ -805,7 +855,8 @@ class ServingEngine:
         accept/reject rule and rolls the rejected tail back by setting
         lengths, which un-materializes the stale rows behind the length
         mask (their blocks stay within the slot's reservation)."""
-        L, C = self.cfg.num_layers, self.speculative + 1
+        self = weakref.proxy(self)
+        C = self.speculative + 1
         paged = self.kv_layout == "paged"
         bl = self.kv_block_len
 
@@ -814,10 +865,9 @@ class ServingEngine:
             stages, shared = params["stages"], params["shared"]
             positions = lengths[:, None] + jnp.arange(C)[None, :]
             x = self._embed(shared, tokens_in, positions)
-            for layer in range(L):
-                chunk = jax.tree.map(lambda p: p[layer], stages)
 
-                def write(kc, vc, k, v, layer=layer):
+            def layer_fn(chunk, x, kc, vc, _, layer):
+                def write(kc, vc, k, v):
                     for c in range(C):
                         if paged:
                             kc = kv_cache.paged_write_token(
@@ -833,9 +883,11 @@ class ServingEngine:
                                 vc, layer, v[:, c:c + 1], lengths + c)
                     return kc, vc
 
-                x, kc, vc = self._layer_chunk(
-                    chunk, x, kc, vc, layer, lengths,
-                    table if paged else None, write)
+                return self._layer_chunk(chunk, x, kc, vc, layer, lengths,
+                                         table if paged else None, write)
+
+            x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
+                                         layer_fn)
             # Choice at window row c conditions on lengths + 1 + c
             # tokens — exactly the position key the c-th vanilla decode
             # step would use.
@@ -848,7 +900,8 @@ class ServingEngine:
         return self._wrap(verify, n_in_rest=6, n_out_rest=3)
 
     def _build_decode(self, steps: Optional[int] = None):
-        L, K = self.cfg.num_layers, int(steps or self.decode_steps)
+        self = weakref.proxy(self)
+        K = int(steps or self.decode_steps)
         paged = self.kv_layout == "paged"
 
         def decode(params, kc, vc, lengths, tok, table, seeds, active):
@@ -857,11 +910,11 @@ class ServingEngine:
             def body(carry, _):
                 kc, vc, lengths, tok = carry
                 x = self._embed(shared, tok[:, None], lengths[:, None])
-                for layer in range(L):
-                    chunk = jax.tree.map(lambda p: p[layer], stages)
-                    x, kc, vc = self._layer_decode(
+                x, kc, vc = self._run_layers(
+                    shared, stages, x, kc, vc,
+                    lambda chunk, x, kc, vc, _, layer: self._layer_decode(
                         chunk, x, kc, vc, layer, lengths,
-                        table=table if paged else None, active=active)
+                        table=table if paged else None, active=active))
                 # The emitted token conditions on lengths + 1 tokens
                 # (the one just written included) — its sampling key.
                 nxt, _ = self._next_token(shared, x[:, 0], seeds,
@@ -1203,7 +1256,8 @@ class ServingEngine:
                         jnp.asarray(prompts_np, jnp.int32), p_lens_j,
                         admit_j, *rest)
         if self.prefill_chunk is None:
-            with telemetry.span("engine/prefill/dispatch"):
+            with telemetry.span("engine/prefill/dispatch",
+                                loop_steps=self.cfg.block.loop_steps):
                 k, v, lengths, tok = self._prefill_jit(*args)
                 self.cache = self._rebuild_cache(k, v, lengths)
                 self._tok = tok
@@ -1254,7 +1308,8 @@ class ServingEngine:
                         self._table_arg(), jnp.asarray(self._sample_seeds),
                         jnp.asarray(padded[:, cs:cs + C], jnp.int32),
                         jnp.int32(cs), p_lens_j, admit_j, *rest)
-            with telemetry.span("engine/prefill/dispatch"):
+            with telemetry.span("engine/prefill/dispatch",
+                                loop_steps=self.cfg.block.loop_steps):
                 k, v, lengths, tok = self._prefill_jit(*args)
                 self.cache = self._rebuild_cache(k, v, lengths)
                 self._tok = tok
@@ -1274,7 +1329,8 @@ class ServingEngine:
             args = (self.params, c.k, c.v, c.lengths, self._tok,
                     self._table_arg(), jnp.asarray(self._sample_seeds),
                     jnp.asarray(active_np))
-        with telemetry.span("engine/decode/dispatch"):
+        with telemetry.span("engine/decode/dispatch",
+                            loop_steps=self.cfg.block.loop_steps):
             k, v, lengths, tok, toks = self._decode_jit(*args)
             self.cache = self._rebuild_cache(k, v, lengths)
             self._tok = tok

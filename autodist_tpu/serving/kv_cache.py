@@ -141,6 +141,19 @@ def write_prompt(cache_arr, layer: int, kv, admit):
     return cache_arr
 
 
+def keep_row_major(cache_arr):
+    """Pin ``cache_arr`` to the layout it is declared in, ``head_dim``
+    minor-most.  A cache of heads of 128 and wider arrives that way and
+    the decode kernel reads it so; left to itself the TPU compiler
+    gives the prefill program's carried cache the layout of the prompt's
+    keys (positions minor-most, from the score product) and copies the
+    whole cache in and out of it (PERF.md section 6, PR 26)."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    return with_layout_constraint(
+        cache_arr, Layout(major_to_minor=tuple(range(cache_arr.ndim))))
+
+
 def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32):
     """One decode step's attention over a layer's cache slice.
 

@@ -1446,7 +1446,8 @@ class CostModel:
                     mean_prompt_len: Optional[float] = None,
                     kv_block_len: int = 16,
                     prefix_hit_rate: float = 0.0,
-                    spec_acceptance: Optional[float] = None) -> DecodeCost:
+                    spec_acceptance: Optional[float] = None,
+                    loop_steps: int = 1) -> DecodeCost:
         """Per-token decode latency for one serving config.
 
         ``config`` is either a training :class:`Strategy` (its Strategy-
@@ -1509,6 +1510,13 @@ class CostModel:
           splits by the bottleneck stage, so prefill-bound and
           decode-bound mixes elect different splits (both directions
           pinned on the handoff term).
+        * **a looped stack** — ``loop_steps`` (the block spec's
+          ``BlockSpec.loop_steps``; 1 for every other model): a token
+          passes the stacked layers that many times, so the stage
+          parameters' FLOPs, the attention term, the tp boundaries and
+          the KV elements a position holds (one set of keys and values
+          per pass and layer) all multiply by it; the parameters'
+          bytes do not — there is one set of weights.
         """
         from autodist_tpu.strategy.ir import (normalize_kernel,
                                               normalize_kv_layout,
@@ -1603,7 +1611,11 @@ class CostModel:
                 i.shape[0] for i in trainable.var_infos()
                 if len(i.shape) >= 3)
             layers = leads.most_common(1)[0][0] if leads else 1
-        layers = int(layers)
+        passes = int(loop_steps)
+        if passes < 1:
+            raise ValueError(f"loop_steps must be >= 1, got {loop_steps}")
+        # what a token passes, and what the cache holds a layer of
+        layers = int(layers) * passes
         elems = bytes_ = 0.0
         for info in trainable.var_infos():
             shard = 1
@@ -1615,7 +1627,10 @@ class CostModel:
                 elif vocab_parallel and any(p.search(short)
                                             for p in v_res):
                     shard = tp
-            elems += info.size / shard
+            # the stacked layers' weights multiply every pass's
+            # activations; embedding, head and final norm once
+            stacked = info.name.startswith("stages/")
+            elems += info.size / shard * (passes if stacked else 1)
             bytes_ += info.byte_size / shard
         mxu_eff = float(self.link_profile.get(
             "mxu_efficiency", _DEFAULT_MXU_EFFICIENCY))

@@ -19,6 +19,9 @@ SCOPES = (
     "mlp",         # wi, GELU, wo
     "kv_write",    # keys / values written into the serving cache
     "lm_head",     # final norm, logits, argmax / sampling; MLM head + loss
+    "norm",        # RMSNorm of a non-default block (BlockSpec), a looped
+                   # stack's per-pass final norm
+    "rope",        # rotary rotation of q and k
     "grad_sync",   # gradient buckets' all-reduce, the reduce-scatters
     "optimizer",   # the update, its application, the gather to storage
 )

@@ -410,28 +410,39 @@ def kernels_phase(*, interpret: bool, seq_len: int = 512, heads: int = 12,
     require_close(ph, f"flash_decode_attention(T={T})", got, ref,
                   ATTN_FWD_RTOL)
     # the same kernel as the engine calls it: the whole cache, a layer
-    # index, only the live blocks read (the other layer holds 1e4), the
-    # step's rows written on the way (slot 2 is not decoding)
-    k5, v5 = (jnp.stack([jnp.full_like(a, 1e4), a]) for a in (kd, vd))
-    kn, vn = rand(slots, 1, H, d), rand(slots, 1, H, d)
-    act = jnp.ones((slots,), bool).at[2].set(False)
-    put = jnp.where(act, lens, T - 1)      # slot 2's row: put back below
-    kw, vw = (kv_cache.write_token(c, 1, n, put).at[1, 2].set(c[1, 2])
-              for c, n in ((k5, kn), (v5, vn)))
-    ref = jax.jit(lambda *a: kv_cache.cached_attention(*a, dtype=bf16))(
-        q1, kw[1], vw[1], lens)
-    got, kg, vg = jax.block_until_ready(jax.jit(
-        lambda q, k, v, l, kn, vn, a:
-        flash_decode.flash_decode_attention_dense(
-            q, k, v, 1, l, new_kv=(kn, vn), active=a, dtype=bf16,
-            interpret=interpret))(q1, k5, v5, lens, kn, vn, act))
-    keep = np.asarray(act)
-    require_close(ph, f"flash_decode_attention_dense(T={T}, layer 1 of 2, "
-                      "writing)", np.asarray(got, np.float32)[keep],
-                  np.asarray(ref, np.float32)[keep], ATTN_FWD_RTOL)
-    require(bool(jnp.array_equal(kg, kw)) and bool(jnp.array_equal(vg, vw)),
-            ph, "the kernel leaves the caches as write_token does",
-            "bit-identical")
+    # index (a traced operand, as a looped stack passes it), only the
+    # live blocks read (the other layer holds 1e4), the step's rows
+    # written on the way (slot 2 is not decoding) — over the [d, block]
+    # tiles of narrow heads and the [block, d] tiles of heads of 128
+    def dense_writing(H, d):
+        q1, kn, vn = (rand(slots, 1, H, d) for _ in range(3))
+        kd, vd = rand(slots, H, T, d), rand(slots, H, T, d)
+        k5, v5 = (jnp.stack([jnp.full_like(a, 1e4), a]) for a in (kd, vd))
+        act = jnp.ones((slots,), bool).at[2].set(False)
+        put = jnp.where(act, lens, T - 1)  # slot 2's row: put back below
+        kw, vw = (kv_cache.write_token(c, 1, n, put).at[1, 2].set(c[1, 2])
+                  for c, n in ((k5, kn), (v5, vn)))
+        ref = jax.jit(lambda *a: kv_cache.cached_attention(*a, dtype=bf16))(
+            q1, kw[1], vw[1], lens)
+        got, kg, vg = jax.block_until_ready(jax.jit(
+            lambda q, k, v, layer, l, kn, vn, a:
+            flash_decode.flash_decode_attention_dense(
+                q, k, v, layer, l, new_kv=(kn, vn), active=a, dtype=bf16,
+                interpret=interpret))(q1, k5, v5, jnp.int32(1), lens, kn,
+                                      vn, act))
+        keep = np.asarray(act)
+        what = f"flash_decode_attention_dense(T={T}, heads of {d}, " \
+            f"layer 1 of 2, writing)"
+        require_close(ph, what, np.asarray(got, np.float32)[keep],
+                      np.asarray(ref, np.float32)[keep], ATTN_FWD_RTOL)
+        require(bool(jnp.array_equal(kg, kw))
+                and bool(jnp.array_equal(vg, vw)), ph,
+                f"heads of {d}: the kernel leaves the caches as "
+                "write_token does", "bit-identical")
+
+    dense_writing(H, d)
+    if d < 128:
+        dense_writing(max(1, H * d // 128), 128)
     done.append("flash_decode_attention")
 
     mb = T // bl

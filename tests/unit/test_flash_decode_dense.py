@@ -43,12 +43,18 @@ def _lm_params(cfg):
 # --------------------------------------------------------------------------- #
 # the kernel against cached_attention
 # --------------------------------------------------------------------------- #
+def _block(head_dim):
+    """Heads of 128 are read as stored, ``[block, d]`` tiles whose rows
+    go back to the cache a sublane tile (16 of bf16) at a time."""
+    return BLOCK if head_dim < 128 else 16
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("blocks", [1, 2, 8])
-@pytest.mark.parametrize("heads_per_step", [1, 4])
+@pytest.mark.parametrize("heads_per_step,d", [(1, 8), (4, 8), (2, 128)])
 def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
-                                                 heads_per_step):
+                                                 heads_per_step, d):
     """Lengths on every side of a block's edge, mixed over the slots;
     the rows above each slot's length and every other layer hold 1e4,
     and the answer is the one a cache of zeros there gives."""
@@ -56,10 +62,11 @@ def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
         flash_decode_attention_dense
     from autodist_tpu.serving.kv_cache import cached_attention
 
+    BLOCK = _block(d)
     T = BLOCK * blocks
     lengths = [min(n, T - 1) for n in
                (0, 1, BLOCK - 1, BLOCK, BLOCK + 1, T - 1)]
-    L, B, H, d, layer = 3, len(lengths), 4, 8, 1
+    L, B, H, layer = 3, len(lengths), 4, 1
     r = np.random.RandomState(blocks)
     q = jnp.asarray(r.randn(B, 1, H, d), dtype)
     clean = r.randn(2, B, H, T, d).astype(np.float32)
@@ -84,7 +91,8 @@ def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("blocks", [1, 2, 8])
-def test_dense_kernel_writes_the_token_as_write_token_does(dtype, blocks):
+@pytest.mark.parametrize("d", [8, 128])
+def test_dense_kernel_writes_the_token_as_write_token_does(dtype, blocks, d):
     """``new_kv``: the caches come back as ``write_token`` leaves them,
     bit for bit (a slot that is not active untouched), and the output is
     ``cached_attention``'s over the written cache."""
@@ -92,10 +100,11 @@ def test_dense_kernel_writes_the_token_as_write_token_does(dtype, blocks):
         flash_decode_attention_dense
     from autodist_tpu.serving.kv_cache import cached_attention, write_token
 
+    BLOCK = _block(d)
     T = BLOCK * blocks
     lengths = [min(n, T - 1) for n in
                (0, 1, BLOCK - 1, BLOCK, BLOCK + 1, T - 1)]
-    L, B, H, d, layer = 2, len(lengths), 4, 8, 1
+    L, B, H, layer = 2, len(lengths), 4, 1
     r = np.random.RandomState(blocks)
     q, k_new, v_new = (jnp.asarray(r.randn(B, 1, H, d), dtype)
                        for _ in range(3))
@@ -134,7 +143,10 @@ def test_dense_kernel_refuses_a_lane_no_block_divides():
 @pytest.mark.parametrize("max_len,head_dim,block", [
     (1024, 64, 128), (512, 64, 128), (256, 64, 128), (128, 32, 128),
     (1000, 64, None),     # no block divides the lane
-    (1024, 128, None),    # the chip keeps wide heads row-major
+    (1024, 128, 128),     # the chip keeps wide heads row-major: read so
+    (512, 128, 128), (64, 128, 64), (512, 256, 128),
+    (1000, 128, None),    # no block divides that lane either
+    (512, 192, None),     # not whole 128-lane tiles: its view would copy
     (24, 8, None),        # nor does it transpose a short lane
 ])
 def test_fused_decode_block_rule(max_len, head_dim, block):
@@ -235,7 +247,8 @@ def test_forced_decode_program_hands_the_kernel_the_cache_itself():
     ("tpu", None, 256, 2, True),               # the measured threshold
     ("tpu", None, 128, 2, False),              # a lane below it
     ("tpu", None, 1000, 2, False),             # no block divides it
-    ("tpu", None, 1024, 1, False),             # head_dim 128: row-major
+    ("tpu", None, 1024, 1, True),              # head_dim 128: row-major
+    ("tpu", None, 128, 1, False),              # ... under the threshold
     ("tpu", {"flash_decode": False}, 1024, 2, False),
     ("cpu", None, 1024, 2, False),
     ("cpu", {"flash_decode": True}, 1024, 2, True),
