@@ -231,6 +231,45 @@ def probe_decode() -> dict:
     return report
 
 
+def probe_prefill() -> dict:
+    """The serving engine's single-shot prefill computes the row it
+    admits and nothing else, structurally: no buffer anywhere carries
+    both the slot count and the prompt bucket (the ``[num_slots,
+    prefill_len, ...]`` activations of a full-batch prefill — the
+    one-row activations at ``[1, prefill_len, ...]`` are the
+    scan-validity control); the row's keys and values land through
+    in-place ``dynamic-update-slice`` (>= 2 per layer) on the donated,
+    aliased cache, with no copy of a slot's lane — let alone of the
+    whole ``[layers, slots, heads, max_len, head_dim]`` cache, in either
+    layout; and the paged program holds no dense lane at all."""
+    S, B, T = programs.PRE_LEN, programs.PRE_SLOTS, programs.DEC_T
+    report = {"num_slots": B, "prefill_len": S, "max_len": T}
+    for layout in ("dense", "paged"):
+        text = programs.prefill_step_text(layout)
+        rules = [R.no_host_transfer(), R.donated_alias(),
+                 R.min_dus(2 * programs.DEC_LAYERS), R.no_collectives()]
+        if layout == "paged":
+            rules.append(R.paged_cache(B, T))
+        else:
+            # one slot's lane of one layer: heads x max_len x head_dim
+            rules.append(R.no_donated_copy(
+                T, 2 * T * programs.DEC_HEAD_DIM, "cache-lane"))
+        facts = _enforce(text, rules, f"prefill[{layout}]")
+        rows = facts.buffers_with_dim(S)
+        assert rows > 0, (
+            f"prefill[{layout}] shows no [.., {S}, ..] buffer — the "
+            "probe's distinctive-dim scan is broken, not proving anything")
+        batch = facts.buffers_with_dims((B, S))
+        assert batch == 0, (
+            f"prefill[{layout}]: {batch} buffer(s) shaped with both the "
+            f"slot count {B} and the prompt bucket {S} — the program "
+            "computes rows it does not admit")
+        report[f"prompt_row_buffers_{layout}"] = rows
+        report[f"slot_batch_buffers_{layout}"] = batch
+        report[f"dynamic_update_slices_{layout}"] = facts.dus
+    return report
+
+
 def probe_quantized() -> dict:
     """The per-collective precision policy, structurally: quantization
     happens *inside* the program — convert-before, narrowed collective
@@ -317,6 +356,7 @@ PROBES = {
     "zero3": probe_zero3,
     "quantized": probe_quantized,
     "decode": probe_decode,
+    "prefill": probe_prefill,
 }
 
 

@@ -399,11 +399,11 @@ DEC_BLOCK_LEN = 16
 DEC_POOL_BLOCKS = 13
 
 
-@functools.lru_cache(maxsize=None)
-def decode_step_text(tensor_parallel: int, vocab_parallel: bool,
-                     kernel=None, kv_layout: str = "dense") -> str:
-    """Optimized HLO of one fused-decode dispatch of the serving
-    engine (memoized like the pipeline texts)."""
+def _serving_engine(tensor_parallel: int = 1, vocab_parallel: bool = False,
+                    kernel=None, kv_layout: str = "dense",
+                    num_slots: int = DEC_SLOTS, prefill_len: int = 8):
+    """The toy serving engine whose programs the decode and prefill
+    probes read."""
     import jax
     import jax.numpy as jnp
     import optax
@@ -418,11 +418,33 @@ def decode_step_text(tensor_parallel: int, vocab_parallel: bool,
                             dropout_rate=0.0, attention_dropout_rate=0.0)
     params = make_pipeline_lm_trainable(
         cfg, optax.sgd(0.1), jax.random.PRNGKey(0)).params
-    engine = ServingEngine(cfg, params, tensor_parallel=tensor_parallel,
-                           vocab_parallel=vocab_parallel, kernel=kernel,
-                           num_slots=DEC_SLOTS, max_len=DEC_T,
-                           prefill_len=8, decode_steps=4,
-                           kv_layout=kv_layout,
-                           kv_block_len=DEC_BLOCK_LEN,
-                           kv_num_blocks=DEC_POOL_BLOCKS)
-    return engine.compiled_decode_text()
+    return ServingEngine(cfg, params, tensor_parallel=tensor_parallel,
+                         vocab_parallel=vocab_parallel, kernel=kernel,
+                         num_slots=num_slots, max_len=DEC_T,
+                         prefill_len=prefill_len, decode_steps=4,
+                         kv_layout=kv_layout,
+                         kv_block_len=DEC_BLOCK_LEN,
+                         kv_num_blocks=DEC_POOL_BLOCKS)
+
+
+@functools.lru_cache(maxsize=None)
+def decode_step_text(tensor_parallel: int, vocab_parallel: bool,
+                     kernel=None, kv_layout: str = "dense") -> str:
+    """Optimized HLO of one fused-decode dispatch of the serving
+    engine (memoized like the pipeline texts)."""
+    return _serving_engine(tensor_parallel, vocab_parallel, kernel,
+                           kv_layout).compiled_decode_text()
+
+
+# The prefill probe's own distinctive extents: no other dimension of
+# the program equals the slot count or the prompt bucket.
+PRE_SLOTS = 5
+PRE_LEN = 11
+
+
+@functools.lru_cache(maxsize=None)
+def prefill_step_text(kv_layout: str = "dense") -> str:
+    """Optimized HLO of the serving engine's single-shot prefill
+    program, one dispatch of which admits one row."""
+    return _serving_engine(kv_layout=kv_layout, num_slots=PRE_SLOTS,
+                           prefill_len=PRE_LEN).compiled_prefill_text()

@@ -273,7 +273,8 @@ class ContinuousBatcher:
                 slot.done = "deadline_exceeded"
 
     def _admit(self):
-        """Fill free slots from the queue with ONE batched prefill.
+        """Fill free slots from the queue with ONE ``engine.prefill``
+        call (which computes the admitted rows only).
 
         Under the paged KV layout admission gates on **free blocks, not
         slots**: a request enters only when its ``prompt + budget``

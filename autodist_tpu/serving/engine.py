@@ -147,7 +147,10 @@ class ServingEngine:
     :func:`~autodist_tpu.models.pipeline_lm.make_pipeline_lm_trainable`
     (stacked per-layer leaves + tied embedding/unembedding).  Slots,
     prompt bucket, and the fused-decode width are static so the whole
-    serving loop is exactly two compiled programs:
+    serving loop is exactly two compiled programs — a prefill over ONE
+    ``[1, prefill_len]`` row with the slot it is admitted to a traced
+    scalar, run once per admitted slot, and the fused decode over all
+    slots:
 
     * ``num_slots`` — batch slots the continuous batcher fills;
     * ``prefill_len`` — the prompt bucket (prompts zero-padded up to
@@ -437,6 +440,8 @@ class ServingEngine:
                 cfg.head_dim, self.max_len,
                 dtype=cfg.dtype)
             self._allocator = None
+            # no table: one unused column, the programs' table operand
+            self._table = np.zeros((self.num_slots, 1), np.int32)
             if self.mesh is not None:
                 csh = NamedSharding(self.mesh, kv_cache.cache_spec())
                 cache = kv_cache.KVCache(
@@ -459,6 +464,7 @@ class ServingEngine:
         self._decode1_jit = None           # lazy K=1 program (catch-up)
         self._copy_block_jit = None        # lazy CoW device copy
         self.last_prefill_chunks = 0
+        self._counts_prefill = True
 
         # ---- speculative draft: a nested engine sharing the cache
         # layout (same block scheme, own pool/params), run unsharded —
@@ -480,6 +486,9 @@ class ServingEngine:
                 temperature=self.temperature, top_k=self.top_k,
                 prefill_chunk=self.prefill_chunk,
                 devices=self._device and [self._device])
+            # the prefill counters say what the TARGET's program
+            # computed for the prompts it admitted
+            self.draft._counts_prefill = False
             self._spec_verify_jit = self._build_spec_verify()
             self._spec_catch = np.zeros((self.num_slots,), bool)
             self._spec_catch_tok = np.zeros((self.num_slots,), np.int32)
@@ -740,6 +749,12 @@ class ServingEngine:
         return FirstCallWithRoom(jax.jit(sm, donate_argnums=(1, 2)))
 
     def _build_prefill(self):
+        """The single-shot prefill program: ONE row, ``[1, prefill_len]``,
+        with the slot it is admitted to a traced scalar —
+        :meth:`prefill` runs it once per admitted slot.  The row's keys
+        and values land in the slot's lane (its blocks, paged) and
+        ``tok[slot]`` / ``lengths[slot]`` are set inside the program;
+        nothing of any other slot is read or written."""
         self = weakref.proxy(self)      # see _wrap: no cycle through jit
         S = self.prefill_len
         paged = self.kv_layout == "paged"
@@ -749,29 +764,29 @@ class ServingEngine:
         from autodist_tpu.kernel.pallas.flash_decode import rows_layout
         row_major = rows_layout(self.cfg.head_dim)
 
-        def prefill(params, kc, vc, lengths, tok, table, seeds, prompts,
-                    p_lens, admit, *rest):
-            # Prefix-caching engines thread a per-slot novel-write
-            # floor; without the knob the program keeps its pre-PR-16
-            # signature and HLO bit-for-bit.
+        def prefill(params, kc, vc, lengths, tok, slot, table_row, seed,
+                    prompt, p_len, *rest):
+            # ``slot`` a scalar; ``table_row`` [1, max_blocks]; ``seed``,
+            # ``p_len`` [1]; ``prompt`` [1, S].  Prefix-caching engines
+            # thread the row's novel-write floor, [1].
             wf = rest[0] if prefix else None
             stages, shared = params["stages"], params["shared"]
             positions = jnp.arange(S)
-            x = self._embed(shared, prompts, positions)
+            x = self._embed(shared, prompt, positions)
             mask = jnp.tril(jnp.ones((S, S), bool))[None, None]
 
             def layer_fn(chunk, x, kc, vc, _, layer):
                 x, k, v = self._layer_prefill(chunk, x, mask, positions)
                 if paged:
                     kc = kv_cache.paged_write_prompt(
-                        kc, layer, k, admit, table, self.kv_block_len,
-                        p_lens, write_from=wf)
+                        kc, layer, k, table_row, self.kv_block_len, p_len,
+                        write_from=wf)
                     vc = kv_cache.paged_write_prompt(
-                        vc, layer, v, admit, table, self.kv_block_len,
-                        p_lens, write_from=wf)
+                        vc, layer, v, table_row, self.kv_block_len, p_len,
+                        write_from=wf)
                 else:
-                    kc = kv_cache.write_prompt(kc, layer, k, admit)
-                    vc = kv_cache.write_prompt(vc, layer, v, admit)
+                    kc = kv_cache.write_prompt(kc, layer, k, slot)
+                    vc = kv_cache.write_prompt(vc, layer, v, slot)
                     if row_major:
                         kc = kv_cache.keep_row_major(kc)
                         vc = kv_cache.keep_row_major(vc)
@@ -780,12 +795,13 @@ class ServingEngine:
             x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
                                          layer_fn)
             last = jnp.take_along_axis(
-                x, (p_lens - 1)[:, None, None], axis=1)[:, 0]
-            # The first emitted token conditions on the p_lens prompt
+                x, (p_len - 1)[:, None, None], axis=1)[:, 0]
+            # The first emitted token conditions on the p_len prompt
             # tokens — its sampling key position.
-            first_tok, _ = self._next_token(shared, last, seeds, p_lens)
-            tok = jnp.where(admit, first_tok, tok)
-            lengths = jnp.where(admit, p_lens, lengths)
+            first_tok, _ = self._next_token(shared, last, seed, p_len)
+            tok = lax.dynamic_update_slice(
+                tok, first_tok.astype(tok.dtype), (slot,))
+            lengths = lax.dynamic_update_slice(lengths, p_len, (slot,))
             return kc, vc, lengths, tok
 
         return self._wrap(prefill, n_in_rest=7 + (1 if prefix else 0),
@@ -1128,7 +1144,7 @@ class ServingEngine:
     def _table_arg(self):
         if self.kv_layout == "paged":
             return self.cache.block_table
-        return jnp.zeros((self.num_slots, 1), jnp.int32)
+        return jnp.asarray(self._table)
 
     # ------------------------------------------------------------------ #
     # copy-on-write + prefix registration (the sharing protocol)
@@ -1228,15 +1244,17 @@ class ServingEngine:
         return (self.max_len - 1 if self.prefill_chunk is not None
                 else self.prefill_len)
     def prefill(self, prompts, p_lens, admit, seeds=None):
-        """Run one prefill over the slot batch; admitted slots adopt
-        their prompt's cache/length and first generated token (greedy,
-        or sampled at the engine's temperature under the slot's
-        ``seeds`` entry).  Single-shot engines dispatch the one
-        ``[B, prefill_len]`` program; chunked engines walk the prompt
-        in ``prefill_chunk`` windows through ONE compiled program
-        (``chunk_start`` is traced), skipping leading chunks every
-        admitted slot already has cached via prefix hits.  Returns the
-        per-slot current token ``[B]`` (numpy)."""
+        """Prefill the admitted slots of the ``[B, S]`` slot batch; each
+        adopts its prompt's cache/length and first generated token
+        (greedy, or sampled at the engine's temperature under the slot's
+        ``seeds`` entry).  Single-shot engines run the one
+        ``[1, prefill_len]`` program once per admitted slot, back to
+        back, the cache donated from one dispatch to the next — only
+        admitted rows are computed; chunked engines walk the prompt in
+        ``prefill_chunk`` windows through ONE compiled ``[B, C]``
+        program (``chunk_start`` is traced), skipping leading chunks
+        every admitted slot already has cached via prefix hits.  Returns
+        the per-slot current token ``[B]`` (numpy), fetched once."""
         with telemetry.span("engine/prefill/stage"):
             prompts_np = np.asarray(prompts)
             p_lens_np = np.asarray(p_lens)
@@ -1245,26 +1263,31 @@ class ServingEngine:
                 self._sample_seeds = np.where(
                     admit_np, np.asarray(seeds, np.int32),
                     self._sample_seeds).astype(np.int32)
-            p_lens_j = jnp.asarray(p_lens_np, jnp.int32)
-            admit_j = jnp.asarray(admit_np)
-            rest = ((jnp.asarray(self._write_from),)
-                    if self.prefix_caching else ())
             if self.prefill_chunk is None:
-                c = self.cache
-                args = (self.params, c.k, c.v, c.lengths, self._tok,
-                        self._table_arg(), jnp.asarray(self._sample_seeds),
-                        jnp.asarray(prompts_np, jnp.int32), p_lens_j,
-                        admit_j, *rest)
+                # One row's operands each, picked here on the host (a
+                # slice made with jnp would be a small program of its
+                # own, compiled at its first use) into arrays that
+                # nothing mutates while a dispatch is in flight.
+                rows = np.flatnonzero(admit_np)
+                picked = [np.asarray(a[rows], np.int32) for a in (
+                    self._table, self._sample_seeds, prompts_np,
+                    p_lens_np, *((self._write_from,)
+                                 if self.prefix_caching else ()))]
         if self.prefill_chunk is None:
             with telemetry.span("engine/prefill/dispatch",
-                                loop_steps=self.cfg.block.loop_steps):
-                k, v, lengths, tok = self._prefill_jit(*args)
-                self.cache = self._rebuild_cache(k, v, lengths)
-                self._tok = tok
+                                loop_steps=self.cfg.block.loop_steps,
+                                rows=len(rows)):
+                for i, slot in enumerate(rows):
+                    c = self.cache
+                    k, v, lengths, tok = self._prefill_jit(
+                        self.params, c.k, c.v, c.lengths, self._tok,
+                        np.int32(slot), *(a[i:i + 1] for a in picked))
+                    self.cache = self._rebuild_cache(k, v, lengths)
+                    self._tok = tok
+            self._count_prefill(len(rows), self.prefill_len)
             self.last_prefill_chunks = 1
         else:
-            self._chunked_prefill(prompts_np, p_lens_np, admit_np,
-                                  p_lens_j, admit_j, rest)
+            self._chunked_prefill(prompts_np, p_lens_np, admit_np)
         with telemetry.span("engine/prefill/register"):
             self._flush_registration(admit_np)
             if self.draft is not None:
@@ -1276,15 +1299,81 @@ class ServingEngine:
         with telemetry.span("engine/prefill/fetch"):
             return np.asarray(jax.device_get(self._tok))
 
-    def _chunked_prefill(self, prompts_np, p_lens_np, admit_np,
-                         p_lens_j, admit_j, rest):
+    def _count_prefill(self, rows: int, positions_a_row: int) -> None:
+        """What the prefill program computed: rows dispatched, and the
+        positions they span (padding included).  A speculative draft's
+        nested engine keeps out of the count."""
+        if not self._counts_prefill:
+            return
+        telemetry.counter("engine/prefill_rows").inc(rows)
+        telemetry.counter("engine/prefill_positions").inc(
+            rows * positions_a_row)
+
+    def _blank_prefill_args(self, slot: int = 0) -> tuple:
+        """The prefill program's operands after ``tok``, typed as
+        :meth:`prefill` hands them over, for a dispatch that changes no
+        request's state: the one-row program over an empty prompt
+        (``p_len`` 0) at ``slot``, the chunked program's first window
+        with no slot admitted."""
+        if self.prefill_chunk is None:
+            zero = np.zeros((1,), np.int32)
+            return (np.int32(slot), np.zeros_like(self._table[:1]), zero,
+                    np.zeros((1, self.prefill_len), np.int32), zero,
+                    *((zero,) if self.prefix_caching else ()))
+        B = self.num_slots
+        return (self._table_arg(), jnp.asarray(self._sample_seeds),
+                jnp.zeros((B, self.prefill_chunk), jnp.int32),
+                jnp.int32(0), jnp.ones((B,), jnp.int32),
+                jnp.zeros((B,), bool),
+                *((jnp.asarray(self._write_from),)
+                  if self.prefix_caching else ()))
+
+    def warm_prefill(self) -> None:
+        """Compile the prefill program by running it once with no
+        request admitted — what a replica does before it takes traffic:
+        :meth:`prefill` dispatches nothing for an empty ``admit``, so
+        the first request would otherwise compile inside a scheduler
+        round.  The one-row program runs at a free slot over an empty
+        prompt: the slot's length stays 0, a paged pool takes no write
+        (no position lies under ``p_len``), and a dense lane and the
+        slot's held token are read again only after the next admission
+        has overwritten them; every other slot stays bit-for-bit.  The
+        chunked program runs one window under an all-false ``admit``,
+        which holds the whole state."""
+        slot, rows, span = 0, self.num_slots, self.prefill_chunk
+        if self.prefill_chunk is None:
+            free = np.flatnonzero(self.lengths == 0)
+            if not free.size:
+                raise RuntimeError(
+                    "warm_prefill needs a free slot: the one-row "
+                    "program writes the lane of the slot it runs at")
+            slot, rows, span = int(free[0]), 1, self.prefill_len
+        c = self.cache
+        with telemetry.span("engine/prefill/dispatch",
+                            loop_steps=self.cfg.block.loop_steps,
+                            rows=rows):
+            k, v, lengths, tok = self._prefill_jit(
+                self.params, c.k, c.v, c.lengths, self._tok,
+                *self._blank_prefill_args(slot))
+            self.cache = self._rebuild_cache(k, v, lengths)
+            self._tok = tok
+        self._count_prefill(rows, span)
+        if self.draft is not None:
+            self.draft.warm_prefill()
+
+    def _chunked_prefill(self, prompts_np, p_lens_np, admit_np):
         C = self.prefill_chunk
         if not admit_np.any():
             self.last_prefill_chunks = 0
             return
+        # cast on the host: jnp casting an int64 array is a program
+        p_lens_j = jnp.asarray(p_lens_np.astype(np.int32))
+        admit_j = jnp.asarray(admit_np)
+        rest = ((jnp.asarray(self._write_from),)
+                if self.prefix_caching else ())
         hi_len = int(p_lens_np[admit_np].max())
         n_chunks = kv_cache.blocks_for(hi_len, C)
-        padded = np.zeros((self.num_slots, n_chunks * C), np.int64)
+        padded = np.zeros((self.num_slots, n_chunks * C), np.int32)
         width = min(prompts_np.shape[1], padded.shape[1])
         padded[:, :width] = prompts_np[:, :width]
         # Chunks fully covered by prefix hits for EVERY admitted slot
@@ -1306,14 +1395,17 @@ class ServingEngine:
             with telemetry.span("engine/prefill/stage"):
                 args = (self.params, c.k, c.v, c.lengths, self._tok,
                         self._table_arg(), jnp.asarray(self._sample_seeds),
-                        jnp.asarray(padded[:, cs:cs + C], jnp.int32),
+                        jnp.asarray(padded[:, cs:cs + C]),
                         jnp.int32(cs), p_lens_j, admit_j, *rest)
             with telemetry.span("engine/prefill/dispatch",
-                                loop_steps=self.cfg.block.loop_steps):
+                                loop_steps=self.cfg.block.loop_steps,
+                                rows=self.num_slots):
                 k, v, lengths, tok = self._prefill_jit(*args)
                 self.cache = self._rebuild_cache(k, v, lengths)
                 self._tok = tok
             dispatched += 1
+        # the chunk program computes every slot's window
+        self._count_prefill(dispatched * self.num_slots, C)
         self.last_prefill_chunks = dispatched
 
     def decode(self, active):
@@ -1478,20 +1570,9 @@ class ServingEngine:
             active).compile().as_text()
 
     def compiled_prefill_text(self) -> str:
-        """Optimized HLO of the prefill program (the ``[B, C]`` window
-        program on a chunked-prefill engine)."""
+        """Optimized HLO of the prefill program (the one-row program;
+        the ``[B, C]`` window program on a chunked-prefill engine)."""
         c = self.cache
-        if self.prefill_chunk is None:
-            window = (jnp.zeros((self.num_slots, self.prefill_len),
-                                jnp.int32),)
-        else:
-            window = (jnp.zeros((self.num_slots, self.prefill_chunk),
-                                jnp.int32), jnp.int32(0))
-        p_lens = jnp.ones((self.num_slots,), jnp.int32)
-        admit = jnp.ones((self.num_slots,), bool)
-        rest = ((jnp.asarray(self._write_from),)
-                if self.prefix_caching else ())
         return self._prefill_jit.lower(
             self.params, c.k, c.v, c.lengths, self._tok,
-            self._table_arg(), jnp.asarray(self._sample_seeds), *window,
-            p_lens, admit, *rest).compile().as_text()
+            *self._blank_prefill_args()).compile().as_text()

@@ -152,17 +152,15 @@ class Replica:
             self._warm_programs()
 
     def _warm_programs(self):
-        """Compile the prefill/decode programs with all-slots-masked
-        dispatches (state untouched) so the first real request never
-        stalls a scheduler round across the heartbeat window — a
-        replica mid-compile must look starting-up (grace), not hung."""
+        """Compile the prefill/decode programs with dispatches that
+        admit and advance nothing (no request's state touched) so the
+        first real request never stalls a scheduler round across the
+        heartbeat window — a replica mid-compile must look starting-up
+        (grace), not hung."""
         import numpy as np
 
-        B, S = self.engine.num_slots, self.engine.prefill_len
-        self.engine.prefill(np.zeros((B, S), np.int32),
-                            np.ones((B,), np.int32),
-                            np.zeros((B,), bool))
-        self.engine.decode(np.zeros((B,), bool))
+        self.engine.warm_prefill()
+        self.engine.decode(np.zeros((self.engine.num_slots,), bool))
 
     # ------------------------------------------------------------------ #
     @property

@@ -119,26 +119,19 @@ def write_token(cache_arr, layer: int, kv, positions):
 
 
 @scope("kv_write")
-def write_prompt(cache_arr, layer: int, kv, admit):
-    """Write a prefill's whole-prompt projections for admitted slots.
+def write_prompt(cache_arr, layer: int, kv, slot):
+    """Write one admitted prompt's projections into its slot's lane.
 
-    ``kv``: ``[B, S, heads, head_dim]``; slot ``i``'s rows land at
-    ``[layer, i, :, 0:S, :]`` when ``admit[i]``, and its existing cache
-    rows are kept bit-for-bit otherwise — the read-modify-write touches
-    only the ``[heads, S, head_dim]`` window, never the full cache (the
-    masking that lets one compiled prefill admit any subset of slots
-    while the others keep decoding state).
+    ``kv``: ``[1, S, heads, head_dim]`` (the one-row prefill's
+    projections); ``slot``: a traced int32 scalar — the rows land at
+    ``[layer, slot, :, 0:S, :]`` through ONE ``dynamic_update_slice``,
+    the in-place form XLA aliases.  The row is admitted by construction
+    (the engine dispatches the program once per admitted slot), so there
+    is no read-modify-write and every other slot's lane is not touched.
     """
-    B, S = kv.shape[0], kv.shape[1]
-    for slot in range(B):
-        new = jnp.transpose(kv[slot], (1, 0, 2))[None, None] \
-            .astype(cache_arr.dtype)                 # [1,1,heads,S,dh]
-        cur = lax.dynamic_slice(cache_arr, (layer, slot, 0, 0, 0),
-                                new.shape)
-        sel = jnp.where(admit[slot], new, cur)
-        cache_arr = lax.dynamic_update_slice(cache_arr, sel,
-                                             (layer, slot, 0, 0, 0))
-    return cache_arr
+    new = jnp.transpose(kv, (0, 2, 1, 3))[None].astype(cache_arr.dtype)
+    return lax.dynamic_update_slice(cache_arr, new,      # [1,1,heads,S,dh]
+                                    (layer, slot, 0, 0, 0))
 
 
 def keep_row_major(cache_arr):
@@ -419,46 +412,41 @@ def paged_write_token(cache_arr, layer: int, kv, positions, block_table,
 
 
 @scope("kv_write")
-def paged_write_prompt(cache_arr, layer: int, kv, admit, block_table,
-                       block_len: int, p_lens, write_from=None):
-    """The paged :func:`write_prompt`: slot ``i``'s prompt rows land
-    block by block through the table when ``admit[i]``.  Unlike the
-    dense path — which writes the whole zero-padded prompt bucket into
-    the slot's private lane — a logical block holding NO real prompt
-    row (``j·block_len >= p_lens[i]``) is left untouched: a short
-    request reserves only its own blocks, so its table row past the
-    reservation points at block 0 (possibly another slot's), and the
-    padding garbage must never land there.  The final *partial* prompt
-    block (``lo < p_lens[i] < hi``) is the slot's own reserved block
-    and is overwritten WHOLE — its tail takes the prompt bucket's
-    zero-padding projections, unreachable behind the length mask (the
-    block-granular write never splits below a block, so only the
-    all-or-nothing ``lo < p_lens`` predicate decides).  Non-admitted
-    slots' mapped blocks are kept bit-for-bit via the same
-    read-modify-write select the dense path uses.
+def paged_write_prompt(cache_arr, layer: int, kv, table_row,
+                       block_len: int, p_len, write_from=None):
+    """The paged :func:`write_prompt`: one admitted prompt's rows land
+    block by block through its slot's table row.  ``kv``: ``[1, S,
+    heads, head_dim]``; ``table_row``: ``[1, max_blocks]``; ``p_len``
+    (and ``write_from``): ``[1]``.  Unlike the dense path — which writes
+    the whole zero-padded prompt bucket into the slot's private lane — a
+    logical block holding NO real prompt row (``j·block_len >= p_len``)
+    is left untouched: a short request reserves only its own blocks, so
+    its table row past the reservation points at block 0 (possibly
+    another slot's), and the padding garbage must never land there.  The
+    final *partial* prompt block (``lo < p_len < hi``) is the slot's own
+    reserved block and is overwritten WHOLE — its tail takes the prompt
+    bucket's zero-padding projections, unreachable behind the length
+    mask (the block-granular write never splits below a block, so only
+    the all-or-nothing ``lo < p_len`` predicate decides).
 
-    ``write_from`` (``[B]`` int32, optional): logical blocks
-    ``j < write_from[i]`` are skipped — they are prefix-cache hits
-    whose physical blocks already hold the identical projections
-    (possibly shared with another slot, where an unsuppressed write
-    would be a write through a shared table entry — ADT116)."""
-    B, S = kv.shape[0], kv.shape[1]
-    n_blocks = blocks_for(S, block_len)
-    for slot in range(B):
-        rows = jnp.transpose(kv[slot], (1, 0, 2))    # [heads, S, dh]
-        for j in range(n_blocks):
-            lo = j * block_len
-            hi = min(lo + block_len, S)
-            new = rows[:, lo:hi][None, None].astype(cache_arr.dtype)
-            blk = block_table[slot, j]
-            cur = lax.dynamic_slice(cache_arr, (layer, blk, 0, 0, 0),
-                                    new.shape)
-            take = admit[slot] & (lo < p_lens[slot])
-            if write_from is not None:
-                take = take & (j >= write_from[slot])
-            sel = jnp.where(take, new, cur)
-            cache_arr = lax.dynamic_update_slice(
-                cache_arr, sel, (layer, blk, 0, 0, 0))
+    ``write_from``: logical blocks ``j < write_from`` are skipped — they
+    are prefix-cache hits whose physical blocks already hold the
+    identical projections (possibly shared with another slot, where an
+    unsuppressed write would be a write through a shared table entry —
+    ADT116)."""
+    S = kv.shape[1]
+    rows = jnp.transpose(kv[0], (1, 0, 2))           # [heads, S, dh]
+    for j in range(blocks_for(S, block_len)):
+        lo = j * block_len
+        hi = min(lo + block_len, S)
+        new = rows[:, lo:hi][None, None].astype(cache_arr.dtype)
+        start = (layer, table_row[0, j], 0, 0, 0)
+        take = lo < p_len[0]
+        if write_from is not None:
+            take = take & (j >= write_from[0])
+        cur = lax.dynamic_slice(cache_arr, start, new.shape)
+        cache_arr = lax.dynamic_update_slice(
+            cache_arr, jnp.where(take, new, cur), start)
     return cache_arr
 
 

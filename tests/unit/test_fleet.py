@@ -120,6 +120,30 @@ def test_fleet_parity_routed_and_failover_greedy(cfg, params, tp,
     assert ("replica-0", "replaced") in states   # lifecycle completed
 
 
+@pytest.mark.parametrize("tp,kv_layout", [
+    (1, "dense"), (1, "paged"), (2, "dense")])
+def test_warm_replicas_compile_nothing_on_their_first_requests(
+        cfg, params, tp, kv_layout):
+    """A replica built with ``warm=True`` has compiled its prefill and
+    decode programs before it takes traffic (a replica mid-compile looks
+    hung across the heartbeat window): the first requests lower and
+    compile nothing, and decode what they decode alone."""
+    from tests.unit.test_serving import CompileEvents
+
+    factory = make_factory(cfg, params, tp=tp, kv_layout=kv_layout)
+    reqs = [(p, 0) for p in PROMPTS]
+    golden = run_alone(factory, reqs)
+    fleet = ServingFleet(factory, replicas=2, warm=True)
+    router = Router(fleet)
+    with CompileEvents().counting() as compiled:
+        rids = [router.submit(p, max_new_tokens=MAX_NEW) for p, _ in reqs]
+        done = router.run()
+    assert compiled == []
+    for i, rid in enumerate(rids):
+        assert done[rid].tokens == golden[i], (i, done[rid])
+    assert_zero_residency(fleet)
+
+
 @pytest.mark.parametrize("tp,kv_layout", [(1, "paged"), (2, "dense")])
 def test_fleet_parity_sampled_seeded(cfg, params, tp, kv_layout):
     """Seeded sampling keeps the same contract: the gumbel keys fold

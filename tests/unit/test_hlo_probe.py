@@ -16,7 +16,8 @@ from tools.hlo_probe import (buffers_with_dim, buffers_with_dim_repeated,
                              narrowed_collective_counts,
                              nonscalar_all_reduces,
                              probe_collective_matmul, probe_decode,
-                             probe_pipeline_tp, probe_quantized,
+                             probe_pipeline_tp, probe_prefill,
+                             probe_quantized,
                              probe_single_replica, probe_steps_per_loop,
                              probe_vocab_parallel, probe_zero3)
 
@@ -149,6 +150,35 @@ def test_decode_step_is_buffer_clean_and_in_place():
     assert report["dynamic_update_slices_vp"] >= 4    # k+v x 2 layers
     assert report["collectives_vp"]["all-reduce"] >= 4
     assert sum(report["collectives_tp1"].values()) == 0
+
+
+def test_prefill_computes_one_row_and_writes_it_in_place():
+    """The serving prefill claims, tier-1 on CPU: a prefill that computes
+    the ``[num_slots, prefill_len]`` slot batch again, or whose cache
+    write regresses to a copy of a lane or of the whole cache, fails CI
+    here — the failure PR 24 and PR 26 each met on the chip."""
+    report = probe_prefill()
+    for layout in ("dense", "paged"):
+        assert report[f"prompt_row_buffers_{layout}"] > 0
+        assert report[f"slot_batch_buffers_{layout}"] == 0
+        assert report[f"dynamic_update_slices_{layout}"] >= 4  # k+v x 2
+
+
+def test_prefill_probe_catches_the_slot_batch_and_the_cache_copy():
+    """The scans the prefill probe rests on, on text shaped as the
+    full-batch program's was: its ``[slots, bucket, ...]`` activations
+    and a whole-cache layout copy are both seen."""
+    from autodist_tpu.analysis.facts import ProgramFacts
+
+    text = """
+  %x = f32[5,11,16]{2,1,0} fusion(f32[5,11]{1,0} %tokens), kind=kLoop
+  %row = f32[1,11,16]{2,1,0} fusion(f32[1,11]{1,0} %tokens), kind=kLoop
+  %cp = f32[2,5,2,8,57]{4,3,2,1,0} copy(f32[2,5,2,8,57]{3,4,2,1,0} %kc)
+"""
+    facts = ProgramFacts.from_hlo(text)
+    assert facts.buffers_with_dims((5, 11)) == 2
+    assert facts.buffers_with_dim(11) == 4
+    assert facts.large_copies_with_dim(57, 2 * 57 * 8) == 1
 
 
 def test_narrowed_collective_helpers_parse_hlo_idioms():

@@ -27,6 +27,8 @@ from autodist_tpu import telemetry
 from autodist_tpu.models import pipeline_lm as lm
 from autodist_tpu.models.transformer import BlockSpec, TransformerConfig
 from autodist_tpu.serving import ServingEngine
+from tests.unit.test_serving import (ADMIT_SUBSETS, admit_id,
+                                     check_prefill_admits, resident_engine)
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -252,6 +254,28 @@ def test_other_cache_layouts_serve_the_new_block(ref, engine_kw):
 
 
 # --------------------------------------------------------------------- #
+# the one-row prefill of the looped block (helpers: test_serving.py)
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module", params=[
+    dict(), dict(kv_layout="paged", kv_block_len=5)],
+    ids=["dense", "paged"])
+def resident_looped(request, ref):
+    rc = _ref_cfg()
+    with jax.default_matmul_precision("highest"):
+        return resident_engine(_cfg(rc), _params(ref, rc), **request.param)
+
+
+@pytest.mark.parametrize("admit", ADMIT_SUBSETS, ids=admit_id)
+def test_looped_prefill_computes_and_writes_only_admitted_slots(
+        resident_looped, ref, admit):
+    """Every pass's keys and values of an admitted row, cache layer by
+    cache layer, and nothing of any other slot."""
+    rc = _ref_cfg()
+    check_prefill_admits(resident_looped, _cfg(rc), _params(ref, rc),
+                         admit)
+
+
+# --------------------------------------------------------------------- #
 # what the engine says of itself, and what it refuses
 # --------------------------------------------------------------------- #
 def test_cache_holds_every_pass_and_says_so(ref):
@@ -390,10 +414,12 @@ def test_decode_cost_prices_every_pass():
 # sha256 of the optimized HLO of ``gpt2-large-postln``'s rehearsal
 # programs (CPU backend, this installation), metadata and the frame
 # tables cut: read on the parent commit of PR 26 (e038533) with the same
-# function.  A PR that means to change these programs reads them anew.
+# function.  A PR that means to change these programs reads them anew:
+# PR 27 made the prefill the one-row program and read it on its own tree
+# (the decode program is still PR 26's parent's).
 PARENT_HLO = {
     "decode": "dfdf116c2b672fb9",
-    "prefill": "aa88773c14c8dbc0",
+    "prefill": "1a653c13b1447b4f",
 }
 
 

@@ -30,6 +30,8 @@ from autodist_tpu.serving import (BlockAllocator, ContinuousBatcher,
                                   PoolExhaustedError, ServingEngine)
 from autodist_tpu.serving import kv_cache
 from autodist_tpu.serving.engine import seed_engine_kwargs
+from tests.unit.test_serving import (ADMIT_SUBSETS, admit_id,
+                                     check_prefill_admits, resident_engine)
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
@@ -204,6 +206,48 @@ def test_paged_write_respects_write_mask():
     # inactive slot 1's write into block 0 was suppressed entirely
     np.testing.assert_array_equal(np.asarray(k[0, 0]),
                                   np.asarray(resident[0, 0]))
+
+
+def test_paged_prompt_write_lands_in_the_rows_own_blocks_only():
+    """One admitted row through its table row: blocks holding a real
+    prompt row are overwritten whole, a logical block past the prompt
+    (its entry may name another slot's block) and one under
+    ``write_from`` (a prefix hit, maybe shared) keep every bit."""
+    c = kv_cache.init_paged_cache(1, 2, 2, 3, max_len=12, block_len=4,
+                                  num_blocks=5)
+    resident = c.k + 7.0
+    kv = jnp.ones((1, 12, 2, 3), jnp.float32)          # [1, S, heads, dh]
+    row = jnp.asarray([[3, 1, 0]], jnp.int32)          # block 0: not ours
+    write = jax.jit(kv_cache.paged_write_prompt, static_argnums=(1, 4))
+    k = write(resident, 0, kv, row, 4, jnp.asarray([6], jnp.int32))
+    for block, new in enumerate([False, True, False, True, False]):
+        np.testing.assert_array_equal(
+            np.asarray(k[0, block]),
+            np.ones((2, 4, 3)) if new else np.asarray(resident[0, block]))
+    k = write(resident, 0, kv, row, 4, jnp.asarray([6], jnp.int32),
+              jnp.asarray([1], jnp.int32))             # block 3 is a hit
+    np.testing.assert_array_equal(np.asarray(k[0, 3]),
+                                  np.asarray(resident[0, 3]))
+    np.testing.assert_array_equal(np.asarray(k[0, 1]), np.ones((2, 4, 3)))
+
+
+# --------------------------------------------------------------------- #
+# the one-row prefill through the block table (the dense twin and the
+# helpers: test_serving.py)
+# --------------------------------------------------------------------- #
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["paged", "paged-prefix-caching"])
+def resident_paged(request, cfg, params):
+    # block_len 5 against a bucket of 8: every prompt ends in a partial
+    # block, and a short one leaves its second table entry unreserved
+    return resident_engine(cfg, params, kv_layout="paged", kv_block_len=5,
+                           prefix_caching=request.param)
+
+
+@pytest.mark.parametrize("admit", ADMIT_SUBSETS, ids=admit_id)
+def test_paged_prefill_computes_and_writes_only_admitted_slots(
+        resident_paged, cfg, params, admit):
+    check_prefill_admits(resident_paged, cfg, params, admit)
 
 
 # --------------------------------------------------------------------- #

@@ -192,10 +192,10 @@ def _in(scope: str, path: str) -> bool:
     return scope in path.split("/")
 
 
-def _check_serving_scopes(lines, cfg, engine):
+def _check_serving_scopes(lines, cfg, engine, rows=None):
     writes = _scopes_of(lines, "dynamic-update-slice", _cache_shape(engine))
-    # per layer: keys and values, one write per slot
-    assert len(writes) == cfg.num_layers * 2 * engine.num_slots
+    # per layer: keys and values, one write per row (decode: per slot)
+    assert len(writes) == cfg.num_layers * 2 * (rows or engine.num_slots)
     assert all(_in("kv_write", w) and not _in("attention", w)
                for w in writes)
     dots = _scopes_of(lines, "dot")
@@ -219,13 +219,12 @@ def test_decode_program_wears_the_scopes(cfg, params, layout):
 def test_prefill_program_wears_the_scopes(cfg, params):
     engine = make_engine(cfg, params)
     c = engine.cache
-    B, S = engine.num_slots, engine.prefill_len
+    one = jnp.ones((1,), jnp.int32)
     _check_serving_scopes(
         _hlo(engine._prefill_jit, engine.params, c.k, c.v, c.lengths,
-             engine._tok, engine._table_arg(),
-             jnp.asarray(engine._sample_seeds),
-             jnp.zeros((B, S), jnp.int32), jnp.ones((B,), jnp.int32),
-             jnp.ones((B,), bool)), cfg, engine)
+             engine._tok, jnp.int32(0), jnp.zeros((1, 1), jnp.int32), one,
+             jnp.zeros((1, engine.prefill_len), jnp.int32), one),
+        cfg, engine, rows=1)
 
 
 def test_training_step_wears_the_scopes():

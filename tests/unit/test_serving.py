@@ -10,6 +10,7 @@ per-token telemetry contract (``kind="serve"`` records through the PR 4
 sink, schema-gated by ``tools/telemetry_report.py --check``) and the
 cost model's decode-latency objective.
 """
+import contextlib
 import json
 import os
 import subprocess
@@ -95,16 +96,19 @@ def test_kv_cache_layout_and_token_writes():
     assert float(jnp.abs(jnp.asarray(np.asarray(k[1])[mask])).sum()) == 0.0
 
 
-def test_kv_cache_prompt_writes_respect_admit_mask():
-    c = init_cache(num_layers=1, num_slots=2, num_heads=2, head_dim=3,
+def test_kv_cache_prompt_write_touches_one_slots_lane():
+    c = init_cache(num_layers=2, num_slots=3, num_heads=2, head_dim=3,
                    max_len=6)
-    resident = c.k + 7.0        # slot state that must survive
-    kv = jnp.ones((2, 4, 2, 3), jnp.float32)       # [B, S, heads, dh]
-    admit = jnp.array([True, False])
-    k = kv_cache.write_prompt(resident, 0, kv, admit)
-    assert float(k[0, 0, :, :4, :].min()) == 1.0   # admitted: new rows
-    np.testing.assert_array_equal(np.asarray(k[0, 1]),
-                                  np.asarray(resident[0, 1]))
+    resident = c.k + 7.0        # every other lane must survive, bit for bit
+    kv = jnp.ones((1, 4, 2, 3), jnp.float32)       # [1, S, heads, dh]
+    k = jax.jit(kv_cache.write_prompt, static_argnums=1)(
+        resident, 1, kv, jnp.int32(2))             # the slot is traced
+    assert float(k[1, 2, :, :4, :].min()) == float(k[1, 2, :, :4].max()) \
+        == 1.0
+    keep = np.ones(k.shape, bool)
+    keep[1, 2, :, :4] = False
+    np.testing.assert_array_equal(np.asarray(k)[keep],
+                                  np.asarray(resident)[keep])
 
 
 def test_cached_attention_masks_beyond_length():
@@ -120,6 +124,243 @@ def test_cached_attention_masks_beyond_length():
         (jnp.arange(T) > lengths[:, None])[:, None, :, None], 1e9, 0.0)
     out2 = kv_cache.cached_attention(q, k + poison, v + poison, lengths)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+# --------------------------------------------------------------------- #
+# the one-row prefill: it computes and writes the admitted slots only
+# (shared with test_paged_kv.py and test_looped_block.py)
+# --------------------------------------------------------------------- #
+PREFILL_SLOTS = 3
+ADMIT_SUBSETS = [tuple(bool(m >> s & 1) for s in range(PREFILL_SLOTS))
+                 for m in range(1 << PREFILL_SLOTS)]
+admit_id = lambda admit: "admit-" + "".join("01"[a] for a in admit)
+
+
+def sequential_prefill(cfg, params, prompt):
+    """The sequential reference of one prompt's prefill: the training
+    stack's own layer function over the unpadded prompt, pass by pass
+    and layer by layer.  ``(first greedy token, k rows, v rows)``, the
+    rows ``[cache_layers, heads, len(prompt), head_dim]``."""
+    from autodist_tpu.models import pipeline_lm as lm
+
+    stages, shared = params["stages"], params["shared"]
+    toks = jnp.asarray(prompt)[None]
+    n = toks.shape[1]
+    x = shared["embedding"][toks]
+    if cfg.block.positions == "learned":
+        x = x + shared["pos_embed"][None, :n]
+    x = x.astype(cfg.dtype)
+    mask = jnp.tril(jnp.ones((n, n), bool))[None, None]
+    ks, vs = [], []
+    for _ in range(cfg.block.loop_steps):
+        for i in range(cfg.num_layers):
+            chunk = jax.tree.map(lambda a, _i=i: a[_i], stages)
+            x, k, v = lm._tp_encoder_layer(cfg, chunk, x, mask, None,
+                                           return_kv=True)
+            ks.append(k[0])
+            vs.append(v[0])                            # [n, heads, dh]
+        if cfg.block.loop_steps > 1:
+            x = lm.final_norm(cfg, shared, x)
+    logits = lm.head_rows(cfg, shared, x[0, -1]).astype(jnp.float32) \
+        @ lm.head_table(cfg, shared).T.astype(jnp.float32)
+    rows = lambda xs: np.stack([np.transpose(np.asarray(a), (1, 0, 2))
+                                for a in xs])
+    return int(jnp.argmax(logits)), rows(ks), rows(vs)
+
+
+def slot_lane(engine, arr, slot):
+    """``slot``'s logical lane of a cache array, ``[cache_layers, heads,
+    positions, head_dim]`` (numpy) — through its block-table row under
+    the paged layout."""
+    arr = np.asarray(arr)
+    if engine.kv_layout != "paged":
+        return arr[:, slot]
+    got = arr[:, engine._table[slot]]              # [L, mb, H, bl, dh]
+    L, mb, H, bl, dh = got.shape
+    return np.transpose(got, (0, 2, 1, 3, 4)).reshape(L, H, mb * bl, dh)
+
+
+def resident_engine(cfg, params, prefill_len=8, **kw):
+    """An engine whose every slot holds a request with a decode window
+    behind it: the state a prefill must leave alone."""
+    eng = ServingEngine(cfg, params, num_slots=PREFILL_SLOTS, max_len=24,
+                        prefill_len=prefill_len, decode_steps=2, **kw)
+    rng = np.random.default_rng(7)
+    p_lens = np.array([4, prefill_len, 2])
+    for slot, n in enumerate(p_lens):           # a no-op for a dense cache
+        eng.reserve_slot(slot, int(n), 6)
+    eng.prefill(rng.integers(0, cfg.vocab_size, (PREFILL_SLOTS, prefill_len)),
+                p_lens, np.ones(PREFILL_SLOTS, bool))
+    eng.decode(np.ones(PREFILL_SLOTS, bool))
+    return eng
+
+
+def check_prefill_admits(engine, cfg, params, admit):
+    """One ``prefill`` call admitting the slots of ``admit`` into a full
+    engine: the admitted slots hold token for token and cache row for
+    cache row what the sequential reference gives, and every other
+    slot's cache rows, length and token are bit-identical to before."""
+    admit = np.asarray(admit, bool)
+    rng = np.random.default_rng(int(np.packbits(admit)[0]) + 11)
+    S = engine.prefill_len
+    prompts = rng.integers(0, cfg.vocab_size, (PREFILL_SLOTS, S))
+    p_lens = rng.integers(1, S + 1, PREFILL_SLOTS)
+    for slot in np.flatnonzero(admit):          # no-ops for a dense cache
+        engine.release_slot(slot)
+        engine.reserve_slot(slot, int(p_lens[slot]), 6)
+    c = engine.cache
+    before = {"k": np.asarray(c.k), "v": np.asarray(c.v),
+              "lengths": engine.lengths.copy(),
+              "tok": np.asarray(engine._tok)}
+    telemetry.reset()
+    toks = engine.prefill(prompts, p_lens, admit)
+    counters = {m["name"]: m["value"]
+                for m in telemetry.get().registry.snapshot()
+                if m["kind"] == "counter"}
+    dispatch = [e["args"] for e in
+                telemetry.get().chrome_trace()["traceEvents"]
+                if e["name"] == "engine/prefill/dispatch"]
+    telemetry.reset()
+    # one call admitting n rows: n rows and n x prefill_len positions
+    assert counters.get("engine/prefill_rows", 0) == admit.sum()
+    assert counters.get("engine/prefill_positions", 0) == admit.sum() * S
+    assert [d["rows"] for d in dispatch] == [admit.sum()]
+    c = engine.cache
+    np.testing.assert_array_equal(toks, np.asarray(engine._tok))
+    for slot in range(PREFILL_SLOTS):
+        if not admit[slot]:
+            continue
+        n = int(p_lens[slot])
+        tok, k, v = sequential_prefill(cfg, params, prompts[slot, :n])
+        assert toks[slot] == tok and engine.lengths[slot] == n
+        for got, want in ((c.k, k), (c.v, v)):
+            np.testing.assert_allclose(
+                slot_lane(engine, got, slot)[:, :, :n], want, atol=2e-5,
+                rtol=0)
+    # everything the admitted slots do not own is as it was: the other
+    # slots' lanes (dense), every block of the pool but theirs (paged)
+    keep = np.ones(before["k"].shape, bool)
+    for slot in np.flatnonzero(admit):
+        if engine.kv_layout == "paged":
+            keep[:, engine._slot_blocks[slot]] = False
+        else:
+            keep[:, slot] = False
+    for name, got in (("k", c.k), ("v", c.v)):
+        np.testing.assert_array_equal(np.asarray(got)[keep],
+                                      before[name][keep])
+    np.testing.assert_array_equal(engine.lengths[~admit],
+                                  before["lengths"][~admit])
+    np.testing.assert_array_equal(toks[~admit], before["tok"][~admit])
+
+
+@pytest.fixture(scope="module", params=[1, 2], ids=["tp1", "tp2"])
+def resident_dense(request, cfg, params):
+    return resident_engine(cfg, params, tensor_parallel=request.param)
+
+
+@pytest.mark.parametrize("admit", ADMIT_SUBSETS, ids=admit_id)
+def test_prefill_computes_and_writes_only_admitted_slots(
+        resident_dense, cfg, params, admit):
+    check_prefill_admits(resident_dense, cfg, params, admit)
+
+
+# --------------------------------------------------------------------- #
+# the warm-up: the prefill program compiled with no request admitted
+# (shared with test_fleet.py)
+# --------------------------------------------------------------------- #
+class CompileEvents:
+    """jax's lowerings and backend compilations while ``counting``: the
+    benchmark's ``CompileCounter`` idiom, less the trace events (under
+    tp > 1 a program's second call looks its trace up again, in
+    microseconds, and reports that as one)."""
+
+    def __init__(self):
+        import jax.monitoring
+
+        self.active = False
+        self.events: list = []
+        jax.monitoring.register_event_duration_secs_listener(self._on)
+
+    def _on(self, name, secs, **kw):
+        if self.active and "/jax/core/compile/" in name \
+                and "jaxpr_trace" not in name:
+            self.events.append(name)
+
+    @contextlib.contextmanager
+    def counting(self):
+        self.events.clear()
+        self.active = True
+        try:
+            yield self.events
+        finally:
+            self.active = False
+
+
+@pytest.fixture(scope="module")
+def compile_events():
+    return CompileEvents()
+
+
+@pytest.mark.parametrize("kw", [
+    {}, {"tensor_parallel": 2},
+    {"kv_layout": "paged", "kv_block_len": 4},
+    {"kv_layout": "paged", "kv_block_len": 4, "prefix_caching": True},
+    {"kv_layout": "paged", "kv_block_len": 4, "prefill_chunk": 4},
+], ids=["dense", "dense-tp2", "paged", "paged-prefix", "paged-chunked"])
+def test_warm_prefill_compiles_and_admits_nothing(cfg, params,
+                                                  compile_events, kw):
+    """``warm_prefill`` on an engine with two requests resident and one
+    slot free: every length, the resident slots' tokens and cache rows
+    are bit-identical after it; no admission after the first warm-up
+    compiles anything, and the one into the slot it ran at gives the
+    sequential reference's token and rows."""
+    eng = ServingEngine(cfg, params, num_slots=PREFILL_SLOTS, max_len=24,
+                        prefill_len=8, decode_steps=2, **kw)
+    S = eng.max_prompt_tokens if eng.prefill_chunk else eng.prefill_len
+    rng = np.random.default_rng(5)
+    prompts = rng.integers(0, cfg.vocab_size, (PREFILL_SLOTS, S))
+    p_lens = np.array([4, 6, 3])
+    live = np.array([True, False, True])
+    telemetry.reset()
+    with compile_events.counting() as compiled:
+        eng.warm_prefill()
+    assert compiled                      # a fresh engine: this compiled
+    for slot in np.flatnonzero(live):           # a no-op for a dense cache
+        eng.reserve_slot(slot, int(p_lens[slot]), 6)
+    with compile_events.counting() as compiled:
+        eng.prefill(prompts, p_lens, live)
+    assert compiled == []                # the first admissions: nothing
+    eng.decode(live)
+    c = eng.cache
+    before = (np.asarray(c.k), np.asarray(c.v), eng.lengths.copy(),
+              np.asarray(eng._tok))
+    eng.warm_prefill()
+    c = eng.cache
+    after = (np.asarray(c.k), np.asarray(c.v), eng.lengths,
+             np.asarray(eng._tok))
+    np.testing.assert_array_equal(after[2], before[2])
+    np.testing.assert_array_equal(after[3][live], before[3][live])
+    for got, was in zip(after[:2], before[:2]):
+        if eng.kv_layout == "paged":            # no block takes a write
+            np.testing.assert_array_equal(got, was)
+        else:                                   # the free slot's lane may
+            np.testing.assert_array_equal(got[:, live], was[:, live])
+    eng.reserve_slot(1, int(p_lens[1]), 6)
+    with compile_events.counting() as compiled:
+        toks = eng.prefill(prompts, p_lens, ~live)
+    telemetry.reset()
+    assert compiled == []
+    tok, k, v = sequential_prefill(cfg, params, prompts[1, :p_lens[1]])
+    assert toks[1] == tok and eng.lengths[1] == p_lens[1]
+    np.testing.assert_allclose(
+        slot_lane(eng, eng.cache.k, 1)[:, :, :p_lens[1]], k, atol=2e-5,
+        rtol=0)
+    np.testing.assert_array_equal(toks[live], before[3][live])
+
+
+def test_warm_prefill_refuses_an_engine_with_no_free_slot(resident_dense):
+    with pytest.raises(RuntimeError, match="free slot"):
+        resident_dense.warm_prefill()
 
 
 # --------------------------------------------------------------------- #
@@ -325,6 +566,9 @@ def test_serving_telemetry_records_and_report(cfg, params, tmp_path):
                 if r.get("kind") == "counter"}
     assert counters["serve/requests"] == 2
     assert counters["serve/tokens"] >= 7
+    # both requests were admitted by one-row prefill dispatches
+    assert counters["engine/prefill_rows"] == 2
+    assert counters["engine/prefill_positions"] == 2 * 8
     hists = {r["name"] for r in recs if r.get("kind") == "histogram"}
     assert {"serve/ttft_ms", "serve/inter_token_ms"} <= hists
 
@@ -342,6 +586,33 @@ def test_serving_telemetry_records_and_report(cfg, params, tmp_path):
         f.write(json.dumps({"kind": "serve", "request": "x"}) + "\n")
     problems = telemetry_report.check_schema(str(tmp_path))
     assert any("serve record missing" in p for p in problems)
+
+
+@pytest.mark.parametrize("doctor,says", [
+    (lambda recs, trace: recs.pop(), "advanced together"),
+    (lambda recs, trace: recs[1].update(value=1), "at least one position"),
+    (lambda recs, trace: trace[0]["args"].pop("rows"), "without their `rows`"),
+    (lambda recs, trace: None, None),
+], ids=["one-counter", "positions-under-rows", "span-without-rows", "sound"])
+def test_schema_gate_holds_the_prefill_work_counters(tmp_path, doctor, says):
+    sys.path.insert(0, os.path.join(REPO, "tools"))
+    try:
+        import telemetry_report
+    finally:
+        sys.path.pop(0)
+    recs = [{"kind": "counter", "name": "engine/prefill_rows", "value": 3},
+            {"kind": "counter", "name": "engine/prefill_positions",
+             "value": 24}]
+    trace = [{"name": "engine/prefill/dispatch", "ph": "X", "ts": 0,
+              "dur": 5, "args": {"loop_steps": 1, "rows": 3}}]
+    doctor(recs, trace)
+    with open(tmp_path / "metrics.jsonl", "w") as f:
+        f.writelines(json.dumps(r) + "\n" for r in recs)
+    with open(tmp_path / "trace.json", "w") as f:
+        json.dump({"traceEvents": trace}, f)
+    problems = telemetry_report.check_schema(str(tmp_path))
+    assert (any(says in p for p in problems) if says else not problems), \
+        problems
 
 
 def test_record_event_contract():

@@ -247,6 +247,28 @@ def test_speculative_matches_vanilla_greedy(cfg, params, draft_params):
         assert_idle_accounting(e.draft)
 
 
+def test_prefill_counters_count_the_targets_program_only(cfg, params):
+    """Under speculation the nested draft engine prefills the same rows
+    through a program of its own; ``engine/prefill_rows`` and
+    ``engine/prefill_positions`` stay the target's, so the share of
+    useful positions is not halved — and a warm-up warms both."""
+    e = make_engine(cfg, params, speculative=2, draft_cfg=cfg,
+                    draft_params=params)
+    e.warm_prefill()
+    telemetry.reset()
+    run_single(e, PROMPT, MAX_NEW)
+    counters = {m["name"]: m["value"]
+                for m in telemetry.get().registry.snapshot()
+                if m["kind"] == "counter"}
+    spans = [ev["args"]["rows"] for ev in
+             telemetry.get().chrome_trace()["traceEvents"]
+             if ev["name"] == "engine/prefill/dispatch"]
+    telemetry.reset()
+    assert counters["engine/prefill_rows"] == 1
+    assert counters["engine/prefill_positions"] == e.prefill_len
+    assert spans == [1, 1]              # the target's and the draft's
+
+
 def test_sampled_parity_across_all_rungs(cfg, params, draft_params):
     """Seeded sampling (temperature 0.9) holds the same exactness:
     the position-keyed gumbel draw makes chunked prefill, the flash

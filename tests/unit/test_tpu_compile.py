@@ -1,6 +1,7 @@
-"""The decode kernel of the main serving path, compiled at real widths
-for a TPU that is described and not attached (the TPU's compiler is
-installed; nothing runs, so this says nothing about results or times).
+"""The decode kernel and the prefill program of the main serving path,
+compiled at real widths for a TPU that is described and not attached
+(the TPU's compiler is installed; nothing runs, so this says nothing
+about results or times).
 
 What the interpreter cannot show: that Mosaic takes the kernel's tiles
 as they are sliced, that its VMEM fits, and that the compiled call holds
@@ -67,3 +68,59 @@ def test_dense_decode_kernel_compiles_in_place(one_chip, L, B, H, T, d):
     # nothing of the cache's size but the kernel's own operands
     assert not re.findall(rf"= bf16\[{L},{B},{H},\d+,\d+\][^ ]* "
                           r"(?:copy|transpose|fusion)\(", text)
+
+
+# the two serving configurations of the benchmark at their own widths,
+# slots, lane and prompt bucket, cut to two layers: what the compiler
+# does with the one cache array does not depend on the depth
+@pytest.mark.parametrize("hidden,heads,mlp,vocab,max_len,bucket,block", [
+    (1280, 20, 5120, 50257, 1024, 512, {}),     # gpt2-large-postln
+    (2048, 16, 5632, 49152, 512, 256, dict(     # ouro-2.6b: 4 passes
+        norm="rmsnorm", norm_placement="sandwich", positions="rope",
+        rope_theta=1e6, ffn="swiglu", bias=False, tied_head=False,
+        loop_steps=4)),
+], ids=["heads64", "heads128-looped"])
+def test_one_row_prefill_compiles_in_place(one_chip, hidden, heads, mlp,
+                                           vocab, max_len, bucket, block):
+    """The prefill program holds the cache it is given, in the layout the
+    decode kernel reads it in: no op of the whole cache's shape but the
+    in-place writes, and temporaries the size of one row's activations
+    (PR 26 met a 6.4 GB copy here, on the chip, after the fact)."""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import BlockSpec, TransformerConfig
+    from autodist_tpu.serving import ServingEngine
+
+    bf16, slots = jnp.bfloat16, 8
+    cfg = TransformerConfig(
+        vocab_size=vocab, hidden_size=hidden, num_layers=2,
+        num_heads=heads, mlp_dim=mlp, max_len=max_len, dtype=bf16,
+        dropout_rate=0.0, attention_dropout_rate=0.0,
+        block=BlockSpec(**block))
+    params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
+                          lm.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    engine = ServingEngine(cfg, params, num_slots=slots, max_len=max_len,
+                           prefill_len=bucket, decode_steps=8)
+    sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                         sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    c = engine.cache
+    with jax.default_matmul_precision("default"):
+        compiled = engine._prefill_jit.lower(
+            jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
+            i32(slots), i32(slots), i32(), i32(1, 1), i32(1),
+            i32(1, bucket), i32(1)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * c.k.size * 2
+    # one row: [1, bucket, mlp] activations and a layer's weights at most
+    assert mem.temp_size_in_bytes < 256 << 20
+    text = compiled.as_text()
+    L, d = engine.cache_layers, cfg.head_dim
+    whole = rf"bf16\[{L},{slots},{heads},\d+,\d+\]"
+    assert not re.findall(rf"= {whole}[^ ]* (?:copy|transpose)\(", text)
+    # the cache as the decode kernel reads it: positions minor-most for
+    # heads under 128 (the chip's own choice), head_dim minor-most above
+    minor = "{3,4,2,1,0" if d < 128 else "{4,3,2,1,0"
+    layouts = set(re.findall(rf"{whole}(\{{[\d,]+)", text))
+    assert layouts == {minor}, layouts

@@ -29,6 +29,8 @@ identical names, reports, and pass/fail behavior — in
   exactly the policied boundaries' wire dtypes.
 * ``probe_decode`` — the serving decode window is buffer-clean,
   in-place, and one fused dispatch per K tokens.
+* ``probe_prefill`` — the serving prefill computes and writes, in place,
+  only the one row a dispatch admits.
 * ``probe_zero3`` — ZeRO-3 stores parameters only as shards across the
   step boundary, gathering per layer on demand.
 
@@ -68,6 +70,7 @@ from autodist_tpu.analysis.probes import (PROBES,  # noqa: E402,F401
                                           probe_collective_matmul,
                                           probe_decode,
                                           probe_pipeline_tp,
+                                          probe_prefill,
                                           probe_quantized,
                                           probe_single_replica,
                                           probe_steps_per_loop,

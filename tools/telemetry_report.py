@@ -49,6 +49,15 @@ _SERVE_KEYS = {"kind", "request", "tokens", "ttft_ms", "tokens_per_sec",
 # kv_layout="paged" but whose metrics lack the pool gauges means the
 # block accounting silently never ran — --check fails it.
 _KV_BLOCK_GAUGES = ("serve/kv_blocks_free", "serve/kv_blocks_used")
+# Prefill work counters (autodist_tpu/serving/engine.py): every prefill
+# dispatch advances engine/prefill_rows (rows the program computed) and
+# engine/prefill_positions (rows x the positions each spans, padding
+# included) together, and its engine/prefill/dispatch span says how many
+# rows it carried.  One counter without the other, fewer positions than
+# rows, or a dispatch span of such a run without its `rows` means the
+# accounting that says what the chip computed for the prompts it
+# admitted was dropped — --check fails it.
+_PREFILL_COUNTERS = ("engine/prefill_rows", "engine/prefill_positions")
 # Per-reshard records (autodist_tpu/elastic/reshard.py): one per
 # executed reshard — route taken (compiled fast path vs host-staged),
 # payload moved, and the host-memory high-water mark the staged route
@@ -434,6 +443,33 @@ def check_schema(run_dir: str) -> list[str]:
                     f"metrics.jsonl: serve records declare "
                     f"kv_layout=\"paged\" but the {gname} gauge is "
                     "missing — the block-pool accounting never emitted")
+
+    # The prefill work counters come together, and with them every
+    # prefill dispatch span names its rows (runs from before the
+    # counters carry neither and keep passing).
+    counters = {r.get("name"): r for r in records
+                if r.get("kind") == "counter"}
+    rows_c, pos_c = (counters.get(n) for n in _PREFILL_COUNTERS)
+    if (rows_c is None) != (pos_c is None):
+        problems.append(
+            f"metrics.jsonl: {_PREFILL_COUNTERS[0]} and "
+            f"{_PREFILL_COUNTERS[1]} are advanced together — one is "
+            "missing")
+    elif rows_c is not None:
+        if pos_c.get("value", 0) < rows_c.get("value", 0):
+            problems.append(
+                f"metrics.jsonl: {_PREFILL_COUNTERS[1]} = "
+                f"{pos_c.get('value')!r} is under "
+                f"{_PREFILL_COUNTERS[0]} = {rows_c.get('value')!r} — a "
+                "row spans at least one position")
+        bare = sum(1 for ev in trace_events
+                   if ev.get("name") == "engine/prefill/dispatch"
+                   and "rows" not in (ev.get("args") or {}))
+        if bare:
+            problems.append(
+                f"trace.json: {bare} engine/prefill/dispatch span(s) "
+                "without their `rows` argument in a run that counts "
+                "prefill rows")
 
     # A scale transition must come with the gauge for the trigger it
     # claims fired: the record says "queue depth crossed the line" —
