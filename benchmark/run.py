@@ -5,7 +5,9 @@
 builds the cell's system, warms every shape the window will use, measures
 for ``--seconds``, checks the outputs against the plain reference, and
 prints as its last line one JSON object: ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` (and ``breakdown`` in a traced run).
+``failed``, ``metrics``, ``device`` (and ``breakdown`` in a traced run),
+then ``checks``: every number ``correct`` compared, beside its limit (the
+same numbers are the last lines on standard error).
 ``--trace 0`` reports the cell's end-to-end metrics with the profiler
 off; ``--trace 1`` profiles a short steady slice and reports its
 per-layer metrics.  Without a TPU, with fewer chips than the cell asks
@@ -156,7 +158,8 @@ def main(argv=None) -> int:
         say("[rehearsal] counts only; a CPU run gives no time, rate or "
             "share of the device")
         print(json.dumps({"rehearsal": True, "correct": result["correct"],
-                          "counts": result["counts"]}))
+                          "counts": result["counts"],
+                          "checks": compared(result["checks"])}))
         return 0 if result["correct"] else 1
 
     section = "per_layer" if ctx.trace else "end_to_end"
@@ -180,8 +183,20 @@ def main(argv=None) -> int:
         line["breakdown"] = result["record"]["breakdown"]
     for name, value in sorted(result.get("counts", {}).items()):
         say(f"[count] {name} = {value}")
+    line["checks"] = compared(result["checks"])
     print(json.dumps(line), flush=True)
     return 0
+
+
+def compared(checks: list) -> dict:
+    """Every number ``correct`` compared, beside its limit, where the
+    record of a run at fault keeps it: printed here as the last lines on
+    standard error, and returned for the end of the result's line."""
+    for name, value, limit, ok, _ in checks:
+        print(f"[check] {name}: {value:.6g} (limit {limit:.6g}) -> "
+              f"{'ok' if ok else 'FAILED'}", file=sys.stderr, flush=True)
+    return {name: {"value": float(value), "limit": float(limit)}
+            for name, value, limit, _, _ in checks}
 
 
 if __name__ == "__main__":

@@ -12,6 +12,14 @@ that the batcher drives (``prefill`` and ``decode_window``) are wrapped
 here, so the moment a request's first and last token reach the host is
 read by this file, not taken from the program's ``Completion``.  A
 request is *due* when the driver saw the reply before it.
+
+``serve_tokens_per_s`` is the tokens that reached the host inside the
+window over the window's length: ``EngineProbe.delivered`` read where the
+window opens and after its last round.  Both ends lie between rounds and
+tokens arrive only inside one, so whole rounds are counted over exactly
+the time they took, whatever share of a request lies on either side.  The
+tails, ``attempted``, ``failed`` and the reference's sample stay on the
+requests that completed in the window.
 """
 from __future__ import annotations
 
@@ -78,6 +86,12 @@ class EngineProbe:
         self.decodes.append(
             (now, len(slots), live + len(slots) * (self.steps - 1) / 2.0))
         return w
+
+    def delivered(self) -> int:
+        """Tokens on the host so far, over every request, each capped at
+        what it asked: a fused window's steps past a request's end are
+        computed and thrown away, never delivered."""
+        return sum(min(n, self.asked[rid]) for rid, n in self.got.items())
 
 
 def serve(ctx, seed: int, seconds: float, say) -> dict:
@@ -149,6 +163,7 @@ def serve(ctx, seed: int, seconds: float, say) -> dict:
     # ---- the window --------------------------------------------------
     telemetry.reset()
     n_pre, n_dec = len(probe.prefills), len(probe.decodes)
+    at_open = probe.delivered()
     rounds = 0
     window.settle_heap()
     with window.profiled(ctx.out_dir, ctx.trace) as log_dir, \
@@ -162,16 +177,13 @@ def serve(ctx, seed: int, seconds: float, say) -> dict:
                 if now - t0 >= seconds:
                     break
             elapsed = now - t0
+    delivered = probe.delivered() - at_open
     t1 = t0 + elapsed
     out = {"setup_s": setup_s, "elapsed": elapsed, "log_dir": log_dir,
            "memory": device.memory_held(ctx.devices, say),
-           "compile_events": compiles.events,
-           "prefill_spans": [
-               e for e in telemetry.get().chrome_trace()["traceEvents"]
-               if e.get("name") == "serve/prefill"],
+           "compile_events": compiles.events, "delivered": delivered,
            "prefills": probe.prefills[n_pre:],
-           "decodes": probe.decodes[n_dec:],
-           "num_slots": engine.num_slots, "prefill_len": engine.prefill_len}
+           "decodes": probe.decodes[n_dec:]}
 
     finished = [(rid, batcher.completions[rid])
                 for rid, at in observed.items() if t0 < at <= t1]
@@ -187,23 +199,21 @@ def serve(ctx, seed: int, seconds: float, say) -> dict:
                  / (len(c.tokens) - 1) for rid, c in good
                  if len(c.tokens) > 1],
         program_ttft_ms=[c.ttft_s * 1e3 for _, c in good],
-        quarter_tokens=[sum(len(c.tokens) for rid, c in good if
-                            q < (observed[rid] - t0) * 4 / elapsed <= q + 1)
-                        for q in range(4)],
         served=[(requests[rid], c.tokens) for rid, c in
                 _sample(good, requests, mix["sample_requests"], seed)])
     out["counts"] = {
         "requests_completed": len(finished), "requests_failed": failed,
-        "tokens_generated": out["tokens"], "scheduler_rounds": rounds,
+        "tokens_generated": out["tokens"],
+        "tokens_delivered_in_window": delivered, "scheduler_rounds": rounds,
         "prefill_dispatches": len(out["prefills"]),
         "requests_admitted": sum(p[1] for p in out["prefills"]),
         "prompt_tokens_admitted": sum(p[2] for p in out["prefills"]),
         "decode_dispatches": len(out["decodes"]),
-        "program_prefill_spans": len(out["prefill_spans"]),
         "compilations_in_window": len(compiles.events),
     }
     say(f"[window] {len(finished)} requests completed ({failed} failed), "
-        f"{out['tokens']} tokens, {rounds} rounds, "
+        f"{out['tokens']} tokens of theirs, {delivered} tokens delivered "
+        f"inside the window, {rounds} rounds, "
         f"{len(out['prefills'])} prefill and {len(out['decodes'])} decode "
         f"dispatches; compile events inside: {len(compiles.events)} "
         f"{sorted(set(compiles.events))}; {gc_timer}")
@@ -243,36 +253,34 @@ def run(ctx, say) -> dict:
     result = {"correct": all(c[3] for c in checks),
               "attempted": got["finished"], "failed": got["failed"],
               "memory": got["memory"],
-              "counts": got["counts"]}
+              "counts": got["counts"], "checks": checks}
     if ctx.rehearse:
         return result
+    rate = got["delivered"] / got["elapsed"]
+    say(f"[window] {got['elapsed']:.3f} s; {rate:.2f} tokens/s delivered "
+        f"inside it ({got['tokens'] / got['elapsed']:.2f} by the tokens of "
+        f"the requests completed in it)")
+    result["end_to_end"] = {"serve_tokens_per_s": rate,
+                            "setup_s": got["setup_s"]}
     ttft, tpot = got["ttft_ms"], got["tpot_ms"]
-    say(f"[window] {got['elapsed']:.3f} s; {len(ttft)} requests in the "
-        f"tails; ttft ms p50 {stats.percentile(ttft, 50):.1f} p95 "
-        f"{stats.percentile(ttft, 95):.1f} max {max(ttft):.1f}; tpot ms "
-        f"p50 {stats.percentile(tpot, 50):.2f} p95 "
-        f"{stats.percentile(tpot, 95):.2f}; the program's own "
-        f"Completion.ttft_s p95 "
-        f"{stats.percentile(got['program_ttft_ms'], 95):.1f} ms")
-    # whether a longer window would steady the rate: quarters of one run
-    # that differ as much as whole runs do say yes, quarters that agree
-    # while runs differ say the difference is the process's, not chance
-    say("[window] tokens/s by quarter of the window: "
-        f"{[round(4 * n / got['elapsed'], 1) for n in got['quarter_tokens']]}")
-    result["end_to_end"] = {
-        "serve_tokens_per_s": got["tokens"] / got["elapsed"],
-        "ttft_p95_ms": stats.percentile(ttft, 95),
-        "tpot_p95_ms": stats.percentile(tpot, 95),
-        "setup_s": got["setup_s"],
-    }
+    if ttft and tpot:     # a window too short to complete a request has none
+        say(f"[window] {len(ttft)} requests in the tails; ttft ms p50 "
+            f"{stats.percentile(ttft, 50):.1f} p95 "
+            f"{stats.percentile(ttft, 95):.1f} max {max(ttft):.1f}; tpot ms "
+            f"p50 {stats.percentile(tpot, 50):.2f} p95 "
+            f"{stats.percentile(tpot, 95):.2f}; the program's own "
+            f"Completion.ttft_s p95 "
+            f"{stats.percentile(got['program_ttft_ms'], 95):.1f} ms")
+        result["end_to_end"].update(
+            ttft_p95_ms=stats.percentile(ttft, 95),
+            tpot_p95_ms=stats.percentile(tpot, 95))
     if ctx.trace:
         record = window.reduce_profile(got["log_dir"])
         record.update(
             cfg=cfg, traffic=mix, chips=ctx.chips, peaks=ctx.peaks,
             flops=loader.load_module("flops", ctx.cell["config"]),
             programs=cfg["trace_programs"],
-            **{k: got[k] for k in ("prefills", "decodes", "prefill_spans",
-                                   "num_slots", "prefill_len")})
+            prefills=got["prefills"], decodes=got["decodes"])
         result["record"] = record
     return result
 

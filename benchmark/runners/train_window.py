@@ -197,7 +197,7 @@ def run(ctx, say) -> dict:
     correct = all(c[3] for c in checks)
 
     result = {"correct": correct, "attempted": steps_done, "failed": 0,
-              "memory": memory, "counts": counts}
+              "memory": memory, "counts": counts, "checks": checks}
     if ctx.rehearse:
         return result
     rate = tokens / elapsed

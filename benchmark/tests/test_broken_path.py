@@ -106,9 +106,22 @@ def test_training_window_repeats_its_first_step(capsys, monkeypatch):
 
 
 def test_serving_sound(capsys):
+    from harness import loader
+
     rc, line, _ = _last_line(capsys, SERVE)
     assert rc == 0 and line["correct"] is True
-    assert line["counts"]["requests_completed"] > 0
+    counts = line["counts"]
+    assert counts["requests_completed"] > 0
+    # both counts of one window: the tokens of the requests that completed
+    # inside it, and the tokens that reached the host inside it.  They
+    # differ by the partial request a caller holds at either end.
+    mix = loader.sized(loader.read_json("traffic", "closed-loop.json"), True)
+    room = mix["callers"] * mix["output_tokens"]["max"]
+    assert counts["tokens_delivered_in_window"] > 0
+    assert abs(counts["tokens_delivered_in_window"]
+               - counts["tokens_generated"]) <= room
+    assert list(line)[-1] == "checks"
+    assert line["checks"]["logit_gap"]["limit"] > 0
 
 
 def test_serving_token_altered_where_it_is_produced(capsys, monkeypatch):
@@ -126,6 +139,8 @@ def test_serving_token_altered_where_it_is_produced(capsys, monkeypatch):
     rc, line, out = _last_line(capsys, SERVE)
     assert rc == 1 and line["correct"] is False
     assert any("logit_gap" in l and "FAILED" in l for l in out)
+    gap = line["checks"]["logit_gap"]
+    assert gap["value"] > gap["limit"]
 
 
 def test_training_leaves_out_the_exchange_between_chips(capsys, monkeypatch):
