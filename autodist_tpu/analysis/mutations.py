@@ -525,7 +525,7 @@ class BlockTraceMutation:
 
 def _block_trace_mutations() -> list[BlockTraceMutation]:
     def drop_cow(t):
-        # The engine skips _cow_protect: the copy and the ref-drop
+        # PagedLayout.protect is skipped: the copy and the ref-drop
         # vanish and the write lands on the still-shared source.
         i = t.index(("cow", 2, 3))
         return t[:i] + [("write", 2)] + t[i + 3:] \

@@ -146,7 +146,7 @@ def lint_block_trace(events, where: str = "block-trace") -> LintReport:
                     where=where, rule="block_cow_trace",
                     fix="copy the shared block into a private one and "
                         "redirect the writer's table row before the "
-                        "write (the engine's _cow_protect)"))
+                        "write (kv_cache.PagedLayout.protect)"))
             elif n == 0:
                 out.append(Diagnostic(
                     "ADT116",

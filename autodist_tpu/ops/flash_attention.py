@@ -132,8 +132,7 @@ def _resolve_blocks(seq_len: int, causal: bool,
 
 def flash_wins(seq_len: int, causal: bool) -> Optional[bool]:
     """Whether measurement says flash beats einsum at this length;
-    ``None`` when unmeasured (callers keep their own default — the bench
-    self-tuner then probes both).  Reads the per-length ``speedup``
+    ``None`` when unmeasured (callers keep their own default).  Reads the per-length ``speedup``
     records the crossover tool writes (nearest measured length), falling
     back to a hand-written ``crossover_len``."""
     br = _branch(causal)

@@ -1,15 +1,15 @@
 """Shared retry/backoff policy — the ONE implementation of
 "try again, a little later, but not forever".
 
-Before this module, every plane hand-rolled its own loop: ``bench.py``'s
-UNAVAILABLE fresh-process backoff, the coordination client's ambiguous
-``None``/``OSError`` returns on a dropped socket, and ``Saver.save``'s
-nothing (one failed write killed the run).  A fleet-scale runtime
-retries in many places but must do it *identically* — capped exponential
-backoff, seeded jitter (deterministic in tests, de-synchronized in
-production), a hard deadline, and a typed "gave up" error — so
-:class:`RetryPolicy` is that one implementation and everything else
-adopts it:
+Before this module, every plane hand-rolled its own loop: a benchmark
+script's UNAVAILABLE fresh-process backoff, the coordination client's
+ambiguous ``None``/``OSError`` returns on a dropped socket, and
+``Saver.save``'s nothing (one failed write killed the run).  A
+fleet-scale runtime retries in many places but must do it
+*identically* — capped exponential backoff, seeded jitter
+(deterministic in tests, de-synchronized in production), a hard
+deadline, and a typed "gave up" error — so :class:`RetryPolicy` is
+that one implementation and everything else adopts it:
 
 * :class:`~autodist_tpu.runtime.coordination.CoordClient` — reconnect
   and retry on dropped/stale sockets, ``CoordUnavailableError`` when
@@ -17,10 +17,7 @@ adopts it:
 * :meth:`~autodist_tpu.checkpoint.saver.Saver.save` — bounded retries
   on write failure, then a coded degrade on the last good checkpoint;
 * the :class:`~autodist_tpu.runtime.cluster.Coordinator`'s supervised
-  worker restarts (backoff between restart attempts);
-* ``bench.py``'s fresh-process backoff (delay math deduped onto
-  :func:`backoff_delay`; the re-exec loop itself cannot use
-  :meth:`RetryPolicy.call` — each attempt is a new interpreter).
+  worker restarts (backoff between restart attempts).
 
 The policy never fires on success: the first attempt is a plain call
 with zero added latency, so adopting it is byte-identical on the happy

@@ -364,7 +364,7 @@ class DisaggServer:
             if not self._queue:
                 return
             free = [i for i in range(eng.num_slots)
-                    if not eng._slot_blocks[i]
+                    if not eng.kv.slot_blocks(i)
                     and not any(r._src_slot == i
                                 and r.prefill_replica == pname
                                 and r.state in ("prefill", "prefilled")
@@ -475,10 +475,10 @@ class DisaggServer:
         best = None
         for pname, eng in self.decode_pool:
             free = [i for i in range(eng.num_slots)
-                    if not eng._slot_blocks[i]]
+                    if not eng.kv.slot_blocks(i)]
             if not free or blocks_needed > eng.free_blocks:
                 continue
-            load = sum(1 for b in eng._slot_blocks if b)
+            load = eng.num_slots - len(free)
             if best is None or (load, pname) < (best[0], best[1]):
                 best = (load, pname, eng, free[0])
         return best
@@ -519,8 +519,8 @@ class DisaggServer:
                     f"[{HandoffError.code}]\n"
                     + report.render("handoff plan"))
             dst.reserve_slot(dst_slot, p_len, req.max_new_tokens)
-            src_ids = src._slot_blocks[req._src_slot][:n]
-            dst_ids = dst._slot_blocks[dst_slot][:n]
+            src_ids = src.kv.slot_blocks(req._src_slot)[:n]
+            dst_ids = dst.kv.slot_blocks(dst_slot)[:n]
             fn = self._handoff_fn(n)
             t0 = time.perf_counter()
             k, v, lengths, tok = fn(
@@ -532,9 +532,8 @@ class DisaggServer:
                 jnp.int32(req.tokens[0]))
             jax.block_until_ready(k)
             dt_ms = (time.perf_counter() - t0) * 1e3
-            dst.cache = kv_cache.PagedKVCache(
-                k=k, v=v, lengths=lengths,
-                block_table=dst.cache.block_table)
+            dst.cache = dataclasses.replace(dst.cache, k=k, v=v,
+                                            lengths=lengths)
             dst._tok = tok
             dst._sample_seeds[dst_slot] = req.seed
             src.release_slot(req._src_slot)

@@ -281,25 +281,6 @@ class ServingEngine:
             raise ValueError("kv_block_len must be >= 1")
         self.max_blocks = kv_cache.blocks_for(self.max_len,
                                               self.kv_block_len)
-        # ---- the dense decode attention: the fused kernel where it
-        # wins, from what can be observed here — a TPU under the
-        # programs, a lane the kernel's blocks divide, heads narrow
-        # enough for the chip to keep the positions minor-most (the
-        # kernel's view of the cache is then the array itself), a lane
-        # long enough by the chip's own readings.  Elsewhere, and on the
-        # CPU always, cached_attention.
-        self._fused_block = None    # the kernel's block, read in place
-        forced = bool(self.kernel.get("flash_decode"))
-        if self.kv_layout == "dense" and (forced or (
-                decode_left_open and jax.default_backend() == "tpu")):
-            from autodist_tpu.kernel.pallas.flash_decode import (
-                MIN_FUSED_DECODE_LEN, fused_decode_block)
-            block = fused_decode_block(self.max_len, cfg.head_dim)
-            if forced:
-                self._fused_block = block
-            elif block and self.max_len >= MIN_FUSED_DECODE_LEN:
-                self._fused_block = block
-                self.kernel = dict(self.kernel, flash_decode=True)
         # Default pool: byte parity with the dense cache (num_slots full
         # lanes) — the capacity win comes from admitting MORE slots than
         # the pool could hold at max_len, gated on free blocks.
@@ -397,58 +378,44 @@ class ServingEngine:
         elif self._device is not None:
             self._tok = jax.device_put(self._tok, self._device)
         self._sample_seeds = np.zeros((self.num_slots,), np.int32)
+        # ---- the cache layout (kv_cache.py's seam): picked here, once;
+        # everything below meets it through ``self.kv`` alone ------------
+        dims = (self.cache_layers, self.num_slots, cfg.num_heads,
+                cfg.head_dim, self.max_len)
         if self.kv_layout == "paged":
-            cache = kv_cache.init_paged_cache(
-                self.cache_layers, self.num_slots, cfg.num_heads,
-                cfg.head_dim, self.max_len,
-                block_len=self.kv_block_len,
-                num_blocks=self.kv_num_blocks, dtype=cfg.dtype)
-            # Host-side block accounting: the free-list allocator and
-            # the numpy mirror of the device block table (refreshed
-            # into the compiled programs as a replicated input).
-            self._allocator = kv_cache.BlockAllocator(self.kv_num_blocks)
-            self._table = np.zeros((self.num_slots, self.max_blocks),
-                                   np.int32)
-            self._slot_blocks: list = [[] for _ in range(self.num_slots)]
-            # Prefix-cache state: block-content keys -> ready physical
-            # block (registered only AFTER the owning prefill dispatch
-            # wrote it — a same-batch sibling must never share an
-            # unwritten block), the reverse map for retirement at
-            # refcount 0, per-slot novel-write floor and hit telemetry,
-            # registrations pending the prefill, and the CoW reserve
-            # pool: one pre-allocated replacement block per extra
-            # reference on a shared *partial-tail* block, so a
-            # copy-on-write can never hit an exhausted pool mid-stream.
-            self._prefix_index: dict = {}
-            self._block_keys: dict = {}
-            self._pending_register: dict = {}
-            self._cow_reserve: dict = {}
-            self._write_from = np.zeros((self.num_slots,), np.int32)
-            self._slot_hits = np.zeros((self.num_slots,), np.int32)
-            if self.mesh is not None:
-                csh = NamedSharding(self.mesh, kv_cache.cache_spec())
-                rep = NamedSharding(self.mesh, P())
-                cache = kv_cache.PagedKVCache(
-                    k=jax.device_put(cache.k, csh),
-                    v=jax.device_put(cache.v, csh),
-                    lengths=jax.device_put(cache.lengths, rep),
-                    block_table=jax.device_put(cache.block_table, rep))
-            self._emit_block_gauges()
+            self.kv = kv_cache.PagedLayout(
+                dims, self.kernel, block_len=self.kv_block_len,
+                num_blocks=self.kv_num_blocks,
+                prefix_caching=self.prefix_caching)
         else:
-            cache = kv_cache.init_cache(
-                self.cache_layers, self.num_slots, cfg.num_heads,
-                cfg.head_dim, self.max_len,
-                dtype=cfg.dtype)
-            self._allocator = None
-            # no table: one unused column, the programs' table operand
-            self._table = np.zeros((self.num_slots, 1), np.int32)
-            if self.mesh is not None:
-                csh = NamedSharding(self.mesh, kv_cache.cache_spec())
-                cache = kv_cache.KVCache(
-                    k=jax.device_put(cache.k, csh),
-                    v=jax.device_put(cache.v, csh),
-                    lengths=jax.device_put(
-                        cache.lengths, NamedSharding(self.mesh, P())))
+            # The dense decode attention: the fused kernel where it
+            # wins, from what can be observed here — a TPU under the
+            # programs, a lane the kernel's blocks divide, heads narrow
+            # enough for the chip to keep the positions minor-most (the
+            # kernel's view of the cache is then the array itself), a
+            # lane long enough by the chip's own readings.  Elsewhere,
+            # and on the CPU always, cached_attention.
+            fused_block = None    # the kernel's block, read in place
+            forced = bool(self.kernel.get("flash_decode"))
+            if forced or (decode_left_open
+                          and jax.default_backend() == "tpu"):
+                from autodist_tpu.kernel.pallas.flash_decode import (
+                    MIN_FUSED_DECODE_LEN, fused_decode_block)
+                block = fused_decode_block(self.max_len, cfg.head_dim)
+                if forced:
+                    fused_block = block
+                elif block and self.max_len >= MIN_FUSED_DECODE_LEN:
+                    fused_block = block
+                    self.kernel = dict(self.kernel, flash_decode=True)
+            self.kv = kv_cache.DenseLayout(dims, self.kernel,
+                                           fused_block=fused_block)
+        cache = self.kv.init_cache(dims, cfg.dtype)
+        if self.mesh is not None:
+            # the k/v arrays split by heads, everything else replicated
+            csh = NamedSharding(self.mesh, kv_cache.cache_spec())
+            rep = NamedSharding(self.mesh, P())
+            cache = jax.tree.map(lambda a: jax.device_put(
+                a, csh if a.ndim == 5 else rep), cache)
         if self._device is not None:
             cache = jax.device_put(cache, self._device)
         self.cache = cache
@@ -462,7 +429,6 @@ class ServingEngine:
                              else self._build_prefill())
         self._decode_jit = self._build_decode()
         self._decode1_jit = None           # lazy K=1 program (catch-up)
-        self._copy_block_jit = None        # lazy CoW device copy
         self.last_prefill_chunks = 0
         self._counts_prefill = True
 
@@ -565,101 +531,23 @@ class ServingEngine:
                                  comm_overlap=self.comm_overlap,
                                  return_kv=True, positions=positions)
 
-    def _layer_decode(self, chunk, x, kc, vc, layer, lengths, table=None,
-                      active=None):
-        """One encoder layer for a single-token step: project, write
-        this layer's k/v into the cache in place (through the block
-        table under the paged layout, suppressed for inactive slots
-        whose table rows hold no reservation), attend over the cache
-        slice — or both at once in the fused dense kernel, where it is
-        elected.  ``layer`` is the CACHE layer (an int, or traced under
-        a looped stack); the sub-blocks around the attention are the
-        pipelined LM's own (``attention_inputs`` .. ``ffn_residual``)."""
+    def _layer_cached(self, chunk, x, kc, vc, positions, attend):
+        """One encoder layer for tokens at ``positions`` against the
+        live cache — a single-token decode step, or the ``[B, C]``
+        window chunked prefill and the speculative verify pass share.
+        Project qkv, then ``attend(q, k, v, kc, vc) -> (out, kc, vc)``
+        writes this layer's k/v in place and attends over the cache,
+        which by then holds every earlier position AND these rows
+        (write-then-attend) — how, and whether as one kernel, is the
+        cache layout's (``self.kv``).  The sub-blocks around the
+        attention are the pipelined LM's own (``attention_inputs`` ..
+        ``ffn_residual``)."""
         from autodist_tpu.models import pipeline_lm as lm
 
         cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
-        dtype = cfg.dtype
-        x, q, k, v = lm.attention_inputs(cfg, chunk, x, lengths[:, None],
-                                         axis, overlap)  # [B, 1, heads, dh]
-        # the cache writes wear their own scope (kv_write), so the
-        # attention scope is left for them and entered again; the fused
-        # dense kernel writes the step's rows itself, as it reads
-        fused = None if table is not None else self._fused_block
-        if table is not None:
-            bl = self.kv_block_len
-            kc = kv_cache.paged_write_token(kc, layer, k, lengths,
-                                            table, bl, write_mask=active)
-            vc = kv_cache.paged_write_token(vc, layer, v, lengths,
-                                            table, bl, write_mask=active)
-        elif not fused:
-            kc = kv_cache.write_token(kc, layer, k, lengths)
-            vc = kv_cache.write_token(vc, layer, v, lengths)
-        with telemetry.scope("attention"):
-            if table is not None:
-                if self.kernel.get("flash_decode"):
-                    from autodist_tpu.kernel.pallas.flash_decode import \
-                        flash_decode_attention_paged
-                    out = flash_decode_attention_paged(
-                        q, kc[layer], vc[layer], lengths, table,
-                        block_len=bl, dtype=dtype)
-                else:
-                    out = kv_cache.paged_cached_attention(
-                        q, kc[layer], vc[layer], lengths, table,
-                        block_len=bl, dtype=dtype)
-            elif fused:
-                # The caches themselves, the layer an operand; a slot
-                # that is not decoding writes nothing and reads one block.
-                from autodist_tpu.kernel.pallas.flash_decode import \
-                    flash_decode_attention_dense
-                out, kc, vc = flash_decode_attention_dense(
-                    q, kc, vc, layer, lengths, new_kv=(k, v),
-                    active=active, dtype=dtype, block_k=fused)
-            elif self.kernel.get("flash_decode"):
-                # forced on a shape the kernel's view of the cache would
-                # copy whole: a copy of this layer's lanes instead
-                from autodist_tpu.kernel.pallas.flash_decode import \
-                    flash_decode_attention
-                out = flash_decode_attention(q, kc[layer], vc[layer],
-                                             lengths, dtype=dtype)
-            else:
-                out = kv_cache.cached_attention(q, kc[layer], vc[layer],
-                                                lengths, dtype=dtype)
-        x = lm.attention_residual(cfg, chunk, x, out, axis, overlap)
-        return lm.ffn_residual(cfg, chunk, x, axis, overlap), kc, vc
-
-    def _layer_chunk(self, chunk, x, kc, vc, layer, starts, table, write):
-        """One encoder layer for a ``[B, C]`` token *window* against the
-        live cache — the shape chunked prefill and the speculative
-        verify pass share.  Project the window's qkv, hand k/v to the
-        caller's ``write`` (block-granular for prompt chunks,
-        token-granular for the verify window), then attend the window's
-        queries over the cache — which now holds every earlier position
-        AND this window's own rows (write-then-attend, the decode
-        step's ordering), masked causally at ``key <= starts + row``."""
-        from autodist_tpu.models import pipeline_lm as lm
-
-        cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
-        dtype = cfg.dtype
-        positions = starts[:, None] + jnp.arange(x.shape[1])[None, :]
         x, q, k, v = lm.attention_inputs(cfg, chunk, x, positions, axis,
                                          overlap)        # [B, C, heads, dh]
-        kc, vc = write(kc, vc, k, v)                # scope: kv_write
-        with telemetry.scope("attention"):
-            if table is not None:
-                bl = self.kv_block_len
-                if self.kernel.get("flash_prefill"):
-                    from autodist_tpu.kernel.pallas.flash_prefill import \
-                        flash_prefill_attention_paged
-                    out = flash_prefill_attention_paged(
-                        q, kc[layer], vc[layer], starts, table,
-                        block_len=bl, dtype=dtype)
-                else:
-                    out = kv_cache.paged_chunk_attention(
-                        q, kc[layer], vc[layer], starts, table,
-                        block_len=bl, dtype=dtype)
-            else:
-                out = kv_cache.chunk_attention(q, kc[layer], vc[layer],
-                                               starts, dtype=dtype)
+        out, kc, vc = attend(q, k, v, kc, vc)
         x = lm.attention_residual(cfg, chunk, x, out, axis, overlap)
         return lm.ffn_residual(cfg, chunk, x, axis, overlap), kc, vc
 
@@ -757,12 +645,7 @@ class ServingEngine:
         nothing of any other slot is read or written."""
         self = weakref.proxy(self)      # see _wrap: no cycle through jit
         S = self.prefill_len
-        paged = self.kv_layout == "paged"
         prefix = self.prefix_caching
-        # heads of 128 and wider: the cache stays as the decode kernel
-        # reads it (narrower heads the chip keeps positions minor-most)
-        from autodist_tpu.kernel.pallas.flash_decode import rows_layout
-        row_major = rows_layout(self.cfg.head_dim)
 
         def prefill(params, kc, vc, lengths, tok, slot, table_row, seed,
                     prompt, p_len, *rest):
@@ -777,19 +660,8 @@ class ServingEngine:
 
             def layer_fn(chunk, x, kc, vc, _, layer):
                 x, k, v = self._layer_prefill(chunk, x, mask, positions)
-                if paged:
-                    kc = kv_cache.paged_write_prompt(
-                        kc, layer, k, table_row, self.kv_block_len, p_len,
-                        write_from=wf)
-                    vc = kv_cache.paged_write_prompt(
-                        vc, layer, v, table_row, self.kv_block_len, p_len,
-                        write_from=wf)
-                else:
-                    kc = kv_cache.write_prompt(kc, layer, k, slot)
-                    vc = kv_cache.write_prompt(vc, layer, v, slot)
-                    if row_major:
-                        kc = kv_cache.keep_row_major(kc)
-                        vc = kv_cache.keep_row_major(vc)
+                kc, vc = self.kv.write_prompt(kc, vc, layer, k, v, slot,
+                                              table_row, p_len, wf)
                 return x, kc, vc
 
             x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
@@ -819,7 +691,6 @@ class ServingEngine:
         token-for-token (the parity golden)."""
         self = weakref.proxy(self)
         C = self.prefill_chunk
-        bl = self.kv_block_len
         prefix = self.prefix_caching
 
         def chunk_prefill(params, kc, vc, lengths, tok, table, seeds,
@@ -831,17 +702,17 @@ class ServingEngine:
             starts = jnp.zeros_like(p_lens) + chunk_start
 
             def layer_fn(chunk, x, kc, vc, _, layer):
-                def write(kc, vc, k, v):
-                    kc = kv_cache.paged_write_chunk(
-                        kc, layer, k, admit, table, bl, chunk_start,
-                        p_lens, write_from=wf)
-                    vc = kv_cache.paged_write_chunk(
-                        vc, layer, v, admit, table, bl, chunk_start,
-                        p_lens, write_from=wf)
-                    return kc, vc
+                def attend(q, k, v, kc, vc):    # block-granular write
+                    kc, vc = self.kv.write_chunk(
+                        kc, vc, layer, k, v, admit, table, chunk_start,
+                        p_lens, wf)
+                    return self.kv.attend_window(
+                        q, kc, vc, layer, starts, table,
+                        dtype=self.cfg.dtype), kc, vc
 
-                return self._layer_chunk(chunk, x, kc, vc, layer, starts,
-                                         table, write)
+                return self._layer_cached(
+                    chunk, x, kc, vc,
+                    starts[:, None] + jnp.arange(C)[None, :], attend)
 
             x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
                                          layer_fn)
@@ -873,8 +744,6 @@ class ServingEngine:
         mask (their blocks stay within the slot's reservation)."""
         self = weakref.proxy(self)
         C = self.speculative + 1
-        paged = self.kv_layout == "paged"
-        bl = self.kv_block_len
 
         def verify(params, kc, vc, lengths, tok, table, seeds,
                    tokens_in, active):
@@ -883,24 +752,17 @@ class ServingEngine:
             x = self._embed(shared, tokens_in, positions)
 
             def layer_fn(chunk, x, kc, vc, _, layer):
-                def write(kc, vc, k, v):
+                def attend(q, k, v, kc, vc):    # token-granular write
                     for c in range(C):
-                        if paged:
-                            kc = kv_cache.paged_write_token(
-                                kc, layer, k[:, c:c + 1], lengths + c,
-                                table, bl, write_mask=active)
-                            vc = kv_cache.paged_write_token(
-                                vc, layer, v[:, c:c + 1], lengths + c,
-                                table, bl, write_mask=active)
-                        else:
-                            kc = kv_cache.write_token(
-                                kc, layer, k[:, c:c + 1], lengths + c)
-                            vc = kv_cache.write_token(
-                                vc, layer, v[:, c:c + 1], lengths + c)
-                    return kc, vc
+                        kc, vc = self.kv.write_token(
+                            kc, vc, layer, k[:, c:c + 1], v[:, c:c + 1],
+                            lengths + c, table, active)
+                    return self.kv.attend_window(
+                        q, kc, vc, layer, lengths, table,
+                        dtype=self.cfg.dtype), kc, vc
 
-                return self._layer_chunk(chunk, x, kc, vc, layer, lengths,
-                                         table if paged else None, write)
+                return self._layer_cached(chunk, x, kc, vc, positions,
+                                          attend)
 
             x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
                                          layer_fn)
@@ -918,7 +780,6 @@ class ServingEngine:
     def _build_decode(self, steps: Optional[int] = None):
         self = weakref.proxy(self)
         K = int(steps or self.decode_steps)
-        paged = self.kv_layout == "paged"
 
         def decode(params, kc, vc, lengths, tok, table, seeds, active):
             stages, shared = params["stages"], params["shared"]
@@ -928,9 +789,11 @@ class ServingEngine:
                 x = self._embed(shared, tok[:, None], lengths[:, None])
                 x, kc, vc = self._run_layers(
                     shared, stages, x, kc, vc,
-                    lambda chunk, x, kc, vc, _, layer: self._layer_decode(
-                        chunk, x, kc, vc, layer, lengths,
-                        table=table if paged else None, active=active))
+                    lambda chunk, x, kc, vc, _, layer: self._layer_cached(
+                        chunk, x, kc, vc, lengths[:, None],
+                        lambda q, k, v, kc, vc: self.kv.decode_attend(
+                            q, k, v, kc, vc, layer, lengths, table, active,
+                            dtype=self.cfg.dtype)))
                 # The emitted token conditions on lengths + 1 tokens
                 # (the one just written included) — its sampling key.
                 nxt, _ = self._next_token(shared, x[:, 0], seeds,
@@ -946,43 +809,9 @@ class ServingEngine:
         return self._wrap(decode, n_in_rest=5, n_out_rest=3)
 
     # ------------------------------------------------------------------ #
-    # host-side block accounting (the batcher's admission predicate)
+    # host-side block accounting (the batcher's admission predicate),
+    # delegated to the layout: kv_cache.PagedLayout holds the pool
     # ------------------------------------------------------------------ #
-    def _prefix_lookup(self, prompt, prompt_len):
-        """Walk the prefix index for ``prompt``'s leading blocks.
-        Returns ``(hits, novel, partial_hit)``: ``hits`` — physical
-        blocks already holding the shared prefix (a contiguous leading
-        run; the chained keys make the first miss terminal), ``novel``
-        — ``{logical_index: key}`` for the blocks THIS request must
-        compute (registered only after its prefill lands, so a same-
-        batch sharer can never read an unwritten block), and
-        ``partial_hit`` — the shared partial-tail physical block, or
-        ``None``.  A partial hit is the one shared block decode will
-        write into, so admission pre-funds its copy-on-write."""
-        if not self.prefix_caching or prompt is None:
-            return [], {}, None
-        toks = np.asarray(prompt).reshape(-1)[:int(prompt_len)]
-        full_keys, partial_key = kv_cache.prefix_block_keys(
-            toks, self.kv_block_len)
-        hits, novel, partial_hit = [], {}, None
-        miss = False
-        for j, key in enumerate(full_keys):
-            phys = None if miss else self._prefix_index.get(key)
-            if phys is None:
-                miss = True
-                novel[j] = key
-            else:
-                hits.append(phys)
-        if partial_key is not None:
-            j = len(full_keys)
-            phys = None if miss else self._prefix_index.get(partial_key)
-            if phys is None:
-                novel[j] = partial_key
-            else:
-                hits.append(phys)
-                partial_hit = phys
-        return hits, novel, partial_hit
-
     def blocks_needed(self, prompt_len: int, max_new_tokens: int,
                       prompt=None) -> int:
         """Pool blocks a request reserves: its worst-case occupancy
@@ -993,20 +822,14 @@ class ServingEngine:
         pre-funded copy-on-write reserve when the partial tail is
         shared: the block decode writes into must have a private copy
         standing by, or a full pool could deadlock the write)."""
-        if self.kv_layout != "paged":
-            return 0
-        span = min(int(prompt_len) + int(max_new_tokens), self.max_len)
-        n = kv_cache.blocks_for(span, self.kv_block_len)
-        hits, _, partial_hit = self._prefix_lookup(prompt, prompt_len)
-        return n - len(hits) + (1 if partial_hit is not None else 0)
+        return self.kv.blocks_needed(prompt_len, max_new_tokens, prompt)
 
     @property
     def free_blocks(self) -> int:
         """Unreserved pool blocks (dense: the pool concept is vacuous —
         reported as 0 used / 0 free is wrong either way, so dense
         returns a sentinel no admission check consults)."""
-        return (self._allocator.free_blocks
-                if self._allocator is not None else 0)
+        return self.kv.accounting()[0]
 
     def reserve_slot(self, slot: int, prompt_len: int,
                      max_new_tokens: int, prompt=None) -> int:
@@ -1022,85 +845,17 @@ class ServingEngine:
         raise here is a bookkeeping bug surfacing loudly (and it raises
         BEFORE any refcount is bumped, so a failed admission leaves the
         pool untouched)."""
-        if self._allocator is None:
-            return 0
-        if self._slot_blocks[slot]:
-            raise ValueError(f"slot {slot} already holds blocks "
-                             f"{self._slot_blocks[slot]}")
-        span = min(int(prompt_len) + int(max_new_tokens), self.max_len)
-        n = kv_cache.blocks_for(span, self.kv_block_len)
-        hits, novel, partial_hit = self._prefix_lookup(prompt, prompt_len)
-        n_hit = len(hits)
-        need = n - n_hit + (1 if partial_hit is not None else 0)
-        new_blocks = self._allocator.alloc(need)
-        if partial_hit is not None:
-            # The shared partial-tail block WILL be written (the first
-            # generated token lands inside it): park one replacement
-            # block per extra reference so the copy-on-write in
-            # _cow_protect never has to allocate mid-stream.
-            self._cow_reserve.setdefault(partial_hit, []).append(
-                new_blocks.pop())
-        for b in hits:
-            self._allocator.share(b)
-        blocks = hits + new_blocks
-        self._slot_blocks[slot] = blocks
-        self._write_from[slot] = n_hit
-        self._slot_hits[slot] = n_hit
-        if novel:
-            self._pending_register[slot] = novel
-        # Tail-fill the row with the slot's LAST block: an over-decode
-        # position past the reservation (a final fused window's
-        # overshoot, or the clamped >= max_len write) then routes into
-        # the slot's own tail block — never block 0, which may be
-        # another slot's live block.
-        self._table[slot, :] = blocks[-1]
-        self._table[slot, :n] = blocks
-        self._sync_table()
-        self._emit_block_gauges()
+        self.cache, n_hit = self.kv.reserve(
+            self.cache, slot, prompt_len, max_new_tokens, prompt)
         if self.draft is not None:
             self.draft.reserve_slot(slot, prompt_len, max_new_tokens)
         return n_hit
 
-    def _trim_reserves(self, block: int) -> None:
-        """Keep ``_cow_reserve[block]`` at one replacement per EXTRA
-        reference (``max(rc - 1, 0)``) — a sharer releasing, or a
-        copy-on-write consuming a reference, returns the now-surplus
-        reserve to the pool."""
-        pool = self._cow_reserve.get(block)
-        if pool is None:
-            return
-        want = max(self._allocator.refcount(block) - 1, 0)
-        while len(pool) > want:
-            self._allocator.free_one(pool.pop())
-        if not pool:
-            del self._cow_reserve[block]
-
-    def _free_blocks(self, blocks) -> None:
-        """Drop one reference per block; fully-released blocks retire
-        their prefix-index registration, and shared survivors shed any
-        now-surplus copy-on-write reserves."""
-        for b in blocks:
-            if self._allocator.free_one(b):
-                key = self._block_keys.pop(b, None)
-                if key is not None and self._prefix_index.get(key) == b:
-                    del self._prefix_index[key]
-            self._trim_reserves(b)
-
     def release_slot(self, slot: int) -> None:
         """Return ``slot``'s blocks to the free list (paged; dense is a
         no-op) — under prefix caching this drops ONE reference per
-        block, so shared prefixes survive their sharers.  The pool rows
-        keep their stale content — unreachable behind the next owner's
-        length mask."""
-        if self._allocator is not None and self._slot_blocks[slot]:
-            self._free_blocks(self._slot_blocks[slot])
-            self._slot_blocks[slot] = []
-            self._table[slot, :] = 0
-            self._pending_register.pop(slot, None)
-            self._write_from[slot] = 0
-            self._slot_hits[slot] = 0
-            self._sync_table()
-            self._emit_block_gauges()
+        block, so shared prefixes survive their sharers."""
+        self.cache = self.kv.release(self.cache, slot)
         if self.speculative is not None:
             self._spec_catch[slot] = False
         if self.draft is not None:
@@ -1111,10 +866,7 @@ class ServingEngine:
         terminal state must restore is ``free + used == total`` (and
         ``free == total`` once no request is resident).  Dense engines
         report the vacuous ``(0, 0, 0)``."""
-        if self._allocator is None:
-            return (0, 0, 0)
-        return (self._allocator.free_blocks, self._allocator.used_blocks,
-                self.kv_num_blocks)
+        return self.kv.accounting()
 
     def release_all_slots(self) -> None:
         """Return EVERY slot's blocks to the free list — the abandon
@@ -1123,115 +875,6 @@ class ServingEngine:
         in-process model must not let the bookkeeping say otherwise)."""
         for slot in range(self.num_slots):
             self.release_slot(slot)
-
-    def _emit_block_gauges(self):
-        telemetry.gauge("serve/kv_blocks_free").set(
-            self._allocator.free_blocks)
-        telemetry.gauge("serve/kv_blocks_used").set(
-            self._allocator.used_blocks)
-
-    def _sync_table(self):
-        """Mirror the host block table onto ``cache.block_table`` so
-        the live cache pytree IS the complete decode state (a consumer
-        serializing/inspecting ``engine.cache`` between dispatches —
-        elastic checkpointing, debug dumps — must never see a stale
-        mapping; the numpy ``_table`` stays the single source the
-        device copy reflects)."""
-        self.cache = kv_cache.PagedKVCache(
-            k=self.cache.k, v=self.cache.v, lengths=self.cache.lengths,
-            block_table=jnp.asarray(self._table))
-
-    def _table_arg(self):
-        if self.kv_layout == "paged":
-            return self.cache.block_table
-        return jnp.asarray(self._table)
-
-    # ------------------------------------------------------------------ #
-    # copy-on-write + prefix registration (the sharing protocol)
-    # ------------------------------------------------------------------ #
-    def _copy_block(self, src: int, dst: int) -> None:
-        """Device-copy pool block ``src`` into ``dst`` across every
-        layer's k/v pools (the copy-on-write data move)."""
-        if self._copy_block_jit is None:
-            self._copy_block_jit = jax.jit(kv_cache.copy_pool_block,
-                                           donate_argnums=(0, 1))
-        k, v = self._copy_block_jit(self.cache.k, self.cache.v,
-                                    jnp.int32(src), jnp.int32(dst))
-        self.cache = kv_cache.PagedKVCache(
-            k=k, v=v, lengths=self.cache.lengths,
-            block_table=self.cache.block_table)
-
-    def _cow_protect(self, active, lengths, n: int) -> None:
-        """The copy-on-write gate: before a dispatch writes positions
-        ``[L, L + n)`` of each active slot, any table entry in that
-        span whose physical block is shared (refcount > 1) is copied
-        into the slot's pre-funded reserve and the writer's row
-        redirected — the sharer keeps the pristine block, and the ADT
-        rule that no write goes through a shared table entry holds by
-        construction.  Every span block (post-redirect) is noted as a
-        ``write`` trace event so ``lint_block_trace`` can replay the
-        protocol."""
-        if self._allocator is None:
-            return
-        bl = self.kv_block_len
-        max_blocks = self._table.shape[1]
-        changed = False
-        for slot in range(self.num_slots):
-            if not active[slot]:
-                continue
-            L = int(lengths[slot])
-            lo = L // bl
-            hi = min((L + n - 1) // bl, max_blocks - 1)
-            for j in range(lo, hi + 1):
-                b = int(self._table[slot, j])
-                if self._allocator.refcount(b) > 1:
-                    pool = self._cow_reserve.get(b)
-                    if not pool:
-                        raise RuntimeError(
-                            f"shared block {b} in slot {slot}'s write "
-                            "span has no copy-on-write reserve — "
-                            "admission must pre-fund every extra "
-                            "reference on a writable block")
-                    r = pool.pop()
-                    if not pool:
-                        del self._cow_reserve[b]
-                    self._copy_block(b, r)
-                    # Redirect EVERY row entry holding b (tail-fill
-                    # duplicates included) — the slot must never write
-                    # through the shared id again.
-                    row = self._table[slot]
-                    row[row == b] = r
-                    self._slot_blocks[slot] = [
-                        r if x == b else x
-                        for x in self._slot_blocks[slot]]
-                    self._allocator.note("cow", b, r)
-                    self._allocator.free_one(b)
-                    self._trim_reserves(b)
-                    changed = True
-                self._allocator.note("write", int(self._table[slot, j]))
-        if changed:
-            self._sync_table()
-            self._emit_block_gauges()
-
-    def _flush_registration(self, admit) -> None:
-        """Publish the prefix keys of blocks the just-landed prefill
-        actually wrote.  Registration waits until AFTER the dispatch so
-        a same-batch request can never hit a block whose content is
-        still pending; two same-batch requests with equal prefixes each
-        keep private blocks and the first to flush wins the index."""
-        if not self.prefix_caching:
-            return
-        for slot in range(self.num_slots):
-            pend = self._pending_register.get(slot)
-            if not pend or not admit[slot]:
-                continue
-            blocks = self._slot_blocks[slot]
-            for j, key in pend.items():
-                if j >= len(blocks) or key in self._prefix_index:
-                    continue
-                self._prefix_index[key] = blocks[j]
-                self._block_keys[blocks[j]] = key
-            self._pending_register.pop(slot, None)
 
     # ------------------------------------------------------------------ #
     # host-side driver API (the batcher's contract)
@@ -1270,8 +913,8 @@ class ServingEngine:
                 # nothing mutates while a dispatch is in flight.
                 rows = np.flatnonzero(admit_np)
                 picked = [np.asarray(a[rows], np.int32) for a in (
-                    self._table, self._sample_seeds, prompts_np,
-                    p_lens_np, *((self._write_from,)
+                    self.kv.table, self._sample_seeds, prompts_np,
+                    p_lens_np, *((self.kv.write_from,)
                                  if self.prefix_caching else ()))]
         if self.prefill_chunk is None:
             with telemetry.span("engine/prefill/dispatch",
@@ -1282,14 +925,14 @@ class ServingEngine:
                     k, v, lengths, tok = self._prefill_jit(
                         self.params, c.k, c.v, c.lengths, self._tok,
                         np.int32(slot), *(a[i:i + 1] for a in picked))
-                    self.cache = self._rebuild_cache(k, v, lengths)
-                    self._tok = tok
+                    self._adopt(k, v, lengths, tok)
             self._count_prefill(len(rows), self.prefill_len)
             self.last_prefill_chunks = 1
         else:
             self._chunked_prefill(prompts_np, p_lens_np, admit_np)
         with telemetry.span("engine/prefill/register"):
-            self._flush_registration(admit_np)
+            if self.prefix_caching:
+                self.kv.register(admit_np)
             if self.draft is not None:
                 # The draft mirrors the target's resident prompts so its
                 # proposals condition on the same context; its
@@ -1317,15 +960,15 @@ class ServingEngine:
         with no slot admitted."""
         if self.prefill_chunk is None:
             zero = np.zeros((1,), np.int32)
-            return (np.int32(slot), np.zeros_like(self._table[:1]), zero,
+            return (np.int32(slot), np.zeros_like(self.kv.table[:1]), zero,
                     np.zeros((1, self.prefill_len), np.int32), zero,
                     *((zero,) if self.prefix_caching else ()))
-        B = self.num_slots
-        return (self._table_arg(), jnp.asarray(self._sample_seeds),
+        B, c = self.num_slots, self.cache
+        return (self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
                 jnp.zeros((B, self.prefill_chunk), jnp.int32),
                 jnp.int32(0), jnp.ones((B,), jnp.int32),
                 jnp.zeros((B,), bool),
-                *((jnp.asarray(self._write_from),)
+                *((jnp.asarray(self.kv.write_from),)
                   if self.prefix_caching else ()))
 
     def warm_prefill(self) -> None:
@@ -1355,8 +998,7 @@ class ServingEngine:
             k, v, lengths, tok = self._prefill_jit(
                 self.params, c.k, c.v, c.lengths, self._tok,
                 *self._blank_prefill_args(slot))
-            self.cache = self._rebuild_cache(k, v, lengths)
-            self._tok = tok
+            self._adopt(k, v, lengths, tok)
         self._count_prefill(rows, span)
         if self.draft is not None:
             self.draft.warm_prefill()
@@ -1369,7 +1011,7 @@ class ServingEngine:
         # cast on the host: jnp casting an int64 array is a program
         p_lens_j = jnp.asarray(p_lens_np.astype(np.int32))
         admit_j = jnp.asarray(admit_np)
-        rest = ((jnp.asarray(self._write_from),)
+        rest = ((jnp.asarray(self.kv.write_from),)
                 if self.prefix_caching else ())
         hi_len = int(p_lens_np[admit_np].max())
         n_chunks = kv_cache.blocks_for(hi_len, C)
@@ -1384,7 +1026,7 @@ class ServingEngine:
         # the first generated token samples from.
         first = 0
         if self.prefix_caching:
-            firsts = [min(int(self._write_from[i]) * self.kv_block_len,
+            firsts = [min(int(self.kv.write_from[i]) * self.kv_block_len,
                           int(p_lens_np[i]) - 1)
                       for i in range(self.num_slots) if admit_np[i]]
             first = min(firsts) // C
@@ -1394,15 +1036,14 @@ class ServingEngine:
             c = self.cache
             with telemetry.span("engine/prefill/stage"):
                 args = (self.params, c.k, c.v, c.lengths, self._tok,
-                        self._table_arg(), jnp.asarray(self._sample_seeds),
+                        self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
                         jnp.asarray(padded[:, cs:cs + C]),
                         jnp.int32(cs), p_lens_j, admit_j, *rest)
             with telemetry.span("engine/prefill/dispatch",
                                 loop_steps=self.cfg.block.loop_steps,
                                 rows=self.num_slots):
                 k, v, lengths, tok = self._prefill_jit(*args)
-                self.cache = self._rebuild_cache(k, v, lengths)
-                self._tok = tok
+                self._adopt(k, v, lengths, tok)
             dispatched += 1
         # the chunk program computes every slot's window
         self._count_prefill(dispatched * self.num_slots, C)
@@ -1414,18 +1055,15 @@ class ServingEngine:
         (numpy; inactive columns repeat the held token)."""
         with telemetry.span("engine/decode/stage"):
             active_np = np.asarray(active, bool)
-            if self.kv_layout == "paged":
-                self._cow_protect(active_np, self.lengths,
-                                  self.decode_steps)
-            c = self.cache
+            c = self.cache = self.kv.protect(self.cache, active_np,
+                                             self.decode_steps)
             args = (self.params, c.k, c.v, c.lengths, self._tok,
-                    self._table_arg(), jnp.asarray(self._sample_seeds),
+                    self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
                     jnp.asarray(active_np))
         with telemetry.span("engine/decode/dispatch",
                             loop_steps=self.cfg.block.loop_steps):
             k, v, lengths, tok, toks = self._decode_jit(*args)
-            self.cache = self._rebuild_cache(k, v, lengths)
-            self._tok = tok
+            self._adopt(k, v, lengths, tok)
         with telemetry.span("engine/decode/fetch"):
             return np.asarray(jax.device_get(toks))
 
@@ -1436,15 +1074,12 @@ class ServingEngine:
         if self._decode1_jit is None:
             self._decode1_jit = self._build_decode(steps=1)
         active_np = np.asarray(active, bool)
-        if self.kv_layout == "paged":
-            self._cow_protect(active_np, self.lengths, 1)
-        c = self.cache
+        c = self.cache = self.kv.protect(self.cache, active_np, 1)
         k, v, lengths, tok, toks = self._decode1_jit(
             self.params, c.k, c.v, c.lengths, self._tok,
-            self._table_arg(), jnp.asarray(self._sample_seeds),
+            self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
             jnp.asarray(active_np))
-        self.cache = self._rebuild_cache(k, v, lengths)
-        self._tok = tok
+        self._adopt(k, v, lengths, tok)
         return np.asarray(jax.device_get(toks))
 
     def decode_window(self, active) -> DecodeWindow:
@@ -1488,17 +1123,16 @@ class ServingEngine:
         proposals = self.draft.decode(active_np)           # [k, B]
         # 4. Verify: one target dispatch over [tok, d_1..d_k].
         lengths_np = self.lengths
-        if self.kv_layout == "paged":
-            self._cow_protect(active_np, lengths_np, ks + 1)
+        self.cache = self.kv.protect(self.cache, active_np, ks + 1)
         tokens_in = np.zeros((B, ks + 1), np.int64)
         tokens_in[:, 0] = tgt_tok
         tokens_in[:, 1:] = proposals.T
         c = self.cache
         k, v, lengths, tok, choices = self._spec_verify_jit(
             self.params, c.k, c.v, c.lengths, self._tok,
-            self._table_arg(), jnp.asarray(self._sample_seeds),
+            self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
             jnp.asarray(tokens_in, jnp.int32), jnp.asarray(active_np))
-        self.cache = self._rebuild_cache(k, v, lengths)
+        self._adopt(k, v, lengths, self._tok)
         choices_np = np.asarray(jax.device_get(choices))   # [B, k+1]
         # 5. Accept/reject + rollback (host-side lengths are the only
         # state that moves — stale verified rows hide behind them).
@@ -1535,14 +1169,12 @@ class ServingEngine:
         return DecodeWindow(tokens=tokens, counts=counts,
                             spec_proposed=proposed, spec_accepted=accepted)
 
-    def _rebuild_cache(self, k, v, lengths):
-        if self.kv_layout == "paged":
-            # block_table is kept current by _sync_table at every
-            # reserve/release — the programs consumed this same array.
-            return kv_cache.PagedKVCache(
-                k=k, v=v, lengths=lengths,
-                block_table=self.cache.block_table)
-        return kv_cache.KVCache(k=k, v=v, lengths=lengths)
+    def _adopt(self, k, v, lengths, tok) -> None:
+        """A program's outputs become the live state (a ``block_table``
+        is current since the last reserve/release: the program's own)."""
+        self.cache = dataclasses.replace(self.cache, k=k, v=v,
+                                         lengths=lengths)
+        self._tok = tok
 
     @property
     def lengths(self):
@@ -1553,9 +1185,7 @@ class ServingEngine:
         """Positions of a dense lane that the decode attention reads or
         skips as one: the fused kernel's block, the whole lane under
         ``cached_attention``; ``None`` for a paged cache."""
-        if self.kv_layout != "dense":
-            return None
-        return self._fused_block or self.max_len
+        return self.kv.decode_block_len
 
     # ------------------------------------------------------------------ #
     # HLO probe hooks (tools/hlo_probe.py --probe decode)
@@ -1566,7 +1196,7 @@ class ServingEngine:
         active = jnp.ones((self.num_slots,), bool)
         return self._decode_jit.lower(
             self.params, c.k, c.v, c.lengths, self._tok,
-            self._table_arg(), jnp.asarray(self._sample_seeds),
+            self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
             active).compile().as_text()
 
     def compiled_prefill_text(self) -> str:

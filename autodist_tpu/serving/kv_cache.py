@@ -56,7 +56,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from autodist_tpu import const
-from autodist_tpu.telemetry import scope
+from autodist_tpu.telemetry import gauge, scope
 
 
 def cache_spec() -> P:
@@ -64,6 +64,7 @@ def cache_spec() -> P:
     return P(None, None, const.MODEL_AXIS, None, None)
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class KVCache:
     """The decode-time state: cache arrays + per-slot occupancy.
@@ -78,17 +79,6 @@ class KVCache:
     k: Any
     v: Any
     lengths: Any
-
-    def tree_flatten(self):
-        return (self.k, self.v, self.lengths), None
-
-    @classmethod
-    def tree_unflatten(cls, _, leaves):
-        return cls(*leaves)
-
-
-jax.tree_util.register_pytree_node(
-    KVCache, KVCache.tree_flatten, KVCache.tree_unflatten)
 
 
 def init_cache(num_layers: int, num_slots: int, num_heads: int,
@@ -329,6 +319,7 @@ def prefix_block_keys(prompt, block_len: int):
     return full_keys, partial_key
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class PagedKVCache:
     """The paged decode state: block pools + table + occupancy.
@@ -345,17 +336,6 @@ class PagedKVCache:
     v: Any
     lengths: Any
     block_table: Any
-
-    def tree_flatten(self):
-        return (self.k, self.v, self.lengths, self.block_table), None
-
-    @classmethod
-    def tree_unflatten(cls, _, leaves):
-        return cls(*leaves)
-
-
-jax.tree_util.register_pytree_node(
-    PagedKVCache, PagedKVCache.tree_flatten, PagedKVCache.tree_unflatten)
 
 
 def init_paged_cache(num_layers: int, num_slots: int, num_heads: int,
@@ -569,3 +549,391 @@ def paged_cached_attention(q, k_pool, v_pool, lengths, block_table, *,
     k_layer = gather_blocks(k_pool, block_table)
     v_layer = gather_blocks(v_pool, block_table)
     return cached_attention(q, k_layer, v_layer, lengths, dtype=dtype)
+
+
+# --------------------------------------------------------------------------- #
+# The seam: how the engine meets a layout
+# --------------------------------------------------------------------------- #
+# ``ServingEngine`` picks one of the two classes below once, from
+# ``kv_layout``: its programs call the traced methods where a layer
+# writes or reads the cache, its host API delegates the accounting (a
+# method that can change the table takes the live cache and hands it
+# back).  A new format is one more class, in this module alone.
+def _both(write, kc, vc, layer, k, v, *a, **kw):
+    """``write`` the keys into ``kc`` and the values into ``vc``."""
+    return write(kc, layer, k, *a, **kw), write(vc, layer, v, *a, **kw)
+
+
+class DenseLayout:
+    """Per-slot ``max_len`` lanes.  A slot owns its lane, so there is no
+    pool to account for: the host methods are constants that touch no
+    device, and admission gates on slots alone.  ``fused_block``: the
+    block with which the fused decode kernel reads the cache in place
+    and writes the step's rows itself — the engine's election, made
+    where backend, ``max_len`` and ``head_dim`` can be observed; without
+    one, :func:`write_token` then :func:`cached_attention`."""
+
+    def __init__(self, dims, kernel, *, fused_block=None):
+        from autodist_tpu.kernel.pallas.flash_decode import rows_layout
+
+        _, num_slots, _, head_dim, self.max_len = dims
+        self.kernel = kernel        # the engine's elections, by name
+        self.fused_block = fused_block
+        # heads of 128 and wider: the cache stays as the decode kernel
+        # reads it (narrower heads the chip keeps positions minor-most)
+        self._row_major = rows_layout(head_dim)
+        # no table: one unused column, the programs' table operand
+        self.table = np.zeros((num_slots, 1), np.int32)
+
+    def init_cache(self, dims, dtype) -> KVCache:
+        return init_cache(*dims, dtype=dtype)
+
+    # ---- traced ------------------------------------------------------ #
+    def write_prompt(self, kc, vc, layer, k, v, slot, table_row, p_len,
+                     write_from):
+        kc, vc = _both(write_prompt, kc, vc, layer, k, v, slot)
+        if self._row_major:
+            kc, vc = keep_row_major(kc), keep_row_major(vc)
+        return kc, vc
+
+    def write_token(self, kc, vc, layer, k, v, positions, table, active):
+        return _both(write_token, kc, vc, layer, k, v, positions)
+
+    def decode_attend(self, q, k, v, kc, vc, layer, lengths, table, active,
+                      *, dtype):
+        """``(out, kc, vc)``: the step's rows written at ``lengths`` and
+        ``q`` attended over ``layer`` — at once in the fused kernel,
+        which writes the rows itself, as it reads."""
+        fused = self.fused_block
+        if not fused:
+            kc, vc = self.write_token(kc, vc, layer, k, v, lengths, table,
+                                      active)
+        with scope("attention"):
+            if fused:
+                # The caches themselves, the layer an operand; a slot
+                # that is not decoding writes nothing and reads one block.
+                from autodist_tpu.kernel.pallas.flash_decode import \
+                    flash_decode_attention_dense
+                out, kc, vc = flash_decode_attention_dense(
+                    q, kc, vc, layer, lengths, new_kv=(k, v),
+                    active=active, dtype=dtype, block_k=fused)
+            elif self.kernel.get("flash_decode"):
+                # forced on a shape the kernel's view of the cache would
+                # copy whole: a copy of this layer's lanes instead
+                from autodist_tpu.kernel.pallas.flash_decode import \
+                    flash_decode_attention
+                out = flash_decode_attention(q, kc[layer], vc[layer],
+                                             lengths, dtype=dtype)
+            else:
+                out = cached_attention(q, kc[layer], vc[layer], lengths,
+                                       dtype=dtype)
+        return out, kc, vc
+
+    def attend_window(self, q, kc, vc, layer, starts, table, *, dtype):
+        with scope("attention"):
+            return chunk_attention(q, kc[layer], vc[layer], starts,
+                                   dtype=dtype)
+
+    # ---- host -------------------------------------------------------- #
+    def table_arg(self, cache):
+        return jnp.asarray(self.table)
+
+    @property
+    def decode_block_len(self) -> int:
+        return self.fused_block or self.max_len
+
+    def blocks_needed(self, prompt_len, max_new_tokens, prompt=None) -> int:
+        return 0
+
+    def accounting(self) -> tuple:
+        return (0, 0, 0)
+
+    def reserve(self, cache, slot, prompt_len, max_new_tokens, prompt=None):
+        return cache, 0
+
+    def release(self, cache, slot):
+        return cache
+
+    def protect(self, cache, active, n):
+        return cache
+
+
+class PagedLayout:
+    """The block pool, the per-slot table and who holds which block:
+    the free-list allocator, the prefix index, the copy-on-write
+    reserve.  The numpy ``table`` is the single source its device mirror
+    ``cache.block_table`` reflects; a method that changes it hands back
+    a cache whose mirror is current, so the cache pytree IS the complete
+    decode state (serialized or inspected between dispatches — elastic
+    checkpointing, debug dumps — it never shows a stale mapping)."""
+
+    decode_block_len = None     # nothing reads a paged lane as one
+
+    def __init__(self, dims, kernel, *, block_len: int, num_blocks: int,
+                 prefix_caching: bool = False):
+        _, self.num_slots, _, _, self.max_len = dims
+        self.kernel = kernel        # the engine's elections, by name
+        self.block_len = block_len
+        self.prefix_caching = prefix_caching
+        # Host-side block accounting: the free-list allocator and the
+        # numpy mirror of the device block table (refreshed into the
+        # compiled programs as a replicated input).
+        self.allocator = BlockAllocator(num_blocks)
+        self.table = np.zeros(
+            (self.num_slots, blocks_for(self.max_len, block_len)), np.int32)
+        self._slot_blocks: list = [[] for _ in range(self.num_slots)]
+        # Prefix-cache state: block-content keys -> ready physical
+        # block (registered only AFTER the owning prefill dispatch
+        # wrote it — a same-batch sibling must never share an
+        # unwritten block), the reverse map for retirement at
+        # refcount 0, per-slot novel-write floor, registrations pending
+        # the prefill, and the CoW reserve pool: one pre-allocated
+        # replacement block per extra reference on a shared
+        # *partial-tail* block, so a copy-on-write can never hit an
+        # exhausted pool mid-stream.
+        self._prefix_index: dict = {}
+        self._block_keys: dict = {}
+        self._pending_register: dict = {}
+        self._cow_reserve: dict = {}
+        self.write_from = np.zeros((self.num_slots,), np.int32)
+        self._copy_block = jax.jit(copy_pool_block, donate_argnums=(0, 1))
+        self._emit_gauges()
+
+    def init_cache(self, dims, dtype) -> PagedKVCache:
+        return init_paged_cache(*dims, block_len=self.block_len, dtype=dtype,
+                                num_blocks=self.allocator.num_blocks)
+
+    # ---- traced ------------------------------------------------------ #
+    def write_prompt(self, kc, vc, layer, k, v, slot, table_row, p_len,
+                     write_from):
+        return _both(paged_write_prompt, kc, vc, layer, k, v, table_row,
+                     self.block_len, p_len, write_from=write_from)
+
+    def write_token(self, kc, vc, layer, k, v, positions, table, active):
+        return _both(paged_write_token, kc, vc, layer, k, v, positions,
+                     table, self.block_len, write_mask=active)
+
+    def write_chunk(self, kc, vc, layer, k, v, admit, table, chunk_start,
+                    p_lens, write_from):
+        return _both(paged_write_chunk, kc, vc, layer, k, v, admit, table,
+                     self.block_len, chunk_start, p_lens, write_from)
+
+    def decode_attend(self, q, k, v, kc, vc, layer, lengths, table, active,
+                      *, dtype):
+        """``(out, kc, vc)``, as :meth:`DenseLayout.decode_attend`."""
+        kc, vc = self.write_token(kc, vc, layer, k, v, lengths, table,
+                                  active)
+        if self.kernel.get("flash_decode"):
+            from autodist_tpu.kernel.pallas.flash_decode import \
+                flash_decode_attention_paged as attend
+        else:
+            attend = paged_cached_attention
+        with scope("attention"):
+            return attend(q, kc[layer], vc[layer], lengths, table,
+                          block_len=self.block_len, dtype=dtype), kc, vc
+
+    def attend_window(self, q, kc, vc, layer, starts, table, *, dtype):
+        if self.kernel.get("flash_prefill"):
+            from autodist_tpu.kernel.pallas.flash_prefill import \
+                flash_prefill_attention_paged as attend
+        else:
+            attend = paged_chunk_attention
+        with scope("attention"):
+            return attend(q, kc[layer], vc[layer], starts, table,
+                          block_len=self.block_len, dtype=dtype)
+
+    # ---- host: the batcher's admission predicate ---------------------- #
+    def table_arg(self, cache):
+        return cache.block_table
+
+    def accounting(self) -> tuple:
+        return (self.allocator.free_blocks, self.allocator.used_blocks,
+                self.allocator.num_blocks)
+
+    def slot_blocks(self, slot: int) -> list:
+        """``slot``'s pool blocks in logical order; none, and it is free."""
+        return list(self._slot_blocks[slot])
+
+    def _prefix_lookup(self, prompt, prompt_len):
+        """Walk the prefix index for ``prompt``'s leading blocks.
+        Returns ``(hits, novel, partial_hit)``: ``hits`` — physical
+        blocks already holding the shared prefix (a contiguous leading
+        run; the chained keys make the first miss terminal), ``novel``
+        — ``{logical_index: key}`` for the blocks THIS request must
+        compute (registered only after its prefill lands, so a same-
+        batch sharer can never read an unwritten block), and
+        ``partial_hit`` — the shared partial-tail physical block, or
+        ``None``.  A partial hit is the one shared block decode will
+        write into, so admission pre-funds its copy-on-write."""
+        if not self.prefix_caching or prompt is None:
+            return [], {}, None
+        toks = np.asarray(prompt).reshape(-1)[:int(prompt_len)]
+        full_keys, partial_key = prefix_block_keys(toks, self.block_len)
+        keys = full_keys + ([partial_key] if partial_key is not None else [])
+        hits, novel = [], {}
+        for j, key in enumerate(keys):
+            # the first miss is terminal: novel is non-empty from there
+            phys = None if novel else self._prefix_index.get(key)
+            if phys is None:
+                novel[j] = key
+            else:
+                hits.append(phys)
+        shared_tail = partial_key is not None and not novel
+        return hits, novel, hits[-1] if shared_tail else None
+
+    def blocks_needed(self, prompt_len, max_new_tokens, prompt=None) -> int:
+        span = min(int(prompt_len) + int(max_new_tokens), self.max_len)
+        n = blocks_for(span, self.block_len)
+        hits, _, partial_hit = self._prefix_lookup(prompt, prompt_len)
+        return n - len(hits) + (1 if partial_hit is not None else 0)
+
+    def reserve(self, cache, slot, prompt_len, max_new_tokens, prompt=None):
+        """``ServingEngine.reserve_slot``: ``(cache, prefix-hit blocks)``."""
+        if self._slot_blocks[slot]:
+            raise ValueError(f"slot {slot} already holds blocks "
+                             f"{self._slot_blocks[slot]}")
+        span = min(int(prompt_len) + int(max_new_tokens), self.max_len)
+        n = blocks_for(span, self.block_len)
+        hits, novel, partial_hit = self._prefix_lookup(prompt, prompt_len)
+        n_hit = len(hits)
+        need = n - n_hit + (1 if partial_hit is not None else 0)
+        new_blocks = self.allocator.alloc(need)
+        if partial_hit is not None:
+            # The shared partial-tail block WILL be written (the first
+            # generated token lands inside it): park one replacement
+            # block per extra reference so the copy-on-write in
+            # protect never has to allocate mid-stream.
+            self._cow_reserve.setdefault(partial_hit, []).append(
+                new_blocks.pop())
+        for b in hits:
+            self.allocator.share(b)
+        blocks = hits + new_blocks
+        self._slot_blocks[slot] = blocks
+        self.write_from[slot] = n_hit
+        if novel:
+            self._pending_register[slot] = novel
+        # Tail-fill the row with the slot's LAST block: an over-decode
+        # position past the reservation (a final fused window's
+        # overshoot, or the clamped >= max_len write) then routes into
+        # the slot's own tail block — never block 0, which may be
+        # another slot's live block.
+        self.table[slot, :] = blocks[-1]
+        self.table[slot, :n] = blocks
+        return self._sync(cache), n_hit
+
+    def _trim_reserves(self, block: int) -> None:
+        """Keep ``_cow_reserve[block]`` at one replacement per EXTRA
+        reference (``max(rc - 1, 0)``) — a sharer releasing, or a
+        copy-on-write consuming a reference, returns the now-surplus
+        reserve to the pool."""
+        pool = self._cow_reserve.get(block)
+        if pool is None:
+            return
+        want = max(self.allocator.refcount(block) - 1, 0)
+        while len(pool) > want:
+            self.allocator.free_one(pool.pop())
+        if not pool:
+            del self._cow_reserve[block]
+
+    def release(self, cache, slot):
+        """Drop one reference per block of ``slot``; fully-released
+        blocks retire their prefix-index registration, and shared
+        survivors shed any now-surplus copy-on-write reserves.  The pool
+        rows keep their stale content — unreachable behind the next
+        owner's length mask."""
+        if not self._slot_blocks[slot]:
+            return cache
+        for b in self._slot_blocks[slot]:
+            if self.allocator.free_one(b):
+                key = self._block_keys.pop(b, None)
+                if key is not None and self._prefix_index.get(key) == b:
+                    del self._prefix_index[key]
+            self._trim_reserves(b)
+        self._slot_blocks[slot] = []
+        self.table[slot, :] = 0
+        self._pending_register.pop(slot, None)
+        self.write_from[slot] = 0
+        return self._sync(cache)
+
+    def _emit_gauges(self):
+        gauge("serve/kv_blocks_free").set(self.allocator.free_blocks)
+        gauge("serve/kv_blocks_used").set(self.allocator.used_blocks)
+
+    def _sync(self, cache) -> PagedKVCache:
+        """``cache`` with the host table mirrored onto its
+        ``block_table``, and the gauges brought up to date."""
+        self._emit_gauges()
+        return dataclasses.replace(cache,
+                                   block_table=jnp.asarray(self.table))
+
+    # ---- host: copy-on-write + prefix registration -------------------- #
+    def protect(self, cache, active, n: int):
+        """The copy-on-write gate: before a dispatch writes positions
+        ``[L, L + n)`` of each active slot, any table entry in that
+        span whose physical block is shared (refcount > 1) is copied
+        into the slot's pre-funded reserve and the writer's row
+        redirected — the sharer keeps the pristine block, and the ADT
+        rule that no write goes through a shared table entry holds by
+        construction.  Every span block (post-redirect) is noted as a
+        ``write`` trace event so ``lint_block_trace`` can replay the
+        protocol."""
+        lengths = np.asarray(jax.device_get(cache.lengths))
+        bl = self.block_len
+        max_blocks = self.table.shape[1]
+        changed = False
+        for slot in range(self.num_slots):
+            if not active[slot]:
+                continue
+            L = int(lengths[slot])
+            lo = L // bl
+            hi = min((L + n - 1) // bl, max_blocks - 1)
+            for j in range(lo, hi + 1):
+                b = int(self.table[slot, j])
+                if self.allocator.refcount(b) > 1:
+                    pool = self._cow_reserve.get(b)
+                    if not pool:
+                        raise RuntimeError(
+                            f"shared block {b} in slot {slot}'s write "
+                            "span has no copy-on-write reserve — "
+                            "admission must pre-fund every extra "
+                            "reference on a writable block")
+                    r = pool.pop()
+                    if not pool:
+                        del self._cow_reserve[b]
+                    # the data move: block b into r across every
+                    # layer's k/v pools
+                    k, v = self._copy_block(
+                        cache.k, cache.v, jnp.int32(b), jnp.int32(r))
+                    cache = dataclasses.replace(cache, k=k, v=v)
+                    # Redirect EVERY row entry holding b (tail-fill
+                    # duplicates included) — the slot must never write
+                    # through the shared id again.
+                    row = self.table[slot]
+                    row[row == b] = r
+                    self._slot_blocks[slot] = [
+                        r if x == b else x
+                        for x in self._slot_blocks[slot]]
+                    self.allocator.note("cow", b, r)
+                    self.allocator.free_one(b)
+                    self._trim_reserves(b)
+                    changed = True
+                self.allocator.note("write", int(self.table[slot, j]))
+        return self._sync(cache) if changed else cache
+
+    def register(self, admit) -> None:
+        """Publish the prefix keys of blocks the just-landed prefill
+        actually wrote.  Registration waits until AFTER the dispatch so
+        a same-batch request can never hit a block whose content is
+        still pending; two same-batch requests with equal prefixes each
+        keep private blocks and the first to flush wins the index."""
+        for slot in range(self.num_slots):
+            pend = self._pending_register.get(slot)
+            if not pend or not admit[slot]:
+                continue
+            blocks = self._slot_blocks[slot]
+            for j, key in pend.items():
+                if j >= len(blocks) or key in self._prefix_index:
+                    continue
+                self._prefix_index[key] = blocks[j]
+                self._block_keys[blocks[j]] = key
+            self._pending_register.pop(slot, None)

@@ -233,11 +233,11 @@ def rank_serving(trainable, resource_spec, candidates=None, *,
 
     The throughput-ladder inputs describe the TRAFFIC, not the config:
     ``prefix_hit_rate`` (fraction of a typical request's blocks shared
-    with a resident prefix — measure it with ``bench.py serve
-    --prompt-mix shared-prefix``) prices ``prefix_caching`` candidates
+    with a resident prefix — the caller's to measure on its own
+    traffic) prices ``prefix_caching`` candidates
     both directions under the capacity objective;
-    ``spec_acceptance`` (draft acceptance rate α — measure it with
-    ``bench.py serve --speculative``) prices ``speculative``
+    ``spec_acceptance`` (draft acceptance rate α, likewise measured by
+    the caller) prices ``speculative``
     candidates both directions under latency.  ``ladder=True`` widens
     the default zoo with the rung candidates
     (:func:`default_serving_candidates` ``ladder=``)."""

@@ -97,8 +97,8 @@ QUANT_PROFILE: dict = {
 
 # Fused-kernel tier pricing (the Strategy IR ``kernel`` slot, PR 13) —
 # analytic defaults; a ``"kernel"`` section in calibration.json
-# (written mechanically from ``tools/flash_crossover.py --decode`` /
-# ``bench.py flash`` measurements) replaces them like ``"link"`` and
+# (``tools/flash_crossover.py --prefill --write-calibration`` writes
+# the prefill constants) replaces them like ``"link"`` and
 # ``"quant"``:
 #
 # * ``quant_ring_wire_factor`` — the EQuARX ring's TRUE-s8 wire vs the
@@ -153,8 +153,7 @@ KERNEL_PROFILE: dict = {
     #   token costs well under a step (the whole point of verifying a
     #   window at once).
     # * ``spec_acceptance_default`` — the acceptance rate assumed when
-    #   the caller has not measured one (``bench.py serve
-    #   --speculative`` measures; the recipe in ROADMAP.md records it).
+    #   the caller has not measured one.
     "flash_prefill_crossover_chunk": 128,
     "flash_prefill_speedup": 1.5,
     "flash_prefill_short_penalty": 0.85,
@@ -172,8 +171,8 @@ KERNEL_PROFILE: dict = {
     # the composed sandwich's HBM-shaped converts) but pays 2(n-1) hop
     # launches per dispatch+combine pair where the monolithic collective
     # pays 2 — so the ring wins exactly when the payload is large enough
-    # that the q/dq saving clears the extra alphas (``bench.py moe``
-    # measures both on silicon).
+    # that the q/dq saving clears the extra alphas (analytic: neither
+    # has been measured on silicon).
     "a2a_ring_wire_factor": 0.25,
     "a2a_ring_qdq_factor": 0.5,
 }
@@ -237,7 +236,7 @@ def load_calibration(path: Optional[str] = None) -> dict:
             # emits) replace the analytic q/dq defaults the same way.
             QUANT_PROFILE.update(dict(data.get("quant", {})))
             # Measured fused-kernel constants (``tools/flash_crossover
-            # .py --decode`` / ``bench.py flash``) replace the kernel
+            # .py --prefill --write-calibration``) replace the kernel
             # tier's analytic defaults the same way.
             KERNEL_PROFILE.update(dict(data.get("kernel", {})))
             return factors

@@ -264,7 +264,7 @@ class Telemetry:
 
     def manifest(self) -> dict:
         """The run manifest: provenance (git SHA, jax/jaxlib versions —
-        the identity stamp ``bench.py`` embeds in every record) plus
+        the identity stamp of every measurement record) plus
         run-level annotations and telemetry bookkeeping."""
         from autodist_tpu.telemetry import records
 

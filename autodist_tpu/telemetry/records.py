@@ -1,7 +1,7 @@
 """Run manifest + provenance: the identity stamp of a measurement.
 
-One schema for what used to live in two places: ``bench.py``'s
-git-SHA/jax-version record (every BENCH_r*.json row) and
+One schema for what used to live in two places: the git-SHA/jax-version
+record of every BENCH_r*.json row (written by a since-deleted script) and
 ``examples/pipeline_train.py``'s hand-rolled ``step_times.json``.  A
 hardware window's numbers must stay interpretable months later — the
 manifest records exactly which code and stack produced them.
@@ -29,7 +29,7 @@ _provenance_cache: dict[str, dict] = {}
 
 def provenance(repo_root: Optional[str] = None, refresh: bool = False) -> dict:
     """Identity stamp: git SHA + jax/jaxlib/python versions (the exact
-    keys ``bench.py`` has always embedded — ``git_sha``/``jax``/
+    keys the BENCH_r*.json rows carry — ``git_sha``/``jax``/
     ``jaxlib`` — so BENCH record consumers keep working).  Cached per
     root: the answer cannot change within a process, but different
     callers may stamp different checkouts."""

@@ -2,8 +2,8 @@
 
 Every chip run that starts with no compiled code pays the whole
 compilation again (BERT-base's train step alone is ~40 s on a v5e), so
-the entry points — ``chip_smoke.py``, ``bench.py``, the ``examples/`` —
-call :func:`enable_compile_cache` first thing.  One rule:
+the entry points — ``chip_smoke.py``, ``benchmark/run.py``, the
+``examples/`` — call :func:`enable_compile_cache` first thing.  One rule:
 
 * ``JAX_COMPILATION_CACHE_DIR`` set: jax reads it itself; this module
   sets no directory, so whoever placed the cache from outside keeps it.

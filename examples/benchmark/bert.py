@@ -8,6 +8,19 @@ BERT-large MLM with chunk-size 256).  Reports examples/sec and MFU.
 from common import BenchmarkLogger, base_parser, run_benchmark
 
 
+def mlm_model_flops_per_example(cfg, seq_len: int, num_masked: int) -> float:
+    """Analytic matmul FLOPs for one BERT MLM training example (fwd x3 for
+    fwd+bwd).  Counts encoder matmuls (qkv 6H^2 + out-proj 2H^2 + mlp
+    4*H*mlp_dim per token), attention score+value einsums (4*L*H per
+    token), and the MLM head (2*H^2 transform + 2*H*V tied decode per
+    masked position)."""
+    H, L, V, P = cfg.hidden_size, seq_len, cfg.vocab_size, num_masked
+    per_token_layer = 8.0 * H * H + 4.0 * H * cfg.mlp_dim + 4.0 * L * H
+    encoder_fwd = L * cfg.num_layers * per_token_layer
+    head_fwd = P * (2.0 * H * H + 2.0 * H * V)
+    return 3.0 * (encoder_fwd + head_fwd)
+
+
 def main():
     from autodist_tpu.utils.compile_cache import enable_compile_cache
 
@@ -68,9 +81,8 @@ def main():
     if args.flash_attention:
         data = {k: v for k, v in data.items() if k != "input_mask"}
 
-    import bench  # repo-root bench.py: the analytic FLOP model
-    flops_per_example = bench.mlm_model_flops_per_example(
-        cfg, seq_len, num_masked)
+    flops_per_example = mlm_model_flops_per_example(cfg, seq_len,
+                                                    num_masked)
     peak = rs.chip.peak_bf16_tflops * 1e12 * n
 
     logger = BenchmarkLogger(args.benchmark_log_dir)

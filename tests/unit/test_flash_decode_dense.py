@@ -186,8 +186,8 @@ def test_engine_forced_kernel_on_the_whole_cache_greedy_parity(max_len, tp):
     fused = ServingEngine(cfg, params, kernel={"flash_decode": True}, **kw)
     plain = ServingEngine(cfg, params, kernel={"flash_decode": False},
                           **kw)
-    assert fused._fused_block == 128 and fused.decode_block_len == 128
-    assert plain._fused_block is None and plain.decode_block_len == max_len
+    assert fused.kv.fused_block == 128 and fused.decode_block_len == 128
+    assert plain.kv.fused_block is None and plain.decode_block_len == max_len
     windows = 0 if max_len == 128 else 2
     np.testing.assert_array_equal(_serve(fused, [125, 3], windows),
                                   _serve(plain, [125, 3], windows))
@@ -212,7 +212,7 @@ def test_forced_decode_program_hands_the_kernel_the_cache_itself():
                         kernel={"flash_decode": True})
     c = eng.cache
     jaxpr = jax.make_jaxpr(eng._decode_jit.fn)(
-        eng.params, c.k, c.v, c.lengths, eng._tok, eng._table_arg(),
+        eng.params, c.k, c.v, c.lengths, eng._tok, eng.kv.table_arg(c),
         jnp.asarray(eng._sample_seeds), jnp.ones((2,), bool)).jaxpr
     calls = [e for e in _walk(jaxpr) if e.primitive.name == "jit"
              and e.params["name"] == "flash_decode_layer"]
@@ -269,7 +269,7 @@ def test_dense_decode_election(monkeypatch, backend, kernel, max_len,
     finally:
         telemetry.reset()
     assert bool(eng.kernel.get("flash_decode")) == elected
-    assert eng._fused_block == (128 if elected else None)
+    assert eng.kv.fused_block == (128 if elected else None)
     assert gauges.get("kernel/flash_decode_elected") == \
         (1 if elected else None)
 

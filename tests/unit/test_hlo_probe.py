@@ -241,9 +241,9 @@ def test_zero3_shards_step_boundary_and_gathers_per_layer():
 
 
 def test_probe_cli_json_output(tmp_path, capsys):
-    """--json writes the machine-readable report (bench.py embeds it as
-    provenance); --probe selects a subset so the CLI contract is
-    testable without recompiling every program."""
+    """--json writes the machine-readable report; --probe selects a
+    subset so the CLI contract is testable without recompiling every
+    program."""
     out = tmp_path / "probe.json"
     rc = main(["--probe", "single_replica", "--json", str(out)])
     assert rc == 0

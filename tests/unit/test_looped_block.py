@@ -447,3 +447,89 @@ def test_default_block_programs_are_the_parents():
                "prefill": engine.compiled_prefill_text()}
     assert {k: hashlib.sha256(_program_text(v).encode()).hexdigest()[:16]
             for k, v in got.items()} == PARENT_HLO
+
+
+# --------------------------------------------------------------------- #
+# the cache layouts are left alone
+# --------------------------------------------------------------------- #
+# The same hash of a small engine's programs under every cache layout
+# and every option that threads an operand through them, read on the
+# parent commit of PR 30 (99f20d8) with ``_seam_programs`` and
+# ``_renumbered`` below: the layouts moved behind
+# ``serving/kv_cache.py``'s seam and the programs had to come out as
+# they went in.  A PR that means to change one of these programs reads
+# it anew.
+SEAM_ENGINES = {
+    "dense-kernel": dict(kernel={"flash_decode": True}),
+    "paged": dict(kv_layout="paged", kv_block_len=4),
+    "paged-prefix": dict(kv_layout="paged", kv_block_len=4,
+                         prefix_caching=True),
+    "paged-chunked": dict(kv_layout="paged", kv_block_len=4,
+                          prefill_chunk=8),
+    "paged-speculative": dict(kv_layout="paged", kv_block_len=4,
+                              speculative=2),
+    "looped-dense": dict(),
+}
+SEAM_HLO = {
+    "dense-kernel": {"decode": "0d95a530a5ad77b4",
+                     "prefill": "081359ed66a56b31"},
+    "paged": {"decode": "687cf78b23188c8a",
+              "prefill": "71fe0f1618f783ba"},
+    "paged-prefix": {"decode": "687cf78b23188c8a",
+                     "prefill": "b730e541bebe6051"},
+    "paged-chunked": {"decode": "687cf78b23188c8a",
+                      "prefill": "abae544c0c152973"},
+    "paged-speculative": {"decode": "687cf78b23188c8a",
+                          "prefill": "71fe0f1618f783ba",
+                          "verify": "f754f3f3fc39941d"},
+    "looped-dense": {"decode": "eae2d96467e736ad",
+                     "prefill": "7405c0758e01a402"},
+}
+
+
+def _seam_programs(name, ref):
+    """``{program: optimized HLO}`` of the small engine ``name``."""
+    from tests.unit.test_serving import make_cfg
+
+    kw = dict(SEAM_ENGINES[name])
+    if name.startswith("looped"):
+        rc = _ref_cfg()
+        cfg, params = _cfg(rc), _params(ref, rc)
+    else:
+        import optax
+
+        cfg = make_cfg(max_len=128)
+        params = lm.make_pipeline_lm_trainable(
+            cfg, optax.sgd(0.1), jax.random.PRNGKey(0)).params
+    if "speculative" in kw:
+        kw.update(draft_cfg=cfg, draft_params=params)
+    with jax.default_matmul_precision("default"):
+        eng = ServingEngine(cfg, params, num_slots=3, max_len=cfg.max_len,
+                            prefill_len=16, decode_steps=4, **kw)
+        got = {"decode": eng.compiled_decode_text(),
+               "prefill": eng.compiled_prefill_text()}
+        if eng.speculative is not None:
+            c, B = eng.cache, eng.num_slots
+            got["verify"] = eng._spec_verify_jit.lower(
+                eng.params, c.k, c.v, c.lengths, eng._tok,
+                eng.kv.table_arg(c), jnp.asarray(eng._sample_seeds),
+                jnp.zeros((B, eng.speculative + 1), jnp.int32),
+                jnp.ones((B,), bool)).compile().as_text()
+    return got
+
+
+def _renumbered(text):
+    """``_program_text`` with every value's number replaced by its order
+    of first appearance: the tracer numbers the ops it later folds, so a
+    trace that computes ``lengths + c`` once where the parent's computed
+    it twice names the same optimized program differently."""
+    seen = {}
+    return re.sub(r"%[\w.-]+", lambda m: seen.setdefault(
+        m.group(0), f"%v{len(seen)}"), _program_text(text))
+
+
+@pytest.mark.parametrize("name", sorted(SEAM_ENGINES))
+def test_cache_layout_programs_are_the_parents(name, ref):
+    got = _seam_programs(name, ref)
+    assert {k: hashlib.sha256(_renumbered(v).encode()).hexdigest()[:16]
+            for k, v in got.items()} == SEAM_HLO[name]

@@ -374,12 +374,12 @@ def test_cache_block_table_mirrors_live_reservations(cfg, params):
     assert np.all(np.asarray(eng.cache.block_table) == 0)
     eng.reserve_slot(1, 5, 8)                  # 13 -> 2 blocks
     np.testing.assert_array_equal(np.asarray(eng.cache.block_table),
-                                  eng._table)
+                                  eng.kv.table)
     assert np.any(np.asarray(eng.cache.block_table)[1] != 0)
-    assert eng._table_arg() is eng.cache.block_table
+    assert eng.kv.table_arg(eng.cache) is eng.cache.block_table
     eng.release_slot(1)
     np.testing.assert_array_equal(np.asarray(eng.cache.block_table),
-                                  np.zeros_like(eng._table))
+                                  np.zeros_like(eng.kv.table))
 
 
 def test_engine_reserve_release_accounting(cfg, params):
@@ -398,6 +398,120 @@ def test_engine_reserve_release_accounting(cfg, params):
     # dense: the predicate is vacuous
     dense = make_engine(cfg, params)
     assert dense.blocks_needed(5, 8) == 0 and dense.free_blocks == 0
+
+
+# --------------------------------------------------------------------- #
+# the layouts' host protocol, without an engine: no model, no program
+# --------------------------------------------------------------------- #
+LAYOUT_DIMS = (2, 3, 2, 8, 16)     # layers, slots, heads, head_dim, max_len
+SHARED = list(range(40, 50))       # 10 tokens: two blocks of 4 and a tail
+
+
+def _paged_layout(num_blocks=12):
+    layout = kv_cache.PagedLayout(LAYOUT_DIMS, {}, block_len=4,
+                                  num_blocks=num_blocks,
+                                  prefix_caching=True)
+    return layout, kv_cache.PagedKVCache(None, None, None, None)
+
+
+def _admit_shared(layout, cache, slot):
+    """Reserve ``slot`` for ``SHARED`` + 4 tokens and publish what its
+    prefill would have written."""
+    cache, hits = layout.reserve(cache, slot, len(SHARED), 4, SHARED)
+    layout.register(np.arange(LAYOUT_DIMS[1]) == slot)
+    return cache, hits
+
+
+def _reserve_release_restores_the_pool():
+    layout, cache = _paged_layout()
+    assert layout.accounting() == (12, 0, 12)
+    assert layout.blocks_needed(10, 4) == 4
+    cache, hits = layout.reserve(cache, 1, 10, 4)
+    assert hits == 0 and layout.accounting() == (8, 4, 12)
+    blocks = layout.slot_blocks(1)
+    assert len(blocks) == 4 and layout.slot_blocks(0) == []
+    # the row: its blocks, then tail-filled with the last one
+    assert list(layout.table[1]) == blocks
+    np.testing.assert_array_equal(np.asarray(cache.block_table),
+                                  layout.table)
+    with pytest.raises(ValueError, match="already holds"):
+        layout.reserve(cache, 1, 4, 4)
+    cache = layout.release(cache, 1)
+    assert layout.accounting() == (12, 0, 12)
+    assert not layout.table.any() and layout.slot_blocks(1) == []
+    assert layout.release(cache, 1) is cache           # idempotent
+
+
+def _prefix_hit_charges_the_novel_suffix_and_the_cow_reserve():
+    layout, cache = _paged_layout()
+    cache, hits = _admit_shared(layout, cache, 0)
+    assert hits == 0 and layout.accounting() == (8, 4, 12)
+    # the same prompt again: both full blocks and the tail are shared;
+    # the pool supplies the one block past the prompt and the
+    # replacement the shared tail's first write will be copied into
+    assert layout.blocks_needed(10, 4, SHARED) == 2
+    # a prompt that parts ways inside the second block shares one
+    assert layout.blocks_needed(10, 4, SHARED[:5] + [0] * 5) == 3
+    cache, hits = layout.reserve(cache, 1, 10, 4, SHARED)
+    assert hits == 3 and layout.accounting() == (6, 6, 12)
+    assert layout.slot_blocks(1)[:3] == layout.slot_blocks(0)[:3]
+    assert layout.write_from[1] == 3 and layout.write_from[0] == 0
+    tail = layout.slot_blocks(0)[2]
+    assert layout.allocator.refcount(tail) == 2
+    # the sharer leaves: its reference and the parked replacement go back
+    cache = layout.release(cache, 1)
+    assert layout.accounting() == (8, 4, 12)
+    assert layout.allocator.refcount(tail) == 1
+    layout.release(cache, 0)
+    assert layout.accounting() == (12, 0, 12)
+    assert layout.blocks_needed(10, 4, SHARED) == 4    # index retired
+
+
+def _failed_reservation_leaves_every_refcount():
+    layout, cache = _paged_layout(num_blocks=6)
+    cache, _ = _admit_shared(layout, cache, 0)
+    cache, hits = layout.reserve(cache, 1, 10, 4, SHARED)
+    assert hits == 3 and layout.accounting() == (0, 6, 6)
+    before = ([layout.allocator.refcount(b) for b in range(6)],
+              layout.table.copy(), layout.write_from.copy())
+    assert layout.blocks_needed(10, 4, SHARED) == 2
+    with pytest.raises(PoolExhaustedError):
+        layout.reserve(cache, 2, 10, 4, SHARED)
+    assert [layout.allocator.refcount(b) for b in range(6)] == before[0]
+    np.testing.assert_array_equal(layout.table, before[1])
+    np.testing.assert_array_equal(layout.write_from, before[2])
+    assert layout.slot_blocks(2) == []
+    assert layout.accounting() == (0, 6, 6)
+
+
+def _dense_layout_accounts_for_nothing():
+    layout = kv_cache.DenseLayout(LAYOUT_DIMS, {})
+    cache = object()        # anything: the hooks must not look inside
+
+    def no_device(*a, **k):
+        raise AssertionError("a dense host hook touched the device")
+
+    with pytest.MonkeyPatch.context() as patch:
+        for name in ("device_put", "device_get", "jit"):
+            patch.setattr(jax, name, no_device)
+        patch.setattr(jnp, "asarray", no_device)
+        assert layout.blocks_needed(10, 4, SHARED) == 0
+        assert layout.accounting() == (0, 0, 0)
+        assert layout.reserve(cache, 0, 10, 4, SHARED) == (cache, 0)
+        assert layout.release(cache, 0) is cache
+        assert layout.protect(cache, np.ones(3, bool), 4) is cache
+    assert layout.decode_block_len == 16
+    assert layout.table.shape == (3, 1) and not layout.table.any()
+
+
+@pytest.mark.parametrize("case", [
+    _reserve_release_restores_the_pool,
+    _prefix_hit_charges_the_novel_suffix_and_the_cow_reserve,
+    _failed_reservation_leaves_every_refcount,
+    _dense_layout_accounts_for_nothing,
+], ids=lambda f: f.__name__.strip("_"))
+def test_layout_host_protocol(case):
+    case()
 
 
 # --------------------------------------------------------------------- #

@@ -179,7 +179,7 @@ def _scopes_of(lines, opcode, shape=None) -> list:
 def _decode_args(engine):
     c = engine.cache
     return (engine.params, c.k, c.v, c.lengths, engine._tok,
-            engine._table_arg(), jnp.asarray(engine._sample_seeds),
+            engine.kv.table_arg(c), jnp.asarray(engine._sample_seeds),
             jnp.ones((engine.num_slots,), bool))
 
 

@@ -175,7 +175,7 @@ def slot_lane(engine, arr, slot):
     arr = np.asarray(arr)
     if engine.kv_layout != "paged":
         return arr[:, slot]
-    got = arr[:, engine._table[slot]]              # [L, mb, H, bl, dh]
+    got = arr[:, engine.kv.table[slot]]              # [L, mb, H, bl, dh]
     L, mb, H, bl, dh = got.shape
     return np.transpose(got, (0, 2, 1, 3, 4)).reshape(L, H, mb * bl, dh)
 
@@ -242,7 +242,7 @@ def check_prefill_admits(engine, cfg, params, admit):
     keep = np.ones(before["k"].shape, bool)
     for slot in np.flatnonzero(admit):
         if engine.kv_layout == "paged":
-            keep[:, engine._slot_blocks[slot]] = False
+            keep[:, engine.kv.slot_blocks(slot)] = False
         else:
             keep[:, slot] = False
     for name, got in (("k", c.k), ("v", c.v)):

@@ -85,7 +85,7 @@ def test_the_engines_programs_trace_with_room():
     engine = ServingEngine(cfg, params, num_slots=2, max_len=cfg.max_len,
                            prefill_len=8, decode_steps=3)
     traced_under = {}
-    for name in ("_layer_decode", "_layer_chunk"):
+    for name in ("_layer_prefill", "_layer_cached"):
         inner = getattr(engine, name)
 
         def spy(*a, _inner=inner, _name=name, **k):

@@ -216,7 +216,7 @@ def test_lint_block_trace_clean_on_real_engine_events(cfg, params):
     e.decode_window(np.array([True, True]))
     e.release_slot(0)
     e.release_slot(1)
-    trace = list(e._allocator.events)
+    trace = list(e.kv.allocator.events)
     assert any(ev[0] == "share" for ev in trace)   # sharing happened
     report = lint_block_trace(trace)
     assert not report.diagnostics, report.render()

@@ -4,8 +4,8 @@ Round-3 verdict Weak #2: at seq 512 plain einsum beats this repo's flash
 kernel and the long-context win was only a projection.  This driver
 measures fwd+bwd wall-clock of both attention implementations across
 sequence lengths and block sizes, printing one JSON line per point —
-the curve that goes into PERF.md and justifies (or bounds) when the
-bench self-tuner should pick the kernel.
+the curve that justifies (or bounds) when a caller should pick the
+kernel.
 
 Usage: ``python tools/flash_crossover.py [--seqs 512,1024,2048,4096]``
 
@@ -55,7 +55,7 @@ def einsum_attention(q, k, v, causal):
 
 def fence(out):
     """Host round-trip on one scalar that depends on the computation —
-    honest timing on proxied backends (see bench.py)."""
+    honest timing on proxied backends."""
     return float(np.asarray(jax.tree.leaves(out)[0]).ravel()[0])
 
 

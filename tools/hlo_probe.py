@@ -83,7 +83,7 @@ def main(argv=None) -> int:
         description="HLO-structural proof of collective claims (CPU mesh)")
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write the report to this file (machine-"
-                         "readable provenance — bench.py embeds it)")
+                         "readable provenance)")
     ap.add_argument("--probe", action="append", choices=sorted(PROBES),
                     help="run only these probes (repeatable; default all)")
     args = ap.parse_args(argv)
