@@ -214,6 +214,15 @@ def lower_gspmd(trainable: Trainable, strategy: Strategy, mesh) -> GspmdLowered:
 
     accum = max(getattr(strategy.graph_config, "accum_steps", 1), 1)
 
+    # The kernel slot's word on the kernels a model's call sites elect
+    # (models.transformer.attend).  On more than one device they keep
+    # the composed path here whatever the word: XLA cannot partition a
+    # bare pallas_call, and the call site sees that it is not inside a
+    # shard_map.
+    from autodist_tpu.parallel.tensor import kernel_scope
+    kernel = strategy.graph_config.kernel
+
+    @kernel_scope(kernel)
     def _step(state, batch, rng):
         def micro(mb, rng_, extra_in):
             def loss_of(params):
@@ -257,6 +266,7 @@ def lower_gspmd(trainable: Trainable, strategy: Strategy, mesh) -> GspmdLowered:
         in_shardings=(state_shardings, None, None),
         out_shardings=(state_shardings, None))
 
+    @kernel_scope(kernel)
     def _eval(state, batch, rng):
         _, _, metrics = trainable.eval_loss(state["params"], state["extra"],
                                             _constrain_batch(batch), rng)

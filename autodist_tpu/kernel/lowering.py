@@ -694,7 +694,13 @@ def lower(trainable: Trainable, strategy: Strategy, mesh) -> Lowered:
 
     accum = max(getattr(strategy.graph_config, "accum_steps", 1), 1)
 
+    # The kernel slot's word on the kernels a model's call sites elect
+    # (models.transformer.attend), opened where the model is traced.
+    from autodist_tpu.parallel.tensor import kernel_scope
+    kernel = strategy.graph_config.kernel
+
     # ---------------- train step ------------------------------------------ #
+    @kernel_scope(kernel)
     def _local_step(state, batch, rng):
         params_store = state["params"]
         local_rng = jax.random.fold_in(rng, lax.axis_index(data_axis))
@@ -829,6 +835,7 @@ def lower(trainable: Trainable, strategy: Strategy, mesh) -> Lowered:
     step_fn = jax.jit(_step, donate_argnums=(0,))
 
     # ---------------- eval step (no update; fetch contract) --------------- #
+    @kernel_scope(kernel)
     def _local_eval(state, batch, rng):
         params_full = _gather_full(plan, data_axis, state["params"])
         loss, _, metrics = trainable.eval_loss(

@@ -43,12 +43,22 @@ from __future__ import annotations
 # The Strategy IR's kernel-slot vocabulary (strategy/ir.py
 # normalize_kernel re-exports this; kernel code stays IR-agnostic).
 KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
-                  "collective_matmul", "a2a_ring")
+                  "collective_matmul", "a2a_ring", "flash_attention")
 
 # Kernels that change the *training* program (the pipeline and expert
 # lowerings honor them); flash_decode/flash_prefill are serving-side
 # (the decode and chunked-prefill programs).
-TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring")
+TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
+                    "flash_attention")
+
+# Kernels elected where they are called, from what the call observes
+# (``models.transformer.attend``).  The kernel slot says nothing about
+# them unless someone overrides: ``True`` takes the kernel wherever it
+# can run, ``False`` forbids it (the composed path, for a comparison) —
+# the one ``False`` the canonical slot keeps.  The word reaches the call
+# site through ``parallel.tensor.kernel_scope``, which the collective,
+# GSPMD and pipeline lowerings open around the model they trace.
+OBSERVED_KERNELS = ("flash_attention",)
 
 # Op-metadata marker prefix: `with jax.named_scope(kernel_marker(name))`
 # around a pallas_call stamps every emitted op's `op_name` metadata, and

@@ -15,8 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from autodist_tpu.models.transformer import (EncoderLayer,
-                                             TransformerConfig,
-                                             dot_product_attention)
+                                             TransformerConfig, attend)
 from autodist_tpu.telemetry import scope
 
 
@@ -191,11 +190,7 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
     x, q, k, v = attention_inputs(cfg, chunk, x, positions, model_axis,
                                   comm_overlap)
     with scope("attention"):
-        if cfg.attention_fn is not None:
-            out = cfg.attention_fn(q, k, v, mask, None)
-        else:
-            out = dot_product_attention(q, k, v, mask, dropout_rate=0.0,
-                                        dtype=cfg.dtype)
+        out = attend(cfg, q, k, v, mask)
     x = attention_residual(cfg, chunk, x, out, model_axis, comm_overlap)
     y = ffn_residual(cfg, chunk, x, model_axis, comm_overlap)
     return (y, k, v) if return_kv else y

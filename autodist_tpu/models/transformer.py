@@ -6,9 +6,10 @@ TF/Keras), rebuilt TPU-first in flax:
 
 * bfloat16 activations by default (MXU-native), fp32 params + softmax
 * ``jax.checkpoint`` (remat) per layer to trade FLOPs for HBM
-* attention pluggable: local einsum attention here; Pallas flash /
-  ring attention live in ``autodist_tpu.ops`` and slot in via
-  ``attention_fn``
+* attention pluggable: ``attend`` picks the fused Pallas kernels of
+  ``autodist_tpu.ops`` where it observes that they fit and the local
+  einsum attention elsewhere; ring attention (or anything else) slots
+  in via ``attention_fn``
 """
 from __future__ import annotations
 
@@ -127,6 +128,59 @@ def dot_product_attention(q, k, v, mask, *, dropout_rate=0.0,
     return jnp.einsum("...hqk,...khd->...qhd", probs, v)
 
 
+def _fused(cfg: TransformerConfig, q, k, v, mask, dropout_rng) -> bool:
+    """Whether attention over these projections (arrays or shapes) takes
+    the fused kernels: what :func:`attend` documents."""
+    if cfg.attention_fn is not None or mask is not None \
+            or dropout_rng is not None:
+        return False
+    from autodist_tpu.ops.flash_attention import fused_attention_fits
+
+    return fused_attention_fits(q, k, v)
+
+
+def _call_fused(traced: bool, kernel, *args):
+    """``kernel(*args)`` under the marker ``attention_device_pct.train``
+    and the program rules find it by, counted once per traced call."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.kernel.pallas import kernel_marker
+
+    if traced:
+        telemetry.counter("kernel/flash_attention_calls").inc()
+        telemetry.gauge("kernel/flash_attention_elected").set(1)
+    with jax.named_scope(kernel_marker("flash_attention")):
+        return kernel(*args)
+
+
+def attend(cfg: TransformerConfig, q, k, v, mask, *, dropout_rng=None,
+           dropout_rate=0.0):
+    """A layer's attention over ``[batch, length, heads, head_dim]``
+    projections: ``cfg.attention_fn`` where the user set one, else ONE
+    attention whose implementation follows what this call observes.
+    Unmasked and without dropout, on operands the fused kernels were
+    measured to win on (``ops.flash_attention.fused_attention_fits``:
+    a TPU, bf16 or float32, whole per-device arrays, head width and
+    length inside the measured range), the scores stay in VMEM forward
+    and backward, under ``kernel_marker("flash_attention")``; everything
+    else — a padding mask, a causal triangle, attention dropout, another
+    type or shape, a GSPMD lowering over several devices — is
+    :func:`dot_product_attention`.  Same mathematics either way:
+    float32 scores and softmax, probabilities in the operand type."""
+    if cfg.attention_fn is not None:
+        return cfg.attention_fn(q, k, v, mask, dropout_rng)
+    traced = isinstance(q, jax.core.Tracer)   # count programs, not init
+    if _fused(cfg, q, k, v, mask, dropout_rng):
+        from autodist_tpu.ops.flash_attention import flash_attention
+
+        return _call_fused(traced, flash_attention, q, k, v)
+    if traced:
+        from autodist_tpu import telemetry
+
+        telemetry.counter("kernel/einsum_attention_calls").inc()
+    return dot_product_attention(q, k, v, mask, dropout_rate=dropout_rate,
+                                 dropout_rng=dropout_rng, dtype=cfg.dtype)
+
+
 class SelfAttention(nn.Module):
     cfg: TransformerConfig
 
@@ -134,21 +188,47 @@ class SelfAttention(nn.Module):
     def __call__(self, x, mask, deterministic: bool):
         cfg = self.cfg
         B, L, _ = x.shape
-        qkv = nn.DenseGeneral(
-            features=(3, cfg.num_heads, cfg.head_dim), axis=-1,
-            dtype=cfg.dtype, name="qkv")(x)
-        q, k, v = jnp.moveaxis(qkv, -3, 0)
         dropout_rng = (None if deterministic or cfg.attention_dropout_rate == 0
                        else self.make_rng("dropout"))
-        if cfg.attention_fn is not None:
-            out = cfg.attention_fn(q, k, v, mask, dropout_rng)
-        else:
-            out = dot_product_attention(
-                q, k, v, mask, dropout_rate=(0.0 if deterministic
-                                             else cfg.attention_dropout_rate),
-                dropout_rng=dropout_rng, dtype=cfg.dtype)
+        heads = (cfg.num_heads, cfg.head_dim)
+        like = jax.ShapeDtypeStruct((B, L, *heads), cfg.dtype)
+        if not self.is_initializing() \
+                and _fused(cfg, like, like, like, mask, dropout_rng):
+            from autodist_tpu.ops.flash_attention import one_pass_fits
+
+            if one_pass_fits(L):
+                return self._attend_packed(x)
+        qkv = nn.DenseGeneral(
+            features=(3, *heads), axis=-1, dtype=cfg.dtype, name="qkv")(x)
+        q, k, v = jnp.moveaxis(qkv, -3, 0)
+        out = attend(cfg, q, k, v, mask, dropout_rng=dropout_rng,
+                     dropout_rate=(0.0 if deterministic
+                                   else cfg.attention_dropout_rate))
         return nn.DenseGeneral(features=cfg.hidden_size, axis=(-2, -1),
                                dtype=cfg.dtype, name="out")(out)
+
+    def _attend_packed(self, x):
+        """The layer where the fused kernels are elected: the same two
+        projections as flat matmuls, so that q, k, v, the output and
+        their gradients stay ``[B, L, heads * head_dim]`` from one
+        matmul to the kernels to the next.  The chip lays a ``[B, L,
+        heads, head_dim]`` array out with ``L`` minor-most where
+        ``head_dim`` is under 128, and a kernel's row-major operand then
+        costs a whole-tensor copy each (PERF.md section 6, PR 31)."""
+        from autodist_tpu.ops.flash_attention import flash_attention_packed
+
+        cfg, params = self.cfg, self.variables["params"]
+        width = cfg.num_heads * cfg.head_dim
+
+        def dense(x, name, features):
+            w, b = (jnp.asarray(params[name][leaf], cfg.dtype)
+                    for leaf in ("kernel", "bias"))
+            return x @ w.reshape(-1, features) + b.reshape(features)
+
+        qkv = dense(x.astype(cfg.dtype), "qkv", 3 * width)
+        out = _call_fused(isinstance(x, jax.core.Tracer),
+                          flash_attention_packed, qkv, cfg.num_heads)
+        return dense(out, "out", cfg.hidden_size)
 
 
 class MlpBlock(nn.Module):

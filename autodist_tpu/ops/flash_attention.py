@@ -474,6 +474,360 @@ def _flash_bhld_lse_bwd(scale, causal, block_q, block_k, interpret,
 _flash_bhld_lse.defvjp(_flash_bhld_lse_fwd, _flash_bhld_lse_bwd)
 
 
+# --------------------------------------------------------------------------- #
+# One-pass kernels: a head's whole [L, L] score tile lives in VMEM.
+#
+# Where the sequence is short enough for that (a float32 [512, 512] tile
+# is 1 MB), the online softmax over key blocks and the backward's split
+# into a dq kernel and a dk/dv kernel, each recomputing the scores, buy
+# nothing: one forward kernel makes the scores, the softmax and the
+# value product in one pass (2 products, 1 exponential a head), and one
+# backward kernel makes dq, dk and dv from one recomputation (5
+# products, 1 exponential).
+#
+# Operands stay as the model holds them, [B, L, heads * head_dim]: a
+# grid step reads lane tiles of whole heads (two heads of 64 to a
+# 128-lane tile), so no head is moved into the batch and nothing is
+# transposed or copied in front of the call.  A head narrower than the
+# tile is picked out by zeroing the other heads' lanes of ONE operand of
+# each product; the MXU contracts over (or fills) 128 lanes either way,
+# so the zeros cost it nothing.
+# --------------------------------------------------------------------------- #
+
+# The longest sequence the one-pass kernels take: some five float32
+# [L, L] tiles live at once in the backward kernel (20 MB at 1024).
+MAX_ONE_PASS_LEN = 1024
+# Mosaic's scoped-VMEM default is 16 MiB; a whole-row step of the
+# backward kernel holds eight double-buffered [L, heads * head_dim]
+# blocks beside the tiles.  A v5e core has 128 MiB.
+_ONE_PASS_VMEM_BYTES = 96 * 2 ** 20
+
+# Rows x lane tiles a grid step takes by default (six tiles of 512).
+_ONE_PASS_STEP_ROWS = 6 * 512
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _lane_tile(heads: int, head_dim: int) -> int:
+    """Lanes of one tile: whole heads, a multiple of the 128-lane vreg
+    where the row divides into such tiles, else the whole row."""
+    tile = head_dim * 128 // math.gcd(head_dim, 128)
+    return tile if (heads * head_dim) % tile == 0 else heads * head_dim
+
+
+def one_pass_fits(seq_len: int) -> bool:
+    """Whether the one-pass kernels take this length."""
+    return seq_len % 8 == 0 and seq_len <= MAX_ONE_PASS_LEN
+
+
+def _own_lanes(tile: int, head_dim: int, i: int):
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, tile), 1)
+    return (lane >= i * head_dim) & (lane < (i + 1) * head_dim)
+
+
+def _one_pass_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *,
+                         scale: float, head_dim: int, tile: int):
+    """One (batch row, group of lane tiles) program.  ``lse_ref`` is the
+    row's whole ``[L, heads]`` block, revisited by every group: a head's
+    column is set by a lane select, whichever group it is in."""
+    group = pl.program_id(1)
+    tiles = q_ref.shape[2] // tile
+    per_tile = tile // head_dim
+    head_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, lse_ref.shape[2]), 1)
+
+    @pl.when(group == 0)
+    def _():
+        lse_ref[0] = jnp.zeros(lse_ref.shape[1:], lse_ref.dtype)
+
+    lse = lse_ref[0]
+    for t in range(tiles):
+        lanes = slice(t * tile, (t + 1) * tile)
+        q, k, v = q_ref[0, :, lanes], k_ref[0, :, lanes], v_ref[0, :, lanes]
+        acc = jnp.zeros(q.shape, jnp.float32)
+        for i in range(per_tile):
+            qi, vi = q, v
+            if per_tile > 1:
+                own = _own_lanes(tile, head_dim, i)
+                qi = jnp.where(own, q, jnp.zeros_like(q))
+                vi = jnp.where(own, v, jnp.zeros_like(v))
+            s = jax.lax.dot_general(
+                qi, k, _NT, preferred_element_type=jnp.float32) * scale
+            m = jnp.max(s, axis=-1, keepdims=True)
+            p = jnp.exp(s - m)
+            l = jnp.sum(p, axis=-1, keepdims=True)
+            acc += jnp.dot(p.astype(v.dtype), vi,
+                           preferred_element_type=jnp.float32) / l
+            head = (group * tiles + t) * per_tile + i
+            lse = jnp.where(head_lane == head, m + jnp.log(l), lse)
+        o_ref[0, :, lanes] = acc.astype(o_ref.dtype)
+    lse_ref[0] = lse
+
+
+def _one_pass_bwd_kernel(q_ref, k_ref, v_ref, lse_ref, g_ref,
+                         dq_ref, dk_ref, dv_ref, *, scale: float,
+                         head_dim: int, tile: int, group=None):
+    """dq, dk and dv of one (batch row, group of lane tiles) from one
+    recomputation of the probabilities (saved logsumexp).
+
+    The softmax's backward is taken as ``dot_product_attention``'s is:
+    ``ds = p * (dp - sum_k(p * dp))`` with the recomputed float32 ``p``,
+    so that a row of ``ds`` sums to zero whatever the forward pass
+    rounded.  The usual shortcut, ``sum_k(p * dp) = sum_d(out * g)``
+    from the saved output, holds only as far as ``out`` was not rounded:
+    the difference lands on every key alike, which is the (otherwise
+    exactly zero) gradient of the key bias, and Adam scales that to a
+    full-size update (PERF.md section 6, PR 31: the cell's
+    ``delta_norm_gap`` read 0.15-0.17 on ``qkv/bias`` with the
+    shortcut)."""
+    if group is None:
+        group = pl.program_id(1)
+    tiles = q_ref.shape[2] // tile
+    per_tile = tile // head_dim
+    head_lane = jax.lax.broadcasted_iota(
+        jnp.int32, (1, lse_ref.shape[2]), 1)
+    lse_all = lse_ref[0]                                      # [L, heads]
+    for t in range(tiles):
+        lanes = slice(t * tile, (t + 1) * tile)
+        q, k, v = q_ref[0, :, lanes], k_ref[0, :, lanes], v_ref[0, :, lanes]
+        g = g_ref[0, :, lanes]
+        dq = jnp.zeros(q.shape, jnp.float32)
+        dk = jnp.zeros(q.shape, jnp.float32)
+        dv = jnp.zeros(q.shape, jnp.float32)
+        for i in range(per_tile):
+            qi, ki, gi = q, k, g
+            if per_tile > 1:
+                own = _own_lanes(tile, head_dim, i)
+                qi = jnp.where(own, q, jnp.zeros_like(q))
+                ki = jnp.where(own, k, jnp.zeros_like(k))
+                gi = jnp.where(own, g, jnp.zeros_like(g))
+            head = (group * tiles + t) * per_tile + i
+            lse = jnp.sum(jnp.where(head_lane == head, lse_all, 0.0),
+                          axis=-1, keepdims=True)              # [L, 1]
+            s = jax.lax.dot_general(
+                qi, k, _NT, preferred_element_type=jnp.float32) * scale
+            p = jnp.exp(s - lse)
+            dp = jax.lax.dot_general(
+                gi, v, _NT, preferred_element_type=jnp.float32)
+            pdp = p * dp
+            ds = ((pdp - p * jnp.sum(pdp, axis=-1, keepdims=True))
+                  * scale).astype(q.dtype)
+            dv += jax.lax.dot_general(
+                p.astype(g.dtype), gi, _TN,
+                preferred_element_type=jnp.float32)
+            dk += jax.lax.dot_general(
+                ds, qi, _TN, preferred_element_type=jnp.float32)
+            dq += jnp.dot(ds, ki, preferred_element_type=jnp.float32)
+        dq_ref[0, :, lanes] = dq.astype(dq_ref.dtype)
+        dk_ref[0, :, lanes] = dk.astype(dk_ref.dtype)
+        dv_ref[0, :, lanes] = dv.astype(dv_ref.dtype)
+
+
+def _one_pass_specs(b, l, hd, heads, tiles_per_step):
+    """(grid, lane tile, ``block(section)`` of an operand that is
+    section ``section`` of its array's last dimension, block of lse)."""
+    tile = _lane_tile(heads, hd // heads)
+    tiles = hd // tile
+    if tiles_per_step is None or tiles % tiles_per_step:
+        # the whole row up to 512 (2.84 ms a layer in the cell's shape
+        # against 2.94 at one tile a step); at 1024 a whole row of six
+        # tiles read 5.09 ms against 4.76 at two or three, and took 28 s
+        # to compile (my chip run, PR 31)
+        tiles_per_step = max(t for t in range(1, tiles + 1)
+                             if tiles % t == 0
+                             and (t == 1 or t * l <= _ONE_PASS_STEP_ROWS))
+    groups = tiles // tiles_per_step
+
+    def block(section=0):
+        return pl.BlockSpec(
+            (1, l, tile * tiles_per_step),
+            lambda b_, g_: (b_, 0, section * groups + g_))
+
+    return ((b, groups), tile, block,
+            pl.BlockSpec((1, l, heads), lambda b_, g_: (b_, 0, 0)))
+
+
+def _one_pass_params(interpret, grid_axes):
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=("parallel",) + ("arbitrary",) * (grid_axes - 1),
+        vmem_limit_bytes=_ONE_PASS_VMEM_BYTES)}
+
+
+# jitted, so that a model's layers share one trace and one lowering of
+# each kernel (12 traces of the whole-row kernels were 3.5 s of the
+# training cell's set-up: my chip run, PR 31)
+_ONE_PASS_STATIC = ("sections", "heads", "scale", "tiles_per_step",
+                    "interpret")
+
+
+@functools.partial(jax.jit, static_argnames=_ONE_PASS_STATIC)
+def _one_pass_fwd(q, k, v, sections, heads, scale, tiles_per_step,
+                  interpret):
+    """q/k/v: sections ``sections`` of ``[B, L, n * heads * head_dim]``
+    arrays (three arrays of one section each, or one array given three
+    times) -> (out ``[B, L, heads * head_dim]``, lse ``[B, L, heads]``)."""
+    b, l, hd = q.shape[0], q.shape[1], q.shape[2] // (max(sections) + 1)
+    grid, tile, block, lse_block = _one_pass_specs(b, l, hd, heads,
+                                                   tiles_per_step)
+    return pl.pallas_call(
+        functools.partial(_one_pass_fwd_kernel, scale=scale,
+                          head_dim=hd // heads, tile=tile),
+        grid=grid, in_specs=[block(s) for s in sections],
+        out_specs=[block(), lse_block],
+        out_shape=[jax.ShapeDtypeStruct((b, l, hd), q.dtype),
+                   jax.ShapeDtypeStruct((b, l, heads), jnp.float32)],
+        interpret=interpret, **_one_pass_params(interpret, 2),
+    )(q, k, v)
+
+
+def _one_pass_bwd_packed_kernel(q_ref, k_ref, v_ref, lse_ref, g_ref,
+                                dqkv_ref, dk_ref, dv_ref, **kw):
+    """The backward kernel writing dq, dk and dv as the three sections
+    of ONE array, so that the projection's gradient needs no
+    concatenate: a third grid axis writes a section a step; its first
+    step computes all three, dk and dv wait in VMEM scratch."""
+    group, section = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(section == 0)
+    def _():
+        _one_pass_bwd_kernel(q_ref, k_ref, v_ref, lse_ref, g_ref,
+                             dqkv_ref, dk_ref, dv_ref, group=group, **kw)
+
+    @pl.when(section == 1)
+    def _():
+        dqkv_ref[...] = dk_ref[...]
+
+    @pl.when(section == 2)
+    def _():
+        dqkv_ref[...] = dv_ref[...]
+
+
+@functools.partial(jax.jit, static_argnames=_ONE_PASS_STATIC)
+def _one_pass_bwd(q, k, v, sections, lse, g, heads, scale,
+                  tiles_per_step, interpret):
+    """(dq, dk, dv) for three arrays; for one packed array, its
+    gradient."""
+    b, l, hd = g.shape
+    grid, tile, block, lse_block = _one_pass_specs(b, l, hd, heads,
+                                                   tiles_per_step)
+    kw = dict(scale=scale, head_dim=hd // heads, tile=tile)
+    in_specs = [*(block(s) for s in sections), lse_block, block()]
+    if sections == (0, 0, 0):
+        return pl.pallas_call(
+            functools.partial(_one_pass_bwd_kernel, **kw),
+            grid=grid, in_specs=in_specs, out_specs=[block()] * 3,
+            out_shape=[jax.ShapeDtypeStruct(g.shape, q.dtype)] * 3,
+            interpret=interpret, **_one_pass_params(interpret, 2),
+        )(q, k, v, lse, g)
+    from jax.experimental.pallas import tpu as pltpu
+
+    def with_section(spec):     # the same block at every section step
+        return pl.BlockSpec(spec.block_shape,
+                            lambda b_, g_, s_: spec.index_map(b_, g_))
+
+    groups, width = grid[1], block().block_shape[2]
+    return pl.pallas_call(
+        functools.partial(_one_pass_bwd_packed_kernel, **kw),
+        grid=(*grid, 3), in_specs=[with_section(s) for s in in_specs],
+        out_specs=pl.BlockSpec(
+            (1, l, width), lambda b_, g_, s_: (b_, 0, s_ * groups + g_)),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        scratch_shapes=[pltpu.VMEM((1, l, width), q.dtype)] * 2,
+        interpret=interpret, **_one_pass_params(interpret, 3),
+    )(q, k, v, lse, g)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_one_pass(q, k, v, heads, scale, tiles_per_step, interpret):
+    return _one_pass_fwd(q, k, v, (0, 0, 0), heads, scale, tiles_per_step,
+                         interpret)[0]
+
+
+def _flash_one_pass_fwd(q, k, v, heads, scale, tiles_per_step, interpret):
+    out, lse = _one_pass_fwd(q, k, v, (0, 0, 0), heads, scale,
+                             tiles_per_step, interpret)
+    return out, (q, k, v, lse)
+
+
+def _flash_one_pass_bwd(heads, scale, tiles_per_step, interpret, res, g):
+    q, k, v, lse = res
+    return tuple(_one_pass_bwd(q, k, v, (0, 0, 0), lse, g, heads, scale,
+                               tiles_per_step, interpret))
+
+
+_flash_one_pass.defvjp(_flash_one_pass_fwd, _flash_one_pass_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3, 4))
+def _flash_one_pass_packed(qkv, heads, scale, tiles_per_step, interpret):
+    return _one_pass_fwd(qkv, qkv, qkv, (0, 1, 2), heads, scale,
+                         tiles_per_step, interpret)[0]
+
+
+def _flash_one_pass_packed_fwd(qkv, heads, scale, tiles_per_step,
+                               interpret):
+    out, lse = _one_pass_fwd(qkv, qkv, qkv, (0, 1, 2), heads, scale,
+                             tiles_per_step, interpret)
+    return out, (qkv, lse)
+
+
+def _flash_one_pass_packed_bwd(heads, scale, tiles_per_step, interpret,
+                               res, g):
+    qkv, lse = res
+    return (_one_pass_bwd(qkv, qkv, qkv, (0, 1, 2), lse, g, heads, scale,
+                          tiles_per_step, interpret),)
+
+
+_flash_one_pass_packed.defvjp(_flash_one_pass_packed_fwd,
+                              _flash_one_pass_packed_bwd)
+
+
+def _one_pass_args(length, head_dim, scale, interpret):
+    if not one_pass_fits(length):
+        raise ValueError(
+            f"the one-pass kernels take lengths that are a multiple of 8 "
+            f"up to {MAX_ONE_PASS_LEN}; got {length}")
+    return (float(1.0 / math.sqrt(head_dim) if scale is None else scale),
+            bool(default_interpret() if interpret is None else interpret))
+
+
+def flash_attention_one_pass(q, k, v, *, scale: Optional[float] = None,
+                             tiles_per_step: Optional[int] = None,
+                             interpret: Optional[bool] = None):
+    """Unmasked attention over ``[batch, length, heads, head_dim]`` by
+    the one-pass kernels (``length`` a multiple of 8, at most
+    :data:`MAX_ONE_PASS_LEN`).  ``tiles_per_step``: lane tiles a grid
+    step takes (``None``: the whole row)."""
+    b, l, h, d = q.shape
+    scale, interpret = _one_pass_args(l, d, scale, interpret)
+    out = _flash_one_pass(*(x.reshape(b, l, h * d) for x in (q, k, v)),
+                          h, scale, tiles_per_step, interpret)
+    return out.reshape(b, l, h, d)
+
+
+def flash_attention_packed(qkv, heads: int, *,
+                           scale: Optional[float] = None,
+                           tiles_per_step: Optional[int] = None,
+                           interpret: Optional[bool] = None):
+    """The same attention on the projection as a matmul leaves it:
+    ``qkv`` ``[batch, length, 3 * heads * head_dim]`` (q, then k, then
+    v; heads; head_dim) -> ``[batch, length, heads * head_dim]``.  The
+    kernels read the three sections in place, so no ``[batch, length,
+    heads, head_dim]`` array exists on either side of the call: the chip
+    keeps one with ``length`` minor-most where ``head_dim`` is under
+    128, and a whole-tensor copy then stands in front of every operand
+    (PERF.md section 6, PR 25 and PR 31)."""
+    b, l, width = qkv.shape
+    scale, interpret = _one_pass_args(l, width // (3 * heads), scale,
+                                      interpret)
+    return _flash_one_pass_packed(qkv, heads, scale, tiles_per_step,
+                                  interpret)
+
+
 def _layout_bhld(q, k, v, scale, block_q, block_k, interpret):
     """Shared wrapper plumbing: pick blocks (8-aligned), zero-pad the
     sequence to a common block multiple (masked inside the kernel), and
@@ -515,12 +869,19 @@ def flash_attention(q, k, v, *, causal: bool = False,
                     interpret: Optional[bool] = None):
     """Fused attention over ``[batch, length, heads, head_dim]`` inputs.
 
+    Unmasked, with no block sizes given and a length the one-pass
+    kernels take (:func:`flash_attention_one_pass`), those run;
+    otherwise the blockwise kernels.
     ``block_q``/``block_k`` default to the measured tuning table
     (:func:`tuned_blocks`; :data:`DEFAULT_BLOCK` when none committed).
     ``interpret=None`` follows :func:`~autodist_tpu.kernel.pallas
     .default_interpret` (the Pallas interpreter off-TPU).  Sequences
     past :data:`MAX_SEQ_BYTES` raise ``ValueError``.
     """
+    if (not causal and block_q is None and block_k is None
+            and one_pass_fits(q.shape[1])):
+        return flash_attention_one_pass(q, k, v, scale=scale,
+                                        interpret=interpret)
     block_q, block_k = _resolve_blocks(int(q.shape[1]), bool(causal),
                                        block_q, block_k)
     (qb, kb, vb, s), (bq, bk, interp), (b, l, h, d) = _layout_bhld(
@@ -554,6 +915,66 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
     out = jnp.moveaxis(out.reshape(b, h, l, d), 1, 2)
     lse = jnp.moveaxis(lse.reshape(b, h, l), 1, 2)       # [B, L, H]
     return out, lse
+
+
+# --------------------------------------------------------------------------- #
+# The election (``models.transformer.attend``): training attention takes
+# the kernels where, and only where, the call can observe that they fit.
+# --------------------------------------------------------------------------- #
+# Head widths and lengths (multiples of 128) inside which the one-pass
+# kernels were measured to beat ``dot_product_attention``, forward and
+# backward, on one v5e (``tools/flash_crossover.py --cell`` at 32768
+# tokens a call, bf16; PERF.md section 6, PR 31): heads of 64 at 128 /
+# 256 / 512 / 1024, composed 4.79 / 7.23 / 11.96 / 20.48 ms a layer
+# against 3.90 / 4.12 / 4.93 / 6.89 on ``[B, L, heads, head_dim]``
+# views and 1.79 / 2.01 / 2.84 / 4.76 on the packed projection; heads
+# of 128 at 256 / 512 / 1024, 7.22 / 10.15 / 15.99 against 6.41 / 6.28 /
+# 7.54 and 2.53 / 2.40 / 3.67.  Nothing outside was measured.
+FUSED_HEAD_DIMS = (64, 128)
+MIN_FUSED_LEN = 128
+MAX_FUSED_LEN = 1024
+
+
+def _backend_is_tpu() -> bool:
+    """The platform a program traced now is compiled for."""
+    device = jax.config.jax_default_device    # None, a Device or a name
+    return (getattr(device, "platform", device)
+            or jax.default_backend()) == "tpu"
+
+
+def _per_device_operands() -> bool:
+    """Whether what is traced now sees whole per-device arrays: inside a
+    ``shard_map`` over every axis of its mesh, or in a process with one
+    device.  Under ``jit`` alone on several devices the operands may be
+    GSPMD-sharded, and XLA cannot partition a bare ``pallas_call``."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if not mesh.empty:
+        return mesh.are_all_axes_manual
+    return jax.device_count() == 1
+
+
+def fused_attention_fits(q, k, v) -> bool:
+    """Whether unmasked, dropout-free attention over these ``[batch,
+    length, heads, head_dim]`` operands takes the fused kernels.  The
+    kernel slot's word (``parallel.tensor.kernel_scope``) overrides what
+    was measured — ``False`` forbids them, ``True`` takes them on any
+    backend, width and length — never what they cannot do: operands of
+    one type, bf16 or float32, whole on the device."""
+    from autodist_tpu.parallel.tensor import kernel_word
+
+    word = kernel_word("flash_attention")
+    if word is False or not (
+            q.shape == k.shape == v.shape and q.ndim == 4
+            and q.dtype == k.dtype == v.dtype
+            and q.dtype in (jnp.bfloat16, jnp.float32)
+            and _per_device_operands()):
+        return False
+    if word:
+        return True
+    length, head_dim = q.shape[1], q.shape[3]
+    return (_backend_is_tpu() and head_dim in FUSED_HEAD_DIMS
+            and MIN_FUSED_LEN <= length <= MAX_FUSED_LEN
+            and length % 128 == 0)
 
 
 def make_attention_fn(causal: bool, *, block_q: Optional[int] = None,

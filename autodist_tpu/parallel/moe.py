@@ -238,6 +238,7 @@ def lower_expert_ir(trainable, strategy, mesh):
     # ``precision["moe_a2a"]`` + ``kernel["a2a_ring"]`` election into it.
     # A strategy that elects either without a slot to bind would silently
     # train at fp32 — fail loudly instead.
+    from autodist_tpu.kernel.pallas import OBSERVED_KERNELS
     from autodist_tpu.parallel._spmd import emit_kernel_gauges
     a2a_prec = strategy.graph_config.precision.get("moe_a2a")
     a2a_kern = bool(strategy.graph_config.kernel.get("a2a_ring"))
@@ -252,7 +253,8 @@ def lower_expert_ir(trainable, strategy, mesh):
             f"{trainable.name!r} has no moe_a2a binding slot (see "
             "make_moe_lm_trainable)")
     emit_kernel_gauges({k: True for k, v in
-                        strategy.graph_config.kernel.items() if v})
+                        strategy.graph_config.kernel.items()
+                        if v and k not in OBSERVED_KERNELS})
 
     def param_spec(name, leaf):
         if name in expert_vars:

@@ -1464,6 +1464,7 @@ def lower_pipeline_ir(trainable, strategy, mesh):
     # Per-boundary precision gauges: a lowering that silently dropped
     # the policy would miss these, and `tools/telemetry_report.py
     # --check` schema-gates them against the run's annotation.
+    from autodist_tpu.kernel.pallas import OBSERVED_KERNELS
     from autodist_tpu.parallel._spmd import (emit_kernel_gauges,
                                              emit_precision_gauges)
     emit_precision_gauges(precision)
@@ -1471,7 +1472,8 @@ def lower_pipeline_ir(trainable, strategy, mesh):
     # (flash_decode's gauge is the serving engine's to emit) — the
     # schema gate `tools/telemetry_report.py --check` matches them
     # against the run's declared kernel annotation.
-    emit_kernel_gauges({k: True for k in kernel if k != "flash_decode"})
+    emit_kernel_gauges({k: True for k in kernel
+                        if k not in ("flash_decode", *OBSERVED_KERNELS)})
     if not d_axes:
         dropped = sorted(nm for nm, p in policies.items()
                          if p.compressor != "none")

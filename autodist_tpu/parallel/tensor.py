@@ -110,18 +110,18 @@ def active_precision(slot: str) -> str:
 # stage code keeps calling the primitives unchanged, and code outside
 # any scope (the sequential reference, every pre-PR-13 program) lowers
 # composed exactly as before.
-_active_kernels: frozenset = frozenset()
+_active_kernels: dict = {}
 
 
 @contextlib.contextmanager
 def kernel_scope(kernel):
     """Activate a fused-kernel election (a ``normalize_kernel`` dict or
     an iterable of kernel names) for the primitives traced inside the
-    ``with`` body."""
+    ``with`` body (or, as a decorator, inside the function)."""
     global _active_kernels
     prev = _active_kernels
-    names = kernel.keys() if isinstance(kernel, dict) else (kernel or ())
-    _active_kernels = frozenset(names)
+    _active_kernels = (dict(kernel) if isinstance(kernel, dict)
+                       else dict.fromkeys(kernel or (), True))
     try:
         yield
     finally:
@@ -129,7 +129,14 @@ def kernel_scope(kernel):
 
 
 def active_kernel(name: str) -> bool:
-    return name in _active_kernels
+    return bool(_active_kernels.get(name))
+
+
+def kernel_word(name: str):
+    """The scope's word on a kernel its call site elects from what it
+    observes (``kernel.pallas.OBSERVED_KERNELS``): ``True`` forces it,
+    ``False`` forbids it, ``None`` leaves the call site to it."""
+    return _active_kernels.get(name)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1,))

@@ -50,7 +50,8 @@ from autodist_tpu.kernel.quantize import (PRECISIONS,  # noqa: E402
 # swap.  An absent slot (the empty dict — what every pre-PR-13 strategy
 # JSON deserializes to) is the composed lowering everywhere.
 # --------------------------------------------------------------------------- #
-from autodist_tpu.kernel.pallas import KERNEL_CHOICES  # noqa: E402
+from autodist_tpu.kernel.pallas import (KERNEL_CHOICES,  # noqa: E402
+                                        OBSERVED_KERNELS)
 
 
 class UnknownKernelError(ValueError):
@@ -69,6 +70,11 @@ def normalize_kernel(policy) -> dict:
     ``True`` so pre-PR-13 JSON round-trips with the slot absent-or-empty
     and hand edits stay readable.  Unknown names raise
     :class:`UnknownKernelError`.
+
+    One exception to "truthy only": a kernel its call site elects from
+    what it observes (:data:`~autodist_tpu.kernel.pallas
+    .OBSERVED_KERNELS`) keeps an explicit ``False`` — the word that
+    forbids it — since absence there means "left to the call site".
     """
     if policy in (None, False, "", {}, (), []):
         return {}
@@ -76,8 +82,11 @@ def normalize_kernel(policy) -> dict:
         return {k: True for k in KERNEL_CHOICES}
     if isinstance(policy, str):
         policy = (policy,)
+    forbidden = ()
     if isinstance(policy, dict):
         names = [k for k, v in policy.items() if v]
+        forbidden = [k for k, v in policy.items()
+                     if v is False and k in OBSERVED_KERNELS]
     elif isinstance(policy, (list, tuple, set, frozenset)):
         names = list(policy)
     else:
@@ -91,7 +100,8 @@ def normalize_kernel(policy) -> dict:
                 f"unknown kernel {name!r}; expected one of "
                 f"{list(KERNEL_CHOICES)}")
         out[name] = True
-    return {k: True for k in KERNEL_CHOICES if k in out}
+    out.update(dict.fromkeys(forbidden, False))
+    return {k: out[k] for k in KERNEL_CHOICES if k in out}
 
 
 # --------------------------------------------------------------------------- #
@@ -542,7 +552,8 @@ class Strategy:
         if gc.precision:
             head += f", precision={gc.precision}"
         if gc.kernel:
-            head += f", kernel={sorted(gc.kernel)}"
+            head += ", kernel=" + str(sorted(
+                k if v else f"no {k}" for k, v in gc.kernel.items()))
         if gc.accum_steps > 1:
             head += f", accum_steps={gc.accum_steps}"
         lines = [head + ")"]

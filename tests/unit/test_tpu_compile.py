@@ -124,3 +124,52 @@ def test_one_row_prefill_compiles_in_place(one_chip, hidden, heads, mlp,
     minor = "{3,4,2,1,0" if d < 128 else "{4,3,2,1,0"
     layouts = set(re.findall(rf"{whole}(\{{[\d,]+)", text))
     assert layouts == {minor}, layouts
+
+
+# one encoder layer's attention at the training cell's widths (BERT-base:
+# 12 heads of 64, 512 positions) and at heads of 128, forward and
+# backward: Mosaic takes the one-pass kernels' tiles, and no array of the
+# operands' size is copied or transposed around the calls
+@pytest.mark.parametrize("hidden,heads,length", [
+    (768, 12, 512), (1024, 8, 1024)], ids=["heads64", "heads128"])
+def test_training_attention_compiles_without_copies(one_chip, hidden,
+                                                    heads, length,
+                                                    monkeypatch):
+    import importlib
+
+    from autodist_tpu.models.transformer import (SelfAttention,
+                                                 TransformerConfig)
+
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    # what a one-chip TPU process observes (the election asks the
+    # backend and the device count, which here are the CPU's)
+    monkeypatch.setattr(fa, "_backend_is_tpu", lambda: True)
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+    cfg = TransformerConfig(hidden_size=hidden, num_heads=heads,
+                            dtype=jnp.bfloat16, dropout_rate=0.0,
+                            attention_dropout_rate=0.0)
+    layer = SelfAttention(cfg)
+    B = 4
+    params = jax.eval_shape(
+        lambda: layer.init(jax.random.PRNGKey(0),
+                           jnp.zeros((1, 8, hidden), jnp.bfloat16), None,
+                           True))
+    sds = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                         sharding=one_chip)
+
+    def loss(params, x):
+        return jnp.sum(layer.apply(params, x, None, True)
+                       .astype(jnp.float32) ** 2)
+
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+            jax.tree.map(sds, params),
+            sds(jax.ShapeDtypeStruct((B, length, hidden),
+                                     jnp.bfloat16))).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2       # forward, backward
+    assert "adtk_flash_attention" in text
+    assert f"[{B},{heads},{length},{length}]" not in text
+    sized = rf"= bf16\[{B},{length},(?:{hidden}|{3 * hidden}|{heads},\d+)\]"
+    assert not re.findall(sized + r"[^ ]* (?:copy|transpose)\(", text)

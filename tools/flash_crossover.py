@@ -9,6 +9,21 @@ kernel.
 
 Usage: ``python tools/flash_crossover.py [--seqs 512,1024,2048,4096]``
 
+``--cell`` is the training half's shorthand: the shape of
+``bert-base-mlm.1chip``'s attention (64 x 512 x 12 x 64, bf16, forward
+and backward, or ``--tokens`` / ``--seqs`` / ``--heads`` / ``--head-dim``
+where given) from the projection as the model's matmul leaves it,
+``[B, L, 3 * heads * head_dim]``.  It prints milliseconds a layer for the
+composed path (``dot_product_attention``), this repo's kernels per
+variant (blockwise per block size; one-pass per lane tiles a grid step,
+on ``[B, L, heads, head_dim]`` views and on the packed projection) and
+``jax.experimental.pallas.ops.tpu.flash_attention`` as a yardstick of
+what a public kernel attains on the same chip (handed its own ``[B,
+heads, L, head_dim]`` layout, which the model would have to transpose
+into).  Nothing is written: the election's constants
+(``ops.flash_attention.FUSED_HEAD_DIMS`` / ``MIN_FUSED_LEN`` /
+``MAX_FUSED_LEN``) are set by hand from a run of this on the chip.
+
 ``--decode`` switches to the serving-side crossover: single-query-per-
 slot shapes (one token attending over a dense KV cache of each
 ``--seqs`` length, ``--slots`` x ``--heads`` x ``--head-dim`` in
@@ -70,11 +85,14 @@ def timed(fn, args, steps):
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--seqs", default="512,1024,2048,4096")
+    ap.add_argument("--seqs", default=None,
+                    help="sequence lengths (default 512,1024,2048,4096; "
+                         "--cell: 512)")
     ap.add_argument("--heads", type=int, default=12)
     ap.add_argument("--head-dim", type=int, default=64)
-    ap.add_argument("--tokens", type=int, default=8192,
-                    help="per-step token budget: batch = tokens // seq")
+    ap.add_argument("--tokens", type=int, default=None,
+                    help="per-step token budget: batch = tokens // seq "
+                         "(default 8192; --cell: 64 x 512)")
     ap.add_argument("--causal", action="store_true")
     ap.add_argument("--blocks", default="128,256,512",
                     help="flash block sizes to try (best reported; "
@@ -85,6 +103,13 @@ def main():
                          "(per-length best blocks + crossover_len; the "
                          "kernel's default blocks and the flash_wins() "
                          "helper read it — commit it at the repo root)")
+    ap.add_argument("--cell", action="store_true",
+                    help="the training cell's attention shape (64 x 512 "
+                         "x 12 x 64 unless --tokens/--seqs/--heads/"
+                         "--head-dim say otherwise), forward + backward: "
+                         "the composed path, this repo's kernels per "
+                         "variant and the public Pallas TPU kernel; "
+                         "writes nothing")
     ap.add_argument("--decode", action="store_true",
                     help="measure the serving-side crossover instead: "
                          "single-query flash-decode vs the composed "
@@ -129,6 +154,10 @@ def main():
                          "(flash_prefill_crossover_chunk / "
                          "flash_prefill_speedup)")
     args = ap.parse_args()
+    args.seqs = args.seqs or ("512" if args.cell else "512,1024,2048,4096")
+    args.tokens = args.tokens or (64 * 512 if args.cell else 8192)
+    if args.cell:
+        return _main_cell(args)
     if args.decode:
         return _main_decode(args)
     if args.prefill:
@@ -196,6 +225,102 @@ def main():
     if args.write and not wrote:
         print("# no successful flash timing; tuning table unchanged",
               file=sys.stderr)
+
+
+def _main_cell(args):
+    """The ``--cell`` mode: one record per (length, variant), then one
+    summary line per length naming the fastest of this repo's variants
+    against the composed path."""
+    from autodist_tpu.models.transformer import dot_product_attention
+    from autodist_tpu.ops.flash_attention import (MAX_ONE_PASS_LEN,
+                                                  _lane_tile,
+                                                  flash_attention_one_pass,
+                                                  flash_attention_packed)
+
+    H, D = args.heads, args.head_dim
+    tiles = H * D // _lane_tile(H, D)
+
+    def views(qkv):
+        b, l, _ = qkv.shape
+        return jnp.moveaxis(qkv.reshape(b, l, 3, H, D), 2, 0)
+
+    def variants(L):
+        out = {"composed": lambda qkv: dot_product_attention(
+            *views(qkv), None, dtype=qkv.dtype).reshape(*qkv.shape[:2], -1)}
+        for blk in (int(b) for b in args.blocks.split(",")):
+            if blk <= L:
+                out[f"blockwise_{blk}"] = lambda qkv, blk=blk: \
+                    flash_attention(*views(qkv), block_q=blk, block_k=blk) \
+                    .reshape(*qkv.shape[:2], -1)
+        if L <= MAX_ONE_PASS_LEN:
+            for t in (t for t in (1, 2, 3, 6, 12) if tiles % t == 0):
+                out[f"one_pass_views_{t}"] = lambda qkv, t=t: \
+                    flash_attention_one_pass(
+                        *views(qkv), tiles_per_step=t) \
+                    .reshape(*qkv.shape[:2], -1)
+                out[f"one_pass_packed_{t}"] = lambda qkv, t=t: \
+                    flash_attention_packed(qkv, H, tiles_per_step=t)
+        return out
+
+    def public():
+        """The public kernel on its own layout, [B, heads, L, head_dim]."""
+        try:
+            from jax.experimental.pallas.ops.tpu import \
+                flash_attention as public_fa
+        except ImportError as e:
+            print(f"# no public kernel here: {e}", file=sys.stderr)
+            return None
+        return lambda q, k, v: public_fa.flash_attention(
+            q, k, v, sm_scale=1.0 / np.sqrt(D))
+
+    def grad_of(attn, cot, operands=1):
+        def loss(*xs):
+            return jnp.sum(attn(*xs).astype(jnp.float32) * cot)
+        return jax.jit(jax.value_and_grad(
+            loss, argnums=tuple(range(operands))))
+
+    for L in (int(x) for x in args.seqs.split(",")):
+        B = max(args.tokens // L, 1)
+        r = np.random.RandomState(0)
+        qkv = jnp.asarray(r.randn(B, L, 3 * H * D), jnp.bfloat16)
+        cot = jnp.asarray(r.randn(B, L, H * D), jnp.float32)
+        times = {}
+        for name, attn in variants(L).items():
+            try:
+                times[name] = timed(grad_of(attn, cot), (qkv,), args.steps)
+            except Exception as e:  # a variant Mosaic refuses is a finding
+                print(f"# {name} L={L} failed: {str(e)[:400]}",
+                      file=sys.stderr)
+        pub = public()
+        if pub is not None:
+            try:
+                bhld = tuple(jnp.asarray(r.randn(B, H, L, D), jnp.bfloat16)
+                             for _ in range(3))
+                cot4 = jnp.asarray(r.randn(B, H, L, D), jnp.float32)
+                times["public_pallas_tpu"] = timed(
+                    grad_of(pub, cot4, 3), bhld, args.steps)
+            except Exception as e:
+                print(f"# public kernel L={L} failed: {str(e)[:400]}",
+                      file=sys.stderr)
+        for name, t in times.items():
+            print(json.dumps({
+                "metric": "train_attention_ms_per_layer", "variant": name,
+                "seq": L, "batch": B, "heads": H, "head_dim": D,
+                "value": round(t * 1e3, 4), "unit": "ms",
+                "attn_tflops": round(
+                    attention_flops(B, L, H, D) / t / 1e12, 2)}),
+                flush=True)
+        ours = {n: t for n, t in times.items()
+                if n not in ("composed", "public_pallas_tpu")}
+        if ours and "composed" in times:
+            best = min(ours, key=ours.get)
+            print(json.dumps({
+                "summary": f"seq {L}: {best} {ours[best] * 1e3:.3f} ms "
+                           f"against composed "
+                           f"{times['composed'] * 1e3:.3f} ms "
+                           f"({times['composed'] / ours[best]:.2f}x)",
+                "backend": jax.default_backend(),
+                "device_kind": jax.devices()[0].device_kind}), flush=True)
 
 
 def _main_decode(args):

@@ -33,7 +33,15 @@ _PRECISION_BITS = {"fp32": 32, "bf16": 16, "int8": 8}
 # for flash_decode); a manifest run.kernel annotation without its gauge
 # means the election was silently dropped — --check fails it.
 _KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
-                   "collective_matmul", "a2a_ring")
+                   "collective_matmul", "a2a_ring", "flash_attention")
+# Training attention's election (autodist_tpu/models/transformer.py
+# attend): every traced call advances one of the two counters, and a
+# call that takes the fused kernels sets kernel/flash_attention_elected.
+# The gauge without a fused call counted, or fused calls without the
+# gauge, means the accounting of which path the program runs was
+# dropped — --check fails it.
+_ATTENTION_COUNTERS = ("kernel/flash_attention_calls",
+                       "kernel/einsum_attention_calls")
 # Per-request serving records (autodist_tpu/serving/batcher.py): the
 # latency facts the serving section aggregates.  The PR-16 throughput-
 # ladder fields are REQUIRED: every completion reports its prefix hit
@@ -470,6 +478,13 @@ def check_schema(run_dir: str) -> list[str]:
                 f"trace.json: {bare} engine/prefill/dispatch span(s) "
                 "without their `rows` argument in a run that counts "
                 "prefill rows")
+
+    fused = (counters.get(_ATTENTION_COUNTERS[0]) or {}).get("value", 0)
+    if bool(fused) != ("kernel/flash_attention_elected" in gauges):
+        problems.append(
+            f"metrics.jsonl: {_ATTENTION_COUNTERS[0]} = {fused!r} and the "
+            "kernel/flash_attention_elected gauge go together — a traced "
+            "call that takes the fused kernels sets both")
 
     # A scale transition must come with the gauge for the trigger it
     # claims fired: the record says "queue depth crossed the line" —
@@ -938,6 +953,15 @@ def render(run_dir: str, trace_filter=None) -> str:
                          f"| {r.get('phase')} | {r.get('action') or '—'} "
                          f"| step {_fmt(r.get('step'))} |")
         lines.append("")
+
+    attention = {r["name"]: r["value"] for r in counters
+                 if r["name"] in _ATTENTION_COUNTERS}
+    if attention:
+        fused, einsum = (attention.get(n, 0) for n in _ATTENTION_COUNTERS)
+        lines += ["## training attention", "",
+                  f"- traced attention calls: {_fmt(fused)} took the fused "
+                  f"kernels (`kernel/flash_attention_elected`), "
+                  f"{_fmt(einsum)} the einsum", ""]
 
     if counters or gauges:
         lines += ["## counters / gauges", "", "| name | value |", "|---|---|"]
