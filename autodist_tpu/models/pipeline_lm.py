@@ -45,11 +45,20 @@ def _rms_norm(x, scale, dtype, eps):
         return (y * scale).astype(dtype)
 
 
+def _norm_scale(spec, p, leaf="scale"):
+    """What the block's RMSNorm multiplies by: the ``scale`` leaf, or
+    ``1 + weight`` where the norm is zero-centred."""
+    if spec.norm_zero_centred:
+        return 1.0 + p[leaf.replace("scale", "weight")].astype(jnp.float32)
+    return p[leaf]
+
+
 def block_norm(cfg: TransformerConfig, x, p):
-    """The block's norm on a raw ``{"scale"[, "bias"]}`` param dict."""
+    """The block's norm on a raw ``{"scale"[, "bias"]}`` param dict
+    (``{"weight"}`` where it is zero-centred)."""
     spec = cfg.block
     if spec.norm == "rmsnorm":
-        return _rms_norm(x, p["scale"], cfg.dtype, spec.norm_eps)
+        return _rms_norm(x, _norm_scale(spec, p), cfg.dtype, spec.norm_eps)
     return _flax_layer_norm(x, p, cfg.dtype, spec.norm_eps)
 
 
@@ -58,14 +67,20 @@ def final_norm(cfg: TransformerConfig, shared, h):
     ``ln_final_*`` leaves: fp32 out for the default block (the training
     loss head's), the block's own RMSNorm otherwise."""
     if cfg.block.norm == "rmsnorm":
-        return _rms_norm(h, shared["ln_final_scale"], cfg.dtype,
-                         cfg.block.norm_eps)
+        return _rms_norm(h, _norm_scale(cfg.block, shared, "ln_final_scale"),
+                         cfg.dtype, cfg.block.norm_eps)
     return _layer_norm(h, shared["ln_final_scale"], shared["ln_final_bias"])
 
 
-def rope(x, positions, theta: float):
+def rope(x, positions, theta: float, fraction: float = 1.0):
     """Rotate-half rotary embedding of ``x`` ``[B, S, heads, d]`` at
-    absolute ``positions`` (``[S]`` or ``[B, S]``), angles in fp32."""
+    absolute ``positions`` (``[S]`` or ``[B, S]``), angles in fp32; on
+    the first ``fraction`` of the ``d`` dimensions, the rest passing
+    through."""
+    if fraction != 1.0:
+        r = int(x.shape[-1] * fraction)
+        return jnp.concatenate(
+            [rope(x[..., :r], positions, theta), x[..., r:]], -1)
     with scope("rope"):
         d = x.shape[-1]
         inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
@@ -86,60 +101,335 @@ def attention_inputs(cfg: TransformerConfig, chunk, x, positions,
     stream in ``cfg.dtype`` and the local heads' projections of it (of
     its norm under sandwich placement), q and k rotated where positions
     are rotary.  One definition for the full-sequence layer, the decode
-    step and the chunk window, which differ only in how they attend."""
+    step and the chunk window, which differ only in how they attend.
+    Returns ``(x, q, k, v, gate)``: ``k`` and ``v`` carry
+    ``cfg.kv_heads`` heads, and ``gate`` (``None`` but in an
+    ``attn_gate`` block) is :func:`attention_residual`'s."""
     from autodist_tpu.parallel.tensor import column_parallel
 
     spec, dtype = cfg.block, cfg.dtype
     att = chunk["attention"]
     x = x.astype(dtype)
     h = (block_norm(cfg, x, chunk["ln_attention_in"])
-         if spec.norm_placement == "sandwich" else x)
+         if spec.norm_placement in ("sandwich", "pre") else x)
+    gate = None
     with scope("attention"):
         qkv = column_parallel(h, att["qkv"]["kernel"].astype(dtype),
                               _bias(att["qkv"], dtype),
                               model_axis=model_axis,
                               comm_overlap=comm_overlap)
-        if qkv.ndim == 3:       # a fused [H, 3 * heads * d] matrix
+        if spec.attn_gate or cfg.kv_heads != cfg.num_heads:
+            # [H, heads * (d [+ d gate]) + 2 * kv_heads * d]: each query
+            # head's q (then its gate), then the key heads, the value heads
+            n, kv, d = cfg.num_heads, cfg.kv_heads, cfg.head_dim
+            q_w = n * d * (2 if spec.attn_gate else 1)
+            q, k, v = jnp.split(qkv, [q_w, q_w + kv * d], axis=-1)
+            q = q.reshape(*q.shape[:2], n, -1)
+            if spec.attn_gate:
+                q, gate = q[..., :d], q[..., d:]
+            k, v = (t.reshape(*t.shape[:2], kv, d) for t in (k, v))
+        elif qkv.ndim == 3:     # a fused [H, 3 * heads * d] matrix
             q, k, v = (t.reshape(*t.shape[:2], -1, cfg.head_dim)
                        for t in jnp.split(qkv, 3, axis=-1))
         else:
             q, k, v = jnp.moveaxis(qkv, -3, 0)
+    if spec.qk_norm:
+        q = block_norm(cfg, q, att["q_norm"])
+        k = block_norm(cfg, k, att["k_norm"])
     if spec.positions == "rope":
-        q = rope(q, positions, spec.rope_theta)
-        k = rope(k, positions, spec.rope_theta)
-    return x, q, k, v
+        q = rope(q, positions, spec.rope_theta, spec.rope_fraction)
+        k = rope(k, positions, spec.rope_theta, spec.rope_fraction)
+    return x, q, k, v, gate
 
 
-def _residual(cfg, x, y, p):
+def _residual(cfg, x, y, chunk, after):
+    """The residual add around a sub-block's output ``y`` and the norm
+    the placement puts after it (``chunk[after]``; none under ``pre``)."""
+    if cfg.block.norm_placement == "pre":
+        return x + y.astype(x.dtype)
     if cfg.block.norm_placement == "sandwich":
-        return x + block_norm(cfg, y, p)
-    return block_norm(cfg, x + y, p)
+        return x + block_norm(cfg, y, chunk[after])
+    return block_norm(cfg, x + y, chunk[after])
 
 
 def attention_residual(cfg: TransformerConfig, chunk, x, out, model_axis,
-                       comm_overlap=None):
-    """Attention's output projection and its residual add and norm."""
+                       comm_overlap=None, gate=None):
+    """Attention's output projection (of ``out * sigmoid(gate)`` in a
+    gated block) and its residual add and norm."""
     from autodist_tpu.parallel.tensor import row_parallel
 
     dtype = cfg.dtype
     att = chunk["attention"]
     with scope("attention"):
+        if gate is not None:
+            out = (out.astype(jnp.float32) * jax.nn.sigmoid(
+                gate.astype(jnp.float32))).astype(dtype)
         a = row_parallel(out, att["out"]["kernel"].astype(dtype),
                          _bias(att["out"], dtype),
                          model_axis=model_axis, axes=2,
                          comm_overlap=comm_overlap)
-    return _residual(cfg, x, a, chunk["ln_attention"])
+    return _residual(cfg, x, a, chunk, "ln_attention")
+
+
+def expand_kv_heads(cfg: TransformerConfig, t):
+    """``[B, S, kv_heads, d]`` keys or values with each head repeated for
+    the query heads that read it (head ``i`` reads ``i // group``)."""
+    group = cfg.num_heads // cfg.kv_heads
+    return t if group == 1 else jnp.repeat(t, group, axis=2)
+
+
+# --------------------------------------------------------------------- #
+# the linear mixer: gated DeltaNet
+# --------------------------------------------------------------------- #
+# Per value head a float32 state S [key_dim, value_dim]:
+#     S <- exp(g_t) S;  delta = beta_t (v_t - S^T k_t);  S <- S + k_t delta^T
+#     o_t = S^T q_t
+# One definition, two entry points: a window of positions (prefill, the
+# full-recompute apply) runs the chunked form, one position (a decode
+# step) the recurrence itself.
+_HI = jax.lax.Precision.HIGHEST
+DELTA_CHUNK = 64       # positions a chunk of the chunked form spans
+
+
+def _l2_normalise(x, eps=1e-6):
+    return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
+
+
+def causal_conv(x, taps, tail):
+    """Depthwise causal convolution and SiLU of ``x`` ``[B, S, C]`` with
+    ``taps`` ``[T, C]`` (the last multiplies the current position), the
+    ``T - 1`` inputs before ``x`` given as ``tail`` ``[B, T - 1, C]``.
+    Returns the activations and the window ``[B, T - 1 + S, C]`` the
+    next tail is cut from.  Sums in float32."""
+    S, T = x.shape[1], taps.shape[0]
+    window = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
+    out = sum(window[:, j:j + S].astype(jnp.float32)
+              * taps[j].astype(jnp.float32) for j in range(T))
+    return jax.nn.silu(out).astype(x.dtype), window
+
+
+def _unit_lower_inverse(m, base: int = 8):
+    """``(I + m)^-1`` for strictly lower-triangular ``m`` ``[.., n, n]``,
+    ``n`` a power of two times ``base``: blocked forward substitution
+    done as whole-matrix matmuls.  The ``base``-wide diagonal blocks are
+    inverted all at once by the finite series ``(I - d)(I + d^2)(I +
+    d^4)..`` on ``m`` masked to them (a product of block-diagonal
+    matrices stays block-diagonal; the series over all ``n`` at once
+    would cancel catastrophically for keys that repeat), then blocks
+    twice as wide from their halves, ``[[A, 0], [B, D]]^-1 = inv - inv
+    [[0, 0], [B, 0]] inv`` with ``inv`` the halves' inverses, until one
+    block is left: 11 matmuls at ``n = 64`` whatever the batch."""
+    n = m.shape[-1]
+    eye = jnp.eye(n, dtype=m.dtype)
+    mm = lambda a, b: jnp.matmul(a, b, precision=_HI)
+    block = jnp.arange(n)[:, None] // base == jnp.arange(n)[None, :] // base
+    d = jnp.where(block, m, 0.0)
+    inv, power, span = eye - d, mm(d, d), 2
+    while span < base:
+        inv, power, span = mm(inv, eye + power), mm(power, power), 2 * span
+    width = base
+    while width < n:
+        wider = jnp.arange(n)[:, None] // (2 * width) \
+            == jnp.arange(n)[None, :] // (2 * width)
+        below = jnp.where(wider & ~block, m, 0.0)   # each pair's B
+        inv = inv - mm(mm(inv, below), inv)
+        block, width = wider, 2 * width
+    return inv
+
+
+def gated_delta_chunked(q, k, v, g, beta, state, chunk: int = DELTA_CHUNK):
+    """The recurrence over a window, ``chunk`` positions at a time.
+    ``q``, ``k``: ``[B, T, heads, dk]`` (normalised, ``q`` scaled);
+    ``v``: ``[B, T, heads, dv]``; ``g`` (log decay, <= 0), ``beta``:
+    ``[B, T, heads]``; ``state``: ``[B, heads, dk, dv]``; all float32.
+    Returns ``(o [B, T, heads, dv], state after position T - 1)``.  A
+    position with ``g == 0`` and ``beta == 0`` leaves the state bit for
+    bit (a padded position; the window is padded so to whole chunks).
+
+    Inside a chunk the ``delta`` of every position is solved for at once
+    (the WY form: ``(I + tril(beta K K^T * decay, -1))^-1``), so the
+    chunk costs matmuls and the state moves once a chunk."""
+    B, T, Hh, dk = q.shape
+    C = chunk
+    pad = -T % C
+    if pad:
+        q, k, v, g, beta = (jnp.pad(t, [(0, 0), (0, pad)]
+                                    + [(0, 0)] * (t.ndim - 2))
+                            for t in (q, k, v, g, beta))
+    N = (T + pad) // C
+    # [B, heads, N, C, ..]
+    split = lambda t: jnp.moveaxis(t, 1, 2).reshape(
+        B, Hh, N, C, *t.shape[3:])
+    q, k, v, g, beta = map(split, (q, k, v, g, beta))
+    mm = lambda eq, a, b: jnp.einsum(eq, a, b, precision=_HI)
+    gc = jnp.cumsum(g, -1)                               # [B, Hh, N, C]
+    upto = jnp.tril(jnp.ones((C, C), bool))              # j <= i
+    decay = jnp.where(upto, jnp.exp(jnp.where(
+        upto, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+    k_beta = k * beta[..., None]
+    m = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
+                  mm("...ik,...jk->...ij", k_beta, k) * decay, 0.0)
+    solve = _unit_lower_inverse(m)
+    u = mm("...ij,...jv->...iv", solve, v * beta[..., None])
+    w = mm("...ij,...jk->...ik", solve, k_beta * jnp.exp(gc)[..., None])
+    within = mm("...ik,...jk->...ij", q, k) * decay      # j <= i kept
+    q_in = q * jnp.exp(gc)[..., None]
+    last = gc[..., -1:]                                  # [B, Hh, N, 1]
+    k_out = k * jnp.exp(last - gc)[..., None]
+    chunks = lambda t: jnp.moveaxis(t, 2, 0)             # N first
+
+    def one_chunk(S, c):
+        u_c, w_c, within_c, q_c, k_c, dec_c = c
+        v_new = u_c - mm("...ik,...kv->...iv", w_c, S)
+        o = mm("...ik,...kv->...iv", q_c, S) \
+            + mm("...ij,...jv->...iv", within_c, v_new)
+        S = S * dec_c[..., None] + mm("...ik,...iv->...kv", k_c, v_new)
+        return S, o
+
+    state, o = jax.lax.scan(
+        one_chunk, state,
+        tuple(map(chunks, (u, w, within, q_in, k_out, jnp.exp(last)))))
+    o = jnp.moveaxis(jnp.moveaxis(o, 0, 2).reshape(B, Hh, N * C, -1), 1, 2)
+    return o[:, :T], state
+
+
+def gated_delta_step(q, k, v, g, beta, state):
+    """One position of the recurrence: ``q``, ``k`` ``[B, heads, dk]``,
+    ``v`` ``[B, heads, dv]``, ``g``, ``beta`` ``[B, heads]``, ``state``
+    ``[B, heads, dk, dv]``, float32.  Returns ``(o [B, heads, dv],
+    state)``.  Elementwise, not matmuls (a float32 product on the MXU
+    would round the state to bf16), and the state is read twice and
+    written once, nothing of its size in between: ``S^T k`` and ``S^T q``
+    come from the state as it stands in one pass, the decay applied to
+    the sums (``o = (S_d + k delta^T)^T q = S_d^T q + delta (k . q)``
+    with ``S_d = exp(g) S``), and the update reads it again."""
+    with scope("state_update"):
+        decay = jnp.exp(g)[..., None]
+        s_k = decay * (state * k[..., None]).sum(-2)
+        s_q = decay * (state * q[..., None]).sum(-2)
+        delta = (v - s_k) * beta[..., None]
+        o = s_q + delta * (k * q).sum(-1, keepdims=True)
+        return o, state * decay[..., None] \
+            + k[..., None] * delta[..., None, :]
+
+
+def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
+                     valid=None, length=None):
+    """The gated-DeltaNet mixer with its residual: ``(x + mixer(N(x)),
+    (tail, S))``.  ``x``: ``[B, S, H]``; ``state``: ``(tail [B, taps - 1,
+    channels], S [B, value_heads, key_dim, value_dim] float32)`` before
+    the window.  One position (``S == 1``, a decode step) runs the
+    recurrence, a longer window the chunked form.  ``valid`` ``[B, S]``
+    marks the window's real positions (a padded one moves neither the
+    state nor, being later, any real position's output) and ``length``
+    ``[B]`` their count: the tail handed back is the one before position
+    ``length``."""
+    spec, dtype, lin = cfg.block, cfg.dtype, cfg.block.linear
+    la = chunk["linear_attention"]
+    kh, vh, dk, dv = lin.key_heads, lin.value_heads, lin.key_dim, \
+        lin.value_dim
+    B, S, _ = x.shape
+    x = x.astype(dtype)
+    h = block_norm(cfg, x, chunk["ln_attention_in"])
+    f32 = lambda t: t.astype(jnp.float32)
+    tail, ssm = state
+    with scope("linear_attention"):
+        mixed = h @ la["qkvz"]["kernel"].astype(dtype)
+        qkv, z = jnp.split(mixed, [lin.conv_channels], axis=-1)
+        # the write strength and the decay: float32 end to end
+        ba = jnp.matmul(f32(h), f32(la["ba"]["kernel"]), precision=_HI)
+        beta = jax.nn.sigmoid(ba[..., :vh])
+        g = -jnp.exp(f32(la["A_log"])) * jax.nn.softplus(
+            ba[..., vh:] + f32(la["dt_bias"]))
+        if valid is not None:
+            beta, g = beta * valid[..., None], g * valid[..., None]
+        qkv, window = causal_conv(qkv, la["conv"]["kernel"], tail)
+        taps = lin.conv_taps - 1
+        if length is None:
+            tail = window[:, -taps:]
+        else:
+            tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+                w, n, taps, axis=0))(window, length)
+        q, k, v = jnp.split(f32(qkv), [kh * dk, 2 * kh * dk], axis=-1)
+        q = _l2_normalise(q.reshape(B, S, kh, dk)) * dk ** -0.5
+        k = _l2_normalise(k.reshape(B, S, kh, dk))
+        q, k = (jnp.repeat(t, vh // kh, axis=2) for t in (q, k))
+        v = v.reshape(B, S, vh, dv)
+        if S == 1:
+            o, ssm = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                      beta[:, 0], ssm)
+            o = o[:, None]
+        else:
+            with scope("state_update"):
+                o, ssm = gated_delta_chunked(q, k, v, g, beta, ssm)
+        # the gated norm: per head over value_dim, a plain scale
+        o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
+                              + spec.norm_eps) * f32(la["norm"]["scale"])
+        o = (o * jax.nn.silu(f32(z.reshape(B, S, vh, dv)))).astype(dtype)
+        y = o.reshape(B, S, vh * dv) @ la["out"]["kernel"].astype(dtype)
+    return _residual(cfg, x, y, chunk, "ln_attention"), (tail, ssm)
+
+
+def blank_linear_state(cfg: TransformerConfig, batch: int):
+    """The state before position 0: no inputs, ``S = 0``."""
+    lin = cfg.block.linear
+    return (jnp.zeros((batch, lin.conv_taps - 1, lin.conv_channels),
+                      cfg.dtype),
+            jnp.zeros((batch, lin.value_heads, lin.key_dim, lin.value_dim),
+                      jnp.float32))
+
+
+def _swiglu(h, wi, wo):
+    gate, up = jnp.split(h @ wi, 2, axis=-1)
+    return (jax.nn.silu(gate) * up) @ wo
+
+
+def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None):
+    """The routed block on normed rows ``h`` ``[B, S, H]``: ``(y, stats)``
+    — the held experts' part of the routed sum
+    (:func:`autodist_tpu.parallel.moe.routed_experts`) plus the shared
+    expert behind its sigmoid gate, which every device computes."""
+    from autodist_tpu.parallel.moe import routed_experts
+
+    spec, dtype = cfg.block.moe, cfg.dtype
+    rows = h.reshape(-1, h.shape[-1])
+    with scope("moe"):
+        with scope("moe_experts"):
+            y, stats = routed_experts(
+                rows, moe_params["router"]["kernel"],
+                moe_params["experts"]["wi"], moe_params["experts"]["wo"],
+                top_k=spec.top_k, first_expert=spec.first_expert,
+                valid=None if valid is None else valid.reshape(-1))
+        if spec.shared_width:
+            sh = moe_params["shared"]
+            gate = jax.nn.sigmoid(jnp.matmul(
+                rows.astype(jnp.float32),
+                moe_params["shared_gate"]["kernel"].astype(jnp.float32),
+                precision=_HI))
+            y = y + gate[:, None] * _swiglu(
+                rows, sh["wi"]["kernel"].astype(dtype),
+                sh["wo"]["kernel"].astype(dtype)).astype(jnp.float32)
+    return y.reshape(h.shape).astype(dtype), stats
 
 
 def ffn_residual(cfg: TransformerConfig, chunk, x, model_axis,
-                 comm_overlap=None):
-    """The feed-forward sub-block with its residual add and norm(s)."""
+                 comm_overlap=None, valid=None, tally=None):
+    """The feed-forward sub-block with its residual add and norm(s).  A
+    routed block (``cfg.block.moe``) goes to :func:`routed_ffn`:
+    ``valid`` marks the rows that are some request's (the others choose
+    no expert), and ``tally``, a list, is handed the layer's ``[rows_held,
+    experts_hit]``."""
     from autodist_tpu.parallel.tensor import column_parallel, row_parallel
 
     spec, dtype = cfg.block, cfg.dtype
-    mlp = chunk["mlp"]
     h = (block_norm(cfg, x, chunk["ln_mlp_in"])
-         if spec.norm_placement == "sandwich" else x)
+         if spec.norm_placement in ("sandwich", "pre") else x)
+    if spec.moe is not None:
+        m, stats = routed_ffn(cfg, chunk["moe"], h, valid)
+        if tally is not None:
+            tally.append(stats)
+        return _residual(cfg, x, m, chunk, "ln_mlp")
+    mlp = chunk["mlp"]
     with scope("mlp"):
         h = column_parallel(h, mlp["wi"]["kernel"].astype(dtype),
                             _bias(mlp["wi"], dtype),
@@ -153,11 +443,12 @@ def ffn_residual(cfg: TransformerConfig, chunk, x, model_axis,
         m = row_parallel(h, mlp["wo"]["kernel"].astype(dtype),
                          _bias(mlp["wo"], dtype),
                          model_axis=model_axis, comm_overlap=comm_overlap)
-    return _residual(cfg, x, m, chunk["ln_mlp"])
+    return _residual(cfg, x, m, chunk, "ln_mlp")
 
 
 def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
-                      comm_overlap=None, return_kv=False, positions=None):
+                      comm_overlap=None, return_kv=False, positions=None,
+                      valid=None):
     """One layer of ``cfg.block`` on Megatron-sharded chunk params.
 
     For the default block this is the flax :class:`EncoderLayer` math,
@@ -184,16 +475,61 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
 
     ``positions`` (rotary blocks only): the rows' absolute positions,
     ``arange`` of the sequence where not given.
+
+    A ``"linear"`` layer of a mixed stack (its chunk holds
+    ``linear_attention``) runs the mixer from a blank state: the whole
+    sequence is the window.  ``valid`` is :func:`ffn_residual`'s.
     """
+    if "linear_attention" in chunk:
+        x, _ = linear_attention(cfg, chunk, x,
+                                blank_linear_state(cfg, x.shape[0]))
+        return ffn_residual(cfg, chunk, x, model_axis, comm_overlap)
     if positions is None and cfg.block.positions == "rope":
         positions = jnp.arange(x.shape[1])
-    x, q, k, v = attention_inputs(cfg, chunk, x, positions, model_axis,
-                                  comm_overlap)
+    x, q, k, v, gate = attention_inputs(cfg, chunk, x, positions,
+                                        model_axis, comm_overlap)
     with scope("attention"):
-        out = attend(cfg, q, k, v, mask)
-    x = attention_residual(cfg, chunk, x, out, model_axis, comm_overlap)
-    y = ffn_residual(cfg, chunk, x, model_axis, comm_overlap)
+        out = attend(cfg, q, expand_kv_heads(cfg, k),
+                     expand_kv_heads(cfg, v), mask)
+    x = attention_residual(cfg, chunk, x, out, model_axis, comm_overlap,
+                           gate)
+    y = ffn_residual(cfg, chunk, x, model_axis, comm_overlap, valid=valid)
     return (y, k, v) if return_kv else y
+
+
+MIXERS = {"full": "attention", "linear": "linear_attention"}
+
+
+def layer_key(l: int) -> str:
+    """Where layer ``l``'s own arrays sit in a sub-tree that is kept a
+    layer apart and not stacked (a routed FFN's experts)."""
+    return f"layer_{l:02d}"
+
+
+def _layer_of(tree, l: int):
+    if not isinstance(tree, dict):
+        return tree[l]
+    if layer_key(l) in tree:
+        return tree[layer_key(l)]
+    return {name: _layer_of(sub, l) for name, sub in tree.items()}
+
+
+def layer_chunk(cfg: TransformerConfig, stages, l: int):
+    """Layer ``l``'s parameters out of ``stages``.  A leaf is stacked
+    over the layers that have it: all of them, but for a mixed stack's
+    mixers (``attention`` over the full layers, ``linear_attention``
+    over the linear ones, each in stack order).  A routed FFN's experts
+    are arrays of their own a layer (:func:`layer_key`): the grouped
+    matmul takes the array whole, and a layer's slice of a stack would
+    reach it as a copy of all its experts, every step."""
+    if not (cfg.block.layer_period or cfg.block.moe):
+        return jax.tree.map(lambda p: p[l], stages)
+    kinds = cfg.block.layer_kinds(cfg.num_layers)
+    chunk = {name: _layer_of(tree, l) for name, tree in stages.items()
+             if name not in MIXERS.values()}
+    mixer, nth = MIXERS[kinds[l]], kinds[:l].count(kinds[l])
+    chunk[mixer] = jax.tree.map(lambda p: p[nth], stages[mixer])
+    return chunk
 
 
 def run_stack(cfg: TransformerConfig, shared, carry, layers):
@@ -246,8 +582,8 @@ def sequential_logits(cfg: TransformerConfig, params, tokens):
     def layers(u, carry):
         x, = carry
         for i in range(cfg.num_layers):
-            chunk = jax.tree.map(lambda a, _i=i: a[_i], stages)
-            x = _tp_encoder_layer(cfg, chunk, x, mask, None)
+            x = _tp_encoder_layer(cfg, layer_chunk(cfg, stages, i), x,
+                                  mask, None)
         return x,
 
     x, = run_stack(cfg, shared, (x,), layers)
@@ -266,34 +602,79 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     gated ``wi`` ``[H, 2 * M]`` (gate, up): the TPU tiles an array's two
     minor dimensions, and the default block's ``[H, 3, heads, d]`` it
     copies whole, before every decode dispatch, into a layout whose
-    tiles hold ``H`` (PERF.md section 6, PR 26)."""
+    tiles hold ``H`` (PERF.md section 6, PR 26).
+
+    A mixed stack (``layer_period``) stacks each mixer's leaves over the
+    layers of its kind (:func:`layer_chunk`).  A grouped or gated
+    attention's ``qkv`` is ``[H, heads * (d [+ d gate]) + 2 * kv_heads *
+    d]``; the linear mixer holds ``qkvz`` ``[H, q | k | v | z]``, ``ba``
+    ``[H, 2 * value_heads]``, the convolution's taps, ``A_log`` and
+    ``dt_bias`` a value head, the gated norm's scale and ``out``; a
+    routed FFN holds the router over ALL experts, the held experts' ``wi``
+    ``[held, H, 2 * M]`` (gate, up) and ``wo`` as arrays of their own a
+    layer (:func:`layer_chunk` says why), and the shared expert with its
+    gate.  A zero-centred norm's leaf is ``weight``."""
     spec = cfg.block
     L, H, M, V = cfg.num_layers, cfg.hidden_size, cfg.mlp_dim, \
         cfg.vocab_size
-    n, d = cfg.num_heads, cfg.head_dim
+    n, d, kv = cfg.num_heads, cfg.head_dim, cfg.kv_heads
+    kinds = spec.layer_kinds(L)
+    Lf = kinds.count("full")
+    scale = "weight" if spec.norm_zero_centred else "scale"
 
-    def dense(shape, bias):
-        return {"kernel": (L,) + shape,
-                **({"bias": (L,) + bias} if spec.bias else {})}
+    def dense(shape, bias, lead=L):
+        return {"kernel": (lead,) + shape,
+                **({"bias": (lead,) + bias} if spec.bias else {})}
 
-    def norm(lead=(L,)):
-        return {"scale": lead + (H,),
-                **({"bias": lead + (H,)} if spec.norm == "layernorm"
+    def norm(lead=(L,), width=H):
+        return {scale: lead + (width,),
+                **({"bias": lead + (width,)} if spec.norm == "layernorm"
                    else {})}
 
     wi = 2 * M if spec.ffn == "swiglu" else M
+    if spec.is_default:
+        qkv = dense((H, 3, n, d), (3, n, d))
+    elif spec.attn_gate or kv != n:
+        qkv = dense((H, n * d * (2 if spec.attn_gate else 1) + 2 * kv * d),
+                    (), Lf)
+    else:
+        qkv = dense((H, 3 * n * d), (3 * n * d,))
     stages = {
-        "attention": {"qkv": dense((H, 3, n, d), (3, n, d))
-                      if spec.is_default else dense((H, 3 * n * d),
-                                                    (3 * n * d,)),
-                      "out": dense((n, d, H), (H,))},
-        "ln_attention": norm(),
+        "attention": {"qkv": qkv, "out": dense((n, d, H), (H,), Lf)},
         "mlp": {"wi": dense((H, wi), (wi,)),
-                "wo": dense((M, H), (H,))},
-        "ln_mlp": norm()}
-    if spec.norm_placement == "sandwich":
+                "wo": dense((M, H), (H,))}}
+    if spec.qk_norm:
+        stages["attention"].update(q_norm=norm((Lf,), d),
+                                   k_norm=norm((Lf,), d))
+    if spec.linear is not None:
+        lin, Ll = spec.linear, kinds.count("linear")
+        inner = lin.value_heads * lin.value_dim
+        stages["linear_attention"] = {
+            "qkvz": dense((H, lin.conv_channels + inner), (), Ll),
+            "ba": dense((H, 2 * lin.value_heads), (), Ll),
+            "conv": {"kernel": (Ll, lin.conv_taps, lin.conv_channels)},
+            "A_log": (Ll, lin.value_heads),
+            "dt_bias": (Ll, lin.value_heads),
+            "norm": {"scale": (Ll, lin.value_dim)},
+            "out": dense((inner, H), (), Ll)}
+    if spec.moe is not None:
+        moe, Ms = spec.moe, spec.moe.shared_width
+        del stages["mlp"]
+        stages["moe"] = {
+            "router": {"kernel": (L, H, moe.num_experts)},
+            "experts": {layer_key(l): {
+                "wi": (moe.experts_held, H, 2 * moe.expert_width),
+                "wo": (moe.experts_held, moe.expert_width, H)} for l in range(L)}}
+        if Ms:
+            stages["moe"].update(
+                shared={"wi": {"kernel": (L, H, 2 * Ms)},
+                        "wo": {"kernel": (L, Ms, H)}},
+                shared_gate={"kernel": (L, H)})
+    if spec.norm_placement != "pre":
+        stages.update(ln_attention=norm(), ln_mlp=norm())
+    if spec.norm_placement in ("sandwich", "pre"):
         stages.update(ln_attention_in=norm(), ln_mlp_in=norm())
-    shared = {"embedding": (V, H), "ln_final_scale": (H,)}
+    shared = {"embedding": (V, H), "ln_final_" + scale: (H,)}
     if spec.norm == "layernorm":
         shared["ln_final_bias"] = (H,)
     if spec.positions == "learned":

@@ -23,6 +23,62 @@ import numpy as np
 
 
 @dataclasses.dataclass(frozen=True)
+class LinearMixerSpec:
+    """A gated-DeltaNet mixer's sizes: ``key_heads`` key (and query)
+    heads of ``key_dim``, ``value_heads`` value heads of ``value_dim``
+    (each key head serves ``value_heads // key_heads`` of them), a
+    depthwise causal convolution of ``conv_taps`` taps over the
+    ``[q, k, v]`` channels.  The state is one ``[key_dim, value_dim]``
+    float32 matrix a value head."""
+
+    key_heads: int
+    value_heads: int
+    key_dim: int
+    value_dim: int
+    conv_taps: int = 4
+
+    def __post_init__(self):
+        if self.value_heads % self.key_heads:
+            raise ValueError("LinearMixerSpec.value_heads must be a "
+                             "multiple of key_heads")
+
+    @property
+    def conv_channels(self) -> int:
+        """Channels the convolution runs over: q, k and v, flat."""
+        return 2 * self.key_heads * self.key_dim \
+            + self.value_heads * self.value_dim
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedFFNSpec:
+    """A routed feed-forward block's sizes.  The router scores all
+    ``num_experts`` and keeps ``top_k`` a token, renormalised to sum 1;
+    this device holds experts ``first_expert .. first_expert +
+    experts_held`` and adds up their terms alone — what the absent ones
+    would have given is some other device's.  Experts are SiLU-gated at
+    ``expert_width``; a shared expert of ``shared_width`` behind a
+    sigmoid gate is computed everywhere (0: none)."""
+
+    num_experts: int
+    top_k: int
+    expert_width: int
+    experts_held: int
+    shared_width: int = 0
+    first_expert: int = 0
+
+    def __post_init__(self):
+        if not 0 < self.top_k <= self.num_experts:
+            raise ValueError("RoutedFFNSpec.top_k must lie in "
+                             "1..num_experts")
+        if self.first_expert < 0 or self.experts_held < 1 \
+                or self.first_expert + self.experts_held > self.num_experts:
+            raise ValueError(
+                f"RoutedFFNSpec holds experts {self.first_expert}.."
+                f"{self.first_expert + self.experts_held} of "
+                f"{self.num_experts}")
+
+
+@dataclasses.dataclass(frozen=True)
 class BlockSpec:
     """What one layer of the stack IS, said once: the serving engine's
     prefill, decode and chunk layers and the pipelined LM's
@@ -34,7 +90,9 @@ class BlockSpec:
     * ``norm`` ``"layernorm"`` | ``"rmsnorm"`` (scale only), at
       ``norm_eps``; ``norm_placement`` ``"post"`` (``LN(x + f(x))``) or
       ``"sandwich"`` (``x + N(f(N(x)))``: a norm before AND after each
-      sub-block, four a layer).
+      sub-block, four a layer) or ``"pre"`` (``x + f(N(x))``, two a
+      layer).  ``norm_zero_centred``: the RMSNorm multiplies by ``1 +
+      weight`` (its leaf is ``weight``, near 0, not ``scale``).
     * ``positions`` ``"learned"`` (a table added to the embedding) or
       ``"rope"`` (rotate-half rotary at ``rope_theta`` on q and k inside
       attention; the key is rotated before it is cached).
@@ -62,10 +120,20 @@ class BlockSpec:
     tied_head: bool = True
     loop_steps: int = 1
     exit_threshold: float = 1.0
+    norm_zero_centred: bool = False
+    kv_heads: Optional[int] = None
+    head_dim: Optional[int] = None
+    rope_fraction: float = 1.0
+    qk_norm: bool = False
+    attn_gate: bool = False
+    layer_period: tuple = ()
+    linear: Optional[LinearMixerSpec] = None
+    moe: Optional[RoutedFFNSpec] = None
 
     def __post_init__(self):
         for name, allowed in (("norm", ("layernorm", "rmsnorm")),
-                              ("norm_placement", ("post", "sandwich")),
+                              ("norm_placement", ("post", "sandwich",
+                                                  "pre")),
                               ("positions", ("learned", "rope")),
                               ("ffn", ("gelu", "swiglu"))):
             if getattr(self, name) not in allowed:
@@ -73,10 +141,32 @@ class BlockSpec:
                                  f": one of {allowed}")
         if self.loop_steps < 1:
             raise ValueError("BlockSpec.loop_steps must be >= 1")
+        if set(self.layer_period) - {"full", "linear"}:
+            raise ValueError(f"BlockSpec.layer_period={self.layer_period}"
+                             ": kinds are 'full' and 'linear'")
+        if ("linear" in self.layer_period) != (self.linear is not None):
+            raise ValueError("BlockSpec.linear gives the sizes of the "
+                             "'linear' layers of layer_period: both or "
+                             "neither")
+        if (self.norm_zero_centred or self.qk_norm) \
+                and self.norm != "rmsnorm":
+            raise ValueError("norm_zero_centred and qk_norm are RMSNorms")
+        if self.moe is not None and self.ffn != "swiglu":
+            raise ValueError("the routed experts are SiLU-gated: "
+                             "BlockSpec.moe needs ffn='swiglu'")
+        if (self.linear or self.moe) and (self.loop_steps != 1
+                                          or self.bias):
+            raise ValueError("a linear mixer or a routed FFN runs in one "
+                             "pass and without biases")
 
     @property
     def is_default(self) -> bool:
         return self == BlockSpec()
+
+    def layer_kinds(self, num_layers: int) -> tuple:
+        """The kind of each of ``num_layers`` layers."""
+        period = self.layer_period or ("full",)
+        return tuple(period[l % len(period)] for l in range(num_layers))
 
 
 @dataclasses.dataclass(unsafe_hash=True)
@@ -109,7 +199,11 @@ class TransformerConfig:
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.block.head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def kv_heads(self) -> int:
+        return self.block.kv_heads or self.num_heads
 
 
 def dot_product_attention(q, k, v, mask, *, dropout_rate=0.0,

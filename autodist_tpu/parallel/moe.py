@@ -4,7 +4,14 @@ Beyond reference parity (SURVEY.md §2.10 lists expert parallelism as
 absent): top-2 gated MoE FFN where experts are sharded across devices and
 tokens travel by ``lax.all_to_all`` — the TPU-idiomatic dispatch
 (einsum-based one-hot dispatch/combine, capacity-bounded static shapes;
-the Mesh-TensorFlow / GShard formulation).
+the Mesh-TensorFlow / GShard formulation) — for the training lowering
+(:func:`top2_gating`, :func:`expert_parallel_ffn`).
+
+The served routed layer (:func:`route_top_k`, :func:`routed_experts`)
+has no capacity and drops nothing: the router scores every expert, a
+device is told which experts it holds and computes their part of the
+result over the (row, expert) pairs that land on them, sorted by expert
+into one grouped matmul whose bytes grow with the experts hit.
 """
 from __future__ import annotations
 
@@ -60,6 +67,99 @@ def top2_gating(gate_logits, capacity: int):
     combine = onehot_pos(mask1, pos1, w1) + onehot_pos(mask2, pos2, w2)
     dispatch = combine > 0.0
     return dispatch, combine, aux_loss
+
+
+def route_top_k(x, router_w, top_k: int):
+    """``(experts [R, top_k] int32, weights [R, top_k] float32)`` of
+    rows ``x`` ``[R, H]``: softmax over ALL the router's outputs in
+    float32, the ``top_k`` largest, renormalised to sum 1 over the
+    chosen experts wherever they live."""
+    logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                        precision=lax.Precision.HIGHEST)
+    weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+    weights = weights / weights.sum(-1, keepdims=True)
+    return experts.astype(jnp.int32), weights
+
+
+def _pairs_bound(pairs: int, held: int, experts: int) -> int:
+    """How many sorted pairs the routed layer works through when the
+    pairs that landed on held experts fit: twice what even routing sends
+    here, in ``64 x odd`` rows — the TPU's grouped matmul tiles its rows
+    by the largest of 512, 256, 128, 64 that divides their count, and a
+    group of a few rows costs a whole tile (at 512 a tile the 64 experts
+    a 1,024-row prompt touches cost 0.58 ms a matmul, 18% of a prefill:
+    PERF.md section 6, PR 32).  ``pairs`` itself where that is no
+    saving."""
+    expected = -(-pairs * held // experts)
+    bound = 64 * (-(-2 * expected // 64) | 1)
+    return bound if bound <= pairs // 2 else pairs
+
+
+def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
+                   first_expert: int = 0, valid=None):
+    """The held experts' part of a routed FFN, without capacity.
+
+    ``x``: ``[R, H]`` rows; ``router_w``: ``[H, E]`` over all ``E``
+    experts; ``expert_wi``: ``[E_held, H, 2 * M]`` (gate, then up) and
+    ``expert_wo``: ``[E_held, M, H]`` — experts ``first_expert ..
+    first_expert + E_held``, the ones this device holds.  Returns ``(y
+    [R, H] float32, stats)``: ``y = sum_e w_e wo_e(silu(gate_e x) *
+    up_e x)`` over the row's chosen experts that are held (a row none of
+    whose choices is held gets 0: the rest of its sum is another
+    device's), and ``stats`` int32 ``[rows_held, experts_hit]`` — the
+    pairs that landed here and the held experts with at least one row.
+
+    The ``R * top_k`` (row, expert) pairs are sorted by held expert, the
+    ones that are not held last, and the experts run over their groups
+    with :func:`jax.lax.ragged_dot`: on the TPU a grouped matmul that
+    visits the groups that have rows, so an expert nobody chose is not
+    read.  Each row's terms come back through one matmul with the
+    pairs' weights laid out by row (a scatter-add is a serial loop on
+    the TPU).  Nothing is dropped: where the held pairs fit
+    :func:`_pairs_bound` only that many sorted pairs are worked through,
+    and all of them where they do not (``lax.cond``: skewed routing
+    costs time, never a row).  ``valid`` (``[R]`` bool): rows that are
+    padding or belong to no request choose nothing."""
+    R, H = x.shape
+    E_held = expert_wi.shape[0]
+    experts, weights = route_top_k(x, router_w, top_k)
+    local = experts - first_expert                       # [R, k]
+    held = (local >= 0) & (local < E_held)
+    if valid is not None:
+        held = held & valid[:, None]
+    key = jnp.where(held, local, E_held).reshape(-1)     # not held: last
+    order = jnp.argsort(key, stable=True)
+    sizes = (key[:, None] == jnp.arange(E_held)[None, :]).sum(
+        0, dtype=jnp.int32)                              # [E_held]
+    weight = jnp.where(held, weights, 0.0).reshape(-1)
+
+    def experts_over(pairs: int):
+        """The first ``pairs`` sorted pairs through the held experts."""
+        first = order[:pairs]
+        rows = first // top_k                            # pair -> its row
+        h = lax.ragged_dot(x[rows], expert_wi.astype(x.dtype), sizes,
+                           preferred_element_type=jnp.float32)
+        gate, up = jnp.split(h, 2, axis=-1)
+        h = (jax.nn.silu(gate) * up).astype(x.dtype)
+        y = lax.ragged_dot(h, expert_wo.astype(x.dtype), sizes,
+                           preferred_element_type=jnp.float32)
+        # rows past the groups are nobody's, whatever the matmul left
+        w = weight[first]
+        y = jnp.where((w > 0)[:, None], y * w[:, None], 0.0)
+        by_row = (rows[None, :] == jnp.arange(R)[:, None]) \
+            .astype(jnp.float32)                         # [R, pairs]
+        return jnp.matmul(by_row, y, precision=lax.Precision.HIGH)
+
+    total = R * top_k
+    bound = _pairs_bound(total, E_held, router_w.shape[-1])
+    n_held = sizes.sum()
+    if bound < total:
+        out = lax.cond(n_held <= bound, lambda: experts_over(bound),
+                       lambda: experts_over(total))
+    else:
+        out = experts_over(total)
+    stats = jnp.stack([n_held, (sizes > 0).sum(dtype=jnp.int32)])
+    return out, stats
 
 
 def _qa2a_impl(x, axis_name, split_axis, concat_axis, precision):

@@ -127,6 +127,19 @@ class HandoffPlan:
         return dataclasses.asdict(self)
 
 
+def check_handoff_block(engine, name: str = "engine") -> None:
+    """Refuse, by name, an engine whose slots own more than pool blocks:
+    the handoff copies a request's blocks of keys and values, and a
+    stack with linear layers also keeps a recurrent state a slot that no
+    block holds."""
+    if getattr(engine, "linear_layers", 0):
+        raise ValueError(
+            f"{name}: the disaggregated handoff copies blocks of keys "
+            f"and values; the block's {engine.linear_layers} linear "
+            "(gated-DeltaNet) layers keep a recurrent state a slot, "
+            "which it would leave behind")
+
+
 class HandoffError(RuntimeError):
     """A handoff plan or its compiled program failed its lint — the
     transfer would stage more than the shard-granularity contract
@@ -240,6 +253,7 @@ class DisaggServer:
     def _validate_pools(self) -> None:
         shapes = set()
         for pname, eng in self.prefill_pool + self.decode_pool:
+            check_handoff_block(eng, pname)
             if eng.kv_layout != "paged":
                 raise ValueError(
                     f"{pname}: the handoff rides the block table — "

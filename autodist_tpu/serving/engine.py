@@ -249,10 +249,43 @@ class ServingEngine:
             raise ValueError(
                 f"tensor_parallel={tensor_parallel} with a non-default "
                 f"block ({spec}): the Megatron rule tables name the "
-                "default block's leaves only")
+                "default block's leaves only (no rule splits a linear "
+                "mixer's heads, its recurrent state or a routed FFN's "
+                "experts)")
+        # a mixed stack: the full layers cache keys and values, the
+        # linear ones keep a recurrent state (kv_cache.RecurrentState)
+        self._kinds = spec.layer_kinds(cfg.num_layers)
+        self.linear_layers = self._kinds.count("linear")
+        grouped = cfg.kv_heads != cfg.num_heads
+        if cfg.num_heads % cfg.kv_heads:
+            raise ValueError(f"num_heads={cfg.num_heads} must be a "
+                             f"multiple of kv_heads={cfg.kv_heads}")
+        # What assumes a cache of keys and values alone, or one key/value
+        # head a query head, refuses such a block by name rather than
+        # serve it wrongly.
+        for knob, asked, what in (
+                ("prefill_chunk", prefill_chunk is not None,
+                 "chunked prefill"),
+                ("speculative", speculative is not None,
+                 "speculative verify"),
+                ("prefix_caching", bool(prefix_caching), "prefix caching"),
+                ("kv_layout='paged'", kv_layout == "paged", "paged KV")):
+            if asked and self.linear_layers:
+                raise ValueError(
+                    f"{knob}: {what} over recurrent state is not served "
+                    f"— the block's {self.linear_layers} linear "
+                    "(gated-DeltaNet) layers keep a state a slot that "
+                    "cannot be rolled back, shared by blocks or cut at a "
+                    "chunk's edge")
+            if asked and grouped:
+                raise ValueError(
+                    f"{knob}: {what} with grouped-query attention "
+                    f"({cfg.num_heads} query heads on {cfg.kv_heads} "
+                    "key/value heads) is not served — the block table's "
+                    "readers take a key/value head a query head")
         # the cache holds every pass's keys and values: a layer's input
         # differs from pass to pass, so its projections do too
-        self.cache_layers = cfg.num_layers * spec.loop_steps
+        self.cache_layers = self._kinds.count("full") * spec.loop_steps
         tp = int(tensor_parallel)
         if tp < 1:
             raise ValueError("tensor_parallel must be >= 1")
@@ -380,8 +413,10 @@ class ServingEngine:
         self._sample_seeds = np.zeros((self.num_slots,), np.int32)
         # ---- the cache layout (kv_cache.py's seam): picked here, once;
         # everything below meets it through ``self.kv`` alone ------------
-        dims = (self.cache_layers, self.num_slots, cfg.num_heads,
+        dims = (self.cache_layers, self.num_slots, cfg.kv_heads,
                 cfg.head_dim, self.max_len)
+        recurrent = ((self.linear_layers, spec.linear)
+                     if self.linear_layers else None)
         if self.kv_layout == "paged":
             self.kv = kv_cache.PagedLayout(
                 dims, self.kernel, block_len=self.kv_block_len,
@@ -397,8 +432,14 @@ class ServingEngine:
             # and on the CPU always, cached_attention.
             fused_block = None    # the kernel's block, read in place
             forced = bool(self.kernel.get("flash_decode"))
-            if forced or (decode_left_open
-                          and jax.default_backend() == "tpu"):
+            if forced and grouped:
+                raise ValueError(
+                    "kernel flash_decode with grouped-query attention: "
+                    "the fused decode kernel reads a key/value head a "
+                    "query head; such a block decodes through "
+                    "cached_attention, which groups them")
+            if not grouped and (forced or (
+                    decode_left_open and jax.default_backend() == "tpu")):
                 from autodist_tpu.kernel.pallas.flash_decode import (
                     MIN_FUSED_DECODE_LEN, fused_decode_block)
                 block = fused_decode_block(self.max_len, cfg.head_dim)
@@ -408,7 +449,8 @@ class ServingEngine:
                     fused_block = block
                     self.kernel = dict(self.kernel, flash_decode=True)
             self.kv = kv_cache.DenseLayout(dims, self.kernel,
-                                           fused_block=fused_block)
+                                           fused_block=fused_block,
+                                           recurrent=recurrent)
         cache = self.kv.init_cache(dims, cfg.dtype)
         if self.mesh is not None:
             # the k/v arrays split by heads, everything else replicated
@@ -420,9 +462,14 @@ class ServingEngine:
             cache = jax.device_put(cache, self._device)
         self.cache = cache
         telemetry.gauge("engine/cache_layers").set(self.cache_layers)
+        held = kv_cache.bytes_held(dims, cfg.dtype, recurrent)
         telemetry.gauge("engine/kv_bytes_per_token").set(
-            2 * self.cache_layers * cfg.num_heads * cfg.head_dim
-            * jnp.dtype(cfg.dtype).itemsize)
+            held["kv_bytes_per_token"])
+        if recurrent:
+            telemetry.gauge("engine/state_bytes_per_slot").set(
+                held["state_bytes_per_slot"])
+        if spec.moe is not None:
+            telemetry.gauge("engine/experts_held").set(spec.moe.experts_held)
 
         self._prefill_jit = (self._build_chunk_prefill()
                              if self.prefill_chunk is not None
@@ -519,7 +566,7 @@ class ServingEngine:
         pos = jnp.take(shared["pos_embed"], positions, axis=0)
         return x + pos.astype(cfg.dtype)
 
-    def _layer_prefill(self, chunk, x, mask, positions):
+    def _layer_prefill(self, chunk, x, mask, positions, valid=None):
         """One encoder layer over the whole prompt — the training
         :func:`~autodist_tpu.models.pipeline_lm._tp_encoder_layer`
         itself (``return_kv=True`` hands back the layer's k/v
@@ -529,9 +576,11 @@ class ServingEngine:
 
         return _tp_encoder_layer(self.cfg, chunk, x, mask, self._axis,
                                  comm_overlap=self.comm_overlap,
-                                 return_kv=True, positions=positions)
+                                 return_kv=True, positions=positions,
+                                 valid=valid)
 
-    def _layer_cached(self, chunk, x, kc, vc, positions, attend):
+    def _layer_cached(self, chunk, x, kc, vc, positions, attend,
+                      valid=None, tally=None):
         """One encoder layer for tokens at ``positions`` against the
         live cache — a single-token decode step, or the ``[B, C]``
         window chunked prefill and the speculative verify pass share.
@@ -541,15 +590,44 @@ class ServingEngine:
         (write-then-attend) — how, and whether as one kernel, is the
         cache layout's (``self.kv``).  The sub-blocks around the
         attention are the pipelined LM's own (``attention_inputs`` ..
-        ``ffn_residual``)."""
+        ``ffn_residual``; ``valid`` and ``tally`` are the latter's)."""
         from autodist_tpu.models import pipeline_lm as lm
 
         cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
-        x, q, k, v = lm.attention_inputs(cfg, chunk, x, positions, axis,
-                                         overlap)        # [B, C, heads, dh]
+        x, q, k, v, gate = lm.attention_inputs(
+            cfg, chunk, x, positions, axis, overlap)     # [B, C, heads, dh]
         out, kc, vc = attend(q, k, v, kc, vc)
-        x = lm.attention_residual(cfg, chunk, x, out, axis, overlap)
-        return lm.ffn_residual(cfg, chunk, x, axis, overlap), kc, vc
+        x = lm.attention_residual(cfg, chunk, x, out, axis, overlap, gate)
+        return self._ffn(chunk, x, valid, tally), kc, vc
+
+    def _ffn(self, chunk, x, valid=None, tally=None):
+        from autodist_tpu.models.pipeline_lm import ffn_residual
+
+        return ffn_residual(self.cfg, chunk, x, self._axis,
+                            self.comm_overlap, valid=valid, tally=tally)
+
+    def _layer_linear(self, chunk, x, state, layer, *, slot=None,
+                      length=None, valid=None, tally=None):
+        """One linear (gated-DeltaNet) layer against the recurrent state
+        the cache manager holds (``state``: its arrays; ``layer``: the
+        layer's place among the linear ones).  A decode step reads and
+        writes every slot's rows; the prefill of one row starts from a
+        blank state — the slot's previous occupant left one that is
+        nobody's — masks the padding out of the recurrence (``valid``,
+        which is also the routed FFN's), cuts the convolution's tail at
+        ``length`` and overwrites ``slot``'s rows."""
+        from autodist_tpu.models import pipeline_lm as lm
+
+        admits = slot is not None
+        before = (lm.blank_linear_state(self.cfg, x.shape[0]) if admits
+                  else kv_cache.read_state(state, layer))
+        x, after = lm.linear_attention(
+            self.cfg, chunk, x, before, valid=valid if admits else None,
+            length=length)
+        with telemetry.scope("linear_attention"), \
+                telemetry.scope("state_update"):
+            state = kv_cache.write_state(state, layer, after, slot)
+        return self._ffn(chunk, x, valid, tally), state
 
     def _run_layers(self, shared, stages, x, kc, vc, layer_fn):
         """Every layer of the stack over ``(x, kc, vc)``, once or — a
@@ -571,6 +649,30 @@ class ServingEngine:
             return x, kc, vc
 
         return run_stack(self.cfg, shared, (x, kc, vc), layers)
+
+    def _run_period(self, shared, stages, x, kc, vc, state, layer_fn,
+                    linear_fn):
+        """:meth:`_run_layers` for a stack that may mix layer kinds:
+        ``(x, kc, vc, state)``.  A mixed stack walks its period in one
+        pass: a full layer goes to ``layer_fn``, a linear one to
+        ``linear_fn(chunk, x, state, nth)`` with the recurrent ``state``
+        arrays, and each kind counts its own ``nth`` layer of the cache
+        manager's arrays.  Any other stack is :meth:`_run_layers`'s, and
+        ``state`` stays the empty tuple it came as."""
+        from autodist_tpu.models.pipeline_lm import layer_chunk
+
+        if not (self.linear_layers or self.cfg.block.moe):
+            return (*self._run_layers(shared, stages, x, kc, vc, layer_fn),
+                    state)
+        kinds = self._kinds
+        for l, kind in enumerate(kinds):
+            chunk = layer_chunk(self.cfg, stages, l)
+            nth = kinds[:l].count(kind)
+            if kind == "linear":
+                x, state = linear_fn(chunk, x, state, nth)
+            else:
+                x, kc, vc = layer_fn(chunk, x, kc, vc, l, nth)
+        return x, kc, vc, state
 
     def _head(self, shared, h):
         """``(rows, table)`` of the output projection for ``[B, H]``
@@ -615,8 +717,10 @@ class ServingEngine:
         mesh at tp>1, with the cache arrays donated so updates alias in
         place.  ``n_in_rest``/``n_out_rest`` count the replicated
         non-cache operands/results after ``(params, k, v)`` /
-        ``(k, v)``.  The first call, which traces and lowers these
-        unrolled programs, gets stack room of its own: where it stands
+        ``(k, v)``.  A stack with linear layers hands the recurrent
+        state's arrays over last, after those (tp=1 only), and they are
+        donated like the cache.  The first call, which traces and lowers
+        these unrolled programs, gets stack room of its own: where it stands
         on the interpreter's frame stack otherwise decides whether
         lowering takes half a second or twenty (``utils/stack_room``).
 
@@ -626,7 +730,10 @@ class ServingEngine:
         the cycle collector finds it — never, on a heap its owner has
         frozen (``gc.freeze``), as a benchmark does before it times."""
         if self.mesh is None:
-            return FirstCallWithRoom(jax.jit(fn, donate_argnums=(1, 2)))
+            first = 3 + n_in_rest
+            state = tuple(range(first, first + len(self._state_args())))
+            return FirstCallWithRoom(jax.jit(
+                fn, donate_argnums=(1, 2) + state))
         cspec = kv_cache.cache_spec()
         sm = jax.shard_map(
             fn, mesh=self.mesh,
@@ -647,25 +754,39 @@ class ServingEngine:
         S = self.prefill_len
         prefix = self.prefix_caching
 
+        routed = self.cfg.block.moe is not None
+
         def prefill(params, kc, vc, lengths, tok, slot, table_row, seed,
                     prompt, p_len, *rest):
             # ``slot`` a scalar; ``table_row`` [1, max_blocks]; ``seed``,
             # ``p_len`` [1]; ``prompt`` [1, S].  Prefix-caching engines
-            # thread the row's novel-write floor, [1].
+            # thread the row's novel-write floor, [1]; a stack with
+            # linear layers its recurrent state's arrays.
             wf = rest[0] if prefix else None
+            state = rest[1 if prefix else 0:]
             stages, shared = params["stages"], params["shared"]
             positions = jnp.arange(S)
             x = self._embed(shared, prompt, positions)
             mask = jnp.tril(jnp.ones((S, S), bool))[None, None]
+            # the bucket's padding: out of the recurrence, and of the
+            # routing (a padded row chooses no expert)
+            valid = (positions[None, :] < p_len[:, None]
+                     if state or routed else None)
 
             def layer_fn(chunk, x, kc, vc, _, layer):
-                x, k, v = self._layer_prefill(chunk, x, mask, positions)
+                x, k, v = self._layer_prefill(chunk, x, mask, positions,
+                                              valid)
                 kc, vc = self.kv.write_prompt(kc, vc, layer, k, v, slot,
                                               table_row, p_len, wf)
                 return x, kc, vc
 
-            x, kc, vc = self._run_layers(shared, stages, x, kc, vc,
-                                         layer_fn)
+            def linear_fn(chunk, x, state, layer):
+                return self._layer_linear(chunk, x, state, layer,
+                                          slot=slot, valid=valid,
+                                          length=p_len)
+
+            x, kc, vc, state = self._run_period(
+                shared, stages, x, kc, vc, state, layer_fn, linear_fn)
             last = jnp.take_along_axis(
                 x, (p_len - 1)[:, None, None], axis=1)[:, 0]
             # The first emitted token conditions on the p_len prompt
@@ -674,7 +795,7 @@ class ServingEngine:
             tok = lax.dynamic_update_slice(
                 tok, first_tok.astype(tok.dtype), (slot,))
             lengths = lax.dynamic_update_slice(lengths, p_len, (slot,))
-            return kc, vc, lengths, tok
+            return (kc, vc, lengths, tok, *state)
 
         return self._wrap(prefill, n_in_rest=7 + (1 if prefix else 0),
                           n_out_rest=2)
@@ -781,30 +902,43 @@ class ServingEngine:
         self = weakref.proxy(self)
         K = int(steps or self.decode_steps)
 
-        def decode(params, kc, vc, lengths, tok, table, seeds, active):
+        routed = self.cfg.block.moe is not None
+
+        def decode(params, kc, vc, lengths, tok, table, seeds, active,
+                   *state):
             stages, shared = params["stages"], params["shared"]
+            # a slot that is not decoding chooses no expert
+            valid = active[:, None] if routed else None
 
             def body(carry, _):
-                kc, vc, lengths, tok = carry
+                kc, vc, lengths, tok, state, routing = carry
+                tally = [] if routed else None
                 x = self._embed(shared, tok[:, None], lengths[:, None])
-                x, kc, vc = self._run_layers(
-                    shared, stages, x, kc, vc,
+                x, kc, vc, state = self._run_period(
+                    shared, stages, x, kc, vc, state,
                     lambda chunk, x, kc, vc, _, layer: self._layer_cached(
                         chunk, x, kc, vc, lengths[:, None],
                         lambda q, k, v, kc, vc: self.kv.decode_attend(
                             q, k, v, kc, vc, layer, lengths, table, active,
-                            dtype=self.cfg.dtype)))
+                            dtype=self.cfg.dtype), valid, tally),
+                    lambda chunk, x, state, layer: self._layer_linear(
+                        chunk, x, state, layer, valid=valid, tally=tally))
                 # The emitted token conditions on lengths + 1 tokens
                 # (the one just written included) — its sampling key.
                 nxt, _ = self._next_token(shared, x[:, 0], seeds,
                                           lengths + 1)
                 nxt = jnp.where(active, nxt, tok)
                 lengths = lengths + active.astype(jnp.int32)
-                return (kc, vc, lengths, nxt), nxt
+                if routed:
+                    routing = (routing[0] + sum(tally),)
+                return (kc, vc, lengths, nxt, state, routing), nxt
 
-            (kc, vc, lengths, tok), toks = lax.scan(
-                body, (kc, vc, lengths, tok), None, length=K)
-            return kc, vc, lengths, tok, toks
+            # [rows_held, experts_hit] over the window's steps and layers
+            routing = (jnp.zeros((2,), jnp.int32),) if routed else ()
+            (kc, vc, lengths, tok, state, routing), toks = lax.scan(
+                body, (kc, vc, lengths, tok, tuple(state), routing), None,
+                length=K)
+            return (kc, vc, lengths, tok, toks, *state, *routing)
 
         return self._wrap(decode, n_in_rest=5, n_out_rest=3)
 
@@ -922,10 +1056,10 @@ class ServingEngine:
                                 rows=len(rows)):
                 for i, slot in enumerate(rows):
                     c = self.cache
-                    k, v, lengths, tok = self._prefill_jit(
+                    self._adopt(*self._prefill_jit(
                         self.params, c.k, c.v, c.lengths, self._tok,
-                        np.int32(slot), *(a[i:i + 1] for a in picked))
-                    self._adopt(k, v, lengths, tok)
+                        np.int32(slot), *(a[i:i + 1] for a in picked),
+                        *self._state_args()))
             self._count_prefill(len(rows), self.prefill_len)
             self.last_prefill_chunks = 1
         else:
@@ -962,7 +1096,8 @@ class ServingEngine:
             zero = np.zeros((1,), np.int32)
             return (np.int32(slot), np.zeros_like(self.kv.table[:1]), zero,
                     np.zeros((1, self.prefill_len), np.int32), zero,
-                    *((zero,) if self.prefix_caching else ()))
+                    *((zero,) if self.prefix_caching else ()),
+                    *self._state_args())
         B, c = self.num_slots, self.cache
         return (self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
                 jnp.zeros((B, self.prefill_chunk), jnp.int32),
@@ -995,10 +1130,9 @@ class ServingEngine:
         with telemetry.span("engine/prefill/dispatch",
                             loop_steps=self.cfg.block.loop_steps,
                             rows=rows):
-            k, v, lengths, tok = self._prefill_jit(
+            self._adopt(*self._prefill_jit(
                 self.params, c.k, c.v, c.lengths, self._tok,
-                *self._blank_prefill_args(slot))
-            self._adopt(k, v, lengths, tok)
+                *self._blank_prefill_args(slot)))
         self._count_prefill(rows, span)
         if self.draft is not None:
             self.draft.warm_prefill()
@@ -1059,13 +1193,37 @@ class ServingEngine:
                                              self.decode_steps)
             args = (self.params, c.k, c.v, c.lengths, self._tok,
                     self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
-                    jnp.asarray(active_np))
+                    jnp.asarray(active_np), *self._state_args())
         with telemetry.span("engine/decode/dispatch",
                             loop_steps=self.cfg.block.loop_steps):
-            k, v, lengths, tok, toks = self._decode_jit(*args)
-            self._adopt(k, v, lengths, tok)
+            k, v, lengths, tok, toks, *rest = self._decode_jit(*args)
+            n_state = len(self._state_args())
+            self._adopt(k, v, lengths, tok, *rest[:n_state])
         with telemetry.span("engine/decode/fetch"):
-            return np.asarray(jax.device_get(toks))
+            # a routed block's [rows_held, experts_hit] ride the fetch
+            toks, *routing = jax.device_get((toks, *rest[n_state:]))
+        self._count_decode(int(active_np.sum()), self.decode_steps,
+                           *routing)
+        return np.asarray(toks)
+
+    def _count_decode(self, rows: int, steps: int, routing=None) -> None:
+        """What a fused decode window moved beside keys and values: the
+        recurrent-state rows its linear layers read and wrote, and what
+        its routed layers chose — every (row, expert) pair, those that
+        landed on held experts, and the held experts some row hit,
+        summed over steps and layers (``moe/layer_steps`` counts those:
+        ``experts_hit`` can reach ``layer_steps x experts_held``)."""
+        if self.linear_layers:
+            telemetry.counter("engine/state_rows").inc(
+                rows * steps * self.linear_layers)
+        if routing is not None:
+            telemetry.counter("moe/layer_steps").inc(
+                steps * self.cfg.num_layers)
+            telemetry.counter("moe/rows_routed").inc(
+                rows * steps * self.cfg.num_layers
+                * self.cfg.block.moe.top_k)
+            telemetry.counter("moe/rows_held").inc(int(routing[0]))
+            telemetry.counter("moe/experts_hit").inc(int(routing[1]))
 
     def decode_one(self, active):
         """A single-token dispatch through a lazily-built K=1 program —
@@ -1075,11 +1233,11 @@ class ServingEngine:
             self._decode1_jit = self._build_decode(steps=1)
         active_np = np.asarray(active, bool)
         c = self.cache = self.kv.protect(self.cache, active_np, 1)
-        k, v, lengths, tok, toks = self._decode1_jit(
+        k, v, lengths, tok, toks, *rest = self._decode1_jit(
             self.params, c.k, c.v, c.lengths, self._tok,
             self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
-            jnp.asarray(active_np))
-        self._adopt(k, v, lengths, tok)
+            jnp.asarray(active_np), *self._state_args())
+        self._adopt(k, v, lengths, tok, *rest[:len(self._state_args())])
         return np.asarray(jax.device_get(toks))
 
     def decode_window(self, active) -> DecodeWindow:
@@ -1169,12 +1327,21 @@ class ServingEngine:
         return DecodeWindow(tokens=tokens, counts=counts,
                             spec_proposed=proposed, spec_accepted=accepted)
 
-    def _adopt(self, k, v, lengths, tok) -> None:
+    def _adopt(self, k, v, lengths, tok, *state) -> None:
         """A program's outputs become the live state (a ``block_table``
-        is current since the last reserve/release: the program's own)."""
+        is current since the last reserve/release: the program's own);
+        ``state``: the recurrent state's arrays, where the stack has
+        linear layers."""
         self.cache = dataclasses.replace(self.cache, k=k, v=v,
                                          lengths=lengths)
+        if state:
+            self.cache.state = kv_cache.RecurrentState(*state)
         self._tok = tok
+
+    def _state_args(self) -> tuple:
+        """The recurrent state's arrays, the programs' last operands."""
+        state = getattr(self.cache, "state", None)
+        return () if state is None else state.arrays()
 
     @property
     def lengths(self):
@@ -1197,7 +1364,7 @@ class ServingEngine:
         return self._decode_jit.lower(
             self.params, c.k, c.v, c.lengths, self._tok,
             self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
-            active).compile().as_text()
+            active, *self._state_args()).compile().as_text()
 
     def compiled_prefill_text(self) -> str:
         """Optimized HLO of the prefill program (the one-row program;

@@ -79,14 +79,93 @@ class KVCache:
     k: Any
     v: Any
     lengths: Any
+    # what a linear mixer's layers keep for a slot (RecurrentState);
+    # None where every layer caches keys and values
+    state: Any = None
 
 
 def init_cache(num_layers: int, num_slots: int, num_heads: int,
                head_dim: int, max_len: int, dtype=jnp.float32) -> KVCache:
-    """All-zero cache with every slot empty."""
+    """All-zero cache with every slot empty.  ``num_layers`` counts the
+    layers that cache keys and values, ``num_heads`` their key/value
+    heads."""
     shape = (num_layers, num_slots, num_heads, max_len, head_dim)
     return KVCache(k=jnp.zeros(shape, dtype), v=jnp.zeros(shape, dtype),
                    lengths=jnp.zeros((num_slots,), jnp.int32))
+
+
+# --------------------------------------------------------------------------- #
+# State that is not per token: a linear mixer's
+# --------------------------------------------------------------------------- #
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass
+class RecurrentState:
+    """What the linear layers hold for each slot, whatever its length:
+    ``conv`` ``[linear_layer, slot, taps - 1, channels]`` — the inputs
+    the causal convolution still needs — and ``ssm`` ``[linear_layer,
+    slot, value_heads, key_dim, value_dim]`` float32, the recurrence's
+    matrix.  A slot's rows are overwritten whole by the prefill that
+    admits it (:func:`write_state`) and carried by decode; between an
+    eviction and the next admission they may hold anything and nothing
+    reads them."""
+
+    conv: Any
+    ssm: Any
+
+    def arrays(self) -> tuple:
+        return (self.conv, self.ssm)
+
+
+def init_state(linear_layers: int, num_slots: int, mixer,
+               dtype) -> RecurrentState:
+    """All-zero state for ``mixer``
+    (:class:`~autodist_tpu.models.transformer.LinearMixerSpec`)."""
+    return RecurrentState(
+        conv=jnp.zeros((linear_layers, num_slots, mixer.conv_taps - 1,
+                        mixer.conv_channels), dtype),
+        ssm=jnp.zeros((linear_layers, num_slots, mixer.value_heads,
+                       mixer.key_dim, mixer.value_dim), jnp.float32))
+
+
+def bytes_held(dims, dtype, recurrent=None) -> dict:
+    """What a request costs the manager: ``kv_bytes_per_token`` over the
+    caching layers of ``dims`` and ``state_bytes_per_slot`` over the
+    linear ones (``recurrent``: ``(linear layers, LinearMixerSpec)``)."""
+    layers, _, heads, head_dim, _ = dims
+    item = jnp.dtype(dtype).itemsize
+    state = 0
+    if recurrent is not None:
+        n, mixer = recurrent
+        state = n * ((mixer.conv_taps - 1) * mixer.conv_channels * item
+                     + mixer.value_heads * mixer.key_dim
+                     * mixer.value_dim * 4)
+    return {"kv_bytes_per_token": 2 * layers * heads * head_dim * item,
+            "state_bytes_per_slot": state}
+
+
+def read_state(arrays, layer: int, slot=None):
+    """``(conv, ssm)`` of linear layer ``layer``: every slot's, or the
+    one row of ``slot`` (a traced scalar) as a batch of one."""
+    if slot is None:
+        return tuple(a[layer] for a in arrays)
+    return tuple(lax.dynamic_slice_in_dim(a[layer], slot, 1, axis=0)
+                 for a in arrays)
+
+
+def write_state(arrays, layer: int, new, slot=None):
+    """Linear layer ``layer``'s state replaced in place by ``new``
+    ``(conv, ssm)``: every slot's (a decode step), or ``slot``'s alone
+    by the prefill that admits it, as :func:`write_prompt` writes its
+    lane — no other slot's row is read or written.  The write is the
+    last of the recurrence's passes over the state (the new state is
+    computed into it), so it wears the caller's scope
+    (``linear_attention`` / ``state_update``), not ``kv_write``."""
+    out = []
+    for arr, rows in zip(arrays, new):
+        start = (layer, 0 if slot is None else slot) + (0,) * (arr.ndim - 2)
+        out.append(lax.dynamic_update_slice(
+            arr, rows[None].astype(arr.dtype), start))
+    return tuple(out)
 
 
 @scope("kv_write")
@@ -150,10 +229,17 @@ def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32):
     numerics so incremental decode agrees with full-sequence recompute.
     Scores live at ``[B, heads, 1, T]`` — never the ``[T, T]`` square
     the prefill's causal pass needs (the HLO decode probe asserts no
-    such buffer exists).
+    such buffer exists).  Fewer key/value heads than query heads: the
+    group of query heads that reads a key/value head rides in the row
+    dimension, ``[B, kv_heads, group, T]``, and the lane is read once.
     """
     depth = q.shape[-1]
-    q2 = jnp.transpose(q, (0, 2, 1, 3))              # [B, heads, 1, dh]
+    B, _, heads, _ = q.shape
+    kv_heads = k_layer.shape[1]
+    if kv_heads != heads:
+        q2 = q.reshape(B, kv_heads, heads // kv_heads, depth)
+    else:
+        q2 = jnp.transpose(q, (0, 2, 1, 3))          # [B, heads, 1, dh]
     # dot_general contracting head_dim directly against the cache's
     # native [.., T, head_dim] layout — an einsum spelling makes XLA
     # transpose (= copy) the whole cache lane every step.
@@ -169,6 +255,8 @@ def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32):
     out = lax.dot_general(
         probs, v_layer.astype(dtype),
         (((3,), (2,)), ((0, 1), (0, 1))))            # [B, heads, 1, dh]
+    if kv_heads != heads:
+        return out.reshape(B, 1, heads, depth)
     return jnp.transpose(out, (0, 2, 1, 3))          # [B, 1, heads, dh]
 
 
@@ -571,14 +659,21 @@ class DenseLayout:
     block with which the fused decode kernel reads the cache in place
     and writes the step's rows itself — the engine's election, made
     where backend, ``max_len`` and ``head_dim`` can be observed; without
-    one, :func:`write_token` then :func:`cached_attention`."""
+    one, :func:`write_token` then :func:`cached_attention`.
 
-    def __init__(self, dims, kernel, *, fused_block=None):
+    ``dims``: ``(caching layers, slots, key/value heads, head_dim,
+    max_len)``.  ``recurrent``: ``(linear layers, LinearMixerSpec)`` of
+    a stack whose other layers keep a :class:`RecurrentState` for the
+    slot — the second kind of state this manager holds, met through
+    :func:`read_state` / :func:`write_state`."""
+
+    def __init__(self, dims, kernel, *, fused_block=None, recurrent=None):
         from autodist_tpu.kernel.pallas.flash_decode import rows_layout
 
         _, num_slots, _, head_dim, self.max_len = dims
         self.kernel = kernel        # the engine's elections, by name
         self.fused_block = fused_block
+        self.recurrent = recurrent
         # heads of 128 and wider: the cache stays as the decode kernel
         # reads it (narrower heads the chip keeps positions minor-most)
         self._row_major = rows_layout(head_dim)
@@ -586,7 +681,11 @@ class DenseLayout:
         self.table = np.zeros((num_slots, 1), np.int32)
 
     def init_cache(self, dims, dtype) -> KVCache:
-        return init_cache(*dims, dtype=dtype)
+        cache = init_cache(*dims, dtype=dtype)
+        if self.recurrent is not None:
+            cache.state = init_state(self.recurrent[0], dims[1],
+                                     self.recurrent[1], dtype)
+        return cache
 
     # ---- traced ------------------------------------------------------ #
     def write_prompt(self, kc, vc, layer, k, v, slot, table_row, p_len,

@@ -199,7 +199,7 @@ def rank_serving(trainable, resource_spec, candidates=None, *,
                  mean_request_len=None, mean_prompt_len=None,
                  objective: str = "latency",
                  prefix_hit_rate: float = 0.0, spec_acceptance=None,
-                 ladder: bool = False, **cost_model_kwargs):
+                 ladder: bool = False, block=None, **cost_model_kwargs):
     """Rank serving configs by the cost model's serving objective —
     AutoStrategy's second objective (ROADMAP: "latency under load, not
     just training step time").
@@ -240,7 +240,10 @@ def rank_serving(trainable, resource_spec, candidates=None, *,
     the caller) prices ``speculative``
     candidates both directions under latency.  ``ladder=True`` widens
     the default zoo with the rung candidates
-    (:func:`default_serving_candidates` ``ladder=``)."""
+    (:func:`default_serving_candidates` ``ladder=``).  ``block``: the
+    model's ``BlockSpec`` where it is not the default — passes, layer
+    kinds, key/value heads, a linear mixer's state and a routed FFN's
+    experts are priced from it (:meth:`CostModel.decode_cost`)."""
     if objective not in ("latency", "capacity", "fleet", "disagg"):
         raise ValueError(
             f"unknown serving objective {objective!r}; expected "
@@ -266,7 +269,8 @@ def rank_serving(trainable, resource_spec, candidates=None, *,
                                   mean_request_len=mean_request_len,
                                   mean_prompt_len=mean_prompt_len,
                                   prefix_hit_rate=prefix_hit_rate,
-                                  spec_acceptance=spec_acceptance)
+                                  spec_acceptance=spec_acceptance,
+                                  block=block)
         except (ValueError, SpecMeshMismatch) as e:
             logging.info("serving candidate %s skipped: %s", cand, e)
             continue

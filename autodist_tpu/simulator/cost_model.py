@@ -385,6 +385,9 @@ class DecodeCost:
     prefill_time_s: float = 0.0
     decode_time_s: float = 0.0
     handoff_time_s: float = 0.0
+    # A mixed stack's linear layers: the recurrent state read and
+    # written a token, at the HBM rate (part of ``compute_time_s``).
+    state_time_s: float = 0.0
 
     @property
     def score(self) -> float:
@@ -1446,7 +1449,7 @@ class CostModel:
                     kv_block_len: int = 16,
                     prefix_hit_rate: float = 0.0,
                     spec_acceptance: Optional[float] = None,
-                    loop_steps: int = 1) -> DecodeCost:
+                    loop_steps: int = 1, block=None) -> DecodeCost:
         """Per-token decode latency for one serving config.
 
         ``config`` is either a training :class:`Strategy` (its Strategy-
@@ -1516,6 +1519,20 @@ class CostModel:
           the KV elements a position holds (one set of keys and values
           per pass and layer) all multiply by it; the parameters'
           bytes do not — there is one set of weights.
+        * **a mixed or routed stack** — ``block`` (the model's
+          ``BlockSpec``; its ``loop_steps`` then stands for the
+          argument): only the ``"full"`` layers of ``layer_period``
+          cache keys and values, ``kv_heads x head_dim`` wide, and pay
+          the attention term; each ``"linear"`` layer instead reads and
+          writes a float32 state of ``value_heads x key_dim x
+          value_dim`` a slot every token, priced at the chip's HBM
+          rate (``state_time_s``) and held a slot in memory and in the
+          capacity term.  A routed FFN's experts (leaves under
+          ``experts/``) are not every parameter once: a row multiplies
+          the ``top_k / num_experts`` of them it chose, and a step
+          reads the held experts some row hit — ``held x (1 - (1 -
+          top_k / num_experts) ^ slots)`` in expectation — at the HBM
+          rate; the larger of the two is their price.
         """
         from autodist_tpu.strategy.ir import (normalize_kernel,
                                               normalize_kv_layout,
@@ -1610,12 +1627,17 @@ class CostModel:
                 i.shape[0] for i in trainable.var_infos()
                 if len(i.shape) >= 3)
             layers = leads.most_common(1)[0][0] if leads else 1
-        passes = int(loop_steps)
+        passes = int(loop_steps if block is None else block.loop_steps)
         if passes < 1:
             raise ValueError(f"loop_steps must be >= 1, got {loop_steps}")
         # what a token passes, and what the cache holds a layer of
-        layers = int(layers) * passes
-        elems = bytes_ = 0.0
+        kinds = block.layer_kinds(int(layers)) if block is not None \
+            else ("full",) * int(layers)
+        linear_layers = kinds.count("linear")
+        layers = kinds.count("full") * passes
+        moe = getattr(block, "moe", None)
+        hbm_rate = self.chip.hbm_gbps * 1e9
+        elems = bytes_ = expert_elems = expert_bytes = 0.0
         for info in trainable.var_infos():
             shard = 1
             if tp > 1:
@@ -1629,12 +1651,33 @@ class CostModel:
             # the stacked layers' weights multiply every pass's
             # activations; embedding, head and final norm once
             stacked = info.name.startswith("stages/")
-            elems += info.size / shard * (passes if stacked else 1)
             bytes_ += info.byte_size / shard
+            if moe is not None and "/experts/" in info.name:
+                expert_elems += info.size
+                expert_bytes += info.byte_size
+                continue
+            elems += info.size / shard * (passes if stacked else 1)
         mxu_eff = float(self.link_profile.get(
             "mxu_efficiency", _DEFAULT_MXU_EFFICIENCY))
         flops_rate = self.chip.peak_bf16_tflops * 1e12 * mxu_eff
         compute = 2.0 * elems * batch_slots / flops_rate
+        if moe is not None:
+            chosen = moe.top_k / moe.num_experts
+            hit = 1.0 - (1.0 - chosen) ** batch_slots
+            compute += max(
+                2.0 * expert_elems * chosen * batch_slots / flops_rate,
+                expert_bytes * hit / hbm_rate)
+        # a linear layer's float32 state a slot, there and back a token
+        state_bytes = 0.0
+        if linear_layers:
+            mixer = block.linear
+            state_bytes = 4.0 * linear_layers * mixer.value_heads \
+                * mixer.key_dim * mixer.value_dim
+        state_time = 2.0 * state_bytes * batch_slots / hbm_rate
+        compute += state_time
+        kv_width = hidden
+        if block is not None and block.kv_heads and block.head_dim:
+            kv_width = block.kv_heads * block.head_dim
         # Attention over the cache: per token, each layer contracts the
         # query against its [heads/tp, max_len, head_dim] cache slice
         # twice (scores + values) — the term that grows with occupancy
@@ -1727,10 +1770,10 @@ class CostModel:
         bl = max(int(kv_block_len), 1)
         resident = (float(-(-int(math.ceil(mean_len)) // bl) * bl)
                     if kv_layout == "paged" else float(max_len))
-        lane_bytes = 2.0 * layers * hidden * kv_bytes_per_elem \
+        lane_bytes = 2.0 * layers * kv_width * kv_bytes_per_elem \
             / max(tp, 1)
         kv = lane_bytes * resident * batch_slots
-        mem = bytes_ + kv
+        mem = bytes_ + kv + state_bytes * batch_slots
         if spec_k is not None:
             # The draft rides along: its params + its full-capacity
             # block pool cost spec_draft_flops_frac of the target's.
@@ -1748,8 +1791,8 @@ class CostModel:
         if prefix_caching:
             resident_eff = max(resident * (1.0 - float(prefix_hit_rate)),
                                float(bl))
-        capacity = max(hbm - bytes_, 0.0) / max(lane_bytes * resident_eff,
-                                                1e-30)
+        capacity = max(hbm - bytes_, 0.0) / max(
+            lane_bytes * resident_eff + state_bytes, 1e-30)
         if spec_k is not None:
             capacity /= 1.0 + draft_frac
         # Router dispatch across DCN: a fleet too big for one slice
@@ -1807,7 +1850,8 @@ class CostModel:
                           decode_replicas=decode_r,
                           prefill_time_s=prefill_t,
                           decode_time_s=decode_t,
-                          handoff_time_s=handoff)
+                          handoff_time_s=handoff,
+                          state_time_s=state_time)
 
     def strategy_cost(self, trainable: Trainable,
                       strategy: Strategy) -> StrategyCost:

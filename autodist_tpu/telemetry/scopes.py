@@ -24,6 +24,13 @@ SCOPES = (
     "rope",        # rotary rotation of q and k
     "grad_sync",   # gradient buckets' all-reduce, the reduce-scatters
     "optimizer",   # the update, its application, the gather to storage
+    "linear_attention",  # a gated-DeltaNet mixer: projections, the
+                   # convolution, the gated norm, the output projection
+    "state_update",  # inside linear_attention: the recurrent state read,
+                   # decayed, written (one step, or a window by chunks)
+    "moe",         # a routed FFN: router, sort, experts, shared expert
+    "moe_experts",  # inside moe: routing and the held experts' grouped
+                   # matmuls over the (row, expert) pairs that hit them
 )
 
 

@@ -23,7 +23,12 @@ weights random from a seed:
    host CPU;
 4. **kernels** — every Pallas kernel compiled through Mosaic
    (``interpret=False``) against the composed path it replaces, both on
-   the chip, then once through the engine;
+   the chip, then once through the engine; and what a mixed stack's
+   decode step runs beside attention, at the widths of the benchmark's
+   ``qwen3-next-80b-a3b`` cell, against its composed form on the chip:
+   the recurrent state's update (one position; a window through the
+   chunked form) and the routed layer (sorted pairs through the grouped
+   matmul, 64 of 512 experts held, 10 a token);
 5. **multi-chip** (when ``jax.device_count() > 1``) — the six lowering
    programs of ``__graft_entry__``, the ring kernels over real ICI,
    tensor-parallel serving, and two one-chip engines on two chips.
@@ -54,6 +59,13 @@ run at ``highest``):
 * attention kernels against the composed bf16 path: ``2^-5`` of the
   reference's largest magnitude forward, ``2^-4`` backward (both sides
   round to bf16 between their matmuls, in different places).
+* the recurrent state's update against the recurrence in einsums at
+  ``highest``: ``1e-5`` of the largest magnitude for one position (both
+  float32, sums in another order), ``1e-4`` through the chunked form
+  (its matmuls run at ``highest``; the solved chunk adds a few float32
+  roundings a position).  The routed layer against every held expert
+  over every row: ``2e-2`` (bf16 products on both sides, summed in
+  another order over 10 experts).
 * ring hop kernels: the scale bit-equal, levels within one (a value on
   a rounding boundary may land on either side); ring collectives
   against the exact float32 collective: ``2^-5`` of its largest
@@ -516,6 +528,103 @@ def kernels_phase(*, interpret: bool, seq_len: int = 512, heads: int = 12,
     return done
 
 
+def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
+                      key_dim: int = 128, value_dim: int = 128,
+                      window: int = 256, hidden: int = 2048,
+                      experts: int = 512, held: int = 64, top_k: int = 10,
+                      width: int = 512, seed: int = 0) -> list:
+    """What a mixed stack's decode step runs beside attention, at the
+    widths of the benchmark's ``qwen3-next-80b-a3b`` cell, against its
+    composed form on the same backend: the recurrent state's update (one
+    position; a window through the chunked form) against the recurrence
+    written with einsums at ``highest``, and the routed layer (sorted
+    pairs through the grouped matmul) against every held expert over
+    every row."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.parallel import moe
+
+    ph = "mixed"
+    r = np.random.RandomState(seed)
+    rand = lambda *shape, scale=1.0, dtype=jnp.float32: jnp.asarray(
+        r.randn(*shape) * scale, dtype)
+    hi = jax.lax.Precision.HIGHEST
+    done = []
+
+    # ---- the recurrent state: one position, then a window ---------------
+    B, Hh, dk, dv, T = slots, value_heads, key_dim, value_dim, window
+    q = lm._l2_normalise(rand(B, T, Hh, dk)) * dk ** -0.5
+    k = lm._l2_normalise(rand(B, T, Hh, dk))
+    v, state = rand(B, T, Hh, dv), rand(B, Hh, dk, dv)
+    g = -jax.nn.softplus(rand(B, T, Hh))
+    beta = jax.nn.sigmoid(rand(B, T, Hh))
+
+    def composed_step(S, at):
+        q_t, k_t, v_t, g_t, b_t = at
+        S = S * jnp.exp(g_t)[..., None, None]
+        d = (v_t - jnp.einsum("bhkv,bhk->bhv", S, k_t, precision=hi)) \
+            * b_t[..., None]
+        S = S + jnp.einsum("bhk,bhv->bhkv", k_t, d, precision=hi)
+        return S, jnp.einsum("bhkv,bhk->bhv", S, q_t, precision=hi)
+
+    first = tuple(t[:, 0] for t in (q, k, v, g, beta))
+    (o, new), s = timed(lambda: jax.block_until_ready(
+        jax.jit(lm.gated_delta_step)(*first, state)))
+    ref_new, ref_o = jax.jit(composed_step)(state, first)
+    require_close(ph, f"gated_delta_step output ({B} slots x {Hh} heads of "
+                      f"{dk} x {dv})", o, ref_o, 1e-5)
+    require_close(ph, "gated_delta_step state", new, ref_new, 1e-5)
+    say(ph, f"state update, one position: first call {s:.2f}s")
+    done.append("gated_delta_step")
+
+    steps = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta))
+    ref_S, ref_o = jax.jit(lambda S, xs: jax.lax.scan(composed_step, S, xs))(
+        state, steps)
+    (o, S), s = timed(lambda: jax.block_until_ready(
+        jax.jit(lm.gated_delta_chunked)(q, k, v, g, beta, state)))
+    require_close(ph, f"gated_delta_chunked output (window of {T})", o,
+                  jnp.moveaxis(ref_o, 0, 1), 1e-4)
+    require_close(ph, "gated_delta_chunked state", S, ref_S, 1e-4)
+    say(ph, f"state update, chunked over {T} positions: first call "
+            f"{s:.2f}s")
+    done.append("gated_delta_chunked")
+
+    # ---- the routed layer: a decode step's rows, a prefill row's --------
+    bf16 = jnp.bfloat16
+    router = rand(hidden, experts, scale=0.02)
+    wi = rand(held, hidden, 2 * width, scale=0.02, dtype=bf16)
+    wo = rand(held, width, hidden, scale=0.02, dtype=bf16)
+
+    def every_expert(x):
+        exps, w = moe.route_top_k(x, router, top_k)
+        full = jnp.zeros((x.shape[0], experts), jnp.float32).at[
+            jnp.arange(x.shape[0])[:, None], exps].set(w)[:, :held]
+        h = jnp.einsum("rh,ehm->erm", x, wi,
+                       preferred_element_type=jnp.float32)
+        h = (jax.nn.silu(h[..., :width]) * h[..., width:]).astype(bf16)
+        y = jnp.einsum("erm,emh->erh", h, wo,
+                       preferred_element_type=jnp.float32)
+        return jnp.einsum("re,erh->rh", full, y, precision=hi)
+
+    for rows in (slots, 8 * slots):
+        x = rand(rows, hidden, dtype=bf16)
+        (got, stats), s = timed(lambda: jax.block_until_ready(jax.jit(
+            lambda x: moe.routed_experts(x, router, wi, wo, top_k=top_k))(x)))
+        require_close(ph, f"routed_experts({rows} rows x {top_k} of "
+                          f"{experts}, {held} held)", got,
+                      jax.jit(every_expert)(x), 2e-2)
+        exps, _ = moe.route_top_k(x, router, top_k)
+        landed = int((np.asarray(exps) < held).sum())
+        require(int(stats[0]) == landed, ph, "pairs on held experts",
+                f"{int(stats[0])} counted, {landed} routed there, "
+                f"{int(stats[1])} experts hit; first call {s:.2f}s")
+    done.append("routed_experts")
+    return done
+
+
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,
                        matmul_shape=(1024, 1024, 1024), seed: int = 0) -> list:
     """The three ring kernels whole, inside ``shard_map`` over
@@ -713,6 +822,7 @@ def main() -> int:
         require_mostly_identical(f"serve:{label}", out["tokens"],
                                  dense["tokens"])
     say("kernel", f"compiled (interpret=False) and agreed: {kernels}")
+    say("mixed", f"agreed with their composed forms: {mixed_block_phase()}")
 
     if n > 1:
         multichip_phase(cfg, params, prompts, dense["tokens"],

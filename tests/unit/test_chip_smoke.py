@@ -67,6 +67,14 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert not any(line.startswith("{") for line in proc.stdout.splitlines())
 
 
+def test_mixed_block_phase_at_toy_width():
+    done = chip_smoke.mixed_block_phase(
+        slots=3, value_heads=2, key_dim=16, value_dim=8, window=37,
+        hidden=32, experts=16, held=8, top_k=4, width=8)
+    assert done == ["gated_delta_step", "gated_delta_chunked",
+                    "routed_experts"]
+
+
 @pytest.mark.slow
 def test_kernel_phase_at_toy_width_under_the_interpreter(lm):
     done = chip_smoke.kernels_phase(

@@ -126,6 +126,73 @@ def test_one_row_prefill_compiles_in_place(one_chip, hidden, heads, mlp,
     assert layouts == {minor}, layouts
 
 
+# one period of the benchmark's mixed stack (three gated-DeltaNet layers
+# and a gated attention layer on 2 of 16 heads of 256, 64 of 512 experts
+# held) at its published widths, 8 slots of 512 positions
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_mixed_stack_compiles_in_place(one_chip, program):
+    """Both programs hold what they are given — the keys and values and
+    the recurrent state alias their outputs, no op copies the state or a
+    layer's experts (a stack of experts sliced by layer reached the
+    grouped matmul as a copy of all of them: 5.4 GB at 16 layers), and
+    the experts run in the compiler's grouped-matmul kernel, twice a
+    layer."""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import (BlockSpec, LinearMixerSpec,
+                                                 RoutedFFNSpec,
+                                                 TransformerConfig)
+    from autodist_tpu.serving import ServingEngine
+
+    bf16, slots, bucket = jnp.bfloat16, 8, 256
+    cfg = TransformerConfig(
+        vocab_size=18992, hidden_size=2048, num_layers=4, num_heads=16,
+        mlp_dim=512, max_len=512, dtype=bf16, dropout_rate=0.0,
+        attention_dropout_rate=0.0,
+        block=BlockSpec(
+            norm="rmsnorm", norm_placement="pre", norm_zero_centred=True,
+            positions="rope", rope_theta=1e7, rope_fraction=0.25,
+            ffn="swiglu", bias=False, tied_head=False, kv_heads=2,
+            head_dim=256, qk_norm=True, attn_gate=True,
+            layer_period=("linear", "linear", "linear", "full"),
+            linear=LinearMixerSpec(16, 32, 128, 128),
+            moe=RoutedFFNSpec(512, 10, 512, experts_held=64, shared_width=512)))
+    params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
+                          lm.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    engine = ServingEngine(cfg, params, num_slots=slots, max_len=512,
+                           prefill_len=bucket, decode_steps=8)
+    sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                         sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    c = engine.cache
+    head = (jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
+            i32(slots), i32(slots))
+    state = tuple(sds(a) for a in engine._state_args())
+    with jax.default_matmul_precision("default"):
+        if program == "decode":
+            compiled = engine._decode_jit.lower(
+                *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
+                    (slots,), jnp.bool_, sharding=one_chip),
+                *state).compile()
+        else:
+            compiled = engine._prefill_jit.lower(
+                *head, i32(), i32(1, 1), i32(1), i32(1, bucket), i32(1),
+                *state).compile()
+    mem = compiled.memory_analysis()
+    held = 2 * c.k.size * 2 + sum(a.size * a.dtype.itemsize
+                                  for a in engine._state_args())
+    assert abs(mem.alias_size_in_bytes - held) < 4096
+    assert mem.temp_size_in_bytes < 128 << 20
+    text = compiled.as_text()
+    assert len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
+                          r"[^\n]*ragged-dot-none", text)) >= 8
+    assert not re.findall(r"= f32\[3,8,32,128,128\][^ ]* (?:copy|transpose)\(",
+                          text)
+    assert not re.findall(r"= bf16\[64,2048,1024\][^ ]* (?:copy|fusion)\(",
+                          text)
+
+
 # one encoder layer's attention at the training cell's widths (BERT-base:
 # 12 heads of 64, 512 positions) and at heads of 128, forward and
 # backward: Mosaic takes the one-pass kernels' tiles, and no array of the

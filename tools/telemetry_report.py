@@ -66,6 +66,16 @@ _KV_BLOCK_GAUGES = ("serve/kv_blocks_free", "serve/kv_blocks_used")
 # accounting that says what the chip computed for the prompts it
 # admitted was dropped — --check fails it.
 _PREFILL_COUNTERS = ("engine/prefill_rows", "engine/prefill_positions")
+# Routing counters of a routed FFN (autodist_tpu/serving/engine.py): every
+# decode window advances moe/layer_steps (steps x layers), moe/rows_routed
+# (the decoding rows' (row, expert) pairs), moe/rows_held (the pairs that
+# landed on experts this device holds) and moe/experts_hit (held experts
+# with at least one row) together.  More pairs held than routed, more
+# experts hit than pairs held, or more than layer_steps x the
+# engine/experts_held gauge means the routing the program reports is not
+# the routing it ran — --check fails it.
+_ROUTING_COUNTERS = ("moe/layer_steps", "moe/rows_routed", "moe/rows_held",
+                     "moe/experts_hit")
 # Per-reshard records (autodist_tpu/elastic/reshard.py): one per
 # executed reshard — route taken (compiled fast path vs host-staged),
 # payload moved, and the host-memory high-water mark the staged route
@@ -478,6 +488,27 @@ def check_schema(run_dir: str) -> list[str]:
                 f"trace.json: {bare} engine/prefill/dispatch span(s) "
                 "without their `rows` argument in a run that counts "
                 "prefill rows")
+
+    routing = [counters.get(n) for n in _ROUTING_COUNTERS]
+    if any(c is not None for c in routing):
+        held_g = gauges.get("engine/experts_held")
+        if any(c is None for c in routing) or held_g is None:
+            problems.append(
+                f"metrics.jsonl: {', '.join(_ROUTING_COUNTERS)} and the "
+                "engine/experts_held gauge come together — one is missing")
+        else:
+            steps, routed, held, hit = (c.get("value", 0) for c in routing)
+            if not hit <= held <= routed:
+                problems.append(
+                    f"metrics.jsonl: moe/experts_hit = {hit!r}, "
+                    f"moe/rows_held = {held!r}, moe/rows_routed = "
+                    f"{routed!r} — an expert that is hit holds a pair, "
+                    "and a pair that is held was routed")
+            if hit > steps * held_g.get("value", 0):
+                problems.append(
+                    f"metrics.jsonl: moe/experts_hit = {hit!r} is over "
+                    f"moe/layer_steps = {steps!r} x engine/experts_held "
+                    f"= {held_g.get('value')!r}")
 
     fused = (counters.get(_ATTENTION_COUNTERS[0]) or {}).get("value", 0)
     if bool(fused) != ("kernel/flash_attention_elected" in gauges):
