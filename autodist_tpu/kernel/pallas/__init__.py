@@ -29,6 +29,12 @@ shape-dependent too):
   the q/dq fused into the hop (no convert sandwich around one
   monolithic collective).
 
+* :func:`~autodist_tpu.kernel.pallas.delta_step.gated_delta_step_fused`
+  — one position of the gated delta rule over the serving cache
+  manager's stacked float32 recurrent state, each ``(slot, head)`` tile
+  read once and written back in place; elected where it is called, from
+  what the call observes (:data:`OBSERVED_KERNELS`).
+
 Every kernel runs under the Pallas interpreter off-TPU (the simulated
 CPU mesh the test harness uses), so each carries a CPU golden pinned
 against its composed lowering; on real TPU the same ``pallas_call``
@@ -43,7 +49,8 @@ from __future__ import annotations
 # The Strategy IR's kernel-slot vocabulary (strategy/ir.py
 # normalize_kernel re-exports this; kernel code stays IR-agnostic).
 KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
-                  "collective_matmul", "a2a_ring", "flash_attention")
+                  "collective_matmul", "a2a_ring", "flash_attention",
+                  "delta_step")
 
 # Kernels that change the *training* program (the pipeline and expert
 # lowerings honor them); flash_decode/flash_prefill are serving-side
@@ -52,13 +59,15 @@ TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
                     "flash_attention")
 
 # Kernels elected where they are called, from what the call observes
-# (``models.transformer.attend``).  The kernel slot says nothing about
-# them unless someone overrides: ``True`` takes the kernel wherever it
-# can run, ``False`` forbids it (the composed path, for a comparison) —
-# the one ``False`` the canonical slot keeps.  The word reaches the call
-# site through ``parallel.tensor.kernel_scope``, which the collective,
-# GSPMD and pipeline lowerings open around the model they trace.
-OBSERVED_KERNELS = ("flash_attention",)
+# (``models.transformer.attend``; ``serving.kv_cache.DenseLayout
+# .advance_state``).  The kernel slot says nothing about them unless
+# someone overrides: ``True`` takes the kernel wherever it can run,
+# ``False`` forbids it (the composed path, for a comparison) — the one
+# ``False`` the canonical slot keeps.  The word reaches ``attend``
+# through ``parallel.tensor.kernel_scope``, which the collective, GSPMD
+# and pipeline lowerings open around the model they trace, and the
+# serving layout from the engine that builds it.
+OBSERVED_KERNELS = ("flash_attention", "delta_step")
 
 # Op-metadata marker prefix: `with jax.named_scope(kernel_marker(name))`
 # around a pallas_call stamps every emitted op's `op_name` metadata, and

@@ -314,7 +314,7 @@ def gated_delta_step(q, k, v, g, beta, state):
 
 
 def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
-                     valid=None, length=None):
+                     valid=None, length=None, step=gated_delta_step):
     """The gated-DeltaNet mixer with its residual: ``(x + mixer(N(x)),
     (tail, S))``.  ``x``: ``[B, S, H]``; ``state``: ``(tail [B, taps - 1,
     channels], S [B, value_heads, key_dim, value_dim] float32)`` before
@@ -323,7 +323,11 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
     marks the window's real positions (a padded one moves neither the
     state nor, being later, any real position's output) and ``length``
     ``[B]`` their count: the tail handed back is the one before position
-    ``length``."""
+    ``length``.  ``step``: what advances ``S`` by the one position, with
+    :func:`gated_delta_step`'s operands and results — a caller that
+    keeps the state elsewhere (the serving engine's cache manager:
+    ``serving.kv_cache.DenseLayout.advance_state``) hands its own, and
+    ``S`` is then whatever that takes and returns."""
     spec, dtype, lin = cfg.block, cfg.dtype, cfg.block.linear
     la = chunk["linear_attention"]
     kh, vh, dk, dv = lin.key_heads, lin.value_heads, lin.key_dim, \
@@ -356,8 +360,8 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
         q, k = (jnp.repeat(t, vh // kh, axis=2) for t in (q, k))
         v = v.reshape(B, S, vh, dv)
         if S == 1:
-            o, ssm = gated_delta_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                      beta[:, 0], ssm)
+            o, ssm = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                          ssm)
             o = o[:, None]
         else:
             with scope("state_update"):

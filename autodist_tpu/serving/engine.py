@@ -30,6 +30,7 @@ the entry-point conveniences.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import weakref
 from typing import Optional
 
@@ -514,6 +515,11 @@ class ServingEngine:
             # gated by `tools/telemetry_report.py --check`.
             from autodist_tpu.parallel._spmd import emit_kernel_gauges
             emit_kernel_gauges(gauges)
+        if self.linear_layers:
+            # the recurrent state's decode step: 1 where the layout's
+            # seam takes the fused kernel, 0 where the composed step
+            telemetry.gauge("kernel/delta_step_elected").set(
+                int(self.kv.state_kernel(self.cache.state.ssm)))
 
     def __setattr__(self, name, value):
         """Handing the engine one of its own methods back (a caller that
@@ -610,23 +616,37 @@ class ServingEngine:
                       length=None, valid=None, tally=None):
         """One linear (gated-DeltaNet) layer against the recurrent state
         the cache manager holds (``state``: its arrays; ``layer``: the
-        layer's place among the linear ones).  A decode step reads and
-        writes every slot's rows; the prefill of one row starts from a
-        blank state — the slot's previous occupant left one that is
-        nobody's — masks the padding out of the recurrence (``valid``,
-        which is also the routed FFN's), cuts the convolution's tail at
-        ``length`` and overwrites ``slot``'s rows."""
+        layer's place among the linear ones).  A decode step advances
+        every slot's rows: the recurrent matrix where the manager keeps
+        it, through the layout's seam (``self.kv.advance_state``: the
+        stacked array goes in and comes out, no slice of it here), the
+        convolution's tail read and written here.  The prefill of one
+        row starts from a blank state — the slot's previous occupant
+        left one that is nobody's — masks the padding out of the
+        recurrence (``valid``, which is also the routed FFN's), cuts the
+        convolution's tail at ``length`` and overwrites ``slot``'s
+        rows."""
         from autodist_tpu.models import pipeline_lm as lm
 
-        admits = slot is not None
-        before = (lm.blank_linear_state(self.cfg, x.shape[0]) if admits
-                  else kv_cache.read_state(state, layer))
-        x, after = lm.linear_attention(
-            self.cfg, chunk, x, before, valid=valid if admits else None,
-            length=length)
-        with telemetry.scope("linear_attention"), \
-                telemetry.scope("state_update"):
-            state = kv_cache.write_state(state, layer, after, slot)
+        if slot is not None:
+            x, after = lm.linear_attention(
+                self.cfg, chunk, x,
+                lm.blank_linear_state(self.cfg, x.shape[0]), valid=valid,
+                length=length)
+            with telemetry.scope("linear_attention"), \
+                    telemetry.scope("state_update"):
+                state = kv_cache.write_state(state, layer, after, slot)
+        else:
+            # both arrays, as the benchmark's planted faults wrap it (the
+            # slice of the matrices is dead code, and compiled away)
+            tail, _ = kv_cache.read_state(state, layer)
+            x, (tail, ssm) = lm.linear_attention(
+                self.cfg, chunk, x, (tail, state[1]),
+                step=functools.partial(self.kv.advance_state, layer=layer))
+            with telemetry.scope("linear_attention"), \
+                    telemetry.scope("state_update"):
+                state = (*kv_cache.write_state(state[:1], layer, (tail,)),
+                         ssm)
         return self._ffn(chunk, x, valid, tally), state
 
     def _run_layers(self, shared, stages, x, kc, vc, layer_fn):

@@ -665,7 +665,8 @@ class DenseLayout:
     max_len)``.  ``recurrent``: ``(linear layers, LinearMixerSpec)`` of
     a stack whose other layers keep a :class:`RecurrentState` for the
     slot — the second kind of state this manager holds, met through
-    :func:`read_state` / :func:`write_state`."""
+    :func:`read_state` / :func:`write_state` and, a decode step's
+    recurrence, :meth:`advance_state`."""
 
     def __init__(self, dims, kernel, *, fused_block=None, recurrent=None):
         from autodist_tpu.kernel.pallas.flash_decode import rows_layout
@@ -732,6 +733,37 @@ class DenseLayout:
         with scope("attention"):
             return chunk_attention(q, kc[layer], vc[layer], starts,
                                    dtype=dtype)
+
+    def state_kernel(self, ssm) -> bool:
+        """Whether a decode step advances ``ssm`` (the stacked recurrent
+        matrices, or their shape and type) in the fused kernel: the
+        election, from what can be observed where it is called
+        (:func:`~autodist_tpu.kernel.pallas.delta_step
+        .delta_step_elected`; the kernel slot's ``delta_step`` forces or
+        forbids)."""
+        from autodist_tpu.kernel.pallas.delta_step import delta_step_elected
+
+        return delta_step_elected(self.kernel.get("delta_step"), ssm.shape,
+                                  ssm.dtype)
+
+    def advance_state(self, q, k, v, g, beta, ssm, layer):
+        """``(o, ssm)``: every slot's recurrent matrix of linear layer
+        ``layer`` advanced by one position
+        (:func:`~autodist_tpu.models.pipeline_lm.gated_delta_step`'s
+        operands, ``ssm`` the stacked array) and read out — in the fused
+        kernel, which reads and writes each tile of the array once, in
+        place, or the composed step on the layer's slice and its
+        :func:`write_state`."""
+        if self.state_kernel(ssm):
+            from autodist_tpu.kernel.pallas.delta_step import \
+                gated_delta_step_fused
+            with scope("state_update"):
+                return gated_delta_step_fused(q, k, v, g, beta, ssm, layer)
+        from autodist_tpu.models.pipeline_lm import gated_delta_step
+
+        o, new = gated_delta_step(q, k, v, g, beta, ssm[layer])
+        with scope("state_update"):
+            return o, write_state((ssm,), layer, (new,))[0]
 
     # ---- host -------------------------------------------------------- #
     def table_arg(self, cache):

@@ -532,14 +532,18 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
                       key_dim: int = 128, value_dim: int = 128,
                       window: int = 256, hidden: int = 2048,
                       experts: int = 512, held: int = 64, top_k: int = 10,
-                      width: int = 512, seed: int = 0) -> list:
+                      width: int = 512, linear_layers: int = 12,
+                      seed: int = 0) -> list:
     """What a mixed stack's decode step runs beside attention, at the
     widths of the benchmark's ``qwen3-next-80b-a3b`` cell, against its
     composed form on the same backend: the recurrent state's update (one
     position; a window through the chunked form) against the recurrence
-    written with einsums at ``highest``, and the routed layer (sorted
-    pairs through the grouped matmul) against every held expert over
-    every row."""
+    written with einsums at ``highest``; where its tiles fit, the fused
+    delta-step kernel over the stacked state of ``linear_layers`` layers
+    against the composed step on a layer's slice, with the seconds of
+    each alone, at every count of heads a grid step can take; and the
+    routed layer (sorted pairs through the grouped matmul) against every
+    held expert over every row."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -579,6 +583,53 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
     require_close(ph, "gated_delta_step state", new, ref_new, 1e-5)
     say(ph, f"state update, one position: first call {s:.2f}s")
     done.append("gated_delta_step")
+
+    # ---- the fused kernel, in place in the cache manager's array --------
+    from autodist_tpu.kernel.pallas import delta_step as ds
+    from autodist_tpu.serving import kv_cache
+
+    if ds.delta_step_fits(state.shape, state.dtype):
+        L, layer, reps = linear_layers, linear_layers // 2, 47
+        stack = lambda: jnp.stack([state] * L)
+
+        def composed(ssm):
+            o, new = lm.gated_delta_step(*first, ssm[layer])
+            return o, kv_cache.write_state((ssm,), layer, (new,))[0]
+
+        def per_call(fn):
+            """Seconds a call of ``fn(ssm) -> (o, ssm)``: ``reps`` calls
+            in one program, the array donated and carried, so that the
+            host's dispatch (longer than the call) is paid once."""
+            many = jax.jit(lambda ssm: jax.lax.fori_loop(
+                0, reps, lambda _, c: fn(c[1]), fn(ssm))[1], donate_argnums=0)
+            ssm = jax.block_until_ready(many(stack()))
+            return timed(lambda: jax.block_until_ready(many(ssm)))[1] \
+                / (reps + 1)
+
+        ref_o, ref_ssm = jax.jit(composed)(stack())
+        took = {"composed": per_call(composed)}
+        for hb in (h for h in (8, 16, 32) if Hh % h == 0):
+            fused = lambda ssm, hb=hb: ds.gated_delta_step_fused(
+                *first, ssm, jnp.int32(layer), heads_per_step=hb)
+            o, ssm = jax.jit(fused)(stack())
+            require_close(ph, f"delta_step kernel output ({hb} heads a "
+                              f"grid step)", o, ref_o, 1e-5)
+            require(bool((ssm[:layer] == state).all()
+                         and (ssm[layer + 1:] == state).all()), ph,
+                    "delta_step kernel leaves the other layers",
+                    "bit for bit")
+            err, _ = max_err(ssm[layer], ref_ssm[layer])
+            require_close(ph, "delta_step kernel state", ssm[layer],
+                          ref_ssm[layer], 1e-5)
+            took[hb] = per_call(fused)
+            say(ph, f"delta_step kernel, {hb} heads a grid step: largest "
+                    f"absolute difference of the state {err:.3g}")
+        moved = 2 * state.size * 4
+        say(ph, f"state update of one of {L} layers, {moved / 1e6:.1f} MB "
+                f"there and back, seconds a call alone: " + ", ".join(
+                    f"{name} {t:.6f} ({moved / t / 1e9:.0f} GB/s)"
+                    for name, t in took.items()))
+        done.append("gated_delta_step_fused")
 
     steps = tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta))
     ref_S, ref_o = jax.jit(lambda S, xs: jax.lax.scan(composed_step, S, xs))(

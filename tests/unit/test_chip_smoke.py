@@ -67,12 +67,19 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
     assert not any(line.startswith("{") for line in proc.stdout.splitlines())
 
 
-def test_mixed_block_phase_at_toy_width():
+@pytest.mark.parametrize("tile,fused", [((16, 8), False),
+                                        ((128, 128), True)])
+def test_mixed_block_phase_at_toy_width(tile, fused):
+    """Tiles of ``[128, 128]`` bring the delta-step kernel in (here
+    under the interpreter); narrower ones it cannot take."""
     done = chip_smoke.mixed_block_phase(
-        slots=3, value_heads=2, key_dim=16, value_dim=8, window=37,
-        hidden=32, experts=16, held=8, top_k=4, width=8)
-    assert done == ["gated_delta_step", "gated_delta_chunked",
-                    "routed_experts"]
+        slots=3, value_heads=8 if fused else 2, key_dim=tile[0],
+        value_dim=tile[1],
+        window=37, hidden=32, experts=16, held=8, top_k=4, width=8,
+        linear_layers=3)
+    assert done == ["gated_delta_step"] \
+        + ["gated_delta_step_fused"] * fused \
+        + ["gated_delta_chunked", "routed_experts"]
 
 
 @pytest.mark.slow

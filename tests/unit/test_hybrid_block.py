@@ -187,8 +187,8 @@ def test_padding_that_reaches_the_state_fails(ref, rc, cfg, params,
     real = lm.linear_attention
     monkeypatch.setattr(
         lm, "linear_attention",
-        lambda cfg_, chunk, x, state, *, valid=None, length=None: real(
-            cfg_, chunk, x, state, valid=None, length=length))
+        lambda cfg_, chunk, x, state, *, valid=None, length=None, **kw:
+        real(cfg_, chunk, x, state, valid=None, length=length, **kw))
     served = _serve(cfg, params, _requests())
     assert _gap(ref, rc, params, served) > 100 * LOGIT_TOL
 

@@ -9,6 +9,8 @@ the cache it is given — no copy of it, no temporary — in the layout the
 chip keeps it in.  One file, one fixture: only the worker that is given
 this file loads the TPU's library, inside a test.
 """
+import hashlib
+import importlib
 import re
 
 import jax
@@ -129,21 +131,41 @@ def test_one_row_prefill_compiles_in_place(one_chip, hidden, heads, mlp,
 # one period of the benchmark's mixed stack (three gated-DeltaNet layers
 # and a gated attention layer on 2 of 16 heads of 256, 64 of 512 experts
 # held) at its published widths, 8 slots of 512 positions
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_mixed_stack_compiles_in_place(one_chip, program):
+# The composed decode program of this stack as the TPU's compiler leaves
+# it (``_program_text`` of tests/unit/test_looped_block.py, hashed), read
+# on the parent commit of PR 33 (c77cb69) with this test: the recurrent
+# state's decode step moved behind ``kv_cache.DenseLayout.advance_state``
+# and the program that declines the kernel had to come out as it went in.
+COMPOSED_DECODE_HLO = "ccf433935aa6acb5"
+
+
+@pytest.mark.parametrize("program", ["decode", "decode-kernel", "prefill"])
+def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     """Both programs hold what they are given — the keys and values and
     the recurrent state alias their outputs, no op copies the state or a
     layer's experts (a stack of experts sliced by layer reached the
     grouped matmul as a copy of all of them: 5.4 GB at 16 layers), and
     the experts run in the compiler's grouped-matmul kernel, twice a
-    layer."""
+    layer.  ``decode-kernel``: the decode program a TPU process elects
+    (here forced through the kernel slot, the backend being the CPU's) —
+    Mosaic takes the delta-step kernel's tiles at the cell's 32 slots of
+    32 heads of ``[128, 128]``, and nothing of a layer's state but the
+    kernel's aliased operand is left: no slice in, no
+    ``dynamic_update_slice`` out."""
     from autodist_tpu.models import pipeline_lm as lm
     from autodist_tpu.models.transformer import (BlockSpec, LinearMixerSpec,
                                                  RoutedFFNSpec,
                                                  TransformerConfig)
     from autodist_tpu.serving import ServingEngine
 
-    bf16, slots, bucket = jnp.bfloat16, 8, 256
+    from tests.unit.test_looped_block import _program_text
+
+    fused = program == "decode-kernel"
+    bf16, slots, bucket = jnp.bfloat16, 32 if fused else 8, 256
+    if fused:
+        monkeypatch.setattr(
+            importlib.import_module("autodist_tpu.kernel.pallas.delta_step"),
+            "default_interpret", lambda: False)
     cfg = TransformerConfig(
         vocab_size=18992, hidden_size=2048, num_layers=4, num_heads=16,
         mlp_dim=512, max_len=512, dtype=bf16, dropout_rate=0.0,
@@ -160,7 +182,9 @@ def test_mixed_stack_compiles_in_place(one_chip, program):
                           lm.param_shapes(cfg),
                           is_leaf=lambda x: isinstance(x, tuple))
     engine = ServingEngine(cfg, params, num_slots=slots, max_len=512,
-                           prefill_len=bucket, decode_steps=8)
+                           prefill_len=bucket, decode_steps=8,
+                           kernel={"delta_step": fused})
+    assert engine.kv.state_kernel(engine.cache.state.ssm) == fused
     sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
                                          sharding=one_chip)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
@@ -170,7 +194,7 @@ def test_mixed_stack_compiles_in_place(one_chip, program):
             i32(slots), i32(slots))
     state = tuple(sds(a) for a in engine._state_args())
     with jax.default_matmul_precision("default"):
-        if program == "decode":
+        if program.startswith("decode"):
             compiled = engine._decode_jit.lower(
                 *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
                     (slots,), jnp.bool_, sharding=one_chip),
@@ -187,10 +211,19 @@ def test_mixed_stack_compiles_in_place(one_chip, program):
     text = compiled.as_text()
     assert len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
                           r"[^\n]*ragged-dot-none", text)) >= 8
-    assert not re.findall(r"= f32\[3,8,32,128,128\][^ ]* (?:copy|transpose)\(",
-                          text)
+    assert not re.findall(
+        rf"= f32\[3,{slots},32,128,128\][^ ]* (?:copy|transpose)\(", text)
     assert not re.findall(r"= bf16\[64,2048,1024\][^ ]* (?:copy|fusion)\(",
                           text)
+    layer_state = rf"f32\[(?:1,)?{slots},32,128,128\]"
+    if fused:
+        assert text.count("adtk_delta_step") >= 3
+        assert not re.findall(layer_state, text)
+    elif program == "decode":
+        assert re.findall(layer_state, text)     # the slice, the write
+        assert "adtk_delta_step" not in text
+        assert hashlib.sha256(_program_text(text).encode()) \
+            .hexdigest()[:16] == COMPOSED_DECODE_HLO
 
 
 # one encoder layer's attention at the training cell's widths (BERT-base:

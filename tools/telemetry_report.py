@@ -33,7 +33,14 @@ _PRECISION_BITS = {"fp32": 32, "bf16": 16, "int8": 8}
 # for flash_decode); a manifest run.kernel annotation without its gauge
 # means the election was silently dropped — --check fails it.
 _KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
-                   "collective_matmul", "a2a_ring", "flash_attention")
+                   "collective_matmul", "a2a_ring", "flash_attention",
+                   "delta_step")
+# The recurrent state's decode step (serving/kv_cache.py DenseLayout
+# .advance_state): an engine whose stack has linear layers says which
+# way its decode advances their state — kernel/delta_step_elected = 1,
+# the fused kernel, or 0, the composed step — so the gauge comes with
+# the engine/state_bytes_per_slot gauge of such a stack, and only there.
+_STATE_KERNEL_GAUGE = "kernel/delta_step_elected"
 # Training attention's election (autodist_tpu/models/transformer.py
 # attend): every traced call advances one of the two counters, and a
 # call that takes the fused kernels sets kernel/flash_attention_elected.
@@ -445,6 +452,17 @@ def check_schema(run_dir: str) -> list[str]:
                 problems.append(
                     f"metrics.jsonl: {name} names an unregistered "
                     f"kernel (have {sorted(_KERNEL_CHOICES)})")
+            elif name == _STATE_KERNEL_GAUGE:
+                if rec.get("value") not in (0, 1):
+                    problems.append(
+                        f"metrics.jsonl: {name} = {rec.get('value')!r} — "
+                        "1 (the fused kernel) or 0 (the composed step)")
+                if "engine/state_bytes_per_slot" not in gauges:
+                    problems.append(
+                        f"metrics.jsonl: {name} without the "
+                        "engine/state_bytes_per_slot gauge — only an "
+                        "engine that holds a recurrent state elects how "
+                        "to advance it")
             elif rec.get("value") != 1:
                 problems.append(
                     f"metrics.jsonl: {name} = {rec.get('value')!r} — an "
