@@ -72,23 +72,33 @@ def final_norm(cfg: TransformerConfig, shared, h):
     return _layer_norm(h, shared["ln_final_scale"], shared["ln_final_bias"])
 
 
-def rope(x, positions, theta: float, fraction: float = 1.0):
+def rope(x, positions, theta: float, fraction: float = 1.0, scaling=None):
     """Rotate-half rotary embedding of ``x`` ``[B, S, heads, d]`` at
     absolute ``positions`` (``[S]`` or ``[B, S]``), angles in fp32; on
     the first ``fraction`` of the ``d`` dimensions, the rest passing
-    through."""
+    through.  ``scaling``
+    (:class:`~autodist_tpu.models.transformer.RopeScaling`): its
+    frequencies in place of ``theta ** (-2i / d)``, cos and sin times
+    its factor."""
     if fraction != 1.0:
         r = int(x.shape[-1] * fraction)
         return jnp.concatenate(
-            [rope(x[..., :r], positions, theta), x[..., r:]], -1)
+            [rope(x[..., :r], positions, theta, scaling=scaling),
+             x[..., r:]], -1)
     with scope("rope"):
         d = x.shape[-1]
-        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        if scaling is None:
+            inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        else:
+            inv = jnp.asarray(scaling.inv_freq(d, theta))
         ang = positions.astype(jnp.float32)[..., None] * inv
         ang = jnp.concatenate([ang, ang], -1)[..., None, :]  # [.., S, 1, d]
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        if scaling is not None and scaling.cos_sin_scale != 1.0:
+            cos, sin = (t * scaling.cos_sin_scale for t in (cos, sin))
         xf = x.astype(jnp.float32)
         rot = jnp.concatenate([-xf[..., d // 2:], xf[..., :d // 2]], -1)
-        return (xf * jnp.cos(ang) + rot * jnp.sin(ang)).astype(x.dtype)
+        return (xf * cos + rot * sin).astype(x.dtype)
 
 
 def _bias(p, dtype):
@@ -137,8 +147,10 @@ def attention_inputs(cfg: TransformerConfig, chunk, x, positions,
         q = block_norm(cfg, q, att["q_norm"])
         k = block_norm(cfg, k, att["k_norm"])
     if spec.positions == "rope":
-        q = rope(q, positions, spec.rope_theta, spec.rope_fraction)
-        k = rope(k, positions, spec.rope_theta, spec.rope_fraction)
+        q = rope(q, positions, spec.rope_theta, spec.rope_fraction,
+                 spec.rope_scaling)
+        k = rope(k, positions, spec.rope_theta, spec.rope_fraction,
+                 spec.rope_scaling)
     return x, q, k, v, gate
 
 
@@ -176,6 +188,101 @@ def expand_kv_heads(cfg: TransformerConfig, t):
     the query heads that read it (head ``i`` reads ``i // group``)."""
     group = cfg.num_heads // cfg.kv_heads
     return t if group == 1 else jnp.repeat(t, group, axis=2)
+
+
+# --------------------------------------------------------------------- #
+# latent attention
+# --------------------------------------------------------------------- #
+# A position is projected down to one row [c | k_pe]: a latent c of
+# kv_rank, normed, and a rotated positional key that every head shares.
+# Head h's key is [W_UK,h c | k_pe] and its value W_UV,h c, both halves
+# of the one up-projection kv_b.  One set of weights, two entry points:
+# a window of positions projects the rows up and attends at the heads'
+# own sizes (:func:`latent_expanded`); one position against cached rows
+# folds W_UK into the query and W_UV into the output, so that the rows
+# are attended as they are cached (:func:`latent_absorbed`):
+#     q_h . k_h(t) = (W_UK,h^T q_nope,h) . c_t + q_pe,h . k_pe,t
+#     sum_t a_h(t) v_h(t) = W_UV,h (sum_t a_h(t) c_t)
+def _latent_inputs(cfg: TransformerConfig, chunk, x, positions):
+    """``(x, q_nope [B, S, heads, nope], q_pe [B, S, heads, rope], row
+    [B, S, kv_rank + rope])``: the residual stream, the heads' queries
+    (``q_pe`` rotated) and the row a position caches."""
+    spec, dtype, lat = cfg.block, cfg.dtype, cfg.block.latent
+    la = chunk["latent_attention"]
+    x = x.astype(dtype)
+    h = block_norm(cfg, x, chunk["ln_attention_in"])
+    with scope("latent_attention"):
+        q = (h @ la["q"]["kernel"].astype(dtype)).reshape(
+            *h.shape[:2], cfg.num_heads, lat.nope_dim + lat.rope_dim)
+        q_nope, q_pe = q[..., :lat.nope_dim], q[..., lat.nope_dim:]
+        down = h @ la["kv_a"]["kernel"].astype(dtype)
+        c = _rms_norm(down[..., :lat.kv_rank], la["kv_norm"]["scale"],
+                      dtype, spec.norm_eps)
+        turn = lambda t: rope(t, positions, spec.rope_theta,
+                              scaling=spec.rope_scaling)
+        k_pe = turn(down[..., None, lat.kv_rank:])[:, :, 0]
+        return x, q_nope, turn(q_pe), jnp.concatenate([c, k_pe], -1)
+
+
+def _latent_output(cfg, chunk, x, out):
+    """The heads' outputs ``[B, S, heads, value_dim]`` through the output
+    projection, and the residual."""
+    w = chunk["latent_attention"]["out"]["kernel"].astype(cfg.dtype)
+    y = out.reshape(*out.shape[:2], -1) @ w
+    return _residual(cfg, x, y, chunk, "ln_attention")
+
+
+def _up_projection(cfg, chunk):
+    """``kv_b`` as ``[kv_rank, heads, nope_dim + value_dim]``."""
+    lat = cfg.block.latent
+    return chunk["latent_attention"]["kv_b"]["kernel"].astype(
+        cfg.dtype).reshape(lat.kv_rank, cfg.num_heads, -1)
+
+
+def latent_expanded(cfg: TransformerConfig, chunk, x, positions, mask):
+    """Latent attention over a window, with its residual: ``(x +
+    attn(N(x)), row)``.  Every position's row is projected up to the
+    heads' keys and values and attended at their own sizes (``nope_dim +
+    rope_dim`` against ``value_dim``) under ``mask``; ``row`` ``[B, S,
+    kv_rank + rope_dim]`` is what each position would cache."""
+    lat, dtype = cfg.block.latent, cfg.dtype
+    x, q_nope, q_pe, row = _latent_inputs(cfg, chunk, x, positions)
+    with scope("latent_attention"):
+        kv = jnp.einsum("bsr,rhd->bshd", row[..., :lat.kv_rank],
+                        _up_projection(cfg, chunk))
+        k_pe = jnp.broadcast_to(row[..., None, lat.kv_rank:], q_pe.shape)
+        q = jnp.concatenate([q_nope, q_pe], -1)
+        k = jnp.concatenate([kv[..., :lat.nope_dim], k_pe], -1)
+        with scope("latent_attend"):
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k) \
+                .astype(jnp.float32) * cfg.block.latent_softmax_scale
+            if mask is not None:
+                scores = jnp.where(mask, scores,
+                                   jnp.finfo(jnp.float32).min)
+            probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
+            out = jnp.einsum("bhqk,bkhd->bqhd", probs,
+                             kv[..., lat.nope_dim:])
+        return _latent_output(cfg, chunk, x, out), row
+
+
+def latent_absorbed(cfg: TransformerConfig, chunk, x, positions, attend):
+    """Latent attention of positions against cached rows, with its
+    residual.  ``attend(q [B, S, heads, kv_rank + rope_dim], row [B, S,
+    1, kv_rank + rope_dim]) -> (o_lat [B, S, heads, kv_rank], carry)``
+    is the cache's: it writes the rows and attends ``q`` over them as ONE
+    key head whose values are its first ``kv_rank`` columns, at
+    ``cfg.block.latent_softmax_scale``.  Returns ``(x + attn(N(x)),
+    carry)``."""
+    lat = cfg.block.latent
+    x, q_nope, q_pe, row = _latent_inputs(cfg, chunk, x, positions)
+    w = _up_projection(cfg, chunk)
+    with scope("latent_attention"):
+        q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :lat.nope_dim])
+        q = jnp.concatenate([q_lat, q_pe], -1)
+    o_lat, carry = attend(q, row[:, :, None, :])
+    with scope("latent_attention"):
+        out = jnp.einsum("bshr,rhd->bshd", o_lat, w[..., lat.nope_dim:])
+        return _latent_output(cfg, chunk, x, out), carry
 
 
 # --------------------------------------------------------------------- #
@@ -392,7 +499,8 @@ def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None):
     """The routed block on normed rows ``h`` ``[B, S, H]``: ``(y, stats)``
     — the held experts' part of the routed sum
     (:func:`autodist_tpu.parallel.moe.routed_experts`) plus the shared
-    expert behind its sigmoid gate, which every device computes."""
+    expert (behind its sigmoid gate, where the block has one), which
+    every device computes."""
     from autodist_tpu.parallel.moe import routed_experts
 
     spec, dtype = cfg.block.moe, cfg.dtype
@@ -403,23 +511,28 @@ def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None):
                 rows, moe_params["router"]["kernel"],
                 moe_params["experts"]["wi"], moe_params["experts"]["wo"],
                 top_k=spec.top_k, first_expert=spec.first_expert,
-                valid=None if valid is None else valid.reshape(-1))
+                valid=None if valid is None else valid.reshape(-1),
+                renormalise=spec.renormalise)
         if spec.shared_width:
             sh = moe_params["shared"]
-            gate = jax.nn.sigmoid(jnp.matmul(
-                rows.astype(jnp.float32),
-                moe_params["shared_gate"]["kernel"].astype(jnp.float32),
-                precision=_HI))
-            y = y + gate[:, None] * _swiglu(
+            gate = None
+            if spec.shared_gate:
+                gate = jax.nn.sigmoid(jnp.matmul(
+                    rows.astype(jnp.float32),
+                    moe_params["shared_gate"]["kernel"].astype(jnp.float32),
+                    precision=_HI))[:, None]
+            shared = _swiglu(
                 rows, sh["wi"]["kernel"].astype(dtype),
                 sh["wo"]["kernel"].astype(dtype)).astype(jnp.float32)
+            y = y + (shared if gate is None else gate * shared)
     return y.reshape(h.shape).astype(dtype), stats
 
 
 def ffn_residual(cfg: TransformerConfig, chunk, x, model_axis,
                  comm_overlap=None, valid=None, tally=None):
     """The feed-forward sub-block with its residual add and norm(s).  A
-    routed block (``cfg.block.moe``) goes to :func:`routed_ffn`:
+    routed layer (its chunk holds ``moe``: every layer of a routed
+    stack but its leading dense ones) goes to :func:`routed_ffn`:
     ``valid`` marks the rows that are some request's (the others choose
     no expert), and ``tally``, a list, is handed the layer's ``[rows_held,
     experts_hit]``."""
@@ -428,7 +541,7 @@ def ffn_residual(cfg: TransformerConfig, chunk, x, model_axis,
     spec, dtype = cfg.block, cfg.dtype
     h = (block_norm(cfg, x, chunk["ln_mlp_in"])
          if spec.norm_placement in ("sandwich", "pre") else x)
-    if spec.moe is not None:
+    if "moe" in chunk:
         m, stats = routed_ffn(cfg, chunk["moe"], h, valid)
         if tally is not None:
             tally.append(stats)
@@ -483,7 +596,19 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
     A ``"linear"`` layer of a mixed stack (its chunk holds
     ``linear_attention``) runs the mixer from a blank state: the whole
     sequence is the window.  ``valid`` is :func:`ffn_residual`'s.
+
+    A latent-attention layer (its chunk holds ``latent_attention``)
+    attends in the expanded form; ``return_kv`` hands back the rows it
+    would cache as one key head, ``[B, S, 1, kv_rank + rope_dim]``, and
+    no values.
     """
+    if "latent_attention" in chunk:
+        if positions is None:
+            positions = jnp.arange(x.shape[1])
+        x, row = latent_expanded(cfg, chunk, x, positions, mask)
+        y = ffn_residual(cfg, chunk, x, model_axis, comm_overlap,
+                         valid=valid)
+        return (y, row[:, :, None, :], None) if return_kv else y
     if "linear_attention" in chunk:
         x, _ = linear_attention(cfg, chunk, x,
                                 blank_linear_state(cfg, x.shape[0]))
@@ -502,6 +627,7 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
 
 
 MIXERS = {"full": "attention", "linear": "linear_attention"}
+_FFNS = ("mlp", "moe")
 
 
 def layer_key(l: int) -> str:
@@ -510,28 +636,44 @@ def layer_key(l: int) -> str:
     return f"layer_{l:02d}"
 
 
-def _layer_of(tree, l: int):
+def _layer_of(tree, l: int, nth: int):
+    """Layer ``l``'s part of ``tree``, the ``nth`` of its stacked
+    leaves."""
     if not isinstance(tree, dict):
-        return tree[l]
+        return tree[nth]
     if layer_key(l) in tree:
         return tree[layer_key(l)]
-    return {name: _layer_of(sub, l) for name, sub in tree.items()}
+    return {name: _layer_of(sub, l, nth) for name, sub in tree.items()}
 
 
 def layer_chunk(cfg: TransformerConfig, stages, l: int):
     """Layer ``l``'s parameters out of ``stages``.  A leaf is stacked
     over the layers that have it: all of them, but for a mixed stack's
     mixers (``attention`` over the full layers, ``linear_attention``
-    over the linear ones, each in stack order).  A routed FFN's experts
-    are arrays of their own a layer (:func:`layer_key`): the grouped
-    matmul takes the array whole, and a layer's slice of a stack would
-    reach it as a copy of all its experts, every step."""
-    if not (cfg.block.layer_period or cfg.block.moe):
+    over the linear ones, each in stack order) and a routed stack's
+    feed-forward kinds (``mlp`` over its leading dense layers, ``moe``
+    over the routed ones: the chunk holds the one its layer runs).  A
+    routed FFN's experts are arrays of their own a layer
+    (:func:`layer_key`): the grouped matmul takes the array whole, and a
+    layer's slice of a stack would reach it as a copy of all its
+    experts, every step."""
+    spec = cfg.block
+    if not (spec.layer_period or spec.moe):
         return jax.tree.map(lambda p: p[l], stages)
-    kinds = cfg.block.layer_kinds(cfg.num_layers)
-    chunk = {name: _layer_of(tree, l) for name, tree in stages.items()
-             if name not in MIXERS.values()}
-    mixer, nth = MIXERS[kinds[l]], kinds[:l].count(kinds[l])
+    kinds = spec.layer_kinds(cfg.num_layers)
+    mixers = (*MIXERS.values(), "latent_attention")
+    routed = spec.moe is not None and l >= spec.dense_layers
+    # the layer's place among the leaves of a sub-tree: the FFN it runs
+    # stacks over the layers of its kind, the FFN it does not run is left
+    # out, everything else stacks over all layers
+    place = {_FFNS[routed]: l - spec.dense_layers if routed else l,
+             _FFNS[not routed]: None}
+    chunk = {name: _layer_of(tree, l, place.get(name, l))
+             for name, tree in stages.items()
+             if name not in mixers and place.get(name, l) is not None}
+    mixer = "latent_attention" if spec.latent is not None \
+        else MIXERS[kinds[l]]
+    nth = kinds[:l].count(kinds[l])
     chunk[mixer] = jax.tree.map(lambda p: p[nth], stages[mixer])
     return chunk
 
@@ -617,7 +759,13 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     routed FFN holds the router over ALL experts, the held experts' ``wi``
     ``[held, H, 2 * M]`` (gate, up) and ``wo`` as arrays of their own a
     layer (:func:`layer_chunk` says why), and the shared expert with its
-    gate.  A zero-centred norm's leaf is ``weight``."""
+    gate (where it has one); its ``dense_layers`` leading layers hold
+    ``mlp`` instead, and the routed leaves stack over the rest.  Latent
+    attention holds ``q`` ``[H, heads * (nope + rope)]``, the
+    down-projection ``kv_a`` ``[H, kv_rank + rope]``, the latent's norm,
+    the up-projection ``kv_b`` ``[kv_rank, heads * (nope + value)]``
+    (each head's key part, then its value part) and ``out``.  A
+    zero-centred norm's leaf is ``weight``."""
     spec = cfg.block
     L, H, M, V = cfg.num_layers, cfg.hidden_size, cfg.mlp_dim, \
         cfg.vocab_size
@@ -643,10 +791,22 @@ def param_shapes(cfg: TransformerConfig) -> dict:
                     (), Lf)
     else:
         qkv = dense((H, 3 * n * d), (3 * n * d,))
+    # a routed stack's leading layers alone carry the dense FFN
+    Ld = spec.dense_layers if spec.moe is not None else L
     stages = {
         "attention": {"qkv": qkv, "out": dense((n, d, H), (H,), Lf)},
-        "mlp": {"wi": dense((H, wi), (wi,)),
-                "wo": dense((M, H), (H,))}}
+        "mlp": {"wi": dense((H, wi), (wi,), Ld),
+                "wo": dense((M, H), (H,), Ld)}}
+    if spec.latent is not None:
+        lat = spec.latent
+        del stages["attention"]
+        stages["latent_attention"] = {
+            "q": dense((H, n * (lat.nope_dim + lat.rope_dim)), ()),
+            "kv_a": dense((H, lat.row), ()),
+            "kv_norm": {"scale": (L, lat.kv_rank)},
+            "kv_b": dense((lat.kv_rank,
+                           n * (lat.nope_dim + lat.value_dim)), ()),
+            "out": dense((n * lat.value_dim, H), ())}
     if spec.qk_norm:
         stages["attention"].update(q_norm=norm((Lf,), d),
                                    k_norm=norm((Lf,), d))
@@ -662,18 +822,20 @@ def param_shapes(cfg: TransformerConfig) -> dict:
             "norm": {"scale": (Ll, lin.value_dim)},
             "out": dense((inner, H), (), Ll)}
     if spec.moe is not None:
-        moe, Ms = spec.moe, spec.moe.shared_width
-        del stages["mlp"]
+        moe, Ms, Lr = spec.moe, spec.moe.shared_width, L - Ld
+        if not Ld:
+            del stages["mlp"]
         stages["moe"] = {
-            "router": {"kernel": (L, H, moe.num_experts)},
+            "router": {"kernel": (Lr, H, moe.num_experts)},
             "experts": {layer_key(l): {
                 "wi": (moe.experts_held, H, 2 * moe.expert_width),
-                "wo": (moe.experts_held, moe.expert_width, H)} for l in range(L)}}
+                "wo": (moe.experts_held, moe.expert_width, H)}
+                for l in range(Ld, L)}}
         if Ms:
-            stages["moe"].update(
-                shared={"wi": {"kernel": (L, H, 2 * Ms)},
-                        "wo": {"kernel": (L, Ms, H)}},
-                shared_gate={"kernel": (L, H)})
+            stages["moe"]["shared"] = {"wi": {"kernel": (Lr, H, 2 * Ms)},
+                                       "wo": {"kernel": (Lr, Ms, H)}}
+            if moe.shared_gate:
+                stages["moe"]["shared_gate"] = {"kernel": (Lr, H)}
     if spec.norm_placement != "pre":
         stages.update(ln_attention=norm(), ln_mlp=norm())
     if spec.norm_placement in ("sandwich", "pre"):

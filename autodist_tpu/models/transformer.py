@@ -50,14 +50,89 @@ class LinearMixerSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class RopeScaling:
+    """YaRN scaling of the rotary frequencies: a frequency is divided by
+    ``factor`` where it turns fewer than ``beta_slow`` times over
+    ``original_max_len`` positions, kept where it turns more than
+    ``beta_fast`` times, and blended linearly between.  ``mscale`` and
+    ``mscale_all_dim`` give the factors that cos and sin (their ratio)
+    and the softmax scale (:attr:`attention_mscale`, squared) are
+    multiplied by."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
+
+    def correction_range(self, dim: int, theta: float) -> tuple:
+        """``(low, high)``: the frequencies below ``low`` are kept, those
+        above ``high`` divided by ``factor``."""
+        def turns_at(rotations):
+            return dim * np.log(self.original_max_len
+                                / (rotations * 2 * np.pi)) \
+                / (2 * np.log(theta))
+        return (max(int(np.floor(turns_at(self.beta_fast))), 0),
+                min(int(np.ceil(turns_at(self.beta_slow))), dim - 1))
+
+    def inv_freq(self, dim: int, theta: float) -> np.ndarray:
+        """The ``dim // 2`` scaled inverse frequencies, float32."""
+        f = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        low, high = self.correction_range(dim, theta)
+        ramp = np.clip((np.arange(dim // 2) - low)
+                       / ((high - low) or 0.001), 0.0, 1.0)
+        return (f / self.factor * ramp + f * (1.0 - ramp)) \
+            .astype(np.float32)
+
+    def _mscale(self, mscale: float) -> float:
+        if self.factor <= 1.0:
+            return 1.0
+        return 0.1 * mscale * float(np.log(self.factor)) + 1.0
+
+    @property
+    def cos_sin_scale(self) -> float:
+        return self._mscale(self.mscale) / self._mscale(self.mscale_all_dim)
+
+    @property
+    def attention_mscale(self) -> float:
+        """``m``: the softmax scale is multiplied by its square."""
+        return self._mscale(self.mscale_all_dim)
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentAttentionSpec:
+    """Latent (compressed) attention's sizes: the input is projected
+    down to ONE row a position, ``[c | k_pe]`` — a latent of ``kv_rank``,
+    normed, and a rotated positional key of ``rope_dim`` that every
+    query head shares — and that row is what the cache holds.  Each head
+    projects ``c`` up to a key of ``nope_dim`` and a value of
+    ``value_dim``; its query is ``[q_nope (nope_dim) | q_pe (rope_dim)]``
+    with rotary on ``q_pe`` alone
+    (:attr:`BlockSpec.latent_softmax_scale` is the scores' scale)."""
+
+    kv_rank: int
+    nope_dim: int
+    rope_dim: int
+    value_dim: int
+
+    @property
+    def row(self) -> int:
+        """Values a cached position holds."""
+        return self.kv_rank + self.rope_dim
+
+
+@dataclasses.dataclass(frozen=True)
 class RoutedFFNSpec:
     """A routed feed-forward block's sizes.  The router scores all
-    ``num_experts`` and keeps ``top_k`` a token, renormalised to sum 1;
+    ``num_experts`` and keeps ``top_k`` a token, renormalised to sum 1
+    (``renormalise``; as the softmax left them where false);
     this device holds experts ``first_expert .. first_expert +
     experts_held`` and adds up their terms alone — what the absent ones
     would have given is some other device's.  Experts are SiLU-gated at
-    ``expert_width``; a shared expert of ``shared_width`` behind a
-    sigmoid gate is computed everywhere (0: none)."""
+    ``expert_width``; a shared expert of ``shared_width``, behind a
+    sigmoid gate where ``shared_gate``, is computed everywhere (0:
+    none)."""
 
     num_experts: int
     top_k: int
@@ -65,6 +140,8 @@ class RoutedFFNSpec:
     experts_held: int
     shared_width: int = 0
     first_expert: int = 0
+    renormalise: bool = True
+    shared_gate: bool = True
 
     def __post_init__(self):
         if not 0 < self.top_k <= self.num_experts:
@@ -101,6 +178,13 @@ class BlockSpec:
     * ``bias`` — whether the projections carry biases.
     * ``tied_head`` — logits from the embedding table, or from
       ``shared["lm_head"]``.
+    * ``latent`` — every layer's attention is latent attention
+      (:class:`LatentAttentionSpec`): the cache holds one row a position
+      and not keys and values a head; ``rope_scaling`` scales the rotary
+      frequencies (:class:`RopeScaling`).
+    * ``dense_layers`` — of a routed stack (``moe``), how many leading
+      layers carry the dense ``ffn`` at ``TransformerConfig.mlp_dim``
+      and no router.
     * ``loop_steps`` ``T`` — the layers run ``T`` times with the same
       weights; the final norm closes every pass (it is pass ``u``'s
       output and pass ``u + 1``'s input), the head reads the last, and
@@ -129,6 +213,9 @@ class BlockSpec:
     layer_period: tuple = ()
     linear: Optional[LinearMixerSpec] = None
     moe: Optional[RoutedFFNSpec] = None
+    latent: Optional[LatentAttentionSpec] = None
+    rope_scaling: Optional[RopeScaling] = None
+    dense_layers: int = 0
 
     def __post_init__(self):
         for name, allowed in (("norm", ("layernorm", "rmsnorm")),
@@ -158,10 +245,35 @@ class BlockSpec:
                                           or self.bias):
             raise ValueError("a linear mixer or a routed FFN runs in one "
                              "pass and without biases")
+        if self.dense_layers and self.moe is None:
+            raise ValueError("BlockSpec.dense_layers counts the leading "
+                             "layers of a routed stack (moe) that are not "
+                             "routed")
+        if self.latent is not None and (
+                self.positions != "rope" or self.layer_period or self.bias
+                or self.loop_steps != 1 or self.qk_norm or self.attn_gate
+                or self.kv_heads or self.head_dim
+                or self.rope_fraction != 1.0):
+            raise ValueError(
+                "latent attention has its own head sizes and one shared "
+                "rotary key: BlockSpec.latent goes with positions='rope' "
+                "and none of layer_period, bias, loop_steps, qk_norm, "
+                "attn_gate, kv_heads, head_dim, rope_fraction")
+        if self.rope_scaling is not None and self.positions != "rope":
+            raise ValueError("BlockSpec.rope_scaling scales rotary "
+                             "positions")
 
     @property
     def is_default(self) -> bool:
         return self == BlockSpec()
+
+    @property
+    def latent_softmax_scale(self) -> float:
+        """What latent attention multiplies its scores by: ``(nope_dim +
+        rope_dim) ** -0.5``, times YaRN's ``m ** 2`` where the rotary
+        frequencies are scaled (:attr:`RopeScaling.attention_mscale`)."""
+        m = self.rope_scaling.attention_mscale if self.rope_scaling else 1.0
+        return (self.latent.nope_dim + self.latent.rope_dim) ** -0.5 * m * m
 
     def layer_kinds(self, num_layers: int) -> tuple:
         """The kind of each of ``num_layers`` layers."""

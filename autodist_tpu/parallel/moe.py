@@ -69,15 +69,17 @@ def top2_gating(gate_logits, capacity: int):
     return dispatch, combine, aux_loss
 
 
-def route_top_k(x, router_w, top_k: int):
+def route_top_k(x, router_w, top_k: int, renormalise: bool = True):
     """``(experts [R, top_k] int32, weights [R, top_k] float32)`` of
     rows ``x`` ``[R, H]``: softmax over ALL the router's outputs in
-    float32, the ``top_k`` largest, renormalised to sum 1 over the
-    chosen experts wherever they live."""
+    float32, the ``top_k`` largest — renormalised to sum 1 over the
+    chosen experts wherever they live, or (``renormalise`` false) as the
+    softmax left them, summing to less than 1."""
     logits = jnp.matmul(x.astype(jnp.float32), router_w.astype(jnp.float32),
                         precision=lax.Precision.HIGHEST)
     weights, experts = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
-    weights = weights / weights.sum(-1, keepdims=True)
+    if renormalise:
+        weights = weights / weights.sum(-1, keepdims=True)
     return experts.astype(jnp.int32), weights
 
 
@@ -96,7 +98,8 @@ def _pairs_bound(pairs: int, held: int, experts: int) -> int:
 
 
 def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
-                   first_expert: int = 0, valid=None):
+                   first_expert: int = 0, valid=None,
+                   renormalise: bool = True):
     """The held experts' part of a routed FFN, without capacity.
 
     ``x``: ``[R, H]`` rows; ``router_w``: ``[H, E]`` over all ``E``
@@ -119,10 +122,11 @@ def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
     :func:`_pairs_bound` only that many sorted pairs are worked through,
     and all of them where they do not (``lax.cond``: skewed routing
     costs time, never a row).  ``valid`` (``[R]`` bool): rows that are
-    padding or belong to no request choose nothing."""
+    padding or belong to no request choose nothing.  ``renormalise`` is
+    :func:`route_top_k`'s."""
     R, H = x.shape
     E_held = expert_wi.shape[0]
-    experts, weights = route_top_k(x, router_w, top_k)
+    experts, weights = route_top_k(x, router_w, top_k, renormalise)
     local = experts - first_expert                       # [R, k]
     held = (local >= 0) & (local < E_held)
     if valid is not None:

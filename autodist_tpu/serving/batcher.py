@@ -458,6 +458,21 @@ class ContinuousBatcher:
         telemetry.counter("serve/kv_blocks_resident").inc(
             steps * len(active) * lane)
 
+    def _count_latent_positions(self, active, steps: int):
+        """``serve/latent_positions_read``: the cached latent rows a
+        window's decode attention must read — a decoding slot's live
+        positions at each step, the one its new token lands in among
+        them, times the layers that cache a latent row.  From the lengths
+        this side holds; nothing is fetched."""
+        layers = getattr(self.engine, "latent_layers", 0)
+        if not layers:
+            return
+        first = np.array([len(s.req.prompt) + len(s.tokens) if a else 0
+                          for s, a in zip(self._slots, active)])
+        at = (first + np.arange(steps)[:, None]) * active  # [steps, B]
+        telemetry.counter("serve/latent_positions_read").inc(
+            int(np.minimum(at, self.engine.max_len).sum()) * layers)
+
     def _decode_window(self):
         """One fused decode dispatch; distribute tokens, evict terminal
         slots."""
@@ -467,6 +482,7 @@ class ContinuousBatcher:
             return
         K = self.engine.decode_steps
         self._count_kv_blocks(active, K)
+        self._count_latent_positions(active, K)
         t0 = time.perf_counter()
         tids = [s.req.trace_id for s, a in zip(self._slots, active)
                 if a and s is not None and s.req.trace_id]

@@ -128,16 +128,21 @@ class HandoffPlan:
 
 
 def check_handoff_block(engine, name: str = "engine") -> None:
-    """Refuse, by name, an engine whose slots own more than pool blocks:
-    the handoff copies a request's blocks of keys and values, and a
-    stack with linear layers also keeps a recurrent state a slot that no
-    block holds."""
+    """Refuse, by name, an engine whose slots own other than pool
+    blocks: the handoff copies a request's blocks of keys and values, and
+    a stack with linear layers also keeps a recurrent state a slot that
+    no block holds; a latent-attention stack caches rows, in lanes."""
     if getattr(engine, "linear_layers", 0):
         raise ValueError(
             f"{name}: the disaggregated handoff copies blocks of keys "
             f"and values; the block's {engine.linear_layers} linear "
             "(gated-DeltaNet) layers keep a recurrent state a slot, "
             "which it would leave behind")
+    if getattr(engine, "latent_layers", 0):
+        raise ValueError(
+            f"{name}: the disaggregated handoff copies blocks of keys "
+            "and values a head; this block caches a latent KV row a "
+            "position (latent attention), which no block holds")
 
 
 class HandoffError(RuntimeError):

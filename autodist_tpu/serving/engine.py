@@ -246,6 +246,13 @@ class ServingEngine:
                 f"all {spec.loop_steps} passes for every token, which is "
                 "what the threshold 1.0 selects; adaptive exit depth "
                 "(the cache rows of skipped passes) is not served")
+        if spec.latent is not None and int(tensor_parallel) > 1:
+            raise ValueError(
+                f"tensor_parallel={tensor_parallel} with a latent KV row: "
+                "the cache holds one row a position that every query head "
+                "reads, and a row has no head axis for the model axis to "
+                "split (heads split with the row replicated is not "
+                "served)")
         if not spec.is_default and int(tensor_parallel) > 1:
             raise ValueError(
                 f"tensor_parallel={tensor_parallel} with a non-default "
@@ -278,6 +285,13 @@ class ServingEngine:
                     "(gated-DeltaNet) layers keep a state a slot that "
                     "cannot be rolled back, shared by blocks or cut at a "
                     "chunk's edge")
+            if asked and spec.latent is not None:
+                raise ValueError(
+                    f"{knob}: {what} with a latent KV row is not served "
+                    "— a cached position is one row of "
+                    f"{spec.latent.row} values for all {cfg.num_heads} "
+                    "query heads, and the block table's readers and the "
+                    "window attention take keys and values a head")
             if asked and grouped:
                 raise ValueError(
                     f"{knob}: {what} with grouped-query attention "
@@ -287,6 +301,8 @@ class ServingEngine:
         # the cache holds every pass's keys and values: a layer's input
         # differs from pass to pass, so its projections do too
         self.cache_layers = self._kinds.count("full") * spec.loop_steps
+        # of them, the layers whose cached position is a latent row
+        self.latent_layers = self.cache_layers if spec.latent else 0
         tp = int(tensor_parallel)
         if tp < 1:
             raise ValueError("tensor_parallel must be >= 1")
@@ -418,7 +434,21 @@ class ServingEngine:
                 cfg.head_dim, self.max_len)
         recurrent = ((self.linear_layers, spec.linear)
                      if self.linear_layers else None)
-        if self.kv_layout == "paged":
+        if spec.latent is not None:
+            if self.kernel.get("flash_decode"):
+                raise ValueError(
+                    "kernel flash_decode with a latent KV row: the fused "
+                    "decode kernels read keys and values of one head "
+                    "size; the absorbed step decodes through "
+                    "cached_attention, every query head on the one row")
+            # one row a position, one key head: the values are its
+            # first kv_rank columns
+            dims = (self.cache_layers, self.num_slots, 1, spec.latent.row,
+                    self.max_len)
+            self.kv = kv_cache.LatentLayout(
+                dims, self.kernel, kv_rank=spec.latent.kv_rank,
+                scale=spec.latent_softmax_scale)
+        elif self.kv_layout == "paged":
             self.kv = kv_cache.PagedLayout(
                 dims, self.kernel, block_len=self.kv_block_len,
                 num_blocks=self.kv_num_blocks,
@@ -463,7 +493,8 @@ class ServingEngine:
             cache = jax.device_put(cache, self._device)
         self.cache = cache
         telemetry.gauge("engine/cache_layers").set(self.cache_layers)
-        held = kv_cache.bytes_held(dims, cfg.dtype, recurrent)
+        held = kv_cache.bytes_held(dims, cfg.dtype, recurrent,
+                                   arrays=1 if spec.latent else 2)
         telemetry.gauge("engine/kv_bytes_per_token").set(
             held["kv_bytes_per_token"])
         if recurrent:
@@ -471,6 +502,8 @@ class ServingEngine:
                 held["state_bytes_per_slot"])
         if spec.moe is not None:
             telemetry.gauge("engine/experts_held").set(spec.moe.experts_held)
+        if spec.latent is not None:
+            telemetry.gauge("engine/latent_lane_rows").set(self.max_len)
 
         self._prefill_jit = (self._build_chunk_prefill()
                              if self.prefill_chunk is not None
@@ -576,8 +609,9 @@ class ServingEngine:
         """One encoder layer over the whole prompt — the training
         :func:`~autodist_tpu.models.pipeline_lm._tp_encoder_layer`
         itself (``return_kv=True`` hands back the layer's k/v
-        projections for the cache fill), so the serving forward cannot
-        drift from the trained math."""
+        projections for the cache fill — of a latent-attention layer,
+        which attends the prompt in the expanded form, the rows), so the
+        serving forward cannot drift from the trained math."""
         from autodist_tpu.models.pipeline_lm import _tp_encoder_layer
 
         return _tp_encoder_layer(self.cfg, chunk, x, mask, self._axis,
@@ -596,10 +630,23 @@ class ServingEngine:
         (write-then-attend) — how, and whether as one kernel, is the
         cache layout's (``self.kv``).  The sub-blocks around the
         attention are the pipelined LM's own (``attention_inputs`` ..
-        ``ffn_residual``; ``valid`` and ``tally`` are the latter's)."""
+        ``ffn_residual``; ``valid`` and ``tally`` are the latter's).  A
+        latent-attention layer hands ``attend`` its absorbed queries and
+        the position's row (``latent_absorbed``) where the others hand
+        q, k and v."""
         from autodist_tpu.models import pipeline_lm as lm
 
         cfg, axis, overlap = self.cfg, self._axis, self.comm_overlap
+        if "latent_attention" in chunk:
+            # the absorbed form: the row is written, then every query
+            # head attends over the rows as they are cached
+            def over_rows(q, row):
+                out, *caches = attend(q, row, None, kc, vc)
+                return out, caches
+
+            x, (kc, vc) = lm.latent_absorbed(cfg, chunk, x, positions,
+                                             over_rows)
+            return self._ffn(chunk, x, valid, tally), kc, vc
         x, q, k, v, gate = lm.attention_inputs(
             cfg, chunk, x, positions, axis, overlap)     # [B, C, heads, dh]
         out, kc, vc = attend(q, k, v, kc, vc)
@@ -1231,17 +1278,17 @@ class ServingEngine:
         recurrent-state rows its linear layers read and wrote, and what
         its routed layers chose — every (row, expert) pair, those that
         landed on held experts, and the held experts some row hit,
-        summed over steps and layers (``moe/layer_steps`` counts those:
-        ``experts_hit`` can reach ``layer_steps x experts_held``)."""
+        summed over steps and routed layers (``moe/layer_steps`` counts
+        those: ``experts_hit`` can reach ``layer_steps x
+        experts_held``)."""
         if self.linear_layers:
             telemetry.counter("engine/state_rows").inc(
                 rows * steps * self.linear_layers)
         if routing is not None:
-            telemetry.counter("moe/layer_steps").inc(
-                steps * self.cfg.num_layers)
+            routed = self.cfg.num_layers - self.cfg.block.dense_layers
+            telemetry.counter("moe/layer_steps").inc(steps * routed)
             telemetry.counter("moe/rows_routed").inc(
-                rows * steps * self.cfg.num_layers
-                * self.cfg.block.moe.top_k)
+                rows * steps * routed * self.cfg.block.moe.top_k)
             telemetry.counter("moe/rows_held").inc(int(routing[0]))
             telemetry.counter("moe/experts_hit").inc(int(routing[1]))
 

@@ -127,10 +127,12 @@ def init_state(linear_layers: int, num_slots: int, mixer,
                        mixer.key_dim, mixer.value_dim), jnp.float32))
 
 
-def bytes_held(dims, dtype, recurrent=None) -> dict:
+def bytes_held(dims, dtype, recurrent=None, arrays: int = 2) -> dict:
     """What a request costs the manager: ``kv_bytes_per_token`` over the
     caching layers of ``dims`` and ``state_bytes_per_slot`` over the
-    linear ones (``recurrent``: ``(linear layers, LinearMixerSpec)``)."""
+    linear ones (``recurrent``: ``(linear layers, LinearMixerSpec)``).
+    ``arrays``: what a position holds a head — keys and values, or
+    (:class:`LatentLayout`) the one row."""
     layers, _, heads, head_dim, _ = dims
     item = jnp.dtype(dtype).itemsize
     state = 0
@@ -139,7 +141,7 @@ def bytes_held(dims, dtype, recurrent=None) -> dict:
         state = n * ((mixer.conv_taps - 1) * mixer.conv_channels * item
                      + mixer.value_heads * mixer.key_dim
                      * mixer.value_dim * 4)
-    return {"kv_bytes_per_token": 2 * layers * heads * head_dim * item,
+    return {"kv_bytes_per_token": arrays * layers * heads * head_dim * item,
             "state_bytes_per_slot": state}
 
 
@@ -216,7 +218,8 @@ def keep_row_major(cache_arr):
         cache_arr, Layout(major_to_minor=tuple(range(cache_arr.ndim))))
 
 
-def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32):
+def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32,
+                     scale=None):
     """One decode step's attention over a layer's cache slice.
 
     ``q``: ``[B, 1, heads, head_dim]`` (the step's query — the token
@@ -232,6 +235,8 @@ def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32):
     such buffer exists).  Fewer key/value heads than query heads: the
     group of query heads that reads a key/value head rides in the row
     dimension, ``[B, kv_heads, group, T]``, and the lane is read once.
+    ``scale``: what the scores are multiplied by in float32, where it is
+    not ``head_dim ** -0.5``.
     """
     depth = q.shape[-1]
     B, _, heads, _ = q.shape
@@ -245,8 +250,11 @@ def cached_attention(q, k_layer, v_layer, lengths, *, dtype=jnp.float32):
     # transpose (= copy) the whole cache lane every step.
     scores = lax.dot_general(
         q2, k_layer.astype(q.dtype),
-        (((3,), (3,)), ((0, 1), (0, 1)))) / np.sqrt(depth)
-    scores = scores.astype(jnp.float32)              # [B, heads, 1, T]
+        (((3,), (3,)), ((0, 1), (0, 1))))
+    if scale is None:
+        scores = (scores / np.sqrt(depth)).astype(jnp.float32)
+    else:
+        scores = scores.astype(jnp.float32) * scale  # [B, heads, 1, T]
     T = k_layer.shape[2]
     ok = jnp.arange(T)[None, None, None, :] <= \
         lengths[:, None, None, None]
@@ -642,11 +650,11 @@ def paged_cached_attention(q, k_pool, v_pool, lengths, block_table, *,
 # --------------------------------------------------------------------------- #
 # The seam: how the engine meets a layout
 # --------------------------------------------------------------------------- #
-# ``ServingEngine`` picks one of the two classes below once, from
-# ``kv_layout``: its programs call the traced methods where a layer
-# writes or reads the cache, its host API delegates the accounting (a
-# method that can change the table takes the live cache and hands it
-# back).  A new format is one more class, in this module alone.
+# ``ServingEngine`` picks one of the three classes below once, from
+# ``kv_layout`` and the block: its programs call the traced methods where
+# a layer writes or reads the cache, its host API delegates the
+# accounting (a method that can change the table takes the live cache
+# and hands it back).  A new format is one more class, in this module alone.
 def _both(write, kc, vc, layer, k, v, *a, **kw):
     """``write`` the keys into ``kc`` and the values into ``vc``."""
     return write(kc, layer, k, *a, **kw), write(vc, layer, v, *a, **kw)
@@ -787,6 +795,62 @@ class DenseLayout:
 
     def protect(self, cache, active, n):
         return cache
+
+
+class LatentLayout(DenseLayout):
+    """Per-slot ``max_len`` lanes of latent-attention rows: a cached
+    position of a layer is ONE row ``[c | k_pe]`` that every query head
+    reads, not keys and values a head.  The cache's ``k`` holds the rows
+    as one key head, ``[layer, slot, 1, max_len, row]``; its values ARE
+    the first ``kv_rank`` columns of those keys, so ``v`` is the same
+    lanes at width 0 and rides the programs untouched.  The traced
+    methods take the row where the other layouts take keys (``k``
+    ``[B, S, 1, row]``) and nothing for ``v``; the host constants are a
+    dense lane's.
+
+    ``dims``: ``(layers, slots, 1, row, max_len)``; ``kv_rank``: how many
+    of a row's leading columns are its values; ``scale``: what the scores
+    are multiplied by (``BlockSpec.latent_softmax_scale``)."""
+
+    def __init__(self, dims, kernel, *, kv_rank: int, scale: float):
+        super().__init__(dims, kernel)
+        self.kv_rank, self.scale = kv_rank, scale
+
+    def init_cache(self, dims, dtype) -> KVCache:
+        layers, slots, heads, row, max_len = dims
+        lanes = (layers, slots, heads, max_len)
+        return KVCache(k=jnp.zeros(lanes + (row,), dtype),
+                       v=jnp.zeros(lanes + (0,), dtype),
+                       lengths=jnp.zeros((slots,), jnp.int32))
+
+    # ---- traced ------------------------------------------------------ #
+    def write_prompt(self, kc, vc, layer, k, v, slot, table_row, p_len,
+                     write_from):
+        return write_prompt(kc, layer, k, slot), vc
+
+    def write_token(self, kc, vc, layer, k, v, positions, table, active):
+        return write_token(kc, layer, k, positions), vc
+
+    def decode_attend(self, q, k, v, kc, vc, layer, lengths, table, active,
+                      *, dtype):
+        """``(o_lat, kc, vc)``: the step's rows written at ``lengths``,
+        then ``q`` ``[B, 1, heads, row]`` — the absorbed queries —
+        attended over ``layer``'s rows as one key head, every query head
+        in the row dimension of the products over the one lane; ``o_lat``
+        ``[B, 1, heads, kv_rank]`` is the weighted sum of the rows'
+        latents."""
+        kc, vc = self.write_token(kc, vc, layer, k, v, lengths, table,
+                                  active)
+        with scope("latent_attention"), scope("latent_attend"):
+            rows = kc[layer]
+            out = cached_attention(q, rows, rows, lengths, dtype=dtype,
+                                   scale=self.scale)
+        return out[..., :self.kv_rank], kc, vc
+
+    def attend_window(self, q, kc, vc, layer, starts, table, *, dtype):
+        raise NotImplementedError(
+            "a window of positions against cached latent rows (chunked "
+            "prefill, the speculative verify pass) is not served")
 
 
 class PagedLayout:

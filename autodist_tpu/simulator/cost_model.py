@@ -1532,7 +1532,16 @@ class CostModel:
           the ``top_k / num_experts`` of them it chose, and a step
           reads the held experts some row hit — ``held x (1 - (1 -
           top_k / num_experts) ^ slots)`` in expectation — at the HBM
-          rate; the larger of the two is their price.
+          rate; the larger of the two is their price.  The experts are
+          priced by their leaves, so a stack whose ``dense_layers``
+          leading layers carry a dense FFN pays those every parameter
+          once, like any dense leaf.
+        * **a latent row** — ``block.latent``: a cached position of a
+          layer is ONE row of ``kv_rank + rope_dim`` values that every
+          query head reads, not ``heads x head_dim x 2``: the cache's
+          bytes, the capacity term and the attention term — the rows of
+          a lane read once a layer at the chip's HBM rate, which is what
+          binds a step of a few query rows — are of that row.
         """
         from autodist_tpu.strategy.ir import (normalize_kernel,
                                               normalize_kv_layout,
@@ -1678,6 +1687,9 @@ class CostModel:
         kv_width = hidden
         if block is not None and block.kv_heads and block.head_dim:
             kv_width = block.kv_heads * block.head_dim
+        # what a position holds a layer: keys and values, or the one row
+        latent = getattr(block, "latent", None)
+        position_elems = 2.0 * kv_width if latent is None else latent.row
         # Attention over the cache: per token, each layer contracts the
         # query against its [heads/tp, max_len, head_dim] cache slice
         # twice (scores + values) — the term that grows with occupancy
@@ -1710,6 +1722,10 @@ class CostModel:
             attn *= float(self.kernel_profile.get(
                 "prefix_caching_overhead",
                 KERNEL_PROFILE["prefix_caching_overhead"]))
+        if latent is not None:
+            # every query head on the one row: a lane's rows once a layer
+            attn = layers * position_elems * kv_bytes_per_elem * max_len \
+                * batch_slots / hbm_rate
         compute += attn
 
         bw_link = float(self.link_profile.get(
@@ -1770,7 +1786,7 @@ class CostModel:
         bl = max(int(kv_block_len), 1)
         resident = (float(-(-int(math.ceil(mean_len)) // bl) * bl)
                     if kv_layout == "paged" else float(max_len))
-        lane_bytes = 2.0 * layers * kv_width * kv_bytes_per_elem \
+        lane_bytes = layers * position_elems * kv_bytes_per_elem \
             / max(tp, 1)
         kv = lane_bytes * resident * batch_slots
         mem = bytes_ + kv + state_bytes * batch_slots

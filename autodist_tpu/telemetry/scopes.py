@@ -31,6 +31,12 @@ SCOPES = (
     "moe",         # a routed FFN: router, sort, experts, shared expert
     "moe_experts",  # inside moe: routing and the held experts' grouped
                    # matmuls over the (row, expert) pairs that hit them
+    "latent_attention",  # a latent-attention mixer: the down- and
+                   # up-projections (absorbed into q and the output in a
+                   # decode step), rotary, the output projection
+    "latent_attend",  # inside latent_attention: the read of the cached
+                   # rows (scores, softmax, weighted sum); in prefill the
+                   # expanded attention
 )
 
 

@@ -28,7 +28,10 @@ weights random from a seed:
    ``qwen3-next-80b-a3b`` cell, against its composed form on the chip:
    the recurrent state's update (one position; a window through the
    chunked form) and the routed layer (sorted pairs through the grouped
-   matmul, 64 of 512 experts held, 10 a token);
+   matmul, 64 of 512 experts held, 10 a token); and a latent-attention
+   layer's absorbed decode step over cached rows against its expanded
+   form over the same window, at the widths of the benchmark's
+   ``deepseek-v2-lite`` cell (16 heads, a row of 512 + 64, bf16);
 5. **multi-chip** (when ``jax.device_count() > 1``) — the six lowering
    programs of ``__graft_entry__``, the ring kernels over real ICI,
    tensor-parallel serving, and two one-chip engines on two chips.
@@ -676,6 +679,93 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
     return done
 
 
+def latent_block_phase(*, slots: int = 8, heads: int = 16,
+                       hidden: int = 2048, kv_rank: int = 512,
+                       nope_dim: int = 128, rope_dim: int = 64,
+                       value_dim: int = 128, window: int = 384,
+                       max_len: int = 512, dtype=None, rtol: float = 3e-2,
+                       seed: int = 0) -> list:
+    """A latent-attention layer's two forms over one set of weights, at
+    the widths of the benchmark's ``deepseek-v2-lite`` cell, on the same
+    backend: the last position of a ``window`` attended in the EXPANDED
+    form (every row projected up to its heads' keys and values) against
+    that position attended in the ABSORBED form over the rows the window
+    cached, through the cache manager's latent layout — the row written,
+    then every query head on the one lane (``LatentLayout
+    .decode_attend``).  The two round differently (the absorbed form
+    makes ``q_lat`` and ``o_lat`` in the activations' type, the expanded
+    form the keys and values), hence ``rtol``."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import (BlockSpec,
+                                                 LatentAttentionSpec,
+                                                 RopeScaling,
+                                                 TransformerConfig)
+    from autodist_tpu.serving import kv_cache
+
+    ph = "latent"
+    dtype = jnp.bfloat16 if dtype is None else dtype
+    yarn = RopeScaling(factor=40.0, original_max_len=4096, mscale=0.707,
+                       mscale_all_dim=0.707)
+    lat = LatentAttentionSpec(kv_rank, nope_dim, rope_dim, value_dim)
+    cfg = TransformerConfig(
+        vocab_size=8, hidden_size=hidden, num_layers=1, num_heads=heads,
+        mlp_dim=8, max_len=max_len, dtype=dtype, dropout_rate=0.0,
+        attention_dropout_rate=0.0,
+        block=BlockSpec(norm="rmsnorm", norm_placement="pre",
+                        positions="rope", rope_scaling=yarn, ffn="swiglu",
+                        bias=False, tied_head=False, latent=lat))
+    r = np.random.RandomState(seed)
+    shapes = lm.param_shapes(cfg)["stages"]
+    chunk = jax.tree.map(
+        lambda shape: jnp.asarray(r.randn(*shape[1:]) * 0.02, dtype),
+        {k: shapes[k] for k in ("latent_attention", "ln_attention_in")},
+        is_leaf=lambda x: isinstance(x, tuple))
+    for norm in (chunk["ln_attention_in"], chunk["latent_attention"]
+                 ["kv_norm"]):
+        norm["scale"] = jnp.ones_like(norm["scale"])
+    B, T = slots, window
+    # a stream as small as an embedding's rows: the mixer's output, which
+    # the norm makes independent of the stream's size, is then most of
+    # what the residual sum holds, and its rounding does not hide it
+    x = jnp.asarray(r.randn(B, T, hidden) * 0.02, dtype)
+    mask = jnp.tril(jnp.ones((T, T), bool))[None, None]
+    (want, rows), s = timed(lambda: jax.block_until_ready(jax.jit(
+        lambda x: lm.latent_expanded(cfg, chunk, x, jnp.arange(T), mask))(x)))
+    say(ph, f"expanded over {T} positions ({B} rows, {heads} heads of "
+            f"{nope_dim + rope_dim} / {value_dim}): first call {s:.2f}s")
+
+    dims = (1, B, 1, lat.row, max_len)
+    layout = kv_cache.LatentLayout(dims, {}, kv_rank=kv_rank,
+                                   scale=cfg.block.latent_softmax_scale)
+    cache = layout.init_cache(dims, dtype)
+    # the window's rows but the last, as a prefill would have left them
+    kc = cache.k.at[0, :, 0, :T - 1].set(rows[:, :T - 1])
+    lengths = jnp.full((B,), T - 1, jnp.int32)
+
+    def absorbed(x_last, kc, vc):
+        def attend(q, row):
+            out, *caches = layout.decode_attend(
+                q, row, None, kc, vc, 0, lengths, None, None, dtype=dtype)
+            return out, caches
+
+        return lm.latent_absorbed(cfg, chunk, x_last, lengths[:, None],
+                                  attend)
+
+    (got, (kc, _)), s = timed(lambda: jax.block_until_ready(
+        jax.jit(absorbed)(x[:, -1:], kc, cache.v)))
+    require_close(ph, f"absorbed step over {T} cached rows of {lat.row} "
+                      f"against the expanded form", got[:, 0], want[:, -1],
+                  rtol)
+    require_close(ph, "the step's row, written where it attends",
+                  kc[0, :, 0, T - 1], rows[:, -1], 1e-6)
+    say(ph, f"absorbed step: first call {s:.2f}s")
+    return ["latent_expanded", "latent_absorbed"]
+
+
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,
                        matmul_shape=(1024, 1024, 1024), seed: int = 0) -> list:
     """The three ring kernels whole, inside ``shard_map`` over
@@ -874,6 +964,7 @@ def main() -> int:
                                  dense["tokens"])
     say("kernel", f"compiled (interpret=False) and agreed: {kernels}")
     say("mixed", f"agreed with their composed forms: {mixed_block_phase()}")
+    say("latent", f"agreed with each other: {latent_block_phase()}")
 
     if n > 1:
         multichip_phase(cfg, params, prompts, dense["tokens"],

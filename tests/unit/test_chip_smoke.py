@@ -82,6 +82,17 @@ def test_mixed_block_phase_at_toy_width(tile, fused):
         + ["gated_delta_chunked", "routed_experts"]
 
 
+def test_latent_block_phase_at_toy_width():
+    """Float32 at a toy width: the absorbed step is the expanded form to
+    the order of the sums."""
+    import jax.numpy as jnp
+
+    done = chip_smoke.latent_block_phase(
+        slots=3, heads=4, hidden=32, kv_rank=16, nope_dim=8, rope_dim=4,
+        value_dim=8, window=21, max_len=32, dtype=jnp.float32, rtol=1e-4)
+    assert done == ["latent_expanded", "latent_absorbed"]
+
+
 @pytest.mark.slow
 def test_kernel_phase_at_toy_width_under_the_interpreter(lm):
     done = chip_smoke.kernels_phase(

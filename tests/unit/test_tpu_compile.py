@@ -226,6 +226,79 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
             .hexdigest()[:16] == COMPOSED_DECODE_HLO
 
 
+# the benchmark's latent-attention stack at its published widths (16
+# heads, a row of 512 + 64, a dense layer of 10,944 then routed layers of
+# 8 of 64 experts), three layers deep, at the cell's 64 slots of 3,072
+# positions and its [1, 1024] prompt row
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_stack_compiles_in_place(one_chip, program):
+    """Both programs hold the lanes of latent rows they are given: the
+    rows alias their output, neither program keeps a second copy of them
+    (no temporary of a lane's size a layer, let alone the cache's), both
+    take the cache in the one layout the chip chooses for a row that is
+    no multiple of its 128 lanes — positions minor-most, so that no
+    program converts it for the other — and the experts run in the
+    compiler's grouped-matmul kernel."""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import (BlockSpec,
+                                                 LatentAttentionSpec,
+                                                 RopeScaling, RoutedFFNSpec,
+                                                 TransformerConfig)
+    from autodist_tpu.serving import ServingEngine
+
+    bf16, slots, bucket, T, L = jnp.bfloat16, 64, 1024, 3072, 3
+    yarn = RopeScaling(40.0, 4096, mscale=0.707, mscale_all_dim=0.707)
+    cfg = TransformerConfig(
+        vocab_size=12800, hidden_size=2048, num_layers=L, num_heads=16,
+        mlp_dim=10944, max_len=T, dtype=bf16, dropout_rate=0.0,
+        attention_dropout_rate=0.0,
+        block=BlockSpec(
+            norm="rmsnorm", norm_placement="pre", positions="rope",
+            rope_scaling=yarn, ffn="swiglu", bias=False, tied_head=False,
+            latent=LatentAttentionSpec(512, 128, 64, 128),
+            dense_layers=1,
+            moe=RoutedFFNSpec(64, 6, 1408, experts_held=8,
+                              shared_width=2816, renormalise=False,
+                              shared_gate=False)))
+    params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
+                          lm.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    engine = ServingEngine(cfg, params, num_slots=slots, max_len=T,
+                           prefill_len=bucket, decode_steps=8)
+    sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                         sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    c = engine.cache
+    assert c.k.shape == (L, slots, 1, T, 576) and c.v.size == 0
+    head = (jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
+            i32(slots), i32(slots))
+    with jax.default_matmul_precision("default"):
+        if program == "decode":
+            compiled = engine._decode_jit.lower(
+                *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
+                    (slots,), jnp.bool_, sharding=one_chip)).compile()
+        else:
+            compiled = engine._prefill_jit.lower(
+                *head, i32(), i32(1, 1), i32(1), i32(1, bucket),
+                i32(1)).compile()
+    mem = compiled.memory_analysis()
+    rows = c.k.size * 2
+    assert abs(mem.alias_size_in_bytes - rows) < 4096
+    # a layer's lanes are 226 MB, in float32 453
+    assert mem.temp_size_in_bytes < 160 << 20
+    text = compiled.as_text()
+    # ({3,4,1,2,0 is the same bytes: the axis of the one key head is 1)
+    layouts = set(re.findall(
+        rf"bf16\[{L},{slots},1,{T},576\](\{{[\d,]+)", text))
+    assert layouts and all(l.startswith("{3,4,") for l in layouts), layouts
+    assert not re.findall(
+        rf"= (?:bf16|f32)\[(?:{L},)?{slots},1,{T},576\][^ ]* "
+        r"(?:copy|transpose|convert)\(", text)
+    assert len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
+                          r"[^\n]*ragged-dot-none", text)) >= 4
+
+
 # one encoder layer's attention at the training cell's widths (BERT-base:
 # 12 heads of 64, 512 positions) and at heads of 128, forward and
 # backward: Mosaic takes the one-pass kernels' tiles, and no array of the

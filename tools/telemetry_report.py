@@ -83,6 +83,17 @@ _PREFILL_COUNTERS = ("engine/prefill_rows", "engine/prefill_positions")
 # the routing it ran — --check fails it.
 _ROUTING_COUNTERS = ("moe/layer_steps", "moe/rows_routed", "moe/rows_held",
                      "moe/experts_hit")
+# Latent rows read (autodist_tpu/serving/batcher.py): an engine whose
+# cached position is a latent-attention row advances
+# serve/latent_positions_read by every decode step's live positions x
+# layers, beside serve/kv_blocks_resident (steps x slots: such a lane is
+# one block) and under the engine/latent_lane_rows (a lane's positions)
+# and engine/cache_layers gauges.  More rows read than steps x slots x
+# max_len x layers hold means the count is not of the rows the steps
+# could read — --check fails it.
+_LATENT_COUNTER = "serve/latent_positions_read"
+_LATENT_BOUND = ("serve/kv_blocks_resident", "engine/latent_lane_rows",
+                 "engine/cache_layers")
 # Per-reshard records (autodist_tpu/elastic/reshard.py): one per
 # executed reshard — route taken (compiled fast path vs host-staged),
 # payload moved, and the host-memory high-water mark the staged route
@@ -527,6 +538,25 @@ def check_schema(run_dir: str) -> list[str]:
                     f"metrics.jsonl: moe/experts_hit = {hit!r} is over "
                     f"moe/layer_steps = {steps!r} x engine/experts_held "
                     f"= {held_g.get('value')!r}")
+
+    latent = counters.get(_LATENT_COUNTER)
+    if latent is not None:
+        held = [counters.get(_LATENT_BOUND[0]),
+                *(gauges.get(n) for n in _LATENT_BOUND[1:])]
+        if any(r is None for r in held):
+            problems.append(
+                f"metrics.jsonl: {_LATENT_COUNTER}, "
+                f"{', '.join(_LATENT_BOUND)} come together — one is "
+                "missing")
+        else:
+            windows, lane, layers = (r.get("value", 0) for r in held)
+            if latent.get("value", 0) > windows * lane * layers:
+                problems.append(
+                    f"metrics.jsonl: {_LATENT_COUNTER} = "
+                    f"{latent.get('value')!r} is over steps x slots "
+                    f"({_LATENT_BOUND[0]} = {windows!r}) x max_len "
+                    f"({_LATENT_BOUND[1]} = {lane!r}) x layers "
+                    f"({_LATENT_BOUND[2]} = {layers!r})")
 
     fused = (counters.get(_ATTENTION_COUNTERS[0]) or {}).get("value", 0)
     if bool(fused) != ("kernel/flash_attention_elected" in gauges):
