@@ -18,6 +18,10 @@ It reads the cache as the TPU lays it out, so the kernel's view of the
 array is the array (:func:`fused_decode_block`): the lanes transposed,
 ``[.., d, T]`` tiles, for heads narrower than the chip's 128 lanes, and
 row-major ``[.., T, d]`` tiles for heads of 128 and wider.  The
+**latent** kernel (:func:`flash_decode_attention_latent`) is its sibling
+for a cache of latent-attention rows, ``[L, B, 1, T, row]``: one array
+that is every query head's keys and, a row's leading columns, their
+values, so a slot's live blocks are read once for both products.  The
 **paged** kernel walks a slot's block table one ``[block_len, d]`` pool
 block per grid step (:func:`online_softmax_step`, shared with the paged
 prefill kernel).
@@ -35,6 +39,7 @@ agreement with the full-recompute ``sequential_logits`` reference.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -56,6 +61,12 @@ DEFAULT_BLOCK_K = 128
 MIN_FUSED_DECODE_LEN = 256
 KV_BLOCK_BYTES = 2 << 20       # one K (or V) block, lane-padded
 VMEM_LIMIT_BYTES = 32 << 20    # K and V blocks double-buffered + carry
+# The latent kernel's cache-block length and how many blocks it keeps in
+# VMEM (all but one of them on their way while one is computed): read on
+# a v5e with ``tools/flash_crossover.py --decode --latent`` at 64 slots x
+# 3,072 x 576, bf16 (PERF.md section 6, PR 36).
+LATENT_BLOCK_K = 256
+LATENT_BUFFERS = 4
 
 
 def online_softmax_step(first_pos, j, q_ref, k_ref, v_ref, o_ref, m_ref,
@@ -500,6 +511,290 @@ def flash_decode_attention(q, k_layer, v_layer, lengths, *,
     return flash_decode_attention_dense(
         q, k_layer[None], v_layer[None], 0, lengths, dtype=dtype,
         block_k=bk, interpret=interpret)
+
+
+# --------------------------------------------------------------------------- #
+# Latent rows: one ``[L, B, 1, T, row]`` array, keys and values at once
+# --------------------------------------------------------------------------- #
+def latent_decode_block(max_len: int, row: int, kv_rank: int, dtype):
+    """The block length with which the latent kernel reads a
+    ``[L, B, 1, max_len, row]`` cache of ``dtype`` in place, or ``None``
+    where it cannot.  Its view of the cache, ``[.., row, max_len]``, is
+    the array only where the chip keeps the positions minor-most: a row
+    that is no multiple of its 128 lanes under a lane that is.  The rows
+    then lie on the sublanes, so ``row`` and ``kv_rank`` (where the
+    values' slice of a tile ends) have to be whole sublane tiles of
+    ``dtype`` (8 of 32 bits, 16 of 16).  The block is the longest
+    multiple of 128 up to :data:`LATENT_BLOCK_K` that divides
+    ``max_len``."""
+    sublanes = 32 // jnp.dtype(dtype).itemsize
+    if max_len % 128 or row % 128 == 0 or row % sublanes \
+            or kv_rank % sublanes or not 0 < kv_rank <= row:
+        return None
+    return max(bk for bk in range(128, min(LATENT_BLOCK_K, max_len) + 1, 128)
+               if max_len % bk == 0)
+
+
+def latent_decode_elected(word, max_len: int, row: int, kv_rank: int,
+                          dtype, backend: Optional[str] = None):
+    """The engine's election for a cache of latent rows: the block the
+    kernel reads it with, or ``None`` for ``write_token`` and
+    ``cached_attention``.  ``word`` is the kernel slot's on
+    ``flash_decode``: ``False`` forbids the kernel; ``None`` leaves it to
+    what can be observed — a TPU under the programs, a cache the kernel
+    reads in place (:func:`latent_decode_block`), a lane of at least
+    :data:`MIN_FUSED_DECODE_LEN`; ``True`` takes it wherever it can run
+    (the interpreter off the TPU), with any block that divides the lane
+    where the kernel's view of the cache is a copy of it."""
+    if word is False:
+        return None
+    block = latent_decode_block(max_len, row, kv_rank, dtype)
+    if word:
+        return block or math.gcd(max_len, LATENT_BLOCK_K)
+    if block and max_len >= MIN_FUSED_DECODE_LEN \
+            and (backend or jax.default_backend()) == "tpu":
+        return block
+    return None
+
+
+def _latent_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
+                          block_len: int, num_blocks: int, kv_rank: int,
+                          scale: float, write: bool):
+    """One slot a grid step: walk the slot's live blocks of its lane of
+    latent rows, ONE ``[row, bk]`` tile of the transposed lane a block —
+    a tile is the keys of every query head and, its first ``kv_rank``
+    sublanes, their values.  Block ``j`` holds positions ``[j*bk,
+    (j+1)*bk)``; the last live block is ``lengths[slot] // bk`` (position
+    ``lengths`` is this step's token), so the trip count is the slot's
+    own and a dead block costs nothing.  The kernel's own DMAs run
+    ahead of the products by all the buffers but one, over the walk of
+    every slot's live blocks in turn (``cur_ref`` holds where it
+    stands), so a slot's first blocks are on their way while the slot
+    before it is computed.
+
+    With ``write`` the step's row is put into the block that holds
+    position ``wpos[slot]`` while it is in VMEM, before the products,
+    and the 128 columns around it go back to the cache (the aliased
+    output), as :func:`_dense_decode_kernel` does with one array more:
+    the cache write of the step, without a pass of its own.  ``wpos <
+    0`` writes nothing.
+
+    Products take the cache's own tiles with float32 results; the
+    running max, sum and accumulator are float32 and ride the loop."""
+    if write:
+        (new_ref, rows_hbm, o_ref, rows_out,
+         buf, sem, wbuf, wsem, cur_ref) = refs
+    else:
+        rows_hbm, o_ref, buf, sem, cur_ref = refs
+    bk, depth = block_len, buf.shape[0]
+    heads = q_ref.shape[0]
+    b, slots = pl.program_id(0), pl.num_programs(0)
+    layer = layer_ref[0]
+    length = len_ref[b]
+
+    def live(slot):                     # its live blocks
+        return jnp.minimum(len_ref[slot] // bk, num_blocks - 1) + 1
+
+    def fetch(slot, j, at):
+        return pltpu.make_async_copy(
+            rows_hbm.at[layer, slot, 0, :,
+                        pl.ds(pl.multiple_of(j * bk, bk), bk)],
+            buf.at[at], sem.at[at])
+
+    # cur_ref: blocks computed, blocks fetched, the slot and the block to
+    # fetch next, whether a write-back is in flight
+    def fetch_next():
+        @pl.when(cur_ref[2] < slots)
+        def _():
+            slot, j = cur_ref[2], cur_ref[3]
+            fetch(slot, j, cur_ref[1] % depth).start()
+            cur_ref[1] = cur_ref[1] + 1
+            last = j + 1 == live(slot)
+            cur_ref[2] = jnp.where(last, slot + 1, slot)
+            cur_ref[3] = jnp.where(last, 0, j + 1)
+
+    @pl.when(b == 0)
+    def _prime():
+        for i in range(5):
+            cur_ref[i] = 0
+        for _ in range(depth - 1):
+            fetch_next()
+
+    q = q_ref[...].astype(buf.dtype)                       # [heads, row]
+    if write:
+        wpos = wpos_ref[b]
+        w = wbuf.shape[1]               # positions written back as one
+        # this slot's row as a column: its lane of the rows' transpose
+        lane = jax.lax.broadcasted_iota(jnp.int32, new_ref.shape, 1)
+        new = jnp.sum(
+            jnp.where(lane == b, new_ref[...].astype(jnp.float32), 0.0),
+            axis=1, keepdims=True).astype(buf.dtype)       # [row, 1]
+
+        def put_back(start):
+            return pltpu.make_async_copy(
+                wbuf, rows_out.at[layer, b, 0, :, pl.ds(start, w)],
+                wsem.at[0])
+
+        def settle():                   # the write-back in flight, if any
+            @pl.when(cur_ref[4] == 1)
+            def _():
+                put_back(0).wait()
+                cur_ref[4] = 0
+
+    def block(j, carry):
+        m, s, acc = carry
+        at = cur_ref[0] % depth
+        fetch_next()        # into the buffer of the block computed last
+        fetch(b, j, at).wait()
+        cur_ref[0] = cur_ref[0] + 1
+
+        if write:
+            # a lane offset may not be dynamic: one branch per 128 columns
+            for c in range(bk // w):
+                @pl.when((wpos >= 0) & (wpos // w == j * (bk // w) + c))
+                def _insert(c=c):
+                    settle()
+                    cols = (at, slice(None), pl.ds(c * w, w))
+                    pos = jax.lax.broadcasted_iota(jnp.int32, (1, w), 1)
+                    tile = jnp.where(pos == wpos - j * bk - c * w, new,
+                                     buf[cols])
+                    buf[cols] = tile
+                    wbuf[...] = tile
+                    put_back(pl.multiple_of(j * bk + c * w, w)).start()
+                    cur_ref[4] = 1
+
+        rows = buf[at]                                     # [row, bk]
+        scores = jax.lax.dot_general(
+            q, rows, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale    # [heads, bk]
+        idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
+        scores = jnp.where(idx <= length, scores, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
+        alpha = jnp.exp(m - m_new)                         # [heads, 1]
+        p = jnp.exp(scores - m_new)                        # [heads, bk]
+        s = s * alpha + jnp.sum(p, axis=-1, keepdims=True)
+        acc = acc * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:kv_rank],
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)            # [heads, kv_rank]
+        return m_new, s, acc
+
+    _, s, acc = jax.lax.fori_loop(0, live(b), block, (
+        jnp.full((heads, 1), NEG_INF, jnp.float32),
+        jnp.zeros((heads, 1), jnp.float32),
+        jnp.zeros((heads, kv_rank), jnp.float32)))
+    if write:
+        @pl.when(b + 1 == slots)
+        def _last():
+            settle()
+    # Position 0 is visible to every slot, so s > 0.
+    o_ref[...] = (acc / s).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "block_len", "buffers", "kv_rank", "scale", "dtype", "interpret"))
+def flash_decode_latent_layer(lengths, wpos, layer, q2, new_t, rows_t, *,
+                              block_len: int, buffers: int, kv_rank: int,
+                              scale: float, dtype, interpret: bool):
+    """The one inner function every layer's call goes through, as
+    :func:`flash_decode_layer` is the dense kernel's.  ``q2``:
+    ``[B, heads, row]``; ``new_t``: the step's rows transposed,
+    ``[row, B]``, or ``None``; ``rows_t``: the cache whole, as
+    ``[L, B, 1, row, T]``.  Returns the weighted sums
+    ``[B, heads, kv_rank]``, and the cache after them when ``new_t`` was
+    written."""
+    _, B, _, row, T = rows_t.shape
+    heads = q2.shape[1]
+    bk = block_len
+    write = new_t is not None
+    w = min(bk, 128)        # the columns that go back around the new one
+    whole = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,          # lengths, wpos, layer (SMEM)
+        grid=(B,),
+        in_specs=[pl.BlockSpec((None, heads, row), lambda b, *_: (b, 0, 0))]
+        # every slot's new row, fetched once: the index never changes
+        + [pl.BlockSpec((row, B), lambda b, *_: (0, 0))] * write + [whole],
+        out_specs=[pl.BlockSpec((None, heads, kv_rank),
+                                lambda b, *_: (b, 0, 0))] + [whole] * write,
+        scratch_shapes=[pltpu.VMEM((buffers, row, bk), rows_t.dtype),
+                        pltpu.SemaphoreType.DMA((buffers,))]
+        + [pltpu.VMEM((row, w), rows_t.dtype),             # write-back
+           pltpu.SemaphoreType.DMA((1,))] * write
+        + [pltpu.SMEM((5,), jnp.int32)],    # where the walk stands
+    )
+    kern = functools.partial(
+        _latent_decode_kernel, block_len=bk, num_blocks=T // bk,
+        kv_rank=kv_rank, scale=scale, write=write)
+    with jax.named_scope(kernel_marker("flash_decode")):
+        res = pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=[jax.ShapeDtypeStruct((B, heads, kv_rank), dtype)]
+            + [jax.ShapeDtypeStruct(rows_t.shape, rows_t.dtype)] * write,
+            # operands: 3 scalars, q, (the new rows), the cache
+            input_output_aliases={5: 1} if write else {},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=VMEM_LIMIT_BYTES),
+            interpret=interpret,
+        )(lengths, wpos, layer, q2, *([new_t] * write), rows_t)
+    return tuple(res) if write else res[0]
+
+
+def flash_decode_attention_latent(q, rows_cache, layer, lengths, *,
+                                  kv_rank: int, scale: float, new_row=None,
+                                  active=None, dtype=jnp.float32,
+                                  block_k: Optional[int] = None,
+                                  interpret: Optional[bool] = None):
+    """Fused :func:`autodist_tpu.serving.kv_cache.cached_attention` of a
+    decode step's absorbed queries over layer ``layer`` of a cache of
+    latent-attention rows (``serving/kv_cache.py LatentLayout``), reading
+    each slot's live blocks once for scores and weighted sum alike —
+    and, given ``new_row``, the step's
+    :func:`~autodist_tpu.serving.kv_cache.write_token` in the same pass.
+
+    ``q``: ``[B, 1, heads, row]``; ``rows_cache``: ``[L, B, 1, T, row]``
+    (the cache array itself, every query head's one key head; a row's
+    first ``kv_rank`` columns are its values); ``layer``: int or int32
+    scalar; ``lengths``: ``[B]`` int32; ``scale``: what the float32
+    scores are multiplied by.  Returns ``[B, 1, heads, kv_rank]`` in
+    ``dtype``.
+
+    ``new_row`` ``[B, 1, 1, row]``: slot ``i``'s row is written at
+    position ``lengths[i]`` before the slot attends, and ``(out,
+    rows_cache)`` comes back, the cache updated in place under ``jit``
+    with donation.  ``active`` (``[B]`` bool): a slot that is not active
+    writes nothing and reads one block; its output means nothing.
+
+    The kernel reads the lanes transposed, ``[.., row, T]``:
+    :func:`latent_decode_block` says where that is how the chip keeps
+    them and gives the default ``block_k``, which must divide ``T``."""
+    _, B, _, T, row = rows_cache.shape
+    bk = int(block_k or latent_decode_block(T, row, kv_rank,
+                                            rows_cache.dtype) or 0)
+    if not bk or T % bk:
+        raise ValueError(
+            f"a cache of latent rows [.., {T}, {row}] of "
+            f"{jnp.dtype(rows_cache.dtype).name} does not divide into "
+            f"blocks of {bk or LATENT_BLOCK_K} positions that the kernel "
+            "reads in place (latent_decode_block)")
+    interp = default_interpret() if interpret is None else bool(interpret)
+    lengths = lengths.astype(jnp.int32)
+    live = lengths if active is None else jnp.where(active, lengths, 0)
+    wpos = jnp.full_like(lengths, -1) if new_row is None else (
+        lengths if active is None else jnp.where(active, lengths, -1))
+    if new_row is not None:
+        new_row = new_row.reshape(B, row).T.astype(rows_cache.dtype)
+    res = flash_decode_latent_layer(
+        live, wpos, jnp.asarray(layer, jnp.int32).reshape(1), q[:, 0],
+        new_row, jnp.swapaxes(rows_cache, 3, 4), block_len=bk,
+        buffers=LATENT_BUFFERS, kv_rank=int(kv_rank), scale=float(scale),
+        dtype=jnp.dtype(dtype), interpret=interp)
+    if new_row is None:
+        return res[:, None]                        # [B, 1, heads, kv_rank]
+    out, rows_t = res
+    return out[:, None], jnp.swapaxes(rows_t, 3, 4)
 
 
 # --------------------------------------------------------------------------- #

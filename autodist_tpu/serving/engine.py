@@ -435,19 +435,28 @@ class ServingEngine:
         recurrent = ((self.linear_layers, spec.linear)
                      if self.linear_layers else None)
         if spec.latent is not None:
-            if self.kernel.get("flash_decode"):
-                raise ValueError(
-                    "kernel flash_decode with a latent KV row: the fused "
-                    "decode kernels read keys and values of one head "
-                    "size; the absorbed step decodes through "
-                    "cached_attention, every query head on the one row")
             # one row a position, one key head: the values are its
             # first kv_rank columns
             dims = (self.cache_layers, self.num_slots, 1, spec.latent.row,
                     self.max_len)
+            # The rows' decode attention: the latent kernel where it can
+            # read the cache in place, from what can be observed here, as
+            # the dense branch below elects (latent_decode_elected says
+            # what).  Elsewhere, and on the CPU always, write_token and
+            # cached_attention.
+            from autodist_tpu.kernel.pallas.flash_decode import \
+                latent_decode_elected
+            # the slot's word: True forces, False forbids, None leaves open
+            word = (True if self.kernel.get("flash_decode")
+                    else None if decode_left_open else False)
+            fused_block = latent_decode_elected(
+                word, self.max_len, spec.latent.row, spec.latent.kv_rank,
+                cfg.dtype)
+            if fused_block:
+                self.kernel = dict(self.kernel, flash_decode=True)
             self.kv = kv_cache.LatentLayout(
                 dims, self.kernel, kv_rank=spec.latent.kv_rank,
-                scale=spec.latent_softmax_scale)
+                scale=spec.latent_softmax_scale, fused_block=fused_block)
         elif self.kv_layout == "paged":
             self.kv = kv_cache.PagedLayout(
                 dims, self.kernel, block_len=self.kv_block_len,
@@ -504,6 +513,10 @@ class ServingEngine:
             telemetry.gauge("engine/experts_held").set(spec.moe.experts_held)
         if spec.latent is not None:
             telemetry.gauge("engine/latent_lane_rows").set(self.max_len)
+            # the rows' decode attention: 1 the latent kernel over the
+            # live blocks, 0 the composed products over whole lanes
+            telemetry.gauge("kernel/latent_decode_elected").set(
+                int(bool(self.kv.fused_block)))
 
         self._prefill_jit = (self._build_chunk_prefill()
                              if self.prefill_chunk is not None

@@ -810,10 +810,15 @@ class LatentLayout(DenseLayout):
 
     ``dims``: ``(layers, slots, 1, row, max_len)``; ``kv_rank``: how many
     of a row's leading columns are its values; ``scale``: what the scores
-    are multiplied by (``BlockSpec.latent_softmax_scale``)."""
+    are multiplied by (``BlockSpec.latent_softmax_scale``);
+    ``fused_block``: the block with which the latent decode kernel reads
+    the cache in place, a slot's live blocks once for scores and
+    weighted sum alike, and writes the step's row itself — the engine's
+    election, as a dense lane's."""
 
-    def __init__(self, dims, kernel, *, kv_rank: int, scale: float):
-        super().__init__(dims, kernel)
+    def __init__(self, dims, kernel, *, kv_rank: int, scale: float,
+                 fused_block=None):
+        super().__init__(dims, kernel, fused_block=fused_block)
         self.kv_rank, self.scale = kv_rank, scale
 
     def init_cache(self, dims, dtype) -> KVCache:
@@ -838,7 +843,18 @@ class LatentLayout(DenseLayout):
         attended over ``layer``'s rows as one key head, every query head
         in the row dimension of the products over the one lane; ``o_lat``
         ``[B, 1, heads, kv_rank]`` is the weighted sum of the rows'
-        latents."""
+        latents.  At once in the fused kernel, which takes the cache
+        itself and the layer as an operand, writes the row as it reads
+        and sums the rows' first ``kv_rank`` columns alone."""
+        if self.fused_block:
+            from autodist_tpu.kernel.pallas.flash_decode import \
+                flash_decode_attention_latent
+            with scope("latent_attention"), scope("latent_attend"):
+                out, kc = flash_decode_attention_latent(
+                    q, kc, layer, lengths, kv_rank=self.kv_rank,
+                    scale=self.scale, new_row=k, active=active,
+                    dtype=dtype, block_k=self.fused_block)
+            return out, kc, vc
         kc, vc = self.write_token(kc, vc, layer, k, v, lengths, table,
                                   active)
         with scope("latent_attention"), scope("latent_attend"):

@@ -684,7 +684,8 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
                        nope_dim: int = 128, rope_dim: int = 64,
                        value_dim: int = 128, window: int = 384,
                        max_len: int = 512, dtype=None, rtol: float = 3e-2,
-                       seed: int = 0) -> list:
+                       lane_slots: int = 64, lane_len: int = 3072,
+                       blocks=(128, 256, 512, 1024), seed: int = 0) -> list:
     """A latent-attention layer's two forms over one set of weights, at
     the widths of the benchmark's ``deepseek-v2-lite`` cell, on the same
     backend: the last position of a ``window`` attended in the EXPANDED
@@ -694,7 +695,12 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
     then every query head on the one lane (``LatentLayout
     .decode_attend``).  The two round differently (the absorbed form
     makes ``q_lat`` and ``o_lat`` in the activations' type, the expanded
-    form the keys and values), hence ``rtol``."""
+    form the keys and values), hence ``rtol``.  Then the latent decode
+    kernel over the cache itself against the composed step (``write_token``
+    and ``cached_attention``) through the same seam, alone, at the cell's
+    ``lane_slots`` lanes of ``lane_len`` positions ~43% full: the output,
+    the cache after the step bit for bit, and the seconds of each for
+    every candidate block in ``blocks`` that divides the lane."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -763,7 +769,58 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
     require_close(ph, "the step's row, written where it attends",
                   kc[0, :, 0, T - 1], rows[:, -1], 1e-6)
     say(ph, f"absorbed step: first call {s:.2f}s")
-    return ["latent_expanded", "latent_absorbed"]
+
+    # ---- the decode kernel, in place in the cache manager's array -------
+    B, T, L, layer, reps = lane_slots, lane_len, 2, 1, 48
+    dims = (L, B, 1, lat.row, T)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    q = jax.random.normal(keys[0], (B, 1, heads, lat.row), dtype)
+    new = jax.random.normal(keys[1], (B, 1, 1, lat.row), dtype)
+    stack = lambda: jax.random.normal(keys[2], (L, B, 1, T, lat.row), dtype)
+    lengths = jnp.asarray(np.linspace(T / 16, 0.8 * T, B), jnp.int32)
+    vc = jnp.zeros((L, B, 1, T, 0), dtype)
+
+    def step(block):
+        """``(q, kc) -> (o_lat, kc)``: one layer's decode step through
+        the layout's seam, the kernel with ``block`` or the composed
+        step."""
+        lay = kv_cache.LatentLayout(
+            dims, {}, kv_rank=kv_rank, scale=cfg.block.latent_softmax_scale,
+            fused_block=block)
+        return lambda q, kc: lay.decode_attend(
+            q, new, None, kc, vc, layer, lengths, None, None,
+            dtype=dtype)[:2]
+
+    def per_call(fn):
+        """Seconds a call of ``fn``: ``reps`` calls in one program, the
+        cache donated and carried and each call's queries made from the
+        last one's output, so that nothing runs side by side or is
+        lifted out, and the host's dispatch is paid once."""
+        def chained(_, c):
+            o, kc = fn(*c)
+            return c[0].at[..., :kv_rank].add(o * 1e-3), kc
+
+        many = jax.jit(lambda kc: jax.lax.fori_loop(
+            0, reps, chained, (q, kc))[1], donate_argnums=0)
+        kc = jax.block_until_ready(many(stack()))
+        return timed(lambda: jax.block_until_ready(many(kc)))[1] / reps
+
+    ref_o, ref_kc = jax.jit(step(None))(q, stack())
+    took = {"composed": per_call(step(None))}
+    for bk in (b for b in blocks if T % b == 0):
+        o, kc = jax.jit(step(bk))(q, stack())
+        require_close(ph, f"latent decode kernel output (blocks of {bk}, "
+                          f"{B} lanes of {T})", o, ref_o, rtol)
+        require(bool((kc == ref_kc).all()), ph,
+                "latent decode kernel leaves the cache as write_token does",
+                "bit for bit")
+        took[bk] = per_call(step(bk))
+    live = int(jnp.sum(lengths + 1)) * lat.row * jnp.dtype(dtype).itemsize
+    say(ph, f"decode attention over one of {L} layers, {live / 1e6:.1f} MB "
+            f"of live rows, us a call alone: " + ", ".join(
+                f"{name} {t * 1e6:.1f} ({live / t / 1e9:.0f} GB/s)"
+                for name, t in took.items()))
+    return ["latent_expanded", "latent_absorbed", "latent_decode_kernel"]
 
 
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,

@@ -84,13 +84,16 @@ def test_mixed_block_phase_at_toy_width(tile, fused):
 
 def test_latent_block_phase_at_toy_width():
     """Float32 at a toy width: the absorbed step is the expanded form to
-    the order of the sums."""
+    the order of the sums, and the decode kernel (interpreted) the
+    composed step at every block."""
     import jax.numpy as jnp
 
     done = chip_smoke.latent_block_phase(
         slots=3, heads=4, hidden=32, kv_rank=16, nope_dim=8, rope_dim=4,
-        value_dim=8, window=21, max_len=32, dtype=jnp.float32, rtol=1e-4)
-    assert done == ["latent_expanded", "latent_absorbed"]
+        value_dim=8, window=21, max_len=32, dtype=jnp.float32, rtol=1e-4,
+        lane_slots=5, lane_len=32, blocks=(8, 16, 24, 32))
+    assert done == ["latent_expanded", "latent_absorbed",
+                    "latent_decode_kernel"]
 
 
 @pytest.mark.slow

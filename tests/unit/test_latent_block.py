@@ -504,14 +504,33 @@ def test_the_shares_add_up_to_the_uncut_layer(cfg, params, shares):
     (dict(kv_layout="paged", kv_block_len=4),
      "paged KV with a latent KV row"),
     (dict(tensor_parallel=2), "tensor_parallel=2 with a latent KV row"),
-    (dict(kernel={"flash_decode": True}),
-     "flash_decode with a latent KV row"),
 ], ids=["chunked-prefill", "speculative", "prefix-caching", "paged",
-        "tensor-parallel", "fused-decode-kernel"])
+        "tensor-parallel"])
 def test_engine_options_refuse_the_block_by_name(cfg, params, kw, names):
     with pytest.raises(ValueError, match=names):
         ServingEngine(cfg, params, num_slots=2, max_len=32, prefill_len=8,
                       **kw)
+
+
+def test_the_forced_decode_kernel_serves_the_composed_tokens(ref, rc, cfg,
+                                                             params):
+    """``kernel={"flash_decode": True}`` with a latent row: the decode
+    attention runs in the latent kernel (interpreted here; a lane of 48
+    in blocks of 16, so slots sit on every side of a block's edge), which
+    writes the step's rows itself, and serves the composed engine's
+    tokens one for one — ragged admissions on three slots, every slot
+    reused after an eviction — as close to the reference."""
+    requests = _requests()
+    telemetry.reset()
+    fused = _serve(cfg, params, requests, kernel={"flash_decode": True})
+    gauges = _counts()
+    composed = _serve(cfg, params, requests)
+    assert gauges["kernel/latent_decode_elected"] == 1
+    assert gauges["kernel/flash_decode_elected"] == 1
+    assert _counts()["kernel/latent_decode_elected"] == 0
+    for (_, got), (_, want) in zip(fused, composed):
+        np.testing.assert_array_equal(got, want)
+    assert _gap(ref, rc, params, fused) <= LOGIT_TOL
 
 
 def test_disaggregated_hand_off_refuses_the_block(cfg, params):

@@ -230,15 +230,29 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
 # heads, a row of 512 + 64, a dense layer of 10,944 then routed layers of
 # 8 of 64 experts), three layers deep, at the cell's 64 slots of 3,072
 # positions and its [1, 1024] prompt row
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_latent_stack_compiles_in_place(one_chip, program):
+# The composed decode program of this stack as the TPU's compiler leaves
+# it (hashed as ``COMPOSED_DECODE_HLO`` above), read on the parent commit
+# of PR 36 (e1b02ce) with this test: the latent decode kernel came in
+# behind ``kv_cache.LatentLayout.decode_attend`` and the program that
+# declines it had to come out as it went in.
+COMPOSED_LATENT_DECODE_HLO = "6bacd6846372c948"
+
+
+@pytest.mark.parametrize("program", ["decode", "decode-kernel", "prefill"])
+def test_latent_stack_compiles_in_place(one_chip, program, monkeypatch):
     """Both programs hold the lanes of latent rows they are given: the
     rows alias their output, neither program keeps a second copy of them
     (no temporary of a lane's size a layer, let alone the cache's), both
     take the cache in the one layout the chip chooses for a row that is
     no multiple of its 128 lanes — positions minor-most, so that no
     program converts it for the other — and the experts run in the
-    compiler's grouped-matmul kernel."""
+    compiler's grouped-matmul kernel.  ``decode-kernel``: the decode
+    program a TPU process elects (here forced through the kernel slot,
+    the backend being the CPU's) — Mosaic takes the latent kernel's
+    ``[576, 256]`` tiles, its view of the cache is the array (a bitcast,
+    no copy, transpose or convert of the cache's or a lane's shape), one
+    lowering serves every layer, and the step's rows go in inside it: no
+    ``dynamic-update-slice`` of a 576-wide row is left."""
     from autodist_tpu.models import pipeline_lm as lm
     from autodist_tpu.models.transformer import (BlockSpec,
                                                  LatentAttentionSpec,
@@ -246,6 +260,13 @@ def test_latent_stack_compiles_in_place(one_chip, program):
                                                  TransformerConfig)
     from autodist_tpu.serving import ServingEngine
 
+    from tests.unit.test_looped_block import _program_text
+
+    fused = program == "decode-kernel"
+    if fused:
+        monkeypatch.setattr(
+            importlib.import_module("autodist_tpu.kernel.pallas.flash_decode"),
+            "default_interpret", lambda: False)
     bf16, slots, bucket, T, L = jnp.bfloat16, 64, 1024, 3072, 3
     yarn = RopeScaling(40.0, 4096, mscale=0.707, mscale_all_dim=0.707)
     cfg = TransformerConfig(
@@ -264,7 +285,9 @@ def test_latent_stack_compiles_in_place(one_chip, program):
                           lm.param_shapes(cfg),
                           is_leaf=lambda x: isinstance(x, tuple))
     engine = ServingEngine(cfg, params, num_slots=slots, max_len=T,
-                           prefill_len=bucket, decode_steps=8)
+                           prefill_len=bucket, decode_steps=8,
+                           kernel={"flash_decode": True} if fused else None)
+    assert engine.kv.fused_block == (256 if fused else None)
     sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
                                          sharding=one_chip)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
@@ -274,14 +297,14 @@ def test_latent_stack_compiles_in_place(one_chip, program):
     head = (jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
             i32(slots), i32(slots))
     with jax.default_matmul_precision("default"):
-        if program == "decode":
-            compiled = engine._decode_jit.lower(
+        if program.startswith("decode"):
+            lowered = engine._decode_jit.lower(
                 *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
-                    (slots,), jnp.bool_, sharding=one_chip)).compile()
+                    (slots,), jnp.bool_, sharding=one_chip))
         else:
-            compiled = engine._prefill_jit.lower(
-                *head, i32(), i32(1, 1), i32(1), i32(1, bucket),
-                i32(1)).compile()
+            lowered = engine._prefill_jit.lower(
+                *head, i32(), i32(1, 1), i32(1), i32(1, bucket), i32(1))
+        compiled = lowered.compile()
     mem = compiled.memory_analysis()
     rows = c.k.size * 2
     assert abs(mem.alias_size_in_bytes - rows) < 4096
@@ -292,11 +315,31 @@ def test_latent_stack_compiles_in_place(one_chip, program):
     layouts = set(re.findall(
         rf"bf16\[{L},{slots},1,{T},576\](\{{[\d,]+)", text))
     assert layouts and all(l.startswith("{3,4,") for l in layouts), layouts
+    # ... as the cache, as a layer's lanes, or as the kernel views them
     assert not re.findall(
-        rf"= (?:bf16|f32)\[(?:{L},)?{slots},1,{T},576\][^ ]* "
+        rf"= (?:bf16|f32)\[(?:{L},)?{slots},1,(?:{T},576|576,{T})\][^ ]* "
         r"(?:copy|transpose|convert)\(", text)
     assert len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
                           r"[^\n]*ragged-dot-none", text)) >= 4
+    row_writes = re.findall(r"576\][^ ]* dynamic-update-slice\(", text)
+    if fused:
+        # the kernel's view: the same bytes, positions minor-most
+        views = set(re.findall(
+            rf"bf16\[{L},{slots},1,576,{T}\](\{{[\d,]+)", text))
+        assert views == {"{4,3,2,1,0"}, views
+        assert len(re.findall(r"custom-call\([^\n]*adtk_flash_decode",
+                              text)) == L
+        assert not row_writes
+        # one lowering, the layer an operand of its L calls
+        stablehlo = lowered.as_text()
+        assert stablehlo.count("func.func private "
+                               "@flash_decode_latent_layer") == 1
+        assert len(re.findall(r"call @flash_decode_latent_layer\(",
+                              stablehlo)) == L
+    elif program == "decode":
+        assert row_writes and "adtk_flash_decode" not in text
+        assert hashlib.sha256(_program_text(text).encode()) \
+            .hexdigest()[:16] == COMPOSED_LATENT_DECODE_HLO
 
 
 # one encoder layer's attention at the training cell's widths (BERT-base:

@@ -34,8 +34,16 @@ the Pallas dense flash-decode kernel on the whole cache, as the engine
 calls it.  Each
 point prints one provenance-stamped record in the bench schema; nothing
 is written (the engine's election threshold is set from such a run).
+With ``--latent`` the cache is one of latent-attention rows at the
+shape of the benchmark's ``deepseek-v2-lite`` cell (64 slots x 3,072
+positions x a row of 512 + 64, 16 query heads on the one lane, the
+slots ``mixed``: ~43% of the lanes live) and the kernel is
+``flash_decode_attention_latent``: the reading its constants
+(``flash_decode.LATENT_BLOCK_K``, and with ``--buffers``
+``LATENT_BUFFERS``) are chosen from.
 """
 import argparse
+import itertools
 import json
 import os
 import sys
@@ -144,6 +152,18 @@ def main():
     ap.add_argument("--heads-per-step", type=int, default=0,
                     help="--decode: heads of a slot per grid step "
                          "(0: as many as fit the kernel's VMEM budget)")
+    ap.add_argument("--latent", action="store_true",
+                    help="--decode: a cache of latent-attention rows "
+                         "(--head-dim the row, --kv-rank its values, "
+                         "--heads query heads on the one lane) and the "
+                         "latent kernel; the other options then default "
+                         "to the deepseek-v2-lite cell's shape")
+    ap.add_argument("--buffers", default="",
+                    help="--latent: blocks the kernel keeps in VMEM, each "
+                         "reported (default: flash_decode.LATENT_BUFFERS)")
+    ap.add_argument("--kv-rank", type=int, default=512,
+                    help="--latent: a row's leading columns that are "
+                         "its values")
     ap.add_argument("--read-only", action="store_true",
                     help="--decode: leave the step's cache write out on "
                          "both sides (the attention alone)")
@@ -153,6 +173,9 @@ def main():
                          "this calibration.json's 'kernel' section "
                          "(flash_prefill_crossover_chunk / "
                          "flash_prefill_speedup)")
+    if "--latent" in sys.argv:
+        ap.set_defaults(slots=64, heads=16, head_dim=576, seqs="3072",
+                        fill="mixed", blocks="128,256,512,1024", layers=2)
     args = ap.parse_args()
     args.seqs = args.seqs or ("512" if args.cell else "512,1024,2048,4096")
     args.tokens = args.tokens or (64 * 512 if args.cell else 8192)
@@ -333,7 +356,9 @@ def _main_decode(args):
     record per (cache length, fill, block length); the summary names the
     shortest lane from which the kernel wins at every fill.  It prints and writes nothing: the election's threshold
     (``flash_decode.MIN_FUSED_DECODE_LEN``) is set by hand from a run
-    of this on the chip."""
+    of this on the chip.  ``--latent``: the one array of latent rows,
+    every query head on its one key head, the values the rows' first
+    ``--kv-rank`` columns, and the latent kernel."""
     from jax import lax
 
     from autodist_tpu.kernel.pallas import flash_decode as fd
@@ -341,6 +366,9 @@ def _main_decode(args):
     from autodist_tpu.telemetry.records import provenance
 
     H, D, B, L = args.heads, args.head_dim, args.slots, args.layers
+    # key/value heads of the cache, and the width of its values' array
+    kv_heads, v_dim = (1, 0) if args.latent else (H, D)
+    scale = D ** -0.5
     dtype = jnp.dtype(args.cache_dtype)
     fills = [f if f == "mixed" else float(f) for f in args.fill.split(",")]
     blocks = [int(b) for b in args.blocks.split(",")]
@@ -355,7 +383,7 @@ def _main_decode(args):
                 q, k, v = carry
                 for layer in range(L):
                     out, k, v = attend(q, k, v, layer, lens)
-                    q = q + out * 1e-3
+                    q = q.at[..., :out.shape[-1]].add(out * 1e-3)
                 return (q, k, v), None
             return lax.scan(one_pass, (q, k, v), None, length=args.reps)[0]
         return jax.jit(run, donate_argnums=(1, 2))
@@ -372,6 +400,12 @@ def _main_decode(args):
         return (time.perf_counter() - t0) / args.steps, k, v
 
     def composed(q, k, v, layer, lens):
+        if args.latent:
+            if not args.read_only:
+                k = write_token(k, layer, q[:, :, :1], lens)
+            out = cached_attention(q, k[layer], k[layer], lens,
+                                   dtype=dtype, scale=scale)
+            return out[..., :args.kv_rank], k, v
         if not args.read_only:
             k = write_token(k, layer, q, lens)
             v = write_token(v, layer, q, lens)
@@ -382,8 +416,8 @@ def _main_decode(args):
     for T in [int(s) for s in args.seqs.split(",")]:
         r = np.random.RandomState(0)
         q = jnp.asarray(r.randn(B, 1, H, D), dtype)
-        k = jnp.asarray(r.randn(L, B, H, T, D), dtype)
-        v = jnp.asarray(r.randn(L, B, H, T, D), dtype)
+        k = jnp.asarray(r.randn(L, B, kv_heads, T, D), dtype)
+        v = jnp.asarray(r.randn(L, B, kv_heads, T, v_dim), dtype)
         for fill in fills:
             if fill == "mixed":     # T/16 ... 0.8 T, evenly over the slots
                 lens = jnp.asarray(np.linspace(T / 16, 0.8 * T, B), jnp.int32)
@@ -391,12 +425,24 @@ def _main_decode(args):
                 lens = jnp.full((B,), max(int(T * fill) - 1, 0), jnp.int32)
             calls = args.reps * L
             t_ref, k, v = window_s(chained(composed), q, k, v, lens)
-            live = 2 * H * int(jnp.sum(lens + 1)) * D * dtype.itemsize
-            for bk in blocks:
+            live = kv_heads * int(jnp.sum(lens + 1)) * (D + v_dim) \
+                * dtype.itemsize
+            buffers = [int(n) for n in args.buffers.split(",") if n] \
+                if args.latent else []
+            for bk, nb in itertools.product(blocks, buffers or [None]):
                 if fd.decode_block_len(T, bk) != bk:
                     continue
+                if nb:      # read where the kernel's call is traced
+                    fd.LATENT_BUFFERS = nb
 
                 def fused(q, k, v, layer, lens, bk=bk):
+                    if args.latent:
+                        res = fd.flash_decode_attention_latent(
+                            q, k, layer, lens, kv_rank=args.kv_rank,
+                            scale=scale, dtype=dtype, block_k=bk,
+                            new_row=None if args.read_only
+                            else q[:, :, :1])
+                        return (res, k, v) if args.read_only else (*res, v)
                     kw = dict(dtype=dtype, block_k=bk,
                               heads_per_step=args.heads_per_step or None)
                     if args.read_only:
@@ -409,11 +455,18 @@ def _main_decode(args):
                     "metric": "flash_decode_crossover",
                     "kv_len": T, "fill": fill, "block": bk,
                     "slots": B, "heads": H, "head_dim": D, "layers": L,
+                    **({"kv_rank": args.kv_rank,
+                        "buffers": fd.LATENT_BUFFERS} if args.latent else {}),
                     "cache_dtype": dtype.name,
                     "composed_us": round(t_ref / calls * 1e6, 2),
                     "kernel_us": round(t_k / calls * 1e6, 2),
                     "kernel_live_gb_per_s": round(
                         live * calls / t_k / 1e9, 1),
+                    # what its walk reads: the live rows rounded to blocks
+                    "kernel_read_gb_per_s": round(
+                        live * calls / t_k / 1e9 * bk * int(jnp.sum(
+                            jnp.minimum(lens // bk + 1, T // bk)))
+                        / int(jnp.sum(lens + 1)), 1),
                     "value": round(t_ref / t_k, 4),
                     "unit": "ratio", "scored": True,
                     "provenance": provenance(),
@@ -427,7 +480,8 @@ def _main_decode(args):
     wins = sorted(T for T in {k[0] for k in best}
                   if all(v > 1.0 for (t, _), v in best.items() if t == T))
     print(json.dumps({
-        "summary": (f"the dense decode kernel wins at every fill from "
+        "summary": (f"the {'latent' if args.latent else 'dense'} decode "
+                    f"kernel wins at every fill from "
                     f"kv_len {wins[0]}" if wins
                     else "cached_attention wins somewhere at every "
                          "measured cache length"),

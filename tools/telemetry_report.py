@@ -35,12 +35,25 @@ _PRECISION_BITS = {"fp32": 32, "bf16": 16, "int8": 8}
 _KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                    "collective_matmul", "a2a_ring", "flash_attention",
                    "delta_step")
-# The recurrent state's decode step (serving/kv_cache.py DenseLayout
-# .advance_state): an engine whose stack has linear layers says which
-# way its decode advances their state — kernel/delta_step_elected = 1,
-# the fused kernel, or 0, the composed step — so the gauge comes with
-# the engine/state_bytes_per_slot gauge of such a stack, and only there.
-_STATE_KERNEL_GAUGE = "kernel/delta_step_elected"
+# Elections made where the kernel is called, reported as 1 (the fused
+# kernel) or 0 (the composed path) by the engine that makes them, and
+# only by it: gauge -> (that engine's own gauge, who it is, what 0 says).
+# kernel/delta_step_elected: how a decode step advances a stack's
+# recurrent state (serving/kv_cache.py DenseLayout.advance_state).
+# kernel/latent_decode_elected: how a decode step attends over cached
+# latent rows (LatentLayout.decode_attend) — the latent kernel over the
+# live blocks, which sets kernel/flash_decode_elected too (the kernel
+# slot's word), or write_token and cached_attention over whole lanes.
+_OBSERVED_ELECTIONS = {
+    "kernel/delta_step_elected": (
+        "engine/state_bytes_per_slot",
+        "only an engine that holds a recurrent state elects how to "
+        "advance it", "the composed step"),
+    "kernel/latent_decode_elected": (
+        "engine/latent_lane_rows",
+        "only an engine that caches latent rows elects how to attend "
+        "over them", "the composed attention"),
+}
 # Training attention's election (autodist_tpu/models/transformer.py
 # attend): every traced call advances one of the two counters, and a
 # call that takes the fused kernels sets kernel/flash_attention_elected.
@@ -86,8 +99,9 @@ _ROUTING_COUNTERS = ("moe/layer_steps", "moe/rows_routed", "moe/rows_held",
 # Latent rows read (autodist_tpu/serving/batcher.py): an engine whose
 # cached position is a latent-attention row advances
 # serve/latent_positions_read by every decode step's live positions x
-# layers, beside serve/kv_blocks_resident (steps x slots: such a lane is
-# one block) and under the engine/latent_lane_rows (a lane's positions)
+# layers, beside serve/kv_blocks_resident (steps x slots x the blocks of
+# a lane: one under the composed attention) and under the
+# engine/latent_lane_rows (a lane's positions)
 # and engine/cache_layers gauges.  More rows read than steps x slots x
 # max_len x layers hold means the count is not of the rows the steps
 # could read — --check fails it.
@@ -459,21 +473,20 @@ def check_schema(run_dir: str) -> list[str]:
         if isinstance(name, str) and name.startswith("kernel/") \
                 and name.endswith("_elected"):
             kname = name[len("kernel/"):-len("_elected")]
-            if kname not in _KERNEL_CHOICES:
-                problems.append(
-                    f"metrics.jsonl: {name} names an unregistered "
-                    f"kernel (have {sorted(_KERNEL_CHOICES)})")
-            elif name == _STATE_KERNEL_GAUGE:
+            if name in _OBSERVED_ELECTIONS:
+                engine_gauge, who, composed = _OBSERVED_ELECTIONS[name]
                 if rec.get("value") not in (0, 1):
                     problems.append(
                         f"metrics.jsonl: {name} = {rec.get('value')!r} — "
-                        "1 (the fused kernel) or 0 (the composed step)")
-                if "engine/state_bytes_per_slot" not in gauges:
+                        f"1 (the fused kernel) or 0 ({composed})")
+                if engine_gauge not in gauges:
                     problems.append(
                         f"metrics.jsonl: {name} without the "
-                        "engine/state_bytes_per_slot gauge — only an "
-                        "engine that holds a recurrent state elects how "
-                        "to advance it")
+                        f"{engine_gauge} gauge — {who}")
+            elif kname not in _KERNEL_CHOICES:
+                problems.append(
+                    f"metrics.jsonl: {name} names an unregistered "
+                    f"kernel (have {sorted(_KERNEL_CHOICES)})")
             elif rec.get("value") != 1:
                 problems.append(
                     f"metrics.jsonl: {name} = {rec.get('value')!r} — an "
@@ -553,8 +566,8 @@ def check_schema(run_dir: str) -> list[str]:
             if latent.get("value", 0) > windows * lane * layers:
                 problems.append(
                     f"metrics.jsonl: {_LATENT_COUNTER} = "
-                    f"{latent.get('value')!r} is over steps x slots "
-                    f"({_LATENT_BOUND[0]} = {windows!r}) x max_len "
+                    f"{latent.get('value')!r} is over steps x slots x "
+                    f"blocks ({_LATENT_BOUND[0]} = {windows!r}) x max_len "
                     f"({_LATENT_BOUND[1]} = {lane!r}) x layers "
                     f"({_LATENT_BOUND[2]} = {layers!r})")
 
