@@ -27,6 +27,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from autodist_tpu import const, telemetry
 from autodist_tpu.kernel.lowering import Lowered
+from autodist_tpu.telemetry import account
 from autodist_tpu.utils import logging
 
 
@@ -45,6 +46,7 @@ class DistributedRunner:
     def __init__(self, trainable, lowered: Lowered, *, rng: Optional[Any] = None,
                  ssp_worker: Optional[str] = None,
                  ssp_num_workers: Optional[int] = None):
+        t_init = account.constructing("runner")
         self.trainable = trainable
         self.lowered = lowered
         self.mesh = lowered.mesh
@@ -60,6 +62,7 @@ class DistributedRunner:
         self._host_step = 0
         self._scanned_fn = None   # built lazily by run_steps
         self._ssp = self._make_ssp_gate(ssp_worker, ssp_num_workers)
+        account.constructed(t_init)
 
     def _make_ssp_gate(self, worker: Optional[str],
                        num_workers: Optional[int]):

@@ -27,11 +27,21 @@ record per completed request — carrying the engine's ``kv_layout`` —
 ``--check``).  Paged engines additionally emit the
 ``serve/kv_blocks_free``/``serve/kv_blocks_used`` pool gauges on every
 reservation/release; a paged run missing them fails the schema gate.
+
+Every scheduler round accounts for itself: the ``serve/step`` span
+carries the round's ordinal and, in memory, where its time went
+(``decode_ms``, ``prefill_ms``, ``own_ms``), what it moved (``admitted``,
+``active``) and how many compile events fell in it; ``serve/round_ms``
+holds every round's length, ``serve/rounds`` counts them, and a round
+that ran slow against the batcher's last ``SLOW_ROUND_HISTORY`` rounds
+bumps ``serve/slow_rounds`` and leaves one ``kind="slow_round"`` record
+with its evidence (``docs/usage/observability.md``).
 """
 from __future__ import annotations
 
 import dataclasses
 import itertools
+import statistics
 import time
 from collections import deque
 from typing import Optional
@@ -39,6 +49,21 @@ from typing import Optional
 import numpy as np
 
 from autodist_tpu import telemetry
+from autodist_tpu.telemetry import account
+
+# The slow-round rule: a round is slow when its decode part exceeds
+# SLOW_ROUND_FACTOR x the median decode part of the batcher's last
+# SLOW_ROUND_HISTORY rounds, or when its own part (the round less its
+# decode and prefill: evict, admit's bookkeeping, distribute) exceeds its
+# median by that factor AND by SLOW_ROUND_OWN_MS.  The prefill part is
+# not judged: it varies with the rows admitted.  Nothing is judged until
+# SLOW_ROUND_MIN_HISTORY rounds are held, and a run keeps the evidence of
+# at most MAX_SLOW_ROUND_EVENTS rounds (the counter keeps counting).
+SLOW_ROUND_HISTORY = 64
+SLOW_ROUND_MIN_HISTORY = 8
+SLOW_ROUND_FACTOR = 1.25
+SLOW_ROUND_OWN_MS = 1.0
+MAX_SLOW_ROUND_EVENTS = 256
 
 
 class OverloadedError(RuntimeError):
@@ -138,6 +163,14 @@ class ContinuousBatcher:
         self._ids = itertools.count()
         self._draining = False
         self.completions: dict[str, Completion] = {}
+        # The rounds' account (`step`).  The history is the batcher's,
+        # not the recorder's: `telemetry.reset()` leaves it warm.
+        self._round = 0
+        self._admitted = self._active = 0
+        self._prefill_s = self._decode_s = 0.0
+        self._recent_decode_ms: deque[float] = deque(
+            maxlen=SLOW_ROUND_HISTORY)
+        self._recent_own_ms: deque[float] = deque(maxlen=SLOW_ROUND_HISTORY)
 
     # ------------------------------------------------------------------ #
     def submit(self, prompt, *, max_new_tokens: int = 16,
@@ -311,8 +344,6 @@ class ContinuousBatcher:
             hits = self.engine.reserve_slot(i, len(req.prompt),
                                             req.max_new_tokens,
                                             prompt=req.prompt) or 0
-            if hits:
-                telemetry.counter("serve/prefix_hit_blocks").inc(hits)
             prompts[i, :len(req.prompt)] = req.prompt
             p_lens[i] = len(req.prompt)
             admit[i] = True
@@ -341,6 +372,7 @@ class ContinuousBatcher:
             telemetry.gauge("serve/queue_depth").set(len(self._queue))
             raise
         t_first = time.perf_counter()
+        self._admitted, self._prefill_s = len(taken), t_first - now
         chunk = getattr(self.engine, "prefill_chunk", None)
         with telemetry.span("serve/distribute"):
             for i, req, hits in taken:
@@ -498,6 +530,7 @@ class ContinuousBatcher:
                 counts = np.where(active, K, 0)
                 proposed = accepted = np.zeros_like(counts)
         dt = time.perf_counter() - t0
+        self._active, self._decode_s = int(active.sum()), dt
         per_tok_ms = dt / max(int(np.max(counts)), 1) * 1e3
         with telemetry.span("serve/distribute"):
             for i, slot in enumerate(self._slots):
@@ -508,11 +541,6 @@ class ContinuousBatcher:
                                    for k in range(int(counts[i])))
                 slot.spec_proposed += int(proposed[i])
                 slot.spec_accepted += int(accepted[i])
-                if proposed[i]:
-                    telemetry.counter("serve/spec_proposed").inc(
-                        int(proposed[i]))
-                    telemetry.counter("serve/spec_accepted").inc(
-                        int(accepted[i]))
                 self._check_terminal(i)
                 # Only tokens the request actually keeps count: a window's
                 # over-decode past EOS/budget is discarded above, and the
@@ -520,16 +548,20 @@ class ContinuousBatcher:
                 # serve records the report aggregates.
                 kept = max(0, len(slot.tokens) - before)
                 slot.inter_token_ms.extend([per_tok_ms] * kept)
-                for _ in range(kept):
-                    telemetry.histogram("serve/inter_token_ms").observe(
-                        per_tok_ms)
+                telemetry.histogram("serve/inter_token_ms").observe(
+                    per_tok_ms, count=kept)
                 telemetry.counter("serve/tokens").inc(kept)
 
     # ------------------------------------------------------------------ #
     def step(self):
         """One scheduler round: expire deadlines, evict finished,
-        admit, decode."""
-        with telemetry.span("serve/step"):
+        admit, decode — and account for the round (module docstring)."""
+        self._round += 1
+        self._admitted = self._active = 0
+        self._prefill_s = self._decode_s = 0.0
+        with telemetry.span("serve/step", round=self._round) as span:
+            t0 = time.perf_counter()
+            compiles = account.compile_events()
             with telemetry.span("serve/evict"):
                 self._expire_slots()
                 for i, slot in enumerate(self._slots):
@@ -539,6 +571,51 @@ class ContinuousBatcher:
                 with telemetry.span("serve/admit"):
                     self._admit()
             self._decode_window()
+            if span is not telemetry.NULL_SPAN:
+                self._account_round(span, t0, compiles)
+
+    def _account_round(self, span, t0: float, compiles: int):
+        """Write the round's account into its span and instruments, and
+        leave a ``slow_round`` record if it ran slow (the rule is at the
+        module's top).  A sound round gathers nothing: the ``engine/*``
+        children are looked up only for a flagged one."""
+        decode_ms, prefill_ms = self._decode_s * 1e3, self._prefill_s * 1e3
+        own_ms = (time.perf_counter() - t0) * 1e3 - decode_ms - prefill_ms
+        slow = {}
+        if len(self._recent_own_ms) >= SLOW_ROUND_MIN_HISTORY:
+            held = statistics.median(self._recent_own_ms)
+            if own_ms > max(SLOW_ROUND_FACTOR * held,
+                            held + SLOW_ROUND_OWN_MS):
+                slow.update(median_own_ms=held, own_excess_ms=own_ms - held)
+        if self._active \
+                and len(self._recent_decode_ms) >= SLOW_ROUND_MIN_HISTORY:
+            held = statistics.median(self._recent_decode_ms)
+            if decode_ms > SLOW_ROUND_FACTOR * held:
+                slow.update(median_decode_ms=held,
+                            decode_excess_ms=decode_ms - held)
+        self._recent_own_ms.append(own_ms)
+        if self._active:
+            self._recent_decode_ms.append(decode_ms)
+        fields = dict(
+            admitted=self._admitted, active=self._active,
+            decode_ms=decode_ms, prefill_ms=prefill_ms,
+            compiles=account.compile_events() - compiles)
+        telemetry.counter("serve/rounds").inc()
+        if slow:
+            flagged = telemetry.counter("serve/slow_rounds")
+            flagged.inc()
+            if flagged.value <= MAX_SLOW_ROUND_EVENTS:
+                children: dict = {}
+                for ev in telemetry.get().spans_since(t0, "engine/"):
+                    children[ev["name"]] = children.get(ev["name"], 0.0) \
+                        + ev["dur"] * 1e-3
+                telemetry.record_event(
+                    "slow_round", round=self._round, own_ms=own_ms,
+                    children_ms=children, **fields, **slow)
+        # the clock again: what the evidence cost is the round's own time
+        round_ms = (time.perf_counter() - t0) * 1e3
+        span.set(own_ms=round_ms - decode_ms - prefill_ms, **fields)
+        telemetry.histogram("serve/round_ms").observe(round_ms)
 
     def run(self) -> dict[str, Completion]:
         """Drain the queue and every in-flight request; returns

@@ -42,6 +42,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from autodist_tpu import const, telemetry
 from autodist_tpu.serving import kv_cache
+from autodist_tpu.telemetry import account
 from autodist_tpu.utils.stack_room import FirstCallWithRoom
 from autodist_tpu.parallel.tensor import (normalize_comm_overlap, vocab_pad,
                                           vocab_parallel_embedding,
@@ -194,6 +195,7 @@ class ServingEngine:
                  speculative: Optional[int] = None,
                  draft_cfg=None, draft_params=None,
                  devices=None):
+        t_init = account.constructing("engine")
         from autodist_tpu.strategy.ir import (normalize_kernel,
                                               normalize_kv_layout,
                                               normalize_prefill_chunk,
@@ -566,6 +568,7 @@ class ServingEngine:
             # seam takes the fused kernel, 0 where the composed step
             telemetry.gauge("kernel/delta_step_elected").set(
                 int(self.kv.state_kernel(self.cache.state.ssm)))
+        account.constructed(t_init)
 
     def __setattr__(self, name, value):
         """Handing the engine one of its own methods back (a caller that

@@ -12,6 +12,7 @@ rebuilt process-wide).  Typical use::
     telemetry.counter("asyncps/push").inc()
     telemetry.record_step(step=3, duration_s=0.012, examples=32)
     telemetry.flush()        # trace.json / metrics.jsonl / manifest.json
+    telemetry.startup()      # the process's compile and start-up account
     telemetry.drift_report(strategy, cost_model, measured,
                            trainable=trainable)
 
@@ -19,6 +20,7 @@ Disabled entirely with ``AUTODIST_TPU_TELEMETRY=0`` (no files, shared
 no-op span/instrument singletons).  See ``docs/usage/observability.md``.
 """
 from autodist_tpu.telemetry import tracing
+from autodist_tpu.telemetry.account import startup, watch_compiles
 from autodist_tpu.telemetry.aggregate import (RollingWindow,
                                               TelemetryAggregator)
 from autodist_tpu.telemetry.core import (NULL_SPAN, Telemetry, configure,
@@ -35,7 +37,7 @@ from autodist_tpu.telemetry.tracing import (current_trace_id, mint_trace_id,
 __all__ = [
     "Telemetry", "get", "configure", "reset", "enabled", "span", "counter",
     "gauge", "histogram", "record_step", "record_event", "annotate",
-    "flush", "manifest", "SCOPES", "scope",
+    "flush", "manifest", "SCOPES", "scope", "startup", "watch_compiles",
     "summary", "drift_report", "provenance", "build_manifest",
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_SPAN", "NULL_INSTRUMENT",

@@ -22,23 +22,29 @@ Config plane (see :mod:`autodist_tpu.const`):
   files are ever written.  Default is ON (cheap: in-memory, bounded).
 * ``AUTODIST_TPU_TELEMETRY_DIR`` — flush destination (also settable via
   :func:`configure`); without a directory, telemetry stays in-memory.
+  A process that runs with the variable set also flushes once when it
+  exits, so a run that never calls :func:`flush` leaves its files.
 * ``AUTODIST_TPU_TELEMETRY_SAMPLE=N`` — keep every Nth per-step record.
 """
 from __future__ import annotations
 
+import atexit
 import json
 import os
 import threading
 import time
+from collections import deque
 from typing import Optional
 
 from autodist_tpu import const
-from autodist_tpu.telemetry import tracing
+from autodist_tpu.telemetry import account, tracing
 from autodist_tpu.telemetry.metrics import (NULL_INSTRUMENT, MetricsRegistry)
 
-# In-memory caps (the default-on-cheap contract): beyond them new spans /
-# step records are counted but not retained, so an unbounded training
-# loop cannot grow the process with observability data.
+# In-memory caps (the default-on-cheap contract): spans and step records
+# are held in rings of these lengths, so an unbounded loop cannot grow
+# the process with observability data and a service that has run for
+# hours still holds its newest spans — the ones an operator looking for
+# a stall wants.  What fell out of a ring is counted as dropped.
 MAX_SPANS = 20000
 MAX_STEP_RECORDS = 100000
 
@@ -132,9 +138,9 @@ class Telemetry:
         self.registry = MetricsRegistry()
         self._lock = threading.Lock()
         self._local = threading.local()
-        self._spans: list[dict] = []
+        self._spans: deque[dict] = deque(maxlen=MAX_SPANS)
         self._spans_dropped = 0
-        self._steps: list[dict] = []
+        self._steps: deque[dict] = deque(maxlen=MAX_STEP_RECORDS)
         self._steps_dropped = 0
         self._steps_seen = 0
         self._annotations: dict = {}
@@ -170,10 +176,26 @@ class Telemetry:
         if depth:
             event.setdefault("args", {}).update(depth=depth, parent=parent)
         with self._lock:
-            if len(self._spans) < MAX_SPANS:
-                self._spans.append(event)
-            else:
+            if len(self._spans) == MAX_SPANS:
                 self._spans_dropped += 1
+            self._spans.append(event)
+
+    def spans_since(self, t_perf: float, prefix: str = "") -> list[dict]:
+        """The held spans that began at or after ``t_perf`` (a
+        ``time.perf_counter`` reading) and whose name starts with
+        ``prefix``, newest first: a walk back from the ring's tail that
+        stops at the first span that ended before ``t_perf``, for a
+        caller that wants the children of a region it has just timed."""
+        since_us = self._epoch_wall_us + (t_perf - self._epoch_perf) * 1e6
+        out = []
+        with self._lock:
+            for event in reversed(self._spans):
+                if event["ts"] + event["dur"] < since_us:
+                    break
+                if event["ts"] >= since_us \
+                        and event["name"].startswith(prefix):
+                    out.append(event)
+        return out
 
     # ---------------- metrics ----------------------------------------- #
     def counter(self, name: str):
@@ -204,9 +226,6 @@ class Telemetry:
             self._steps_seen += 1
             if self.sample > 1 and (self._steps_seen - 1) % self.sample:
                 return False
-            if len(self._steps) >= MAX_STEP_RECORDS:
-                self._steps_dropped += 1
-                return False
             rec = {"kind": "step", "step": int(step),
                    "duration_ms": float(duration_s) * 1e3}
             if steps != 1:
@@ -215,8 +234,15 @@ class Telemetry:
                 rec["examples"] = int(examples)
             for k, v in extra.items():
                 rec[k] = _jsonable(v)
-            self._steps.append(rec)
+            self._append_record(rec)
         return True
+
+    def _append_record(self, rec: dict) -> None:
+        """Under the lock: the newest record in, the oldest out of a
+        full ring and counted."""
+        if len(self._steps) == MAX_STEP_RECORDS:
+            self._steps_dropped += 1
+        self._steps.append(rec)
 
     def record_event(self, kind: str, **fields) -> bool:
         """One typed event record on the JSONL sink (``kind`` other than
@@ -242,10 +268,7 @@ class Telemetry:
             if tid is not None:
                 rec["trace_id"] = tid
         with self._lock:
-            if len(self._steps) >= MAX_STEP_RECORDS:
-                self._steps_dropped += 1
-                return False
-            self._steps.append(rec)
+            self._append_record(rec)
         return True
 
     def step_records(self) -> list[dict]:
@@ -278,6 +301,15 @@ class Telemetry:
                     "sample": self.sample}
         return records.build_manifest(annotations=ann, telemetry=book)
 
+    def startup_record(self) -> dict:
+        """The process's account (:func:`account.startup`) as a record,
+        with ``run_started_s``: how long after the package's import this
+        recorder was created — the last ``reset()``, so what the account
+        holds beyond this run's ``compile/*`` instruments happened in
+        those seconds."""
+        return dict(account.startup(), kind="startup",
+                    run_started_s=self._epoch_perf - account.T_IMPORT)
+
     # ---------------- sinks ------------------------------------------- #
     def chrome_trace(self) -> dict:
         with self._lock:
@@ -299,8 +331,9 @@ class Telemetry:
         """Write every sink and return ``{artifact: path}``.
 
         Artifacts: ``trace.json`` (chrome trace), ``metrics.jsonl``
-        (per-step records then instrument snapshots, one object per
-        line), ``manifest.json``, ``summary.txt``.  A no-op (returns
+        (per-step records, instrument snapshots, then the process's
+        ``kind="startup"`` account, one object per line),
+        ``manifest.json``, ``summary.txt``.  A no-op (returns
         ``{}``) when disabled or when no directory is configured — the
         disabled path never writes files.
         """
@@ -323,6 +356,7 @@ class Telemetry:
                 f.write(json.dumps(rec) + "\n")
             for snap in self.registry.snapshot():
                 f.write(json.dumps(snap) + "\n")
+            f.write(json.dumps(self.startup_record()) + "\n")
         paths["metrics"] = jsonl_path
 
         manifest_path = os.path.join(out_dir, "manifest.json")
@@ -375,6 +409,19 @@ def get() -> Telemetry:
             if _singleton is None:
                 _singleton = Telemetry()
     return _singleton
+
+
+def _flush_at_exit() -> None:
+    """Leave the live recorder's files where ``AUTODIST_TPU_TELEMETRY_DIR``
+    says, if it says anything: a directory given in code
+    (:func:`configure`) belongs to a caller who flushes when they mean
+    to."""
+    out_dir = const.ENV.AUTODIST_TPU_TELEMETRY_DIR.val
+    if out_dir and _singleton is not None:
+        _singleton.flush(out_dir)
+
+
+atexit.register(_flush_at_exit)
 
 
 def configure(out_dir: Optional[str] = None, sample: Optional[int] = None,

@@ -81,15 +81,20 @@ class Histogram:
         self._max: Optional[float] = None
         self._lock = threading.Lock()
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, count: int = 1) -> None:
+        """``count`` observations of ``value`` (a fused window's tokens
+        share one latency: one call, not one a token)."""
         value = float(value)
+        if count < 1:
+            return
         with self._lock:
-            self._count += 1
-            self._sum += value
+            self._count += count
+            self._sum += value * count
             self._min = value if self._min is None else min(self._min, value)
             self._max = value if self._max is None else max(self._max, value)
-            if len(self._values) < HISTOGRAM_CAP:
-                self._values.append(value)
+            room = HISTOGRAM_CAP - len(self._values)
+            if room > 0:
+                self._values.extend([value] * min(count, room))
 
     @property
     def count(self) -> int:
@@ -131,7 +136,7 @@ class NullInstrument:
     def set(self, value: float) -> None:
         pass
 
-    def observe(self, value: float) -> None:
+    def observe(self, value: float, count: int = 1) -> None:
         pass
 
     def percentile(self, q: float) -> None:

@@ -162,8 +162,30 @@ _SCALE_TRIGGERS = {"queue_depth": "autoscale/queue_depth",
 # drift.json report.
 _DRIFT_KEYS = {"kind", "term", "ratio", "threshold", "step",
                "predicted", "measured", "direction"}
+# The batcher's round account (autodist_tpu/serving/batcher.py): every
+# `serve/step` span names its `round` and says where the round's time
+# went; `serve/rounds` counts them, and a round flagged slow leaves one
+# `kind="slow_round"` record with the medians it was held against and
+# the durations of its engine/* children.
+_ROUND_KEYS = ("round", "admitted", "active", "decode_ms", "prefill_ms",
+               "own_ms", "compiles")
+_SLOW_ROUND_KEYS = {"kind", *_ROUND_KEYS, "children_ms"}
+_SLOW_ROUND_HELD = ("median_decode_ms", "median_own_ms")
+# A round's parts are read off the clock just before its span closes:
+# they may not exceed the span, and over a run fall short of it by no
+# more than this much a round and this share of the whole.
+_ROUND_SLACK_MS = 0.05
+_SLOW_ROUNDS_SHOWN = 20     # rows of the report's table; the file has all
+_ROUND_SLACK_SHARE = 1e-3
+# The process's compile and start-up account
+# (autodist_tpu/telemetry/account.py), the last line a flush writes.
+_STARTUP_KEYS = {"kind", "since_import_s", "run_started_s", "trace_s",
+                 "lower_s", "backend_s", "cache_retrieval_s", "cache_hits",
+                 "cache_misses", "compile_events", "engine_s", "runner_s",
+                 "engine_built_s", "runner_built_s"}
 _KINDS = ("step", "serve", "reshard", "fault", "dispatch", "handoff",
-          "scale", "drift", "counter", "gauge", "histogram")
+          "scale", "drift", "slow_round", "startup", "counter", "gauge",
+          "histogram")
 
 
 def _event_trace_ids(ev: dict):
@@ -193,6 +215,151 @@ def load_jsonl(path: str) -> list[dict]:
                 raise ValueError(f"{path}:{i + 1}: not an object")
             records.append(rec)
     return records
+
+
+def _pct(values, q) -> float:
+    return float(np.percentile(np.asarray(values, float), q))
+
+
+def rounds_summary(events: list, records: list):
+    """The batcher's rounds of one run, from its chrome-trace events and
+    its metrics records: how many, how long (``round_ms`` p50 / p95 /
+    max), where the time went (medians of ``decode_ms``, of
+    ``prefill_ms`` a row admitted, of ``own_ms``), the compile events
+    that fell inside rounds, and the rounds flagged slow with the excess
+    over the medians they were held against.  ``None`` for a run whose
+    ``serve/step`` spans carry no account (or that served nothing)."""
+    steps = [e for e in events if e.get("name") == "serve/step"
+             and "decode_ms" in (e.get("args") or {})]
+    if not steps:
+        return None
+    args = [e["args"] for e in steps]
+    dur = [float(e["dur"]) * 1e-3 for e in steps]
+    decoded = [a["decode_ms"] for a in args if a["active"]]
+    a_row = [a["prefill_ms"] / a["admitted"] for a in args if a["admitted"]]
+    slow = [r for r in records if r.get("kind") == "slow_round"]
+    counted = {r["name"]: r["value"] for r in records
+               if r.get("kind") == "counter"
+               and r.get("name") in ("serve/rounds", "serve/slow_rounds")}
+    return {
+        "rounds": len(steps),
+        "round_ms_p50": _pct(dur, 50), "round_ms_p95": _pct(dur, 95),
+        "round_ms_max": max(dur),
+        "decode_ms_p50": _pct(decoded, 50) if decoded else None,
+        "prefill_ms_a_row_p50": _pct(a_row, 50) if a_row else None,
+        "own_ms_p50": _pct([a["own_ms"] for a in args], 50),
+        "rows_admitted": sum(a["admitted"] for a in args),
+        "compiles": sum(a["compiles"] for a in args),
+        "slow_rounds": counted.get("serve/slow_rounds", 0),
+        "slow_excess_ms": sum(r.get("decode_excess_ms", 0.0)
+                              + r.get("own_excess_ms", 0.0) for r in slow),
+        "slow": slow,
+    }
+
+
+def rounds_line(summary: dict) -> str:
+    """``rounds_summary`` on one line (the benchmark's ``[rounds]``)."""
+    return "[rounds] " + " ".join(
+        f"{k}={_fmt(v, 6)}" for k, v in summary.items() if k != "slow")
+
+
+def startup_summary(records: list):
+    """What the process did before this run's recorder was created (a
+    benchmark's window): the ``kind="startup"`` account less the run's
+    own ``compile/*`` instruments.  ``programs_s`` is the seconds jax
+    spent on programs there — tracing, lowering and the backend's
+    compile-or-retrieve, of which ``cache_retrieval_s`` is the part
+    that came from the compilation cache.  ``None`` without the
+    account."""
+    account = next((r for r in records if r.get("kind") == "startup"), None)
+    if account is None:
+        return None
+    in_run = {r["name"]: r.get("sum" if r["kind"] == "histogram"
+                               else "value", 0)
+              for r in records if r.get("kind") in ("histogram", "counter")
+              and str(r.get("name", "")).startswith("compile/")}
+    before = {k: account[k] - in_run.get("compile/" + k, 0)
+              for k in ("trace_s", "lower_s", "backend_s",
+                        "cache_retrieval_s", "cache_hits", "cache_misses")}
+    return {"import_to_run_s": account["run_started_s"],
+            "engine_s": account["engine_s"],
+            "runner_s": account["runner_s"],
+            "programs_s": before["trace_s"] + before["lower_s"]
+            + before["backend_s"], **before,
+            "compile_events_in_run": sum(
+                r.get("count", 0) for r in records
+                if r.get("kind") == "histogram" and r.get("name") in (
+                    "compile/trace_s", "compile/lower_s",
+                    "compile/backend_s"))}
+
+
+def startup_line(summary: dict, **more) -> str:
+    """``startup_summary`` on one line (the benchmark's ``[startup]``);
+    ``more`` is what only the caller can know (the seconds before the
+    package was imported)."""
+    return "[startup] " + " ".join(
+        f"{k}={_fmt(v, 6)}" for k, v in {**more, **summary}.items())
+
+
+def _check_rounds(records: list, trace_events: list,
+                  spans_dropped) -> list[str]:
+    """The round account's gates: the counter and the spans agree, a
+    round's parts add up to its span, and a slow round's record names
+    what it was held against."""
+    problems = []
+    counters = {r.get("name"): r.get("value", 0) for r in records
+                if r.get("kind") == "counter"}
+    steps = [e for e in trace_events if e.get("name") == "serve/step"
+             and "round" in (e.get("args") or {})]
+    for ev in steps:
+        missing = [k for k in _ROUND_KEYS if k not in ev["args"]]
+        if missing:
+            problems.append(
+                f"trace.json: serve/step round {ev['args']['round']!r} "
+                f"lacks {missing} — a round's account comes whole")
+            return problems
+    if steps and not spans_dropped \
+            and counters.get("serve/rounds") != len(steps):
+        problems.append(
+            f"metrics.jsonl: serve/rounds = "
+            f"{counters.get('serve/rounds')!r} but trace.json holds "
+            f"{len(steps)} serve/step span(s) and none was dropped")
+    short = whole = 0.0
+    for ev in steps:
+        a = ev["args"]
+        parts = a["decode_ms"] + a["prefill_ms"] + a["own_ms"]
+        dur = float(ev["dur"]) * 1e-3
+        if parts > dur + 1e-3:
+            problems.append(
+                f"trace.json: round {a['round']!r}: decode_ms + prefill_ms"
+                f" + own_ms = {parts:.4f} exceeds the span's {dur:.4f} ms")
+            break
+        short, whole = short + dur - parts, whole + dur
+    if short > _ROUND_SLACK_MS * len(steps) + _ROUND_SLACK_SHARE * whole:
+        problems.append(
+            f"trace.json: the rounds' parts fall {short:.3f} ms short of "
+            f"their {len(steps)} spans' {whole:.3f} ms — decode_ms + "
+            "prefill_ms + own_ms is the span's duration")
+    slow = [r for r in records if r.get("kind") == "slow_round"]
+    for r in slow:
+        missing = _SLOW_ROUND_KEYS - set(r)
+        if missing or not any(k in r for k in _SLOW_ROUND_HELD):
+            problems.append(
+                f"metrics.jsonl: slow_round record of round "
+                f"{r.get('round')!r} lacks {sorted(missing)} or the median "
+                "it was held against")
+            break
+    if len(slow) > counters.get("serve/slow_rounds", 0):
+        problems.append(
+            f"metrics.jsonl: {len(slow)} slow_round record(s) but "
+            f"serve/slow_rounds = {counters.get('serve/slow_rounds')!r} — "
+            "every record bumps the counter")
+    for r in records:
+        if r.get("kind") == "startup" and _STARTUP_KEYS - set(r):
+            problems.append(
+                f"metrics.jsonl: startup record lacks "
+                f"{sorted(_STARTUP_KEYS - set(r))}")
+    return problems
 
 
 def check_schema(run_dir: str) -> list[str]:
@@ -344,6 +511,8 @@ def check_schema(run_dir: str) -> list[str]:
                         f"{rec['term']!r} with ratio {rec['ratio']} "
                         f"INSIDE its ±{rec['threshold']} band — a "
                         "breach record that never breached")
+        elif kind in ("slow_round", "startup"):
+            pass            # held whole by _check_rounds, below
         elif "name" not in rec:
             problems.append(f"metrics.jsonl:{i + 1}: {kind} without name")
         elif kind == "histogram" and "count" not in rec:
@@ -531,9 +700,19 @@ def check_schema(run_dir: str) -> list[str]:
                 "without their `rows` argument in a run that counts "
                 "prefill rows")
 
+    # An engine sets its gauges once, where it is built.  One built before
+    # this run's recorder was created (a `telemetry.reset()` since: a
+    # benchmark's window) left them in the recorder that went, so the
+    # rules that hold counters against those gauges hold the counters
+    # alone.
+    account = next((r for r in records if r.get("kind") == "startup"), {})
+    engine_predates_run = 0 < account.get("engine_built_s", 0) \
+        < account.get("run_started_s", 0)
     routing = [counters.get(n) for n in _ROUTING_COUNTERS]
     if any(c is not None for c in routing):
         held_g = gauges.get("engine/experts_held")
+        if held_g is None and engine_predates_run:
+            held_g = {"value": float("inf")}
         if any(c is None for c in routing) or held_g is None:
             problems.append(
                 f"metrics.jsonl: {', '.join(_ROUTING_COUNTERS)} and the "
@@ -556,6 +735,8 @@ def check_schema(run_dir: str) -> list[str]:
     if latent is not None:
         held = [counters.get(_LATENT_BOUND[0]),
                 *(gauges.get(n) for n in _LATENT_BOUND[1:])]
+        if engine_predates_run and held[0] is not None:
+            held = [r or {"value": float("inf")} for r in held]
         if any(r is None for r in held):
             problems.append(
                 f"metrics.jsonl: {_LATENT_COUNTER}, "
@@ -595,12 +776,15 @@ def check_schema(run_dir: str) -> list[str]:
             break
 
     manifest = os.path.join(run_dir, "manifest.json")
+    spans_dropped = 0
     if os.path.exists(manifest):
         try:
             with open(manifest) as f:
                 m = json.load(f)
             if m.get("kind") != "manifest" or "provenance" not in m:
                 problems.append("manifest.json: kind/provenance missing")
+            spans_dropped = (m.get("telemetry") or {}).get(
+                "spans_dropped", 0)
             declared = (m.get("run") or {}).get("collective_precision")
             if isinstance(declared, dict):
                 # A run annotated with a precision policy must carry the
@@ -651,6 +835,7 @@ def check_schema(run_dir: str) -> list[str]:
                             f"with the declared kernel election")
         except ValueError as e:
             problems.append(f"manifest.json: invalid ({e})")
+    problems += _check_rounds(records, trace_events, spans_dropped)
 
     drift = os.path.join(run_dir, "drift.json")
     if os.path.exists(drift):
@@ -786,6 +971,60 @@ def _trace_sections(run_dir: str, records: list,
     return lines
 
 
+def _rounds_section(run_dir: str, records: list) -> list:
+    """The batcher's rounds and the process's start-up, where the run
+    recorded them: the table of ``rounds_summary``, each slow round with
+    its evidence, and ``startup_summary``."""
+    events = []
+    path = os.path.join(run_dir, "trace.json")
+    if os.path.exists(path):
+        try:
+            with open(path) as f:
+                events = json.load(f)["traceEvents"]
+        except (ValueError, KeyError, TypeError):
+            pass
+    lines = []
+    got = rounds_summary(events, records)
+    if got is not None:
+        lines += ["## Rounds", "",
+                  "| rounds | round ms p50 | p95 | max | decode ms p50 | "
+                  "prefill ms a row p50 | own ms p50 | rows admitted | "
+                  "compile events | slow rounds | slow excess ms |",
+                  "|---|---|---|---|---|---|---|---|---|---|---|",
+                  "| " + " | ".join(_fmt(got[k], 4) for k in (
+                      "rounds", "round_ms_p50", "round_ms_p95",
+                      "round_ms_max", "decode_ms_p50",
+                      "prefill_ms_a_row_p50", "own_ms_p50",
+                      "rows_admitted", "compiles", "slow_rounds",
+                      "slow_excess_ms")) + " |", ""]
+        if got["slow"]:
+            lines += ["| slow round | decode ms (median) | own ms (median) "
+                      "| prefill ms | compiles | engine/* children ms |",
+                      "|---|---|---|---|---|---|"]
+            for r in got["slow"][:_SLOW_ROUNDS_SHOWN]:
+                kids = ", ".join(f"{k} {_fmt(v, 4)}" for k, v in
+                                 sorted(r.get("children_ms", {}).items()))
+                lines.append(
+                    f"| {r.get('round')} | {_fmt(r.get('decode_ms'), 4)} "
+                    f"({_fmt(r.get('median_decode_ms'), 4)}) "
+                    f"| {_fmt(r.get('own_ms'), 4)} "
+                    f"({_fmt(r.get('median_own_ms'), 4)}) "
+                    f"| {_fmt(r.get('prefill_ms'), 4)} "
+                    f"| {r.get('compiles')} | {kids or '—'} |")
+            if len(got["slow"]) > _SLOW_ROUNDS_SHOWN:
+                lines.append(f"| … {len(got['slow']) - _SLOW_ROUNDS_SHOWN} "
+                             "more in metrics.jsonl | | | | | |")
+            lines.append("")
+    before = startup_summary(records)
+    if before is not None:
+        lines += ["## Start-up (before this run's recorder was created)",
+                  "", "| " + " | ".join(before) + " |",
+                  "|" + "---|" * len(before),
+                  "| " + " | ".join(_fmt(v, 4) for v in before.values())
+                  + " |", ""]
+    return lines
+
+
 def render(run_dir: str, trace_filter=None) -> str:
     """The markdown report for one flushed run directory."""
     records = load_jsonl(os.path.join(run_dir, "metrics.jsonl"))
@@ -896,6 +1135,8 @@ def render(run_dir: str, trace_filter=None) -> str:
                       f"| {_fmt(float(np.percentile(chunked, 50)) if chunked else None)} "
                       f"| {proposed} | {accepted} "
                       f"| {_fmt(acceptance)} |", ""]
+
+    lines += _rounds_section(run_dir, records)
 
     if dispatches:
         # The fleet section: routing decisions by reason, the hedge
