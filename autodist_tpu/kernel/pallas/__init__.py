@@ -34,6 +34,10 @@ shape-dependent too):
   manager's stacked float32 recurrent state, each ``(slot, head)`` tile
   read once and written back in place; elected where it is called, from
   what the call observes (:data:`OBSERVED_KERNELS`).
+* :func:`~autodist_tpu.kernel.pallas.grouped_matmul.grouped_matmul` — a
+  decode step's sorted (row, expert) pairs through the held experts that
+  have rows, gate/up, SiLU and down in one call, each expert's weights
+  streamed once; elected where it is called too.
 
 Every kernel runs under the Pallas interpreter off-TPU (the simulated
 CPU mesh the test harness uses), so each carries a CPU golden pinned
@@ -50,7 +54,7 @@ from __future__ import annotations
 # normalize_kernel re-exports this; kernel code stays IR-agnostic).
 KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                   "collective_matmul", "a2a_ring", "flash_attention",
-                  "delta_step")
+                  "delta_step", "grouped_matmul")
 
 # Kernels that change the *training* program (the pipeline and expert
 # lowerings honor them); flash_decode/flash_prefill are serving-side
@@ -60,14 +64,15 @@ TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
 
 # Kernels elected where they are called, from what the call observes
 # (``models.transformer.attend``; ``serving.kv_cache.DenseLayout
-# .advance_state``).  The kernel slot says nothing about them unless
+# .advance_state``; ``parallel.moe.routed_experts``).  The kernel slot says nothing about them unless
 # someone overrides: ``True`` takes the kernel wherever it can run,
 # ``False`` forbids it (the composed path, for a comparison) — the one
 # ``False`` the canonical slot keeps.  The word reaches ``attend``
 # through ``parallel.tensor.kernel_scope``, which the collective, GSPMD
-# and pipeline lowerings open around the model they trace, and the
-# serving layout from the engine that builds it.
-OBSERVED_KERNELS = ("flash_attention", "delta_step")
+# and pipeline lowerings open around the model they trace, the serving
+# layout from the engine that builds it, and the routed layer through
+# the engine's ``_ffn``.
+OBSERVED_KERNELS = ("flash_attention", "delta_step", "grouped_matmul")
 
 # Op-metadata marker prefix: `with jax.named_scope(kernel_marker(name))`
 # around a pallas_call stamps every emitted op's `op_name` metadata, and

@@ -495,10 +495,12 @@ def _swiglu(h, wi, wo):
     return (jax.nn.silu(gate) * up) @ wo
 
 
-def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None):
+def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None,
+               kernel=None):
     """The routed block on normed rows ``h`` ``[B, S, H]``: ``(y, stats)``
     — the held experts' part of the routed sum
-    (:func:`autodist_tpu.parallel.moe.routed_experts`) plus the shared
+    (:func:`autodist_tpu.parallel.moe.routed_experts`, which ``kernel``,
+    the kernel slot's word on ``grouped_matmul``, is for) plus the shared
     expert (behind its sigmoid gate, where the block has one), which
     every device computes."""
     from autodist_tpu.parallel.moe import routed_experts
@@ -512,7 +514,7 @@ def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None):
                 moe_params["experts"]["wi"], moe_params["experts"]["wo"],
                 top_k=spec.top_k, first_expert=spec.first_expert,
                 valid=None if valid is None else valid.reshape(-1),
-                renormalise=spec.renormalise)
+                renormalise=spec.renormalise, kernel=kernel)
         if spec.shared_width:
             sh = moe_params["shared"]
             gate = None
@@ -529,20 +531,20 @@ def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None):
 
 
 def ffn_residual(cfg: TransformerConfig, chunk, x, model_axis,
-                 comm_overlap=None, valid=None, tally=None):
+                 comm_overlap=None, valid=None, tally=None, kernel=None):
     """The feed-forward sub-block with its residual add and norm(s).  A
     routed layer (its chunk holds ``moe``: every layer of a routed
     stack but its leading dense ones) goes to :func:`routed_ffn`:
     ``valid`` marks the rows that are some request's (the others choose
-    no expert), and ``tally``, a list, is handed the layer's ``[rows_held,
-    experts_hit]``."""
+    no expert), ``tally``, a list, is handed the layer's ``[rows_held,
+    experts_hit]``, and ``kernel`` is :func:`routed_ffn`'s."""
     from autodist_tpu.parallel.tensor import column_parallel, row_parallel
 
     spec, dtype = cfg.block, cfg.dtype
     h = (block_norm(cfg, x, chunk["ln_mlp_in"])
          if spec.norm_placement in ("sandwich", "pre") else x)
     if "moe" in chunk:
-        m, stats = routed_ffn(cfg, chunk["moe"], h, valid)
+        m, stats = routed_ffn(cfg, chunk["moe"], h, valid, kernel)
         if tally is not None:
             tally.append(stats)
         return _residual(cfg, x, m, chunk, "ln_mlp")

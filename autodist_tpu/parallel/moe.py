@@ -97,9 +97,22 @@ def _pairs_bound(pairs: int, held: int, experts: int) -> int:
     return bound if bound <= pairs // 2 else pairs
 
 
+def ragged_products(x, expert_wi, expert_wo, sizes):
+    """``wo_e(silu(gate_e x) * up_e x)`` of sorted rows ``x`` ``[P, H]``,
+    expert ``e``'s the ``sizes[e]`` after its predecessors', through two
+    :func:`jax.lax.ragged_dot`: ``[P, H]`` float32, whatever the matmul
+    leaves in the rows past the groups."""
+    h = lax.ragged_dot(x, expert_wi.astype(x.dtype), sizes,
+                       preferred_element_type=jnp.float32)
+    gate, up = jnp.split(h, 2, axis=-1)
+    h = (jax.nn.silu(gate) * up).astype(x.dtype)
+    return lax.ragged_dot(h, expert_wo.astype(x.dtype), sizes,
+                          preferred_element_type=jnp.float32)
+
+
 def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
                    first_expert: int = 0, valid=None,
-                   renormalise: bool = True):
+                   renormalise: bool = True, kernel=None):
     """The held experts' part of a routed FFN, without capacity.
 
     ``x``: ``[R, H]`` rows; ``router_w``: ``[H, E]`` over all ``E``
@@ -113,17 +126,29 @@ def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
     pairs that landed here and the held experts with at least one row.
 
     The ``R * top_k`` (row, expert) pairs are sorted by held expert, the
-    ones that are not held last, and the experts run over their groups
-    with :func:`jax.lax.ragged_dot`: on the TPU a grouped matmul that
-    visits the groups that have rows, so an expert nobody chose is not
-    read.  Each row's terms come back through one matmul with the
-    pairs' weights laid out by row (a scatter-add is a serial loop on
-    the TPU).  Nothing is dropped: where the held pairs fit
-    :func:`_pairs_bound` only that many sorted pairs are worked through,
-    and all of them where they do not (``lax.cond``: skewed routing
-    costs time, never a row).  ``valid`` (``[R]`` bool): rows that are
-    padding or belong to no request choose nothing.  ``renormalise`` is
-    :func:`route_top_k`'s."""
+    ones that are not held last, and the experts run over their groups:
+    a grouped matmul that visits the groups that have rows, so an expert
+    nobody chose is not read.  Which one follows what the call observes
+    (:func:`~autodist_tpu.kernel.pallas.grouped_matmul
+    .grouped_matmul_elected`): a decode step's few pairs of bf16 rows on
+    a TPU go through this repo's kernel, gate/up, SiLU and down in one
+    call that reads each hit expert once; anything else — a prefill's
+    thousands of pairs, another type or width, the CPU — through two
+    :func:`jax.lax.ragged_dot` (:func:`ragged_products`).  ``kernel`` is
+    the kernel slot's word on ``grouped_matmul``: ``True`` takes the
+    kernel wherever it can run, ``False`` forbids it.  Each row's terms
+    come back through one matmul with the pairs' weights laid out by row
+    (a scatter-add is a serial loop on the TPU).  Nothing is dropped:
+    the kernel works from the groups' sizes and takes every pair; the
+    composed products work through :func:`_pairs_bound` sorted pairs
+    where the held pairs fit, and through all of them where they do not
+    (``lax.cond``: skewed routing costs time, never a row).  ``valid``
+    (``[R]`` bool): rows that are padding or belong to no request choose
+    nothing.  ``renormalise`` is :func:`route_top_k`'s."""
+    from autodist_tpu import telemetry
+    from autodist_tpu.kernel.pallas.grouped_matmul import (
+        grouped_matmul, grouped_matmul_elected)
+
     R, H = x.shape
     E_held = expert_wi.shape[0]
     experts, weights = route_top_k(x, router_w, top_k, renormalise)
@@ -137,16 +162,11 @@ def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
         0, dtype=jnp.int32)                              # [E_held]
     weight = jnp.where(held, weights, 0.0).reshape(-1)
 
-    def experts_over(pairs: int):
+    def experts_over(pairs: int, products=ragged_products):
         """The first ``pairs`` sorted pairs through the held experts."""
         first = order[:pairs]
         rows = first // top_k                            # pair -> its row
-        h = lax.ragged_dot(x[rows], expert_wi.astype(x.dtype), sizes,
-                           preferred_element_type=jnp.float32)
-        gate, up = jnp.split(h, 2, axis=-1)
-        h = (jax.nn.silu(gate) * up).astype(x.dtype)
-        y = lax.ragged_dot(h, expert_wo.astype(x.dtype), sizes,
-                           preferred_element_type=jnp.float32)
+        y = products(x[rows], expert_wi, expert_wo, sizes)
         # rows past the groups are nobody's, whatever the matmul left
         w = weight[first]
         y = jnp.where((w > 0)[:, None], y * w[:, None], 0.0)
@@ -157,7 +177,14 @@ def routed_experts(x, router_w, expert_wi, expert_wo, *, top_k: int,
     total = R * top_k
     bound = _pairs_bound(total, E_held, router_w.shape[-1])
     n_held = sizes.sum()
-    if bound < total:
+    fused = expert_wi.dtype == expert_wo.dtype == x.dtype \
+        and grouped_matmul_elected(kernel, total, H, expert_wo.shape[1],
+                                   x.dtype)
+    if fused:
+        if isinstance(x, jax.core.Tracer):  # count programs, not init
+            telemetry.counter("kernel/grouped_matmul_calls").inc()
+        out = experts_over(total, grouped_matmul)
+    elif bound < total:
         out = lax.cond(n_held <= bound, lambda: experts_over(bound),
                        lambda: experts_over(total))
     else:

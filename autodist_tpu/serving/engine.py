@@ -513,6 +513,22 @@ class ServingEngine:
                 held["state_bytes_per_slot"])
         if spec.moe is not None:
             telemetry.gauge("engine/experts_held").set(spec.moe.experts_held)
+            # a decode step's routed layer: 1 this repo's grouped-matmul
+            # kernel over the experts hit, 0 two ragged_dot (what the
+            # call in parallel.moe.routed_experts will observe: the last
+            # layer of a routed stack is a routed one)
+            from autodist_tpu.kernel.pallas.grouped_matmul import \
+                grouped_matmul_elected
+            from autodist_tpu.models.pipeline_lm import layer_chunk
+            experts = jax.eval_shape(
+                lambda stages: layer_chunk(cfg, stages, cfg.num_layers - 1)
+                ["moe"]["experts"], self.params["stages"])
+            telemetry.gauge("kernel/grouped_matmul_elected").set(int(
+                experts["wi"].dtype == experts["wo"].dtype == cfg.dtype
+                and grouped_matmul_elected(
+                    self.kernel.get("grouped_matmul"),
+                    self.num_slots * spec.moe.top_k, cfg.hidden_size,
+                    spec.moe.expert_width, cfg.dtype)))
         if spec.latent is not None:
             telemetry.gauge("engine/latent_lane_rows").set(self.max_len)
             # the rows' decode attention: 1 the latent kernel over the
@@ -672,8 +688,11 @@ class ServingEngine:
     def _ffn(self, chunk, x, valid=None, tally=None):
         from autodist_tpu.models.pipeline_lm import ffn_residual
 
+        # a routed layer elects its grouped matmul where it is called;
+        # the kernel slot's word (True, False or none) goes with it
         return ffn_residual(self.cfg, chunk, x, self._axis,
-                            self.comm_overlap, valid=valid, tally=tally)
+                            self.comm_overlap, valid=valid, tally=tally,
+                            kernel=self.kernel.get("grouped_matmul"))
 
     def _layer_linear(self, chunk, x, state, layer, *, slot=None,
                       length=None, valid=None, tally=None):

@@ -28,10 +28,13 @@ weights random from a seed:
    ``qwen3-next-80b-a3b`` cell, against its composed form on the chip:
    the recurrent state's update (one position; a window through the
    chunked form) and the routed layer (sorted pairs through the grouped
-   matmul, 64 of 512 experts held, 10 a token); and a latent-attention
-   layer's absorbed decode step over cached rows against its expanded
-   form over the same window, at the widths of the benchmark's
-   ``deepseek-v2-lite`` cell (16 heads, a row of 512 + 64, bf16);
+   matmul, 64 of 512 experts held, 10 a token: two ``ragged_dot`` and,
+   at a decode step's rows, this repo's grouped-matmul kernel); and a
+   latent-attention layer's absorbed decode step over cached rows
+   against its expanded form over the same window, at the widths of the
+   benchmark's ``deepseek-v2-lite`` cell (16 heads, a row of 512 + 64,
+   bf16), with that cell's routed layer (8 of 64 experts held, 6 a
+   token) the same two ways;
 5. **multi-chip** (when ``jax.device_count() > 1``) — the six lowering
    programs of ``__graft_entry__``, the ring kernels over real ICI,
    tensor-parallel serving, and two one-chip engines on two chips.
@@ -546,13 +549,12 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
     against the composed step on a layer's slice, with the seconds of
     each alone, at every count of heads a grid step can take; and the
     routed layer (sorted pairs through the grouped matmul) against every
-    held expert over every row."""
+    held expert over every row (:func:`routed_layer_checks`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from autodist_tpu.models import pipeline_lm as lm
-    from autodist_tpu.parallel import moe
 
     ph = "mixed"
     r = np.random.RandomState(seed)
@@ -647,13 +649,37 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
     done.append("gated_delta_chunked")
 
     # ---- the routed layer: a decode step's rows, a prefill row's --------
+    done += routed_layer_checks(
+        ph, r, rows=(slots, 8 * slots), hidden=hidden, experts=experts,
+        held=held, top_k=top_k, width=width)
+    return done
+
+
+def routed_layer_checks(ph, r, *, rows, hidden: int, experts: int,
+                        held: int, top_k: int, width: int,
+                        renormalise: bool = True, rtol: float = 2e-2) -> list:
+    """``moe.routed_experts`` over bf16 experts of these widths against
+    every held expert over every row, for each count of ``rows``: with
+    the grouped-matmul kernel forbidden (two ``ragged_dot``) and, where
+    the kernel takes the pairs (a decode step's; compiled on a TPU, the
+    interpreter elsewhere), with it forced."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.kernel.pallas.grouped_matmul import \
+        grouped_matmul_elected
+    from autodist_tpu.parallel import moe
+
     bf16 = jnp.bfloat16
-    router = rand(hidden, experts, scale=0.02)
-    wi = rand(held, hidden, 2 * width, scale=0.02, dtype=bf16)
-    wo = rand(held, width, hidden, scale=0.02, dtype=bf16)
+    rand = lambda *shape, dtype=jnp.float32: jnp.asarray(
+        r.randn(*shape) * 0.02, dtype)
+    router = rand(hidden, experts)
+    wi = rand(held, hidden, 2 * width, dtype=bf16)
+    wo = rand(held, width, hidden, dtype=bf16)
 
     def every_expert(x):
-        exps, w = moe.route_top_k(x, router, top_k)
+        exps, w = moe.route_top_k(x, router, top_k, renormalise)
         full = jnp.zeros((x.shape[0], experts), jnp.float32).at[
             jnp.arange(x.shape[0])[:, None], exps].set(w)[:, :held]
         h = jnp.einsum("rh,ehm->erm", x, wi,
@@ -661,21 +687,31 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
         h = (jax.nn.silu(h[..., :width]) * h[..., width:]).astype(bf16)
         y = jnp.einsum("erm,emh->erh", h, wo,
                        preferred_element_type=jnp.float32)
-        return jnp.einsum("re,erh->rh", full, y, precision=hi)
+        return jnp.einsum("re,erh->rh", full, y,
+                          precision=jax.lax.Precision.HIGHEST)
 
-    for rows in (slots, 8 * slots):
-        x = rand(rows, hidden, dtype=bf16)
-        (got, stats), s = timed(lambda: jax.block_until_ready(jax.jit(
-            lambda x: moe.routed_experts(x, router, wi, wo, top_k=top_k))(x)))
-        require_close(ph, f"routed_experts({rows} rows x {top_k} of "
-                          f"{experts}, {held} held)", got,
-                      jax.jit(every_expert)(x), 2e-2)
-        exps, _ = moe.route_top_k(x, router, top_k)
+    done = ["routed_experts"]
+    for n in rows:
+        x = jnp.asarray(r.randn(n, hidden), bf16)
+        want = jax.jit(every_expert)(x)
+        exps, _ = moe.route_top_k(x, router, top_k, renormalise)
         landed = int((np.asarray(exps) < held).sum())
-        require(int(stats[0]) == landed, ph, "pairs on held experts",
-                f"{int(stats[0])} counted, {landed} routed there, "
-                f"{int(stats[1])} experts hit; first call {s:.2f}s")
-    done.append("routed_experts")
+        words = {"ragged_dot": False}
+        if grouped_matmul_elected(True, n * top_k, hidden, width, bf16):
+            words["grouped_matmul"] = True
+        for name, word in words.items():
+            (got, stats), s = timed(lambda: jax.block_until_ready(jax.jit(
+                lambda x: moe.routed_experts(
+                    x, router, wi, wo, top_k=top_k, renormalise=renormalise,
+                    kernel=word))(x)))
+            require_close(ph, f"routed_experts({n} rows x {top_k} of "
+                              f"{experts}, {held} held) through {name}",
+                          got, want, rtol)
+            require(int(stats[0]) == landed, ph, "pairs on held experts",
+                    f"{int(stats[0])} counted, {landed} routed there, "
+                    f"{int(stats[1])} experts hit; first call {s:.2f}s")
+            if word and name not in done:
+                done.append(name)
     return done
 
 
@@ -685,7 +721,9 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
                        value_dim: int = 128, window: int = 384,
                        max_len: int = 512, dtype=None, rtol: float = 3e-2,
                        lane_slots: int = 64, lane_len: int = 3072,
-                       blocks=(128, 256, 512, 1024), seed: int = 0) -> list:
+                       blocks=(128, 256, 512, 1024), experts: int = 64,
+                       held: int = 8, top_k: int = 6, width: int = 1408,
+                       seed: int = 0) -> list:
     """A latent-attention layer's two forms over one set of weights, at
     the widths of the benchmark's ``deepseek-v2-lite`` cell, on the same
     backend: the last position of a ``window`` attended in the EXPANDED
@@ -700,7 +738,10 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
     and ``cached_attention``) through the same seam, alone, at the cell's
     ``lane_slots`` lanes of ``lane_len`` positions ~43% full: the output,
     the cache after the step bit for bit, and the seconds of each for
-    every candidate block in ``blocks`` that divides the lane."""
+    every candidate block in ``blocks`` that divides the lane.  Last the
+    cell's routed layer (``top_k`` of ``experts``, not renormalised,
+    ``held`` of them here) at a decode step's ``lane_slots`` rows and a
+    prefill row's, as :func:`routed_layer_checks` holds it."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -820,7 +861,11 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
             f"of live rows, us a call alone: " + ", ".join(
                 f"{name} {t * 1e6:.1f} ({live / t / 1e9:.0f} GB/s)"
                 for name, t in took.items()))
-    return ["latent_expanded", "latent_absorbed", "latent_decode_kernel"]
+    return ["latent_expanded", "latent_absorbed", "latent_decode_kernel"] \
+        + routed_layer_checks(
+            ph, r, rows=(lane_slots, 16 * lane_slots), hidden=hidden,
+            experts=experts, held=held, top_k=top_k, width=width,
+            renormalise=False)
 
 
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,

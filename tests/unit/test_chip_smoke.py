@@ -71,29 +71,38 @@ def test_chip_smoke_refuses_to_run_without_a_tpu():
                                         ((128, 128), True)])
 def test_mixed_block_phase_at_toy_width(tile, fused):
     """Tiles of ``[128, 128]`` bring the delta-step kernel in (here
-    under the interpreter); narrower ones it cannot take."""
+    under the interpreter), widths in whole lanes of 128 the
+    grouped-matmul kernel at a decode step's rows; narrower ones they
+    cannot take."""
     done = chip_smoke.mixed_block_phase(
         slots=3, value_heads=8 if fused else 2, key_dim=tile[0],
         value_dim=tile[1],
-        window=37, hidden=32, experts=16, held=8, top_k=4, width=8,
-        linear_layers=3)
+        window=37, hidden=128 if fused else 32, experts=16, held=8, top_k=4,
+        width=128 if fused else 8, linear_layers=3)
     assert done == ["gated_delta_step"] \
         + ["gated_delta_step_fused"] * fused \
-        + ["gated_delta_chunked", "routed_experts"]
+        + ["gated_delta_chunked", "routed_experts"] \
+        + ["grouped_matmul"] * fused
 
 
-def test_latent_block_phase_at_toy_width():
+@pytest.mark.parametrize("hidden,width,fused", [(32, 8, False),
+                                                (128, 128, True)])
+def test_latent_block_phase_at_toy_width(hidden, width, fused):
     """Float32 at a toy width: the absorbed step is the expanded form to
     the order of the sums, and the decode kernel (interpreted) the
-    composed step at every block."""
+    composed step at every block; the routed layer's bf16 experts go
+    through the grouped-matmul kernel too where their widths are whole
+    lanes of 128."""
     import jax.numpy as jnp
 
     done = chip_smoke.latent_block_phase(
-        slots=3, heads=4, hidden=32, kv_rank=16, nope_dim=8, rope_dim=4,
+        slots=3, heads=4, hidden=hidden, kv_rank=16, nope_dim=8, rope_dim=4,
         value_dim=8, window=21, max_len=32, dtype=jnp.float32, rtol=1e-4,
-        lane_slots=5, lane_len=32, blocks=(8, 16, 24, 32))
+        lane_slots=5, lane_len=32, blocks=(8, 16, 24, 32), experts=16,
+        held=4, top_k=3, width=width)
     assert done == ["latent_expanded", "latent_absorbed",
-                    "latent_decode_kernel"]
+                    "latent_decode_kernel", "routed_experts"] \
+        + ["grouped_matmul"] * fused
 
 
 @pytest.mark.slow

@@ -139,6 +139,27 @@ def test_one_row_prefill_compiles_in_place(one_chip, hidden, heads, mlp,
 COMPOSED_DECODE_HLO = "ccf433935aa6acb5"
 
 
+def _experts_run_in(text, lowered, fused, layers, ragged):
+    """A compiled program's routed layers: this repo's grouped-matmul
+    kernel, one custom call a layer out of one lowering and neither a
+    ``ragged-dot`` nor the ``conditional`` on the pairs' bound beside it
+    (``fused``: a decode step on a TPU), or the compiler's grouped
+    matmul, at least ``ragged`` of them."""
+    found = len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
+                           r"[^\n]*ragged-dot-none", text))
+    calls = len(re.findall(r"custom-call\([^\n]*adtk_grouped_matmul", text))
+    if not fused:
+        assert found >= ragged and not calls
+        assert "adtk_grouped_matmul" not in text
+        return
+    assert calls == layers and not found
+    assert "ragged-dot" not in text and " conditional(" not in text
+    stablehlo = lowered.as_text()
+    assert stablehlo.count("func.func private @grouped_matmul_layer") == 1
+    assert len(re.findall(r"call @grouped_matmul_layer\(",
+                          stablehlo)) == layers
+
+
 @pytest.mark.parametrize("program", ["decode", "decode-kernel", "prefill"])
 def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     """Both programs hold what they are given — the keys and values and
@@ -151,7 +172,10 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     Mosaic takes the delta-step kernel's tiles at the cell's 32 slots of
     32 heads of ``[128, 128]``, and nothing of a layer's state but the
     kernel's aliased operand is left: no slice in, no
-    ``dynamic_update_slice`` out."""
+    ``dynamic_update_slice`` out; and Mosaic takes the grouped-matmul
+    kernel at the cell's 320 pairs through ``[64, 2048, 1024]`` experts,
+    one call a layer over the experts as they are held, with no
+    ``ragged-dot`` and no ``conditional`` on the pairs' bound left."""
     from autodist_tpu.models import pipeline_lm as lm
     from autodist_tpu.models.transformer import (BlockSpec, LinearMixerSpec,
                                                  RoutedFFNSpec,
@@ -163,9 +187,11 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     fused = program == "decode-kernel"
     bf16, slots, bucket = jnp.bfloat16, 32 if fused else 8, 256
     if fused:
-        monkeypatch.setattr(
-            importlib.import_module("autodist_tpu.kernel.pallas.delta_step"),
-            "default_interpret", lambda: False)
+        for kernel in ("delta_step", "grouped_matmul"):
+            monkeypatch.setattr(
+                importlib.import_module(
+                    f"autodist_tpu.kernel.pallas.{kernel}"),
+                "default_interpret", lambda: False)
     cfg = TransformerConfig(
         vocab_size=18992, hidden_size=2048, num_layers=4, num_heads=16,
         mlp_dim=512, max_len=512, dtype=bf16, dropout_rate=0.0,
@@ -183,7 +209,9 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
                           is_leaf=lambda x: isinstance(x, tuple))
     engine = ServingEngine(cfg, params, num_slots=slots, max_len=512,
                            prefill_len=bucket, decode_steps=8,
-                           kernel={"delta_step": fused})
+                           kernel={"delta_step": fused,
+                                   **({"grouped_matmul": True} if fused
+                                      else {})})
     assert engine.kv.state_kernel(engine.cache.state.ssm) == fused
     sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
                                          sharding=one_chip)
@@ -195,22 +223,22 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     state = tuple(sds(a) for a in engine._state_args())
     with jax.default_matmul_precision("default"):
         if program.startswith("decode"):
-            compiled = engine._decode_jit.lower(
+            lowered = engine._decode_jit.lower(
                 *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
                     (slots,), jnp.bool_, sharding=one_chip),
-                *state).compile()
+                *state)
         else:
-            compiled = engine._prefill_jit.lower(
+            lowered = engine._prefill_jit.lower(
                 *head, i32(), i32(1, 1), i32(1), i32(1, bucket), i32(1),
-                *state).compile()
+                *state)
+        compiled = lowered.compile()
     mem = compiled.memory_analysis()
     held = 2 * c.k.size * 2 + sum(a.size * a.dtype.itemsize
                                   for a in engine._state_args())
     assert abs(mem.alias_size_in_bytes - held) < 4096
     assert mem.temp_size_in_bytes < 128 << 20
     text = compiled.as_text()
-    assert len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
-                          r"[^\n]*ragged-dot-none", text)) >= 8
+    _experts_run_in(text, lowered, fused, layers=4, ragged=8)
     assert not re.findall(
         rf"= f32\[3,{slots},32,128,128\][^ ]* (?:copy|transpose)\(", text)
     assert not re.findall(r"= bf16\[64,2048,1024\][^ ]* (?:copy|fusion)\(",
@@ -252,7 +280,10 @@ def test_latent_stack_compiles_in_place(one_chip, program, monkeypatch):
     ``[576, 256]`` tiles, its view of the cache is the array (a bitcast,
     no copy, transpose or convert of the cache's or a lane's shape), one
     lowering serves every layer, and the step's rows go in inside it: no
-    ``dynamic-update-slice`` of a 576-wide row is left."""
+    ``dynamic-update-slice`` of a 576-wide row is left; and Mosaic takes
+    the grouped-matmul kernel at the cell's 384 pairs through ``[8, 2048,
+    2816]`` experts, one call a routed layer over the experts as they
+    are held."""
     from autodist_tpu.models import pipeline_lm as lm
     from autodist_tpu.models.transformer import (BlockSpec,
                                                  LatentAttentionSpec,
@@ -264,9 +295,11 @@ def test_latent_stack_compiles_in_place(one_chip, program, monkeypatch):
 
     fused = program == "decode-kernel"
     if fused:
-        monkeypatch.setattr(
-            importlib.import_module("autodist_tpu.kernel.pallas.flash_decode"),
-            "default_interpret", lambda: False)
+        for kernel in ("flash_decode", "grouped_matmul"):
+            monkeypatch.setattr(
+                importlib.import_module(
+                    f"autodist_tpu.kernel.pallas.{kernel}"),
+                "default_interpret", lambda: False)
     bf16, slots, bucket, T, L = jnp.bfloat16, 64, 1024, 3072, 3
     yarn = RopeScaling(40.0, 4096, mscale=0.707, mscale_all_dim=0.707)
     cfg = TransformerConfig(
@@ -286,7 +319,9 @@ def test_latent_stack_compiles_in_place(one_chip, program, monkeypatch):
                           is_leaf=lambda x: isinstance(x, tuple))
     engine = ServingEngine(cfg, params, num_slots=slots, max_len=T,
                            prefill_len=bucket, decode_steps=8,
-                           kernel={"flash_decode": True} if fused else None)
+                           kernel={"flash_decode": True,
+                                   "grouped_matmul": True} if fused
+                           else None)
     assert engine.kv.fused_block == (256 if fused else None)
     sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
                                          sharding=one_chip)
@@ -319,8 +354,10 @@ def test_latent_stack_compiles_in_place(one_chip, program, monkeypatch):
     assert not re.findall(
         rf"= (?:bf16|f32)\[(?:{L},)?{slots},1,(?:{T},576|576,{T})\][^ ]* "
         r"(?:copy|transpose|convert)\(", text)
-    assert len(re.findall(r"ROOT %ragged-dot-none|= \S+ custom-call\("
-                          r"[^\n]*ragged-dot-none", text)) >= 4
+    _experts_run_in(text, lowered, fused, layers=L - 1, ragged=4)
+    # a layer's experts reach the grouped matmul as they are held
+    assert not re.findall(r"= bf16\[8,2048,2816\][^ ]* (?:copy|fusion)\(",
+                          text)
     row_writes = re.findall(r"576\][^ ]* dynamic-update-slice\(", text)
     if fused:
         # the kernel's view: the same bytes, positions minor-most

@@ -34,7 +34,7 @@ _PRECISION_BITS = {"fp32": 32, "bf16": 16, "int8": 8}
 # means the election was silently dropped — --check fails it.
 _KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                    "collective_matmul", "a2a_ring", "flash_attention",
-                   "delta_step")
+                   "delta_step", "grouped_matmul")
 # Elections made where the kernel is called, reported as 1 (the fused
 # kernel) or 0 (the composed path) by the engine that makes them, and
 # only by it: gauge -> (that engine's own gauge, who it is, what 0 says).
@@ -53,6 +53,10 @@ _OBSERVED_ELECTIONS = {
         "engine/latent_lane_rows",
         "only an engine that caches latent rows elects how to attend "
         "over them", "the composed attention"),
+    "kernel/grouped_matmul_elected": (
+        "engine/experts_held",
+        "only an engine whose block routes elects how to run the held "
+        "experts", "two ragged_dot"),
 }
 # Training attention's election (autodist_tpu/models/transformer.py
 # attend): every traced call advances one of the two counters, and a
