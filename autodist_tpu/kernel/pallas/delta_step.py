@@ -17,6 +17,11 @@ Same mathematics as the composed step, float32 throughout, and
 elementwise: a float32 product on the MXU at default precision would
 round the state to bf16.  ``g == 0`` and ``beta == 0`` leave a tile bit
 for bit (``1 * S + k * 0``).
+
+The decay is a column: row ``i`` of a tile is multiplied by its own
+``exp(g_i)`` (Kimi Delta Attention's gate a key channel).  A head's one
+decay (gated DeltaNet) is the same column broadcast, so there is one
+kernel, and the decay rides in VMEM beside the key as ``[hb, dk]``.
 """
 from __future__ import annotations
 
@@ -70,36 +75,40 @@ def _heads_per_step(heads: int) -> int:
                if heads % hb == 0)
 
 
-def _delta_step_kernel(layer_ref, scal_ref, k_ref, q_ref, v_ref, s_ref,
-                       o_ref, s_out_ref, *, hb: int, heads: int):
-    """``hb`` heads of one slot.  ``k_ref``, ``q_ref``: ``[hb, dk]`` and
+def _delta_step_kernel(layer_ref, scal_ref, k_ref, q_ref, d_ref, v_ref,
+                       s_ref, o_ref, s_out_ref, *, hb: int, heads: int):
+    """``hb`` heads of one slot.  ``k_ref``, ``q_ref``, ``d_ref`` (the
+    decay of each row of the tile, ``exp(g)``): ``[hb, dk]`` and
     ``v_ref``: ``[hb, dv]``, a head a row; ``scal_ref`` (SMEM): every
-    (slot, head)'s decay, write strength and ``k . q``, three scalars
-    each; ``s_ref`` / ``s_out_ref``: the tiles ``[hb, dk, dv]``, one
-    array.  A key or query multiplies its tile along ``dk``, the
-    sublane axis: turned to a column it broadcasts along the lanes."""
+    (slot, head)'s write strength and ``k . q``, two scalars each;
+    ``s_ref`` / ``s_out_ref``: the tiles ``[hb, dk, dv]``, one array.  A
+    key, query or decay multiplies its tile along ``dk``, the sublane
+    axis: turned to a column it broadcasts along the lanes.  The decayed
+    tile's sums come from the tile as it stands, the decay folded into
+    the key and the query (``S_d^T k = S^T (d k)``)."""
     del layer_ref                       # the index maps read it
-    first = (pl.program_id(0) * heads + pl.program_id(1) * hb) * 3
-    k_cols, q_cols = k_ref[...].T, q_ref[...].T             # [dk, hb]
+    first = (pl.program_id(0) * heads + pl.program_id(1) * hb) * 2
+    d_cols = d_ref[...].T                                   # [dk, hb]
+    k_cols, q_cols = k_ref[...].T, q_ref[...].T
     for j in range(hb):
-        decay, beta, kq = (scal_ref[first + 3 * j + i] for i in range(3))
+        beta, kq = (scal_ref[first + 2 * j + i] for i in range(2))
         S = s_ref[j]                                        # [dk, dv]
-        kc, qc = k_cols[:, j:j + 1], q_cols[:, j:j + 1]
-        s_k = decay * jnp.sum(S * kc, axis=0, keepdims=True)
-        s_q = decay * jnp.sum(S * qc, axis=0, keepdims=True)
+        dc, kc = d_cols[:, j:j + 1], k_cols[:, j:j + 1]
+        s_k = jnp.sum(S * (dc * kc), axis=0, keepdims=True)
+        s_q = jnp.sum(S * (dc * q_cols[:, j:j + 1]), axis=0, keepdims=True)
         delta = (v_ref[j:j + 1, :] - s_k) * beta            # [1, dv]
         o_ref[j:j + 1, :] = s_q + delta * kq
-        s_out_ref[j] = S * decay + kc * delta
+        s_out_ref[j] = S * dc + kc * delta
 
 
 @functools.partial(jax.jit, static_argnames=("heads_per_step", "interpret"))
-def delta_step_layer(layer, scal, k, q, v, ssm, *, heads_per_step: int,
+def delta_step_layer(layer, scal, k, q, d, v, ssm, *, heads_per_step: int,
                      interpret: bool):
     """The one inner function every layer's call goes through (``layer``
     an operand: a decode body of any depth lowers the kernel once).
-    ``scal``: ``[B * H * 3]``; ``k``, ``q``: ``[B, H, dk]``; ``v``:
-    ``[B, H, dv]``; ``ssm``: the stacked state.  Returns ``(o [B, H,
-    dv], ssm)``."""
+    ``scal``: ``[B * H * 2]``; ``k``, ``q``, ``d``: ``[B, H, dk]``;
+    ``v``: ``[B, H, dv]``; ``ssm``: the stacked state.  Returns ``(o [B,
+    H, dv], ssm)``."""
     _, B, H, dk, dv = ssm.shape
     hb = heads_per_step
     rows = lambda width: pl.BlockSpec((None, hb, width),
@@ -109,7 +118,7 @@ def delta_step_layer(layer, scal, k, q, v, ssm, *, heads_per_step: int,
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,          # layer, scal (SMEM)
         grid=(B, H // hb),
-        in_specs=[rows(dk), rows(dk), rows(dv), tiles],
+        in_specs=[rows(dk), rows(dk), rows(dk), rows(dv), tiles],
         out_specs=[rows(dv), tiles],
     )
     return pl.pallas_call(
@@ -117,13 +126,13 @@ def delta_step_layer(layer, scal, k, q, v, ssm, *, heads_per_step: int,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, H, dv), jnp.float32),
                    jax.ShapeDtypeStruct(ssm.shape, ssm.dtype)],
-        # operands: layer, scal, k, q, v, the state
-        input_output_aliases={5: 1},
+        # operands: layer, scal, k, q, d, v, the state
+        input_output_aliases={6: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(layer, scal, k, q, v, ssm)
+    )(layer, scal, k, q, d, v, ssm)
 
 
 def gated_delta_step_fused(q, k, v, g, beta, ssm, layer, *,
@@ -132,7 +141,8 @@ def gated_delta_step_fused(q, k, v, g, beta, ssm, layer, *,
     """``models.pipeline_lm.gated_delta_step`` on linear layer ``layer``
     of the stacked state, in place.  ``q``, ``k``: ``[B, heads, dk]``
     (normalised, ``q`` scaled); ``v``: ``[B, heads, dv]``; ``g`` (log
-    decay), ``beta``: ``[B, heads]``; ``ssm``: ``[linear layers, B,
+    decay): ``[B, heads]``, or ``[B, heads, dk]`` a row of the tile its
+    own; ``beta``: ``[B, heads]``; ``ssm``: ``[linear layers, B,
     heads, dk, dv]`` float32 — the cache manager's array itself, no
     slice; ``layer``: int or int32 scalar.  Returns ``(o [B, heads, dv],
     ssm)``, the array updated in place under ``jit`` with donation.
@@ -150,10 +160,14 @@ def gated_delta_step_fused(q, k, v, g, beta, ssm, layer, *,
                          "into whole sublane tiles of 8")
     f32 = lambda t: t.astype(jnp.float32)
     q, k, v, g, beta = map(f32, (q, k, v, g, beta))
-    scal = jnp.stack([jnp.exp(g), beta, (k * q).sum(-1)], -1).reshape(-1)
+    scal = jnp.stack([beta, (k * q).sum(-1)], -1).reshape(-1)
+    decay = jnp.exp(g)
+    if decay.ndim < k.ndim:             # a head's one decay: every row's
+        decay = jnp.broadcast_to(decay[..., None], k.shape)
     interp = default_interpret() if interpret is None else bool(interpret)
     with jax.named_scope(kernel_marker("delta_step")):
         o, ssm = delta_step_layer(
-            jnp.asarray(layer, jnp.int32).reshape(1), scal, k, q, v, ssm,
+            jnp.asarray(layer, jnp.int32).reshape(1), scal, k, q, decay, v,
+            ssm,
             heads_per_step=hb, interpret=interp)
     return o, ssm

@@ -72,14 +72,30 @@ def final_norm(cfg: TransformerConfig, shared, h):
     return _layer_norm(h, shared["ln_final_scale"], shared["ln_final_bias"])
 
 
-def rope(x, positions, theta: float, fraction: float = 1.0, scaling=None):
+def rope(x, positions, theta: float, fraction: float = 1.0, scaling=None,
+         interleave: bool = False):
     """Rotate-half rotary embedding of ``x`` ``[B, S, heads, d]`` at
     absolute ``positions`` (``[S]`` or ``[B, S]``), angles in fp32; on
     the first ``fraction`` of the ``d`` dimensions, the rest passing
     through.  ``scaling``
     (:class:`~autodist_tpu.models.transformer.RopeScaling`): its
     frequencies in place of ``theta ** (-2i / d)``, cos and sin times
-    its factor."""
+    its factor.  ``interleave``: pair ``i`` is dimensions ``(2i, 2i +
+    1)`` and not ``(i, i + d / 2)``."""
+    if interleave:
+        with scope("rope"):
+            d = x.shape[-1]
+            inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d) \
+                if scaling is None else jnp.asarray(scaling.inv_freq(d, theta))
+            ang = (positions.astype(jnp.float32)[..., None] * inv
+                   )[..., None, :]                       # [.., S, 1, d / 2]
+            factor = 1.0 if scaling is None else scaling.cos_sin_scale
+            cos, sin = jnp.cos(ang) * factor, jnp.sin(ang) * factor
+            xf = x.astype(jnp.float32)
+            even, odd = xf[..., 0::2], xf[..., 1::2]
+            return jnp.stack([even * cos - odd * sin,
+                              odd * cos + even * sin], -1) \
+                .reshape(x.shape).astype(x.dtype)
     if fraction != 1.0:
         r = int(x.shape[-1] * fraction)
         return jnp.concatenate(
@@ -219,16 +235,25 @@ def _latent_inputs(cfg: TransformerConfig, chunk, x, positions):
         c = _rms_norm(down[..., :lat.kv_rank], la["kv_norm"]["scale"],
                       dtype, spec.norm_eps)
         turn = lambda t: rope(t, positions, spec.rope_theta,
-                              scaling=spec.rope_scaling)
+                              scaling=spec.rope_scaling,
+                              interleave=spec.rope_interleave)
         k_pe = turn(down[..., None, lat.kv_rank:])[:, :, 0]
         return x, q_nope, turn(q_pe), jnp.concatenate([c, k_pe], -1)
 
 
 def _latent_output(cfg, chunk, x, out):
-    """The heads' outputs ``[B, S, heads, value_dim]`` through the output
-    projection, and the residual."""
-    w = chunk["latent_attention"]["out"]["kernel"].astype(cfg.dtype)
-    y = out.reshape(*out.shape[:2], -1) @ w
+    """The heads' outputs ``[B, S, heads, value_dim]`` — in an
+    ``attn_gate`` block each head's times ``sigmoid(N(x) w_head)``, the
+    gate in float32 — through the output projection, and the residual."""
+    la = chunk["latent_attention"]
+    if cfg.block.attn_gate:
+        h = block_norm(cfg, x, chunk["ln_attention_in"])
+        gate = jax.nn.sigmoid(jnp.matmul(
+            h.astype(jnp.float32), la["gate"]["kernel"].astype(jnp.float32),
+            precision=_HI))                              # [B, S, heads]
+        out = (out.astype(jnp.float32) * gate[..., None]).astype(cfg.dtype)
+    y = out.reshape(*out.shape[:2], -1) @ la["out"]["kernel"].astype(
+        cfg.dtype)
     return _residual(cfg, x, y, chunk, "ln_attention")
 
 
@@ -344,20 +369,70 @@ def _unit_lower_inverse(m, base: int = 8):
     return inv
 
 
+DELTA_SUBCHUNK = 16    # positions whose decays are multiplied out apart
+
+
+def _channel_decay_products(q, k, gc, sub: int = DELTA_SUBCHUNK):
+    """``(kk, qk)`` ``[.., C, C]`` of a chunk whose decay is a vector over
+    the key channels: ``kk[t, j] = sum_c k_tc k_jc exp(G_tc - G_jc)`` and
+    ``qk`` the same with ``q_t``, for ``j <= t`` (above the diagonal
+    whatever comes out; the callers mask it).  ``q``, ``k``, ``gc`` (the
+    log decays summed from the chunk's first position, falling):
+    ``[.., C, dk]``.
+
+    ``exp(-G)`` over a whole chunk overflows float32 (a channel at its
+    floor of -5 a position passes e^88 in 18 positions), so no ``exp``
+    here takes a positive argument: the chunk is cut into sub-chunks of
+    ``sub`` positions; a block of rows ``I`` against the columns before
+    it factors at ``B_I``, the sum just before ``I``'s first position —
+    ``exp(G_t - B_I)`` and ``exp(B_I - G_j)`` are both at most 1 — and
+    the ``[sub, sub]`` blocks on the diagonal take the difference ``G_t -
+    G_j`` before the ``exp``, channel by channel."""
+    C = k.shape[-2]
+    mm = lambda a, b: jnp.einsum("...ik,...jk->...ij", a, b, precision=_HI)
+    kk_rows, qk_rows = [], []
+    for i in range(0, C, sub):
+        at = slice(i, i + sub)
+        g_i = gc[..., at, :]
+        before = gc[..., i - 1:i, :] if i else jnp.zeros_like(gc[..., :1, :])
+        # the diagonal block: differences first, j <= t kept
+        diff = g_i[..., :, None, :] - g_i[..., None, :, :]
+        low = jnp.tril(jnp.ones((g_i.shape[-2],) * 2, bool))[..., None]
+        w = jnp.exp(jnp.where(low, diff, 0.0)) * k[..., None, at, :]
+        blocks = [(w * t[..., at, None, :]).sum(-1) for t in (k, q)]
+        if i:
+            # the columns before the block, through B_I
+            into = jnp.exp(g_i - before)
+            back = k[..., :i, :] * jnp.exp(before - gc[..., :i, :])
+            blocks = [jnp.concatenate([mm(t[..., at, :] * into, back), d],
+                                      -1) for t, d in zip((k, q), blocks)]
+        pad = [(0, 0)] * (k.ndim - 1) + [(0, C - i - g_i.shape[-2])]
+        kk_i, qk_i = (jnp.pad(b, pad) for b in blocks)
+        kk_rows.append(kk_i)
+        qk_rows.append(qk_i)
+    return jnp.concatenate(kk_rows, -2), jnp.concatenate(qk_rows, -2)
+
+
 def gated_delta_chunked(q, k, v, g, beta, state, chunk: int = DELTA_CHUNK):
     """The recurrence over a window, ``chunk`` positions at a time.
     ``q``, ``k``: ``[B, T, heads, dk]`` (normalised, ``q`` scaled);
-    ``v``: ``[B, T, heads, dv]``; ``g`` (log decay, <= 0), ``beta``:
-    ``[B, T, heads]``; ``state``: ``[B, heads, dk, dv]``; all float32.
+    ``v``: ``[B, T, heads, dv]``; ``g`` (log decay, <= 0): ``[B, T,
+    heads]``, one a head, or ``[B, T, heads, dk]``, one a row of the
+    state; ``beta``: ``[B, T, heads]``; ``state``: ``[B, heads, dk,
+    dv]``; all float32.
     Returns ``(o [B, T, heads, dv], state after position T - 1)``.  A
     position with ``g == 0`` and ``beta == 0`` leaves the state bit for
     bit (a padded position; the window is padded so to whole chunks).
 
     Inside a chunk the ``delta`` of every position is solved for at once
     (the WY form: ``(I + tril(beta K K^T * decay, -1))^-1``), so the
-    chunk costs matmuls and the state moves once a chunk."""
+    chunk costs matmuls and the state moves once a chunk.  With a decay
+    a channel the chunk's ``K K^T * decay`` is no product of two
+    matrices: :func:`_channel_decay_products` sums it channel by
+    channel."""
     B, T, Hh, dk = q.shape
     C = chunk
+    by_channel = g.ndim == q.ndim
     pad = -T % C
     if pad:
         q, k, v, g, beta = (jnp.pad(t, [(0, 0), (0, pad)]
@@ -369,20 +444,36 @@ def gated_delta_chunked(q, k, v, g, beta, state, chunk: int = DELTA_CHUNK):
         B, Hh, N, C, *t.shape[3:])
     q, k, v, g, beta = map(split, (q, k, v, g, beta))
     mm = lambda eq, a, b: jnp.einsum(eq, a, b, precision=_HI)
-    gc = jnp.cumsum(g, -1)                               # [B, Hh, N, C]
-    upto = jnp.tril(jnp.ones((C, C), bool))              # j <= i
-    decay = jnp.where(upto, jnp.exp(jnp.where(
-        upto, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
-    k_beta = k * beta[..., None]
-    m = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
-                  mm("...ik,...jk->...ij", k_beta, k) * decay, 0.0)
-    solve = _unit_lower_inverse(m)
-    u = mm("...ij,...jv->...iv", solve, v * beta[..., None])
-    w = mm("...ij,...jk->...ik", solve, k_beta * jnp.exp(gc)[..., None])
-    within = mm("...ik,...jk->...ij", q, k) * decay      # j <= i kept
-    q_in = q * jnp.exp(gc)[..., None]
-    last = gc[..., -1:]                                  # [B, Hh, N, 1]
-    k_out = k * jnp.exp(last - gc)[..., None]
+    if by_channel:
+        gc = jnp.cumsum(g, -2)                           # [B, Hh, N, C, dk]
+        kk, within = _channel_decay_products(q, k, gc)
+        k_beta = k * beta[..., None]
+        m = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
+                      kk * beta[..., None], 0.0)
+        solve = _unit_lower_inverse(m)
+        u = mm("...ij,...jv->...iv", solve, v * beta[..., None])
+        w = mm("...ij,...jk->...ik", solve, k_beta * jnp.exp(gc))
+        within = jnp.where(jnp.tril(jnp.ones((C, C), bool)), within, 0.0)
+        q_in = q * jnp.exp(gc)
+        last = gc[..., -1:, :]                           # [B, Hh, N, 1, dk]
+        k_out = k * jnp.exp(last - gc)
+        dec = jnp.exp(last)[..., 0, :]                   # a row's decay
+    else:
+        gc = jnp.cumsum(g, -1)                           # [B, Hh, N, C]
+        upto = jnp.tril(jnp.ones((C, C), bool))          # j <= i
+        decay = jnp.where(upto, jnp.exp(jnp.where(
+            upto, gc[..., :, None] - gc[..., None, :], 0.0)), 0.0)
+        k_beta = k * beta[..., None]
+        m = jnp.where(jnp.tril(jnp.ones((C, C), bool), -1),
+                      mm("...ik,...jk->...ij", k_beta, k) * decay, 0.0)
+        solve = _unit_lower_inverse(m)
+        u = mm("...ij,...jv->...iv", solve, v * beta[..., None])
+        w = mm("...ij,...jk->...ik", solve, k_beta * jnp.exp(gc)[..., None])
+        within = mm("...ik,...jk->...ij", q, k) * decay  # j <= i kept
+        q_in = q * jnp.exp(gc)[..., None]
+        last = gc[..., -1:]                              # [B, Hh, N, 1]
+        k_out = k * jnp.exp(last - gc)[..., None]
+        dec = jnp.exp(last)
     chunks = lambda t: jnp.moveaxis(t, 2, 0)             # N first
 
     def one_chunk(S, c):
@@ -390,39 +481,51 @@ def gated_delta_chunked(q, k, v, g, beta, state, chunk: int = DELTA_CHUNK):
         v_new = u_c - mm("...ik,...kv->...iv", w_c, S)
         o = mm("...ik,...kv->...iv", q_c, S) \
             + mm("...ij,...jv->...iv", within_c, v_new)
+        # dec_c: [B, heads, 1] a head, or [B, heads, dk] a row
         S = S * dec_c[..., None] + mm("...ik,...iv->...kv", k_c, v_new)
         return S, o
 
     state, o = jax.lax.scan(
         one_chunk, state,
-        tuple(map(chunks, (u, w, within, q_in, k_out, jnp.exp(last)))))
+        tuple(map(chunks, (u, w, within, q_in, k_out, dec))))
     o = jnp.moveaxis(jnp.moveaxis(o, 0, 2).reshape(B, Hh, N * C, -1), 1, 2)
     return o[:, :T], state
 
 
 def gated_delta_step(q, k, v, g, beta, state):
     """One position of the recurrence: ``q``, ``k`` ``[B, heads, dk]``,
-    ``v`` ``[B, heads, dv]``, ``g``, ``beta`` ``[B, heads]``, ``state``
+    ``v`` ``[B, heads, dv]``, ``g`` ``[B, heads]`` (or ``[B, heads, dk]``,
+    a decay a row of the state), ``beta`` ``[B, heads]``, ``state``
     ``[B, heads, dk, dv]``, float32.  Returns ``(o [B, heads, dv],
     state)``.  Elementwise, not matmuls (a float32 product on the MXU
     would round the state to bf16), and the state is read twice and
     written once, nothing of its size in between: ``S^T k`` and ``S^T q``
     come from the state as it stands in one pass, the decay applied to
     the sums (``o = (S_d + k delta^T)^T q = S_d^T q + delta (k . q)``
-    with ``S_d = exp(g) S``), and the update reads it again."""
+    with ``S_d = exp(g) S``; a decay a row goes into ``k`` and ``q``
+    first: ``S_d^T k = S^T (exp(g) k)``), and the update reads it
+    again."""
     with scope("state_update"):
-        decay = jnp.exp(g)[..., None]
-        s_k = decay * (state * k[..., None]).sum(-2)
-        s_q = decay * (state * q[..., None]).sum(-2)
+        if g.ndim == q.ndim:
+            rows = jnp.exp(g)                            # [B, heads, dk]
+            s_k = (state * (rows * k)[..., None]).sum(-2)
+            s_q = (state * (rows * q)[..., None]).sum(-2)
+        else:
+            decay = jnp.exp(g)[..., None]
+            s_k = decay * (state * k[..., None]).sum(-2)
+            s_q = decay * (state * q[..., None]).sum(-2)
+            rows = decay
         delta = (v - s_k) * beta[..., None]
         o = s_q + delta * (k * q).sum(-1, keepdims=True)
-        return o, state * decay[..., None] \
+        return o, state * rows[..., None] \
             + k[..., None] * delta[..., None, :]
 
 
 def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
                      valid=None, length=None, step=gated_delta_step):
-    """The gated-DeltaNet mixer with its residual: ``(x + mixer(N(x)),
+    """The delta-rule mixer with its residual — gated DeltaNet, or
+    (``LinearMixerSpec.gate`` ``"channel"``) Kimi Delta Attention, whose
+    decay is a vector over the key channels — ``(x + mixer(N(x)),
     (tail, S))``.  ``x``: ``[B, S, H]``; ``state``: ``(tail [B, taps - 1,
     channels], S [B, value_heads, key_dim, value_dim] float32)`` before
     the window.  One position (``S == 1``, a decode step) runs the
@@ -444,16 +547,36 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
     h = block_norm(cfg, x, chunk["ln_attention_in"])
     f32 = lambda t: t.astype(jnp.float32)
     tail, ssm = state
+    by_channel = lin.gate == "channel"
     with scope("linear_attention"):
-        mixed = h @ la["qkvz"]["kernel"].astype(dtype)
-        qkv, z = jnp.split(mixed, [lin.conv_channels], axis=-1)
-        # the write strength and the decay: float32 end to end
-        ba = jnp.matmul(f32(h), f32(la["ba"]["kernel"]), precision=_HI)
-        beta = jax.nn.sigmoid(ba[..., :vh])
-        g = -jnp.exp(f32(la["A_log"])) * jax.nn.softplus(
-            ba[..., vh:] + f32(la["dt_bias"]))
-        if valid is not None:
-            beta, g = beta * valid[..., None], g * valid[..., None]
+        if by_channel:
+            # q, k, v, the decay and the output gate each on its own
+            # projection; a decay a key channel, bounded at the floor
+            qkv = h @ la["qkv"]["kernel"].astype(dtype)
+            z = h @ la["gate"]["kernel"].astype(dtype)
+            beta = jax.nn.sigmoid(jnp.matmul(
+                f32(h), f32(la["beta"]["kernel"]), precision=_HI))
+            f = jnp.matmul(h, la["decay"]["kernel"].astype(dtype),
+                           preferred_element_type=jnp.float32)
+            # flat over (head, channel) until the recurrence takes it: a
+            # reshape right behind the projection has the compiler re-lay
+            # the stacked kernel out, whole, before every dispatch
+            g = (lin.gate_floor * jax.nn.sigmoid(
+                jnp.repeat(jnp.exp(f32(la["A_log"])), dk)
+                * (f + f32(la["dt_bias"])))).reshape(B, S, vh, dk)
+            if valid is not None:
+                beta = beta * valid[..., None]
+                g = g * valid[..., None, None]
+        else:
+            mixed = h @ la["qkvz"]["kernel"].astype(dtype)
+            qkv, z = jnp.split(mixed, [lin.conv_channels], axis=-1)
+            # the write strength and the decay: float32 end to end
+            ba = jnp.matmul(f32(h), f32(la["ba"]["kernel"]), precision=_HI)
+            beta = jax.nn.sigmoid(ba[..., :vh])
+            g = -jnp.exp(f32(la["A_log"])) * jax.nn.softplus(
+                ba[..., vh:] + f32(la["dt_bias"]))
+            if valid is not None:
+                beta, g = beta * valid[..., None], g * valid[..., None]
         qkv, window = causal_conv(qkv, la["conv"]["kernel"], tail)
         taps = lin.conv_taps - 1
         if length is None:
@@ -476,7 +599,11 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
         # the gated norm: per head over value_dim, a plain scale
         o = o * jax.lax.rsqrt(jnp.mean(o * o, -1, keepdims=True)
                               + spec.norm_eps) * f32(la["norm"]["scale"])
-        o = (o * jax.nn.silu(f32(z.reshape(B, S, vh, dv)))).astype(dtype)
+        if by_channel:      # flat, as the decay above
+            o = (o.reshape(B, S, vh * dv)
+                 * jax.nn.sigmoid(f32(z))).astype(dtype)
+        else:
+            o = (o * jax.nn.silu(f32(z.reshape(B, S, vh, dv)))).astype(dtype)
         y = o.reshape(B, S, vh * dv) @ la["out"]["kernel"].astype(dtype)
     return _residual(cfg, x, y, chunk, "ln_attention"), (tail, ssm)
 
@@ -493,6 +620,19 @@ def blank_linear_state(cfg: TransformerConfig, batch: int):
 def _swiglu(h, wi, wo):
     gate, up = jnp.split(h @ wi, 2, axis=-1)
     return (jax.nn.silu(gate) * up) @ wo
+
+
+def _router_rule(spec, moe_params) -> dict:
+    """What a router other than the plain softmax top-k adds to
+    :func:`autodist_tpu.parallel.moe.route_top_k`'s arguments (nothing
+    for that one: its call stays as it was)."""
+    if spec.scores == "softmax" and spec.groups == 1 and spec.scale == 1.0 \
+            and not spec.correction:
+        return {}
+    return dict(scores=spec.scores, groups=spec.groups,
+                groups_kept=spec.groups_kept, scale=spec.scale,
+                correction=moe_params["router"]["correction"]
+                if spec.correction else None)
 
 
 def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None,
@@ -514,7 +654,8 @@ def routed_ffn(cfg: TransformerConfig, moe_params, h, valid=None,
                 moe_params["experts"]["wi"], moe_params["experts"]["wo"],
                 top_k=spec.top_k, first_expert=spec.first_expert,
                 valid=None if valid is None else valid.reshape(-1),
-                renormalise=spec.renormalise, kernel=kernel)
+                renormalise=spec.renormalise, kernel=kernel,
+                **_router_rule(spec, moe_params))
         if spec.shared_width:
             sh = moe_params["shared"]
             gate = None
@@ -628,7 +769,8 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
     return (y, k, v) if return_kv else y
 
 
-MIXERS = {"full": "attention", "linear": "linear_attention"}
+MIXERS = {"full": "attention", "linear": "linear_attention",
+          "latent": "latent_attention"}
 _FFNS = ("mlp", "moe")
 
 
@@ -652,7 +794,8 @@ def layer_chunk(cfg: TransformerConfig, stages, l: int):
     """Layer ``l``'s parameters out of ``stages``.  A leaf is stacked
     over the layers that have it: all of them, but for a mixed stack's
     mixers (``attention`` over the full layers, ``linear_attention``
-    over the linear ones, each in stack order) and a routed stack's
+    over the linear ones, ``latent_attention`` over the latent ones, each
+    in stack order) and a routed stack's
     feed-forward kinds (``mlp`` over its leading dense layers, ``moe``
     over the routed ones: the chunk holds the one its layer runs).  A
     routed FFN's experts are arrays of their own a layer
@@ -663,7 +806,7 @@ def layer_chunk(cfg: TransformerConfig, stages, l: int):
     if not (spec.layer_period or spec.moe):
         return jax.tree.map(lambda p: p[l], stages)
     kinds = spec.layer_kinds(cfg.num_layers)
-    mixers = (*MIXERS.values(), "latent_attention")
+    mixers = tuple(MIXERS.values())
     routed = spec.moe is not None and l >= spec.dense_layers
     # the layer's place among the leaves of a sub-tree: the FFN it runs
     # stacks over the layers of its kind, the FFN it does not run is left
@@ -673,8 +816,7 @@ def layer_chunk(cfg: TransformerConfig, stages, l: int):
     chunk = {name: _layer_of(tree, l, place.get(name, l))
              for name, tree in stages.items()
              if name not in mixers and place.get(name, l) is not None}
-    mixer = "latent_attention" if spec.latent is not None \
-        else MIXERS[kinds[l]]
+    mixer = MIXERS[kinds[l]]
     nth = kinds[:l].count(kinds[l])
     chunk[mixer] = jax.tree.map(lambda p: p[nth], stages[mixer])
     return chunk
@@ -766,7 +908,14 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     attention holds ``q`` ``[H, heads * (nope + rope)]``, the
     down-projection ``kv_a`` ``[H, kv_rank + rope]``, the latent's norm,
     the up-projection ``kv_b`` ``[kv_rank, heads * (nope + value)]``
-    (each head's key part, then its value part) and ``out``.  A
+    (each head's key part, then its value part) and ``out``, and in an
+    ``attn_gate`` block the heads' ``gate`` ``[H, heads]``; in a mixed
+    stack they stack over the latent layers.  A linear mixer whose gate
+    is a channel's holds ``qkv`` ``[H, q | k | v]``, ``decay`` ``[H,
+    value_heads * key_dim]``, ``gate`` ``[H, value_heads * value_dim]``
+    and ``beta`` ``[H, value_heads]`` in place of ``qkvz`` and ``ba``,
+    and ``dt_bias`` a channel.  A router with a correction holds it
+    beside its kernel, ``correction`` ``[num_experts]``.  A
     zero-centred norm's leaf is ``weight``."""
     spec = cfg.block
     L, H, M, V = cfg.num_layers, cfg.hidden_size, cfg.mlp_dim, \
@@ -799,16 +948,19 @@ def param_shapes(cfg: TransformerConfig) -> dict:
         "attention": {"qkv": qkv, "out": dense((n, d, H), (H,), Lf)},
         "mlp": {"wi": dense((H, wi), (wi,), Ld),
                 "wo": dense((M, H), (H,), Ld)}}
-    if spec.latent is not None:
-        lat = spec.latent
+    if not Lf:
         del stages["attention"]
+    if spec.latent is not None:
+        lat, Lt = spec.latent, kinds.count("latent")
         stages["latent_attention"] = {
-            "q": dense((H, n * (lat.nope_dim + lat.rope_dim)), ()),
-            "kv_a": dense((H, lat.row), ()),
-            "kv_norm": {"scale": (L, lat.kv_rank)},
+            "q": dense((H, n * (lat.nope_dim + lat.rope_dim)), (), Lt),
+            "kv_a": dense((H, lat.row), (), Lt),
+            "kv_norm": {"scale": (Lt, lat.kv_rank)},
             "kv_b": dense((lat.kv_rank,
-                           n * (lat.nope_dim + lat.value_dim)), ()),
-            "out": dense((n * lat.value_dim, H), ())}
+                           n * (lat.nope_dim + lat.value_dim)), (), Lt),
+            "out": dense((n * lat.value_dim, H), (), Lt)}
+        if spec.attn_gate:
+            stages["latent_attention"]["gate"] = dense((H, n), (), Lt)
     if spec.qk_norm:
         stages["attention"].update(q_norm=norm((Lf,), d),
                                    k_norm=norm((Lf,), d))
@@ -823,12 +975,23 @@ def param_shapes(cfg: TransformerConfig) -> dict:
             "dt_bias": (Ll, lin.value_heads),
             "norm": {"scale": (Ll, lin.value_dim)},
             "out": dense((inner, H), (), Ll)}
+        if lin.gate == "channel":
+            mixer = stages["linear_attention"]
+            del mixer["qkvz"], mixer["ba"]
+            mixer.update(
+                qkv=dense((H, lin.conv_channels), (), Ll),
+                decay=dense((H, lin.value_heads * lin.key_dim), (), Ll),
+                gate=dense((H, inner), (), Ll),
+                beta=dense((H, lin.value_heads), (), Ll),
+                dt_bias=(Ll, lin.value_heads * lin.key_dim))
     if spec.moe is not None:
         moe, Ms, Lr = spec.moe, spec.moe.shared_width, L - Ld
         if not Ld:
             del stages["mlp"]
         stages["moe"] = {
-            "router": {"kernel": (Lr, H, moe.num_experts)},
+            "router": {"kernel": (Lr, H, moe.num_experts),
+                       **({"correction": (Lr, moe.num_experts)}
+                          if moe.correction else {})},
             "experts": {layer_key(l): {
                 "wi": (moe.experts_held, H, 2 * moe.expert_width),
                 "wo": (moe.experts_held, moe.expert_width, H)}

@@ -29,18 +29,36 @@ class LinearMixerSpec:
     (each key head serves ``value_heads // key_heads`` of them), a
     depthwise causal convolution of ``conv_taps`` taps over the
     ``[q, k, v]`` channels.  The state is one ``[key_dim, value_dim]``
-    float32 matrix a value head."""
+    float32 matrix a value head.
+
+    ``gate`` says what the state's decay is: ``"head"`` — one log decay
+    a head and position, ``-exp(A_log) softplus(a + dt_bias)``, beside a
+    fused ``qkvz`` / ``ba`` pair of projections and a SiLU output gate
+    (gated DeltaNet) — or ``"channel"`` — ``key_dim`` a head, row ``i``
+    of the state decaying by its own ``gate_floor * sigmoid(exp(A_log)
+    (f_i + dt_bias_i))``, bounded below by ``gate_floor`` (< 0), with q,
+    k and v projected each on its own beside the decay's and a sigmoid
+    output gate's projections (Kimi Delta Attention)."""
 
     key_heads: int
     value_heads: int
     key_dim: int
     value_dim: int
     conv_taps: int = 4
+    gate: str = "head"
+    gate_floor: float = 0.0
 
     def __post_init__(self):
         if self.value_heads % self.key_heads:
             raise ValueError("LinearMixerSpec.value_heads must be a "
                              "multiple of key_heads")
+        if self.gate not in ("head", "channel"):
+            raise ValueError(f"LinearMixerSpec.gate={self.gate!r}: one of "
+                             "('head', 'channel')")
+        if (self.gate == "channel") != (self.gate_floor < 0):
+            raise ValueError("LinearMixerSpec.gate_floor (< 0) is the "
+                             "'channel' gate's lower bound: both or "
+                             "neither")
 
     @property
     def conv_channels(self) -> int:
@@ -125,8 +143,15 @@ class LatentAttentionSpec:
 @dataclasses.dataclass(frozen=True)
 class RoutedFFNSpec:
     """A routed feed-forward block's sizes.  The router scores all
-    ``num_experts`` and keeps ``top_k`` a token, renormalised to sum 1
-    (``renormalise``; as the softmax left them where false);
+    ``num_experts`` (``scores``: a ``"softmax"`` over them, or a
+    ``"sigmoid"`` of each) and keeps ``top_k`` a token, renormalised to
+    sum 1 (``renormalise``; as the scores left them where false), times
+    ``scale``.  With ``groups`` > 1 the experts lie in that many equal
+    groups in order, a token keeps the ``groups_kept`` groups whose two
+    best scores sum highest, and its ``top_k`` come from those alone.
+    ``correction``: a learned term an expert (the leaf ``correction``) is
+    added to the scores that CHOOSE groups and experts; the weights are
+    of the scores without it.
     this device holds experts ``first_expert .. first_expert +
     experts_held`` and adds up their terms alone — what the absent ones
     would have given is some other device's.  Experts are SiLU-gated at
@@ -142,11 +167,29 @@ class RoutedFFNSpec:
     first_expert: int = 0
     renormalise: bool = True
     shared_gate: bool = True
+    scores: str = "softmax"
+    groups: int = 1
+    groups_kept: int = 1
+    scale: float = 1.0
+    correction: bool = False
 
     def __post_init__(self):
         if not 0 < self.top_k <= self.num_experts:
             raise ValueError("RoutedFFNSpec.top_k must lie in "
                              "1..num_experts")
+        if self.scores not in ("softmax", "sigmoid"):
+            raise ValueError(f"RoutedFFNSpec.scores={self.scores!r}: one "
+                             "of ('softmax', 'sigmoid')")
+        if self.groups < 1 or self.num_experts % self.groups \
+                or not 0 < self.groups_kept <= self.groups \
+                or self.top_k > self.groups_kept \
+                * (self.num_experts // self.groups) \
+                or (self.groups > 1 and self.num_experts // self.groups < 2):
+            raise ValueError(
+                f"RoutedFFNSpec keeps {self.groups_kept} of {self.groups} "
+                f"groups of {self.num_experts} experts for {self.top_k} a "
+                "token: equal groups of at least two, enough kept to "
+                "choose from")
         if self.first_expert < 0 or self.experts_held < 1 \
                 or self.first_expert + self.experts_held > self.num_experts:
             raise ValueError(
@@ -178,10 +221,18 @@ class BlockSpec:
     * ``bias`` — whether the projections carry biases.
     * ``tied_head`` — logits from the embedding table, or from
       ``shared["lm_head"]``.
-    * ``latent`` — every layer's attention is latent attention
+    * ``layer_period`` — the kinds of layer the stack repeats in order:
+      ``"full"`` (softmax attention over cached keys and values),
+      ``"linear"`` (``linear``'s recurrent mixer) and ``"latent"``
+      (``latent``'s attention); empty: every layer alike.
+    * ``latent`` — the sizes of the ``"latent"`` layers' attention
       (:class:`LatentAttentionSpec`): the cache holds one row a position
-      and not keys and values a head; ``rope_scaling`` scales the rotary
-      frequencies (:class:`RopeScaling`).
+      and not keys and values a head.  With an empty ``layer_period``
+      every layer is latent.  ``attn_gate`` on such a layer is a gate a
+      head: its output times ``sigmoid(x w_h)`` before the output
+      projection.  ``rope_scaling`` scales the rotary frequencies
+      (:class:`RopeScaling`); ``rope_interleave``: the rotary pairs are
+      neighbours ``(2i, 2i + 1)`` and not halves ``(i, i + d / 2)``.
     * ``dense_layers`` — of a routed stack (``moe``), how many leading
       layers carry the dense ``ffn`` at ``TransformerConfig.mlp_dim``
       and no router.
@@ -216,6 +267,7 @@ class BlockSpec:
     latent: Optional[LatentAttentionSpec] = None
     rope_scaling: Optional[RopeScaling] = None
     dense_layers: int = 0
+    rope_interleave: bool = False
 
     def __post_init__(self):
         for name, allowed in (("norm", ("layernorm", "rmsnorm")),
@@ -228,9 +280,19 @@ class BlockSpec:
                                  f": one of {allowed}")
         if self.loop_steps < 1:
             raise ValueError("BlockSpec.loop_steps must be >= 1")
-        if set(self.layer_period) - {"full", "linear"}:
+        if set(self.layer_period) - {"full", "linear", "latent"}:
             raise ValueError(f"BlockSpec.layer_period={self.layer_period}"
-                             ": kinds are 'full' and 'linear'")
+                             ": kinds are 'full', 'linear' and 'latent'")
+        if {"full", "latent"} <= set(self.layer_period):
+            raise ValueError(
+                "'full' and 'latent' layers in one layer_period: the cache "
+                "manager holds keys and values a head or a latent row a "
+                "position beside the recurrent state, not both")
+        if self.layer_period and ("latent" in self.layer_period) \
+                != (self.latent is not None):
+            raise ValueError("BlockSpec.latent gives the sizes of the "
+                             "'latent' layers of layer_period: both or "
+                             "neither")
         if ("linear" in self.layer_period) != (self.linear is not None):
             raise ValueError("BlockSpec.linear gives the sizes of the "
                              "'linear' layers of layer_period: both or "
@@ -250,15 +312,19 @@ class BlockSpec:
                              "layers of a routed stack (moe) that are not "
                              "routed")
         if self.latent is not None and (
-                self.positions != "rope" or self.layer_period or self.bias
-                or self.loop_steps != 1 or self.qk_norm or self.attn_gate
+                self.positions != "rope" or self.bias
+                or self.loop_steps != 1 or self.qk_norm
                 or self.kv_heads or self.head_dim
                 or self.rope_fraction != 1.0):
             raise ValueError(
                 "latent attention has its own head sizes and one shared "
                 "rotary key: BlockSpec.latent goes with positions='rope' "
-                "and none of layer_period, bias, loop_steps, qk_norm, "
-                "attn_gate, kv_heads, head_dim, rope_fraction")
+                "and none of bias, loop_steps, qk_norm, kv_heads, "
+                "head_dim, rope_fraction")
+        if self.rope_interleave and self.latent is None:
+            raise ValueError("BlockSpec.rope_interleave pairs the rotary "
+                             "dimensions of latent attention's positional "
+                             "slices")
         if self.rope_scaling is not None and self.positions != "rope":
             raise ValueError("BlockSpec.rope_scaling scales rotary "
                              "positions")
@@ -277,7 +343,8 @@ class BlockSpec:
 
     def layer_kinds(self, num_layers: int) -> tuple:
         """The kind of each of ``num_layers`` layers."""
-        period = self.layer_period or ("full",)
+        period = self.layer_period or (
+            ("latent",) if self.latent is not None else ("full",))
         return tuple(period[l % len(period)] for l in range(num_layers))
 
 

@@ -262,8 +262,9 @@ class ServingEngine:
                 "default block's leaves only (no rule splits a linear "
                 "mixer's heads, its recurrent state or a routed FFN's "
                 "experts)")
-        # a mixed stack: the full layers cache keys and values, the
-        # linear ones keep a recurrent state (kv_cache.RecurrentState)
+        # a mixed stack: the full layers cache keys and values (the
+        # latent ones a row), the linear ones keep a recurrent state
+        # (kv_cache.RecurrentState)
         self._kinds = spec.layer_kinds(cfg.num_layers)
         self.linear_layers = self._kinds.count("linear")
         grouped = cfg.kv_heads != cfg.num_heads
@@ -302,9 +303,10 @@ class ServingEngine:
                     "readers take a key/value head a query head")
         # the cache holds every pass's keys and values: a layer's input
         # differs from pass to pass, so its projections do too
-        self.cache_layers = self._kinds.count("full") * spec.loop_steps
+        self.cache_layers = (cfg.num_layers - self.linear_layers) \
+            * spec.loop_steps
         # of them, the layers whose cached position is a latent row
-        self.latent_layers = self.cache_layers if spec.latent else 0
+        self.latent_layers = self._kinds.count("latent")
         tp = int(tensor_parallel)
         if tp < 1:
             raise ValueError("tensor_parallel must be >= 1")
@@ -458,7 +460,8 @@ class ServingEngine:
                 self.kernel = dict(self.kernel, flash_decode=True)
             self.kv = kv_cache.LatentLayout(
                 dims, self.kernel, kv_rank=spec.latent.kv_rank,
-                scale=spec.latent_softmax_scale, fused_block=fused_block)
+                scale=spec.latent_softmax_scale, fused_block=fused_block,
+                recurrent=recurrent)
         elif self.kv_layout == "paged":
             self.kv = kv_cache.PagedLayout(
                 dims, self.kernel, block_len=self.kv_block_len,
@@ -511,6 +514,15 @@ class ServingEngine:
         if recurrent:
             telemetry.gauge("engine/state_bytes_per_slot").set(
                 held["state_bytes_per_slot"])
+        if recurrent and spec.latent is not None:
+            # two kinds of state in the one manager: how many layers of
+            # each, and the bytes it holds of each over all slots
+            telemetry.gauge("kv/latent_layers").set(self.latent_layers)
+            telemetry.gauge("kv/linear_layers").set(self.linear_layers)
+            telemetry.gauge("kv/row_bytes").set(
+                held["kv_bytes_per_token"] * self.max_len * self.num_slots)
+            telemetry.gauge("kv/state_bytes").set(
+                held["state_bytes_per_slot"] * self.num_slots)
         if spec.moe is not None:
             telemetry.gauge("engine/experts_held").set(spec.moe.experts_held)
             # a decode step's routed layer: 1 this repo's grouped-matmul
@@ -756,7 +768,7 @@ class ServingEngine:
                     linear_fn):
         """:meth:`_run_layers` for a stack that may mix layer kinds:
         ``(x, kc, vc, state)``.  A mixed stack walks its period in one
-        pass: a full layer goes to ``layer_fn``, a linear one to
+        pass: a full or a latent layer goes to ``layer_fn``, a linear one to
         ``linear_fn(chunk, x, state, nth)`` with the recurrent ``state``
         arrays, and each kind counts its own ``nth`` layer of the cache
         manager's arrays.  Any other stack is :meth:`_run_layers`'s, and
@@ -1035,8 +1047,10 @@ class ServingEngine:
                     routing = (routing[0] + sum(tally),)
                 return (kc, vc, lengths, nxt, state, routing), nxt
 
-            # [rows_held, experts_hit] over the window's steps and layers
-            routing = (jnp.zeros((2,), jnp.int32),) if routed else ()
+            # [rows_held, experts_hit] (and, of a router that keeps
+            # groups, groups_hit) over the window's steps and layers
+            routing = (jnp.zeros((3 if self.cfg.block.moe.groups > 1
+                                  else 2,), jnp.int32),) if routed else ()
             (kc, vc, lengths, tok, state, routing), toks = lax.scan(
                 body, (kc, vc, lengths, tok, tuple(state), routing), None,
                 length=K)
@@ -1312,8 +1326,9 @@ class ServingEngine:
         """What a fused decode window moved beside keys and values: the
         recurrent-state rows its linear layers read and wrote, and what
         its routed layers chose — every (row, expert) pair, those that
-        landed on held experts, and the held experts some row hit,
-        summed over steps and routed layers (``moe/layer_steps`` counts
+        landed on held experts, the held experts some row hit and (a
+        router that keeps groups) the rows that kept a held expert's
+        group, summed over steps and routed layers (``moe/layer_steps`` counts
         those: ``experts_hit`` can reach ``layer_steps x
         experts_held``)."""
         if self.linear_layers:
@@ -1326,6 +1341,8 @@ class ServingEngine:
                 rows * steps * routed * self.cfg.block.moe.top_k)
             telemetry.counter("moe/rows_held").inc(int(routing[0]))
             telemetry.counter("moe/experts_hit").inc(int(routing[1]))
+            if len(routing) > 2:
+                telemetry.counter("moe/groups_hit").inc(int(routing[2]))
 
     def decode_one(self, active):
         """A single-token dispatch through a lazily-built K=1 program —

@@ -690,9 +690,14 @@ class DenseLayout:
         self.table = np.zeros((num_slots, 1), np.int32)
 
     def init_cache(self, dims, dtype) -> KVCache:
-        cache = init_cache(*dims, dtype=dtype)
+        return self._with_state(init_cache(*dims, dtype=dtype), dtype)
+
+    def _with_state(self, cache: KVCache, dtype) -> KVCache:
+        """``cache`` with the linear layers' blank state beside it, where
+        the stack has such layers."""
         if self.recurrent is not None:
-            cache.state = init_state(self.recurrent[0], dims[1],
+            cache.state = init_state(self.recurrent[0],
+                                     cache.lengths.shape[0],
                                      self.recurrent[1], dtype)
         return cache
 
@@ -758,7 +763,8 @@ class DenseLayout:
         """``(o, ssm)``: every slot's recurrent matrix of linear layer
         ``layer`` advanced by one position
         (:func:`~autodist_tpu.models.pipeline_lm.gated_delta_step`'s
-        operands, ``ssm`` the stacked array) and read out — in the fused
+        operands — ``g`` a head's decay or a key channel's —, ``ssm`` the
+        stacked array) and read out — in the fused
         kernel, which reads and writes each tile of the array once, in
         place, or the composed step on the layer's slice and its
         :func:`write_state`."""
@@ -814,19 +820,23 @@ class LatentLayout(DenseLayout):
     ``fused_block``: the block with which the latent decode kernel reads
     the cache in place, a slot's live blocks once for scores and
     weighted sum alike, and writes the step's row itself — the engine's
-    election, as a dense lane's."""
+    election, as a dense lane's.  ``recurrent``: as a dense lane's — the
+    stack's other layers are linear ones, and the manager holds their
+    :class:`RecurrentState` beside the latent layers' rows."""
 
     def __init__(self, dims, kernel, *, kv_rank: int, scale: float,
-                 fused_block=None):
-        super().__init__(dims, kernel, fused_block=fused_block)
+                 fused_block=None, recurrent=None):
+        super().__init__(dims, kernel, fused_block=fused_block,
+                         recurrent=recurrent)
         self.kv_rank, self.scale = kv_rank, scale
 
     def init_cache(self, dims, dtype) -> KVCache:
         layers, slots, heads, row, max_len = dims
         lanes = (layers, slots, heads, max_len)
-        return KVCache(k=jnp.zeros(lanes + (row,), dtype),
-                       v=jnp.zeros(lanes + (0,), dtype),
-                       lengths=jnp.zeros((slots,), jnp.int32))
+        return self._with_state(
+            KVCache(k=jnp.zeros(lanes + (row,), dtype),
+                    v=jnp.zeros(lanes + (0,), dtype),
+                    lengths=jnp.zeros((slots,), jnp.int32)), dtype)
 
     # ---- traced ------------------------------------------------------ #
     def write_prompt(self, kc, vc, layer, k, v, slot, table_row, p_len,

@@ -1522,8 +1522,9 @@ class CostModel:
         * **a mixed or routed stack** — ``block`` (the model's
           ``BlockSpec``; its ``loop_steps`` then stands for the
           argument): only the ``"full"`` layers of ``layer_period``
-          cache keys and values, ``kv_heads x head_dim`` wide, and pay
-          the attention term; each ``"linear"`` layer instead reads and
+          cache keys and values, ``kv_heads x head_dim`` wide (its
+          ``"latent"`` layers a row, below), and pay the attention
+          term; each ``"linear"`` layer instead reads and
           writes a float32 state of ``value_heads x key_dim x
           value_dim`` a slot every token, priced at the chip's HBM
           rate (``state_time_s``) and held a slot in memory and in the
@@ -1643,7 +1644,7 @@ class CostModel:
         kinds = block.layer_kinds(int(layers)) if block is not None \
             else ("full",) * int(layers)
         linear_layers = kinds.count("linear")
-        layers = kinds.count("full") * passes
+        layers = (len(kinds) - linear_layers) * passes
         moe = getattr(block, "moe", None)
         hbm_rate = self.chip.hbm_gbps * 1e9
         elems = bytes_ = expert_elems = expert_bytes = 0.0

@@ -31,6 +31,8 @@ SCOPES = (
     "moe",         # a routed FFN: router, sort, experts, shared expert
     "moe_experts",  # inside moe: routing and the held experts' grouped
                    # matmuls over the (row, expert) pairs that hit them
+    "moe_route",   # inside moe_experts: the router's scores, the groups
+                   # kept, the top-k and its weights
     "latent_attention",  # a latent-attention mixer: the down- and
                    # up-projections (absorbed into q and the output in a
                    # decode step), rotary, the output projection

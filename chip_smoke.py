@@ -868,6 +868,136 @@ def latent_block_phase(*, slots: int = 8, heads: int = 16,
             renormalise=False)
 
 
+def hybrid_latent_phase(*, states=((32, 32, 12, "head"),
+                                  (96, 32, 10, "channel")),
+                        key_dim: int = 128, value_dim: int = 128,
+                        latent_heads=(16, 32), kv_rank: int = 512,
+                        rope_dim: int = 64, lane_slots: int = 96,
+                        lane_len: int = 3072, block: int = 256,
+                        dtype=None, rtol: float = 2e-2, reps: int = 47,
+                        seed: int = 0) -> list:
+    """The two kernels a mixed latent / delta-rule stack's decode step
+    elects, each alone against its composed form through the cache
+    manager's seam, with the us a call of each.  The delta-step kernel
+    over the stacked state at each of ``states`` ``(slots, heads, linear
+    layers, gate)`` — the benchmark's ``qwen3-next-80b-a3b`` cell (a
+    decay a head) and its ``ling-3.0-flash`` cell (a decay a row of each
+    ``[key_dim, value_dim]`` tile): one kernel, the head's decay the same
+    column broadcast.  The latent decode kernel over ``lane_slots`` lanes
+    of ``lane_len`` rows of ``kv_rank + rope_dim``, ~43% live, at each
+    count of absorbed query heads in ``latent_heads`` (16:
+    ``deepseek-v2-lite``; 32: ``ling-3.0-flash``)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.kernel.pallas import delta_step as ds
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.serving import kv_cache
+
+    ph = "hybrid"
+    dtype = jnp.bfloat16 if dtype is None else dtype
+    r = np.random.RandomState(seed)
+    rand = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    done = []
+
+    def per_call(fn, first, carried):
+        """Seconds a call of ``fn(first, carried) -> (o, carried)``:
+        ``reps`` calls in one program, the array donated and carried and
+        each call's ``first`` made from ALL of the last one's output (of
+        ``first``'s shape), so that nothing is lifted out or cut down to
+        the part that is used, and the host's dispatch (longer than the
+        call) is paid once."""
+        def chained(_, c):
+            o, arr = fn(*c)
+            return c[0] + 1e-3 * o.astype(c[0].dtype), arr
+
+        # both carried values come back: a chain nothing reads is dead
+        # code, and a composed step's would be removed
+        many = jax.jit(lambda arr: jax.lax.fori_loop(
+            0, reps, chained, (first, arr)), donate_argnums=0)
+        arr = jax.block_until_ready(many(carried()))[1]
+        return timed(lambda: jax.block_until_ready(many(arr)))[1] / reps
+
+    # ---- the state kernel: a decay a head, a decay a row ---------------
+    dk, dv = key_dim, value_dim
+    for B, Hh, L, gate in states:
+        if not ds.delta_step_fits((L, B, Hh, dk, dv), jnp.float32):
+            continue
+        layer = L // 2
+        q = lm._l2_normalise(rand(B, Hh, dk)) * dk ** -0.5
+        k, v = lm._l2_normalise(rand(B, Hh, dk)), rand(B, Hh, dv)
+        g = -5.0 * jax.nn.sigmoid(
+            rand(*((B, Hh, dk) if gate == "channel" else (B, Hh))))
+        beta, state = jax.nn.sigmoid(rand(B, Hh)), rand(B, Hh, dk, dv)
+        stack = lambda: jnp.stack([state] * L)
+        took = {}
+        for name, word in (("composed", False), ("kernel", True)):
+            lay = kv_cache.DenseLayout((1, B, 1, 8, 8), {"delta_step": word})
+            step = lambda v_, ssm, lay=lay: lay.advance_state(
+                q, k, v_, g, beta, ssm, jnp.int32(layer))
+            o, ssm = jax.jit(step)(v, stack())
+            if word:
+                require_close(ph, f"delta_step kernel output, a decay a "
+                                  f"{gate} ({B} slots x {Hh} heads, {L} "
+                                  f"layers)", o, ref_o, 1e-5)
+                require_close(ph, "delta_step kernel state", ssm[layer],
+                              ref_ssm[layer], 1e-5)
+                require(bool((ssm[:layer] == state).all()
+                             and (ssm[layer + 1:] == state).all()), ph,
+                        "delta_step kernel leaves the other layers",
+                        "bit for bit")
+            ref_o, ref_ssm = o, ssm
+            took[name] = per_call(step, v, stack)
+        moved = 2 * state.size * 4
+        say(ph, f"state update, a decay a {gate}, one of {L} layers of "
+                f"{B} slots x {Hh} heads, {moved / 1e6:.1f} MB there and "
+                f"back, us a call alone: " + ", ".join(
+                    f"{name} {t * 1e6:.1f} ({moved / t / 1e9:.0f} GB/s)"
+                    for name, t in took.items()))
+        done.append(f"delta_step:{gate}")
+
+    # ---- the latent decode kernel: 16 and 32 absorbed heads ------------
+    B, T, L, layer, row = lane_slots, lane_len, 2, 1, kv_rank + rope_dim
+    dims = (L, B, 1, row, T)
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    new = jax.random.normal(keys[1], (B, 1, 1, row), dtype)
+    stack = lambda: jax.random.normal(keys[2], (L, B, 1, T, row), dtype)
+    lengths = jnp.asarray(np.linspace(T / 16, 0.8 * T, B), jnp.int32)
+    vc = jnp.zeros((L, B, 1, T, 0), dtype)
+    live = int(jnp.sum(lengths + 1)) * row * jnp.dtype(dtype).itemsize
+    for heads in latent_heads:
+        q = jax.random.normal(keys[0], (B, 1, heads, row), dtype)
+        took = {}
+        for name, blk in (("composed", None), ("kernel", block)):
+            lay = kv_cache.LatentLayout(dims, {}, kv_rank=kv_rank,
+                                        scale=row ** -0.5, fused_block=blk)
+            def step(q_, kc, lay=lay):
+                # the weighted sum at the query's width, for per_call
+                o, kc, _ = lay.decode_attend(q_, new, None, kc, vc, layer,
+                                             lengths, None, None,
+                                             dtype=dtype)
+                return jnp.pad(o, [(0, 0)] * 3 + [(0, rope_dim)]), kc
+
+            o, kc = jax.jit(step)(q, stack())
+            if blk:
+                require_close(ph, f"latent decode kernel output, {heads} "
+                                  f"heads ({B} lanes of {T}, blocks of "
+                                  f"{blk})", o, ref_o, rtol)
+                require(bool((kc == ref_kc).all()), ph,
+                        "latent decode kernel leaves the cache as "
+                        "write_token does", "bit for bit")
+            ref_o, ref_kc = o, kc
+            took[name] = per_call(step, q, stack)
+        say(ph, f"decode attention, {heads} absorbed heads over one of "
+                f"{L} layers, {live / 1e6:.1f} MB of live rows, us a call "
+                f"alone: " + ", ".join(
+                    f"{name} {t * 1e6:.1f} ({live / t / 1e9:.0f} GB/s)"
+                    for name, t in took.items()))
+        done.append(f"latent_decode:{heads}")
+    return done
+
+
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,
                        matmul_shape=(1024, 1024, 1024), seed: int = 0) -> list:
     """The three ring kernels whole, inside ``shard_map`` over
@@ -1067,6 +1197,8 @@ def main() -> int:
     say("kernel", f"compiled (interpret=False) and agreed: {kernels}")
     say("mixed", f"agreed with their composed forms: {mixed_block_phase()}")
     say("latent", f"agreed with each other: {latent_block_phase()}")
+    say("hybrid", f"agreed with their composed forms: "
+                  f"{hybrid_latent_phase()}")
 
     if n > 1:
         multichip_phase(cfg, params, prompts, dense["tokens"],

@@ -105,6 +105,21 @@ def test_latent_block_phase_at_toy_width(hidden, width, fused):
         + ["grouped_matmul"] * fused
 
 
+def test_hybrid_latent_phase_at_toy_width():
+    """The two kernels of a mixed latent / delta-rule stack's decode
+    step under the interpreter: the state kernel with a decay a head and
+    a decay a row of each ``[128, 128]`` tile, the latent decode kernel
+    at two counts of absorbed heads."""
+    import jax.numpy as jnp
+
+    done = chip_smoke.hybrid_latent_phase(
+        states=((2, 8, 2, "head"), (3, 8, 3, "channel"), (2, 4, 2, "head")),
+        latent_heads=(2, 4), kv_rank=16, rope_dim=4, lane_slots=3,
+        lane_len=32, block=16, dtype=jnp.float32, rtol=1e-4, reps=1)
+    assert done == ["delta_step:head", "delta_step:channel",
+                    "latent_decode:2", "latent_decode:4"]
+
+
 @pytest.mark.slow
 def test_kernel_phase_at_toy_width_under_the_interpreter(lm):
     done = chip_smoke.kernels_phase(

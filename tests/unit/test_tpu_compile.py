@@ -379,6 +379,123 @@ def test_latent_stack_compiles_in_place(one_chip, program, monkeypatch):
             .hexdigest()[:16] == COMPOSED_LATENT_DECODE_HLO
 
 
+# one period of the benchmark's mixed latent / delta-rule stack at its
+# published widths (five KDA layers, 32 heads of a [128, 128] state whose
+# decay is a vector over the key channels, closed by a latent-attention
+# layer of 32 heads on a row of 512 + 64; two dense layers of 6,144 then
+# routed ones of 64 of 512 experts of 768, sigmoid scores, 4 of 8 groups)
+# at the cell's 96 slots of 3,072 positions and its [1, 1024] prompt row
+@pytest.mark.parametrize("program", ["decode", "decode-kernel", "prefill"])
+def test_hybrid_latent_stack_compiles_in_place(one_chip, program,
+                                               monkeypatch):
+    """Both programs hold the two kinds of state they are given — the
+    lanes of latent rows and the stacked float32 recurrent state alias
+    their outputs — and no op copies, slices or converts the stacked
+    state, the row cache or a layer's ``[64, 2560, 1536]`` experts.
+    ``decode-kernel``: the decode program a TPU process elects (here
+    forced through the kernel slot, the backend being the CPU's) — Mosaic
+    takes the delta-step kernel with a decay a row of each ``[128, 128]``
+    tile at the cell's 96 slots of 32 heads, the latent kernel at 32
+    absorbed heads on its ``[576, 256]`` tiles, and the grouped-matmul
+    kernel at the cell's 768 pairs, one call a layer each."""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import (BlockSpec,
+                                                 LatentAttentionSpec,
+                                                 LinearMixerSpec,
+                                                 RoutedFFNSpec,
+                                                 TransformerConfig)
+    from autodist_tpu.serving import ServingEngine
+
+    fused = program == "decode-kernel"
+    if fused:
+        for kernel in ("delta_step", "flash_decode", "grouped_matmul"):
+            monkeypatch.setattr(
+                importlib.import_module(
+                    f"autodist_tpu.kernel.pallas.{kernel}"),
+                "default_interpret", lambda: False)
+    bf16, slots, bucket, T, L = jnp.bfloat16, 96, 1024, 3072, 6
+    cfg = TransformerConfig(
+        vocab_size=19648, hidden_size=2560, num_layers=L, num_heads=32,
+        mlp_dim=6144, max_len=T, dtype=bf16, dropout_rate=0.0,
+        attention_dropout_rate=0.0,
+        block=BlockSpec(
+            norm="rmsnorm", norm_placement="pre", positions="rope",
+            rope_theta=6e6, rope_interleave=True, ffn="swiglu", bias=False,
+            tied_head=False, attn_gate=True,
+            layer_period=("linear",) * 5 + ("latent",),
+            linear=LinearMixerSpec(32, 32, 128, 128, gate="channel",
+                                   gate_floor=-5.0),
+            latent=LatentAttentionSpec(512, 128, 64, 128), dense_layers=2,
+            moe=RoutedFFNSpec(512, 8, 768, experts_held=64,
+                              shared_width=768, shared_gate=False,
+                              scores="sigmoid", groups=8, groups_kept=4,
+                              scale=2.5, correction=True)))
+    params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
+                          lm.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    engine = ServingEngine(cfg, params, num_slots=slots, max_len=T,
+                           prefill_len=bucket, decode_steps=8,
+                           kernel={"delta_step": True, "flash_decode": True,
+                                   "grouped_matmul": True} if fused
+                           else {"delta_step": False})
+    assert engine.kv.state_kernel(engine.cache.state.ssm) == fused
+    assert engine.kv.fused_block == (256 if fused else None)
+    sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                         sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    c = engine.cache
+    assert c.k.shape == (1, slots, 1, T, 576) and c.v.size == 0
+    assert c.state.ssm.shape == (5, slots, 32, 128, 128)
+    assert c.state.ssm.dtype == jnp.float32
+    head = (jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
+            i32(slots), i32(slots))
+    state = tuple(sds(a) for a in engine._state_args())
+    with jax.default_matmul_precision("default"):
+        if program.startswith("decode"):
+            lowered = engine._decode_jit.lower(
+                *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
+                    (slots,), jnp.bool_, sharding=one_chip),
+                *state)
+        else:
+            lowered = engine._prefill_jit.lower(
+                *head, i32(), i32(1, 1), i32(1), i32(1, bucket), i32(1),
+                *state)
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    held = c.k.size * 2 + sum(a.size * a.dtype.itemsize
+                              for a in engine._state_args())
+    assert abs(mem.alias_size_in_bytes - held) < 4096
+    print(program, "temp bytes", mem.temp_size_in_bytes)
+    # the composed step works on a layer's slice of the state (201 MB)
+    # and a layer's lanes in float32 (680 MB); the kernels on neither
+    assert mem.temp_size_in_bytes < (256 << 20 if fused else 1 << 30)
+    text = compiled.as_text()
+    _experts_run_in(text, lowered, fused, layers=L - 2, ragged=4)
+    # the stacked state, the row cache, a layer's experts: as they are held
+    assert not re.findall(
+        rf"= f32\[5,{slots},32,128,128\][^ ]* "
+        r"(?:copy|slice|transpose|convert)\(", text)
+    assert not re.findall(
+        rf"= (?:bf16|f32)\[(?:1,)?{slots},1,(?:{T},576|576,{T})\][^ ]* "
+        r"(?:copy|slice|transpose|convert)\(", text)
+    assert not re.findall(
+        r"= (?:bf16|f32)\[64,2560,1536\][^ ]* "
+        r"(?:copy|slice|fusion|convert)\(", text)
+    layer_state = rf"f32\[(?:1,)?{slots},32,128,128\]"
+    if fused:
+        assert len(re.findall(r"custom-call\([^\n]*adtk_delta_step",
+                              text)) == 5
+        assert not re.findall(layer_state, text)
+        assert len(re.findall(r"custom-call\([^\n]*adtk_flash_decode",
+                              text)) == 1
+        assert not re.findall(r"576\][^ ]* dynamic-update-slice\(", text)
+    elif program == "decode":
+        assert re.findall(layer_state, text)     # the slice, the write
+        assert "adtk_delta_step" not in text
+        assert "adtk_flash_decode" not in text
+
+
 # one encoder layer's attention at the training cell's widths (BERT-base:
 # 12 heads of 64, 512 positions) and at heads of 128, forward and
 # backward: Mosaic takes the one-pass kernels' tiles, and no array of the

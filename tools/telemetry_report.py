@@ -100,6 +100,18 @@ _PREFILL_COUNTERS = ("engine/prefill_rows", "engine/prefill_positions")
 # the routing it ran — --check fails it.
 _ROUTING_COUNTERS = ("moe/layer_steps", "moe/rows_routed", "moe/rows_held",
                      "moe/experts_hit")
+# A router that keeps some of its expert groups also advances
+# moe/groups_hit: the rows, a step and a layer, whose kept groups include
+# one this device holds an expert of.  It comes with the counters above;
+# a pair is held only through a kept group, so pairs held with no group
+# hit, or more rows with a group hit than pairs routed, is a break.
+_GROUPS_COUNTER = "moe/groups_hit"
+# Two kinds of state in one cache manager (a latent row a position beside
+# a recurrent state a slot): an engine that holds both says so once, with
+# kv/latent_layers, kv/linear_layers, kv/row_bytes and kv/state_bytes —
+# all four or none, every one positive.
+_TWO_STATE_GAUGES = ("kv/latent_layers", "kv/linear_layers", "kv/row_bytes",
+                     "kv/state_bytes")
 # Latent rows read (autodist_tpu/serving/batcher.py): an engine whose
 # cached position is a latent-attention row advances
 # serve/latent_positions_read by every decode step's live positions x
@@ -734,6 +746,30 @@ def check_schema(run_dir: str) -> list[str]:
                     f"metrics.jsonl: moe/experts_hit = {hit!r} is over "
                     f"moe/layer_steps = {steps!r} x engine/experts_held "
                     f"= {held_g.get('value')!r}")
+
+    groups = counters.get(_GROUPS_COUNTER)
+    if groups is not None:
+        if any(c is None for c in routing):
+            problems.append(
+                f"metrics.jsonl: {_GROUPS_COUNTER} without "
+                f"{', '.join(_ROUTING_COUNTERS)} — the groups a router "
+                "keeps are counted with what it routed")
+        else:
+            kept, routed, held = (c.get("value", 0) for c in
+                                  (groups, routing[1], routing[2]))
+            if kept > routed or (held and not kept):
+                problems.append(
+                    f"metrics.jsonl: {_GROUPS_COUNTER} = {kept!r} beside "
+                    f"moe/rows_routed = {routed!r} and moe/rows_held = "
+                    f"{held!r} — a pair is held only through a group its "
+                    "row kept, and a row that kept one was routed")
+    both = [gauges.get(n) for n in _TWO_STATE_GAUGES]
+    if any(g is not None for g in both) and not all(
+            g is not None and g.get("value", 0) > 0 for g in both):
+        problems.append(
+            f"metrics.jsonl: {', '.join(_TWO_STATE_GAUGES)} come together "
+            "and positive — an engine sets them where its cache manager "
+            "holds latent rows beside recurrent state")
 
     latent = counters.get(_LATENT_COUNTER)
     if latent is not None:
