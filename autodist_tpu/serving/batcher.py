@@ -1,8 +1,10 @@
 """Continuous batching: a request queue feeding the fused decode loop.
 
 The serving-side counterpart of the training stack's steps-per-loop
-discipline: requests of ragged lengths share ONE compiled prefill and
-ONE compiled decode program — slots that are empty or whose request
+discipline: requests of ragged lengths share a fixed few compiled
+programs — a one-row prefill a rung of ``prefill_len`` (a prompt runs at
+the shortest rung that holds it) and ONE decode program, all made at the
+engine's first dispatch — and slots that are empty or whose request
 already finished ride along masked (``active=False`` holds their state),
 so admission and eviction never trigger a recompile.  A request's life:
 

@@ -31,6 +31,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import gc
 import weakref
 from typing import Optional
 
@@ -43,7 +44,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from autodist_tpu import const, telemetry
 from autodist_tpu.serving import kv_cache
 from autodist_tpu.telemetry import account
-from autodist_tpu.utils.stack_room import FirstCallWithRoom
+from autodist_tpu.utils.stack_room import (FirstCallWithRoom,
+                                           call_with_stack_room)
 from autodist_tpu.parallel.tensor import (normalize_comm_overlap, vocab_pad,
                                           vocab_parallel_embedding,
                                           vocab_parallel_greedy_token)
@@ -63,6 +65,29 @@ class DecodeWindow:
     counts: np.ndarray
     spec_proposed: np.ndarray
     spec_accepted: np.ndarray
+
+
+# The shortest prompt length a prefill program is built for.  A rung is
+# worth a program of its own only where the shorter row is shorter in
+# time, and under 256 positions no served stack's is: a routed row reads
+# every held expert from ~128 positions x 8-10 experts a token up, GPT-2
+# large's [1, 128] row would be 1.4 ms of products under 1.9 ms of weight
+# reads, and Ouro's [1, 256] row (42.4 ms) is already within about 2x of
+# its four passes' weight reads, so a [1, 128] rung there is worth under
+# 3% of device time (PERF.md section 6, PR 42).  The delta rule's chunk
+# of 64 and the attention kernels' blocks all divide 256.
+MIN_PREFILL_RUNG = 256
+
+
+def prefill_rungs(prefill_len: int) -> tuple:
+    """The prompt lengths an engine builds a one-row prefill program for,
+    ascending: ``prefill_len`` and its halves down to
+    :data:`MIN_PREFILL_RUNG` (512 -> ``(256, 512)``; 1024 -> ``(256, 512,
+    1024)``; 256 and anything shorter: itself alone)."""
+    rungs = [int(prefill_len)]
+    while rungs[-1] % 2 == 0 and rungs[-1] // 2 >= MIN_PREFILL_RUNG:
+        rungs.append(rungs[-1] // 2)
+    return tuple(reversed(rungs))
 
 
 def serving_param_specs(params, tp: int, vocab_parallel: bool):
@@ -149,15 +174,18 @@ class ServingEngine:
     :func:`~autodist_tpu.models.pipeline_lm.make_pipeline_lm_trainable`
     (stacked per-layer leaves + tied embedding/unembedding).  Slots,
     prompt bucket, and the fused-decode width are static so the whole
-    serving loop is exactly two compiled programs — a prefill over ONE
-    ``[1, prefill_len]`` row with the slot it is admitted to a traced
-    scalar, run once per admitted slot, and the fused decode over all
-    slots:
+    serving loop is a fixed handful of compiled programs — a prefill
+    over ONE ``[1, S]`` row for each rung ``S`` of
+    :func:`prefill_rungs` (``prefill_len`` and its halves down to 256),
+    with the slot it is admitted to a traced scalar, run once per
+    admitted slot at the shortest rung the prompt fits, and the fused
+    decode over all slots.  The engine's first dispatch makes them all
+    (:meth:`_prepare`), so no program compiles at its first row:
 
     * ``num_slots`` — batch slots the continuous batcher fills;
-    * ``prefill_len`` — the prompt bucket (prompts zero-padded up to
-      it; padded positions write garbage k/v that masked reads never
-      see and forward decode overwrites);
+    * ``prefill_len`` — the prompt bucket, and the top rung (prompts
+      zero-padded up to their rung; padded positions write garbage k/v
+      that masked reads never see and forward decode overwrites);
     * ``decode_steps`` — tokens per fused decode dispatch (``K``).
 
     ``tensor_parallel``/``vocab_parallel``/``comm_overlap`` mirror the
@@ -548,10 +576,19 @@ class ServingEngine:
             telemetry.gauge("kernel/latent_decode_elected").set(
                 int(bool(self.kv.fused_block)))
 
+        # ---- the programs: a one-row prefill a rung (chunked: the one
+        # window program) and the fused decode.  ``_prefill_jit`` is the
+        # top rung's; ``_compiled`` holds every program's executable
+        # once the first dispatch has prepared them -----------------------
+        self._rung_jits = ({} if self.prefill_chunk is not None else
+                           {S: self._build_prefill(S)
+                            for S in prefill_rungs(self.prefill_len)})
+        self.prefill_rungs = tuple(self._rung_jits)
         self._prefill_jit = (self._build_chunk_prefill()
                              if self.prefill_chunk is not None
-                             else self._build_prefill())
+                             else self._rung_jits[self.prefill_len])
         self._decode_jit = self._build_decode()
+        self._compiled = None
         self._decode1_jit = None           # lazy K=1 program (catch-up)
         self.last_prefill_chunks = 0
         self._counts_prefill = True
@@ -706,41 +743,27 @@ class ServingEngine:
                             self.comm_overlap, valid=valid, tally=tally,
                             kernel=self.kernel.get("grouped_matmul"))
 
-    def _layer_linear(self, chunk, x, state, layer, *, slot=None,
-                      length=None, valid=None, tally=None):
-        """One linear (gated-DeltaNet) layer against the recurrent state
-        the cache manager holds (``state``: its arrays; ``layer``: the
-        layer's place among the linear ones).  A decode step advances
+    def _layer_linear(self, chunk, x, state, layer, *, valid=None,
+                      tally=None):
+        """A decode step of one linear (gated-DeltaNet) layer against the
+        recurrent state the cache manager holds (``state``: its arrays;
+        ``layer``: the layer's place among the linear ones).  It advances
         every slot's rows: the recurrent matrix where the manager keeps
         it, through the layout's seam (``self.kv.advance_state``: the
         stacked array goes in and comes out, no slice of it here), the
-        convolution's tail read and written here.  The prefill of one
-        row starts from a blank state — the slot's previous occupant
-        left one that is nobody's — masks the padding out of the
-        recurrence (``valid``, which is also the routed FFN's), cuts the
-        convolution's tail at ``length`` and overwrites ``slot``'s
-        rows."""
+        convolution's tail read and written here.  (The prompt's pass
+        through such a layer is :meth:`_build_prefill`'s.)"""
         from autodist_tpu.models import pipeline_lm as lm
 
-        if slot is not None:
-            x, after = lm.linear_attention(
-                self.cfg, chunk, x,
-                lm.blank_linear_state(self.cfg, x.shape[0]), valid=valid,
-                length=length)
-            with telemetry.scope("linear_attention"), \
-                    telemetry.scope("state_update"):
-                state = kv_cache.write_state(state, layer, after, slot)
-        else:
-            # both arrays, as the benchmark's planted faults wrap it (the
-            # slice of the matrices is dead code, and compiled away)
-            tail, _ = kv_cache.read_state(state, layer)
-            x, (tail, ssm) = lm.linear_attention(
-                self.cfg, chunk, x, (tail, state[1]),
-                step=functools.partial(self.kv.advance_state, layer=layer))
-            with telemetry.scope("linear_attention"), \
-                    telemetry.scope("state_update"):
-                state = (*kv_cache.write_state(state[:1], layer, (tail,)),
-                         ssm)
+        # both arrays, as the benchmark's planted faults wrap it (the
+        # slice of the matrices is dead code, and compiled away)
+        tail, _ = kv_cache.read_state(state, layer)
+        x, (tail, ssm) = lm.linear_attention(
+            self.cfg, chunk, x, (tail, state[1]),
+            step=functools.partial(self.kv.advance_state, layer=layer))
+        with telemetry.scope("linear_attention"), \
+                telemetry.scope("state_update"):
+            state = (*kv_cache.write_state(state[:1], layer, (tail,)), ssm)
         return self._ffn(chunk, x, valid, tally), state
 
     def _run_layers(self, shared, stages, x, kc, vc, layer_fn):
@@ -833,10 +856,12 @@ class ServingEngine:
         non-cache operands/results after ``(params, k, v)`` /
         ``(k, v)``.  A stack with linear layers hands the recurrent
         state's arrays over last, after those (tp=1 only), and they are
-        donated like the cache.  The first call, which traces and lowers
-        these unrolled programs, gets stack room of its own: where it stands
-        on the interpreter's frame stack otherwise decides whether
-        lowering takes half a second or twenty (``utils/stack_room``).
+        donated like the cache.  Whatever traces and lowers these
+        unrolled programs — :meth:`_prepare`, or the first call of one it
+        does not make (the lazy K=1 decode) — gets stack room of its own:
+        where it stands on the interpreter's frame stack otherwise decides
+        whether lowering takes half a second or twenty
+        (``utils/stack_room``).
 
         The builders hand ``fn`` a weak proxy of the engine: a program
         that held the engine that holds it would be a cycle, and an
@@ -857,15 +882,19 @@ class ServingEngine:
             check_vma=False)
         return FirstCallWithRoom(jax.jit(sm, donate_argnums=(1, 2)))
 
-    def _build_prefill(self):
-        """The single-shot prefill program: ONE row, ``[1, prefill_len]``,
+    def _build_prefill(self, S: int):
+        """A rung's single-shot prefill program: ONE row, ``[1, S]``,
         with the slot it is admitted to a traced scalar —
-        :meth:`prefill` runs it once per admitted slot.  The row's keys
-        and values land in the slot's lane (its blocks, paged) and
-        ``tok[slot]`` / ``lengths[slot]`` are set inside the program;
-        nothing of any other slot is read or written."""
+        :meth:`prefill` runs it once per admitted slot whose prompt fits
+        ``S`` and no shorter rung.  The row's keys and values land in the
+        slot's lane (its blocks, paged) and ``tok[slot]`` /
+        ``lengths[slot]`` are set inside the program; nothing of any
+        other slot is read or written, and the rung's padding (positions
+        ``p_len`` to ``S``) reaches neither the recurrent state, nor an
+        expert, nor a paged block past the prompt's own."""
+        from autodist_tpu.models import pipeline_lm as lm
+
         self = weakref.proxy(self)      # see _wrap: no cycle through jit
-        S = self.prefill_len
         prefix = self.prefix_caching
 
         routed = self.cfg.block.moe is not None
@@ -887,17 +916,37 @@ class ServingEngine:
             valid = (positions[None, :] < p_len[:, None]
                      if state or routed else None)
 
+            # Traced once a kind of layer: the layers of a kind differ in
+            # their weights alone, which are operands, so jax traces such
+            # a function at the first of them and binds its equations
+            # again at every later one (``inline``: the program is
+            # equation for equation what the unrolled trace gives).
+            # Nothing in one may depend on the layer's index, so the
+            # writes into the cache manager's arrays stay outside.
+            once = functools.partial(jax.jit, inline=True)
+            attend_prompt = once(lambda chunk, x: self._layer_prefill(
+                chunk, x, mask, positions, valid))
+            # a linear layer starts from a blank state — the slot's
+            # previous occupant left one that is nobody's — masks the
+            # padding out of the recurrence and cuts the convolution's
+            # tail at p_len
+            mix_prompt = once(lambda chunk, x: lm.linear_attention(
+                self.cfg, chunk, x, lm.blank_linear_state(self.cfg, 1),
+                valid=valid, length=p_len))
+            ffn = once(lambda chunk, x: self._ffn(chunk, x, valid))
+
             def layer_fn(chunk, x, kc, vc, _, layer):
-                x, k, v = self._layer_prefill(chunk, x, mask, positions,
-                                              valid)
+                x, k, v = attend_prompt(chunk, x)
                 kc, vc = self.kv.write_prompt(kc, vc, layer, k, v, slot,
                                               table_row, p_len, wf)
                 return x, kc, vc
 
             def linear_fn(chunk, x, state, layer):
-                return self._layer_linear(chunk, x, state, layer,
-                                          slot=slot, valid=valid,
-                                          length=p_len)
+                x, after = mix_prompt(chunk, x)
+                with telemetry.scope("linear_attention"), \
+                        telemetry.scope("state_update"):
+                    state = kv_cache.write_state(state, layer, after, slot)
+                return ffn(chunk, x), state
 
             x, kc, vc, state = self._run_period(
                 shared, stages, x, kc, vc, state, layer_fn, linear_fn)
@@ -1058,6 +1107,60 @@ class ServingEngine:
 
         return self._wrap(decode, n_in_rest=5, n_out_rest=3)
 
+    def _prepare(self) -> dict:
+        """Make every program the constructor built, here and now: each
+        is traced, lowered (with stack room: see :meth:`_wrap`) and
+        compiled in turn, and from then on every dispatch goes through
+        the executables — a rung never compiles at its first row,
+        whichever lengths the first prompts have.  The operands are typed
+        as the host path hands them over (an executable takes no others),
+        and donation is the jitted functions' own.
+
+        In turn, on this thread, because that is what the chip's host
+        gives: a cached executable's retrieval holds the interpreter as
+        tracing does, and the three of the GPT-2 cell's engine on worker
+        threads beside the lowering took 7.8 s where one after another
+        they take 6.3 (PERF.md section 6, PR 42).  The cycle collector
+        is paused meanwhile: a trace allocates containers by the million,
+        none of them garbage before it ends, and the collector's passes
+        over them were 0.2 s of that engine's 1.7 s of tracing."""
+        c = self.cache
+        head = (self.params, c.k, c.v, c.lengths, self._tok)
+        # numpy where the host path hands a placed copy over: typed the
+        # same, and made without a program of its own
+        table, seeds = self.kv.table_arg(c), self._sample_seeds
+        slots = np.zeros((self.num_slots,), bool)
+        todo = {"decode": (self._decode_jit, (
+            table, seeds, slots, *self._state_args()))}
+        if self.prefill_chunk is not None:
+            todo["prefill"] = (self._prefill_jit, self._blank_prefill_args())
+        for S, jitted in reversed(self._rung_jits.items()):
+            todo[S] = (jitted, self._blank_prefill_args(length=S))
+        if self.speculative is not None:
+            todo["verify"] = (self._spec_verify_jit, (
+                table, seeds,
+                np.zeros((self.num_slots, self.speculative + 1), np.int32),
+                slots))
+        collecting = gc.isenabled()
+        gc.disable()
+        try:
+            with telemetry.span("engine/prepare", programs=len(todo)):
+                self._compiled = {
+                    key: call_with_stack_room(
+                        jitted.lower, *head, *args).compile()
+                    for key, (jitted, args) in todo.items()}
+        finally:
+            if collecting:
+                gc.enable()
+        return self._compiled
+
+    @property
+    def _programs(self) -> dict:
+        """The programs' executables, by rung (``"decode"``, ``"verify"``
+        and a chunked engine's ``"prefill"`` by name): prepared at the
+        first dispatch, whichever program it asks for."""
+        return self._compiled or self._prepare()
+
     # ------------------------------------------------------------------ #
     # host-side block accounting (the batcher's admission predicate),
     # delegated to the layout: kv_cache.PagedLayout holds the pool
@@ -1140,10 +1243,12 @@ class ServingEngine:
         """Prefill the admitted slots of the ``[B, S]`` slot batch; each
         adopts its prompt's cache/length and first generated token
         (greedy, or sampled at the engine's temperature under the slot's
-        ``seeds`` entry).  Single-shot engines run the one
-        ``[1, prefill_len]`` program once per admitted slot, back to
-        back, the cache donated from one dispatch to the next — only
-        admitted rows are computed; chunked engines walk the prompt in
+        ``seeds`` entry).  Single-shot engines run a rung's ``[1, S]``
+        program once per admitted slot — the shortest rung the row's
+        prompt fits, an integer comparison here on the host — back to
+        back, the cache donated from one dispatch to the next: only
+        admitted rows are computed, and of each no more padding than its
+        rung's; chunked engines walk the prompt in
         ``prefill_chunk`` windows through ONE compiled ``[B, C]``
         program (``chunk_start`` is traced), skipping leading chunks
         every admitted slot already has cached via prefix hits.  Returns
@@ -1162,21 +1267,30 @@ class ServingEngine:
                 # own, compiled at its first use) into arrays that
                 # nothing mutates while a dispatch is in flight.
                 rows = np.flatnonzero(admit_np)
-                picked = [np.asarray(a[rows], np.int32) for a in (
-                    self.kv.table, self._sample_seeds, prompts_np,
-                    p_lens_np, *((self.kv.write_from,)
-                                 if self.prefix_caching else ()))]
+                table, seed, prompt, p_len, *wf = (
+                    np.asarray(a[rows], np.int32) for a in (
+                        self.kv.table, self._sample_seeds, prompts_np,
+                        p_lens_np, *((self.kv.write_from,)
+                                     if self.prefix_caching else ())))
+                # each row's rung: the shortest that holds its prompt
+                rungs = self.prefill_rungs
+                fits = np.minimum(np.searchsorted(rungs, p_len),
+                                  len(rungs) - 1)
+                by_rung = {rungs[r]: int(n)
+                           for r, n in enumerate(np.bincount(fits)) if n}
+                positions = sum(S * n for S, n in by_rung.items())
         if self.prefill_chunk is None:
             with telemetry.span("engine/prefill/dispatch",
                                 loop_steps=self.cfg.block.loop_steps,
-                                rows=len(rows)):
-                for i, slot in enumerate(rows):
-                    c = self.cache
-                    self._adopt(*self._prefill_jit(
+                                rows=len(rows), positions=positions):
+                for i, (slot, r) in enumerate(zip(rows, fits)):
+                    S, one, c = rungs[r], slice(i, i + 1), self.cache
+                    self._adopt(*self._programs[S](
                         self.params, c.k, c.v, c.lengths, self._tok,
-                        np.int32(slot), *(a[i:i + 1] for a in picked),
-                        *self._state_args()))
-            self._count_prefill(len(rows), self.prefill_len)
+                        np.int32(slot), table[one], seed[one],
+                        prompt[one, :S], p_len[one],
+                        *(a[one] for a in wf), *self._state_args()))
+            self._count_prefill(len(rows), positions, by_rung)
             self.last_prefill_chunks = 1
         else:
             self._chunked_prefill(prompts_np, p_lens_np, admit_np)
@@ -1192,27 +1306,33 @@ class ServingEngine:
         with telemetry.span("engine/prefill/fetch"):
             return np.asarray(jax.device_get(self._tok))
 
-    def _count_prefill(self, rows: int, positions_a_row: int) -> None:
-        """What the prefill program computed: rows dispatched, and the
-        positions they span (padding included).  A speculative draft's
-        nested engine keeps out of the count."""
+    def _count_prefill(self, rows: int, positions: int, by_rung=()) -> None:
+        """What the prefill programs computed: rows dispatched, the
+        positions they span (padding included, each row at ITS rung) and
+        — ``by_rung``: ``{S: rows}`` — how often each rung engaged
+        (``engine/prefill_rung_rows/<S>``).  A speculative draft's nested
+        engine keeps out of the count."""
         if not self._counts_prefill:
             return
         telemetry.counter("engine/prefill_rows").inc(rows)
-        telemetry.counter("engine/prefill_positions").inc(
-            rows * positions_a_row)
+        telemetry.counter("engine/prefill_positions").inc(positions)
+        for S in by_rung:
+            telemetry.counter(f"engine/prefill_rung_rows/{S}").inc(
+                by_rung[S])
 
-    def _blank_prefill_args(self, slot: int = 0) -> tuple:
+    def _blank_prefill_args(self, slot: int = 0,
+                            length: Optional[int] = None) -> tuple:
         """The prefill program's operands after ``tok``, typed as
         :meth:`prefill` hands them over, for a dispatch that changes no
-        request's state: the one-row program over an empty prompt
-        (``p_len`` 0) at ``slot``, the chunked program's first window
-        with no slot admitted."""
+        request's state: the one-row program (the top rung's, or the
+        rung's of ``length``) over an empty prompt (``p_len`` 0) at
+        ``slot``, the chunked program's first window with no slot
+        admitted."""
         if self.prefill_chunk is None:
             zero = np.zeros((1,), np.int32)
             return (np.int32(slot), np.zeros_like(self.kv.table[:1]), zero,
-                    np.zeros((1, self.prefill_len), np.int32), zero,
-                    *((zero,) if self.prefix_caching else ()),
+                    np.zeros((1, length or self.prefill_len), np.int32),
+                    zero, *((zero,) if self.prefix_caching else ()),
                     *self._state_args())
         B, c = self.num_slots, self.cache
         return (self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
@@ -1223,33 +1343,37 @@ class ServingEngine:
                   if self.prefix_caching else ()))
 
     def warm_prefill(self) -> None:
-        """Compile the prefill program by running it once with no
+        """Make the engine's programs (:meth:`_prepare`: every rung and
+        the decode program) and run the prefill once with no
         request admitted — what a replica does before it takes traffic:
         :meth:`prefill` dispatches nothing for an empty ``admit``, so
         the first request would otherwise compile inside a scheduler
-        round.  The one-row program runs at a free slot over an empty
-        prompt: the slot's length stays 0, a paged pool takes no write
-        (no position lies under ``p_len``), and a dense lane and the
-        slot's held token are read again only after the next admission
-        has overwritten them; every other slot stays bit-for-bit.  The
-        chunked program runs one window under an all-false ``admit``,
-        which holds the whole state."""
-        slot, rows, span = 0, self.num_slots, self.prefill_chunk
+        round.  The top rung's one-row program runs at a free slot over
+        an empty prompt: the slot's length stays 0, a paged pool takes no
+        write (no position lies under ``p_len``), and a dense lane and
+        the slot's held token are read again only after the next
+        admission has overwritten them; every other slot stays
+        bit-for-bit.  The chunked program runs one window under an
+        all-false ``admit``, which holds the whole state."""
+        slot, rows, key = 0, self.num_slots, "prefill"
+        span = self.prefill_chunk
         if self.prefill_chunk is None:
             free = np.flatnonzero(self.lengths == 0)
             if not free.size:
                 raise RuntimeError(
                     "warm_prefill needs a free slot: the one-row "
                     "program writes the lane of the slot it runs at")
-            slot, rows, span = int(free[0]), 1, self.prefill_len
+            slot, rows = int(free[0]), 1
+            span = key = self.prefill_len
         c = self.cache
         with telemetry.span("engine/prefill/dispatch",
                             loop_steps=self.cfg.block.loop_steps,
-                            rows=rows):
-            self._adopt(*self._prefill_jit(
+                            rows=rows, positions=rows * span):
+            self._adopt(*self._programs[key](
                 self.params, c.k, c.v, c.lengths, self._tok,
                 *self._blank_prefill_args(slot)))
-        self._count_prefill(rows, span)
+        self._count_prefill(rows, rows * span,
+                            {span: 1} if self.prefill_rungs else ())
         if self.draft is not None:
             self.draft.warm_prefill()
 
@@ -1291,12 +1415,13 @@ class ServingEngine:
                         jnp.int32(cs), p_lens_j, admit_j, *rest)
             with telemetry.span("engine/prefill/dispatch",
                                 loop_steps=self.cfg.block.loop_steps,
-                                rows=self.num_slots):
-                k, v, lengths, tok = self._prefill_jit(*args)
-                self._adopt(k, v, lengths, tok)
+                                rows=self.num_slots,
+                                positions=self.num_slots * C):
+                self._adopt(*self._programs["prefill"](*args))
             dispatched += 1
         # the chunk program computes every slot's window
-        self._count_prefill(dispatched * self.num_slots, C)
+        self._count_prefill(dispatched * self.num_slots,
+                            dispatched * self.num_slots * C)
         self.last_prefill_chunks = dispatched
 
     def decode(self, active):
@@ -1312,7 +1437,8 @@ class ServingEngine:
                     jnp.asarray(active_np), *self._state_args())
         with telemetry.span("engine/decode/dispatch",
                             loop_steps=self.cfg.block.loop_steps):
-            k, v, lengths, tok, toks, *rest = self._decode_jit(*args)
+            k, v, lengths, tok, toks, *rest = self._programs["decode"](
+                *args)
             n_state = len(self._state_args())
             self._adopt(k, v, lengths, tok, *rest[:n_state])
         with telemetry.span("engine/decode/fetch"):
@@ -1405,7 +1531,7 @@ class ServingEngine:
         tokens_in[:, 0] = tgt_tok
         tokens_in[:, 1:] = proposals.T
         c = self.cache
-        k, v, lengths, tok, choices = self._spec_verify_jit(
+        k, v, lengths, tok, choices = self._programs["verify"](
             self.params, c.k, c.v, c.lengths, self._tok,
             self.kv.table_arg(c), jnp.asarray(self._sample_seeds),
             jnp.asarray(tokens_in, jnp.int32), jnp.asarray(active_np))

@@ -117,6 +117,8 @@ def test_one_batcher_step_records_the_span_tree(cfg, params):
         "serve/prefill": {"serve/admit"},
         "engine/prefill/stage": {"serve/prefill"},
         "engine/prefill/dispatch": {"serve/prefill"},
+        # a fresh engine's first dispatch makes all of its programs
+        "engine/prepare": {"engine/prefill/dispatch"},
         "engine/prefill/register": {"serve/prefill"},
         "engine/prefill/fetch": {"serve/prefill"},
         "serve/decode": {"serve/step"},

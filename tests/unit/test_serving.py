@@ -592,8 +592,18 @@ def test_serving_telemetry_records_and_report(cfg, params, tmp_path):
     (lambda recs, trace: recs.pop(), "advanced together"),
     (lambda recs, trace: recs[1].update(value=1), "at least one position"),
     (lambda recs, trace: trace[0]["args"].pop("rows"), "without their `rows`"),
+    (lambda recs, trace: recs.append(
+        {"kind": "counter", "name": "engine/prefill_rung_rows/16",
+         "value": 2}), "a row runs at one rung"),
+    (lambda recs, trace: recs.append(
+        {"kind": "counter", "name": "engine/prefill_rung_rows/8",
+         "value": 4}), "a row runs at one rung"),
+    (lambda recs, trace: recs.append(
+        {"kind": "counter", "name": "engine/prefill_rung_rows/8",
+         "value": 3}), None),
     (lambda recs, trace: None, None),
-], ids=["one-counter", "positions-under-rows", "span-without-rows", "sound"])
+], ids=["one-counter", "positions-under-rows", "span-without-rows",
+        "rung-positions-over", "rung-rows-over", "rungs-sound", "sound"])
 def test_schema_gate_holds_the_prefill_work_counters(tmp_path, doctor, says):
     sys.path.insert(0, os.path.join(REPO, "tools"))
     try:

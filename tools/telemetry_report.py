@@ -90,6 +90,13 @@ _KV_BLOCK_GAUGES = ("serve/kv_blocks_free", "serve/kv_blocks_used")
 # accounting that says what the chip computed for the prompts it
 # admitted was dropped — --check fails it.
 _PREFILL_COUNTERS = ("engine/prefill_rows", "engine/prefill_positions")
+# A single-shot engine also says at which rung each row ran
+# (engine/prefill_rung_rows/<S>: rows dispatched through the [1, S]
+# program).  The rungs' rows are rows, and their positions positions: more
+# of either than the two counters above hold means a row was counted at a
+# rung it did not run at — --check fails it.  (Fewer is sound: a chunked
+# engine in the same run counts rows and no rung.)
+_RUNG_COUNTER = "engine/prefill_rung_rows/"
 # Routing counters of a routed FFN (autodist_tpu/serving/engine.py): every
 # decode window advances moe/layer_steps (steps x layers), moe/rows_routed
 # (the decoding rows' (row, expert) pairs), moe/rows_held (the pairs that
@@ -707,6 +714,18 @@ def check_schema(run_dir: str) -> list[str]:
                 f"{pos_c.get('value')!r} is under "
                 f"{_PREFILL_COUNTERS[0]} = {rows_c.get('value')!r} — a "
                 "row spans at least one position")
+        rungs = {int(n[len(_RUNG_COUNTER):]): c.get("value", 0)
+                 for n, c in counters.items()
+                 if n.startswith(_RUNG_COUNTER)}
+        if sum(rungs.values()) > rows_c.get("value", 0) \
+                or sum(S * n for S, n in rungs.items()) \
+                > pos_c.get("value", 0):
+            problems.append(
+                f"metrics.jsonl: the {_RUNG_COUNTER}<S> counters hold "
+                f"{rungs!r}, more rows or positions than "
+                f"{_PREFILL_COUNTERS[0]} = {rows_c.get('value')!r} and "
+                f"{_PREFILL_COUNTERS[1]} = {pos_c.get('value')!r} — a "
+                "row runs at one rung")
         bare = sum(1 for ev in trace_events
                    if ev.get("name") == "engine/prefill/dispatch"
                    and "rows" not in (ev.get("args") or {}))
