@@ -34,6 +34,11 @@ shape-dependent too):
   manager's stacked float32 recurrent state, each ``(slot, head)`` tile
   read once and written back in place; elected where it is called, from
   what the call observes (:data:`OBSERVED_KERNELS`).
+* :func:`~autodist_tpu.kernel.pallas.retention_step.retention_step_fused`
+  — one position of power retention over the same manager's state of
+  ``[offsets, 128, 128]`` tiles a key/value head, a slab of offsets a
+  grid step, the group's read-outs summed across the steps; elected
+  where it is called too.
 * :func:`~autodist_tpu.kernel.pallas.grouped_matmul.grouped_matmul` — a
   decode step's sorted (row, expert) pairs through the held experts that
   have rows, gate/up, SiLU and down in one call, each expert's weights
@@ -54,7 +59,7 @@ from __future__ import annotations
 # normalize_kernel re-exports this; kernel code stays IR-agnostic).
 KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                   "collective_matmul", "a2a_ring", "flash_attention",
-                  "delta_step", "grouped_matmul")
+                  "delta_step", "grouped_matmul", "retention_step")
 
 # Kernels that change the *training* program (the pipeline and expert
 # lowerings honor them); flash_decode/flash_prefill are serving-side
@@ -64,7 +69,8 @@ TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
 
 # Kernels elected where they are called, from what the call observes
 # (``models.transformer.attend``; ``serving.kv_cache.DenseLayout
-# .advance_state``; ``parallel.moe.routed_experts``).  The kernel slot says nothing about them unless
+# .advance_state`` and ``.advance_retention``;
+# ``parallel.moe.routed_experts``).  The kernel slot says nothing about them unless
 # someone overrides: ``True`` takes the kernel wherever it can run,
 # ``False`` forbids it (the composed path, for a comparison) — the one
 # ``False`` the canonical slot keeps.  The word reaches ``attend``
@@ -72,7 +78,8 @@ TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
 # and pipeline lowerings open around the model they trace, the serving
 # layout from the engine that builds it, and the routed layer through
 # the engine's ``_ffn``.
-OBSERVED_KERNELS = ("flash_attention", "delta_step", "grouped_matmul")
+OBSERVED_KERNELS = ("flash_attention", "delta_step", "grouped_matmul",
+                    "retention_step")
 
 # Op-metadata marker prefix: `with jax.named_scope(kernel_marker(name))`
 # around a pallas_call stamps every emitted op's `op_name` metadata, and

@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from autodist_tpu.models.transformer import (EncoderLayer,
                                              TransformerConfig, attend)
@@ -122,7 +123,7 @@ def _bias(p, dtype):
 
 
 def attention_inputs(cfg: TransformerConfig, chunk, x, positions,
-                     model_axis, comm_overlap=None):
+                     model_axis, comm_overlap=None, mixer="attention"):
     """The layer up to its attention: ``(x, q, k, v)`` — the residual
     stream in ``cfg.dtype`` and the local heads' projections of it (of
     its norm under sandwich placement), q and k rotated where positions
@@ -130,16 +131,18 @@ def attention_inputs(cfg: TransformerConfig, chunk, x, positions,
     step and the chunk window, which differ only in how they attend.
     Returns ``(x, q, k, v, gate)``: ``k`` and ``v`` carry
     ``cfg.kv_heads`` heads, and ``gate`` (``None`` but in an
-    ``attn_gate`` block) is :func:`attention_residual`'s."""
+    ``attn_gate`` block) is :func:`attention_residual`'s.  ``mixer``
+    names the chunk's sub-tree that holds the projections, and the scope
+    they wear (a retention layer's: ``"linear_attention"``)."""
     from autodist_tpu.parallel.tensor import column_parallel
 
     spec, dtype = cfg.block, cfg.dtype
-    att = chunk["attention"]
+    att = chunk[mixer]
     x = x.astype(dtype)
     h = (block_norm(cfg, x, chunk["ln_attention_in"])
          if spec.norm_placement in ("sandwich", "pre") else x)
     gate = None
-    with scope("attention"):
+    with scope(mixer):
         qkv = column_parallel(h, att["qkv"]["kernel"].astype(dtype),
                               _bias(att["qkv"], dtype),
                               model_axis=model_axis,
@@ -181,14 +184,15 @@ def _residual(cfg, x, y, chunk, after):
 
 
 def attention_residual(cfg: TransformerConfig, chunk, x, out, model_axis,
-                       comm_overlap=None, gate=None):
+                       comm_overlap=None, gate=None, mixer="attention"):
     """Attention's output projection (of ``out * sigmoid(gate)`` in a
-    gated block) and its residual add and norm."""
+    gated block) and its residual add and norm.  ``mixer``: as
+    :func:`attention_inputs`'s."""
     from autodist_tpu.parallel.tensor import row_parallel
 
     dtype = cfg.dtype
-    att = chunk["attention"]
-    with scope("attention"):
+    att = chunk[mixer]
+    with scope(mixer):
         if gate is not None:
             out = (out.astype(jnp.float32) * jax.nn.sigmoid(
                 gate.astype(jnp.float32))).astype(dtype)
@@ -608,13 +612,186 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
     return _residual(cfg, x, y, chunk, "ln_attention"), (tail, ssm)
 
 
+# --------------------------------------------------------------------- #
+# the linear mixer: power retention
+# --------------------------------------------------------------------- #
+# q, k and v are attention's own (grouped heads, a norm a head, rotary);
+# a log gate gamma <= 0 a key/value head and position.  In the attention
+# form position t weighs position s <= t by
+#     a_ts = exp(G_t - G_s) (q_t . k_s)^2,  G_t = sum_{r <= t} gamma_r
+# (q scaled by d^-0.5) and y_t = sum_s a_ts v_s / (sum_s a_ts + eps).
+# With phi(x) the symmetric square of x, phi(q) . phi(k) = (q . k)^2, so
+# a key/value head carries the same sums forward as a state S and a
+# normaliser z, float32:
+#     S_t = exp(gamma_t) S_{t-1} + phi(k_t) v_t^T;  z_t likewise of phi(k_t)
+#     y_t = S_t^T phi(q_t) / (z_t . phi(q_t) + eps)   (decay, write, READ)
+# phi is laid out by offset: phi(x)[o, i] = c_o x_i x_{(i + o) mod d} for
+# o = 0 .. d / 2, c = 1 at o = 0 (the squares) and at o = d / 2 (whose
+# pairs each appear twice), sqrt(2) between: (d / 2 + 1) d entries for
+# d (d + 1) / 2 distinct products, and a row is one rotation of x.  S is
+# [offsets, dv, d], an offset's tile with d on its last axis.
+RETENTION_CHUNK = 64   # positions a chunk of the chunked form spans
+RETENTION_EPS = 1e-6   # what the normaliser's sum is kept above
+
+
+def symmetric_square(x):
+    """``phi(x)`` ``[.., d / 2 + 1, d]`` of ``x`` ``[.., d]`` (``d``
+    even), float32: ``phi(q) . phi(k) == (q . k) ** 2`` over both axes."""
+    d = x.shape[-1]
+    x = x.astype(jnp.float32)
+    rolled = jnp.stack([jnp.roll(x, -o, -1) for o in range(d // 2 + 1)], -2)
+    c = np.full((d // 2 + 1, 1), 2.0 ** 0.5, np.float32)
+    c[0] = c[d // 2] = 1.0      # the squares; the pairs that appear twice
+    return x[..., None, :] * rolled * c
+
+
+def retention_step(q, k, v, g, state, eps: float = RETENTION_EPS):
+    """One position of the recurrence.  ``q`` ``[B, heads, d]`` (scaled);
+    ``k``, ``v`` ``[B, kv, d]``; ``g`` ``[B, kv]`` (log gate, <= 0);
+    ``state``: ``(S [B, kv, offsets, dv, d], z [B, offsets, kv, d])``
+    float32 (``LinearMixerSpec.normaliser_shape`` says why the heads lie
+    inside the offsets there).  Query head ``i`` reads key/value head ``i // (heads //
+    kv)``.  Returns ``(y [B, heads, dv], (S, z))``: the state decayed,
+    written to and THEN read, so a position sees itself."""
+    S, z = state
+    B, kv = k.shape[:2]
+    f32 = lambda t: t.astype(jnp.float32)
+    with scope("state_update"):
+        decay = jnp.exp(f32(g))
+        pk = symmetric_square(k)                         # [B, kv, O, d]
+        pq = symmetric_square(q).reshape(B, kv, -1, *pk.shape[-2:])
+        S = S * decay[..., None, None, None] \
+            + f32(v)[:, :, None, :, None] * pk[..., None, :]
+        z = z * decay[:, None, :, None] + jnp.swapaxes(pk, 1, 2)
+        num = jnp.einsum("bgoai,bghoi->bgha", S, pq, precision=_HI)
+        den = jnp.einsum("bogi,bghoi->bgh", z, pq, precision=_HI)
+        y = num / (den[..., None] + eps)
+        return y.reshape(B, -1, y.shape[-1]), (S, z)
+
+
+def retention_chunked(q, k, v, g, state, chunk: int = RETENTION_CHUNK,
+                      eps: float = RETENTION_EPS):
+    """The recurrence over a window, ``chunk`` positions at a time.
+    ``q`` ``[B, T, heads, d]`` (scaled); ``k``, ``v`` ``[B, T, kv, d]``;
+    ``g`` ``[B, T, kv]``; ``state`` as :func:`retention_step`'s.  Returns
+    ``(y [B, T, heads, dv], state after position T - 1)``.  A position
+    with ``g == 0`` and ``k == 0`` leaves the state bit for bit (a padded
+    position; the window is padded so to whole chunks).
+
+    A chunk attends inside itself in the attention form (``[C, C]``
+    scores squared, the gates' differences taken before the ``exp``),
+    reads the state before it through ``phi(q)`` — built for this chunk
+    alone — and leaves the state after it; the state moves once a
+    chunk."""
+    B, T, H, d = q.shape
+    kv, C = k.shape[2], chunk
+    f32 = lambda t: t.astype(jnp.float32)
+    q, k, v, g = map(f32, (q, k, v, g))
+    pad = -T % C
+    if pad:
+        q, k, v, g = (jnp.pad(t, [(0, 0), (0, pad)]
+                              + [(0, 0)] * (t.ndim - 2))
+                      for t in (q, k, v, g))
+    N = (T + pad) // C
+    def split(t):
+        """``[B, T, heads.., x]`` -> ``[N, B, heads.., C, x]``: chunks
+        first, a chunk's positions beside the last axis."""
+        t = jnp.moveaxis(t, 1, -2)
+        return jnp.moveaxis(
+            t.reshape(*t.shape[:-2], N, C, t.shape[-1]), -3, 0)
+
+    q = split(q.reshape(B, T + pad, kv, H // kv, d))     # [N,B,kv,grp,C,d]
+    k, v = split(k), split(v)                            # [N,B,kv,C,d]
+    g = split(g[..., None])[..., 0]                      # [N,B,kv,C]
+    mm = lambda eq, a, b: jnp.einsum(eq, a, b, precision=_HI)
+    upto = jnp.tril(jnp.ones((C, C), bool))              # s <= t
+
+    def one_chunk(state, c):
+        S, z = state
+        q_c, k_c, v_c, g_c = c
+        G = jnp.cumsum(g_c, -1)                          # [B, kv, C]
+        decay = jnp.where(upto, jnp.exp(jnp.where(
+            upto, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+        a = mm("bghtd,bgsd->bghts", q_c, k_c) ** 2 * decay[:, :, None]
+        pq = symmetric_square(q_c)                       # [B,kv,grp,C,O,d]
+        into = jnp.exp(G)[:, :, None, :]                 # [B, kv, 1, C]
+        num = mm("bghts,bgsv->bghtv", a, v_c) \
+            + into[..., None] * mm("bghtoi,bgoai->bghta", pq, S)
+        den = a.sum(-1) + into * mm("bghtoi,bogi->bght", pq, z)
+        last = G[..., -1:]                               # [B, kv, 1]
+        pk = symmetric_square(k_c) \
+            * jnp.exp(last - G)[..., None, None]         # [B, kv, C, O, d]
+        S = S * jnp.exp(last)[..., None, None] \
+            + mm("bgsoi,bgsa->bgoai", pk, v_c)
+        z = z * jnp.exp(last)[:, None] + jnp.swapaxes(pk.sum(2), 1, 2)
+        return (S, z), num / (den[..., None] + eps)
+
+    state, y = jax.lax.scan(one_chunk, state, (q, k, v, g))
+    # [N, B, kv, grp, C, dv] -> [B, T, heads, dv]
+    y = jnp.moveaxis(y, 0, 3).reshape(B, H, N * C, -1)
+    return jnp.moveaxis(y, 1, 2)[:, :T], state
+
+
+def retention_attention(cfg: TransformerConfig, chunk, x, state, positions,
+                        *, valid=None, step=retention_step):
+    """The power-retention mixer with its residual: ``(x + mixer(N(x)),
+    (S, z))``.  ``x``: ``[B, S, H]`` at absolute ``positions``; ``state``
+    before the window, as :func:`retention_step`'s.  q, k and v are
+    :func:`attention_inputs`' (grouped heads, q/k norm, rotary) from the
+    layer's ``linear_attention`` sub-tree, which holds the gate's ``[H,
+    kv]`` projection beside them; the log gate ``log sigmoid`` of it is
+    float32 end to end.  One position runs the recurrence, a longer
+    window the chunked form; ``valid`` and ``step`` are
+    :func:`linear_attention`'s."""
+    spec = cfg.block
+    la = chunk["linear_attention"]
+    x, q, k, v, _ = attention_inputs(cfg, chunk, x, positions, None,
+                                     mixer="linear_attention")
+    h = (block_norm(cfg, x, chunk["ln_attention_in"])
+         if spec.norm_placement in ("sandwich", "pre") else x)
+    f32 = lambda t: t.astype(jnp.float32)
+    with scope("linear_attention"):
+        g = jax.nn.log_sigmoid(jnp.matmul(
+            f32(h), f32(la["gate"]["kernel"]), precision=_HI))
+        q, k, v = f32(q) * cfg.head_dim ** -0.5, f32(k), f32(v)
+        if valid is not None:       # a padded position: no decay, no write
+            g = g * valid[..., None]
+            k = k * valid[..., None, None]
+        if x.shape[1] == 1:
+            y, state = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], state)
+            y = y[:, None]
+        else:
+            with scope("state_update"):
+                y, state = retention_chunked(q, k, v, g, state)
+    x = attention_residual(cfg, chunk, x, y.astype(cfg.dtype), None,
+                           mixer="linear_attention")
+    return x, state
+
+
+def mix_linear(cfg: TransformerConfig, chunk, x, state, positions, *,
+               valid=None, length=None, step=None):
+    """A ``"linear"`` layer's mixer with its residual, whichever
+    recurrence ``cfg.block.linear.rule`` names: ``(x + mixer(N(x)),
+    state)``.  ``state`` is the tuple of arrays the rule keeps (the delta
+    rule's ``(tail, S)``, retention's ``(S, z)``); ``positions`` reach
+    the rule whose q and k are rotated."""
+    kw = {} if step is None else {"step": step}
+    if cfg.block.linear.rule == "retention":
+        return retention_attention(cfg, chunk, x, state, positions,
+                                   valid=valid, **kw)
+    return linear_attention(cfg, chunk, x, state, valid=valid,
+                            length=length, **kw)
+
+
 def blank_linear_state(cfg: TransformerConfig, batch: int):
-    """The state before position 0: no inputs, ``S = 0``."""
+    """The state before position 0: no inputs, ``S = 0`` (and, of
+    retention, ``z = 0``)."""
     lin = cfg.block.linear
+    ssm = jnp.zeros((batch, *lin.state_shape), jnp.float32)
+    if lin.has_normaliser:
+        return (ssm, jnp.zeros((batch, *lin.normaliser_shape), jnp.float32))
     return (jnp.zeros((batch, lin.conv_taps - 1, lin.conv_channels),
-                      cfg.dtype),
-            jnp.zeros((batch, lin.value_heads, lin.key_dim, lin.value_dim),
-                      jnp.float32))
+                      cfg.dtype), ssm)
 
 
 def _swiglu(h, wi, wo):
@@ -753,8 +930,10 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
                          valid=valid)
         return (y, row[:, :, None, :], None) if return_kv else y
     if "linear_attention" in chunk:
-        x, _ = linear_attention(cfg, chunk, x,
-                                blank_linear_state(cfg, x.shape[0]))
+        if positions is None:
+            positions = jnp.arange(x.shape[1])
+        x, _ = mix_linear(cfg, chunk, x, blank_linear_state(cfg, x.shape[0]),
+                          positions)
         return ffn_residual(cfg, chunk, x, model_axis, comm_overlap)
     if positions is None and cfg.block.positions == "rope":
         positions = jnp.arange(x.shape[1])
@@ -914,9 +1093,12 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     is a channel's holds ``qkv`` ``[H, q | k | v]``, ``decay`` ``[H,
     value_heads * key_dim]``, ``gate`` ``[H, value_heads * value_dim]``
     and ``beta`` ``[H, value_heads]`` in place of ``qkvz`` and ``ba``,
-    and ``dt_bias`` a channel.  A router with a correction holds it
-    beside its kernel, ``correction`` ``[num_experts]``.  A
-    zero-centred norm's leaf is ``weight``."""
+    and ``dt_bias`` a channel.  A power-retention mixer holds attention's
+    own leaves under ``linear_attention`` — ``qkv``, ``out`` and, in a
+    ``qk_norm`` block, ``q_norm`` and ``k_norm``, stacked over its layers
+    — and the gate's ``gate`` ``[H, kv_heads]`` beside them.  A router
+    with a correction holds it beside its kernel, ``correction``
+    ``[num_experts]``.  A zero-centred norm's leaf is ``weight``."""
     spec = cfg.block
     L, H, M, V = cfg.num_layers, cfg.hidden_size, cfg.mlp_dim, \
         cfg.vocab_size
@@ -935,17 +1117,23 @@ def param_shapes(cfg: TransformerConfig) -> dict:
                    else {})}
 
     wi = 2 * M if spec.ffn == "swiglu" else M
+    retention = spec.linear is not None and spec.linear.rule == "retention"
+    # the layers whose mixer projects attention's q, k and v
+    La = kinds.count("linear") if retention else Lf
     if spec.is_default:
         qkv = dense((H, 3, n, d), (3, n, d))
     elif spec.attn_gate or kv != n:
         qkv = dense((H, n * d * (2 if spec.attn_gate else 1) + 2 * kv * d),
-                    (), Lf)
+                    (), La)
     else:
-        qkv = dense((H, 3 * n * d), (3 * n * d,))
+        qkv = dense((H, 3 * n * d), (3 * n * d,), La)
     # a routed stack's leading layers alone carry the dense FFN
     Ld = spec.dense_layers if spec.moe is not None else L
+    attention = {"qkv": qkv, "out": dense((n, d, H), (H,), La)}
+    if spec.qk_norm:
+        attention.update(q_norm=norm((La,), d), k_norm=norm((La,), d))
     stages = {
-        "attention": {"qkv": qkv, "out": dense((n, d, H), (H,), Lf)},
+        "attention": attention,
         "mlp": {"wi": dense((H, wi), (wi,), Ld),
                 "wo": dense((M, H), (H,), Ld)}}
     if not Lf:
@@ -961,10 +1149,12 @@ def param_shapes(cfg: TransformerConfig) -> dict:
             "out": dense((n * lat.value_dim, H), (), Lt)}
         if spec.attn_gate:
             stages["latent_attention"]["gate"] = dense((H, n), (), Lt)
-    if spec.qk_norm:
-        stages["attention"].update(q_norm=norm((Lf,), d),
-                                   k_norm=norm((Lf,), d))
-    if spec.linear is not None:
+    if retention:
+        # attention's own projections and norms feed the recurrence; the
+        # gate's projection a key/value head rides with them
+        stages["linear_attention"] = dict(attention,
+                                          gate=dense((H, kv), (), La))
+    elif spec.linear is not None:
         lin, Ll = spec.linear, kinds.count("linear")
         inner = lin.value_heads * lin.value_dim
         stages["linear_attention"] = {

@@ -24,21 +24,37 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class LinearMixerSpec:
-    """A gated-DeltaNet mixer's sizes: ``key_heads`` key (and query)
-    heads of ``key_dim``, ``value_heads`` value heads of ``value_dim``
-    (each key head serves ``value_heads // key_heads`` of them), a
-    depthwise causal convolution of ``conv_taps`` taps over the
-    ``[q, k, v]`` channels.  The state is one ``[key_dim, value_dim]``
-    float32 matrix a value head.
+    """A recurrent mixer's sizes, and which recurrence it runs (``rule``).
 
-    ``gate`` says what the state's decay is: ``"head"`` — one log decay
-    a head and position, ``-exp(A_log) softplus(a + dt_bias)``, beside a
-    fused ``qkvz`` / ``ba`` pair of projections and a SiLU output gate
-    (gated DeltaNet) — or ``"channel"`` — ``key_dim`` a head, row ``i``
-    of the state decaying by its own ``gate_floor * sigmoid(exp(A_log)
-    (f_i + dt_bias_i))``, bounded below by ``gate_floor`` (< 0), with q,
-    k and v projected each on its own beside the decay's and a sigmoid
-    output gate's projections (Kimi Delta Attention)."""
+    ``"delta"`` — gated DeltaNet: ``key_heads`` key (and query) heads of
+    ``key_dim``, ``value_heads`` value heads of ``value_dim`` (each key
+    head serves ``value_heads // key_heads`` of them), a depthwise causal
+    convolution of ``conv_taps`` taps over the ``[q, k, v]`` channels.
+    The state is one ``[key_dim, value_dim]`` float32 matrix a value
+    head.  ``gate`` says what the state's decay is: ``"head"`` — one log
+    decay a head and position, ``-exp(A_log) softplus(a + dt_bias)``,
+    beside a fused ``qkvz`` / ``ba`` pair of projections and a SiLU
+    output gate (gated DeltaNet) — or ``"channel"`` — ``key_dim`` a head,
+    row ``i`` of the state decaying by its own ``gate_floor *
+    sigmoid(exp(A_log) (f_i + dt_bias_i))``, bounded below by
+    ``gate_floor`` (< 0), with q, k and v projected each on its own
+    beside the decay's and a sigmoid output gate's projections (Kimi
+    Delta Attention).
+
+    ``"retention"`` — power retention (:meth:`retention` builds it): q, k
+    and v are attention's own (``BlockSpec.kv_heads`` key/value heads of
+    ``BlockSpec.head_dim``, q/k norm and rotary as the block says, the
+    query heads grouped over them the way grouped-query attention groups
+    them), no convolution, no write strength, one log gate a key/value
+    head and position.  A KEY/VALUE head keeps the state: the sum of
+    ``phi(k) v^T`` with ``phi`` the key's symmetric ``power``-th (2nd)
+    power, ``key_dim (key_dim + 1) / 2`` distinct products, and a
+    normaliser, the sum of ``phi(k)``, beside it.  They are laid out by
+    offset (:attr:`state_offsets`): row ``(o, i)`` is ``k_i k_{(i + o)
+    mod key_dim}`` for ``o = 0 .. key_dim / 2`` — a lane rotation builds
+    a whole row — so the last offset's pairs are held twice:
+    ``(key_dim / 2 + 1) key_dim`` rows (:attr:`state_rows`) for
+    :attr:`state_rows_packed` distinct ones."""
 
     key_heads: int
     value_heads: int
@@ -47,8 +63,13 @@ class LinearMixerSpec:
     conv_taps: int = 4
     gate: str = "head"
     gate_floor: float = 0.0
+    rule: str = "delta"
+    power: int = 1
 
     def __post_init__(self):
+        if self.rule not in ("delta", "retention"):
+            raise ValueError(f"LinearMixerSpec.rule={self.rule!r}: one of "
+                             "('delta', 'retention')")
         if self.value_heads % self.key_heads:
             raise ValueError("LinearMixerSpec.value_heads must be a "
                              "multiple of key_heads")
@@ -59,12 +80,96 @@ class LinearMixerSpec:
             raise ValueError("LinearMixerSpec.gate_floor (< 0) is the "
                              "'channel' gate's lower bound: both or "
                              "neither")
+        if self.rule == "retention" and (
+                self.power != 2 or self.conv_taps or self.gate != "head"
+                or self.key_heads != self.value_heads
+                or self.key_dim != self.value_dim or self.key_dim % 2):
+            raise ValueError(
+                "power retention is served at degree 2, with no "
+                "convolution and no gate kind, a state a key/value head "
+                "of even size (LinearMixerSpec.retention builds it)")
+        if self.rule == "delta" and self.power != 1:
+            raise ValueError("the delta rule reads its key as it is: "
+                             "LinearMixerSpec.power is retention's degree")
+
+    @classmethod
+    def retention(cls, kv_heads: int, head_dim: int, power: int = 2):
+        """Power retention over ``kv_heads`` key/value heads of
+        ``head_dim`` (the block's own ``kv_heads`` and ``head_dim``)."""
+        return cls(kv_heads, kv_heads, head_dim, head_dim, conv_taps=0,
+                   rule="retention", power=power)
 
     @property
     def conv_channels(self) -> int:
         """Channels the convolution runs over: q, k and v, flat."""
         return 2 * self.key_heads * self.key_dim \
             + self.value_heads * self.value_dim
+
+    # ---- what the cache manager holds a slot and layer: read from here,
+    # never spelled as shapes there ------------------------------------
+    @property
+    def has_conv(self) -> bool:
+        """Whether a slot keeps a convolution tail (``conv_taps - 1``
+        rows of its input)."""
+        return self.rule == "delta"
+
+    @property
+    def has_normaliser(self) -> bool:
+        """Whether a normaliser rides beside the state matrix."""
+        return self.rule == "retention"
+
+    @property
+    def state_heads(self) -> int:
+        """Heads that hold a state: the value heads of the delta rule,
+        the key/value heads of retention."""
+        return self.value_heads
+
+    @property
+    def state_offsets(self) -> int:
+        """Retention's offsets ``o`` (see the class): ``key_dim / 2 + 1``."""
+        return self.key_dim // 2 + 1
+
+    @property
+    def state_rows(self) -> int:
+        """Rows of ``value_dim`` a head's state holds, as laid out."""
+        if self.rule == "delta":
+            return self.key_dim
+        return self.state_offsets * self.key_dim
+
+    @property
+    def state_rows_packed(self) -> int:
+        """The distinct rows among them: what the recurrence needs."""
+        if self.rule == "delta":
+            return self.key_dim
+        return self.key_dim * (self.key_dim + 1) // 2
+
+    @property
+    def state_shape(self) -> tuple:
+        """A slot's state matrix in one layer: ``[heads, key_dim,
+        value_dim]``, or retention's ``[heads, offsets, value_dim,
+        key_dim]`` (an offset's tile holds ``key_dim`` on the lanes)."""
+        if self.rule == "delta":
+            return (self.value_heads, self.key_dim, self.value_dim)
+        return (self.key_heads, self.state_offsets, self.value_dim,
+                self.key_dim)
+
+    @property
+    def normaliser_shape(self) -> tuple:
+        """A slot's normaliser in one layer (``has_normaliser``):
+        ``[offsets, heads, key_dim]`` — the heads inside the offsets, so
+        that the two minor dimensions are whole ``(8, 128)`` tiles at 8
+        heads of 128 and the chip keeps the array as it is declared (65
+        offsets second-minor it pads, and re-lays the array out around
+        every kernel call)."""
+        return (self.state_offsets, self.key_heads, self.key_dim)
+
+    @property
+    def state_floats(self) -> int:
+        """float32 values a slot holds in one layer, normaliser and all."""
+        n = int(np.prod(self.state_shape))
+        if self.has_normaliser:
+            n += int(np.prod(self.normaliser_shape))
+        return n
 
 
 @dataclasses.dataclass(frozen=True)
@@ -224,7 +329,12 @@ class BlockSpec:
     * ``layer_period`` — the kinds of layer the stack repeats in order:
       ``"full"`` (softmax attention over cached keys and values),
       ``"linear"`` (``linear``'s recurrent mixer) and ``"latent"``
-      (``latent``'s attention); empty: every layer alike.
+      (``latent``'s attention); empty: every layer alike.  ``("linear",)``
+      is a stack with no caching layer at all.  Where the mixer is power
+      retention (``linear.rule``), ``qk_norm``, ``kv_heads``,
+      ``head_dim`` and rotary ``positions`` are the LINEAR layers' q and
+      k (attention's own projections feed the recurrence); the delta
+      rule has its own and refuses them in a stack without full layers.
     * ``latent`` — the sizes of the ``"latent"`` layers' attention
       (:class:`LatentAttentionSpec`): the cache holds one row a position
       and not keys and values a head.  With an empty ``layer_period``
@@ -321,6 +431,24 @@ class BlockSpec:
                 "rotary key: BlockSpec.latent goes with positions='rope' "
                 "and none of bias, loop_steps, qk_norm, kv_heads, "
                 "head_dim, rope_fraction")
+        if self.linear is not None:
+            retention = self.linear.rule == "retention"
+            attends = "full" in self.layer_period
+            if retention and (attends or self.attn_gate or (
+                    self.linear.key_heads, self.linear.key_dim)
+                    != (self.kv_heads, self.head_dim)):
+                raise ValueError(
+                    "power retention reads the block's own q, k and v: "
+                    "BlockSpec.kv_heads and head_dim are its "
+                    "LinearMixerSpec's heads and size, with no 'full' "
+                    "layer beside it and no attn_gate")
+            if not retention and not attends and (
+                    self.qk_norm or self.kv_heads or self.head_dim):
+                raise ValueError(
+                    "qk_norm, kv_heads and head_dim are attention's (or "
+                    "power retention's) q and k: a delta-rule mixer has "
+                    "its own heads and l2 norm, and this layer_period "
+                    "has no 'full' layer")
         if self.rope_interleave and self.latent is None:
             raise ValueError("BlockSpec.rope_interleave pairs the rotary "
                              "dimensions of latent attention's positional "

@@ -136,8 +136,8 @@ def check_handoff_block(engine, name: str = "engine") -> None:
         raise ValueError(
             f"{name}: the disaggregated handoff copies blocks of keys "
             f"and values; the block's {engine.linear_layers} linear "
-            "(gated-DeltaNet) layers keep a recurrent state a slot, "
-            "which it would leave behind")
+            "layers keep a recurrent state a slot, which it would leave "
+            "behind")
     if getattr(engine, "latent_layers", 0):
         raise ValueError(
             f"{name}: the disaggregated handoff copies blocks of keys "

@@ -79,6 +79,12 @@ class DecodeWindow:
 MIN_PREFILL_RUNG = 256
 
 
+# what a linear mixer's rule is called where a refusal names it, and
+# the kernel that advances its state by a position
+_RULES = {"delta": "delta-rule", "retention": "power-retention"}
+_STATE_KERNELS = {"delta": "delta_step", "retention": "retention_step"}
+
+
 def prefill_rungs(prefill_len: int) -> tuple:
     """The prompt lengths an engine builds a one-row prefill program for,
     ascending: ``prefill_len`` and its halves down to
@@ -288,8 +294,9 @@ class ServingEngine:
                 f"tensor_parallel={tensor_parallel} with a non-default "
                 f"block ({spec}): the Megatron rule tables name the "
                 "default block's leaves only (no rule splits a linear "
-                "mixer's heads, its recurrent state or a routed FFN's "
-                "experts)")
+                "mixer's heads, its recurrent state — a delta rule's "
+                "value heads, power retention's key/value heads — or a "
+                "routed FFN's experts)")
         # a mixed stack: the full layers cache keys and values (the
         # latent ones a row), the linear ones keep a recurrent state
         # (kv_cache.RecurrentState)
@@ -313,9 +320,9 @@ class ServingEngine:
                 raise ValueError(
                     f"{knob}: {what} over recurrent state is not served "
                     f"— the block's {self.linear_layers} linear "
-                    "(gated-DeltaNet) layers keep a state a slot that "
-                    "cannot be rolled back, shared by blocks or cut at a "
-                    "chunk's edge")
+                    f"({_RULES[spec.linear.rule]}) layers keep a state a "
+                    "slot that cannot be rolled back, shared by blocks or "
+                    "cut at a chunk's edge")
             if asked and spec.latent is not None:
                 raise ValueError(
                     f"{knob}: {what} with a latent KV row is not served "
@@ -330,7 +337,8 @@ class ServingEngine:
                     "key/value heads) is not served — the block table's "
                     "readers take a key/value head a query head")
         # the cache holds every pass's keys and values: a layer's input
-        # differs from pass to pass, so its projections do too
+        # differs from pass to pass, so its projections do too (no layer
+        # at all where every one is a linear one)
         self.cache_layers = (cfg.num_layers - self.linear_layers) \
             * spec.loop_steps
         # of them, the layers whose cached position is a latent row
@@ -511,7 +519,7 @@ class ServingEngine:
                     "the fused decode kernel reads a key/value head a "
                     "query head; such a block decodes through "
                     "cached_attention, which groups them")
-            if not grouped and (forced or (
+            if self.cache_layers and not grouped and (forced or (
                     decode_left_open and jax.default_backend() == "tpu")):
                 from autodist_tpu.kernel.pallas.flash_decode import (
                     MIN_FUSED_DECODE_LEN, fused_decode_block)
@@ -540,17 +548,20 @@ class ServingEngine:
         telemetry.gauge("engine/kv_bytes_per_token").set(
             held["kv_bytes_per_token"])
         if recurrent:
+            # the recurrent state: its bytes a slot and over all slots,
+            # and the rows a head holds as they are laid out
             telemetry.gauge("engine/state_bytes_per_slot").set(
                 held["state_bytes_per_slot"])
+            telemetry.gauge("kv/state_bytes").set(
+                held["state_bytes_per_slot"] * self.num_slots)
+            telemetry.gauge("kv/state_rows").set(spec.linear.state_rows)
         if recurrent and spec.latent is not None:
             # two kinds of state in the one manager: how many layers of
-            # each, and the bytes it holds of each over all slots
+            # each, and the bytes the rows take over all slots
             telemetry.gauge("kv/latent_layers").set(self.latent_layers)
             telemetry.gauge("kv/linear_layers").set(self.linear_layers)
             telemetry.gauge("kv/row_bytes").set(
                 held["kv_bytes_per_token"] * self.max_len * self.num_slots)
-            telemetry.gauge("kv/state_bytes").set(
-                held["state_bytes_per_slot"] * self.num_slots)
         if spec.moe is not None:
             telemetry.gauge("engine/experts_held").set(spec.moe.experts_held)
             # a decode step's routed layer: 1 this repo's grouped-matmul
@@ -630,9 +641,11 @@ class ServingEngine:
             emit_kernel_gauges(gauges)
         if self.linear_layers:
             # the recurrent state's decode step: 1 where the layout's
-            # seam takes the fused kernel, 0 where the composed step
-            telemetry.gauge("kernel/delta_step_elected").set(
-                int(self.kv.state_kernel(self.cache.state.ssm)))
+            # seam takes the rule's fused kernel, 0 where the composed step
+            telemetry.gauge(
+                f"kernel/{_STATE_KERNELS[spec.linear.rule]}_elected").set(
+                int(self.kv.state_kernel(self.cache.state.ssm,
+                                         cfg.num_heads // cfg.kv_heads)))
         account.constructed(t_init)
 
     def __setattr__(self, name, value):
@@ -743,18 +756,26 @@ class ServingEngine:
                             self.comm_overlap, valid=valid, tally=tally,
                             kernel=self.kernel.get("grouped_matmul"))
 
-    def _layer_linear(self, chunk, x, state, layer, *, valid=None,
-                      tally=None):
-        """A decode step of one linear (gated-DeltaNet) layer against the
-        recurrent state the cache manager holds (``state``: its arrays;
-        ``layer``: the layer's place among the linear ones).  It advances
-        every slot's rows: the recurrent matrix where the manager keeps
-        it, through the layout's seam (``self.kv.advance_state``: the
-        stacked array goes in and comes out, no slice of it here), the
-        convolution's tail read and written here.  (The prompt's pass
-        through such a layer is :meth:`_build_prefill`'s.)"""
+    def _layer_linear(self, chunk, x, state, layer, positions, *,
+                      valid=None, tally=None):
+        """A decode step of one linear layer against the recurrent state
+        the cache manager holds (``state``: its arrays; ``layer``: the
+        layer's place among the linear ones; ``positions``: the rows',
+        for a rule whose q and k are rotated).  It advances every slot's
+        rows: the recurrent matrix (and power retention's normaliser)
+        where the manager keeps it, through the layout's seam
+        (``self.kv.advance_state`` / ``advance_retention``: the stacked
+        arrays go in and come out, no slice of them here), the delta
+        rule's convolution tail read and written here.  (The prompt's
+        pass through such a layer is :meth:`_build_prefill`'s.)"""
         from autodist_tpu.models import pipeline_lm as lm
 
+        if self.cfg.block.linear.rule == "retention":
+            x, state = lm.retention_attention(
+                self.cfg, chunk, x, state, positions,
+                step=functools.partial(self.kv.advance_retention,
+                                       layer=layer))
+            return self._ffn(chunk, x, valid, tally), state
         # both arrays, as the benchmark's planted faults wrap it (the
         # slice of the matrices is dead code, and compiled away)
         tail, _ = kv_cache.read_state(state, layer)
@@ -930,9 +951,9 @@ class ServingEngine:
             # previous occupant left one that is nobody's — masks the
             # padding out of the recurrence and cuts the convolution's
             # tail at p_len
-            mix_prompt = once(lambda chunk, x: lm.linear_attention(
+            mix_prompt = once(lambda chunk, x: lm.mix_linear(
                 self.cfg, chunk, x, lm.blank_linear_state(self.cfg, 1),
-                valid=valid, length=p_len))
+                positions, valid=valid, length=p_len))
             ffn = once(lambda chunk, x: self._ffn(chunk, x, valid))
 
             def layer_fn(chunk, x, kc, vc, _, layer):
@@ -1085,7 +1106,8 @@ class ServingEngine:
                             q, k, v, kc, vc, layer, lengths, table, active,
                             dtype=self.cfg.dtype), valid, tally),
                     lambda chunk, x, state, layer: self._layer_linear(
-                        chunk, x, state, layer, valid=valid, tally=tally))
+                        chunk, x, state, layer, lengths[:, None],
+                        valid=valid, tally=tally))
                 # The emitted token conditions on lengths + 1 tokens
                 # (the one just written included) — its sampling key.
                 nxt, _ = self._next_token(shared, x[:, 0], seeds,
@@ -1316,6 +1338,9 @@ class ServingEngine:
             return
         telemetry.counter("engine/prefill_rows").inc(rows)
         telemetry.counter("engine/prefill_positions").inc(positions)
+        if self.linear_layers:      # a state built a prompt and layer
+            telemetry.counter("engine/state_prompts").inc(
+                rows * self.linear_layers)
         for S in by_rung:
             telemetry.counter(f"engine/prefill_rung_rows/{S}").inc(
                 by_rung[S])
@@ -1580,7 +1605,8 @@ class ServingEngine:
         self.cache = dataclasses.replace(self.cache, k=k, v=v,
                                          lengths=lengths)
         if state:
-            self.cache.state = kv_cache.RecurrentState(*state)
+            self.cache.state = kv_cache.RecurrentState.of(
+                self.cfg.block.linear, state)
         self._tok = tok
 
     def _state_args(self) -> tuple:

@@ -47,7 +47,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import hashlib
-from typing import Any
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -100,54 +100,76 @@ def init_cache(num_layers: int, num_slots: int, num_heads: int,
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class RecurrentState:
-    """What the linear layers hold for each slot, whatever its length:
-    ``conv`` ``[linear_layer, slot, taps - 1, channels]`` — the inputs
-    the causal convolution still needs — and ``ssm`` ``[linear_layer,
-    slot, value_heads, key_dim, value_dim]`` float32, the recurrence's
-    matrix.  A slot's rows are overwritten whole by the prefill that
-    admits it (:func:`write_state`) and carried by decode; between an
-    eviction and the next admission they may hold anything and nothing
-    reads them."""
+    """What the linear layers hold for each slot, whatever its length —
+    what the mixer's rule keeps, as its spec says
+    (:class:`~autodist_tpu.models.transformer.LinearMixerSpec`):
+    ``ssm`` ``[linear_layer, slot, *state_shape]`` float32, the
+    recurrence's matrix (the delta rule's ``[value_heads, key_dim,
+    value_dim]``, power retention's ``[kv_heads, offsets, value_dim,
+    key_dim]``); ``conv`` ``[linear_layer, slot, taps - 1, channels]`` —
+    the inputs a causal convolution still needs — where the rule has
+    one, else ``None``; ``norm`` ``[linear_layer, slot,
+    *normaliser_shape]`` float32 where a normaliser rides beside the
+    matrix, else ``None``.  A slot's rows are overwritten whole by the
+    prefill that admits it (:func:`write_state`) and carried by decode;
+    between an eviction and the next admission they may hold anything
+    and nothing reads them."""
 
     conv: Any
     ssm: Any
+    norm: Any = None
 
     def arrays(self) -> tuple:
-        return (self.conv, self.ssm)
+        """The arrays held, in the order the programs take them and
+        :meth:`of` puts them back: ``(conv, ssm)`` or ``(ssm, norm)``."""
+        return tuple(a for a in (self.conv, self.ssm, self.norm)
+                     if a is not None)
+
+    @classmethod
+    def of(cls, mixer, arrays) -> "RecurrentState":
+        """:meth:`arrays` back into a state of ``mixer``'s rule."""
+        if mixer.has_normaliser:
+            ssm, norm = arrays
+            return cls(conv=None, ssm=ssm, norm=norm)
+        return cls(*arrays)
 
 
 def init_state(linear_layers: int, num_slots: int, mixer,
                dtype) -> RecurrentState:
     """All-zero state for ``mixer``
     (:class:`~autodist_tpu.models.transformer.LinearMixerSpec`)."""
+    lead = (linear_layers, num_slots)
     return RecurrentState(
-        conv=jnp.zeros((linear_layers, num_slots, mixer.conv_taps - 1,
-                        mixer.conv_channels), dtype),
-        ssm=jnp.zeros((linear_layers, num_slots, mixer.value_heads,
-                       mixer.key_dim, mixer.value_dim), jnp.float32))
+        conv=jnp.zeros(lead + (mixer.conv_taps - 1, mixer.conv_channels),
+                       dtype) if mixer.has_conv else None,
+        ssm=jnp.zeros(lead + mixer.state_shape, jnp.float32),
+        norm=jnp.zeros(lead + mixer.normaliser_shape, jnp.float32)
+        if mixer.has_normaliser else None)
 
 
 def bytes_held(dims, dtype, recurrent=None, arrays: int = 2) -> dict:
     """What a request costs the manager: ``kv_bytes_per_token`` over the
-    caching layers of ``dims`` and ``state_bytes_per_slot`` over the
-    linear ones (``recurrent``: ``(linear layers, LinearMixerSpec)``).
-    ``arrays``: what a position holds a head — keys and values, or
-    (:class:`LatentLayout`) the one row."""
+    caching layers of ``dims`` (0 where the stack has none) and
+    ``state_bytes_per_slot`` over the linear ones (``recurrent``:
+    ``(linear layers, LinearMixerSpec)``).  ``arrays``: what a position
+    holds a head — keys and values, or (:class:`LatentLayout`) the one
+    row."""
     layers, _, heads, head_dim, _ = dims
     item = jnp.dtype(dtype).itemsize
     state = 0
     if recurrent is not None:
         n, mixer = recurrent
-        state = n * ((mixer.conv_taps - 1) * mixer.conv_channels * item
-                     + mixer.value_heads * mixer.key_dim
-                     * mixer.value_dim * 4)
+        conv = (mixer.conv_taps - 1) * mixer.conv_channels * item \
+            if mixer.has_conv else 0
+        state = n * (conv + mixer.state_floats * 4)
     return {"kv_bytes_per_token": arrays * layers * heads * head_dim * item,
             "state_bytes_per_slot": state}
 
 
 def read_state(arrays, layer: int, slot=None):
-    """``(conv, ssm)`` of linear layer ``layer``: every slot's, or the
-    one row of ``slot`` (a traced scalar) as a batch of one."""
+    """The state's ``arrays`` (:meth:`RecurrentState.arrays`) of linear
+    layer ``layer``: every slot's, or the one row of ``slot`` (a traced
+    scalar) as a batch of one."""
     if slot is None:
         return tuple(a[layer] for a in arrays)
     return tuple(lax.dynamic_slice_in_dim(a[layer], slot, 1, axis=0)
@@ -155,8 +177,9 @@ def read_state(arrays, layer: int, slot=None):
 
 
 def write_state(arrays, layer: int, new, slot=None):
-    """Linear layer ``layer``'s state replaced in place by ``new``
-    ``(conv, ssm)``: every slot's (a decode step), or ``slot``'s alone
+    """Linear layer ``layer``'s state replaced in place by ``new`` (an
+    array for each of ``arrays``): every slot's (a decode step), or
+    ``slot``'s alone
     by the prefill that admits it, as :func:`write_prompt` writes its
     lane — no other slot's row is read or written.  The write is the
     last of the recurrence's passes over the state (the new state is
@@ -674,12 +697,16 @@ class DenseLayout:
     a stack whose other layers keep a :class:`RecurrentState` for the
     slot — the second kind of state this manager holds, met through
     :func:`read_state` / :func:`write_state` and, a decode step's
-    recurrence, :meth:`advance_state`."""
+    recurrence, :meth:`advance_state` (the delta rule) or
+    :meth:`advance_retention` (power retention).  A stack of linear
+    layers alone has no caching layer: the key/value arrays are empty,
+    a slot's memory does not depend on its length, ``max_len`` bounds
+    positions only and no decode-attention kernel is elected."""
 
     def __init__(self, dims, kernel, *, fused_block=None, recurrent=None):
         from autodist_tpu.kernel.pallas.flash_decode import rows_layout
 
-        _, num_slots, _, head_dim, self.max_len = dims
+        self.cache_layers, num_slots, _, head_dim, self.max_len = dims
         self.kernel = kernel        # the engine's elections, by name
         self.fused_block = fused_block
         self.recurrent = recurrent
@@ -747,13 +774,21 @@ class DenseLayout:
             return chunk_attention(q, kc[layer], vc[layer], starts,
                                    dtype=dtype)
 
-    def state_kernel(self, ssm) -> bool:
+    def state_kernel(self, ssm, group: int = 1) -> bool:
         """Whether a decode step advances ``ssm`` (the stacked recurrent
-        matrices, or their shape and type) in the fused kernel: the
-        election, from what can be observed where it is called
-        (:func:`~autodist_tpu.kernel.pallas.delta_step
-        .delta_step_elected`; the kernel slot's ``delta_step`` forces or
-        forbids)."""
+        matrices, or their shape and type) in the fused kernel of the
+        mixer's rule: the election, from what can be observed where it
+        is called (:func:`~autodist_tpu.kernel.pallas.delta_step
+        .delta_step_elected`, or :func:`~autodist_tpu.kernel.pallas
+        .retention_step.retention_step_elected` with the ``group`` of
+        query heads that read a state; the kernel slot's ``delta_step``
+        / ``retention_step`` forces or forbids)."""
+        if len(ssm.shape) == 6:     # power retention's [.., offsets, dv, d]
+            from autodist_tpu.kernel.pallas.retention_step import \
+                retention_step_elected
+            return retention_step_elected(
+                self.kernel.get("retention_step"), ssm.shape, ssm.dtype,
+                group)
         from autodist_tpu.kernel.pallas.delta_step import delta_step_elected
 
         return delta_step_elected(self.kernel.get("delta_step"), ssm.shape,
@@ -779,13 +814,37 @@ class DenseLayout:
         with scope("state_update"):
             return o, write_state((ssm,), layer, (new,))[0]
 
+    def advance_retention(self, q, k, v, g, state, layer):
+        """``(y, (ssm, norm))``: every slot's state and normaliser of
+        linear layer ``layer`` advanced by one position of power
+        retention (:func:`~autodist_tpu.models.pipeline_lm
+        .retention_step`'s operands, ``state`` the stacked arrays) and
+        read by every query head — in the fused kernel, which takes the
+        arrays whole and moves each tile once, in place, or the composed
+        step on the layer's slices and their :func:`write_state`."""
+        from autodist_tpu.models.pipeline_lm import (RETENTION_EPS,
+                                                     retention_step)
+
+        if self.state_kernel(state[0], q.shape[1] // k.shape[1]):
+            from autodist_tpu.kernel.pallas.retention_step import \
+                retention_step_fused
+            with scope("state_update"):
+                return retention_step_fused(q, k, v, g, state, layer,
+                                            eps=RETENTION_EPS)
+        y, new = retention_step(q, k, v, g, read_state(state, layer))
+        with scope("state_update"):
+            return y, write_state(state, layer, new)
+
     # ---- host -------------------------------------------------------- #
     def table_arg(self, cache):
         return jnp.asarray(self.table)
 
     @property
-    def decode_block_len(self) -> int:
-        return self.fused_block or self.max_len
+    def decode_block_len(self) -> Optional[int]:
+        """Positions of a lane the decode attention reads as one; ``None``
+        where no layer caches a lane."""
+        return (self.fused_block or self.max_len) if self.cache_layers \
+            else None
 
     def blocks_needed(self, prompt_len, max_new_tokens, prompt=None) -> int:
         return 0

@@ -1680,9 +1680,8 @@ class CostModel:
         # a linear layer's float32 state a slot, there and back a token
         state_bytes = 0.0
         if linear_layers:
-            mixer = block.linear
-            state_bytes = 4.0 * linear_layers * mixer.value_heads \
-                * mixer.key_dim * mixer.value_dim
+            # the rule's matrix (and, of power retention, its normaliser)
+            state_bytes = 4.0 * linear_layers * block.linear.state_floats
         state_time = 2.0 * state_bytes * batch_slots / hbm_rate
         compute += state_time
         kv_width = hidden

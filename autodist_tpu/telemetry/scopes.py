@@ -24,8 +24,10 @@ SCOPES = (
     "rope",        # rotary rotation of q and k
     "grad_sync",   # gradient buckets' all-reduce, the reduce-scatters
     "optimizer",   # the update, its application, the gather to storage
-    "linear_attention",  # a gated-DeltaNet mixer: projections, the
-                   # convolution, the gated norm, the output projection
+    "linear_attention",  # a recurrent mixer: a gated-DeltaNet layer's
+                   # projections, convolution, gated norm and output
+                   # projection; a power-retention layer's q/k/v, gate and
+                   # output projections
     "state_update",  # inside linear_attention: the recurrent state read,
                    # decayed, written (one step, or a window by chunks)
     "moe",         # a routed FFN: router, sort, experts, shared expert

@@ -998,6 +998,158 @@ def hybrid_latent_phase(*, states=((32, 32, 12, "head"),
     return done
 
 
+def retention_block_phase(*, slots: int = 16, kv_heads: int = 8,
+                          group: int = 5, head_dim: int = 128,
+                          layers: int = 8, check_slots: int = 2,
+                          window: int = 160, offsets=(5, 13, 65),
+                          reps: int = 11, seed: int = 0) -> list:
+    """A power-retention stack's state step at the widths of the
+    benchmark's ``brumby-14b-base`` cell, against its composed forms on
+    the same backend: the symmetric square's identity; one position and a
+    window through the chunked form against the attention form written
+    out (``check_slots`` rows); where its tiles fit, the retention-step
+    kernel over the stacked state of ``layers`` layers through the cache
+    manager's seam against the composed step on a layer's slice
+    (``check_slots`` slots: the composed step's temporaries at the cell's
+    16 do not fit beside the state), the other layers bit for bit; then
+    on a TPU, the kernel alone at the cell's ``slots`` x ``kv_heads``
+    tiles, the us a call at each count of offsets a grid step takes,
+    beside a plain pass over the same bytes (the layer's tiles read,
+    scaled and written back in place)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.kernel.pallas import retention_step as rs
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import LinearMixerSpec
+    from autodist_tpu.serving import kv_cache
+
+    ph = "retention"
+    r = np.random.RandomState(seed)
+    rand = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    kv, d, n = kv_heads, head_dim, kv_heads * group
+    mixer = LinearMixerSpec.retention(kv, d)
+    done = []
+
+    # ---- phi, one position, a window: against the attention form -------
+    B, T = check_slots, window
+    q, k, v = rand(B, T, n, d) * d ** -0.5, rand(B, T, kv, d), \
+        rand(B, T, kv, d)
+    # jitted: alone, a rotation by 0 or by half the lanes trips a check of
+    # the TPU compiler's fusion emitter (seen on the chip, PR 43)
+    pq, pk = jax.jit(lambda a, b: (lm.symmetric_square(a),
+                                   lm.symmetric_square(b)))(
+        q[:, 0, :kv], k[:, 0])
+    require_close(ph, "phi(q) . phi(k) against (q . k)^2",
+                  (pq * pk).sum((-1, -2)),
+                  jnp.einsum("bhd,bhd->bh", q[:, 0, :kv], k[:, 0],
+                             precision=hi) ** 2, 1e-5)
+    g = -jax.nn.softplus(rand(B, T, kv))
+    blank = (jnp.zeros((B, *mixer.state_shape), jnp.float32),
+             jnp.zeros((B, *mixer.normaliser_shape), jnp.float32))
+
+    def attention_form(q, k, v, g):
+        G = jnp.repeat(jnp.cumsum(g, 1), group, 2)          # [B, T, n]
+        kk, vv = (jnp.repeat(t, group, 2) for t in (k, v))
+        seen = jnp.tril(jnp.ones((T, T), bool))
+        w = jnp.where(seen, jnp.exp(jnp.where(
+            seen, G.transpose(0, 2, 1)[..., :, None]
+            - G.transpose(0, 2, 1)[..., None, :], 0.0)), 0.0)
+        a = jnp.einsum("bthd,bshd->bhts", q, kk, precision=hi) ** 2 * w
+        return jnp.einsum("bhts,bshd->bthd", a, vv, precision=hi) \
+            / (a.sum(-1).transpose(0, 2, 1)[..., None] + lm.RETENTION_EPS)
+
+    ref = jax.jit(attention_form)(q, k, v, g)
+    (y, after), s = timed(lambda: jax.block_until_ready(
+        jax.jit(lm.retention_chunked)(q, k, v, g, blank)))
+    require_close(ph, f"retention_chunked output (window of {T}, {n} heads "
+                      f"on {kv} of {d})", y, ref, 1e-4)
+    say(ph, f"state built, chunked over {T} positions: first call {s:.2f}s")
+    before = jax.jit(lm.retention_chunked)(q[:, :-1], k[:, :-1], v[:, :-1],
+                                           g[:, :-1], blank)[1]
+    last = tuple(t[:, -1] for t in (q, k, v, g))
+    y1, stepped = jax.jit(lm.retention_step)(*last, before)
+    require_close(ph, "retention_step output after the window", y1,
+                  ref[:, -1], 1e-4)
+    require_close(ph, "retention_step state against the chunked form's",
+                  stepped[0], after[0], 1e-4)
+    done += ["retention_chunked", "retention_step"]
+
+    # ---- the fused kernel, in place in the cache manager's arrays ------
+    shape = (layers, slots, *mixer.state_shape)
+    if not rs.retention_step_fits(shape, jnp.float32, group):
+        return done
+    layer = layers // 2
+    lay = lambda word: kv_cache.DenseLayout(
+        (0, 1, kv, d, 8), {"retention_step": word}, recurrent=(layers, mixer))
+    small = lambda: (jnp.stack([before[0]] * layers),
+                     jnp.stack([before[1]] * layers))
+    step = lambda word: jax.jit(
+        lambda ssm, nrm: lay(word).advance_retention(
+            *last, (ssm, nrm), jnp.int32(layer)))
+    ref_y, (ref_ssm, ref_nrm) = step(False)(*small())
+    y, (ssm, nrm) = step(True)(*small())
+    require_close(ph, f"retention_step kernel output ({B} slots x {kv} "
+                      f"heads, {layers} layers)", y, ref_y, 1e-4)
+    require_close(ph, "retention_step kernel state", ssm[layer],
+                  ref_ssm[layer], 1e-5)
+    require_close(ph, "retention_step kernel normaliser", nrm[layer],
+                  ref_nrm[layer], 1e-5)
+    require(bool((ssm[:layer] == before[0]).all()
+                 and (ssm[layer + 1:] == before[0]).all()
+                 and (nrm[:layer] == before[1]).all()
+                 and (nrm[layer + 1:] == before[1]).all()), ph,
+            "retention_step kernel leaves the other layers", "bit for bit")
+    done.append("retention_step_fused")
+    if jax.default_backend() != "tpu":     # the interpreter: no times
+        return done
+
+    # ---- alone, at the cell's tiles: us a call, beside a plain pass ----
+    B = slots
+    q1, k1, v1 = rand(B, n, d) * d ** -0.5, rand(B, kv, d), rand(B, kv, d)
+    g1 = -jax.nn.softplus(rand(B, kv))
+    tile = rand(1, 1, kv, *mixer.state_shape[1:])
+    full = lambda: (jnp.broadcast_to(tile, shape) + 0.0,
+                    jnp.zeros((layers, B, *mixer.normaliser_shape),
+                              jnp.float32))
+
+    def per_call(fn):
+        """Seconds a call of ``fn(q, state) -> (y, state)``: ``reps``
+        calls in one program, the arrays donated and carried and each
+        call's query made from the last one's output."""
+        def chained(_, c):
+            y, state = fn(*c)
+            return c[0] + 1e-3 * y, state
+
+        many = jax.jit(lambda state: jax.lax.fori_loop(
+            0, reps, chained, (q1, state)), donate_argnums=0)
+        state = jax.block_until_ready(many(full()))[1]
+        return timed(lambda: jax.block_until_ready(many(state)))[1] / reps
+
+    def plain(q_, state):
+        ssm, nrm = state
+        at = (layer,) + (0,) * (ssm.ndim - 1)
+        rows = jax.lax.dynamic_slice(ssm, at, (1, *ssm.shape[1:]))
+        return q_, (jax.lax.dynamic_update_slice(ssm, rows * 0.999, at), nrm)
+
+    took = {"plain pass": per_call(plain)}
+    for ob in offsets:
+        took[f"kernel, {ob} offsets a step"] = per_call(
+            lambda q_, state, ob=ob: rs.retention_step_fused(
+                q_, k1, v1, g1, state, jnp.int32(layer),
+                eps=lm.RETENTION_EPS, offsets_per_step=ob))
+    moved = 2 * 4 * B * int(np.prod(mixer.state_shape))
+    say(ph, f"state step of one of {layers} layers, {B} slots x {kv} heads "
+            f"of [{mixer.state_offsets}, {d}, {d}], {moved / 1e6:.1f} MB "
+            f"there and back, us a call alone: " + ", ".join(
+                f"{name} {t * 1e6:.1f} ({moved / t / 1e9:.0f} GB/s)"
+                for name, t in took.items()))
+    done.append("retention_step_alone")
+    return done
+
+
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,
                        matmul_shape=(1024, 1024, 1024), seed: int = 0) -> list:
     """The three ring kernels whole, inside ``shard_map`` over
@@ -1199,6 +1351,8 @@ def main() -> int:
     say("latent", f"agreed with each other: {latent_block_phase()}")
     say("hybrid", f"agreed with their composed forms: "
                   f"{hybrid_latent_phase()}")
+    say("retention", f"agreed with their composed forms: "
+                     f"{retention_block_phase()}")
 
     if n > 1:
         multichip_phase(cfg, params, prompts, dense["tokens"],

@@ -120,6 +120,19 @@ def test_hybrid_latent_phase_at_toy_width():
                     "latent_decode:2", "latent_decode:4"]
 
 
+def test_retention_block_phase_at_toy_width():
+    """A power-retention stack's state step at heads of 16 (2 key/value
+    heads, 2 query heads each): the symmetric square, the chunked form
+    and the recurrence against the attention form; the kernel's tiles are
+    heads of 128, so the phase stops where it would run
+    (``tests/unit/test_retention_block.py`` runs it under the
+    interpreter)."""
+    done = chip_smoke.retention_block_phase(
+        slots=2, kv_heads=2, group=2, head_dim=16, layers=2, check_slots=2,
+        window=70)
+    assert done == ["retention_chunked", "retention_step"]
+
+
 @pytest.mark.slow
 def test_kernel_phase_at_toy_width_under_the_interpreter(lm):
     done = chip_smoke.kernels_phase(

@@ -78,12 +78,13 @@ def _model(family):
         "linear-routed": ("qwen3-next-80b-a3b", "hybrid_moe_lm_serving"),
         "latent-routed": ("deepseek-v2-lite", "latent_moe_lm_serving"),
         "kda-latent": ("ling-3.0-flash", "hybrid_latent_moe_lm_serving"),
+        "retention": ("brumby-14b-base", "retention_lm_serving"),
     }[family]
     return (*_routed(name, builder), {})
 
 
 FAMILIES = ["dense-postln", "looped", "linear-routed", "latent-routed",
-            "kda-latent", "dense-paged-prefix"]
+            "kda-latent", "retention", "dense-paged-prefix"]
 RESIDENT = {0: 300, 2: 5}       # slot -> its resident prompt's length
 
 

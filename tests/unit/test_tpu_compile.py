@@ -496,6 +496,116 @@ def test_hybrid_latent_stack_compiles_in_place(one_chip, program,
         assert "adtk_flash_decode" not in text
 
 
+# two layers of the benchmark's power-retention stack at its published
+# widths (40 query heads on 8 key/value heads of 128, an FFN of 17,408; an
+# eighth of the vocabulary: the head's size does not bear on what the
+# compiler does with the state) at the cell's 16 slots of 2,560 positions
+# and its [1, 1024] prompt row: no layer caches keys and values
+@pytest.mark.parametrize("program", ["decode-kernel", "prefill"])
+def test_retention_stack_compiles_in_place(one_chip, program, monkeypatch):
+    """Both programs hold the state they are given — the stacked float32
+    state and its normaliser alias their outputs, the empty key/value
+    arrays take no byte — and no op copies, slices, transposes or
+    converts the stacked state: what is left of its whole shape is the
+    in-place write (the kernel's aliased operand in decode, the
+    ``dynamic-update-slice`` of the admitted slot's rows in prefill).
+    ``decode-kernel``: the decode program a TPU process elects (here
+    forced through the kernel slot, the backend being the CPU's) — Mosaic
+    takes the retention-step kernel at the cell's 16 slots x 8 heads of
+    ``[65, 128, 128]``, a slab of 13 offsets a grid step, one call a
+    layer out of one lowering.  (The composed decode step does not fit
+    the chip beside the cell's 12.8 GB at all: its temporaries are 4 GB.)"""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import (BlockSpec, LinearMixerSpec,
+                                                 TransformerConfig)
+    from autodist_tpu.serving import ServingEngine
+
+    fused = program == "decode-kernel"
+    if fused:
+        monkeypatch.setattr(
+            importlib.import_module(
+                "autodist_tpu.kernel.pallas.retention_step"),
+            "default_interpret", lambda: False)
+    bf16, slots, bucket, T, L = jnp.bfloat16, 16, 1024, 2560, 2
+    cfg = TransformerConfig(
+        vocab_size=18992, hidden_size=5120, num_layers=L, num_heads=40,
+        mlp_dim=17408, max_len=32768, dtype=bf16, dropout_rate=0.0,
+        attention_dropout_rate=0.0,
+        block=BlockSpec(
+            norm="rmsnorm", norm_placement="pre", positions="rope",
+            rope_theta=1e6, ffn="swiglu", bias=False, tied_head=False,
+            kv_heads=8, head_dim=128, qk_norm=True,
+            layer_period=("linear",),
+            linear=LinearMixerSpec.retention(8, 128)))
+    params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
+                          lm.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    engine = ServingEngine(cfg, params, num_slots=slots, max_len=T,
+                           prefill_len=bucket, decode_steps=8,
+                           kernel={"retention_step": fused})
+    c = engine.cache
+    assert engine.kv.state_kernel(c.state.ssm, 5) == fused
+    assert engine.kv.fused_block is None and c.k.size == c.v.size == 0
+    assert c.state.conv is None
+    assert c.state.ssm.shape == (L, slots, 8, 65, 128, 128)
+    assert c.state.norm.shape == (L, slots, 65, 8, 128)
+    assert c.state.ssm.dtype == c.state.norm.dtype == jnp.float32
+    sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                         sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    head = (jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
+            i32(slots), i32(slots))
+    state = tuple(sds(a) for a in engine._state_args())
+    with jax.default_matmul_precision("default"):
+        if fused:
+            lowered = engine._decode_jit.lower(
+                *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
+                    (slots,), jnp.bool_, sharding=one_chip),
+                *state)
+        else:
+            lowered = engine._prefill_jit.lower(
+                *head, i32(), i32(1, 1), i32(1), i32(1, bucket), i32(1),
+                *state)
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize for a in engine._state_args())
+    weights = sum(a.size * a.dtype.itemsize
+                  for a in jax.tree.leaves(engine.params))
+    # 2 layers x 16 slots x (8 x 65 x 128 x 128 + 65 x 8 x 128) x 4 B
+    assert held == 2 * 16 * (8_519_680 + 66_560) * 4
+    assert abs(mem.alias_size_in_bytes - held) < 4096
+    assert abs(mem.argument_size_in_bytes - held - weights) < 1 << 20
+    # a prompt row's FFN activations and one chunk's phi; never a layer's
+    # slice of the state (570 MB)
+    assert mem.temp_size_in_bytes < (16 << 20 if fused else 400 << 20)
+    text = compiled.as_text()
+    whole = rf"f32\[{L},{slots},8,65,128,128\]"
+    assert not re.findall(
+        rf"= {whole}[^ ]* (?:copy|slice|transpose|convert)\(", text)
+    assert not re.findall(
+        rf"= f32\[{L},{slots},65,8,128\][^ ]* "
+        r"(?:copy|slice|transpose|convert)\(", text)
+    layer_state = rf"f32\[(?:1,)?{slots},8,65,128,128\]"
+    assert not re.findall(layer_state, text)
+    if fused:
+        assert len(re.findall(r"custom-call\([^\n]*adtk_retention_step",
+                              text)) == L
+        assert not re.findall(rf"{whole}[^ ]* dynamic-update-slice\(",
+                              text)
+        stablehlo = lowered.as_text()
+        assert stablehlo.count("func.func private "
+                               "@retention_step_layer") == 1
+        assert len(re.findall(r"call @retention_step_layer\(",
+                              stablehlo)) == L
+    else:
+        # the admitted slot's rows, written where they lie: once a layer
+        assert len(re.findall(
+            rf"ROOT [^ ]+ = {whole}[^ ]* dynamic-update-slice\(",
+            text)) == L
+        assert "adtk_retention_step" not in text
+
+
 # one encoder layer's attention at the training cell's widths (BERT-base:
 # 12 heads of 64, 512 positions) and at heads of 128, forward and
 # backward: Mosaic takes the one-pass kernels' tiles, and no array of the

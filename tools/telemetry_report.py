@@ -34,18 +34,24 @@ _PRECISION_BITS = {"fp32": 32, "bf16": 16, "int8": 8}
 # means the election was silently dropped — --check fails it.
 _KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                    "collective_matmul", "a2a_ring", "flash_attention",
-                   "delta_step", "grouped_matmul")
+                   "delta_step", "grouped_matmul", "retention_step")
 # Elections made where the kernel is called, reported as 1 (the fused
 # kernel) or 0 (the composed path) by the engine that makes them, and
 # only by it: gauge -> (that engine's own gauge, who it is, what 0 says).
 # kernel/delta_step_elected: how a decode step advances a stack's
-# recurrent state (serving/kv_cache.py DenseLayout.advance_state).
+# recurrent state (serving/kv_cache.py DenseLayout.advance_state);
+# kernel/retention_step_elected: the same of a power-retention stack
+# (DenseLayout.advance_retention).
 # kernel/latent_decode_elected: how a decode step attends over cached
 # latent rows (LatentLayout.decode_attend) — the latent kernel over the
 # live blocks, which sets kernel/flash_decode_elected too (the kernel
 # slot's word), or write_token and cached_attention over whole lanes.
 _OBSERVED_ELECTIONS = {
     "kernel/delta_step_elected": (
+        "engine/state_bytes_per_slot",
+        "only an engine that holds a recurrent state elects how to "
+        "advance it", "the composed step"),
+    "kernel/retention_step_elected": (
         "engine/state_bytes_per_slot",
         "only an engine that holds a recurrent state elects how to "
         "advance it", "the composed step"),
@@ -116,9 +122,20 @@ _GROUPS_COUNTER = "moe/groups_hit"
 # Two kinds of state in one cache manager (a latent row a position beside
 # a recurrent state a slot): an engine that holds both says so once, with
 # kv/latent_layers, kv/linear_layers, kv/row_bytes and kv/state_bytes —
-# all four or none, every one positive.
+# the first three come with all four, every one positive.
 _TWO_STATE_GAUGES = ("kv/latent_layers", "kv/linear_layers", "kv/row_bytes",
                      "kv/state_bytes")
+# Any stack that keeps a recurrent state says what it holds of it:
+# kv/state_rows (the rows a head's state holds as laid out: a delta
+# rule's key_dim, power retention's (d / 2 + 1) d), kv/state_bytes over
+# all slots and engine/state_bytes_per_slot.  kv/state_rows comes with
+# the other two, every one positive.  (kv/state_bytes alone is an engine
+# of before the rows were reported, or one of the four above.)
+_STATE_GAUGES = ("kv/state_rows", "kv/state_bytes",
+                 "engine/state_bytes_per_slot")
+# engine/state_prompts: the states the prefill programs built, one a
+# prompt and linear layer — whole layers of engine/prefill_rows.
+_STATE_PROMPTS = "engine/state_prompts"
 # Latent rows read (autodist_tpu/serving/batcher.py): an engine whose
 # cached position is a latent-attention row advances
 # serve/latent_positions_read by every decode step's live positions x
@@ -783,12 +800,30 @@ def check_schema(run_dir: str) -> list[str]:
                     f"{held!r} — a pair is held only through a group its "
                     "row kept, and a row that kept one was routed")
     both = [gauges.get(n) for n in _TWO_STATE_GAUGES]
-    if any(g is not None for g in both) and not all(
+    if any(g is not None for g in both[:3]) and not all(
             g is not None and g.get("value", 0) > 0 for g in both):
         problems.append(
             f"metrics.jsonl: {', '.join(_TWO_STATE_GAUGES)} come together "
             "and positive — an engine sets them where its cache manager "
             "holds latent rows beside recurrent state")
+    if gauges.get(_STATE_GAUGES[0]) is not None and not all(
+            (gauges.get(n) or {}).get("value", 0) > 0
+            for n in _STATE_GAUGES):
+        problems.append(
+            f"metrics.jsonl: {', '.join(_STATE_GAUGES)} come together and "
+            "positive — an engine whose stack keeps a recurrent state says "
+            "how many rows a head holds, its bytes over all slots and a "
+            "slot's")
+    built = counters.get(_STATE_PROMPTS)
+    if built is not None:
+        rows = (counters.get(_PREFILL_COUNTERS[0]) or {}).get("value", 0)
+        if not rows or built.get("value", 0) % rows \
+                or built.get("value", 0) < rows:
+            problems.append(
+                f"metrics.jsonl: {_STATE_PROMPTS} = "
+                f"{built.get('value')!r} beside {_PREFILL_COUNTERS[0]} = "
+                f"{rows!r} — a prefill row builds one state in every "
+                "linear layer: whole layers of the rows")
 
     latent = counters.get(_LATENT_COUNTER)
     if latent is not None:
