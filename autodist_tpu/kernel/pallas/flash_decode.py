@@ -14,6 +14,9 @@ The **dense** kernel (:func:`flash_decode_attention_dense`) is what
 reads only each slot's live blocks (a block above ``lengths[slot]`` is
 neither fetched nor computed), handles all the heads of a slot that
 fit VMEM in one grid step, and multiplies the cache's own bf16 tiles.
+Fewer key/value heads than query heads: the group of query heads that
+reads a key/value head rides in the row dimension of the products (as
+in ``cached_attention``), so a block is fetched once for all of them.
 It reads the cache as the TPU lays it out, so the kernel's view of the
 array is the array (:func:`fused_decode_block`): the lanes transposed,
 ``[.., d, T]`` tiles, for heads narrower than the chip's 128 lanes, and
@@ -61,6 +64,15 @@ DEFAULT_BLOCK_K = 128
 MIN_FUSED_DECODE_LEN = 256
 KV_BLOCK_BYTES = 2 << 20       # one K (or V) block, lane-padded
 VMEM_LIMIT_BYTES = 32 << 20    # K and V blocks double-buffered + carry
+# Heads of 256 and wider walk blocks of 256: one block of each array is
+# on its way at a time, ~0.3 us before its bytes flow, and the few
+# key/value heads such a model has make a block of 128 too small to hide
+# it.  Read on a v5e with ``chip_smoke.py:mixed_block_phase`` at 32 slots
+# x 2 key/value heads x 256 under 8 query heads each, bf16: 107 / 89 / 87
+# us a layer call at blocks of 128 / 256 / 512 with the lanes a fifth
+# full, 400 / 281 / 245 us full (PERF.md section 6, PR 44).
+WIDE_HEAD_DIM = 256
+WIDE_BLOCK_K = 256
 # The latent kernel's cache-block length and how many blocks it keeps in
 # VMEM (all but one of them on their way while one is computed): read on
 # a v5e with ``tools/flash_crossover.py --decode --latent`` at 64 slots x
@@ -159,8 +171,13 @@ def fused_decode_block(max_len: int, head_dim: int):
     multiple of 128, and the kernel reads ``[d, block]`` tiles; heads of
     a multiple of 128 it keeps row-major, and the kernel reads
     ``[block, d]`` tiles (:func:`rows_layout`).  Any other shape would
-    be a copy of all of it."""
-    bk = decode_block_len(max_len)
+    be a copy of all of it.  The block is :data:`DEFAULT_BLOCK_K`, or
+    for heads of :data:`WIDE_HEAD_DIM` and wider :data:`WIDE_BLOCK_K`
+    where that divides the lane."""
+    bk = None
+    if head_dim >= WIDE_HEAD_DIM:
+        bk = decode_block_len(max_len, WIDE_BLOCK_K)
+    bk = bk or decode_block_len(max_len)
     if bk is None:
         return None
     if rows_layout(head_dim):
@@ -188,8 +205,10 @@ def _heads_per_step(heads: int, block_len: int, d: int, itemsize: int):
 def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
                          block_len: int, num_blocks: int, scale: float,
                          write: bool, rows: bool):
-    """One (slot, head group) grid step: walk the slot's live blocks of
-    the lane with the kernel's own double-buffered DMA — of the
+    """One (slot, block of key/value heads) grid step: walk the slot's
+    live blocks of the lane with the kernel's own double-buffered DMA —
+    once for the ``G`` query heads that read each key/value head, the
+    rows of ``q_ref`` (``[hb, G, d]``; one a head without grouping) — of the
     TRANSPOSED lane, ``[hb, d, bk]`` tiles with the positions on the
     lanes (the layout a TPU keeps a cache of narrow heads in), or with
     ``rows`` of the lane as stored, ``[hb, bk, d]`` tiles (heads of 128
@@ -250,7 +269,7 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
     m_ref[...] = jnp.full_like(m_ref, NEG_INF)
     s_ref[...] = jnp.zeros_like(s_ref)
     acc_ref[...] = jnp.zeros_like(acc_ref)
-    q = q_ref[...]                                         # [hb, 1, d]
+    q = q_ref[...]                                         # [hb, G, d]
     if write:
         # the new key and value rows [hb, 2, d]; as columns, [hb, d, 2],
         # for the transposed tiles
@@ -331,20 +350,20 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
         scores = jax.lax.dot_general(
             q.astype(k.dtype), k,
             (((2,), (2 if rows else 1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale    # [hb, 1, bk]
+            preferred_element_type=jnp.float32) * scale    # [hb, G, bk]
         idx = j * bk + jax.lax.broadcasted_iota(jnp.int32, (1, 1, bk), 2)
         scores = jnp.where(idx <= length, scores, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(scores, axis=-1, keepdims=True))
-        alpha = jnp.exp(m - m_new)                         # [hb, 1, 1]
-        p = jnp.exp(scores - m_new)                        # [hb, 1, bk]
+        alpha = jnp.exp(m - m_new)                         # [hb, G, 1]
+        p = jnp.exp(scores - m_new)                        # [hb, G, bk]
         m_ref[...] = m_new
         s_ref[...] = s_ref[...] * alpha + jnp.sum(p, axis=-1,
                                                    keepdims=True)
         acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v,
             (((2,), (1 if rows else 2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)            # [hb, 1, d]
+            preferred_element_type=jnp.float32)            # [hb, G, d]
 
         return carry
 
@@ -365,22 +384,23 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
                        interpret: bool, rows: bool = False):
     """The one inner function every layer's call goes through: ``layer``
     is an operand, so a decode body of any depth lowers this kernel
-    once.  ``q2``: ``[B, H, 1, d]``; ``new_kv``: ``[B, H, 2, d]`` (the
-    step's key and value rows) or ``None``; the caches whole, as
-    ``[L, B, H, d, T]`` (with ``rows`` as ``[L, B, H, T, d]``).  Returns
-    the attention output, and the two caches after it when ``new_kv``
-    was written."""
+    once.  ``q2``: ``[B, H, G, d]``, the ``G`` query heads of each of the
+    ``H`` key/value heads; ``new_kv``: ``[B, H, 2, d]`` (the step's key
+    and value rows, one a key/value head) or ``None``; the caches whole,
+    as ``[L, B, H, d, T]`` (with ``rows`` as ``[L, B, H, T, d]``).
+    Returns the attention output ``[B, H, G, d]``, and the two caches
+    after it when ``new_kv`` was written."""
     if rows:
         _, B, H, T, d = kt_cache.shape
     else:
         _, B, H, d, T = kt_cache.shape
-    bk, hb = block_len, heads_per_step
+    bk, hb, G = block_len, heads_per_step, q2.shape[2]
     write = new_kv is not None
 
     def row_map(b, h, *_):
         return b, h, 0, 0
 
-    row = pl.BlockSpec((None, hb, 1, d), row_map)
+    row = pl.BlockSpec((None, hb, G, d), row_map)
     pair = pl.BlockSpec((None, hb, 2, d), row_map)     # new key, value
     # what goes back to the cache around the new position: 128 columns
     # of the transposed lane; of the lane as stored, one sublane tile of
@@ -388,7 +408,7 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
     w = min(bk, 32 // kt_cache.dtype.itemsize if rows else 128)
     tile = (lambda n: (hb, n, d)) if rows else (lambda n: (hb, d, n))
     whole = pl.BlockSpec(memory_space=pl.ANY)
-    out = jax.ShapeDtypeStruct((B, H, 1, d), dtype)
+    out = jax.ShapeDtypeStruct((B, H, G, d), dtype)
     cache = jax.ShapeDtypeStruct(kt_cache.shape, kt_cache.dtype)
     buf = pltpu.VMEM((2, *tile(bk)), kt_cache.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -400,9 +420,9 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
         + [pltpu.VMEM((2, *tile(w)), kt_cache.dtype),      # write-back
            pltpu.SemaphoreType.DMA((2,))] * write
         + [pltpu.SMEM((2,), jnp.int32),     # parity, write-back pending
-           pltpu.VMEM((hb, 1, 1), jnp.float32),            # max
-           pltpu.VMEM((hb, 1, 1), jnp.float32),            # sum
-           pltpu.VMEM((hb, 1, d), jnp.float32)],           # acc
+           pltpu.VMEM((hb, G, 1), jnp.float32),            # max
+           pltpu.VMEM((hb, G, 1), jnp.float32),            # sum
+           pltpu.VMEM((hb, G, d), jnp.float32)],           # acc
     )
     kern = functools.partial(
         _dense_decode_kernel, block_len=bk, num_blocks=T // bk,
@@ -434,12 +454,14 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     :func:`~autodist_tpu.serving.kv_cache.write_token` in the same pass.
 
     ``q``: ``[B, 1, heads, head_dim]``; ``k_cache``/``v_cache``:
-    ``[L, B, heads, T, head_dim]`` (the cache arrays themselves: no
-    slice is taken, the kernel picks the layer); ``layer``: int or
-    int32 scalar; ``lengths``: ``[B]`` int32.  Returns
+    ``[L, B, kv_heads, T, head_dim]`` (the cache arrays themselves: no
+    slice is taken, the kernel picks the layer), ``kv_heads`` a divisor
+    of ``heads`` — query head ``h`` reads key/value head ``h // (heads
+    // kv_heads)``, as in ``cached_attention``; ``layer``: int or int32
+    scalar; ``lengths``: ``[B]`` int32.  Returns
     ``[B, 1, heads, head_dim]`` in ``dtype``.
 
-    ``new_kv=(k, v)``, each ``[B, 1, heads, head_dim]``: slot ``i``'s
+    ``new_kv=(k, v)``, each ``[B, 1, kv_heads, head_dim]``: slot ``i``'s
     rows are written at position ``lengths[i]`` before the slot attends,
     and ``(out, k_cache, v_cache)`` comes back, the caches updated in
     place under ``jit`` with donation.  ``active`` (``[B]`` bool, with or
@@ -454,9 +476,13 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     is a copy of what it is given (:func:`fused_decode_block` says
     which).  ``T`` must divide into
     blocks (:func:`decode_block_len`); ``heads_per_step`` defaults to as
-    many heads of a slot as fit :data:`KV_BLOCK_BYTES`.
+    many key/value heads of a slot as fit :data:`KV_BLOCK_BYTES`.
     """
     _, _, H, T, d = k_cache.shape
+    B, _, heads, _ = q.shape
+    if heads % H:
+        raise ValueError(f"heads={heads} must be a multiple of the cache's "
+                         f"key/value heads={H}")
     bk = decode_block_len(T, block_k)
     if bk is None:
         raise ValueError(
@@ -466,7 +492,8 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     hb = int(heads_per_step or _heads_per_step(
         H, bk, d, jnp.dtype(k_cache.dtype).itemsize))
     if H % hb:
-        raise ValueError(f"heads_per_step={hb} must divide heads={H}")
+        raise ValueError(f"heads_per_step={hb} must divide the cache's "
+                         f"heads={H}")
     interp = default_interpret() if interpret is None else bool(interpret)
     lengths = lengths.astype(jnp.int32)
     live = lengths if active is None else jnp.where(active, lengths, 0)
@@ -479,13 +506,14 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     view = (lambda c: c) if rows else (lambda c: jnp.swapaxes(c, 3, 4))
     res = flash_decode_layer(
         live, wpos, jnp.asarray(layer, jnp.int32).reshape(1),
-        jnp.swapaxes(q, 1, 2), new_kv, view(k_cache), view(v_cache),
+        q.reshape(B, H, heads // H, d), new_kv, view(k_cache),
+        view(v_cache),
         block_len=bk, heads_per_step=hb, dtype=jnp.dtype(dtype),
         interpret=interp, rows=rows)
     if new_kv is None:
-        return jnp.swapaxes(res, 1, 2)             # [B, 1, H, d]
+        return res.reshape(q.shape)                # [B, 1, heads, d]
     out, kt, vt = res
-    return jnp.swapaxes(out, 1, 2), view(kt), view(vt)
+    return out.reshape(q.shape), view(kt), view(vt)
 
 
 def flash_decode_attention(q, k_layer, v_layer, lengths, *,
@@ -494,7 +522,7 @@ def flash_decode_attention(q, k_layer, v_layer, lengths, *,
                            interpret: Optional[bool] = None):
     """:func:`flash_decode_attention_dense` on one layer's slice.
 
-    ``k_layer``/``v_layer``: ``[B, heads, T, head_dim]``.  ``block_k``
+    ``k_layer``/``v_layer``: ``[B, kv_heads, T, head_dim]``.  ``block_k``
     defaults to :data:`DEFAULT_BLOCK_K` capped at the cache length.  A
     cache length that ``block_k`` does not divide is zero-padded per
     call (a copy of the layer's cache; padded positions sit above every

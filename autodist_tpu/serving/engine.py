@@ -508,18 +508,14 @@ class ServingEngine:
             # wins, from what can be observed here — a TPU under the
             # programs, a lane the kernel's blocks divide, heads narrow
             # enough for the chip to keep the positions minor-most (the
-            # kernel's view of the cache is then the array itself), a
-            # lane long enough by the chip's own readings.  Elsewhere,
-            # and on the CPU always, cached_attention.
+            # kernel's view of the cache is then the array itself) or of
+            # whole 128-lane tiles (read as stored), a lane long enough
+            # by the chip's own readings; grouped query heads ride in
+            # the kernel's rows.  Elsewhere, and on the CPU always,
+            # cached_attention.
             fused_block = None    # the kernel's block, read in place
             forced = bool(self.kernel.get("flash_decode"))
-            if forced and grouped:
-                raise ValueError(
-                    "kernel flash_decode with grouped-query attention: "
-                    "the fused decode kernel reads a key/value head a "
-                    "query head; such a block decodes through "
-                    "cached_attention, which groups them")
-            if self.cache_layers and not grouped and (forced or (
+            if self.cache_layers and (forced or (
                     decode_left_open and jax.default_backend() == "tpu")):
                 from autodist_tpu.kernel.pallas.flash_decode import (
                     MIN_FUSED_DECODE_LEN, fused_decode_block)

@@ -743,7 +743,9 @@ class DenseLayout:
                       *, dtype):
         """``(out, kc, vc)``: the step's rows written at ``lengths`` and
         ``q`` attended over ``layer`` — at once in the fused kernel,
-        which writes the rows itself, as it reads."""
+        which writes the rows itself, as it reads.  ``q`` holds the query
+        heads, ``k`` and ``v`` one row a key/value head: either path
+        puts the group that reads a key/value head in its rows."""
         fused = self.fused_block
         if not fused:
             kc, vc = self.write_token(kc, vc, layer, k, v, lengths, table,

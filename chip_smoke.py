@@ -80,6 +80,7 @@ run at ``highest``):
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import sys
@@ -539,17 +540,28 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
                       window: int = 256, hidden: int = 2048,
                       experts: int = 512, held: int = 64, top_k: int = 10,
                       width: int = 512, linear_layers: int = 12,
-                      seed: int = 0) -> list:
-    """What a mixed stack's decode step runs beside attention, at the
-    widths of the benchmark's ``qwen3-next-80b-a3b`` cell, against its
+                      kv_heads: int = 2, group: int = 8,
+                      head_dim: int = 256, lane_len: int = 2560,
+                      full_layers: int = 4, blocks=(128, 256),
+                      dtype=None, seed: int = 0) -> list:
+    """What a mixed stack's decode step runs, at the widths of the
+    benchmark's ``qwen3-next-80b-a3b`` cell, against its
     composed form on the same backend: the recurrent state's update (one
     position; a window through the chunked form) against the recurrence
     written with einsums at ``highest``; where its tiles fit, the fused
     delta-step kernel over the stacked state of ``linear_layers`` layers
     against the composed step on a layer's slice, with the seconds of
-    each alone, at every count of heads a grid step can take; and the
-    routed layer (sorted pairs through the grouped matmul) against every
-    held expert over every row (:func:`routed_layer_checks`)."""
+    each alone, at every count of heads a grid step can take; the full
+    layers' decode attention, ``group`` query heads on each of
+    ``kv_heads`` key/value heads of ``head_dim`` — the dense decode
+    kernel over the cache itself against ``write_token`` then
+    ``cached_attention`` through the cache manager's seam, alone, over
+    ``slots`` lanes of ``lane_len`` positions as full as the cell's
+    traffic leaves them, and full: the output, the cache after the step
+    bit for bit, and the us a call of each at every block in ``blocks``
+    (:func:`grouped_decode_checks`); and the routed layer (sorted pairs
+    through the grouped matmul) against every held expert over every row
+    (:func:`routed_layer_checks`)."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -648,11 +660,109 @@ def mixed_block_phase(*, slots: int = 32, value_heads: int = 32,
             f"{s:.2f}s")
     done.append("gated_delta_chunked")
 
+    # ---- the full layers' decode attention, a group a key/value head ----
+    done += grouped_decode_checks(
+        ph, r, slots=slots, kv_heads=kv_heads, group=group,
+        head_dim=head_dim, lane_len=lane_len, layers=full_layers,
+        blocks=blocks, dtype=jnp.bfloat16 if dtype is None else dtype)
+
     # ---- the routed layer: a decode step's rows, a prefill row's --------
     done += routed_layer_checks(
         ph, r, rows=(slots, 8 * slots), hidden=hidden, experts=experts,
         held=held, top_k=top_k, width=width)
     return done
+
+
+def seconds_a_call(fn, first, carried, *, reps: int) -> float:
+    """Seconds a call of ``fn(first, carried) -> (o, carried)``: ``reps``
+    calls in one program, the arrays ``carried()`` makes donated and
+    carried and each call's ``first`` made from ALL of the last one's
+    output (of ``first``'s shape), so that nothing is lifted out or cut
+    down to the part that is used, and the host's dispatch (longer than
+    the call) is paid once."""
+    import jax
+
+    def chained(_, c):
+        o, arr = fn(*c)
+        return c[0] + 1e-3 * o.astype(c[0].dtype), arr
+
+    # both carried values come back: a chain nothing reads is dead
+    # code, and a composed step's would be removed
+    many = jax.jit(lambda arr: jax.lax.fori_loop(
+        0, reps, chained, (first, arr)), donate_argnums=0)
+    arr = jax.block_until_ready(many(carried()))[1]
+    return timed(lambda: jax.block_until_ready(many(arr)))[1] / reps
+
+
+def grouped_decode_checks(ph, r, *, slots: int, kv_heads: int, group: int,
+                          head_dim: int, lane_len: int, layers: int,
+                          blocks, dtype, reps: int = 47) -> list:
+    """One layer's decode step through ``kv_cache.DenseLayout
+    .decode_attend`` over a ``[layers, slots, kv_heads, lane_len,
+    head_dim]`` cache, ``group`` query heads a key/value head: the dense
+    decode kernel at each block of ``blocks`` that divides the lane
+    against ``write_token`` then ``cached_attention`` (output; the cache
+    after the step bit for bit), and the seconds a call of each alone —
+    with the lanes as full as the ``qwen3-next-80b-a3b`` cell's traffic
+    leaves a decoding slot's (a prompt of lognormal median 128, sigma
+    0.8, and a part of an output of median 384, sigma 0.6, met in
+    proportion to its length), and with every lane full."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.serving import kv_cache
+
+    B, H, G, d, T, L = slots, kv_heads, group, head_dim, lane_len, layers
+    layer = L // 2
+    tol = 3e-2 if jnp.dtype(dtype).itemsize < 4 else 1e-4
+    rand = lambda *shape: jnp.asarray(r.randn(*shape), dtype)
+    q, k_new, v_new = rand(B, 1, H * G, d), rand(B, 1, H, d), rand(B, 1, H, d)
+    kc0, vc0 = rand(L, B, H, T, d), rand(L, B, H, T, d)
+    stack = lambda: (kc0 + 0, vc0 + 0)      # fresh arrays to donate
+    outs = r.lognormal(np.log(384), 0.6, 16 * B)
+    outs = r.choice(outs, B, p=outs / outs.sum())
+    live = np.minimum(r.lognormal(np.log(128), 0.8, B) + r.rand(B) * outs,
+                      T - 2).astype(np.int32)
+    active = jnp.ones((B,), bool)
+
+    def step(block, lengths):
+        """``(q, (kc, vc)) -> (out, (kc, vc))``: the kernel with
+        ``block``, or the composed step."""
+        lay = kv_cache.DenseLayout((L, B, H, d, T), {}, fused_block=block)
+
+        def attend(q, caches):
+            out, *caches = lay.decode_attend(
+                q, k_new, v_new, *caches, layer, lengths, None, active,
+                dtype=dtype)
+            return out, tuple(caches)
+
+        return attend
+
+    per_call = lambda fn: seconds_a_call(fn, q, stack, reps=reps)
+    for fill, lengths in (("the traffic's", jnp.asarray(live)),
+                          ("full", jnp.full((B,), T - 1, jnp.int32))):
+        ref, ref_caches = jax.jit(step(None, lengths))(q, stack())
+        took = {"composed": per_call(step(None, lengths))}
+        for bk in (b for b in blocks if T % b == 0):
+            got, caches = jax.jit(step(bk, lengths))(q, stack())
+            require_close(ph, f"grouped decode kernel output (blocks of "
+                              f"{bk}, {B} lanes of {T}, {H} x {G} heads of "
+                              f"{d}, {fill})", got, ref, tol)
+            require(all(bool((a == b).all())
+                        for a, b in zip(caches, ref_caches)), ph,
+                    "grouped decode kernel leaves the caches as "
+                    "write_token does", "bit for bit")
+            took[bk] = per_call(step(bk, lengths))
+        moved = int(jnp.sum(lengths + 1)) * 2 * H * d \
+            * jnp.dtype(dtype).itemsize
+        say(ph, f"decode attention over one of {L} layers, {fill} lanes "
+                f"(mean {float(jnp.mean(lengths)):.0f} of {T}), "
+                f"{moved / 1e6:.1f} MB of live keys and values, us a call "
+                f"alone: " + ", ".join(
+                    f"{name} {t * 1e6:.1f} ({moved / t / 1e9:.0f} GB/s)"
+                    for name, t in took.items()))
+    return ["grouped_decode_kernel"]
 
 
 def routed_layer_checks(ph, r, *, rows, hidden: int, experts: int,
@@ -901,23 +1011,7 @@ def hybrid_latent_phase(*, states=((32, 32, 12, "head"),
     rand = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
     done = []
 
-    def per_call(fn, first, carried):
-        """Seconds a call of ``fn(first, carried) -> (o, carried)``:
-        ``reps`` calls in one program, the array donated and carried and
-        each call's ``first`` made from ALL of the last one's output (of
-        ``first``'s shape), so that nothing is lifted out or cut down to
-        the part that is used, and the host's dispatch (longer than the
-        call) is paid once."""
-        def chained(_, c):
-            o, arr = fn(*c)
-            return c[0] + 1e-3 * o.astype(c[0].dtype), arr
-
-        # both carried values come back: a chain nothing reads is dead
-        # code, and a composed step's would be removed
-        many = jax.jit(lambda arr: jax.lax.fori_loop(
-            0, reps, chained, (first, arr)), donate_argnums=0)
-        arr = jax.block_until_ready(many(carried()))[1]
-        return timed(lambda: jax.block_until_ready(many(arr)))[1] / reps
+    per_call = functools.partial(seconds_a_call, reps=reps)
 
     # ---- the state kernel: a decay a head, a decay a row ---------------
     dk, dv = key_dim, value_dim

@@ -73,15 +73,22 @@ def test_mixed_block_phase_at_toy_width(tile, fused):
     """Tiles of ``[128, 128]`` bring the delta-step kernel in (here
     under the interpreter), widths in whole lanes of 128 the
     grouped-matmul kernel at a decode step's rows; narrower ones they
-    cannot take."""
+    cannot take.  The dense decode kernel takes a group of query heads a
+    key/value head either way: heads of 8 on ``[d, block]`` tiles, heads
+    of 128 on ``[block, d]`` tiles as stored."""
+    import jax.numpy as jnp
+
     done = chip_smoke.mixed_block_phase(
         slots=3, value_heads=8 if fused else 2, key_dim=tile[0],
         value_dim=tile[1],
         window=37, hidden=128 if fused else 32, experts=16, held=8, top_k=4,
-        width=128 if fused else 8, linear_layers=3)
+        width=128 if fused else 8, linear_layers=3, kv_heads=2,
+        group=2 if fused else 4, head_dim=128 if fused else 8, lane_len=64,
+        full_layers=2, blocks=(16, 32, 48), dtype=jnp.float32)
     assert done == ["gated_delta_step"] \
         + ["gated_delta_step_fused"] * fused \
-        + ["gated_delta_chunked", "routed_experts"] \
+        + ["gated_delta_chunked", "grouped_decode_kernel",
+           "routed_experts"] \
         + ["grouped_matmul"] * fused
 
 

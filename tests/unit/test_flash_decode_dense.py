@@ -2,7 +2,8 @@
 
 Interpreter-mode goldens of ``flash_decode_attention_dense`` against
 ``cached_attention`` (the kernel takes the ``[L, B, H, T, d]`` cache
-itself and a layer index; dead blocks and stale rows are unreachable),
+itself and a layer index; dead blocks and stale rows are unreachable;
+a group of query heads on each key/value head rides in its rows),
 the engine's greedy parity with the kernel forced where it reads the
 cache in place, the shape of the forced decode program (one inner
 function for every layer, the layer an operand, no slice of the
@@ -40,6 +41,21 @@ def _lm_params(cfg):
                                       jax.random.PRNGKey(0)).params
 
 
+def _grouped(kv_heads, **kw):
+    """A plain decoder whose ``num_heads`` query heads read ``kv_heads``
+    key/value heads, with seeded weights (such a block is served, not
+    trained)."""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import BlockSpec
+
+    cfg = _lm_cfg(block=BlockSpec(kv_heads=kv_heads), **kw)
+    leaves, tree = jax.tree.flatten(
+        lm.param_shapes(cfg), is_leaf=lambda x: isinstance(x, tuple))
+    keys = jax.random.split(jax.random.PRNGKey(0), len(leaves))
+    return cfg, tree.unflatten([0.2 * jax.random.normal(k, s, cfg.dtype)
+                                for k, s in zip(keys, leaves)])
+
+
 # --------------------------------------------------------------------------- #
 # the kernel against cached_attention
 # --------------------------------------------------------------------------- #
@@ -52,9 +68,12 @@ def _block(head_dim):
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("blocks", [1, 2, 8])
-@pytest.mark.parametrize("heads_per_step,d", [(1, 8), (4, 8), (2, 128)])
+@pytest.mark.parametrize("heads_per_step,d,group", [
+    (1, 8, 1), (4, 8, 1), (2, 128, 1),
+    (2, 64, 2), (1, 256, 8),    # query heads a key/value head
+])
 def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
-                                                 heads_per_step, d):
+                                                 heads_per_step, d, group):
     """Lengths on every side of a block's edge, mixed over the slots;
     the rows above each slot's length and every other layer hold 1e4,
     and the answer is the one a cache of zeros there gives."""
@@ -68,7 +87,7 @@ def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
                (0, 1, BLOCK - 1, BLOCK, BLOCK + 1, T - 1)]
     L, B, H, layer = 3, len(lengths), 4, 1
     r = np.random.RandomState(blocks)
-    q = jnp.asarray(r.randn(B, 1, H, d), dtype)
+    q = jnp.asarray(r.randn(B, 1, H * group, d), dtype)
     clean = r.randn(2, B, H, T, d).astype(np.float32)
     live = np.arange(T)[None, :] <= np.asarray(lengths)[:, None]  # [B, T]
     clean *= live[None, :, None, :, None]
@@ -81,7 +100,7 @@ def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
     got = flash_decode_attention_dense(
         q, k, v, layer, lens, dtype=dtype, block_k=BLOCK,
         heads_per_step=heads_per_step)
-    assert got.dtype == dtype and got.shape == (B, 1, H, d)
+    assert got.dtype == dtype and got.shape == (B, 1, H * group, d)
     tol = 1e-5 if dtype == jnp.float32 else 3e-2
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(ref, np.float32),
@@ -91,11 +110,18 @@ def test_dense_kernel_golden_vs_cached_attention(dtype, blocks,
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["float32", "bfloat16"])
 @pytest.mark.parametrize("blocks", [1, 2, 8])
-@pytest.mark.parametrize("d", [8, 128])
-def test_dense_kernel_writes_the_token_as_write_token_does(dtype, blocks, d):
+@pytest.mark.parametrize("d,group", [
+    (8, 1), (128, 1),
+    (64, 2), (64, 8), (128, 2), (128, 8), (256, 2), (256, 8),
+])
+def test_dense_kernel_writes_the_token_as_write_token_does(dtype, blocks, d,
+                                                           group):
     """``new_kv``: the caches come back as ``write_token`` leaves them,
-    bit for bit (a slot that is not active untouched), and the output is
-    ``cached_attention``'s over the written cache."""
+    bit for bit (a slot that is not active untouched: it writes nothing
+    and reads one block), and the output is ``cached_attention``'s over
+    the written cache — with a ``group`` of query heads on each
+    key/value head too: one row a key/value head goes in, not one a
+    query head."""
     from autodist_tpu.kernel.pallas.flash_decode import \
         flash_decode_attention_dense
     from autodist_tpu.serving.kv_cache import cached_attention, write_token
@@ -106,8 +132,9 @@ def test_dense_kernel_writes_the_token_as_write_token_does(dtype, blocks, d):
                (0, 1, BLOCK - 1, BLOCK, BLOCK + 1, T - 1)]
     L, B, H, layer = 2, len(lengths), 4, 1
     r = np.random.RandomState(blocks)
-    q, k_new, v_new = (jnp.asarray(r.randn(B, 1, H, d), dtype)
-                       for _ in range(3))
+    q = jnp.asarray(r.randn(B, 1, H * group, d), dtype)
+    k_new, v_new = (jnp.asarray(r.randn(B, 1, H, d), dtype)
+                    for _ in range(2))
     k, v = (jnp.asarray(r.randn(L, B, H, T, d), dtype) for _ in range(2))
     lens = jnp.asarray(lengths, jnp.int32)
     active = jnp.asarray([True, True, False, True, True, True])
@@ -140,11 +167,24 @@ def test_dense_kernel_refuses_a_lane_no_block_divides():
                                      block_k=8)
 
 
+def test_dense_kernel_refuses_query_heads_no_group_divides():
+    from autodist_tpu.kernel.pallas.flash_decode import \
+        flash_decode_attention_dense
+
+    z = jnp.zeros((1, 1, 2, 16, 8))
+    with pytest.raises(ValueError, match="multiple of the cache's"):
+        flash_decode_attention_dense(jnp.zeros((1, 1, 3, 8)), z, z, 0,
+                                     jnp.zeros((1,), jnp.int32),
+                                     block_k=8)
+
+
 @pytest.mark.parametrize("max_len,head_dim,block", [
     (1024, 64, 128), (512, 64, 128), (256, 64, 128), (128, 32, 128),
     (1000, 64, None),     # no block divides the lane
     (1024, 128, 128),     # the chip keeps wide heads row-major: read so
-    (512, 128, 128), (64, 128, 64), (512, 256, 128),
+    (512, 128, 128), (64, 128, 64),
+    (512, 256, 256), (2560, 256, 256),    # wide heads: blocks of 256
+    (640, 256, 128), (128, 256, 128),     # ... where they divide the lane
     (1000, 128, None),    # no block divides that lane either
     (512, 192, None),     # not whole 128-lane tiles: its view would copy
     (24, 8, None),        # nor does it transpose a short lane
@@ -191,6 +231,30 @@ def test_engine_forced_kernel_on_the_whole_cache_greedy_parity(max_len, tp):
     windows = 0 if max_len == 128 else 2
     np.testing.assert_array_equal(_serve(fused, [125, 3], windows),
                                   _serve(plain, [125, 3], windows))
+
+
+@pytest.mark.parametrize("kv_heads,max_len", [(2, 256), (1, 512),
+                                              (2, 48)])
+def test_engine_grouped_heads_through_the_kernel_greedy_parity(kv_heads,
+                                                               max_len):
+    """A plain decoder of four query heads on two key/value heads and on
+    one, forced through the kernel on the whole cache (and, a lane no
+    block reads in place, on a layer's slice): token for token the same
+    engine on ``cached_attention``, across a block's edge."""
+    from autodist_tpu.serving import ServingEngine
+
+    cfg, params = _grouped(kv_heads, num_heads=4)
+    p_lens = [125, 3] if max_len > 128 else [20, 3]
+    kw = dict(num_slots=2, max_len=max_len, prefill_len=max(p_lens) + 1,
+              decode_steps=4)
+    fused = ServingEngine(cfg, params, kernel={"flash_decode": True}, **kw)
+    plain = ServingEngine(cfg, params, kernel={"flash_decode": False},
+                          **kw)
+    assert fused.cache.k.shape[2] == kv_heads
+    assert fused.kv.fused_block == (128 if max_len > 128 else None)
+    assert plain.kv.fused_block is None
+    np.testing.assert_array_equal(_serve(fused, p_lens, 2),
+                                  _serve(plain, p_lens, 2))
 
 
 def _walk(jaxpr):
@@ -241,27 +305,39 @@ def test_forced_decode_program_hands_the_kernel_the_cache_itself():
 # --------------------------------------------------------------------------- #
 # the election
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("backend,kernel,max_len,heads,elected", [
-    ("tpu", None, 1024, 2, True),
-    ("tpu", ("quant_ring",), 1024, 2, True),   # no word on flash_decode
-    ("tpu", None, 256, 2, True),               # the measured threshold
-    ("tpu", None, 128, 2, False),              # a lane below it
-    ("tpu", None, 1000, 2, False),             # no block divides it
-    ("tpu", None, 1024, 1, True),              # head_dim 128: row-major
-    ("tpu", None, 128, 1, False),              # ... under the threshold
-    ("tpu", {"flash_decode": False}, 1024, 2, False),
-    ("cpu", None, 1024, 2, False),
-    ("cpu", {"flash_decode": True}, 1024, 2, True),
+@pytest.mark.parametrize("backend,kernel,max_len,heads,kv_heads,elected", [
+    ("tpu", None, 1024, 2, None, True),
+    ("tpu", ("quant_ring",), 1024, 2, None, True),   # no word on the kernel
+    ("tpu", None, 256, 2, None, True),         # the measured threshold
+    ("tpu", None, 128, 2, None, False),        # a lane below it
+    ("tpu", None, 1000, 2, None, False),       # no block divides it
+    ("tpu", None, 1024, 1, None, True),        # head_dim 128: row-major
+    ("tpu", None, 128, 1, None, False),        # ... under the threshold
+    ("tpu", {"flash_decode": False}, 1024, 2, None, False),
+    ("cpu", None, 1024, 2, None, False),
+    ("cpu", {"flash_decode": True}, 1024, 2, None, True),
+    # four query heads on two key/value heads, and on one: as above
+    ("tpu", None, 1024, 4, 2, True),
+    ("tpu", None, 1024, 4, 1, True),
+    ("tpu", None, 128, 4, 2, False),
+    ("tpu", {"flash_decode": False}, 1024, 4, 2, False),
+    ("cpu", None, 1024, 4, 2, False),
+    ("cpu", {"flash_decode": True}, 1024, 4, 2, True),
 ])
 def test_dense_decode_election(monkeypatch, backend, kernel, max_len,
-                               heads, elected):
+                               heads, kv_heads, elected):
     from autodist_tpu.serving import ServingEngine
 
-    cfg = _lm_cfg(hidden_size=128, num_heads=heads, max_len=1024)
+    size = dict(hidden_size=128, num_heads=heads, max_len=1024)
+    if kv_heads:
+        cfg, params = _grouped(kv_heads, **size)
+    else:
+        cfg = _lm_cfg(**size)
+        params = _lm_params(cfg)
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
     telemetry.reset()
     try:
-        eng = ServingEngine(cfg, _lm_params(cfg), num_slots=2,
+        eng = ServingEngine(cfg, params, num_slots=2,
                             max_len=max_len, prefill_len=8, kernel=kernel)
         gauges = {m["name"]: m["value"]
                   for m in telemetry.get().registry.snapshot()
@@ -284,6 +360,7 @@ def test_paged_engine_is_left_out_of_the_election(monkeypatch):
     assert not eng.kernel and eng.decode_block_len is None
 
 
+@pytest.mark.parametrize("kv_heads", [None, 1], ids=["a-head", "grouped"])
 @pytest.mark.parametrize("kernel,attended,resident", [
     # one request of 250 prompt tokens beside an empty slot, K = 4, two
     # windows.  Blocks of 128 (four a lane): the first window's steps
@@ -294,11 +371,17 @@ def test_paged_engine_is_left_out_of_the_election(monkeypatch):
     (None, 2 * 4 * 2, 2 * 4 * 2),
 ])
 def test_kv_block_counters_read_what_the_lengths_imply(kernel, attended,
-                                                       resident):
+                                                       resident, kv_heads):
+    """Per layer and key/value head, so a group of query heads on one
+    changes neither count."""
     from autodist_tpu.serving import ContinuousBatcher, ServingEngine
 
-    cfg = _lm_cfg()
-    eng = ServingEngine(cfg, _lm_params(cfg), num_slots=2, max_len=512,
+    if kv_heads:
+        cfg, params = _grouped(kv_heads)
+    else:
+        cfg = _lm_cfg()
+        params = _lm_params(cfg)
+    eng = ServingEngine(cfg, params, num_slots=2, max_len=512,
                         prefill_len=256, decode_steps=4, kernel=kernel)
     telemetry.reset()
     try:

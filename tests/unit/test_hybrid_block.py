@@ -486,13 +486,34 @@ def test_the_recurrence_stays_float32_beside_bf16_activations(cfg):
      "prefix caching"),
     (dict(kv_layout="paged", kv_block_len=4), "paged KV"),
     (dict(tensor_parallel=2), "tensor_parallel"),
-    (dict(kernel={"flash_decode": True}), "flash_decode"),
 ], ids=["chunked-prefill", "speculative", "prefix-caching", "paged",
-        "tensor-parallel", "fused-decode-kernel"])
+        "tensor-parallel"])
 def test_engine_options_refuse_the_block_by_name(cfg, params, kw, names):
     with pytest.raises(ValueError, match=names):
         ServingEngine(cfg, params, num_slots=2, max_len=32, prefill_len=8,
                       **kw)
+
+
+@pytest.mark.parametrize("max_len,block", [(256, 128), (48, None)],
+                         ids=["whole-cache", "a-layers-slice"])
+def test_the_fused_decode_kernel_serves_the_composed_tokens(cfg, params,
+                                                            max_len, block):
+    """The full layers' grouped query heads through the fused decode
+    kernel (forced: the interpreter) — over the whole cache in blocks of
+    128, and over a layer's slice where no block reads the lane in place
+    — serve the greedy tokens of ``cached_attention``, request for
+    request, beside the linear layers' state."""
+    requests = _requests(n=5)
+    long = dataclasses.replace(cfg, max_len=256)    # rotary: no table
+    served = {}
+    for word in (False, True):
+        engine_kw = dict(max_len=max_len, kernel={"flash_decode": word})
+        assert ServingEngine(long, params, num_slots=3, prefill_len=16,
+                             **engine_kw).kv.fused_block == (
+            block if word else None)
+        served[word] = _serve(long, params, requests, **engine_kw)
+    for (_, fused), (_, plain) in zip(served[True], served[False]):
+        np.testing.assert_array_equal(fused, plain)
 
 
 def test_grouped_heads_alone_refuse_the_block_table(cfg, params):

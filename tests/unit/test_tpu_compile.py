@@ -175,7 +175,13 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     ``dynamic_update_slice`` out; and Mosaic takes the grouped-matmul
     kernel at the cell's 320 pairs through ``[64, 2048, 1024]`` experts,
     one call a layer over the experts as they are held, with no
-    ``ragged-dot`` and no ``conditional`` on the pairs' bound left."""
+    ``ragged-dot`` and no ``conditional`` on the pairs' bound left; and
+    Mosaic takes the dense decode kernel with eight query heads in the
+    rows of each of the 2 key/value heads of 256 at the cell's lane of
+    2,560 positions — one call a full layer out of one lowering, its
+    ``[256, 256]`` tiles read from the cache as stored, the step's rows
+    written inside it: no op of a layer's lanes and no
+    ``dynamic-update-slice`` of a row is left."""
     from autodist_tpu.models import pipeline_lm as lm
     from autodist_tpu.models.transformer import (BlockSpec, LinearMixerSpec,
                                                  RoutedFFNSpec,
@@ -186,15 +192,16 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
 
     fused = program == "decode-kernel"
     bf16, slots, bucket = jnp.bfloat16, 32 if fused else 8, 256
+    T = 2560 if fused else 512
     if fused:
-        for kernel in ("delta_step", "grouped_matmul"):
+        for kernel in ("delta_step", "flash_decode", "grouped_matmul"):
             monkeypatch.setattr(
                 importlib.import_module(
                     f"autodist_tpu.kernel.pallas.{kernel}"),
                 "default_interpret", lambda: False)
     cfg = TransformerConfig(
         vocab_size=18992, hidden_size=2048, num_layers=4, num_heads=16,
-        mlp_dim=512, max_len=512, dtype=bf16, dropout_rate=0.0,
+        mlp_dim=512, max_len=T, dtype=bf16, dropout_rate=0.0,
         attention_dropout_rate=0.0,
         block=BlockSpec(
             norm="rmsnorm", norm_placement="pre", norm_zero_centred=True,
@@ -207,12 +214,14 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
                           lm.param_shapes(cfg),
                           is_leaf=lambda x: isinstance(x, tuple))
-    engine = ServingEngine(cfg, params, num_slots=slots, max_len=512,
+    engine = ServingEngine(cfg, params, num_slots=slots, max_len=T,
                            prefill_len=bucket, decode_steps=8,
                            kernel={"delta_step": fused,
-                                   **({"grouped_matmul": True} if fused
+                                   **({"flash_decode": True,
+                                       "grouped_matmul": True} if fused
                                       else {})})
     assert engine.kv.state_kernel(engine.cache.state.ssm) == fused
+    assert engine.kv.fused_block == (256 if fused else None)
     sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
                                          sharding=one_chip)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
@@ -244,12 +253,31 @@ def test_mixed_stack_compiles_in_place(one_chip, program, monkeypatch):
     assert not re.findall(r"= bf16\[64,2048,1024\][^ ]* (?:copy|fusion)\(",
                           text)
     layer_state = rf"f32\[(?:1,)?{slots},32,128,128\]"
+    # a full layer's lanes, and a step's rows going into them
+    lanes = re.findall(rf"bf16\[{slots},2,{T},256\]", text)
+    row_writes = re.findall(r",256\][^ ]* dynamic-update-slice\(", text)
     if fused:
         assert text.count("adtk_delta_step") >= 3
         assert not re.findall(layer_state, text)
+        assert len(re.findall(r"custom-call\([^\n]*adtk_flash_decode",
+                              text)) == 1
+        assert not lanes and not row_writes
+        # the cache as stored, head_dim minor-most: the kernel's view
+        layouts = set(re.findall(
+            rf"bf16\[1,{slots},2,{T},256\](\{{[\d,]+)", text))
+        assert layouts == {"{4,3,2,1,0"}, layouts
+        assert not re.findall(rf"= bf16\[1,{slots},2,{T},256\][^ ]* "
+                              r"(?:copy|transpose|fusion)\(", text)
+        # one lowering, the layer an operand of its calls
+        stablehlo = lowered.as_text()
+        assert stablehlo.count("func.func private "
+                               "@flash_decode_layer") == 1
+        assert len(re.findall(r"call @flash_decode_layer\(",
+                              stablehlo)) == 1
     elif program == "decode":
         assert re.findall(layer_state, text)     # the slice, the write
         assert "adtk_delta_step" not in text
+        assert lanes and row_writes and "adtk_flash_decode" not in text
         assert hashlib.sha256(_program_text(text).encode()) \
             .hexdigest()[:16] == COMPOSED_DECODE_HLO
 
