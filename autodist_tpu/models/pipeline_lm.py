@@ -630,7 +630,7 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
 # pairs each appear twice), sqrt(2) between: (d / 2 + 1) d entries for
 # d (d + 1) / 2 distinct products, and a row is one rotation of x.  S is
 # [offsets, dv, d], an offset's tile with d on its last axis.
-RETENTION_CHUNK = 64   # positions a chunk of the chunked form spans
+RETENTION_CHUNK = 64   # positions of a window whose keys are expanded at once
 RETENTION_EPS = 1e-6   # what the normaliser's sum is kept above
 
 
@@ -671,77 +671,79 @@ def retention_step(q, k, v, g, state, eps: float = RETENTION_EPS):
 
 def retention_chunked(q, k, v, g, state, chunk: int = RETENTION_CHUNK,
                       eps: float = RETENTION_EPS):
-    """The recurrence over a window, ``chunk`` positions at a time.
-    ``q`` ``[B, T, heads, d]`` (scaled); ``k``, ``v`` ``[B, T, kv, d]``;
-    ``g`` ``[B, T, kv]``; ``state`` as :func:`retention_step`'s.  Returns
-    ``(y [B, T, heads, dv], state after position T - 1)``.  A position
-    with ``g == 0`` and ``k == 0`` leaves the state bit for bit (a padded
-    position; the window is padded so to whole chunks).
+    """The recurrence over a window.  ``q`` ``[B, T, heads, d]``
+    (scaled); ``k``, ``v`` ``[B, T, kv, d]``; ``g`` ``[B, T, kv]``;
+    ``state`` as :func:`retention_step`'s, or ``None``: no inputs yet,
+    the window starts at position 0.  Returns ``(y [B, T, heads, dv],
+    state after position T - 1)``.  A position with ``g == 0`` and ``k
+    == 0`` leaves the state bit for bit and weighs nothing at any later
+    position (a padded position).
 
-    A chunk attends inside itself in the attention form (``[C, C]``
-    scores squared, the gates' differences taken before the ``exp``),
-    reads the state before it through ``phi(q)`` — built for this chunk
-    alone — and leaves the state after it; the state moves once a
-    chunk."""
+    The outputs are the attention form over the whole window (``[T, T]``
+    scores squared, the gates' differences taken before the ``exp``) —
+    ``phi(q) . phi(k) = (q . k)^2``, so no query is expanded — plus, only
+    where a state came in, its read through ``phi(q)``.  The state after
+    is built once, from the keys alone, ``chunk`` positions at a time
+    (what bounds ``phi(k)``), on top of the decayed state that came
+    in."""
     B, T, H, d = q.shape
     kv, C = k.shape[2], chunk
-    f32 = lambda t: t.astype(jnp.float32)
-    q, k, v, g = map(f32, (q, k, v, g))
-    pad = -T % C
-    if pad:
-        q, k, v, g = (jnp.pad(t, [(0, 0), (0, pad)]
-                              + [(0, 0)] * (t.ndim - 2))
-                      for t in (q, k, v, g))
-    N = (T + pad) // C
-    def split(t):
-        """``[B, T, heads.., x]`` -> ``[N, B, heads.., C, x]``: chunks
-        first, a chunk's positions beside the last axis."""
-        t = jnp.moveaxis(t, 1, -2)
-        return jnp.moveaxis(
-            t.reshape(*t.shape[:-2], N, C, t.shape[-1]), -3, 0)
-
-    q = split(q.reshape(B, T + pad, kv, H // kv, d))     # [N,B,kv,grp,C,d]
-    k, v = split(k), split(v)                            # [N,B,kv,C,d]
-    g = split(g[..., None])[..., 0]                      # [N,B,kv,C]
+    heads_first = lambda t: jnp.moveaxis(                # [B, heads.., T, x]
+        t.astype(jnp.float32), 1, -2)
+    q = heads_first(q.reshape(B, T, kv, H // kv, d))     # [B, kv, grp, T, d]
+    k, v = heads_first(k), heads_first(v)                # [B, kv, T, d]
+    G = jnp.cumsum(heads_first(g[..., None])[..., 0], -1)    # [B, kv, T]
     mm = lambda eq, a, b: jnp.einsum(eq, a, b, precision=_HI)
-    upto = jnp.tril(jnp.ones((C, C), bool))              # s <= t
+    upto = jnp.tril(jnp.ones((T, T), bool))              # s <= t
+    decay = jnp.where(upto, jnp.exp(jnp.where(
+        upto, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
+    a = mm("bghtd,bgsd->bghts", q, k) ** 2 * decay[:, :, None]
+    num, den = mm("bghts,bgsv->bghtv", a, v), a.sum(-1)
+    last = G[..., -1:]                                   # [B, kv, 1]
+    O = d // 2 + 1
+    if state is None:       # nothing to read, and the build starts at 0
+        state = (jnp.zeros((B, kv, O, v.shape[-1], d), jnp.float32),
+                 jnp.zeros((B, O, kv, d), jnp.float32))
+    else:
+        S, z = state
+        pq = symmetric_square(q)                         # [B,kv,grp,T,O,d]
+        into = jnp.exp(G)[:, :, None, :]                 # [B, kv, 1, T]
+        num = num + into[..., None] * mm("bghtoi,bgoai->bghta", pq, S)
+        den = den + into * mm("bghtoi,bogi->bght", pq, z)
+        state = (S * jnp.exp(last)[..., None, None],
+                 z * jnp.exp(last)[:, None])
+    y = (num / (den[..., None] + eps)).reshape(B, H, T, -1)
+
+    # what position s leaves in the state after the window: its key's
+    # square times exp(G_T - G_s); whole chunks, the tail's keys 0
+    pad = -T % C
+    w = jnp.pad(jnp.exp(last - G), [(0, 0), (0, 0), (0, pad)])
+    k, v = (jnp.pad(t, [(0, 0), (0, 0), (0, pad), (0, 0)]) for t in (k, v))
+    chunks = lambda t: jnp.moveaxis(
+        t.reshape(B, kv, (T + pad) // C, C, *t.shape[3:]), 2, 0)
 
     def one_chunk(state, c):
-        S, z = state
-        q_c, k_c, v_c, g_c = c
-        G = jnp.cumsum(g_c, -1)                          # [B, kv, C]
-        decay = jnp.where(upto, jnp.exp(jnp.where(
-            upto, G[..., :, None] - G[..., None, :], 0.0)), 0.0)
-        a = mm("bghtd,bgsd->bghts", q_c, k_c) ** 2 * decay[:, :, None]
-        pq = symmetric_square(q_c)                       # [B,kv,grp,C,O,d]
-        into = jnp.exp(G)[:, :, None, :]                 # [B, kv, 1, C]
-        num = mm("bghts,bgsv->bghtv", a, v_c) \
-            + into[..., None] * mm("bghtoi,bgoai->bghta", pq, S)
-        den = a.sum(-1) + into * mm("bghtoi,bogi->bght", pq, z)
-        last = G[..., -1:]                               # [B, kv, 1]
-        pk = symmetric_square(k_c) \
-            * jnp.exp(last - G)[..., None, None]         # [B, kv, C, O, d]
-        S = S * jnp.exp(last)[..., None, None] \
-            + mm("bgsoi,bgsa->bgoai", pk, v_c)
-        z = z * jnp.exp(last)[:, None] + jnp.swapaxes(pk.sum(2), 1, 2)
-        return (S, z), num / (den[..., None] + eps)
+        k_c, v_c, w_c = c
+        pk = symmetric_square(k_c) * w_c[..., None, None]    # [B,kv,C,O,d]
+        return (state[0] + mm("bgsoi,bgsa->bgoai", pk, v_c),
+                state[1] + jnp.swapaxes(pk.sum(2), 1, 2)), None
 
-    state, y = jax.lax.scan(one_chunk, state, (q, k, v, g))
-    # [N, B, kv, grp, C, dv] -> [B, T, heads, dv]
-    y = jnp.moveaxis(y, 0, 3).reshape(B, H, N * C, -1)
-    return jnp.moveaxis(y, 1, 2)[:, :T], state
+    state, _ = jax.lax.scan(one_chunk, state,
+                            (chunks(k), chunks(v), chunks(w)))
+    return jnp.moveaxis(y, 1, 2), state
 
 
 def retention_attention(cfg: TransformerConfig, chunk, x, state, positions,
                         *, valid=None, step=retention_step):
     """The power-retention mixer with its residual: ``(x + mixer(N(x)),
     (S, z))``.  ``x``: ``[B, S, H]`` at absolute ``positions``; ``state``
-    before the window, as :func:`retention_step`'s.  q, k and v are
+    before the window, as :func:`retention_chunked`'s (``None``: the
+    window starts at position 0).  q, k and v are
     :func:`attention_inputs`' (grouped heads, q/k norm, rotary) from the
     layer's ``linear_attention`` sub-tree, which holds the gate's ``[H,
     kv]`` projection beside them; the log gate ``log sigmoid`` of it is
-    float32 end to end.  One position runs the recurrence, a longer
-    window the chunked form; ``valid`` and ``step`` are
+    float32 end to end.  One position on a state runs the recurrence,
+    any other window the chunked form; ``valid`` and ``step`` are
     :func:`linear_attention`'s."""
     spec = cfg.block
     la = chunk["linear_attention"]
@@ -757,7 +759,7 @@ def retention_attention(cfg: TransformerConfig, chunk, x, state, positions,
         if valid is not None:       # a padded position: no decay, no write
             g = g * valid[..., None]
             k = k * valid[..., None, None]
-        if x.shape[1] == 1:
+        if x.shape[1] == 1 and state is not None:
             y, state = step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], state)
             y = y[:, None]
         else:
@@ -773,12 +775,16 @@ def mix_linear(cfg: TransformerConfig, chunk, x, state, positions, *,
     """A ``"linear"`` layer's mixer with its residual, whichever
     recurrence ``cfg.block.linear.rule`` names: ``(x + mixer(N(x)),
     state)``.  ``state`` is the tuple of arrays the rule keeps (the delta
-    rule's ``(tail, S)``, retention's ``(S, z)``); ``positions`` reach
-    the rule whose q and k are rotated."""
+    rule's ``(tail, S)``, retention's ``(S, z)``), or ``None``: no inputs
+    yet, the window starts at position 0 — which retention's chunked form
+    takes as it is and the delta rule as :func:`blank_linear_state`'s
+    zeros; ``positions`` reach the rule whose q and k are rotated."""
     kw = {} if step is None else {"step": step}
     if cfg.block.linear.rule == "retention":
         return retention_attention(cfg, chunk, x, state, positions,
                                    valid=valid, **kw)
+    if state is None:
+        state = blank_linear_state(cfg, x.shape[0])
     return linear_attention(cfg, chunk, x, state, valid=valid,
                             length=length, **kw)
 
@@ -914,7 +920,7 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
     ``arange`` of the sequence where not given.
 
     A ``"linear"`` layer of a mixed stack (its chunk holds
-    ``linear_attention``) runs the mixer from a blank state: the whole
+    ``linear_attention``) runs the mixer from no state: the whole
     sequence is the window.  ``valid`` is :func:`ffn_residual`'s.
 
     A latent-attention layer (its chunk holds ``latent_attention``)
@@ -932,8 +938,7 @@ def _tp_encoder_layer(cfg: TransformerConfig, chunk, x, mask, model_axis,
     if "linear_attention" in chunk:
         if positions is None:
             positions = jnp.arange(x.shape[1])
-        x, _ = mix_linear(cfg, chunk, x, blank_linear_state(cfg, x.shape[0]),
-                          positions)
+        x, _ = mix_linear(cfg, chunk, x, None, positions)
         return ffn_residual(cfg, chunk, x, model_axis, comm_overlap)
     if positions is None and cfg.block.positions == "rope":
         positions = jnp.arange(x.shape[1])

@@ -943,13 +943,12 @@ class ServingEngine:
             once = functools.partial(jax.jit, inline=True)
             attend_prompt = once(lambda chunk, x: self._layer_prefill(
                 chunk, x, mask, positions, valid))
-            # a linear layer starts from a blank state — the slot's
-            # previous occupant left one that is nobody's — masks the
-            # padding out of the recurrence and cuts the convolution's
-            # tail at p_len
+            # a linear layer starts from no state — the slot's previous
+            # occupant left one that is nobody's — masks the padding out
+            # of the recurrence and cuts the convolution's tail at p_len
             mix_prompt = once(lambda chunk, x: lm.mix_linear(
-                self.cfg, chunk, x, lm.blank_linear_state(self.cfg, 1),
-                positions, valid=valid, length=p_len))
+                self.cfg, chunk, x, None, positions, valid=valid,
+                length=p_len))
             ffn = once(lambda chunk, x: self._ffn(chunk, x, valid))
 
             def layer_fn(chunk, x, kc, vc, _, layer):
@@ -1334,9 +1333,12 @@ class ServingEngine:
             return
         telemetry.counter("engine/prefill_rows").inc(rows)
         telemetry.counter("engine/prefill_positions").inc(positions)
-        if self.linear_layers:      # a state built a prompt and layer
-            telemetry.counter("engine/state_prompts").inc(
-                rows * self.linear_layers)
+        if self.linear_layers:
+            # a state built a prompt and layer, and how many of them from
+            # no state (all: no prompt's pass is handed one)
+            for name in ("engine/state_prompts",
+                         "engine/state_prompts_blank"):
+                telemetry.counter(name).inc(rows * self.linear_layers)
         for S in by_rung:
             telemetry.counter(f"engine/prefill_rung_rows/{S}").inc(
                 by_rung[S])

@@ -1141,8 +1141,6 @@ def retention_block_phase(*, slots: int = 16, kv_heads: int = 8,
                   jnp.einsum("bhd,bhd->bh", q[:, 0, :kv], k[:, 0],
                              precision=hi) ** 2, 1e-5)
     g = -jax.nn.softplus(rand(B, T, kv))
-    blank = (jnp.zeros((B, *mixer.state_shape), jnp.float32),
-             jnp.zeros((B, *mixer.normaliser_shape), jnp.float32))
 
     def attention_form(q, k, v, g):
         G = jnp.repeat(jnp.cumsum(g, 1), group, 2)          # [B, T, n]
@@ -1156,13 +1154,23 @@ def retention_block_phase(*, slots: int = 16, kv_heads: int = 8,
             / (a.sum(-1).transpose(0, 2, 1)[..., None] + lm.RETENTION_EPS)
 
     ref = jax.jit(attention_form)(q, k, v, g)
+    # a prompt's pass: the window from no state, then a window ON a state
     (y, after), s = timed(lambda: jax.block_until_ready(
-        jax.jit(lm.retention_chunked)(q, k, v, g, blank)))
-    require_close(ph, f"retention_chunked output (window of {T}, {n} heads "
-                      f"on {kv} of {d})", y, ref, 1e-4)
-    say(ph, f"state built, chunked over {T} positions: first call {s:.2f}s")
+        jax.jit(lm.retention_chunked)(q, k, v, g, None)))
+    require_close(ph, f"retention_chunked output (window of {T} from no "
+                      f"state, {n} heads on {kv} of {d})", y, ref, 1e-4)
+    say(ph, f"state built once over {T} positions: first call {s:.2f}s")
+    half = T // 2
+    head = jax.jit(lm.retention_chunked)(
+        *(t[:, :half] for t in (q, k, v, g)), None)[1]
+    y2, carried = jax.jit(lm.retention_chunked)(
+        *(t[:, half:] for t in (q, k, v, g)), head)
+    require_close(ph, f"retention_chunked output of {T - half} positions on "
+                      f"the state of {half}", y2, ref[:, half:], 1e-4)
+    require_close(ph, "its state against the whole window's", carried[0],
+                  after[0], 1e-4)
     before = jax.jit(lm.retention_chunked)(q[:, :-1], k[:, :-1], v[:, :-1],
-                                           g[:, :-1], blank)[1]
+                                           g[:, :-1], None)[1]
     last = tuple(t[:, -1] for t in (q, k, v, g))
     y1, stepped = jax.jit(lm.retention_step)(*last, before)
     require_close(ph, "retention_step output after the window", y1,

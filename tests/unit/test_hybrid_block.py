@@ -219,6 +219,36 @@ def _admit(cfg, params, bucket, prompt, padding=0):
     return int(toks[1]), [np.asarray(a)[:, 1] for a in engine._state_args()]
 
 
+def test_a_prompts_pass_hands_the_delta_rule_its_zeros(cfg, params,
+                                                        monkeypatch):
+    """The engine's prompt path says "no state yet"; the delta rule is
+    still handed what it was: the very zeros ``blank_linear_state`` makes
+    for one row, an empty convolution tail and an empty matrix."""
+    made, seen = [], []
+    blank, mixer = lm.blank_linear_state, lm.linear_attention
+
+    def making(cfg, batch):
+        made.append(blank(cfg, batch))
+        return made[-1]
+
+    def looking(cfg, chunk, x, state, **kw):
+        if x.shape[1] == 16:        # the prompt's row, not a decode step
+            seen.append(state)
+        return mixer(cfg, chunk, x, state, **kw)
+
+    monkeypatch.setattr(lm, "blank_linear_state", making)
+    monkeypatch.setattr(lm, "linear_attention", looking)
+    _admit(cfg, params, 16, np.arange(5))
+    assert seen                                 # traced once a kind
+    for state in seen:
+        assert any(state is zeros for zeros in made)
+        tail, ssm = state
+        assert tail.shape == (1, cfg.block.linear.conv_taps - 1,
+                              cfg.block.linear.conv_channels)
+        assert ssm.shape == (1, *cfg.block.linear.state_shape)
+        assert (tail.dtype, ssm.dtype) == (cfg.dtype, jnp.float32)
+
+
 @pytest.mark.parametrize("p_len", [1, 2, 3, 9, 16])
 def test_padding_leaves_the_state_bit_for_bit(cfg, params, p_len):
     """The same prompt in the same bucket, padded with token 0 or with

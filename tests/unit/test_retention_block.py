@@ -121,6 +121,23 @@ def _attention_form(q, k, v, g, eps=lm.RETENTION_EPS):
         / (a.sum(-1).transpose(0, 2, 1)[..., None] + eps)
 
 
+def _stepped(q, k, v, g, state):
+    """The recurrence, position by position: ``(y [B, T, ..], state)``."""
+    def one(state, at):
+        y, state = lm.retention_step(*at, state)
+        return state, y
+
+    state, ys = jax.lax.scan(one, state, tuple(
+        jnp.moveaxis(t, 1, 0) for t in (q, k, v, g)))
+    return jnp.moveaxis(ys, 0, 1), state
+
+
+def _same(got, want, tol=2e-5):
+    """To float32 rounding, by the larger side's size."""
+    np.testing.assert_allclose(got, want, rtol=0,
+                               atol=tol * float(jnp.abs(want).max()))
+
+
 @pytest.mark.parametrize("d", [16, 128])
 def test_the_symmetric_square_keeps_the_squared_product(d):
     q, k = jax.random.normal(jax.random.PRNGKey(d), (2, 7, d))
@@ -151,17 +168,104 @@ def test_recurrence_chunked_and_attention_forms_agree(gate, T, tol, chunk):
     assert after[0].dtype == after[1].dtype == jnp.float32
     np.testing.assert_allclose(got, want, atol=tol, rtol=0)
 
-    def one(state, at):
-        y, state = lm.retention_step(*at, state)
-        return state, y
-
-    stepped, ys = jax.lax.scan(one, _blank(B, kv, d), tuple(
-        jnp.moveaxis(t, 1, 0) for t in (q, k, v, g)))
-    np.testing.assert_allclose(jnp.moveaxis(ys, 0, 1), want, atol=tol,
-                               rtol=0)
+    ys, stepped = _stepped(q, k, v, g, _blank(B, kv, d))
+    np.testing.assert_allclose(ys, want, atol=tol, rtol=0)
     for a, b in zip(stepped, after):
         np.testing.assert_allclose(a, b, rtol=0,
                                    atol=1e-5 * float(jnp.abs(b).max()))
+
+
+# 5 query heads a key/value head (the published group); windows below, of
+# and not a multiple of the block of the state's build
+@pytest.mark.parametrize("T", [40, lm.RETENTION_CHUNK, 150],
+                         ids=["below", "one-block", "ragged"])
+def test_a_window_from_no_state_is_the_recurrence(T):
+    """``state=None`` — no inputs yet — against the recurrence from
+    zeros position by position, and against the same window handed the
+    zeros: outputs, ``S`` and ``z``."""
+    B, n, kv, d = 2, 10, 2, 16
+    q, k, v, g = _operands(3, B, T, n, kv, d, 0.3)
+    chunked = jax.jit(lm.retention_chunked)
+    got, (S, z) = chunked(q, k, v, g, None)
+    assert got.shape == (B, T, n, d)
+    assert S.dtype == z.dtype == jnp.float32
+    assert (S.shape, z.shape) == tuple(a.shape for a in _blank(B, kv, d))
+    want, (want_s, want_z) = _stepped(q, k, v, g, _blank(B, kv, d))
+    _same(got, want, 1e-4)
+    _same(S, want_s)
+    _same(z, want_z)
+    zeros, (zeros_s, zeros_z) = chunked(q, k, v, g, _blank(B, kv, d))
+    _same(got, zeros)
+    _same(S, zeros_s)
+    _same(z, zeros_z)
+
+
+@pytest.mark.parametrize("T", [1, 40, 150])
+def test_a_window_handed_a_state_is_still_the_recurrence(T):
+    """The general term is kept: 70 positions through the recurrence,
+    then the window on the state they left — the read through
+    ``phi(q)``, the state decayed over the window and built on."""
+    B, n, kv, d = 2, 10, 2, 16
+    q, k, v, g = _operands(4, B, 70 + T, n, kv, d, 0.3)
+    head, tail = (tuple(t[:, :70] for t in (q, k, v, g)),
+                  tuple(t[:, 70:] for t in (q, k, v, g)))
+    _, state = _stepped(*head, _blank(B, kv, d))
+    assert float(jnp.abs(state[0]).max()) > 1.0          # not blank
+    got, (S, z) = jax.jit(lm.retention_chunked)(*tail, state)
+    want, (want_s, want_z) = _stepped(*tail, state)
+    _same(got, want, 1e-4)
+    _same(S, want_s)
+    _same(z, want_z)
+
+
+@pytest.mark.parametrize("blank", [True, False], ids=["no-state", "state"])
+def test_a_padded_tail_leaves_the_state_bit_for_bit(blank):
+    """``retention_attention``'s ``valid``: 90 positions of 150 are the
+    prompt's, the tail's gates and keys are 0.  Whatever the tail's
+    queries and values hold, ``S`` and ``z`` are the same bits, the
+    prompt's outputs too — and they are the 90 positions' alone, to
+    rounding.  A window that is ALL padding hands a state back as it
+    came."""
+    B, T, p_len, n, kv, d = 2, 150, 90, 10, 2, 16
+    q, k, v, g = _operands(5, B, T, n, kv, d, 0.3)
+    valid = (jnp.arange(T) < p_len)[None, :]
+    k, g = k * valid[..., None, None], g * valid[..., None]
+    state = None if blank else _stepped(
+        *_operands(6, B, 30, n, kv, d, 0.3), _blank(B, kv, d))[1]
+    chunked = jax.jit(lm.retention_chunked)
+    got, after = chunked(q, k, v, g, state)
+    junk = jnp.where(valid[..., None, None], 0.0, 7.0)
+    other, after_junk = chunked(q + junk, k, v - junk, g, state)
+    for a, b in zip(after, after_junk):
+        assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+    assert (np.asarray(got[:, :p_len]).tobytes()
+            == np.asarray(other[:, :p_len]).tobytes())
+    short, after_short = chunked(*(t[:, :p_len] for t in (q, k, v, g)),
+                                 state)
+    _same(got[:, :p_len], short)
+    for a, b in zip(after, after_short):
+        _same(a, b)
+    if not blank:
+        _, back = chunked(q, jnp.zeros_like(k), v, jnp.zeros_like(g), state)
+        for a, b in zip(back, state):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes()
+
+
+def test_a_padded_position_weighs_nothing_later():
+    """A position inside the window whose gate and key are 0: every
+    later position's numerator and normaliser are what they are without
+    it — the outputs and the state of the window with the position cut
+    out."""
+    B, T, at, n, kv, d = 2, 50, 20, 10, 2, 16
+    q, k, v, g = _operands(7, B, T, n, kv, d, 0.3)
+    k, g = k.at[:, at].set(0.0), g.at[:, at].set(0.0)
+    got, after = jax.jit(lm.retention_chunked)(q, k, v, g, None)
+    cut = lambda t: jnp.delete(t, at, axis=1)
+    want, want_after = jax.jit(lm.retention_chunked)(
+        *map(cut, (q, k, v, g)), None)
+    _same(cut(got), want)
+    for a, b in zip(after, want_after):
+        _same(a, b)
 
 
 def test_a_slow_gate_needs_a_float32_state():
@@ -294,11 +398,12 @@ def test_retention_spec_refuses(change):
         LinearMixerSpec(2, 2, 16, 16, power=2)
 
 
-@pytest.mark.parametrize("length", [150])
+@pytest.mark.parametrize("length", [1, 150])
 def test_sequential_logits_match_the_reference(ref, rc, cfg, params, length):
-    """150 positions: three chunks of the chunked form, the state carried
-    from one to the next (one position is the engine's decode step,
-    below)."""
+    """150 positions from no state: the attention form over the window,
+    the state built in three blocks; one position from no state is a
+    window like another (one position ON a state is the engine's decode
+    step, below)."""
     wide = dataclasses.replace(cfg, max_len=256)
     tokens = jax.random.randint(jax.random.PRNGKey(length), (2, length), 0,
                                 cfg.vocab_size)
@@ -331,6 +436,8 @@ def test_prefill_then_decode_through_the_cache(ref, rc, cfg, params):
     assert "serve/kv_blocks_resident" not in counts
     assert counts["engine/state_rows"] > 0
     assert counts["engine/state_prompts"] == 4 * len(requests)
+    # every one of them from no state
+    assert counts["engine/state_prompts_blank"] == 4 * len(requests)
 
 
 def test_the_state_is_float32_and_no_keys_are_held(cfg, params):
@@ -498,9 +605,37 @@ def test_the_mixer_wears_the_scopes_the_readers_look_for(cfg, params):
         assert not any("/attention/" in n for n in names)
 
 
-def test_report_check_knows_the_gauges_and_the_counter(tmp_path):
+def test_a_prompts_program_expands_no_query(cfg, params):
+    """The engine's lowered prefill program multiplies no ``[.., offsets,
+    d]`` expansion of a QUERY (a row of ``phi`` a query head and
+    position: the read of a state, which a prompt's pass is not handed);
+    the keys' expansion, a key/value head's, is what builds the state.
+    The same search finds the read where a state does come in."""
+    import re
+
+    engine = ServingEngine(cfg, params, num_slots=2, max_len=48,
+                           prefill_len=16, decode_steps=4)
+    c = engine.cache
+    prompt = engine._prefill_jit.lower(
+        engine.params, c.k, c.v, c.lengths, engine._tok,
+        *engine._blank_prefill_args()).as_text()
+    kv, group, d = cfg.kv_heads, cfg.num_heads // cfg.kv_heads, cfg.head_dim
+    dots = lambda text: [line for line in text.splitlines()
+                         if "stablehlo.dot_general" in line]
+    of_a_query = re.compile(rf"tensor<1x{kv}x{group}x\d+x{d // 2 + 1}x{d}xf32>")
+    of_a_key = re.compile(rf"tensor<1x{kv}x\d+x{d // 2 + 1}x{d}xf32>")
+    assert not [line for line in dots(prompt) if of_a_query.search(line)]
+    assert len([line for line in dots(prompt)
+                if of_a_key.search(line)]) == 1      # traced once a kind
+    q, k, v, g = _operands(8, 1, 16, cfg.num_heads, kv, d, 0.3)
+    carried = jax.jit(lm.retention_chunked).lower(
+        q, k, v, g, _blank(1, kv, d)).as_text()
+    assert len([line for line in dots(carried)
+                if of_a_query.search(line)]) == 2    # S and z
+
+
+def _report_tool():
     import importlib
-    import json
     import os
     import sys
 
@@ -508,9 +643,16 @@ def test_report_check_knows_the_gauges_and_the_counter(tmp_path):
 
     sys.path.insert(0, os.path.join(ROOT, "tools"))
     try:
-        report = importlib.import_module("telemetry_report")
+        return importlib.import_module("telemetry_report")
     finally:
         sys.path.pop(0)
+
+
+def test_report_check_knows_the_gauges_and_the_counter(tmp_path):
+    import json
+    import os
+
+    report = _report_tool()
     gauge = lambda name, v: {"kind": "gauge", "name": name, "value": v}
     count = lambda name, v: {"kind": "counter", "name": name, "value": v}
     sound = [gauge("engine/state_bytes_per_slot", 274763776),
@@ -536,3 +678,34 @@ def test_report_check_knows_the_gauges_and_the_counter(tmp_path):
     assert any("whole layers of the rows" in p
                for p in problems(sound[:-1]
                                  + [count("engine/state_prompts", 57)]))
+
+
+@pytest.mark.parametrize("records,says", [
+    ([("engine/state_prompts", 56), ("engine/state_prompts_blank", 56)],
+     None),
+    # the day a state is handed across a chunk's edge
+    ([("engine/state_prompts", 56), ("engine/state_prompts_blank", 40)],
+     None),
+    ([("engine/state_prompts", 56), ("engine/state_prompts_blank", 57)],
+     "some of the states built"),
+    ([("engine/state_prompts_blank", 56)], "some of the states built"),
+], ids=["equal", "fewer", "more", "alone"])
+def test_report_check_holds_the_blank_count_under_the_states_built(
+        tmp_path, records, says):
+    import json
+    import os
+
+    report = _report_tool()
+    rows = [{"kind": "counter", "name": "engine/prefill_rows", "value": 7},
+            {"kind": "counter", "name": "engine/prefill_positions",
+             "value": 7 * 256},
+            {"kind": "counter", "name": "engine/prefill_rung_rows/256",
+             "value": 7}] + [
+        {"kind": "counter", "name": n, "value": v} for n, v in records]
+    with open(os.path.join(tmp_path, "metrics.jsonl"), "w") as f:
+        f.write("\n".join(json.dumps(r) for r in rows) + "\n")
+    problems = report.check_schema(str(tmp_path))
+    if says is None:
+        assert problems == []
+    else:
+        assert any(says in p for p in problems)

@@ -604,10 +604,14 @@ def test_retention_stack_compiles_in_place(one_chip, program, monkeypatch):
     assert held == 2 * 16 * (8_519_680 + 66_560) * 4
     assert abs(mem.alias_size_in_bytes - held) < 4096
     assert abs(mem.argument_size_in_bytes - held - weights) < 1 << 20
-    # a prompt row's FFN activations and one chunk's phi; never a layer's
-    # slice of the state (570 MB)
+    # a prompt row's FFN activations, its [40, 1024, 1024] scores and one
+    # block's phi(k); never a layer's slice of the state (570 MB)
     assert mem.temp_size_in_bytes < (16 << 20 if fused else 400 << 20)
     text = compiled.as_text()
+    # a prompt's pass expands its keys, a block at a time, and no query
+    # (a group's five heads of [positions, 65, 128] a key/value head)
+    assert fused or re.findall(r"f32\[(?:1,)?8,\d+,65,128\]", text)
+    assert not re.findall(r"f32\[(?:1,)?8,5,\d+,65,128\]", text)
     whole = rf"f32\[{L},{slots},8,65,128,128\]"
     assert not re.findall(
         rf"= {whole}[^ ]* (?:copy|slice|transpose|convert)\(", text)

@@ -136,6 +136,10 @@ _STATE_GAUGES = ("kv/state_rows", "kv/state_bytes",
 # engine/state_prompts: the states the prefill programs built, one a
 # prompt and linear layer — whole layers of engine/prefill_rows.
 _STATE_PROMPTS = "engine/state_prompts"
+# engine/state_prompts_blank: those of them built from no state (a
+# prompt's pass that starts at position 0) — beside engine/state_prompts
+# alone, and never more of them.
+_STATE_PROMPTS_BLANK = "engine/state_prompts_blank"
 # Latent rows read (autodist_tpu/serving/batcher.py): an engine whose
 # cached position is a latent-attention row advances
 # serve/latent_positions_read by every decode step's live positions x
@@ -824,6 +828,14 @@ def check_schema(run_dir: str) -> list[str]:
                 f"{built.get('value')!r} beside {_PREFILL_COUNTERS[0]} = "
                 f"{rows!r} — a prefill row builds one state in every "
                 "linear layer: whole layers of the rows")
+    blank = counters.get(_STATE_PROMPTS_BLANK)
+    if blank is not None and not (
+            0 <= blank.get("value", 0) <= (built or {}).get("value", -1)):
+        problems.append(
+            f"metrics.jsonl: {_STATE_PROMPTS_BLANK} = "
+            f"{blank.get('value')!r} beside {_STATE_PROMPTS} = "
+            f"{(built or {}).get('value')!r} — the states built from no "
+            "state are some of the states built")
 
     latent = counters.get(_LATENT_COUNTER)
     if latent is not None:
