@@ -1,17 +1,28 @@
 """The Pallas fused-kernel tier: cost-model alternatives the search elects.
 
-Three TPU kernels replace hot composed-XLA-op paths when — and only
-when — the Strategy IR's ``kernel`` slot elects them (a calibratable
-crossover decision, never an unconditional swap; the hierarchical
-placement results of arxiv 2110.10548 say the win is topology-
-dependent, and the round-3 flash-crossover measurements say it is
-shape-dependent too):
+These TPU kernels replace hot composed-XLA-op paths when — and only
+when — they are elected: by the Strategy IR's ``kernel`` slot, or where
+they are called, from what the call observes (:data:`OBSERVED_KERNELS`;
+the decode-attention kernels by the cache layout,
+``serving.kv_cache.layout_for``) — a calibratable crossover decision,
+never an unconditional swap; the hierarchical placement results of arxiv
+2110.10548 say the win is topology-dependent, and the round-3
+flash-crossover measurements say it is shape-dependent too:
 
-* :func:`~autodist_tpu.kernel.pallas.flash_decode.flash_decode_attention`
-  — single-query-per-slot block-streaming attention over the TP-sharded
-  KV cache (online softmax, masked slot lengths), the decode analog of
-  ``ops/flash_attention.py`` and the kernel that finally lets
-  ``ServingEngine`` accept ``attention_fn``.
+* :func:`~autodist_tpu.kernel.pallas.flash_decode
+  .flash_decode_attention_dense` — single-query-per-slot block-streaming
+  attention over the whole TP-sharded dense KV cache (online softmax,
+  masked slot lengths, a slot's live blocks only, the step's rows written
+  on the way), the decode analog of ``ops/flash_attention.py`` and the
+  kernel that lets ``ServingEngine`` accept ``attention_fn``; elected by
+  :func:`~autodist_tpu.kernel.pallas.flash_decode.dense_decode_elected`.
+  Its siblings: ``flash_decode_attention_latent`` over a cache of
+  latent-attention rows (``latent_decode_elected``) and
+  ``flash_decode_attention_paged`` over a block pool through its table.
+* :func:`~autodist_tpu.kernel.pallas.flash_prefill
+  .flash_prefill_attention_paged` — a chunk of prompt rows against the
+  paged cache, the page walk of the paged decode kernel with a window of
+  queries.
 * :func:`~autodist_tpu.kernel.pallas.quant_ring.quantized_ring_all_reduce`
   — the EQuARX-style fused quantize-into-all-reduce (PAPERS.md
   2506.17615): quantize/dequantize happens *per hop inside the ring
@@ -107,10 +118,6 @@ def default_interpret() -> bool:
 def __getattr__(name):
     # Lazy kernel re-exports: importing the registry (strategy/ir.py
     # does, at module import) must not pull jax.experimental.pallas.
-    if name == "flash_decode_attention":
-        from autodist_tpu.kernel.pallas.flash_decode import \
-            flash_decode_attention
-        return flash_decode_attention
     if name == "flash_prefill_attention_paged":
         from autodist_tpu.kernel.pallas.flash_prefill import \
             flash_prefill_attention_paged

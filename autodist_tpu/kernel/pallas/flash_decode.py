@@ -192,6 +192,32 @@ def rows_layout(head_dim: int) -> bool:
     return head_dim >= 128
 
 
+def dense_decode_elected(word, max_len: int, head_dim: int,
+                         backend: Optional[str] = None):
+    """The engine's election for a dense cache of keys and values: the
+    block the kernel reads it with, the step's rows written on the way,
+    or ``None`` for ``write_token`` and ``cached_attention``.  ``word``
+    is the kernel slot's on ``flash_decode``: ``False`` forbids the
+    kernel; ``None`` leaves it to what can be observed — a TPU under the
+    programs, a cache the kernel reads in place
+    (:func:`fused_decode_block`: grouped query heads ride in its rows), a
+    lane of at least :data:`MIN_FUSED_DECODE_LEN` by the chip's own
+    readings; ``True`` takes it wherever it can run (the interpreter off
+    the TPU), with any block that divides the lane where the kernel's
+    view of the cache is a copy of it — what forcing the latent kernel
+    means too (:func:`latent_decode_elected`)."""
+    if word is False:
+        return None
+    block = fused_decode_block(max_len, head_dim)
+    if word:
+        return block or decode_block_len(max_len) \
+            or math.gcd(max_len, DEFAULT_BLOCK_K)
+    if block and max_len >= MIN_FUSED_DECODE_LEN \
+            and (backend or jax.default_backend()) == "tpu":
+        return block
+    return None
+
+
 def _heads_per_step(heads: int, block_len: int, d: int, itemsize: int):
     """Most heads of a slot (a divisor of ``heads``) whose K block fits
     :data:`KV_BLOCK_BYTES` of VMEM (the minor dimension pads to the 128
@@ -487,8 +513,8 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     if bk is None:
         raise ValueError(
             f"a cache lane of {T} positions does not divide into blocks "
-            f"of {int(block_k or DEFAULT_BLOCK_K)}; use "
-            "flash_decode_attention on the layer's slice (it pads a copy)")
+            f"of {int(block_k or DEFAULT_BLOCK_K)}: give a block_k that "
+            "divides it (dense_decode_elected does)")
     hb = int(heads_per_step or _heads_per_step(
         H, bk, d, jnp.dtype(k_cache.dtype).itemsize))
     if H % hb:
@@ -514,31 +540,6 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
         return res.reshape(q.shape)                # [B, 1, heads, d]
     out, kt, vt = res
     return out.reshape(q.shape), view(kt), view(vt)
-
-
-def flash_decode_attention(q, k_layer, v_layer, lengths, *,
-                           dtype=jnp.float32,
-                           block_k: Optional[int] = None,
-                           interpret: Optional[bool] = None):
-    """:func:`flash_decode_attention_dense` on one layer's slice.
-
-    ``k_layer``/``v_layer``: ``[B, kv_heads, T, head_dim]``.  ``block_k``
-    defaults to :data:`DEFAULT_BLOCK_K` capped at the cache length.  A
-    cache length that ``block_k`` does not divide is zero-padded per
-    call (a copy of the layer's cache; padded positions sit above every
-    legal length, so the mask never reads them as keys) — size
-    ``max_len`` to a block multiple where that matters.
-    """
-    T = k_layer.shape[2]
-    bk = min(int(block_k or DEFAULT_BLOCK_K), T)
-    pad = (-T) % bk
-    if pad:
-        cfg = [(0, 0), (0, 0), (0, pad), (0, 0)]
-        k_layer = jnp.pad(k_layer, cfg)
-        v_layer = jnp.pad(v_layer, cfg)
-    return flash_decode_attention_dense(
-        q, k_layer[None], v_layer[None], 0, lengths, dtype=dtype,
-        block_k=bk, interpret=interpret)
 
 
 # --------------------------------------------------------------------------- #

@@ -18,9 +18,7 @@ through a custom VJP.
 from __future__ import annotations
 
 import functools
-import json
 import math
-import os
 from typing import Optional
 
 import jax
@@ -41,117 +39,17 @@ NEG_INF = float(np.finfo(np.float32).min)
 # itemsize x 128-lane groups of head_dim.
 MAX_SEQ_BYTES = 16384 * 2
 
-# --------------------------------------------------------------------------- #
-# Measured tuning table (written by tools/flash_crossover.py --write):
-# per-(causal, seq-length) best block sizes and the einsum-vs-flash
-# crossover, so on-silicon measurements are adopted by every caller that
-# leaves block sizes unset — instead of living only in prose.  (No such
-# table has been written yet: the file is absent until the kernel is
-# measured on the chip.)
-# --------------------------------------------------------------------------- #
+# The block sizes a caller gets who names none: chosen, with the training
+# election's bounds (``FUSED_HEAD_DIMS``, ``MIN_FUSED_LEN``,
+# ``MAX_FUSED_LEN`` below), from ``tools/flash_crossover.py``'s readings
+# on the chip.
 DEFAULT_BLOCK = 128
-_TUNING_ENV = "AUTODIST_TPU_FLASH_TUNING"
-_tuning_cache: Optional[dict] = None
 
 
-def _tuning_path() -> Optional[str]:
-    p = os.environ.get(_TUNING_ENV)
-    if p:
-        return p if os.path.exists(p) else None
-    root = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    p = os.path.join(root, "flash_tuning.json")
-    return p if os.path.exists(p) else None
-
-
-def load_tuning(path: Optional[str] = None, *, reload: bool = False) -> dict:
-    """The measured tuning table ({} when none has been committed or the
-    file is not a JSON object — graceful degradation, never a crash in
-    the attention hot path)."""
-    global _tuning_cache
-    if path is None and _tuning_cache is not None and not reload:
-        return _tuning_cache
-    p = path or _tuning_path()
-    table: dict = {}
-    if p:
-        try:
-            with open(p) as f:
-                loaded = json.load(f)
-            table = loaded if isinstance(loaded, dict) else {}
-        except (OSError, ValueError):
-            table = {}
-    if path is None and table.get("backend") == "cpu":
-        # Dev-smoke artifact: interpret-mode timings say nothing about
-        # the TPU kernel, so auto-load ignores a CPU-provenance table
-        # (an explicit ``path`` argument still wins).
-        from autodist_tpu.utils import logging
-        logging.warning("ignoring CPU-provenance flash tuning table %s "
-                        "(pass the path explicitly to force)", p)
-        table = {}
-    if path is None:
-        _tuning_cache = table
-    return table
-
-
-def _branch(causal: bool, table: Optional[dict] = None) -> dict:
-    t = table if table is not None else load_tuning()
-    br = t.get("causal" if causal else "noncausal", {})
-    return br if isinstance(br, dict) else {}
-
-
-def _nearest_len(lens: list[int], seq_len: int) -> int:
-    at_or_below = [l for l in lens if l <= seq_len]
-    return at_or_below[-1] if at_or_below else lens[0]
-
-
-def tuned_blocks(seq_len: int, causal: bool) -> tuple[int, int]:
-    """Measured best (block_q, block_k) for this sequence length: the
-    nearest measured length at or below ``seq_len`` (falling back to the
-    nearest above, then :data:`DEFAULT_BLOCK`)."""
-    blocks = _branch(causal).get("blocks", {})
-    if isinstance(blocks, dict) and blocks:
-        try:
-            pick = _nearest_len(sorted(int(k) for k in blocks), seq_len)
-            b = blocks[str(pick)]
-            bq, bk = (b if isinstance(b, (list, tuple)) else (b, b))
-            return int(bq), int(bk)
-        except (TypeError, ValueError):
-            pass
-    return DEFAULT_BLOCK, DEFAULT_BLOCK
-
-
-def _resolve_blocks(seq_len: int, causal: bool,
-                    block_q: Optional[int],
+def _resolve_blocks(block_q: Optional[int],
                     block_k: Optional[int]) -> tuple[int, int]:
-    if block_q is not None and block_k is not None:
-        return block_q, block_k
-    tq, tk = tuned_blocks(seq_len, causal)
-    return (tq if block_q is None else block_q,
-            tk if block_k is None else block_k)
-
-
-def flash_wins(seq_len: int, causal: bool) -> Optional[bool]:
-    """Whether measurement says flash beats einsum at this length;
-    ``None`` when unmeasured (callers keep their own default).  Reads the per-length ``speedup``
-    records the crossover tool writes (nearest measured length), falling
-    back to a hand-written ``crossover_len``."""
-    br = _branch(causal)
-    speedup = br.get("speedup", {})
-    if isinstance(speedup, dict) and speedup:
-        try:
-            pick = _nearest_len(sorted(int(k) for k in speedup), seq_len)
-            return float(speedup[str(pick)]) > 1.0
-        except (TypeError, ValueError):
-            pass
-    if "crossover_len" not in br:
-        return None
-    cl = br["crossover_len"]
-    if cl is None:        # recorded: einsum won at every measured length
-        return False
-    try:
-        return seq_len >= int(cl)
-    except (TypeError, ValueError):
-        return None
+    return (DEFAULT_BLOCK if block_q is None else block_q,
+            DEFAULT_BLOCK if block_k is None else block_k)
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, scale: float,
@@ -872,8 +770,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
     Unmasked, with no block sizes given and a length the one-pass
     kernels take (:func:`flash_attention_one_pass`), those run;
     otherwise the blockwise kernels.
-    ``block_q``/``block_k`` default to the measured tuning table
-    (:func:`tuned_blocks`; :data:`DEFAULT_BLOCK` when none committed).
+    ``block_q``/``block_k`` default to :data:`DEFAULT_BLOCK`.
     ``interpret=None`` follows :func:`~autodist_tpu.kernel.pallas
     .default_interpret` (the Pallas interpreter off-TPU).  Sequences
     past :data:`MAX_SEQ_BYTES` raise ``ValueError``.
@@ -882,8 +779,7 @@ def flash_attention(q, k, v, *, causal: bool = False,
             and one_pass_fits(q.shape[1])):
         return flash_attention_one_pass(q, k, v, scale=scale,
                                         interpret=interpret)
-    block_q, block_k = _resolve_blocks(int(q.shape[1]), bool(causal),
-                                       block_q, block_k)
+    block_q, block_k = _resolve_blocks(block_q, block_k)
     (qb, kb, vb, s), (bq, bk, interp), (b, l, h, d) = _layout_bhld(
         q, k, v, scale, block_q, block_k, interpret)
     out = _flash_bhld(qb, kb, vb, s, bool(causal), bq, bk, interp, int(l))
@@ -905,8 +801,7 @@ def flash_attention_with_lse(q, k, v, *, causal: bool = False,
     + out_b·e^{lse_b−lse_m}`` — and the merge's lse cotangent is handled
     by the kernel's VJP.
     """
-    block_q, block_k = _resolve_blocks(int(q.shape[1]), bool(causal),
-                                       block_q, block_k)
+    block_q, block_k = _resolve_blocks(block_q, block_k)
     (qb, kb, vb, s), (bq, bk, interp), (b, l, h, d) = _layout_bhld(
         q, k, v, scale, block_q, block_k, interpret)
     out, lse = _flash_bhld_lse(qb, kb, vb, s, bool(causal), bq, bk,
@@ -980,8 +875,7 @@ def fused_attention_fits(q, k, v) -> bool:
 def make_attention_fn(causal: bool, *, block_q: Optional[int] = None,
                       block_k: Optional[int] = None):
     """Adapter for ``TransformerConfig.attention_fn``: ``(q, k, v, mask,
-    dropout_rng) -> out``.  Block sizes default to the measured tuning
-    table (:func:`tuned_blocks`).
+    dropout_rng) -> out``.  Block sizes default to :data:`DEFAULT_BLOCK`.
 
     The flash kernel supports exactly two masking structures: none, and
     the static causal triangle.  With ``causal=True`` the mask the model

@@ -129,20 +129,13 @@ class HandoffPlan:
 
 def check_handoff_block(engine, name: str = "engine") -> None:
     """Refuse, by name, an engine whose slots own other than pool
-    blocks: the handoff copies a request's blocks of keys and values, and
-    a stack with linear layers also keeps a recurrent state a slot that
-    no block holds; a latent-attention stack caches rows, in lanes."""
-    if getattr(engine, "linear_layers", 0):
-        raise ValueError(
-            f"{name}: the disaggregated handoff copies blocks of keys "
-            f"and values; the block's {engine.linear_layers} linear "
-            "layers keep a recurrent state a slot, which it would leave "
-            "behind")
-    if getattr(engine, "latent_layers", 0):
-        raise ValueError(
-            f"{name}: the disaggregated handoff copies blocks of keys "
-            "and values a head; this block caches a latent KV row a "
-            "position (latent attention), which no block holds")
+    blocks of keys and values a head: the handoff copies a request's
+    blocks, and what else a cache layout holds for a slot — a recurrent
+    state, latent rows, lanes — it would leave behind.  The layout says
+    whether it serves the handoff, and why not
+    (``serving/kv_cache.py``)."""
+    if "handoff" not in engine.kv.serves:
+        raise ValueError(engine.kv.refusal("handoff", name))
 
 
 class HandoffError(RuntimeError):
@@ -259,10 +252,6 @@ class DisaggServer:
         shapes = set()
         for pname, eng in self.prefill_pool + self.decode_pool:
             check_handoff_block(eng, pname)
-            if eng.kv_layout != "paged":
-                raise ValueError(
-                    f"{pname}: the handoff rides the block table — "
-                    "disaggregated pools require kv_layout='paged'")
             if getattr(eng, "speculative", None) is not None:
                 raise ValueError(
                     f"{pname}: speculative decoding is not supported "

@@ -673,11 +673,30 @@ def paged_cached_attention(q, k_pool, v_pool, lengths, block_table, *,
 # --------------------------------------------------------------------------- #
 # The seam: how the engine meets a layout
 # --------------------------------------------------------------------------- #
-# ``ServingEngine`` picks one of the three classes below once, from
-# ``kv_layout`` and the block: its programs call the traced methods where
-# a layer writes or reads the cache, its host API delegates the
-# accounting (a method that can change the table takes the live cache
-# and hands it back).  A new format is one more class, in this module alone.
+# :func:`layout_for` picks one of the three classes below once, from the
+# block and ``kv_layout``, and elects the decode-attention kernel: the
+# engine's programs call the traced methods where a layer writes or reads
+# the cache, its host API delegates the accounting (a method that can
+# change the table takes the live cache and hands it back).  A layout
+# says what it ``serves`` of :data:`FEATURES`, and why not the rest
+# (``refusal``): the engine's options and the disaggregated handoff ask
+# it.  A new format is one more class, in this module alone.
+
+# What rides the block table, by the knob that asks for it: what a
+# refusal calls it, and what it does with the table.
+FEATURES = {
+    "prefill_chunk": ("chunked prefill",
+                      "writes prompt chunks through the block table"),
+    "speculative": ("speculative verify",
+                    "attends a window through the block table's readers"),
+    "prefix_caching": ("prefix caching", "shares physical pool blocks"),
+    "handoff": ("the disaggregated handoff",
+                "copies a request's blocks of keys and values"),
+}
+# what a linear mixer's rule is called where a refusal names it
+_RULES = {"delta": "delta-rule", "retention": "power-retention"}
+
+
 def _both(write, kc, vc, layer, k, v, *a, **kw):
     """``write`` the keys into ``kc`` and the values into ``vc``."""
     return write(kc, layer, k, *a, **kw), write(vc, layer, v, *a, **kw)
@@ -688,9 +707,9 @@ class DenseLayout:
     pool to account for: the host methods are constants that touch no
     device, and admission gates on slots alone.  ``fused_block``: the
     block with which the fused decode kernel reads the cache in place
-    and writes the step's rows itself — the engine's election, made
-    where backend, ``max_len`` and ``head_dim`` can be observed; without
-    one, :func:`write_token` then :func:`cached_attention`.
+    and writes the step's rows itself — :func:`layout_for`'s election
+    (``flash_decode.dense_decode_elected``); without one,
+    :func:`write_token` then :func:`cached_attention`.
 
     ``dims``: ``(caching layers, slots, key/value heads, head_dim,
     max_len)``.  ``recurrent``: ``(linear layers, LinearMixerSpec)`` of
@@ -703,9 +722,23 @@ class DenseLayout:
     a slot's memory does not depend on its length, ``max_len`` bounds
     positions only and no decode-attention kernel is elected."""
 
+    arrays = 2          # what a position holds a head: a key and a value
+    # why the block's cache is lanes whatever ``kv_layout`` was asked,
+    # each ``(what it is, what stands in the way)``: :func:`layout_for`
+    # says
+    unpaged = ()
+
+    @property
+    def serves(self) -> frozenset:
+        """Lanes of keys and values a head serve the verify window, and
+        its rollback behind the lengths; lanes that could not be paged
+        (:attr:`unpaged`) serve nothing of :data:`FEATURES`."""
+        return frozenset() if self.unpaged else frozenset({"speculative"})
+
     def __init__(self, dims, kernel, *, fused_block=None, recurrent=None):
         from autodist_tpu.kernel.pallas.flash_decode import rows_layout
 
+        self.dims = dims
         self.cache_layers, num_slots, _, head_dim, self.max_len = dims
         self.kernel = kernel        # the engine's elections, by name
         self.fused_block = fused_block
@@ -727,6 +760,32 @@ class DenseLayout:
                                      cache.lengths.shape[0],
                                      self.recurrent[1], dtype)
         return cache
+
+    def gauges(self, dtype) -> dict:
+        """What the layout holds, by the gauge that says it: the bytes a
+        token takes, and of a recurrent state the bytes a slot and all
+        slots take and the rows a head holds as they are laid out."""
+        held = bytes_held(self.dims, dtype, self.recurrent, self.arrays)
+        out = {"engine/kv_bytes_per_token": held["kv_bytes_per_token"]}
+        if self.recurrent is not None:
+            out["engine/state_bytes_per_slot"] = held["state_bytes_per_slot"]
+            out["kv/state_bytes"] = \
+                held["state_bytes_per_slot"] * self.dims[1]
+            out["kv/state_rows"] = self.recurrent[1].state_rows
+        return out
+
+    def refusal(self, feature: str, subject: str) -> str:
+        """Why ``feature`` (of :data:`FEATURES`; any other name is what
+        the refusal calls it) is not served, for the ``ValueError`` of
+        whoever was asked for it (``subject``: the knob, or the
+        handoff's pool)."""
+        what, uses = FEATURES.get(feature, (feature, None))
+        if not self.unpaged:
+            return (f"{subject}: {what} {uses} — it requires "
+                    "kv_layout='paged'")
+        kinds, why = zip(*self.unpaged)
+        return (f"{subject}: {what} {' and '.join(kinds)} is not served "
+                f"— {'; '.join(why)}")
 
     # ---- traced ------------------------------------------------------ #
     def write_prompt(self, kc, vc, layer, k, v, slot, table_row, p_len,
@@ -759,13 +818,6 @@ class DenseLayout:
                 out, kc, vc = flash_decode_attention_dense(
                     q, kc, vc, layer, lengths, new_kv=(k, v),
                     active=active, dtype=dtype, block_k=fused)
-            elif self.kernel.get("flash_decode"):
-                # forced on a shape the kernel's view of the cache would
-                # copy whole: a copy of this layer's lanes instead
-                from autodist_tpu.kernel.pallas.flash_decode import \
-                    flash_decode_attention
-                out = flash_decode_attention(q, kc[layer], vc[layer],
-                                             lengths, dtype=dtype)
             else:
                 out = cached_attention(q, kc[layer], vc[layer], lengths,
                                        dtype=dtype)
@@ -880,16 +932,38 @@ class LatentLayout(DenseLayout):
     are multiplied by (``BlockSpec.latent_softmax_scale``);
     ``fused_block``: the block with which the latent decode kernel reads
     the cache in place, a slot's live blocks once for scores and
-    weighted sum alike, and writes the step's row itself — the engine's
-    election, as a dense lane's.  ``recurrent``: as a dense lane's — the
+    weighted sum alike, and writes the step's row itself — elected as a
+    dense lane's (``flash_decode.latent_decode_elected``).
+    ``recurrent``: as a dense lane's — the
     stack's other layers are linear ones, and the manager holds their
     :class:`RecurrentState` beside the latent layers' rows."""
+
+    arrays = 1          # the one row
+    serves = frozenset()    # no reader takes a window of rows
 
     def __init__(self, dims, kernel, *, kv_rank: int, scale: float,
                  fused_block=None, recurrent=None):
         super().__init__(dims, kernel, fused_block=fused_block,
                          recurrent=recurrent)
         self.kv_rank, self.scale = kv_rank, scale
+
+    def gauges(self, dtype) -> dict:
+        """A dense lane's, and: the positions of a lane of rows; the
+        rows' decode attention, 1 the latent kernel over the live
+        blocks, 0 the composed products over whole lanes; beside a
+        recurrent state, the two kinds of state in the one manager — how
+        many layers of each, and the bytes the rows take over all
+        slots."""
+        out = super().gauges(dtype)
+        layers, slots, _, _, max_len = self.dims
+        out["engine/latent_lane_rows"] = max_len
+        out["kernel/latent_decode_elected"] = int(bool(self.fused_block))
+        if self.recurrent is not None:
+            out["kv/latent_layers"] = layers
+            out["kv/linear_layers"] = self.recurrent[0]
+            out["kv/row_bytes"] = \
+                out["engine/kv_bytes_per_token"] * max_len * slots
+        return out
 
     def init_cache(self, dims, dtype) -> KVCache:
         layers, slots, heads, row, max_len = dims
@@ -950,9 +1024,14 @@ class PagedLayout:
     checkpointing, debug dumps — it never shows a stale mapping)."""
 
     decode_block_len = None     # nothing reads a paged lane as one
+    arrays = 2
+    recurrent = None
+    serves = frozenset(FEATURES)
+    gauges = DenseLayout.gauges     # of dims, arrays and no recurrent state
 
     def __init__(self, dims, kernel, *, block_len: int, num_blocks: int,
                  prefix_caching: bool = False):
+        self.dims = dims
         _, self.num_slots, _, _, self.max_len = dims
         self.kernel = kernel        # the engine's elections, by name
         self.block_len = block_len
@@ -1219,3 +1298,99 @@ class PagedLayout:
                 self._prefix_index[key] = blocks[j]
                 self._block_keys[blocks[j]] = key
             self._pending_register.pop(slot, None)
+
+
+# --------------------------------------------------------------------------- #
+# The one decision: which layout a block's cache takes, and its kernel
+# --------------------------------------------------------------------------- #
+def layout_for(cfg, kernel, *, num_slots: int, max_len: int,
+               kv_layout: str = "dense",
+               kv_block_len: Optional[int] = None,
+               kv_num_blocks: Optional[int] = None,
+               prefix_caching: bool = False, prefill_chunk=None,
+               speculative=None):
+    """The cache layout of an engine of ``num_slots`` slots of
+    ``max_len`` positions over ``cfg``'s stack — the class, its ``dims``,
+    the recurrent state beside it, the decode-attention kernel's block —
+    from what can be observed where it is built: the block (which layers
+    cache keys and values a head, which a latent row, which keep a
+    recurrent state), the head sizes and type, and ``kv_layout`` with
+    (paged) its pool of ``kv_num_blocks`` blocks of ``kv_block_len``.
+
+    ``kernel``: the normalised kernel words, but with ``flash_decode``
+    ``True`` (forces the decode-attention kernel), ``False`` (forbids it)
+    or absent — left to the one election a kernel, beside the kernel
+    (``flash_decode.dense_decode_elected`` / ``latent_decode_elected``;
+    zero caching layers elect nothing, and a paged pool's kernel runs on
+    ``True`` alone).  The layout's ``kernel`` is those words, canonical
+    again: ``flash_decode`` ``True`` where forced or elected.
+
+    ``prefix_caching``, ``prefill_chunk``, ``speculative``: the engine's
+    knobs of :data:`FEATURES`, as it was asked for them.  What the layout
+    does not serve of them is refused here, by name (``ValueError``), in
+    the order of :data:`FEATURES`, and after them a ``kv_layout='paged'``
+    that the block's cache cannot be."""
+    from autodist_tpu.kernel.pallas import flash_decode
+
+    spec = cfg.block
+    linear = spec.layer_kinds(cfg.num_layers).count("linear")
+    # the cache holds every pass's keys and values: a layer's input
+    # differs from pass to pass, so its projections do too (no layer at
+    # all where every one is a linear one)
+    layers = (cfg.num_layers - linear) * spec.loop_steps
+    dims = (layers, num_slots, cfg.kv_heads, cfg.head_dim, max_len)
+    recurrent = (linear, spec.linear) if linear else None
+    unpaged = []
+    if linear:
+        unpaged.append((
+            "over recurrent state",
+            f"the block's {linear} linear ({_RULES[spec.linear.rule]}) "
+            "layers keep a state a slot that cannot be rolled back, shared "
+            "by blocks or cut at a chunk's edge"))
+    if spec.latent is not None:
+        unpaged.append((
+            "with a latent KV row",
+            f"a cached position is one row of {spec.latent.row} values for "
+            f"all {cfg.num_heads} query heads, and the block table's "
+            "readers and the window attention take keys and values a head"))
+    if cfg.kv_heads != cfg.num_heads:
+        unpaged.append((
+            f"with grouped-query attention ({cfg.num_heads} query heads on "
+            f"{cfg.kv_heads} key/value heads)",
+            "the block table's readers take a key/value head a query head"))
+    word = kernel.get("flash_decode")
+    kernel = {k: v for k, v in kernel.items() if k != "flash_decode" or v}
+    if kv_layout == "paged" and not unpaged:
+        if kv_num_blocks < blocks_for(max_len, kv_block_len):
+            raise ValueError(
+                f"kv_num_blocks={kv_num_blocks} cannot hold even one "
+                f"full-length request ({blocks_for(max_len, kv_block_len)} "
+                f"blocks of {kv_block_len})")
+        return PagedLayout(dims, kernel, block_len=kv_block_len,
+                           num_blocks=kv_num_blocks,
+                           prefix_caching=prefix_caching)
+    if spec.latent is not None:
+        # one row a position, one key head: the values are its first
+        # kv_rank columns
+        row, rank = spec.latent.row, spec.latent.kv_rank
+        layout = LatentLayout(
+            (layers, num_slots, 1, row, max_len), kernel, kv_rank=rank,
+            scale=spec.latent_softmax_scale, recurrent=recurrent,
+            fused_block=flash_decode.latent_decode_elected(
+                word, max_len, row, rank, cfg.dtype))
+    else:
+        layout = DenseLayout(
+            dims, kernel, recurrent=recurrent,
+            fused_block=flash_decode.dense_decode_elected(
+                word, max_len, cfg.head_dim) if layers else None)
+    if layout.fused_block:
+        kernel["flash_decode"] = True
+    layout.unpaged = tuple(unpaged)
+    asked = dict(prefill_chunk=prefill_chunk, speculative=speculative,
+                 prefix_caching=prefix_caching)
+    for feature in FEATURES:
+        if asked.get(feature) and feature not in layout.serves:
+            raise ValueError(layout.refusal(feature, feature))
+    if kv_layout == "paged":        # which this block's cache cannot be
+        raise ValueError(layout.refusal("paged KV", "kv_layout='paged'"))
+    return layout

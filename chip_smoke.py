@@ -422,12 +422,14 @@ def kernels_phase(*, interpret: bool, seq_len: int = 512, heads: int = 12,
     q1 = rand(slots, 1, H, d)
     kd, vd = rand(slots, H, T, d), rand(slots, H, T, d)
     got, ref = jax.block_until_ready((
-        jax.jit(lambda *a: flash_decode.flash_decode_attention(
-            *a, dtype=bf16, interpret=interpret))(q1, kd, vd, lens),
+        jax.jit(lambda q, k, v, lens:
+                flash_decode.flash_decode_attention_dense(
+                    q, k[None], v[None], 0, lens, dtype=bf16,
+                    interpret=interpret))(q1, kd, vd, lens),
         jax.jit(lambda *a: kv_cache.cached_attention(
             *a, dtype=bf16))(q1, kd, vd, lens)))
-    require_close(ph, f"flash_decode_attention(T={T})", got, ref,
-                  ATTN_FWD_RTOL)
+    require_close(ph, f"flash_decode_attention_dense(T={T}, reading)", got,
+                  ref, ATTN_FWD_RTOL)
     # the same kernel as the engine calls it: the whole cache, a layer
     # index (a traced operand, as a looped stack passes it), only the
     # live blocks read (the other layer holds 1e4), the step's rows
@@ -462,7 +464,7 @@ def kernels_phase(*, interpret: bool, seq_len: int = 512, heads: int = 12,
     dense_writing(H, d)
     if d < 128:
         dense_writing(max(1, H * d // 128), 128)
-    done.append("flash_decode_attention")
+    done.append("flash_decode_attention_dense")
 
     mb = T // bl
     pool_k, pool_v = (rand(slots * mb, H, bl, d) for _ in range(2))

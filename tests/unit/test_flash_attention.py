@@ -3,15 +3,10 @@
 Runs the Pallas interpreter on the CPU harness; on TPU the same code
 compiles to the fused kernel.
 """
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))))
 
 from autodist_tpu.models.transformer import dot_product_attention
 from autodist_tpu.ops import flash_attention, make_attention_fn
@@ -148,81 +143,9 @@ def test_attention_fn_rejects_padding_mask():
         fn(q, k, v, mask, None)
 
 
-# --------------------------------------------------------------------------- #
-# Measured tuning table (tools/flash_crossover.py --write)
-# --------------------------------------------------------------------------- #
-def test_tuning_table_resolution(tmp_path, monkeypatch):
-    import json
-
-    import importlib
-    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
-
-    table = {"causal": {"crossover_len": 1024,
-                        "blocks": {"512": 128, "2048": [256, 512]}},
-             "noncausal": {"crossover_len": None,
-                           "blocks": {"1024": 256}}}
-    p = tmp_path / "flash_tuning.json"
-    p.write_text(json.dumps(table))
-    monkeypatch.setenv("AUTODIST_TPU_FLASH_TUNING", str(p))
-    fa.load_tuning(reload=True)
-    try:
-        # exact + nearest-below + nearest-above fallbacks
-        assert fa.tuned_blocks(512, True) == (128, 128)
-        assert fa.tuned_blocks(1024, True) == (128, 128)   # below: 512
-        assert fa.tuned_blocks(4096, True) == (256, 512)   # below: 2048
-        assert fa.tuned_blocks(256, True) == (128, 128)    # above: 512
-        assert fa.tuned_blocks(1024, False) == (256, 256)
-        # crossover semantics: measured-and-lost => False everywhere
-        assert fa.flash_wins(512, True) is False
-        assert fa.flash_wins(2048, True) is True
-        assert fa.flash_wins(99999, False) is False        # null crossover
-    finally:
-        monkeypatch.delenv("AUTODIST_TPU_FLASH_TUNING")
-        fa.load_tuning(reload=True)
-
-
-def test_cpu_provenance_tuning_skipped_on_autoload(tmp_path, monkeypatch):
-    """A table written by a CPU (interpret-mode) crossover run must not
-    steer TPU kernel defaults: auto-load ignores backend=cpu tables; an
-    explicit path still loads them."""
-    import json
-
-    import importlib
-    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
-
-    table = {"causal": {"blocks": {"512": 512}}, "backend": "cpu"}
-    p = tmp_path / "flash_tuning.json"
-    p.write_text(json.dumps(table))
-    monkeypatch.setenv("AUTODIST_TPU_FLASH_TUNING", str(p))
-    fa.load_tuning(reload=True)
-    try:
-        assert fa.tuned_blocks(512, True) == (fa.DEFAULT_BLOCK,
-                                              fa.DEFAULT_BLOCK)
-        assert fa.load_tuning(str(p))["causal"]["blocks"]["512"] == 512
-    finally:
-        monkeypatch.delenv("AUTODIST_TPU_FLASH_TUNING")
-        fa.load_tuning(reload=True)
-
-
-def test_tuning_absent_defaults(monkeypatch, tmp_path):
-    import importlib
-    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
-
-    monkeypatch.setenv("AUTODIST_TPU_FLASH_TUNING",
-                       str(tmp_path / "missing.json"))
-    fa.load_tuning(reload=True)
-    try:
-        assert fa.tuned_blocks(512, True) == (fa.DEFAULT_BLOCK,
-                                              fa.DEFAULT_BLOCK)
-        assert fa.flash_wins(512, True) is None
-    finally:
-        monkeypatch.delenv("AUTODIST_TPU_FLASH_TUNING")
-        fa.load_tuning(reload=True)
-
-
 def test_flash_attention_default_blocks_run():
-    """block_q/block_k=None resolve through the table (or defaults) and
-    the kernel still matches the reference einsum."""
+    """block_q/block_k=None resolve to DEFAULT_BLOCK and the kernel
+    still matches the reference einsum."""
     import numpy as np
 
     from autodist_tpu.ops.flash_attention import flash_attention
@@ -238,93 +161,6 @@ def test_flash_attention_default_blocks_run():
     ref = jnp.einsum("bhlm,bmhd->blhd", p, v)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                rtol=2e-4, atol=2e-5)
-
-
-def test_crossover_tool_write_merges(tmp_path):
-    """--write merges per-branch without clobbering the other branch."""
-    import json
-    import subprocess
-    import sys
-
-    out = tmp_path / "flash_tuning.json"
-    # backend stamp matches the run below: same-provenance tables merge
-    # (unstamped/cross-backend ones are discarded — separate test below)
-    out.write_text(json.dumps(
-        {"causal": {"crossover_len": 777, "blocks": {"512": 64}},
-         "noncausal": {"blocks": {"999": 32}, "speedup": {"999": 2.0}},
-         "backend": "cpu"}))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "tools/flash_crossover.py", "--seqs", "128",
-         "--heads", "2", "--head-dim", "16", "--tokens", "256",
-         "--blocks", "64", "--steps", "1", "--write", str(out)],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True,
-        timeout=300)
-    assert proc.returncode == 0, proc.stderr
-    table = json.loads(out.read_text())
-    # other branch untouched; same branch merged PER LENGTH
-    assert table["causal"] == {"crossover_len": 777, "blocks": {"512": 64}}
-    nb = table["noncausal"]
-    assert nb["blocks"]["999"] == 32, "prior length must be preserved"
-    assert "128" in nb["blocks"] and "128" in nb["speedup"]
-    # crossover derived from per-length speedups (999 won at 2.0)
-    assert nb["crossover_len"] in (128, 999)
-    assert table["backend"] == "cpu", "written table must carry provenance"
-
-
-def test_crossover_tool_write_discards_unstamped(tmp_path):
-    """A tuning table without a backend stamp (or from another backend)
-    has unknown provenance: --write starts fresh instead of merging, so
-    stale entries can't masquerade under this run's stamp."""
-    import json
-    import subprocess
-    import sys
-
-    out = tmp_path / "flash_tuning.json"
-    out.write_text(json.dumps(
-        {"causal": {"crossover_len": 777, "blocks": {"512": 64}}}))
-    env = dict(os.environ, JAX_PLATFORMS="cpu")
-    proc = subprocess.run(
-        [sys.executable, "tools/flash_crossover.py", "--seqs", "128",
-         "--heads", "2", "--head-dim", "16", "--tokens", "256",
-         "--blocks", "64", "--steps", "1", "--write", str(out)],
-        cwd=REPO_ROOT, env=env, capture_output=True, text=True,
-        timeout=300)
-    assert proc.returncode == 0, proc.stderr
-    table = json.loads(out.read_text())
-    assert "causal" not in table, "unstamped table must be discarded"
-    assert "128" in table["noncausal"]["blocks"]
-
-
-def test_flash_wins_prefers_per_length_speedups(tmp_path, monkeypatch):
-    """The per-length speedup records (what --write persists) drive
-    flash_wins by nearest measured length; a corrupt table degrades to
-    'unmeasured', never a crash."""
-    import importlib
-    import json
-
-    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
-    p = tmp_path / "t.json"
-    p.write_text(json.dumps({"noncausal": {
-        "speedup": {"512": 0.88, "2048": 1.4},
-        "blocks": {"512": 128, "2048": 256},
-        "crossover_len": 2048}}))
-    monkeypatch.setenv("AUTODIST_TPU_FLASH_TUNING", str(p))
-    fa.load_tuning(reload=True)
-    try:
-        assert fa.flash_wins(512, False) is False
-        assert fa.flash_wins(1024, False) is False   # nearest below: 512
-        assert fa.flash_wins(2048, False) is True
-        assert fa.flash_wins(8192, False) is True
-        # corrupt table: wrong types everywhere -> graceful defaults
-        p.write_text(json.dumps(["not", "a", "dict"]))
-        fa.load_tuning(reload=True)
-        assert fa.flash_wins(512, False) is None
-        assert fa.tuned_blocks(512, False) == (fa.DEFAULT_BLOCK,
-                                               fa.DEFAULT_BLOCK)
-    finally:
-        monkeypatch.delenv("AUTODIST_TPU_FLASH_TUNING")
-        fa.load_tuning(reload=True)
 
 
 def test_flash_bf16_inputs_match_einsum_reference():

@@ -238,9 +238,9 @@ def test_engine_forced_kernel_on_the_whole_cache_greedy_parity(max_len, tp):
 def test_engine_grouped_heads_through_the_kernel_greedy_parity(kv_heads,
                                                                max_len):
     """A plain decoder of four query heads on two key/value heads and on
-    one, forced through the kernel on the whole cache (and, a lane no
-    block reads in place, on a layer's slice): token for token the same
-    engine on ``cached_attention``, across a block's edge."""
+    one, forced through the kernel on the whole cache (a lane no block
+    reads in place as one block: on a TPU, a copy): token for token the
+    same engine on ``cached_attention``, across a block's edge."""
     from autodist_tpu.serving import ServingEngine
 
     cfg, params = _grouped(kv_heads, num_heads=4)
@@ -251,7 +251,7 @@ def test_engine_grouped_heads_through_the_kernel_greedy_parity(kv_heads,
     plain = ServingEngine(cfg, params, kernel={"flash_decode": False},
                           **kw)
     assert fused.cache.k.shape[2] == kv_heads
-    assert fused.kv.fused_block == (128 if max_len > 128 else None)
+    assert fused.kv.fused_block == min(128, max_len)
     assert plain.kv.fused_block is None
     np.testing.assert_array_equal(_serve(fused, p_lens, 2),
                                   _serve(plain, p_lens, 2))

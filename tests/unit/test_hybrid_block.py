@@ -524,14 +524,14 @@ def test_engine_options_refuse_the_block_by_name(cfg, params, kw, names):
                       **kw)
 
 
-@pytest.mark.parametrize("max_len,block", [(256, 128), (48, None)],
-                         ids=["whole-cache", "a-layers-slice"])
+@pytest.mark.parametrize("max_len,block", [(256, 128), (48, 48)],
+                         ids=["whole-cache", "a-lane-of-one-block"])
 def test_the_fused_decode_kernel_serves_the_composed_tokens(cfg, params,
                                                             max_len, block):
     """The full layers' grouped query heads through the fused decode
     kernel (forced: the interpreter) — over the whole cache in blocks of
-    128, and over a layer's slice where no block reads the lane in place
-    — serve the greedy tokens of ``cached_attention``, request for
+    128, and as one block where none reads the lane in place — serve the
+    greedy tokens of ``cached_attention``, request for
     request, beside the linear layers' state."""
     requests = _requests(n=5)
     long = dataclasses.replace(cfg, max_len=256)    # rotary: no table

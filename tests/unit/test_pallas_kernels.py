@@ -58,24 +58,31 @@ def _lm_batch(vocab, batch=8, length=8, seed=0):
 # --------------------------------------------------------------------------- #
 # Kernel goldens vs the composed lowerings (interpreter mode)
 # --------------------------------------------------------------------------- #
-@pytest.mark.parametrize("lengths,block_k", [
-    ([0, 3, 56], 16),      # slot shorter than one block + near-full
-    ([1, 15, 16], 16),     # block-boundary edges
-    ([55, 2, 30], 13),     # T=57 non-divisible by block 13
+@pytest.mark.parametrize("lengths,block_k,T", [
+    ([0, 3, 56], 16, 64),      # slot shorter than one block + near-full
+    ([1, 15, 16], 16, 64),     # block-boundary edges
+    ([55, 2, 30], 13, 57),     # a lane of 57 does not divide by 13
 ])
-def test_flash_decode_golden_vs_cached_attention(lengths, block_k):
+def test_flash_decode_golden_vs_cached_attention(lengths, block_k, T):
     from autodist_tpu.kernel.pallas.flash_decode import \
-        flash_decode_attention
+        flash_decode_attention_dense
     from autodist_tpu.serving.kv_cache import cached_attention
 
-    B, H, T, d = 3, 2, 57, 8
+    B, H, d = 3, 2, 8
     r = np.random.RandomState(0)
     q = jnp.asarray(r.randn(B, 1, H, d), jnp.float32)
     k = jnp.asarray(r.randn(B, H, T, d), jnp.float32)
     v = jnp.asarray(r.randn(B, H, T, d), jnp.float32)
     lens = jnp.asarray(lengths, jnp.int32)
+    if T % block_k:
+        # the dense entry takes the cache as it is: no padded copy
+        with pytest.raises(ValueError, match="does not divide into blocks"):
+            flash_decode_attention_dense(q, k[None], v[None], 0, lens,
+                                         block_k=block_k)
+        return
     ref = cached_attention(q, k, v, lens)
-    got = flash_decode_attention(q, k, v, lens, block_k=block_k)
+    got = flash_decode_attention_dense(q, k[None], v[None], 0, lens,
+                                       block_k=block_k)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                atol=1e-5, rtol=1e-5)
 

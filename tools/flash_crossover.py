@@ -106,11 +106,6 @@ def main():
                     help="flash block sizes to try (best reported; "
                          "--decode: cache-block lengths, each reported)")
     ap.add_argument("--steps", type=int, default=10)
-    ap.add_argument("--write", default="",
-                    help="merge results into this flash_tuning.json "
-                         "(per-length best blocks + crossover_len; the "
-                         "kernel's default blocks and the flash_wins() "
-                         "helper read it — commit it at the repo root)")
     ap.add_argument("--cell", action="store_true",
                     help="the training cell's attention shape (64 x 512 "
                          "x 12 x 64 unless --tokens/--seqs/--heads/"
@@ -189,7 +184,6 @@ def main():
     H, D = args.heads, args.head_dim
     causal = bool(args.causal)
     records = []
-    wrote = False
     for L in [int(s) for s in args.seqs.split(",")]:
         B = max(args.tokens // L, 1)
         r = np.random.RandomState(0)
@@ -233,10 +227,6 @@ def main():
         }
         records.append(rec)
         print(json.dumps(rec), flush=True)
-        if args.write:
-            # Merge-write after EVERY length, not once at the end: a
-            # timeout mid-run must not lose the points already measured.
-            wrote = _merge_write(records, args.write, causal) or wrote
     wins = [r for r in records if (r["flash_speedup"] or 0) > 1.0]
     print(json.dumps({
         "summary": "flash wins from seq "
@@ -245,9 +235,6 @@ def main():
         "backend": jax.default_backend(),
         "device_kind": jax.devices()[0].device_kind,
     }))
-    if args.write and not wrote:
-        print("# no successful flash timing; tuning table unchanged",
-              file=sys.stderr)
 
 
 def _main_cell(args):
@@ -598,62 +585,6 @@ def _main_prefill(args):
         os.replace(tmp, args.write_calibration)
         print(f"# wrote kernel section to {args.write_calibration}",
               file=sys.stderr)
-
-
-def _merge_write(records, path, causal) -> bool:
-    """Merge measured points into the tuning table the kernel reads, PER
-    LENGTH: previously measured lengths (and the other causal-ness
-    branch) are preserved; lengths where flash failed to run write
-    nothing — a measurement failure must stay distinguishable from
-    "flash measured and lost" (flash_wins derives the verdict from the
-    per-length speedup records at read time)."""
-    ok = [r for r in records
-          if r["flash_block"] and r["flash_speedup"] is not None]
-    if not ok:
-        return False
-    table = {}
-    if os.path.exists(path):
-        try:
-            with open(path) as f:
-                loaded = json.load(f)
-            table = loaded if isinstance(loaded, dict) else {}
-        except (OSError, ValueError):
-            table = {}
-    if table and table.get("backend") != jax.default_backend():
-        # Cross-backend merge would mislabel stale entries under this
-        # run's provenance stamp (or discard this run's via the old
-        # stamp) — measurements from different backends don't compose;
-        # start a fresh table.  Unstamped legacy tables have unknown
-        # provenance: same treatment.
-        print(f"# discarding {path} measured on "
-              f"{table.get('backend')!r} (this run: "
-              f"{jax.default_backend()!r})", file=sys.stderr)
-        table = {}
-    key = "causal" if causal else "noncausal"
-    branch = table.get(key)
-    branch = dict(branch) if isinstance(branch, dict) else {}
-    blocks = branch.get("blocks")
-    blocks = dict(blocks) if isinstance(blocks, dict) else {}
-    speedup = branch.get("speedup")
-    speedup = dict(speedup) if isinstance(speedup, dict) else {}
-    for r in ok:
-        blocks[str(r["seq"])] = r["flash_block"]
-        speedup[str(r["seq"])] = r["flash_speedup"]
-    branch["blocks"] = blocks
-    branch["speedup"] = speedup
-    measured_wins = sorted(int(k) for k, v in speedup.items() if v > 1.0)
-    branch["crossover_len"] = measured_wins[0] if measured_wins else None
-    table[key] = branch
-    table["device_kind"] = jax.devices()[0].device_kind
-    # Provenance: load_tuning refuses to auto-load CPU-measured tables
-    # (interpret-mode timings would mislead TPU defaults).
-    table["backend"] = jax.default_backend()
-    tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(table, f, indent=1)
-    os.replace(tmp, path)   # a mid-write kill must not corrupt the table
-    print(f"# wrote {path}", file=sys.stderr)
-    return True
 
 
 if __name__ == "__main__":
