@@ -50,6 +50,11 @@ flash-crossover measurements say it is shape-dependent too:
   ``[offsets, 128, 128]`` tiles a key/value head, a slab of offsets a
   grid step, the group's read-outs summed across the steps; elected
   where it is called too.
+* :func:`~autodist_tpu.kernel.pallas.ssd_step.ssd_step_fused` — one
+  position of a Mamba-2 state-space layer over the same manager's state,
+  a group's heads ONE ``[N, heads * P]`` matrix (the key and the query
+  shared, the decay a row), read once and written back in place; elected
+  where it is called too.
 * :func:`~autodist_tpu.kernel.pallas.grouped_matmul.grouped_matmul` — a
   decode step's sorted (row, expert) pairs through the held experts that
   have rows, gate/up, SiLU and down in one call, each expert's weights
@@ -70,7 +75,8 @@ from __future__ import annotations
 # normalize_kernel re-exports this; kernel code stays IR-agnostic).
 KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                   "collective_matmul", "a2a_ring", "flash_attention",
-                  "delta_step", "grouped_matmul", "retention_step")
+                  "delta_step", "grouped_matmul", "retention_step",
+                  "ssd_step")
 
 # Kernels that change the *training* program (the pipeline and expert
 # lowerings honor them); flash_decode/flash_prefill are serving-side
@@ -80,7 +86,7 @@ TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
 
 # Kernels elected where they are called, from what the call observes
 # (``models.transformer.attend``; ``serving.kv_cache.DenseLayout
-# .advance_state`` and ``.advance_retention``;
+# .advance_state``, ``.advance_retention`` and ``.advance_ssd``;
 # ``parallel.moe.routed_experts``).  The kernel slot says nothing about them unless
 # someone overrides: ``True`` takes the kernel wherever it can run,
 # ``False`` forbids it (the composed path, for a comparison) — the one
@@ -90,7 +96,7 @@ TRAINING_KERNELS = ("quant_ring", "collective_matmul", "a2a_ring",
 # layout from the engine that builds it, and the routed layer through
 # the engine's ``_ffn``.
 OBSERVED_KERNELS = ("flash_attention", "delta_step", "grouped_matmul",
-                    "retention_step")
+                    "retention_step", "ssd_step")
 
 # Op-metadata marker prefix: `with jax.named_scope(kernel_marker(name))`
 # around a pallas_call stamps every emitted op's `op_name` metadata, and

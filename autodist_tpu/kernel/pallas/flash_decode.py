@@ -218,6 +218,12 @@ def dense_decode_elected(word, max_len: int, head_dim: int,
     return None
 
 
+def softmax_scale(d: int, scale=None) -> float:
+    """What a kernel multiplies its float32 scores by: ``scale`` (the
+    block's ``softmax_scale``) where given, else ``d ** -0.5``."""
+    return 1.0 / float(np.sqrt(d)) if scale is None else float(scale)
+
+
 def _heads_per_step(heads: int, block_len: int, d: int, itemsize: int):
     """Most heads of a slot (a divisor of ``heads``) whose K block fits
     :data:`KV_BLOCK_BYTES` of VMEM (the minor dimension pads to the 128
@@ -404,10 +410,10 @@ def _dense_decode_kernel(len_ref, wpos_ref, layer_ref, q_ref, *refs,
 
 
 @functools.partial(jax.jit, static_argnames=(
-    "block_len", "heads_per_step", "dtype", "interpret", "rows"))
+    "block_len", "heads_per_step", "dtype", "interpret", "rows", "scale"))
 def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
                        *, block_len: int, heads_per_step: int, dtype,
-                       interpret: bool, rows: bool = False):
+                       interpret: bool, rows: bool = False, scale=None):
     """The one inner function every layer's call goes through: ``layer``
     is an operand, so a decode body of any depth lowers this kernel
     once.  ``q2``: ``[B, H, G, d]``, the ``G`` query heads of each of the
@@ -415,7 +421,8 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
     and value rows, one a key/value head) or ``None``; the caches whole,
     as ``[L, B, H, d, T]`` (with ``rows`` as ``[L, B, H, T, d]``).
     Returns the attention output ``[B, H, G, d]``, and the two caches
-    after it when ``new_kv`` was written."""
+    after it when ``new_kv`` was written.  ``scale``:
+    :func:`softmax_scale`'s."""
     if rows:
         _, B, H, T, d = kt_cache.shape
     else:
@@ -452,7 +459,7 @@ def flash_decode_layer(lengths, wpos, layer, q2, new_kv, kt_cache, vt_cache,
     )
     kern = functools.partial(
         _dense_decode_kernel, block_len=bk, num_blocks=T // bk,
-        scale=1.0 / float(np.sqrt(d)), write=write, rows=rows)
+        scale=softmax_scale(d, scale), write=write, rows=rows)
     with jax.named_scope(kernel_marker("flash_decode")):
         res = pl.pallas_call(
             kern,
@@ -473,7 +480,8 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
                                  dtype=jnp.float32,
                                  block_k: Optional[int] = None,
                                  heads_per_step: Optional[int] = None,
-                                 interpret: Optional[bool] = None):
+                                 interpret: Optional[bool] = None,
+                                 scale: Optional[float] = None):
     """Fused :func:`autodist_tpu.serving.kv_cache.cached_attention` over
     layer ``layer`` of the whole dense cache, reading only each slot's
     live blocks — and, given ``new_kv``, the step's
@@ -502,7 +510,8 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
     is a copy of what it is given (:func:`fused_decode_block` says
     which).  ``T`` must divide into
     blocks (:func:`decode_block_len`); ``heads_per_step`` defaults to as
-    many key/value heads of a slot as fit :data:`KV_BLOCK_BYTES`.
+    many key/value heads of a slot as fit :data:`KV_BLOCK_BYTES`;
+    ``scale`` is :func:`softmax_scale`'s.
     """
     _, _, H, T, d = k_cache.shape
     B, _, heads, _ = q.shape
@@ -535,7 +544,8 @@ def flash_decode_attention_dense(q, k_cache, v_cache, layer, lengths, *,
         q.reshape(B, H, heads // H, d), new_kv, view(k_cache),
         view(v_cache),
         block_len=bk, heads_per_step=hb, dtype=jnp.dtype(dtype),
-        interpret=interp, rows=rows)
+        interpret=interp, rows=rows,
+        scale=None if scale is None else float(scale))
     if new_kv is None:
         return res.reshape(q.shape)                # [B, 1, heads, d]
     out, kt, vt = res
@@ -842,7 +852,8 @@ def _paged_decode_kernel(len_ref, tab_ref, q_ref, k_ref, v_ref, o_ref,
 
 def flash_decode_attention_paged(q, k_pool, v_pool, lengths, block_table,
                                  *, block_len: int, dtype=jnp.float32,
-                                 interpret: Optional[bool] = None):
+                                 interpret: Optional[bool] = None,
+                                 scale: Optional[float] = None):
     """Drop-in fused replacement for :func:`autodist_tpu.serving.
     kv_cache.paged_cached_attention` — the paged-cache flash decode.
 
@@ -864,7 +875,7 @@ def flash_decode_attention_paged(q, k_pool, v_pool, lengths, block_table,
     B, _, H, d = q.shape
     mb = block_table.shape[1]
     interp = default_interpret() if interpret is None else bool(interpret)
-    scale = 1.0 / float(np.sqrt(d))
+    scale = softmax_scale(d, scale)
 
     q2 = jnp.swapaxes(q, 1, 2)                 # [B, H, 1, d]
     tab = block_table.astype(jnp.int32)

@@ -36,13 +36,13 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from autodist_tpu.kernel.pallas import default_interpret, kernel_marker
 from autodist_tpu.kernel.pallas.flash_decode import (carry_scratch,
-                                                     online_softmax_step)
+                                                     online_softmax_step,
+                                                     softmax_scale)
 
 
 def _paged_prefill_kernel(start_ref, tab_ref, q_ref, k_ref, v_ref,
@@ -61,7 +61,8 @@ def _paged_prefill_kernel(start_ref, tab_ref, q_ref, k_ref, v_ref,
 
 def flash_prefill_attention_paged(q, k_pool, v_pool, starts, block_table,
                                   *, block_len: int, dtype=jnp.float32,
-                                  interpret: Optional[bool] = None):
+                                  interpret: Optional[bool] = None,
+                                  scale: Optional[float] = None):
     """Drop-in fused replacement for :func:`autodist_tpu.serving.
     kv_cache.paged_chunk_attention` — the paged-cache flash prefill.
 
@@ -78,7 +79,7 @@ def flash_prefill_attention_paged(q, k_pool, v_pool, starts, block_table,
     B, C, H, d = q.shape
     mb = block_table.shape[1]
     interp = default_interpret() if interpret is None else bool(interpret)
-    scale = 1.0 / float(np.sqrt(d))
+    scale = softmax_scale(d, scale)
 
     q2 = jnp.swapaxes(q, 1, 2)                 # [B, H, C, d]
     tab = block_table.astype(jnp.int32)

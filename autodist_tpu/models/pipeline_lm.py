@@ -175,7 +175,11 @@ def attention_inputs(cfg: TransformerConfig, chunk, x, positions,
 
 def _residual(cfg, x, y, chunk, after):
     """The residual add around a sub-block's output ``y`` and the norm
-    the placement puts after it (``chunk[after]``; none under ``pre``)."""
+    the placement puts after it (``chunk[after]``; none under ``pre``);
+    ``y`` times the block's ``residual_multiplier`` first, where it has
+    one."""
+    if cfg.block.residual_multiplier != 1.0:
+        y = y * cfg.block.residual_multiplier
     if cfg.block.norm_placement == "pre":
         return x + y.astype(x.dtype)
     if cfg.block.norm_placement == "sandwich":
@@ -331,17 +335,46 @@ def _l2_normalise(x, eps=1e-6):
     return x * jax.lax.rsqrt((x * x).sum(-1, keepdims=True) + eps)
 
 
-def causal_conv(x, taps, tail):
+def causal_conv(x, taps, tail, bias=None):
     """Depthwise causal convolution and SiLU of ``x`` ``[B, S, C]`` with
     ``taps`` ``[T, C]`` (the last multiplies the current position), the
-    ``T - 1`` inputs before ``x`` given as ``tail`` ``[B, T - 1, C]``.
-    Returns the activations and the window ``[B, T - 1 + S, C]`` the
-    next tail is cut from.  Sums in float32."""
+    ``T - 1`` inputs before ``x`` given as ``tail`` ``[B, T - 1, C]``,
+    and ``bias`` ``[C]`` added before the SiLU where the convolution has
+    one.  Returns the activations and the window ``[B, T - 1 + S, C]``
+    the next tail is cut from.  Sums in float32."""
     S, T = x.shape[1], taps.shape[0]
     window = jnp.concatenate([tail.astype(x.dtype), x], axis=1)
     out = sum(window[:, j:j + S].astype(jnp.float32)
               * taps[j].astype(jnp.float32) for j in range(T))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     return jax.nn.silu(out).astype(x.dtype), window
+
+
+def conv_tail(window, taps: int, length=None):
+    """The ``taps`` inputs the next window's convolution still needs, cut
+    from :func:`causal_conv`'s ``window``: its last rows, or — ``length``
+    ``[B]``, the rows' real positions — those before position ``length``
+    (a row's padding lies behind them)."""
+    if length is None:
+        return window[:, -taps:]
+    return jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
+        w, n, taps, axis=0))(window, length)
+
+
+def causal_conv_step(x, taps, tail, bias=None):
+    """:func:`causal_conv` of ONE position over a flat tail: ``x`` ``[B,
+    C]``, ``tail`` ``[B, (T - 1) * C]`` (the inputs before it, oldest
+    first).  Returns the activations ``[B, C]`` and the next tail.  Where
+    ``C`` is whole lanes every slice here is lane-aligned: nothing is
+    re-laid out."""
+    T, C = taps.shape[0], x.shape[-1]
+    window = jnp.concatenate([tail.astype(x.dtype), x], axis=-1)
+    out = sum(window[:, j * C:(j + 1) * C].astype(jnp.float32)
+              * taps[j].astype(jnp.float32) for j in range(T))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
+    return jax.nn.silu(out).astype(x.dtype), window[:, C:]
 
 
 def _unit_lower_inverse(m, base: int = 8):
@@ -582,12 +615,7 @@ def linear_attention(cfg: TransformerConfig, chunk, x, state, *,
             if valid is not None:
                 beta, g = beta * valid[..., None], g * valid[..., None]
         qkv, window = causal_conv(qkv, la["conv"]["kernel"], tail)
-        taps = lin.conv_taps - 1
-        if length is None:
-            tail = window[:, -taps:]
-        else:
-            tail = jax.vmap(lambda w, n: jax.lax.dynamic_slice_in_dim(
-                w, n, taps, axis=0))(window, length)
+        tail = conv_tail(window, lin.conv_taps - 1, length)
         q, k, v = jnp.split(f32(qkv), [kh * dk, 2 * kh * dk], axis=-1)
         q = _l2_normalise(q.reshape(B, S, kh, dk)) * dk ** -0.5
         k = _l2_normalise(k.reshape(B, S, kh, dk))
@@ -755,7 +783,7 @@ def retention_attention(cfg: TransformerConfig, chunk, x, state, positions,
     with scope("linear_attention"):
         g = jax.nn.log_sigmoid(jnp.matmul(
             f32(h), f32(la["gate"]["kernel"]), precision=_HI))
-        q, k, v = f32(q) * cfg.head_dim ** -0.5, f32(k), f32(v)
+        q, k, v = f32(q) * cfg.softmax_scale, f32(k), f32(v)
         if valid is not None:       # a padded position: no decay, no write
             g = g * valid[..., None]
             k = k * valid[..., None, None]
@@ -770,19 +798,195 @@ def retention_attention(cfg: TransformerConfig, chunk, x, state, positions,
     return x, state
 
 
+# --------------------------------------------------------------------- #
+# the linear mixer: a Mamba-2 state-space layer (SSD)
+# --------------------------------------------------------------------- #
+# heads of P values; a GROUP of heads shares one B and one C of N (the
+# state size); a head and position has one step Delta = softplus(dt +
+# dt_bias) and one decay a = exp(Delta A), A = -exp(A_log), float32:
+#     S_t[h] = a_t[h] S_{t-1}[h] + (Delta_t[h] x_t[h]) (x) B_t    [P x N]
+#     y_t[h] = S_t[h] C_t + D[h] x_t[h]            (decay, write, READ)
+# A group's heads are kept as ONE matrix [N, heads a group * P]: B down
+# the rows, each head's P values side by side along the columns, the
+# decay a row vector constant over a head's P columns — an update is
+# ``S * a_row + B_col * (Delta x)_row`` and a read a sum down the rows.
+SSD_CHUNK = 256        # positions a chunk of the chunked form spans
+
+
+def ssd_step(x, Bm, Cm, g, dt, state):
+    """One position of the recurrence.  ``x`` ``[B, heads, P]``; ``Bm``,
+    ``Cm`` ``[B, groups, N]``; ``g`` (log decay ``Delta A``, <= 0) and
+    ``dt`` (``Delta``) ``[B, heads]``; ``state`` ``[B, groups, N, heads a
+    group * P]``; all float32.  Returns ``(y [B, heads, P], state)``
+    without the skip: the state decayed, written to and THEN read.
+    Elementwise, not matmuls (a float32 product on the MXU at default
+    precision would round the state), and ``g == 0`` with ``dt == 0``
+    leaves the state bit for bit."""
+    with scope("state_update"):
+        Bsz, heads, P = x.shape
+        G = Bm.shape[1]
+        rows = lambda t: t.reshape(Bsz, G, 1, -1)        # [B, G, 1, W]
+        decay = rows(jnp.repeat(jnp.exp(g), P, axis=-1))
+        dx = rows(dt[..., None] * x)
+        state = state * decay + Bm[..., None] * dx
+        y = (state * Cm[..., None]).sum(-2)              # [B, G, W]
+        return y.reshape(Bsz, heads, P), state
+
+
+def ssd_chunked(x, Bm, Cm, g, dt, state, chunk: int = SSD_CHUNK):
+    """The recurrence over a window, ``chunk`` positions at a time
+    (Mamba-2's SSD).  ``x`` ``[B, T, heads, P]``; ``Bm``, ``Cm`` ``[B, T,
+    groups, N]``; ``g``, ``dt`` ``[B, T, heads]``; ``state`` as
+    :func:`ssd_step`'s, or ``None``: no inputs yet, the window starts at
+    position 0.  Returns ``(y [B, T, heads, P], state after position T -
+    1)``, ``y`` without the skip.  A position with ``g == 0`` and ``dt ==
+    0`` (a padded position; the window is padded so to whole chunks)
+    leaves the state as it was and weighs nothing at any later position.
+
+    Inside a chunk the attention form, ``(L * (C B^T)) (Delta x)`` with
+    ``L_ts = exp(sum_{s < r <= t} g_r)`` (the sums' difference taken
+    before the ``exp``, nothing above the diagonal); each chunk's closing
+    state from its own positions; the carry from chunk to chunk, read by
+    the next chunk's positions through their ``C``.  float32 at
+    ``highest`` throughout."""
+    Bsz, T, heads, P = x.shape
+    G, N, C = Bm.shape[2], Bm.shape[3], min(chunk, T)
+    hg = heads // G
+    pad = -T % C
+    if pad:
+        x, Bm, Cm, g, dt = (jnp.pad(t, [(0, 0), (0, pad)]
+                                    + [(0, 0)] * (t.ndim - 2))
+                            for t in (x, Bm, Cm, g, dt))
+    n = (T + pad) // C
+    mm = lambda eq, a, b: jnp.einsum(eq, a, b, precision=_HI)
+    # [B, n, C, G, hg, ..]: a chunk's positions, a group's heads
+    cut = lambda t, *last: t.reshape(Bsz, n, C, G, *last)
+    dx = cut(dt[..., None] * x, hg, P)
+    Bm, Cm = cut(Bm, N), cut(Cm, N)
+    gc = jnp.cumsum(cut(g, hg), 2)                       # [B, n, C, G, hg]
+    upto = jnp.tril(jnp.ones((C, C), bool))[:, :, None, None]    # s <= t
+    diff = gc[:, :, :, None] - gc[:, :, None, :]         # [B,n,t,s,G,hg]
+    L = jnp.where(upto, jnp.exp(jnp.where(upto, diff, 0.0)), 0.0)
+    cb = mm("bntgk,bnsgk->bntsg", Cm, Bm)
+    y = mm("bntsgh,bnsghp->bntghp", cb[..., None] * L, dx)
+    last = gc[:, :, -1]                                  # [B, n, G, hg]
+    # what a chunk's own positions leave in its closing state
+    built = mm("bnsgk,bnsghp->bngkhp", Bm,
+               dx * jnp.exp(last[:, :, None] - gc)[..., None])
+    into = jnp.exp(gc)                                   # a position's decay
+    over = jnp.exp(last)                                 # a chunk's
+    if state is None:
+        state = jnp.zeros((Bsz, G, N, hg * P), jnp.float32)
+    chunks = lambda t: jnp.moveaxis(t, 1, 0)             # n first
+
+    def one_chunk(S, c):
+        C_c, into_c, built_c, over_c = c
+        S = S.reshape(Bsz, G, N, hg, P)
+        read = mm("btgk,bgkhp->btghp", C_c, S) * into_c[..., None]
+        S = S * over_c[:, :, None, :, None] + built_c
+        return S.reshape(Bsz, G, N, hg * P), read
+
+    state, read = jax.lax.scan(
+        one_chunk, state, tuple(map(chunks, (Cm, into, built, over))))
+    y = y + jnp.moveaxis(read, 0, 1)
+    return y.reshape(Bsz, n * C, heads, P)[:, :T], state
+
+
+def gated_group_norm(y, z, scale, groups: int, eps: float):
+    """``y`` ``[.., width]`` gated by ``SiLU(z)`` and THEN normed: one
+    RMSNorm over each of ``groups`` equal runs of the width, times
+    ``scale`` ``[width]``; float32."""
+    gated = (y * jax.nn.silu(z)).reshape(*y.shape[:-1], groups, -1)
+    o = gated * jax.lax.rsqrt(jnp.mean(gated * gated, -1, keepdims=True)
+                              + eps)
+    return o.reshape(y.shape) * scale
+
+
+def ssd_attention(cfg: TransformerConfig, chunk, x, state, *, valid=None,
+                  length=None, step=ssd_step):
+    """The state-space mixer with its residual: ``(x + m mixer(N(x)),
+    (tail, S))``, ``m`` the block's ``residual_multiplier``.  ``x``:
+    ``[B, S, H]``; ``state``: ``(tail [B, (taps - 1) * channels] —
+    flat, oldest first: ``LinearMixerSpec.tail_shape`` —, S [B, groups,
+    N, heads a group * P] float32)`` before the window — ``S`` ``None``:
+    no inputs yet.  The one input projection is ``[z | xBC |
+    dt]`` and the convolution (with its bias, under ``state_conv``) runs
+    over ``xBC = [x | B | C]``; ``softplus``, the decay and the state are
+    float32; the skip ``D x`` joins the read-out; the output is gated by
+    ``SiLU(z)`` and THEN normed, one RMSNorm a group.  One position on a
+    state runs the recurrence, any other window the chunked form;
+    ``valid``, ``length`` and ``step`` are :func:`linear_attention`'s."""
+    spec, dtype, lin = cfg.block, cfg.dtype, cfg.block.linear
+    la = chunk["linear_attention"]
+    G, heads, N, P = lin.key_heads, lin.value_heads, lin.key_dim, \
+        lin.value_dim
+    inner = heads * P
+    B, S, _ = x.shape
+    x = x.astype(dtype)
+    h = block_norm(cfg, x, chunk["ln_attention_in"])
+    f32 = lambda t: t.astype(jnp.float32)
+    tail, ssm = state
+    with scope("linear_attention"):
+        proj = jnp.matmul(h, la["in_proj"]["kernel"].astype(dtype),
+                          preferred_element_type=jnp.float32)
+        z, xbc, dt = jnp.split(proj, [inner, inner + lin.conv_channels],
+                               axis=-1)
+        # the step and the log decay: float32 end to end
+        dt = jax.nn.softplus(dt + f32(la["dt_bias"]))    # [B, S, heads]
+        g = -jnp.exp(f32(la["A_log"])) * dt
+        if valid is not None:       # a padded position: no decay, no write
+            dt, g = dt * valid[..., None], g * valid[..., None]
+        with scope("state_conv"):
+            conv = la["conv"]
+            if S == 1:      # a decode step: the flat tail as it is held
+                xbc, tail = causal_conv_step(
+                    xbc[:, 0].astype(dtype), conv["kernel"], tail,
+                    conv.get("bias"))
+                xbc = xbc[:, None]
+            else:
+                taps = lin.conv_taps - 1
+                xbc, window = causal_conv(
+                    xbc.astype(dtype), conv["kernel"],
+                    tail.reshape(B, taps, -1), conv.get("bias"))
+                tail = conv_tail(window, taps, length).reshape(B, -1)
+        xs, Bm, Cm = jnp.split(f32(xbc), [inner, inner + G * N], axis=-1)
+        xs = xs.reshape(B, S, heads, P)
+        Bm, Cm = Bm.reshape(B, S, G, N), Cm.reshape(B, S, G, N)
+        if S == 1 and ssm is not None:
+            y, ssm = step(xs[:, 0], Bm[:, 0], Cm[:, 0], g[:, 0], dt[:, 0],
+                          ssm)
+            y = y[:, None]
+        else:
+            with scope("state_update"):
+                y, ssm = ssd_chunked(xs, Bm, Cm, g, dt, ssm)
+        y = y + f32(la["D"])[:, None] * xs
+        o = gated_group_norm(y.reshape(B, S, inner), z,
+                             f32(la["norm"]["scale"]), G, spec.norm_eps)
+        y = o.astype(dtype) @ la["out"]["kernel"].astype(dtype)
+    return _residual(cfg, x, y, chunk, "ln_attention"), (tail, ssm)
+
+
 def mix_linear(cfg: TransformerConfig, chunk, x, state, positions, *,
                valid=None, length=None, step=None):
     """A ``"linear"`` layer's mixer with its residual, whichever
     recurrence ``cfg.block.linear.rule`` names: ``(x + mixer(N(x)),
     state)``.  ``state`` is the tuple of arrays the rule keeps (the delta
-    rule's ``(tail, S)``, retention's ``(S, z)``), or ``None``: no inputs
-    yet, the window starts at position 0 — which retention's chunked form
-    takes as it is and the delta rule as :func:`blank_linear_state`'s
-    zeros; ``positions`` reach the rule whose q and k are rotated."""
+    rule's and the state-space layer's ``(tail, S)``, retention's ``(S,
+    z)``), or ``None``: no inputs yet, the window starts at position 0 —
+    which retention's chunked form takes as it is, the state-space
+    layer's as a blank tail and no matrix, and the delta rule as
+    :func:`blank_linear_state`'s zeros; ``positions`` reach the rule
+    whose q and k are rotated."""
     kw = {} if step is None else {"step": step}
-    if cfg.block.linear.rule == "retention":
+    rule = cfg.block.linear.rule
+    if rule == "retention":
         return retention_attention(cfg, chunk, x, state, positions,
                                    valid=valid, **kw)
+    if rule == "ssd":
+        if state is None:
+            state = (blank_linear_state(cfg, x.shape[0])[0], None)
+        return ssd_attention(cfg, chunk, x, state, valid=valid,
+                             length=length, **kw)
     if state is None:
         state = blank_linear_state(cfg, x.shape[0])
     return linear_attention(cfg, chunk, x, state, valid=valid,
@@ -796,8 +1000,7 @@ def blank_linear_state(cfg: TransformerConfig, batch: int):
     ssm = jnp.zeros((batch, *lin.state_shape), jnp.float32)
     if lin.has_normaliser:
         return (ssm, jnp.zeros((batch, *lin.normaliser_shape), jnp.float32))
-    return (jnp.zeros((batch, lin.conv_taps - 1, lin.conv_channels),
-                      cfg.dtype), ssm)
+    return (jnp.zeros((batch, *lin.tail_shape), cfg.dtype), ssm)
 
 
 def _swiglu(h, wi, wo):
@@ -1029,8 +1232,22 @@ def run_stack(cfg: TransformerConfig, shared, carry, layers):
 
 def head_rows(cfg: TransformerConfig, shared, h):
     """What the output projection multiplies: the final norm of ``h``,
-    unless the looped stack's last pass already applied it."""
-    return h if cfg.block.loop_steps > 1 else final_norm(cfg, shared, h)
+    unless the looped stack's last pass already applied it — divided by
+    the block's ``logits_scaling`` where it has one (in float32; the
+    logits are the rows' products, so they come out divided)."""
+    rows = h if cfg.block.loop_steps > 1 else final_norm(cfg, shared, h)
+    if cfg.block.logits_scaling != 1.0:
+        rows = (rows.astype(jnp.float32) / cfg.block.logits_scaling) \
+            .astype(rows.dtype)
+    return rows
+
+
+def embedding_rows(cfg: TransformerConfig, rows):
+    """The token embedding's ``rows`` as the stream takes them: times the
+    block's ``embedding_multiplier`` where it has one."""
+    if cfg.block.embedding_multiplier != 1.0:
+        rows = rows * cfg.block.embedding_multiplier
+    return rows
 
 
 def head_table(cfg: TransformerConfig, shared):
@@ -1048,7 +1265,7 @@ def sequential_logits(cfg: TransformerConfig, params, tokens):
     logits."""
     stages, shared = params["stages"], params["shared"]
     L = tokens.shape[1]
-    x = shared["embedding"][tokens]
+    x = embedding_rows(cfg, shared["embedding"][tokens])
     if cfg.block.positions == "learned":
         x = x + shared["pos_embed"][None, :L]
     mask = jnp.tril(jnp.ones((L, L), bool))[None, None]
@@ -1101,7 +1318,11 @@ def param_shapes(cfg: TransformerConfig) -> dict:
     and ``dt_bias`` a channel.  A power-retention mixer holds attention's
     own leaves under ``linear_attention`` — ``qkv``, ``out`` and, in a
     ``qk_norm`` block, ``q_norm`` and ``k_norm``, stacked over its layers
-    — and the gate's ``gate`` ``[H, kv_heads]`` beside them.  A router
+    — and the gate's ``gate`` ``[H, kv_heads]`` beside them.  A
+    state-space (ssd) mixer holds ``in_proj`` ``[H, z | x | B | C | dt]``,
+    the convolution's taps (and ``bias``), ``A_log``, ``D`` and
+    ``dt_bias`` a head, the gated norm's scale over the inner width and
+    ``out``.  A router
     with a correction holds it beside its kernel, ``correction``
     ``[num_experts]``.  A zero-centred norm's leaf is ``weight``."""
     spec = cfg.block
@@ -1159,6 +1380,20 @@ def param_shapes(cfg: TransformerConfig) -> dict:
         # gate's projection a key/value head rides with them
         stages["linear_attention"] = dict(attention,
                                           gate=dense((H, kv), (), La))
+    elif spec.linear is not None and spec.linear.rule == "ssd":
+        lin, Ll = spec.linear, kinds.count("linear")
+        inner = lin.value_heads * lin.value_dim
+        stages["linear_attention"] = {
+            "in_proj": dense((H, inner + lin.conv_channels
+                              + lin.value_heads), (), Ll),
+            "conv": {"kernel": (Ll, lin.conv_taps, lin.conv_channels),
+                     **({"bias": (Ll, lin.conv_channels)}
+                        if lin.conv_bias else {})},
+            "A_log": (Ll, lin.value_heads),
+            "D": (Ll, lin.value_heads),
+            "dt_bias": (Ll, lin.value_heads),
+            "norm": {"scale": (Ll, inner)},
+            "out": dense((inner, H), (), Ll)}
     elif spec.linear is not None:
         lin, Ll = spec.linear, kinds.count("linear")
         inner = lin.value_heads * lin.value_dim

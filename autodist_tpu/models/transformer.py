@@ -54,7 +54,22 @@ class LinearMixerSpec:
     mod key_dim}`` for ``o = 0 .. key_dim / 2`` — a lane rotation builds
     a whole row — so the last offset's pairs are held twice:
     ``(key_dim / 2 + 1) key_dim`` rows (:attr:`state_rows`) for
-    :attr:`state_rows_packed` distinct ones."""
+    :attr:`state_rows_packed` distinct ones.
+
+    ``"ssd"`` — a Mamba-2 state-space layer (:meth:`ssd` builds it):
+    ``value_heads`` heads of ``value_dim`` (the layer's inner width),
+    ``key_heads`` GROUPS, each with one B (the write's key) and one C (the
+    read's query) of ``key_dim`` — the state size — that all its
+    ``value_heads // key_heads`` heads share; a depthwise causal
+    convolution of ``conv_taps`` taps, with a bias where ``conv_bias``,
+    over ``[x | B | C]``; one scalar decay a head and position,
+    ``exp(Delta A)`` with ``Delta = softplus(dt + dt_bias)`` the write's
+    strength too; a skip ``D`` a head; no l2 norm, no normaliser; the
+    output gated by ``SiLU(z)`` BEFORE one RMSNorm a group.  A group's
+    state is ONE ``[key_dim, heads a group * value_dim]`` float32 matrix:
+    B down the rows, every head's values side by side along the
+    columns, the decay a row vector that is constant over a head's
+    ``value_dim`` columns."""
 
     key_heads: int
     value_heads: int
@@ -65,14 +80,17 @@ class LinearMixerSpec:
     gate_floor: float = 0.0
     rule: str = "delta"
     power: int = 1
+    conv_bias: bool = False
 
     def __post_init__(self):
-        if self.rule not in ("delta", "retention"):
+        if self.rule not in ("delta", "retention", "ssd"):
             raise ValueError(f"LinearMixerSpec.rule={self.rule!r}: one of "
-                             "('delta', 'retention')")
+                             "('delta', 'retention', 'ssd')")
         if self.value_heads % self.key_heads:
-            raise ValueError("LinearMixerSpec.value_heads must be a "
-                             "multiple of key_heads")
+            raise ValueError(
+                "LinearMixerSpec.value_heads must be a multiple of "
+                "key_heads (a state-space layer's groups divide its "
+                f"heads): {self.value_heads} on {self.key_heads}")
         if self.gate not in ("head", "channel"):
             raise ValueError(f"LinearMixerSpec.gate={self.gate!r}: one of "
                              "('head', 'channel')")
@@ -88,9 +106,19 @@ class LinearMixerSpec:
                 "power retention is served at degree 2, with no "
                 "convolution and no gate kind, a state a key/value head "
                 "of even size (LinearMixerSpec.retention builds it)")
-        if self.rule == "delta" and self.power != 1:
-            raise ValueError("the delta rule reads its key as it is: "
+        if self.rule != "retention" and self.power != 1:
+            raise ValueError("the delta rule and the state-space layer "
+                             "read their key as it is: "
                              "LinearMixerSpec.power is retention's degree")
+        if self.rule == "ssd" and (self.gate != "head"
+                                   or self.conv_taps < 2):
+            raise ValueError(
+                "a state-space (ssd) layer decays by one scalar a head and "
+                "convolves its input over at least two taps "
+                "(LinearMixerSpec.ssd builds it)")
+        if self.conv_bias and self.rule != "ssd":
+            raise ValueError("LinearMixerSpec.conv_bias is the state-space "
+                             "(ssd) layer's convolution's")
 
     @classmethod
     def retention(cls, kv_heads: int, head_dim: int, power: int = 2):
@@ -99,9 +127,21 @@ class LinearMixerSpec:
         return cls(kv_heads, kv_heads, head_dim, head_dim, conv_taps=0,
                    rule="retention", power=power)
 
+    @classmethod
+    def ssd(cls, heads: int, head_dim: int, state_dim: int, groups: int = 1,
+            conv_taps: int = 4, conv_bias: bool = True):
+        """A Mamba-2 state-space layer by its config's own keys: ``heads``
+        (``mamba_n_heads``) of ``head_dim`` (``mamba_d_head``), a state of
+        ``state_dim`` (``mamba_d_state``), ``groups`` (``mamba_n_groups``)
+        of B and C, ``conv_taps`` (``mamba_d_conv``) and ``conv_bias``
+        (``mamba_conv_bias``)."""
+        return cls(groups, heads, state_dim, head_dim, conv_taps=conv_taps,
+                   rule="ssd", conv_bias=conv_bias)
+
     @property
     def conv_channels(self) -> int:
-        """Channels the convolution runs over: q, k and v, flat."""
+        """Channels the convolution runs over: q, k and v (of a
+        state-space layer C, B and x), flat."""
         return 2 * self.key_heads * self.key_dim \
             + self.value_heads * self.value_dim
 
@@ -111,7 +151,22 @@ class LinearMixerSpec:
     def has_conv(self) -> bool:
         """Whether a slot keeps a convolution tail (``conv_taps - 1``
         rows of its input)."""
-        return self.rule == "delta"
+        return self.rule != "retention"
+
+    @property
+    def tail_shape(self) -> tuple:
+        """A slot's convolution tail in one layer (``has_conv``): the
+        ``conv_taps - 1`` inputs before the next position, ``[taps - 1,
+        channels]`` — of a state-space layer FLAT, ``[(taps - 1) *
+        channels]``, oldest first: its channels are whole lanes, so a
+        decode step shifts and convolves by lane-aligned slices, and the
+        chip keeps the stacked array as declared (three rows second-minor
+        it pads to a sublane tile, and re-lays the whole array out around
+        every layer's write: 18% of a decode step, PERF.md section 6,
+        PR 48)."""
+        if self.rule == "ssd":
+            return ((self.conv_taps - 1) * self.conv_channels,)
+        return (self.conv_taps - 1, self.conv_channels)
 
     @property
     def has_normaliser(self) -> bool:
@@ -120,9 +175,15 @@ class LinearMixerSpec:
 
     @property
     def state_heads(self) -> int:
-        """Heads that hold a state: the value heads of the delta rule,
-        the key/value heads of retention."""
+        """Heads that hold a state: the value heads of the delta rule
+        and of a state-space layer, the key/value heads of retention."""
         return self.value_heads
+
+    @property
+    def group_width(self) -> int:
+        """Columns of a state-space group's matrix: its heads' values,
+        side by side."""
+        return self.value_heads // self.key_heads * self.value_dim
 
     @property
     def state_offsets(self) -> int:
@@ -132,14 +193,14 @@ class LinearMixerSpec:
     @property
     def state_rows(self) -> int:
         """Rows of ``value_dim`` a head's state holds, as laid out."""
-        if self.rule == "delta":
+        if self.rule != "retention":
             return self.key_dim
         return self.state_offsets * self.key_dim
 
     @property
     def state_rows_packed(self) -> int:
         """The distinct rows among them: what the recurrence needs."""
-        if self.rule == "delta":
+        if self.rule != "retention":
             return self.key_dim
         return self.key_dim * (self.key_dim + 1) // 2
 
@@ -147,9 +208,12 @@ class LinearMixerSpec:
     def state_shape(self) -> tuple:
         """A slot's state matrix in one layer: ``[heads, key_dim,
         value_dim]``, or retention's ``[heads, offsets, value_dim,
-        key_dim]`` (an offset's tile holds ``key_dim`` on the lanes)."""
+        key_dim]`` (an offset's tile holds ``key_dim`` on the lanes), or
+        a state-space layer's ``[groups, key_dim, group_width]``."""
         if self.rule == "delta":
             return (self.value_heads, self.key_dim, self.value_dim)
+        if self.rule == "ssd":
+            return (self.key_heads, self.key_dim, self.group_width)
         return (self.key_heads, self.state_offsets, self.value_dim,
                 self.key_dim)
 
@@ -318,9 +382,18 @@ class BlockSpec:
       sub-block, four a layer) or ``"pre"`` (``x + f(N(x))``, two a
       layer).  ``norm_zero_centred``: the RMSNorm multiplies by ``1 +
       weight`` (its leaf is ``weight``, near 0, not ``scale``).
-    * ``positions`` ``"learned"`` (a table added to the embedding) or
+    * ``positions`` ``"learned"`` (a table added to the embedding),
       ``"rope"`` (rotate-half rotary at ``rope_theta`` on q and k inside
-      attention; the key is rotated before it is cached).
+      attention; the key is rotated before it is cached) or ``"none"``
+      (nothing positional anywhere: causal attention over what recurrent
+      layers beside it have ordered).
+    * ``embedding_multiplier``, ``residual_multiplier``,
+      ``logits_scaling`` — the embedding's rows TIMES the first, every
+      sub-block's output TIMES the second before it is added to the
+      stream, the logits DIVIDED by the third; ``softmax_scale`` — what
+      attention multiplies its scores by (``None``: ``head_dim **
+      -0.5``), read by every path that attends (:attr:`TransformerConfig
+      .softmax_scale`).
     * ``ffn`` ``"gelu"`` (``wo(gelu(wi x))``) or ``"swiglu"``
       (``wo(silu(gate x) * (up x))``, gate and up stacked in ``wi``).
     * ``bias`` — whether the projections carry biases.
@@ -378,12 +451,16 @@ class BlockSpec:
     rope_scaling: Optional[RopeScaling] = None
     dense_layers: int = 0
     rope_interleave: bool = False
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    softmax_scale: Optional[float] = None
 
     def __post_init__(self):
         for name, allowed in (("norm", ("layernorm", "rmsnorm")),
                               ("norm_placement", ("post", "sandwich",
                                                   "pre")),
-                              ("positions", ("learned", "rope")),
+                              ("positions", ("learned", "rope", "none")),
                               ("ffn", ("gelu", "swiglu"))):
             if getattr(self, name) not in allowed:
                 raise ValueError(f"BlockSpec.{name}={getattr(self, name)!r}"
@@ -447,8 +524,25 @@ class BlockSpec:
                 raise ValueError(
                     "qk_norm, kv_heads and head_dim are attention's (or "
                     "power retention's) q and k: a delta-rule mixer has "
-                    "its own heads and l2 norm, and this layer_period "
-                    "has no 'full' layer")
+                    "its own heads and l2 norm, a state-space (ssd) layer "
+                    "its B and C as they leave the convolution, and this "
+                    "layer_period has no 'full' layer")
+            if self.linear.rule == "ssd" and not attends \
+                    and self.positions == "rope":
+                raise ValueError(
+                    "rotary positions turn attention's q and k: a "
+                    "state-space (ssd) layer has none to turn, and this "
+                    "layer_period has no 'full' layer")
+        if self.latent is not None and self.softmax_scale is not None:
+            raise ValueError("latent attention's scale is its own "
+                             "(BlockSpec.latent_softmax_scale): "
+                             "softmax_scale is the 'full' layers'")
+        if min(self.embedding_multiplier, self.residual_multiplier,
+               self.logits_scaling) <= 0 or (
+                   self.softmax_scale is not None
+                   and self.softmax_scale <= 0):
+            raise ValueError("BlockSpec's multipliers, logits_scaling and "
+                             "softmax_scale are positive")
         if self.rope_interleave and self.latent is None:
             raise ValueError("BlockSpec.rope_interleave pairs the rotary "
                              "dimensions of latent attention's positional "
@@ -512,13 +606,26 @@ class TransformerConfig:
     def kv_heads(self) -> int:
         return self.block.kv_heads or self.num_heads
 
+    @property
+    def softmax_scale(self) -> float:
+        """What attention multiplies its scores by: the block's, or
+        ``head_dim ** -0.5``."""
+        if self.block.softmax_scale is not None:
+            return float(self.block.softmax_scale)
+        return self.head_dim ** -0.5
+
 
 def dot_product_attention(q, k, v, mask, *, dropout_rate=0.0,
-                          dropout_rng=None, dtype=jnp.bfloat16):
-    """Plain einsum attention (softmax in fp32 for stability)."""
+                          dropout_rng=None, dtype=jnp.bfloat16, scale=None):
+    """Plain einsum attention (softmax in fp32 for stability).  ``scale``:
+    what the scores are multiplied by, in float32, where it is not
+    ``head_dim ** -0.5`` (``BlockSpec.softmax_scale``)."""
     depth = q.shape[-1]
-    scores = jnp.einsum("...qhd,...khd->...hqk", q, k) / np.sqrt(depth)
-    scores = scores.astype(jnp.float32)
+    scores = jnp.einsum("...qhd,...khd->...hqk", q, k)
+    if scale is None:
+        scores = (scores / np.sqrt(depth)).astype(jnp.float32)
+    else:
+        scores = scores.astype(jnp.float32) * scale
     if mask is not None:
         scores = jnp.where(mask, scores, jnp.finfo(jnp.float32).min)
     probs = jax.nn.softmax(scores, axis=-1).astype(dtype)
@@ -533,7 +640,8 @@ def _fused(cfg: TransformerConfig, q, k, v, mask, dropout_rng) -> bool:
     """Whether attention over these projections (arrays or shapes) takes
     the fused kernels: what :func:`attend` documents."""
     if cfg.attention_fn is not None or mask is not None \
-            or dropout_rng is not None:
+            or dropout_rng is not None \
+            or cfg.block.softmax_scale is not None:
         return False
     from autodist_tpu.ops.flash_attention import fused_attention_fits
 
@@ -568,6 +676,11 @@ def attend(cfg: TransformerConfig, q, k, v, mask, *, dropout_rng=None,
     :func:`dot_product_attention`.  Same mathematics either way:
     float32 scores and softmax, probabilities in the operand type."""
     if cfg.attention_fn is not None:
+        if cfg.block.softmax_scale is not None:
+            raise ValueError(
+                "cfg.attention_fn scales its scores by head_dim ** -0.5: "
+                f"BlockSpec.softmax_scale={cfg.block.softmax_scale} is "
+                "served by the einsum attention alone")
         return cfg.attention_fn(q, k, v, mask, dropout_rng)
     traced = isinstance(q, jax.core.Tracer)   # count programs, not init
     if _fused(cfg, q, k, v, mask, dropout_rng):
@@ -579,7 +692,8 @@ def attend(cfg: TransformerConfig, q, k, v, mask, *, dropout_rng=None,
 
         telemetry.counter("kernel/einsum_attention_calls").inc()
     return dot_product_attention(q, k, v, mask, dropout_rate=dropout_rate,
-                                 dropout_rng=dropout_rng, dtype=cfg.dtype)
+                                 dropout_rng=dropout_rng, dtype=cfg.dtype,
+                                 scale=cfg.block.softmax_scale)
 
 
 class SelfAttention(nn.Module):

@@ -80,7 +80,8 @@ MIN_PREFILL_RUNG = 256
 
 
 # the kernel that advances a linear mixer's state by a position, by rule
-_STATE_KERNELS = {"delta": "delta_step", "retention": "retention_step"}
+_STATE_KERNELS = {"delta": "delta_step", "retention": "retention_step",
+                  "ssd": "ssd_step"}
 
 
 def prefill_rungs(prefill_len: int) -> tuple:
@@ -295,8 +296,9 @@ class ServingEngine:
                 f"block ({spec}): the Megatron rule tables name the "
                 "default block's leaves only (no rule splits a linear "
                 "mixer's heads, its recurrent state — a delta rule's "
-                "value heads, power retention's key/value heads — or a "
-                "routed FFN's experts)")
+                "value heads, power retention's key/value heads, a "
+                "state-space (ssd) layer's groups — or a routed FFN's "
+                "experts)")
         # a mixed stack: the full layers cache keys and values (the
         # latent ones a row), the linear ones keep a recurrent state
         # (kv_cache.RecurrentState)
@@ -568,12 +570,16 @@ class ServingEngine:
     def _embed(self, shared, tokens, positions):
         """Token (+ learned position) embedding for ``[B, S]`` token ids
         at per-token ``positions`` (``[B, S]`` or a static ``[S]``); a
-        rotary block's positions enter inside attention instead."""
+        rotary block's positions enter inside attention instead, and a
+        block without positions has none to add.  The rows times the
+        block's ``embedding_multiplier``."""
+        from autodist_tpu.models.pipeline_lm import embedding_rows
+
         cfg = self.cfg
-        x = vocab_parallel_embedding(
+        x = embedding_rows(cfg, vocab_parallel_embedding(
             tokens, shared["embedding"], model_axis=self._axis
             if self.vocab_parallel else None,
-            comm_overlap=self.comm_overlap).astype(cfg.dtype)
+            comm_overlap=self.comm_overlap).astype(cfg.dtype))
         if cfg.block.positions != "learned":
             return x
         pos = jnp.take(shared["pos_embed"], positions, axis=0)
@@ -644,13 +650,15 @@ class ServingEngine:
         for a rule whose q and k are rotated).  It advances every slot's
         rows: the recurrent matrix (and power retention's normaliser)
         where the manager keeps it, through the layout's seam
-        (``self.kv.advance_state`` / ``advance_retention``: the stacked
-        arrays go in and come out, no slice of them here), the delta
-        rule's convolution tail read and written here.  (The prompt's
-        pass through such a layer is :meth:`_build_prefill`'s.)"""
+        (``self.kv.advance_state`` / ``advance_retention`` /
+        ``advance_ssd``: the stacked arrays go in and come out, no slice
+        of them here), the convolution tail of the rules that have one
+        read and written here.  (The prompt's pass through such a layer is
+        :meth:`_build_prefill`'s.)"""
         from autodist_tpu.models import pipeline_lm as lm
 
-        if self.cfg.block.linear.rule == "retention":
+        rule = self.cfg.block.linear.rule
+        if rule == "retention":
             x, state = lm.retention_attention(
                 self.cfg, chunk, x, state, positions,
                 step=functools.partial(self.kv.advance_retention,
@@ -659,11 +667,17 @@ class ServingEngine:
         # both arrays, as the benchmark's planted faults wrap it (the
         # slice of the matrices is dead code, and compiled away)
         tail, _ = kv_cache.read_state(state, layer)
-        x, (tail, ssm) = lm.linear_attention(
+        # the rule's mixer, its seam, and what the tail's write back is a
+        # part of (a state-space layer's: the convolution's shift)
+        mixer, seam, write = {
+            "delta": (lm.linear_attention, self.kv.advance_state,
+                      "state_update"),
+            "ssd": (lm.ssd_attention, self.kv.advance_ssd, "state_conv"),
+        }[rule]
+        x, (tail, ssm) = mixer(
             self.cfg, chunk, x, (tail, state[1]),
-            step=functools.partial(self.kv.advance_state, layer=layer))
-        with telemetry.scope("linear_attention"), \
-                telemetry.scope("state_update"):
+            step=functools.partial(seam, layer=layer))
+        with telemetry.scope("linear_attention"), telemetry.scope(write):
             state = (*kv_cache.write_state(state[:1], layer, (tail,)), ssm)
         return self._ffn(chunk, x, valid, tally), state
 

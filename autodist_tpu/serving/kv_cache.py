@@ -106,8 +106,10 @@ class RecurrentState:
     ``ssm`` ``[linear_layer, slot, *state_shape]`` float32, the
     recurrence's matrix (the delta rule's ``[value_heads, key_dim,
     value_dim]``, power retention's ``[kv_heads, offsets, value_dim,
-    key_dim]``); ``conv`` ``[linear_layer, slot, taps - 1, channels]`` —
-    the inputs a causal convolution still needs — where the rule has
+    key_dim]``, a state-space layer's ``[groups, state size, heads a
+    group * head size]``); ``conv`` ``[linear_layer, slot, *tail_shape]``
+    — the inputs a causal convolution still needs, ``[taps - 1,
+    channels]`` or (a state-space layer's) flat — where the rule has
     one, else ``None``; ``norm`` ``[linear_layer, slot,
     *normaliser_shape]`` float32 where a normaliser rides beside the
     matrix, else ``None``.  A slot's rows are overwritten whole by the
@@ -140,8 +142,8 @@ def init_state(linear_layers: int, num_slots: int, mixer,
     (:class:`~autodist_tpu.models.transformer.LinearMixerSpec`)."""
     lead = (linear_layers, num_slots)
     return RecurrentState(
-        conv=jnp.zeros(lead + (mixer.conv_taps - 1, mixer.conv_channels),
-                       dtype) if mixer.has_conv else None,
+        conv=jnp.zeros(lead + mixer.tail_shape, dtype)
+        if mixer.has_conv else None,
         ssm=jnp.zeros(lead + mixer.state_shape, jnp.float32),
         norm=jnp.zeros(lead + mixer.normaliser_shape, jnp.float32)
         if mixer.has_normaliser else None)
@@ -584,7 +586,8 @@ def paged_write_chunk(cache_arr, layer: int, kv, admit, block_table,
     return cache_arr
 
 
-def chunk_attention(q, k_layer, v_layer, starts, *, dtype=jnp.float32):
+def chunk_attention(q, k_layer, v_layer, starts, *, dtype=jnp.float32,
+                    scale=None):
     """A token window's causal attention over contiguous cache lanes:
     window row ``r`` of slot ``i`` is the query at absolute position
     ``starts[i] + r`` and attends to every cached key at positions
@@ -594,14 +597,18 @@ def chunk_attention(q, k_layer, v_layer, starts, *, dtype=jnp.float32):
     head_dim]``; ``k_layer``/``v_layer``: ``[B, heads, T, head_dim]``.
     Serves the chunked-prefill composed path (via
     :func:`paged_chunk_attention`) and the dense speculative verify
-    pass, where every slot's window begins at its own length."""
+    pass, where every slot's window begins at its own length.
+    ``scale``: as :func:`cached_attention`'s."""
     depth = q.shape[-1]
     C = q.shape[1]
     q2 = jnp.transpose(q, (0, 2, 1, 3))              # [B, H, C, dh]
     scores = lax.dot_general(
         q2, k_layer.astype(q.dtype),
-        (((3,), (3,)), ((0, 1), (0, 1)))) / np.sqrt(depth)
-    scores = scores.astype(jnp.float32)              # [B, H, C, T]
+        (((3,), (3,)), ((0, 1), (0, 1))))
+    if scale is None:
+        scores = (scores / np.sqrt(depth)).astype(jnp.float32)
+    else:
+        scores = scores.astype(jnp.float32) * scale  # [B, H, C, T]
     T = k_layer.shape[2]
     ok = jnp.arange(T)[None, None, None, :] <= \
         (starts[:, None] + jnp.arange(C)[None, :])[:, None, :, None]
@@ -614,7 +621,7 @@ def chunk_attention(q, k_layer, v_layer, starts, *, dtype=jnp.float32):
 
 
 def paged_chunk_attention(q, k_pool, v_pool, starts, block_table, *,
-                          block_len: int, dtype=jnp.float32):
+                          block_len: int, dtype=jnp.float32, scale=None):
     """The paged :func:`chunk_attention`: gather the slot's blocks into
     contiguous lanes, then the same masked math (``T`` becomes the
     padded ``max_blocks * block_len`` extent).  The composed gather
@@ -623,7 +630,8 @@ def paged_chunk_attention(q, k_pool, v_pool, starts, block_table, *,
     del block_len  # implied by the pool's block extent
     k_layer = gather_blocks(k_pool, block_table)     # [B, H, T, dh]
     v_layer = gather_blocks(v_pool, block_table)
-    return chunk_attention(q, k_layer, v_layer, starts, dtype=dtype)
+    return chunk_attention(q, k_layer, v_layer, starts, dtype=dtype,
+                           scale=scale)
 
 
 def copy_pool_block(k_pool, v_pool, src, dst):
@@ -657,7 +665,7 @@ def gather_blocks(pool, block_table):
 
 
 def paged_cached_attention(q, k_pool, v_pool, lengths, block_table, *,
-                           block_len: int, dtype=jnp.float32):
+                           block_len: int, dtype=jnp.float32, scale=None):
     """One decode step's attention over a layer's *paged* cache slice:
     gather the slot's blocks into a contiguous lane, then run the exact
     :func:`cached_attention` masked math (T becomes the padded
@@ -667,7 +675,8 @@ def paged_cached_attention(q, k_pool, v_pool, lengths, block_table, *,
     del block_len  # implied by the pool's block extent
     k_layer = gather_blocks(k_pool, block_table)
     v_layer = gather_blocks(v_pool, block_table)
-    return cached_attention(q, k_layer, v_layer, lengths, dtype=dtype)
+    return cached_attention(q, k_layer, v_layer, lengths, dtype=dtype,
+                            scale=scale)
 
 
 # --------------------------------------------------------------------------- #
@@ -694,7 +703,8 @@ FEATURES = {
                 "copies a request's blocks of keys and values"),
 }
 # what a linear mixer's rule is called where a refusal names it
-_RULES = {"delta": "delta-rule", "retention": "power-retention"}
+_RULES = {"delta": "delta-rule", "retention": "power-retention",
+          "ssd": "state-space (ssd)"}
 
 
 def _both(write, kc, vc, layer, k, v, *a, **kw):
@@ -735,10 +745,11 @@ class DenseLayout:
         (:attr:`unpaged`) serve nothing of :data:`FEATURES`."""
         return frozenset() if self.unpaged else frozenset({"speculative"})
 
-    def __init__(self, dims, kernel, *, fused_block=None, recurrent=None):
+    def __init__(self, dims, kernel, *, fused_block=None, recurrent=None,
+                 scale=None):
         from autodist_tpu.kernel.pallas.flash_decode import rows_layout
 
-        self.dims = dims
+        self.dims, self.scale = dims, scale
         self.cache_layers, num_slots, _, head_dim, self.max_len = dims
         self.kernel = kernel        # the engine's elections, by name
         self.fused_block = fused_block
@@ -817,32 +828,43 @@ class DenseLayout:
                     flash_decode_attention_dense
                 out, kc, vc = flash_decode_attention_dense(
                     q, kc, vc, layer, lengths, new_kv=(k, v),
-                    active=active, dtype=dtype, block_k=fused)
+                    active=active, dtype=dtype, block_k=fused,
+                    scale=self.scale)
             else:
                 out = cached_attention(q, kc[layer], vc[layer], lengths,
-                                       dtype=dtype)
+                                       dtype=dtype, scale=self.scale)
         return out, kc, vc
 
     def attend_window(self, q, kc, vc, layer, starts, table, *, dtype):
         with scope("attention"):
             return chunk_attention(q, kc[layer], vc[layer], starts,
-                                   dtype=dtype)
+                                   dtype=dtype, scale=self.scale)
 
-    def state_kernel(self, ssm, group: int = 1) -> bool:
+    def state_kernel(self, ssm, group: int = 1, rule=None) -> bool:
         """Whether a decode step advances ``ssm`` (the stacked recurrent
         matrices, or their shape and type) in the fused kernel of the
-        mixer's rule: the election, from what can be observed where it
-        is called (:func:`~autodist_tpu.kernel.pallas.delta_step
-        .delta_step_elected`, or :func:`~autodist_tpu.kernel.pallas
+        mixer's rule (``rule``: the seam's own where a seam asks, else
+        ``self.recurrent``'s spec's; the delta rule's for a layout built
+        without one): the election, from what can be observed where it is
+        called
+        (:func:`~autodist_tpu.kernel.pallas.delta_step
+        .delta_step_elected`, :func:`~autodist_tpu.kernel.pallas.ssd_step
+        .ssd_step_elected`, or :func:`~autodist_tpu.kernel.pallas
         .retention_step.retention_step_elected` with the ``group`` of
         query heads that read a state; the kernel slot's ``delta_step``
-        / ``retention_step`` forces or forbids)."""
-        if len(ssm.shape) == 6:     # power retention's [.., offsets, dv, d]
+        / ``ssd_step`` / ``retention_step`` forces or forbids)."""
+        rule = rule or (self.recurrent[1].rule if self.recurrent
+                        else "delta")
+        if rule == "retention":
             from autodist_tpu.kernel.pallas.retention_step import \
                 retention_step_elected
             return retention_step_elected(
                 self.kernel.get("retention_step"), ssm.shape, ssm.dtype,
                 group)
+        if rule == "ssd":
+            from autodist_tpu.kernel.pallas.ssd_step import ssd_step_elected
+            return ssd_step_elected(self.kernel.get("ssd_step"), ssm.shape,
+                                    ssm.dtype)
         from autodist_tpu.kernel.pallas.delta_step import delta_step_elected
 
         return delta_step_elected(self.kernel.get("delta_step"), ssm.shape,
@@ -857,7 +879,7 @@ class DenseLayout:
         kernel, which reads and writes each tile of the array once, in
         place, or the composed step on the layer's slice and its
         :func:`write_state`."""
-        if self.state_kernel(ssm):
+        if self.state_kernel(ssm, rule="delta"):
             from autodist_tpu.kernel.pallas.delta_step import \
                 gated_delta_step_fused
             with scope("state_update"):
@@ -879,7 +901,8 @@ class DenseLayout:
         from autodist_tpu.models.pipeline_lm import (RETENTION_EPS,
                                                      retention_step)
 
-        if self.state_kernel(state[0], q.shape[1] // k.shape[1]):
+        if self.state_kernel(state[0], q.shape[1] // k.shape[1],
+                             "retention"):
             from autodist_tpu.kernel.pallas.retention_step import \
                 retention_step_fused
             with scope("state_update"):
@@ -888,6 +911,28 @@ class DenseLayout:
         y, new = retention_step(q, k, v, g, read_state(state, layer))
         with scope("state_update"):
             return y, write_state(state, layer, new)
+
+    def advance_ssd(self, x, Bm, Cm, g, dt, ssm, layer):
+        """``(y, ssm)``: every slot's matrices of linear layer ``layer``
+        advanced by one position of a state-space layer
+        (:func:`~autodist_tpu.models.pipeline_lm.ssd_step`'s operands,
+        ``ssm`` the stacked array) and read through ``Cm`` — in the fused
+        kernel, which reads and writes each matrix once, in place
+        (``kernel/ssd_step_calls`` counts the programs that call it), or
+        the composed step on the layer's slice and its
+        :func:`write_state`."""
+        if self.state_kernel(ssm, rule="ssd"):
+            from autodist_tpu import telemetry
+            from autodist_tpu.kernel.pallas.ssd_step import ssd_step_fused
+            if isinstance(ssm, jax.core.Tracer):    # programs, not calls
+                telemetry.counter("kernel/ssd_step_calls").inc()
+            with scope("state_update"):
+                return ssd_step_fused(x, Bm, Cm, g, dt, ssm, layer)
+        from autodist_tpu.models.pipeline_lm import ssd_step
+
+        y, new = ssd_step(x, Bm, Cm, g, dt, ssm[layer])
+        with scope("state_update"):
+            return y, write_state((ssm,), layer, (new,))[0]
 
     # ---- host -------------------------------------------------------- #
     def table_arg(self, cache):
@@ -1030,8 +1075,8 @@ class PagedLayout:
     gauges = DenseLayout.gauges     # of dims, arrays and no recurrent state
 
     def __init__(self, dims, kernel, *, block_len: int, num_blocks: int,
-                 prefix_caching: bool = False):
-        self.dims = dims
+                 prefix_caching: bool = False, scale=None):
+        self.dims, self.scale = dims, scale
         _, self.num_slots, _, _, self.max_len = dims
         self.kernel = kernel        # the engine's elections, by name
         self.block_len = block_len
@@ -1091,7 +1136,8 @@ class PagedLayout:
             attend = paged_cached_attention
         with scope("attention"):
             return attend(q, kc[layer], vc[layer], lengths, table,
-                          block_len=self.block_len, dtype=dtype), kc, vc
+                          block_len=self.block_len, dtype=dtype,
+                          scale=self.scale), kc, vc
 
     def attend_window(self, q, kc, vc, layer, starts, table, *, dtype):
         if self.kernel.get("flash_prefill"):
@@ -1101,7 +1147,8 @@ class PagedLayout:
             attend = paged_chunk_attention
         with scope("attention"):
             return attend(q, kc[layer], vc[layer], starts, table,
-                          block_len=self.block_len, dtype=dtype)
+                          block_len=self.block_len, dtype=dtype,
+                          scale=self.scale)
 
     # ---- host: the batcher's admission predicate ---------------------- #
     def table_arg(self, cache):
@@ -1368,7 +1415,8 @@ def layout_for(cfg, kernel, *, num_slots: int, max_len: int,
                 f"blocks of {kv_block_len})")
         return PagedLayout(dims, kernel, block_len=kv_block_len,
                            num_blocks=kv_num_blocks,
-                           prefix_caching=prefix_caching)
+                           prefix_caching=prefix_caching,
+                           scale=spec.softmax_scale)
     if spec.latent is not None:
         # one row a position, one key head: the values are its first
         # kv_rank columns
@@ -1380,7 +1428,7 @@ def layout_for(cfg, kernel, *, num_slots: int, max_len: int,
                 word, max_len, row, rank, cfg.dtype))
     else:
         layout = DenseLayout(
-            dims, kernel, recurrent=recurrent,
+            dims, kernel, recurrent=recurrent, scale=spec.softmax_scale,
             fused_block=flash_decode.dense_decode_elected(
                 word, max_len, cfg.head_dim) if layers else None)
     if layout.fused_block:

@@ -27,9 +27,13 @@ SCOPES = (
     "linear_attention",  # a recurrent mixer: a gated-DeltaNet layer's
                    # projections, convolution, gated norm and output
                    # projection; a power-retention layer's q/k/v, gate and
-                   # output projections
+                   # output projections; a state-space (ssd) layer's input
+                   # projection, step, gate, norm and output projection
     "state_update",  # inside linear_attention: the recurrent state read,
                    # decayed, written (one step, or a window by chunks)
+    "state_conv",  # inside linear_attention, of a state-space (ssd)
+                   # layer: the causal convolution over [x | B | C] — the
+                   # tail's shift and write, the taps, the bias, the SiLU
     "moe",         # a routed FFN: router, sort, experts, shared expert
     "moe_experts",  # inside moe: routing and the held experts' grouped
                    # matmuls over the (row, expert) pairs that hit them

@@ -1254,6 +1254,149 @@ def retention_block_phase(*, slots: int = 16, kv_heads: int = 8,
     return done
 
 
+def ssd_block_phase(*, slots: int = 64, heads: int = 64, head_dim: int = 64,
+                    state: int = 128, groups: int = 1, layers: int = 36,
+                    check_slots: int = 2, window: int = 300,
+                    reps: int = 11, seed: int = 0) -> list:
+    """A Mamba-2 state-space stack's state step at the widths of the
+    benchmark's ``granite-4.0-h-micro`` cell, against its composed forms
+    on the same backend: a window through the chunked form (from no
+    state, as a prompt's pass runs it, and on the state of the half
+    before it) and one position after it against the recurrence run
+    position by position (``check_slots`` rows); where its matrices fit,
+    the ssd-step kernel over the stacked state of ``layers`` layers
+    through the cache manager's seam against the composed step on a
+    layer's slice, output and state, the other layers bit for bit; then
+    on a TPU, the kernel alone at the cell's ``slots`` matrices of
+    ``[state, heads * head_dim]``, the us a call beside a plain copy of
+    the same bytes (the layer's matrices read, scaled and written back in
+    place) and beside the composed step."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from autodist_tpu.kernel.pallas import ssd_step as ss
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import LinearMixerSpec
+    from autodist_tpu.serving import kv_cache
+
+    ph = "ssd"
+    r = np.random.RandomState(seed)
+    rand = lambda *shape: jnp.asarray(r.randn(*shape), jnp.float32)
+    P, N, G = head_dim, state, groups
+    mixer = LinearMixerSpec.ssd(heads, P, N, G)
+    done = []
+
+    # ---- a window, then one position: against position by position -----
+    B, T = check_slots, window
+    x, Bm, Cm = rand(B, T, heads, P), rand(B, T, G, N), rand(B, T, G, N)
+    dt = jax.nn.softplus(rand(B, T, heads))
+    g = -dt * jnp.exp(0.02 * rand(heads))
+
+    def by_position(x, Bm, Cm, g, dt):
+        def one(S, at):
+            y, S = lm.ssd_step(*at, S)
+            return S, y
+        first = lambda t: jnp.moveaxis(t, 1, 0)
+        S, y = jax.lax.scan(
+            one, jnp.zeros((B, *mixer.state_shape), jnp.float32),
+            tuple(map(first, (x, Bm, Cm, g, dt))))
+        return jnp.moveaxis(y, 0, 1), S
+
+    ref_y, ref_S = jax.jit(by_position)(x, Bm, Cm, g, dt)
+    chunked = jax.jit(lm.ssd_chunked)
+    (y, after), s = timed(lambda: jax.block_until_ready(
+        chunked(x, Bm, Cm, g, dt, None)))
+    require_close(ph, f"ssd_chunked output (window of {T} from no state, "
+                      f"{heads} heads of {P} on {G} group(s) of {N})", y,
+                  ref_y, 1e-4)
+    require_close(ph, "its closing state", after, ref_S, 1e-4)
+    say(ph, f"a window of {T} in chunks of {lm.SSD_CHUNK}: first call "
+            f"{s:.2f}s")
+    half = T // 2
+    ops = (x, Bm, Cm, g, dt)
+    head = chunked(*(t[:, :half] for t in ops), None)[1]
+    y2, carried = chunked(*(t[:, half:] for t in ops), head)
+    require_close(ph, f"ssd_chunked output of {T - half} positions on the "
+                      f"state of {half}", y2, ref_y[:, half:], 1e-4)
+    require_close(ph, "its state against the whole window's", carried,
+                  after, 1e-4)
+    before = chunked(*(t[:, :-1] for t in ops), None)[1]
+    last = tuple(t[:, -1] for t in ops)
+    y1, stepped = jax.jit(lm.ssd_step)(*last, before)
+    require_close(ph, "ssd_step output after the window", y1, ref_y[:, -1],
+                  1e-4)
+    require_close(ph, "ssd_step state against the chunked form's", stepped,
+                  after, 1e-4)
+    done += ["ssd_chunked", "ssd_step"]
+
+    # ---- the fused kernel, in place in the cache manager's array -------
+    shape = (layers, slots, *mixer.state_shape)
+    if not ss.ssd_step_fits(shape, jnp.float32):
+        return done
+    layer = layers // 2
+    lay = lambda word: kv_cache.DenseLayout(
+        (0, 1, 1, P, 8), {"ssd_step": word}, recurrent=(layers, mixer))
+    few = min(layers, 3)
+    at = few // 2
+    small = lambda: jnp.stack([before] * few)
+    step = lambda word: jax.jit(lambda ssm: lay(word).advance_ssd(
+        *last, ssm, jnp.int32(at)))
+    want_y, want_ssm = step(False)(small())
+    y, ssm = step(True)(small())
+    require_close(ph, f"ssd_step kernel output ({B} slots x {G} matrices "
+                      f"of [{N}, {mixer.group_width}], {few} layers)", y,
+                  want_y, 1e-4)
+    require_close(ph, "ssd_step kernel state", ssm[at], want_ssm[at], 1e-5)
+    require(bool((ssm[:at] == before).all()
+                 and (ssm[at + 1:] == before).all()), ph,
+            "ssd_step kernel leaves the other layers", "bit for bit")
+    done.append("ssd_step_fused")
+    if jax.default_backend() != "tpu":     # the interpreter: no times
+        return done
+
+    # ---- alone, at the cell's matrices: us a call ----------------------
+    B = slots
+    x1, B1, C1 = rand(B, heads, P), rand(B, G, N), rand(B, G, N)
+    dt1 = jax.nn.softplus(rand(B, heads))
+    g1 = -dt1
+    tile = rand(1, 1, *mixer.state_shape)
+    full = lambda: jnp.broadcast_to(tile, shape) + 0.0
+
+    def per_call(fn):
+        """Seconds a call of ``fn(x, ssm) -> (y, ssm)``: ``reps`` calls in
+        one program, the array donated and carried and each call's input
+        made from the last one's output."""
+        def chained(_, c):
+            y, ssm = fn(*c)
+            return c[0] + 1e-3 * y, ssm
+
+        many = jax.jit(lambda ssm: jax.lax.fori_loop(
+            0, reps, chained, (x1, ssm)), donate_argnums=0)
+        ssm = jax.block_until_ready(many(full()))[1]
+        return timed(lambda: jax.block_until_ready(many(ssm)))[1] / reps
+
+    def plain(x_, ssm):
+        where = (layer,) + (0,) * (ssm.ndim - 1)
+        rows = jax.lax.dynamic_slice(ssm, where, (1, *ssm.shape[1:]))
+        return x_, jax.lax.dynamic_update_slice(ssm, rows * 0.999, where)
+
+    took = {
+        "plain copy": per_call(plain),
+        "kernel": per_call(lambda x_, ssm: ss.ssd_step_fused(
+            x_, B1, C1, g1, dt1, ssm, jnp.int32(layer))),
+        "composed step": per_call(lambda x_, ssm: lay(False).advance_ssd(
+            x_, B1, C1, g1, dt1, ssm, jnp.int32(layer)))}
+    moved = 2 * 4 * B * int(np.prod(mixer.state_shape))
+    say(ph, f"state step of one of {layers} layers, {B} slots x {G} "
+            f"matrices of [{N}, {mixer.group_width}], {moved / 1e6:.1f} MB "
+            f"there and back, us a call alone: " + ", ".join(
+                f"{name} {t * 1e6:.1f} ({moved / t / 1e9:.0f} GB/s)"
+                for name, t in took.items()))
+    done.append("ssd_step_alone")
+    return done
+
+
 def ring_kernels_phase(devices, *, interpret: bool, elems: int = 1 << 18,
                        matmul_shape=(1024, 1024, 1024), seed: int = 0) -> list:
     """The three ring kernels whole, inside ``shard_map`` over
@@ -1457,6 +1600,7 @@ def main() -> int:
                   f"{hybrid_latent_phase()}")
     say("retention", f"agreed with their composed forms: "
                      f"{retention_block_phase()}")
+    say("ssd", f"agreed with their composed forms: {ssd_block_phase()}")
 
     if n > 1:
         multichip_phase(cfg, params, prompts, dense["tokens"],

@@ -140,6 +140,22 @@ def test_retention_block_phase_at_toy_width():
     assert done == ["retention_chunked", "retention_step"]
 
 
+def test_ssd_block_phase_at_toy_width():
+    """A state-space stack's state step at 8 heads of 16 over a state of
+    16 (one lane tile of width, so the kernel runs under the interpreter
+    too): the chunked form and the recurrence position by position, the
+    kernel against the composed step through the cache manager's seam;
+    the times are the chip's."""
+    done = chip_smoke.ssd_block_phase(
+        slots=2, heads=8, head_dim=16, state=16, groups=1, layers=3,
+        check_slots=2, window=70)
+    assert done == ["ssd_chunked", "ssd_step", "ssd_step_fused"]
+    # half a lane tile of width: the phase stops where the kernel would run
+    assert chip_smoke.ssd_block_phase(
+        slots=2, heads=4, head_dim=16, state=16, layers=2, check_slots=2,
+        window=20) == ["ssd_chunked", "ssd_step"]
+
+
 @pytest.mark.slow
 def test_kernel_phase_at_toy_width_under_the_interpreter(lm):
     done = chip_smoke.kernels_phase(

@@ -79,12 +79,13 @@ def _model(family):
         "latent-routed": ("deepseek-v2-lite", "latent_moe_lm_serving"),
         "kda-latent": ("ling-3.0-flash", "hybrid_latent_moe_lm_serving"),
         "retention": ("brumby-14b-base", "retention_lm_serving"),
+        "ssd": ("granite-4.0-h-micro", "hybrid_ssd_lm_serving"),
     }[family]
     return (*_routed(name, builder), {})
 
 
 FAMILIES = ["dense-postln", "looped", "linear-routed", "latent-routed",
-            "kda-latent", "retention", "dense-paged-prefix"]
+            "kda-latent", "retention", "ssd", "dense-paged-prefix"]
 RESIDENT = {0: 300, 2: 5}       # slot -> its resident prompt's length
 
 

@@ -638,6 +638,115 @@ def test_retention_stack_compiles_in_place(one_chip, program, monkeypatch):
         assert "adtk_retention_step" not in text
 
 
+# three layers of the benchmark's state-space stack at its published
+# widths (64 heads of 64 over one shared B/C of 128; 32 query heads on 8
+# key/value heads of 64; an FFN of 8,192; an eighth of the vocabulary) at
+# the cell's 64 slots of 3,072 positions and its [1, 1024] prompt row: a
+# state-space layer, an attention layer, a state-space layer
+@pytest.mark.parametrize("program", ["decode-kernel", "prefill"])
+def test_ssd_stack_compiles_in_place(one_chip, program, monkeypatch):
+    """Both programs hold what they are given — the stacked float32
+    matrices, the convolution tails and the attention layer's lanes alias
+    their outputs — and no op copies, slices, transposes or converts the
+    stacked matrices: what is left of their whole shape is the in-place
+    write (the kernel's aliased operand in decode, the
+    ``dynamic-update-slice`` of the admitted slot's rows in prefill).
+    ``decode-kernel``: the decode program a TPU process elects (here
+    forced through the kernel slot, the backend being the CPU's) — Mosaic
+    takes the ssd-step kernel at the cell's 64 slots of one ``[128,
+    4096]`` matrix, one call a state-space layer out of one lowering, and
+    the grouped dense decode kernel at four query heads a key/value head
+    of 64 with the block's own softmax scale."""
+    from autodist_tpu.models import pipeline_lm as lm
+    from autodist_tpu.models.transformer import (BlockSpec, LinearMixerSpec,
+                                                 TransformerConfig)
+    from autodist_tpu.serving import ServingEngine
+
+    fused = program == "decode-kernel"
+    if fused:
+        for name in ("ssd_step", "flash_decode"):
+            monkeypatch.setattr(
+                importlib.import_module(
+                    "autodist_tpu.kernel.pallas." + name),
+                "default_interpret", lambda: False)
+    bf16, slots, bucket, T, L = jnp.bfloat16, 64, 1024, 3072, 3
+    cfg = TransformerConfig(
+        vocab_size=12544, hidden_size=2048, num_layers=L, num_heads=32,
+        mlp_dim=8192, max_len=131072, dtype=bf16, dropout_rate=0.0,
+        attention_dropout_rate=0.0,
+        block=BlockSpec(
+            norm="rmsnorm", norm_placement="pre", norm_eps=1e-5,
+            positions="none", ffn="swiglu", bias=False, tied_head=True,
+            kv_heads=8, head_dim=64,
+            layer_period=("linear", "full", "linear"),
+            linear=LinearMixerSpec.ssd(64, 64, 128),
+            embedding_multiplier=12.0, residual_multiplier=0.22,
+            logits_scaling=8.0, softmax_scale=0.015625))
+    params = jax.tree.map(lambda shape: jnp.zeros(shape, bf16),
+                          lm.param_shapes(cfg),
+                          is_leaf=lambda x: isinstance(x, tuple))
+    engine = ServingEngine(
+        cfg, params, num_slots=slots, max_len=T, prefill_len=bucket,
+        decode_steps=8,
+        kernel={"ssd_step": fused, "flash_decode": fused})
+    c = engine.cache
+    assert engine.kv.state_kernel(c.state.ssm) == fused
+    assert bool(engine.kv.fused_block) == fused
+    assert c.state.ssm.shape == (2, slots, 1, 128, 4096)
+    assert c.state.conv.shape == (2, slots, 3 * 4352)
+    assert c.state.ssm.dtype == jnp.float32 and c.state.norm is None
+    assert c.k.shape == (1, slots, 8, T, 64)
+    sds = lambda a: jax.ShapeDtypeStruct(jnp.shape(a), a.dtype,
+                                         sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    head = (jax.tree.map(sds, engine.params), sds(c.k), sds(c.v),
+            i32(slots), i32(slots))
+    state = tuple(sds(a) for a in engine._state_args())
+    with jax.default_matmul_precision("default"):
+        if fused:
+            lowered = engine._decode_jit.lower(
+                *head, i32(slots, 1), i32(slots), jax.ShapeDtypeStruct(
+                    (slots,), jnp.bool_, sharding=one_chip),
+                *state)
+        else:
+            lowered = engine._prefill_jit.lower(
+                *head, i32(), i32(1, 1), i32(1), i32(1, bucket), i32(1),
+                *state)
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    held = sum(a.size * a.dtype.itemsize
+               for a in (*engine._state_args(), c.k, c.v))
+    # 2 x 64 matrices of 2 MB, 2 x 64 tails, a layer of lanes each way
+    assert held == 2 * 64 * (2_097_152 + 26_112) + 2 * 64 * 8 * T * 64 * 2
+    assert abs(mem.alias_size_in_bytes - held) < 4096
+    # a prompt row's FFN activations and its chunks' [64, 256, 256]
+    # weights; a decode step's rows; never a layer's slice of the state
+    # (134 MB)
+    assert mem.temp_size_in_bytes < (24 << 20 if fused else 320 << 20)
+    text = compiled.as_text()
+    whole = rf"f32\[2,{slots},1,128,4096\]"
+    assert not re.findall(
+        rf"= {whole}[^ ]* (?:copy|slice|transpose|convert)\(", text)
+    assert not re.findall(rf"f32\[(?:1,)?{slots},1,128,4096\]", text)
+    if fused:
+        assert len(re.findall(r"custom-call\([^\n]*adtk_ssd_step",
+                              text)) == 2
+        assert len(re.findall(r"custom-call\([^\n]*adtk_flash_decode",
+                              text)) == 1
+        assert not re.findall(rf"{whole}[^ ]* dynamic-update-slice\(",
+                              text)
+        stablehlo = lowered.as_text()
+        assert stablehlo.count("func.func private @ssd_step_layer") == 1
+        assert len(re.findall(r"call @ssd_step_layer\(", stablehlo)) == 2
+    else:
+        # the admitted slot's matrix, written where it lies: once a layer
+        assert len(re.findall(
+            rf"ROOT [^ ]+ = {whole}[^ ]* dynamic-update-slice\(",
+            text)) == 2
+        assert "adtk_ssd_step" not in text
+
+
 # one encoder layer's attention at the training cell's widths (BERT-base:
 # 12 heads of 64, 512 positions) and at heads of 128, forward and
 # backward: Mosaic takes the one-pass kernels' tiles, and no array of the

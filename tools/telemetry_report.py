@@ -34,14 +34,17 @@ _PRECISION_BITS = {"fp32": 32, "bf16": 16, "int8": 8}
 # means the election was silently dropped — --check fails it.
 _KERNEL_CHOICES = ("flash_decode", "flash_prefill", "quant_ring",
                    "collective_matmul", "a2a_ring", "flash_attention",
-                   "delta_step", "grouped_matmul", "retention_step")
+                   "delta_step", "grouped_matmul", "retention_step",
+                   "ssd_step")
 # Elections made where the kernel is called, reported as 1 (the fused
 # kernel) or 0 (the composed path) by the engine that makes them, and
 # only by it: gauge -> (that engine's own gauge, who it is, what 0 says).
 # kernel/delta_step_elected: how a decode step advances a stack's
 # recurrent state (serving/kv_cache.py DenseLayout.advance_state);
 # kernel/retention_step_elected: the same of a power-retention stack
-# (DenseLayout.advance_retention).
+# (DenseLayout.advance_retention); kernel/ssd_step_elected: of a stack
+# of Mamba-2 state-space layers (DenseLayout.advance_ssd), whose decode
+# program counts its traced calls of the kernel in kernel/ssd_step_calls.
 # kernel/latent_decode_elected: how a decode step attends over cached
 # latent rows (LatentLayout.decode_attend) — the latent kernel over the
 # live blocks, which sets kernel/flash_decode_elected too (the kernel
@@ -52,6 +55,10 @@ _OBSERVED_ELECTIONS = {
         "only an engine that holds a recurrent state elects how to "
         "advance it", "the composed step"),
     "kernel/retention_step_elected": (
+        "engine/state_bytes_per_slot",
+        "only an engine that holds a recurrent state elects how to "
+        "advance it", "the composed step"),
+    "kernel/ssd_step_elected": (
         "engine/state_bytes_per_slot",
         "only an engine that holds a recurrent state elects how to "
         "advance it", "the composed step"),
@@ -136,6 +143,7 @@ _STATE_GAUGES = ("kv/state_rows", "kv/state_bytes",
 # engine/state_prompts: the states the prefill programs built, one a
 # prompt and linear layer — whole layers of engine/prefill_rows.
 _STATE_PROMPTS = "engine/state_prompts"
+_SSD_CALLS = "kernel/ssd_step_calls"
 # engine/state_prompts_blank: those of them built from no state (a
 # prompt's pass that starts at position 0) — beside engine/state_prompts
 # alone, and never more of them.
@@ -836,6 +844,15 @@ def check_schema(run_dir: str) -> list[str]:
             f"{blank.get('value')!r} beside {_STATE_PROMPTS} = "
             f"{(built or {}).get('value')!r} — the states built from no "
             "state are some of the states built")
+
+    # kernel/ssd_step_calls: a decode program traced the state-space
+    # kernel — only where the engine's election said 1.
+    if counters.get(_SSD_CALLS) is not None and (
+            gauges.get("kernel/ssd_step_elected") or {}).get("value") != 1:
+        problems.append(
+            f"metrics.jsonl: {_SSD_CALLS} without kernel/ssd_step_elected "
+            "= 1 — a program calls the kernel only where the engine's "
+            "layout elected it")
 
     latent = counters.get(_LATENT_COUNTER)
     if latent is not None:
