@@ -32,7 +32,11 @@ def bert_large(**kw) -> TransformerConfig:
 
 
 class BertModel(nn.Module):
-    """Embeddings + encoder + MLM transform head."""
+    """Embeddings + encoder + MLM transform head.
+
+    Returns ``(logits, bias)``: the tied decode's product over the
+    flattened masked positions, ``[B*P, V]`` in the model's dtype, and
+    the float32 output bias ``[V]``; :func:`mlm_loss_head` takes both."""
 
     cfg: TransformerConfig
 
@@ -65,38 +69,49 @@ class BertModel(nn.Module):
         x = Encoder(cfg, name="encoder")(x, attn_mask, deterministic)
 
         # MLM head: gather masked positions (static count), transform,
-        # decode against the tied embedding table.
+        # decode against the tied embedding table.  The B x P masked
+        # positions are ONE axis from here to the loss: B*P rows pad to a
+        # whole tile once, where [B, P] pads P in every row of B, and the
+        # table's gradient is then one contraction over all of them.
         with scope("lm_head"):
             gathered = jnp.take_along_axis(
                 x, masked_pos[..., None], axis=1)     # [B, P, H]
+            gathered = gathered.reshape(-1, cfg.hidden_size)  # [B*P, H]
             h = nn.Dense(cfg.hidden_size, dtype=cfg.dtype,
                          name="mlm_dense")(gathered)
             h = nn.gelu(h)
             h = nn.LayerNorm(dtype=cfg.dtype, name="mlm_ln")(h)
             # Tied-embedding decode on the MXU in model dtype (the
-            # [H, V] matmul is the head's FLOP bulk); logits promoted to
-            # fp32 for the softmax by the loss head.
-            logits = embed.attend(h).astype(jnp.float32)  # [B, P, V]
-            logits = logits + self.param(
-                "mlm_bias", nn.initializers.zeros, (cfg.vocab_size,),
-                jnp.float32)
-        return logits
+            # [H, V] matmul is the head's FLOP bulk), stored once as it
+            # comes; the loss head adds the bias in fp32 inside its reads.
+            logits = embed.attend(h)                  # [B*P, V]
+            bias = self.param("mlm_bias", nn.initializers.zeros,
+                              (cfg.vocab_size,), jnp.float32)
+        return logits, bias
 
 
 @scope("lm_head")
-def mlm_loss_head(logits, batch):
+def mlm_loss_head(logits, batch, bias):
     """Masked-LM cross entropy over the static masked positions.
+
+    ``logits`` are ``[B*P, V]`` as :class:`BertModel` hands them (a
+    ``[B, P, V]`` caller is flattened here) in the model's dtype, and
+    the logits proper are ``float32(logits) + bias``.  Each reduction
+    forms those inside its own read of the stored product, and the
+    target is gathered from the product itself plus ``bias[label]`` (the
+    same float32, bit for bit), so nothing of the logits' size is ever
+    written in float32.
 
     ``ll = logit[target] - logsumexp(logits)`` instead of a full
     ``log_softmax``: mathematically identical, but skips materializing a
-    second [B, P, V] tensor (one full HBM write+read of the logits'
-    size per step)."""
-    labels = batch["masked_ids"]       # [B, P]
-    weights = batch["masked_weights"]  # [B, P] 0 for padding predictions
-    logits = logits.astype(jnp.float32)
-    lse = jax.scipy.special.logsumexp(logits, axis=-1)           # [B, P]
-    target = jnp.take_along_axis(logits, labels[..., None],
-                                 axis=-1)[..., 0]
+    second tensor of the logits' size."""
+    labels = batch["masked_ids"].reshape(-1)        # [B*P]
+    weights = batch["masked_weights"].reshape(-1)   # 0 for padding predictions
+    logits = logits.reshape(-1, logits.shape[-1])
+    target = jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+    target = target.astype(jnp.float32) + bias[labels]
+    logits = logits.astype(jnp.float32) + bias
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)           # [B*P]
     ll = target - lse
     denom = jnp.maximum(weights.sum(), 1.0)
     loss = -(ll * weights).sum() / denom
@@ -124,10 +139,10 @@ def make_mlm_trainable(cfg: TransformerConfig, optimizer, rng,
                            deterministic=True)
 
     def loss(params, extra, batch, step_rng):
-        logits = model.apply({"params": params}, batch,
-                             deterministic=False,
-                             rngs={"dropout": step_rng})
-        l, metrics = mlm_loss_head(logits, batch)
+        logits, bias = model.apply({"params": params}, batch,
+                                   deterministic=False,
+                                   rngs={"dropout": step_rng})
+        l, metrics = mlm_loss_head(logits, batch, bias)
         return l, extra, dict(metrics, loss=l)
 
     return Trainable(loss, variables["params"], optimizer,

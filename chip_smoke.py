@@ -217,8 +217,12 @@ def training_phase(cfg, *, resource_spec, batch_per_device: int,
 
     def host_loss():
         with jax.default_device(cpu):
-            f = jax.jit(lambda p, b: bert.mlm_loss_head(
-                model32.apply({"params": p}, b, deterministic=True), b)[0])
+            def loss32(p, b):
+                logits, bias = model32.apply({"params": p}, b,
+                                             deterministic=True)
+                return bert.mlm_loss_head(logits, b, bias)[0]
+
+            f = jax.jit(loss32)
             return float(f(jax.device_put(params0, cpu),
                            jax.device_put(batch, cpu)))
 

@@ -794,3 +794,108 @@ def test_training_attention_compiles_without_copies(one_chip, hidden,
     assert f"[{B},{heads},{length},{length}]" not in text
     sized = rf"= bf16\[{B},{length},(?:{hidden}|{3 * hidden}|{heads},\d+)\]"
     assert not re.findall(sized + r"[^ ]* (?:copy|transpose)\(", text)
+
+
+def _stored(text):
+    """``[(name, shape)]`` of the entry computation's instructions: what
+    the program keeps in memory between its fusions (a tuple's parts
+    each; what a fusion computes inside itself is not here)."""
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    out = []
+    for m in re.finditer(r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) [\w\-]+\(",
+                         entry, re.M):
+        out.extend((m.group(1), s) for s in re.findall(
+            r"\w+\[[\d,]*\](?:\{[^}]*\})?", m.group(2)))
+    return out
+
+
+def _padded_share(shape):
+    """Elements the tiled layout keeps over the elements of ``shape``
+    (``bf16[64,76,30522]{2,1,0:T(8,128)(2,1)}``: the two minor
+    dimensions are padded to whole tiles)."""
+    m = re.fullmatch(r"\w+\[([\d,]+)\]\{([\d,]+):T\((\d+),(\d+)\).*\}",
+                     shape)
+    dims = [int(d) for d in m.group(1).split(",")]
+    minor, second = (int(i) for i in m.group(2).split(",")[:2])
+    share = 1.0
+    for axis, tile in ((minor, int(m.group(4))), (second, int(m.group(3)))):
+        share *= -(-dims[axis] // tile) * tile / dims[axis]
+    return share
+
+
+# one encoder layer of the training cell's step at its own widths (64
+# sequences of 512, 76 predictions a sequence, BERT-base's 30,522-row
+# tied table, adamw with a bf16 first moment): the head's 64 x 76 rows
+# are one axis of 4,864 = 38 x 128, so the logits are stored once, in
+# bf16, with no padding; nothing of their size is written in float32;
+# and the table's gradient is one contraction over all 4,864 rows
+def test_training_head_stores_the_logits_once(one_chip, monkeypatch):
+    import dataclasses
+
+    import optax
+
+    from autodist_tpu.models import bert
+
+    B, L, P = 64, 512, 76
+    cfg = dataclasses.replace(bert.bert_base(dtype=jnp.bfloat16),
+                              num_layers=1)
+    V = cfg.vocab_size
+    opt = optax.adamw(1e-4, weight_decay=0.01, mu_dtype=jnp.bfloat16)
+    trainable = bert.make_mlm_trainable(
+        cfg, opt, jax.random.PRNGKey(0), batch_size=2, seq_len=L,
+        num_masked=P, with_input_mask=False)
+    fa = importlib.import_module("autodist_tpu.ops.flash_attention")
+    # what a one-chip TPU process observes, once the init has run here
+    monkeypatch.setattr(fa, "_backend_is_tpu", lambda: True)
+    monkeypatch.setattr(fa, "default_interpret", lambda: False)
+    monkeypatch.setattr(jax, "device_count", lambda: 1)
+
+    def step(params, opt_state, batch, rng):
+        def loss(p):
+            l, _, metrics = trainable.loss(p, None, batch, rng)
+            return l, metrics
+        (_, metrics), grads = jax.value_and_grad(loss, has_aux=True)(params)
+        updates, opt_state = opt.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, metrics
+
+    sds = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((B, L), jnp.int32, sharding=one_chip)
+    picks = jax.ShapeDtypeStruct((B, P), jnp.int32, sharding=one_chip)
+    batch = {"input_ids": ids, "segment_ids": ids,
+             "masked_positions": picks, "masked_ids": picks,
+             "masked_weights": jax.ShapeDtypeStruct(
+                 (B, P), jnp.float32, sharding=one_chip)}
+    with jax.default_matmul_precision("default"):
+        compiled = jax.jit(step, donate_argnums=(0, 1)).lower(
+            jax.tree.map(sds, trainable.params),
+            jax.tree.map(sds, jax.eval_shape(opt.init, trainable.params)),
+            batch, jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                        sharding=one_chip)).compile()
+    text = compiled.as_text()
+
+    # the logits: every stored array with the vocabulary beside the rows
+    rows = rf"(?:{B * P}|{B},{P}|{B},\d+,{P}|{P},{B})"
+    logits = [(n, s) for n, s in _stored(text)
+              if re.match(rf"\w+\[(?:{rows},{V}|{V},{rows})\]", s)]
+    assert len({n for n, _ in logits}) == 1, logits
+    (_, shape), = logits
+    assert shape.startswith(f"bf16[{B * P},{V}]"), shape
+    assert _padded_share(shape) < 1.003, shape
+    assert _padded_share("bf16[64,76,30522]{2,1,0:T(8,128)(2,1)}") > 1.05
+
+    # every product with the table or the logits among its operands or as
+    # its result is a plain contraction: no window over the batch
+    products = 0
+    for body in re.split(r"\n(?=%|ENTRY )", text):
+        shapes = dict(re.findall(r"^\s*(?:ROOT )?(%[\w.\-]+) = (\S+) ", body,
+                                 re.M))
+        for line in body.splitlines():
+            if " convolution(" not in line:
+                continue
+            result, operands = line.split(" convolution(", 1)
+            names = re.findall(r"%[\w.\-]+", operands.split(")", 1)[0])
+            if str(V) in result + " ".join(shapes.get(n, "") for n in names):
+                products += 1
+                assert "window={size=" not in line, line
+    assert products == 3        # the logits, dx, dW
